@@ -24,8 +24,8 @@ _decoders: Dict[str, Type["Decoder"]] = {}
 #
 # Host decoders read N tensors of one frame (boxes/classes/scores/num,
 # heatmaps+offsets, ...).  Draining them one .np() at a time costs N
-# device→host crossings per frame — on a remote/tunneled device each
-# blocking fetch is a full link round-trip.  ``drain_once`` packs every
+# device→host crossings per frame, each a blocking fetch that pays the
+# full round trip.  ``drain_once`` packs every
 # device-resident tensor into ONE uint8 array on the device (a jitted
 # bitcast+concat — no math, pure layout) and drains that single array,
 # then seeds each source Tensor's host cache from the split so later

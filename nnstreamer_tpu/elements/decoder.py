@@ -56,8 +56,7 @@ class TensorDecoder(TransformElement):
         # Host decoders read every tensor on host: start ALL device→host
         # copies before the first blocking read, so a multi-tensor frame
         # (e.g. boxes/classes/scores/num) costs one device round-trip
-        # instead of one per tensor — on remote/tunneled devices each
-        # blocking fetch is ~100 ms.  A device-rendering decoder
+        # instead of one per tensor.  A device-rendering decoder
         # (bounding_boxes option7=device) consumes the tensors in HBM,
         # and a device-PREREDUCING one (argmax/top-k/packed drain of a
         # device-resident frame) drains only its small reduced result —

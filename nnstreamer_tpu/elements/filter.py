@@ -188,9 +188,9 @@ class TensorFilter(Element):
     #: measure device *execution*, not async dispatch (XLA dispatch
     #: returns in ~µs regardless of the computation).  Sampling is
     #: TIME-based — at most one blocking sample per interval — because a
-    #: block costs a full device round-trip, which on a remote/tunneled
-    #: device is ~100 ms: a count-based every-Nth rule would burn a fixed
-    #: fraction of throughput on stats.  Unsampled invokes run ahead of
+    #: block drains the async run-ahead: a count-based every-Nth rule
+    #: would burn a fixed fraction of throughput on stats, whatever the
+    #: frame rate.  Unsampled invokes run ahead of
     #: the device.  ``latency=1`` forces every invoke synchronous
     #: (reference prop).  Per element, the ``stat-sample-interval-ms``
     #: property overrides this class-wide default (seconds here, ms on

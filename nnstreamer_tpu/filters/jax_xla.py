@@ -70,25 +70,30 @@ def _timed_first_call(fn: Callable, stats_key) -> Callable:
 
 
 def _aot_call(lowered, jitted: Callable, pkey: Optional[str] = None,
-              bucket: int = 0) -> Callable:
+              bucket: int = 0,
+              devices: Optional[Sequence[Any]] = None) -> Callable:
     """Serve dispatches from an already-traced ``Lowered``: AOT-compile
-    it on first use so the whole path costs one trace, falling back to
-    the jit wrapper if the AOT build or its stricter call signature
-    (exact avals, no weak-type promotion) rejects this program.  A
-    rejected *call* cannot have consumed donated buffers, so retrying
-    through ``jitted`` is safe.
+    it on first use so the whole path costs one trace.  A program the
+    backend refuses to BUILD raises here, once, with the backend's own
+    error — ``jitted`` would hand XLA the same program, so retrying
+    through it could only pay a second compile and lose the first
+    message.  Only the AOT *call* signature (exact avals, no weak-type
+    promotion) is stricter than jit dispatch: a rejected call cannot
+    have consumed donated buffers, so that one case retries through
+    ``jitted`` — logged, and counted as an ``aot_fallback`` compile so
+    the second build is never silent.
 
     ``pkey`` (``runtime/compilecache.py``) arms the persistent AOT
     cache for this executable: the first use tries to DESERIALIZE the
     program from ``NNS_TPU_COMPILE_CACHE_DIR`` (counted as a
-    ``persist_hit`` compile) before paying the XLA build, and a fresh
-    build is serialized back for the next process — the cold-start
-    removal ROADMAP item 3 asks for, measured by ``bench.py
+    ``persist_hit`` compile) onto ``devices`` — the executable's own
+    device list — before paying the XLA build, and a fresh build is
+    serialized back for the next process, measured by ``bench.py
     --lifecycle``."""
     # the Lowered (traced jaxpr + IR) lives in state, not the closure's
-    # free variables, so it can be dropped the moment the executable or
-    # the fallback is resolved — a long-running serving process must
-    # not pin megabytes of IR per (model, bucket)
+    # free variables, so it can be dropped the moment the executable is
+    # resolved — a long-running serving process must not pin megabytes
+    # of IR per (model, bucket)
     state: Dict[str, Any] = {"lowered": lowered}
     del lowered
 
@@ -100,19 +105,20 @@ def _aot_call(lowered, jitted: Callable, pkey: Optional[str] = None,
         if compiled is None:
             from ..runtime import compilecache as _pcache
 
-            try:
-                compiled = state["c"] = _pcache.load_or_compile(
-                    pkey, state["lowered"], bucket=bucket)
-            except Exception:  # noqa: BLE001 - backend-dependent AOT API
-                state["fb"] = jitted
-                state.pop("lowered", None)
-                return jitted(*args)
+            compiled = state["c"] = _pcache.load_or_compile(
+                pkey, state["lowered"], bucket=bucket, devices=devices)
             state.pop("lowered", None)
         try:
             return compiled(*args)
-        except (TypeError, ValueError):
-            # signature mismatch (AOT is stricter than jit dispatch):
-            # permanently fall back before any execution happened
+        except (TypeError, ValueError) as e:
+            # signature mismatch: permanently fall back before any
+            # execution happened
+            from ..utils.log import logw
+
+            logw("jax-xla: AOT executable (bucket %d) rejected its "
+                 "arguments, recompiling through jit: %s: %s",
+                 bucket, type(e).__name__, e)
+            COMPILE_STATS.record("aot_fallback", bucket=bucket)
             state["fb"] = jitted
             return jitted(*args)
 
@@ -667,6 +673,28 @@ class JaxXlaFilter(FilterSubplugin):
                                 bucket, placement,
                                 donate=self._donate)
 
+    def _exec_devices(self) -> List[Any]:
+        """The devices this instance's executables run on, in
+        assignment order — what a persisted executable must be loaded
+        back onto (``runtime/compilecache.load``)."""
+        if self._mesh is not None:
+            return list(self._mesh.devices.flat)
+        return [self._device]
+
+    def _capture_cost(self, model: ModelDef, lowered, bucket: int,
+                      avals) -> None:
+        """Executable cost capture (obs/xlacost.py) off a jit lowering:
+        static flops/bytes, keyed (model, bucket), with where — and on
+        what kind of device — the executable runs."""
+        _xlacost.capture(
+            model.name, lowered, bucket=bucket,
+            placement=self._placement_label(),
+            platform=self._platform(),
+            device_kind=self._exec_devices()[0].device_kind,
+            in_bytes=_avals_nbytes(avals),
+            out_bytes=_avals_nbytes(
+                _jax().tree_util.tree_leaves(lowered.out_info)))
+
     def _normalized_fn(self, model: ModelDef, in_spec: TensorsSpec):
         """The per-frame computation as one traceable callable: fused
         transform prologue + model + fused decoder epilogue, outputs
@@ -710,23 +738,16 @@ class JaxXlaFilter(FilterSubplugin):
         # Infer output schema without running the device: the jit
         # LOWERING yields the out avals AND the executable's static
         # cost (HLO cost analysis — no XLA build, measured ~1 ms) in
-        # one trace; eval_shape stays as the fallback for backends
-        # whose lowering stage lacks out_info/cost_analysis.
+        # one trace.
         avals = [jax.ShapeDtypeStruct(t.shape, t.dtype.np_dtype)
                  for t in in_spec.tensors]
-        lowered = None
         try:
-            try:
-                lowered = jitted.lower(*avals)
-                out_avals = jax.tree_util.tree_leaves(lowered.out_info)
-            except (AttributeError, TypeError):
-                lowered = None
-                out_avals = jax.tree_util.tree_leaves(
-                    jax.eval_shape(normalized, *avals))
+            lowered = jitted.lower(*avals)
         except Exception as e:
             raise FilterError(
                 f"jax-xla: model {model.name} rejects input {in_spec}: {e}"
             ) from e
+        out_avals = jax.tree_util.tree_leaves(lowered.out_info)
         # compile telemetry: one count per _compile call (`kind` names
         # the path — cold/reshape/reload), seconds = trace+abstract-eval
         # here plus the executable's first invocation (the lazy XLA
@@ -736,24 +757,17 @@ class JaxXlaFilter(FilterSubplugin):
         out_spec = TensorsSpec.from_shapes(
             [o.shape for o in out_avals],
             [np.dtype(o.dtype) for o in out_avals])
+        # bucket 0 is the single-frame executable; a reshape/reload
+        # overwrites the row so the gauges describe what currently serves
+        self._capture_cost(model, lowered, 0, avals)
         fn = jitted
-        if lowered is not None:
-            # executable cost capture (obs/xlacost.py): bucket 0 is the
-            # single-frame executable; a reshape/reload overwrites the
-            # row so the gauges describe what currently serves
-            _xlacost.capture(
-                model.name, lowered, bucket=0,
-                placement=self._placement_label(),
-                platform=self._platform(),
-                in_bytes=_avals_nbytes(avals),
-                out_bytes=_avals_nbytes(out_avals))
-            pkey = self._persist_key(model, in_spec, 0)
-            if pkey is not None:
-                # persistent cache armed: serve the single-frame path
-                # AOT off this same lowering too, so a warm-cache
-                # process skips the XLA build here exactly like on the
-                # bucket path (jit fallback on signature rejection)
-                fn = _aot_call(lowered, jitted, pkey=pkey, bucket=0)
+        pkey = self._persist_key(model, in_spec, 0)
+        if pkey is not None:
+            # persistent cache armed: serve the single-frame path AOT
+            # off this same lowering too, so a warm-cache process skips
+            # the XLA build here exactly like on the bucket path
+            fn = _aot_call(lowered, jitted, pkey=pkey, bucket=0,
+                           devices=self._exec_devices())
         return _Compiled(_timed_first_call(fn, skey), in_spec, out_spec,
                          with_pre=with_pre,
                          with_post=with_post,
@@ -871,14 +885,16 @@ class JaxXlaFilter(FilterSubplugin):
     @staticmethod
     def _put_input(jax, x, where):
         """``device_put`` one input to a device/sharding, counting the
-        host→device crossing into the transfer ledger (byte-exact; a
-        device→device reshard counts too — it crosses the boundary the
-        roundtrip floor is made of)."""
+        move into the transfer ledger byte-exact: ``h2d`` for a host
+        array, ``d2d`` for an array that is already device-resident (a
+        reshard onto this executable's layout travels chip to chip,
+        never through the host)."""
         if not _xfer.ACTIVE:
             return jax.device_put(x, where)
         t0 = time.perf_counter()
         y = jax.device_put(x, where)
-        _xfer.record("h2d", "input", int(getattr(x, "nbytes", 0)),
+        _xfer.record("d2d" if isinstance(x, jax.Array) else "h2d", "input",
+                     int(getattr(x, "nbytes", 0)),
                      time.perf_counter() - t0)
         return y
 
@@ -953,27 +969,17 @@ class JaxXlaFilter(FilterSubplugin):
         # executable cost capture for this bucket's window program: ONE
         # trace — the capture's Lowered is also what serves dispatches
         # (AOT-compiled on the first call, so the XLA build stays lazy
-        # and first-call-attributed exactly as before; jit's own call
-        # path would re-trace since lower() doesn't seed its cache)
-        lowered = None
-        try:
-            avals = [jax.ShapeDtypeStruct(t.shape, t.dtype.np_dtype)
-                     for _ in range(bucket) for t in in_spec.tensors]
-            lowered = jitted.lower(*avals)
-            _xlacost.capture(
-                model.name, lowered, bucket=bucket,
-                placement=self._placement_label(),
-                platform=self._platform(),
-                in_bytes=_avals_nbytes(avals),
-                out_bytes=_avals_nbytes(
-                    jax.tree_util.tree_leaves(lowered.out_info)))
-        except Exception:  # noqa: BLE001 - capture must not break compile
-            lowered = None
+        # and first-call-attributed; jit's own call path would re-trace
+        # since lower() doesn't seed its cache)
+        avals = [jax.ShapeDtypeStruct(t.shape, t.dtype.np_dtype)
+                 for _ in range(bucket) for t in in_spec.tensors]
+        lowered = jitted.lower(*avals)
+        self._capture_cost(model, lowered, bucket, avals)
         skey = COMPILE_STATS.record(
             "bucket", time.perf_counter() - t_compile0, bucket=bucket)
         fn = _aot_call(lowered, jitted,
                        pkey=self._persist_key(model, in_spec, bucket),
-                       bucket=bucket) if lowered is not None else jitted
+                       bucket=bucket, devices=self._exec_devices())
         return _timed_first_call(fn, skey)
 
     def _compile_batched_stacked(self, model: ModelDef,
@@ -1008,21 +1014,11 @@ class JaxXlaFilter(FilterSubplugin):
         if self._donate:
             kw["donate_argnums"] = tuple(range(nt))
         jitted = jax.jit(batched, **kw)
-        lowered = None
-        try:
-            avals = [jax.ShapeDtypeStruct((gbucket,) + tuple(t.shape),
-                                          t.dtype.np_dtype)
-                     for t in in_spec.tensors]
-            lowered = jitted.lower(*avals)
-            _xlacost.capture(
-                model.name, lowered, bucket=gbucket,
-                placement=self._placement_label(),
-                platform=self._platform(),
-                in_bytes=_avals_nbytes(avals),
-                out_bytes=_avals_nbytes(
-                    jax.tree_util.tree_leaves(lowered.out_info)))
-        except Exception:  # noqa: BLE001 - capture must not break compile
-            lowered = None
+        avals = [jax.ShapeDtypeStruct((gbucket,) + tuple(t.shape),
+                                      t.dtype.np_dtype)
+                 for t in in_spec.tensors]
+        lowered = jitted.lower(*avals)
+        self._capture_cost(model, lowered, gbucket, avals)
         skey = COMPILE_STATS.record(
             "bucket", time.perf_counter() - t_compile0, bucket=gbucket)
         # the stacked window program takes ONE (gbucket, ...) array per
@@ -1031,7 +1027,7 @@ class JaxXlaFilter(FilterSubplugin):
         fn = _aot_call(lowered, jitted,
                        pkey=self._persist_key(
                            model, ("stacked", in_spec), gbucket),
-                       bucket=gbucket) if lowered is not None else jitted
+                       bucket=gbucket, devices=self._exec_devices())
         return _timed_first_call(fn, skey)
 
     def _invoke_batched_stacked(self, frames: Sequence[Sequence[Any]],

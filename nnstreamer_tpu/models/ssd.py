@@ -147,9 +147,12 @@ def _preselect_top_k(scores, k: int):
     approximate top-k (ApproxTopK HLO — avoids a full sort of the anchor
     axis; recall ≥ the default 0.95 target, fine for a preselect that is
     already a heuristic); elsewhere exact ``lax.top_k``.  The backend
-    check happens at trace time, matching where the computation
-    compiles for the common (default-device) case."""
-    if jax.default_backend() == "tpu":
+    check (``ops.kernels.on_tpu``, shared with the Pallas kernels)
+    happens at trace time, matching where the computation compiles for
+    the common (default-device) case."""
+    from ..ops.kernels import on_tpu
+
+    if on_tpu():
         return jax.lax.approx_max_k(scores, k)
     return jax.lax.top_k(scores, k)
 

@@ -68,8 +68,9 @@ def yolo_init(key, num_classes: int = 80, width: int = 32,
               depth: int = 1) -> Params:
     """Init the v8-style pyramid network.  ``width`` scales channels;
     ``depth`` adds residual dw+pw refinement blocks per stage (the C2f
-    repeat analog) — width=64, depth=2 at 640px lands in real
-    yolov8n FLOPs territory (~9 GFLOP/frame vs yolov8n's 8.7)."""
+    repeat analog) — width=64, depth=2 at 640px is the bench
+    configuration, yolov8n-class work per frame (``bench.yolo_flops()``
+    computes the figure from the exact program)."""
     rng = _rng_of(key)
     c = [width, width * 2, width * 4, width * 8]
     p: Params = {

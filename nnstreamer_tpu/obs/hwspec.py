@@ -1,26 +1,27 @@
 """Hardware peak table — the denominator of every utilization figure.
 
-MFU and HBM-bandwidth utilization are ratios against *hardware* peaks,
-which until now lived as loose ``V5E_*`` constants inside ``bench.py``
-— invisible to the registry, so the live telemetry could report time
-but never "fraction of what the silicon could do".  This module is the
-one source of truth: ``bench.py`` imports its constants from here, and
-the scrape-time MFU join (:mod:`.xlacost`) resolves the running
-backend's spec through :func:`spec_for_platform`.
+MFU and HBM-bandwidth utilization are ratios against *hardware* peaks.
+This module is the one source of truth: ``bench.py`` imports its
+constants from here, and the scrape-time MFU join (:mod:`.xlacost`)
+resolves the spec of the device an executable was compiled for through
+:func:`spec_for_device_kind`.
 
-Unknown backends (the CPU tests run on, or a TPU generation not in the
-table) resolve to ``None``: cost capture still exports the program's
-flops / bytes / arithmetic intensity — those are computation-intrinsic
-— but no utilization gauge is derived, because a made-up peak would be
-worse than none.  :func:`set_override` lets a deployment (or a test)
-pin the spec explicitly, e.g. when modeling v5e numbers from a CPU dry
-run the way ``bench.py`` always has.
+The table is keyed by ``jax.Device.device_kind`` — the string the
+runtime reports for the silicon — not by the platform tag: every TPU
+generation says ``platform == "tpu"``, and quoting one generation's
+peaks for another would be a made-up number.  A kind that is not in
+the table (the CPU the tests run on, or a TPU generation nobody has
+entered) resolves to ``None``: cost capture still exports the
+program's flops / bytes / arithmetic intensity — those are
+computation-intrinsic — but no utilization gauge and no price is
+derived.  :func:`set_override` lets a test pin the spec explicitly.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import os
+import sys
 import threading
 from typing import Dict, Optional
 
@@ -42,24 +43,22 @@ class HwSpec:
         return self.peak_flops / self.hbm_bw if self.hbm_bw else 0.0
 
 
-#: v5e public spec — the numbers every bench figure has been quoted
-#: against since the first roofline block (197 TFLOP/s bf16, 819 GB/s
-#: HBM, 1,600 Gbps/chip aggregate ICI, $1.20/chip-hour on-demand list)
+#: TPU v5e, per chip.  Source: Google Cloud documentation, "TPU v5e"
+#: system architecture page — 197 TFLOP/s bf16, 819 GB/s HBM2e,
+#: 1,600 Gbit/s (= 200 GB/s) inter-chip interconnect.  $1.20 is the
+#: on-demand list price per chip-hour (Cloud TPU pricing page); a
+#: deployment's own price goes in NNS_TPU_CHIP_HOUR_USD.
 V5E = HwSpec(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
              ici_bw=200e9, chip_hour_usd=1.20)
 
-#: bench.py compatibility constants (satellite: one source of truth —
-#: the bench imports these instead of carrying its own copies)
-V5E_BF16_PEAK = V5E.peak_flops
-V5E_HBM_BW = V5E.hbm_bw
+#: the scaling projection's ICI figure (bench.py, tests/test_scaling_model.py)
 V5E_ICI_BYTES_PER_S = V5E.ici_bw
 
-#: platform tag (``jax.Device.platform``) -> spec.  TPU resolves to the
-#: v5e figures (the paper's target part); CPU and anything unknown maps
-#: to None — intensity-only reporting (see module docstring).
-PLATFORM_SPECS: Dict[str, Optional[HwSpec]] = {
-    "tpu": V5E,
-    "cpu": None,
+#: ``jax.Device.device_kind`` -> spec.  A v5e chip reports
+#: "TPU v5 lite".  Anything absent — CPU included — is unknown
+#: hardware: intensity-only reporting, no utilization, no price.
+DEVICE_KIND_SPECS: Dict[str, HwSpec] = {
+    "TPU v5 lite": V5E,
 }
 
 _lock = threading.Lock()
@@ -76,21 +75,31 @@ def set_override(spec: Optional[HwSpec]) -> Optional[HwSpec]:
     return prev
 
 
-def spec_for_platform(platform: Optional[str]) -> Optional[HwSpec]:
-    """The peak table entry for a backend platform tag, or None when
-    the hardware is unknown (utilization must not be derived)."""
+def spec_for_device_kind(device_kind: Optional[str]) -> Optional[HwSpec]:
+    """The peak table entry for a ``jax.Device.device_kind``, or None
+    when the hardware is unknown (utilization must not be derived)."""
     with _lock:
         if _override is not None:
             return _override
-    return PLATFORM_SPECS.get(str(platform or "").lower())
+    return DEVICE_KIND_SPECS.get(str(device_kind or ""))
 
 
-def chip_hour_price(platform: Optional[str] = None) -> float:
+def default_device_kind() -> Optional[str]:
+    """``device_kind`` of the process's first device; None while jax is
+    not even imported (a scrape must not be what initializes — and on
+    an accelerator host, claims — the backend)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.devices()[0].device_kind
+
+
+def chip_hour_price(device_kind: Optional[str]) -> float:
     """The $/chip-hour figure the tenant cost export multiplies
     device-seconds by (``nns_tenant_dollars_total``).  Resolution
     order: ``NNS_TPU_CHIP_HOUR_USD`` (deployment override — negotiated
     pricing differs from list), then the active spec override, then the
-    platform table.  0.0 when the hardware (and hence a price) is
+    device-kind table.  0.0 when the hardware (and hence a price) is
     unknown — a dollars figure from a made-up price would be worse
     than none; the tenant table still carries device-seconds."""
     env = os.environ.get("NNS_TPU_CHIP_HOUR_USD", "").strip()
@@ -99,9 +108,5 @@ def chip_hour_price(platform: Optional[str] = None) -> float:
             return max(float(env), 0.0)
         except ValueError:
             pass  # a malformed override must not break a scrape
-    spec = spec_for_platform(platform)
-    if spec is None and platform is None:
-        # no platform named: price against the default part (the same
-        # v5e-by-default stance the bench's roofline figures take)
-        spec = V5E
+    spec = spec_for_device_kind(device_kind)
     return spec.chip_hour_usd if spec is not None else 0.0

@@ -1583,7 +1583,7 @@ REGISTRY = MetricsRegistry(collect_stages=True,
 # -- dispatch cost attribution (nns_invoke_*) ---------------------------------
 
 #: phase histogram bounds (seconds): 10µs CPU-backend dispatches up to
-#: multi-second remote-tunnel round trips
+#: multi-second windows
 INVOKE_PHASE_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, .001,
                         .0025, .005, .01, .025, .05, .1, .25, .5, 1.0,
                         2.5, float("inf"))

@@ -151,13 +151,15 @@ class TenantStats:
         (pool, tenant).  Dollars derive from the CURRENT chip-hour
         price (``obs/hwspec.py``, env-overridable) — attribution stores
         time, never money."""
-        from .hwspec import chip_hour_price
-
-        usd_per_s = chip_hour_price() / 3600.0
         with self._lock:
             rows = [(pool, tenant, r.frames, r.device_ns, r.lat_total,
                      r.lat_within, dict(r.shed))
                     for (pool, tenant), r in sorted(self._rows.items())]
+        if not rows:
+            return []
+        from .hwspec import chip_hour_price, default_device_kind
+
+        usd_per_s = chip_hour_price(default_device_kind()) / 3600.0
         out: List[dict] = []
         for pool, tenant, frames, ns, lt, lw, shed in rows:
             dev_s = ns / 1e9

@@ -28,10 +28,11 @@ module makes model efficiency first-class telemetry:
   and ``runtime/serving.py`` when a model is opened.
 - **Roofline** — arithmetic intensity (flops/byte) against the
   hardware ridge (:mod:`.hwspec`) classifies every executable
-  compute- vs bandwidth-bound.  On an unknown backend (the CPU tests
-  run on) the spec resolves to None: flops / bytes / intensity still
-  export — they are properties of the program — but no utilization
-  gauge is derived.
+  compute- vs bandwidth-bound.  The spec is looked up by the
+  ``device_kind`` the executable was compiled for; on a kind the table
+  does not hold (the CPU the tests run on) it resolves to None: flops /
+  bytes / intensity still export — they are properties of the program
+  — but no utilization gauge is derived.
 
 Exported by the metrics registry like every other collected stat:
 ``nns_executable_{flops,bytes,peak_memory_bytes}{source,bucket,
@@ -46,7 +47,7 @@ import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 from . import hooks as _hooks
-from .hwspec import HwSpec, spec_for_platform
+from .hwspec import HwSpec, spec_for_device_kind
 
 #: fast-path flag (same contract as obs/transfer.py): honors the global
 #: obs kill switch at process start
@@ -89,13 +90,14 @@ def _peak_memory(ca: dict, in_bytes: int, out_bytes: int
 
 
 class _Row:
-    __slots__ = ("placement", "platform", "flops", "bytes",
+    __slots__ = ("placement", "platform", "device_kind", "flops", "bytes",
                  "peak_memory", "peak_memory_estimated", "in_bytes",
                  "out_bytes", "compiles")
 
     def __init__(self):
         self.placement = ""
         self.platform = ""
+        self.device_kind = ""
         self.flops = 0.0
         self.bytes = 0.0
         self.peak_memory = 0
@@ -128,7 +130,7 @@ class XlaCostStats:
 
     def record(self, source: str, bucket: int, placement: str,
                platform: str, ca: dict, in_bytes: int = 0,
-               out_bytes: int = 0) -> None:
+               out_bytes: int = 0, device_kind: str = "") -> None:
         key = (str(source), int(bucket))
         peak, est = _peak_memory(ca, in_bytes, out_bytes)
         with self._lock:
@@ -137,6 +139,7 @@ class XlaCostStats:
                 row = self._rows[key] = _Row()
             row.placement = str(placement)
             row.platform = str(platform)
+            row.device_kind = str(device_kind)
             row.flops = float(ca.get("flops", 0.0) or 0.0)
             row.bytes = float(ca.get("bytes accessed", 0.0) or 0.0)
             row.peak_memory = peak
@@ -264,7 +267,7 @@ class XlaCostStats:
                 continue
             acc = per_exec.get(key, (0.0, 0))
             per_exec[key] = (acc[0] + dsum, acc[1] + dcount)
-            spec = spec_for_platform(row.platform)
+            spec = spec_for_device_kind(row.device_kind)
             util = _utilization(row, spec, dsum, dcount)
             if util:
                 with self._lock:
@@ -272,7 +275,7 @@ class XlaCostStats:
                 samples.append({"labels": dict(labels), **util})
         table: List[dict] = []
         for (source, bucket), row in sorted(rows.items()):
-            spec = spec_for_platform(row.platform)
+            spec = spec_for_device_kind(row.device_kind)
             entry = {
                 "source": source, "bucket": bucket,
                 "placement": row.placement, "platform": row.platform,
@@ -330,7 +333,8 @@ XLA_COST = XlaCostStats()
 
 def capture(source: str, lowered: Any, bucket: int = 0,
             placement: str = "", platform: str = "",
-            in_bytes: int = 0, out_bytes: int = 0) -> None:
+            in_bytes: int = 0, out_bytes: int = 0,
+            device_kind: str = "") -> None:
     """Record one executable's static cost from its jit lowering —
     called at the ``_compile`` / ``_compile_batched`` seams.  Inert
     under the global obs kill switch; never raises (a backend without
@@ -341,7 +345,8 @@ def capture(source: str, lowered: Any, bucket: int = 0,
     if not ca:
         return
     XLA_COST.record(source, bucket, placement, platform, ca,
-                    in_bytes=in_bytes, out_bytes=out_bytes)
+                    in_bytes=in_bytes, out_bytes=out_bytes,
+                    device_kind=device_kind)
 
 
 def map_source(source: str, model: str) -> None:

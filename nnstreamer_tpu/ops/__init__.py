@@ -11,6 +11,7 @@ don't tile or Pallas is unavailable.
 
 from .kernels import (
     flash_attention,
+    flash_attention_available,
     flash_attention_reference,
     scale_bias_cast,
     scale_bias_cast_available,
@@ -18,5 +19,6 @@ from .kernels import (
 
 __all__ = [
     "scale_bias_cast", "scale_bias_cast_available",
-    "flash_attention", "flash_attention_reference",
+    "flash_attention", "flash_attention_available",
+    "flash_attention_reference",
 ]

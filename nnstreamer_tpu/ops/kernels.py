@@ -14,10 +14,13 @@ Parity/role:
   (parallel/collectives.ring_attention rotates K/V blocks between chips
   with the same math).
 
-Both compile natively on TPU and run under the Pallas interpreter on
-CPU backends (tests); callers use the jnp reference automatically when
-shapes don't meet the tiling constraints (lane dim multiple of 128,
-sublane multiple of 8 for f32).
+Both compile natively on TPU (Mosaic) and run under the Pallas
+interpreter on CPU backends (tests).  A shape that does not meet the
+tiling constraints — lane dim a multiple of 128, sublane dim a multiple
+of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32 of
+1-byte elements) — takes the jnp reference: the ``*_available``
+predicates are the whole eligibility rule, so the fallback is a
+decision made here, never an exception caught somewhere.
 """
 
 from __future__ import annotations
@@ -28,7 +31,12 @@ from typing import Optional
 import numpy as np
 
 _LANE = 128
-_SUBLANE = 8
+
+
+def _sublane(dtype) -> int:
+    """Rows of one (sublane x 128) VMEM tile of ``dtype``: a tile is
+    8 x 128 32-bit words, so narrower elements pack more rows."""
+    return 32 // max(min(np.dtype(dtype).itemsize, 4), 1)
 
 
 def _pl():
@@ -39,46 +47,61 @@ def _pl():
     return jax, pl, pltpu
 
 
-def _interpret() -> bool:
+def on_tpu() -> bool:
+    """Whether programs built now compile for a TPU.  The ONE place the
+    package asks: the kernels below (Mosaic vs the Pallas interpreter)
+    and ``models/ssd.py`` (approximate vs exact top-k) both read it,
+    and ``chip_smoke.py`` asserts what it returns on the chip."""
     import jax
 
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "tpu"
+
+
+def _interpret() -> bool:
+    return not on_tpu()
 
 
 # -- fused scale/bias/cast ---------------------------------------------------
 
 
-def scale_bias_cast_available(shape, in_dtype, rows: int = _SUBLANE) -> bool:
-    """Kernel eligibility: element count must tile into (8k, 128) blocks
-    and the input must not be float64 (the kernel computes in f32; f64
-    inputs take the precision-preserving jnp fallback)."""
+def scale_bias_cast_available(shape, in_dtype,
+                              out_dtype=np.float32) -> bool:
+    """Kernel eligibility: the element count must tile into
+    (rows, 128) blocks whose row count suits BOTH the input's and the
+    output's tile height (a uint8 frame tiles at 32 rows, an f32 result
+    at 8), and the input must not be float64 (the kernel computes in
+    f32; f64 inputs take the precision-preserving jnp fallback)."""
     if np.dtype(in_dtype) == np.dtype(np.float64):
         return False
     n = int(np.prod(shape))
-    return n % (_LANE * rows) == 0
+    rows = max(_sublane(in_dtype), _sublane(out_dtype))
+    return n > 0 and n % (_LANE * rows) == 0
 
 
 def scale_bias_cast(x, scale: float, bias: float, out_dtype=np.float32,
                     block_rows: int = 256):
     """``((x + bias) * scale).astype(out_dtype)`` as one tiled VPU kernel.
 
-    Accepts any shape whose element count tiles into (8k, 128) blocks;
+    Accepts any shape :func:`scale_bias_cast_available` admits;
     otherwise computes the jnp reference.
     """
     import jax.numpy as jnp
 
     out_dtype = jnp.dtype(out_dtype)
     n = int(np.prod(x.shape))
-    if not scale_bias_cast_available(x.shape, x.dtype):
+    if not scale_bias_cast_available(x.shape, x.dtype, out_dtype):
         # fallback computes at the input's precision when it is wider
         ct = jnp.promote_types(x.dtype, jnp.float32)
         return ((x.astype(ct) + bias) * scale).astype(out_dtype)
     jax, pl, pltpu = _pl()
     rows = n // _LANE
-    block = min(block_rows, rows)
+    # the largest block <= block_rows that divides the rows AND is a
+    # whole number of tiles of both dtypes (eligibility guarantees
+    # ``step`` itself divides the rows, so the search terminates)
+    step = max(_sublane(x.dtype), _sublane(out_dtype))
+    block = max(min(block_rows, rows) // step * step, step)
     while rows % block:
-        block //= 2
-    block = max(block, _SUBLANE)
+        block -= step
 
     def kernel(in_ref, out_ref):
         v = in_ref[:]
@@ -116,12 +139,29 @@ def flash_attention_reference(q, k, v, scale: Optional[float] = None):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
+def flash_attention_available(q_shape, k_shape, dtype,
+                              block_q: int = 128,
+                              block_k: int = 128) -> bool:
+    """Kernel eligibility for (..., S, D) queries against (..., Sk, D)
+    keys: D and the K block whole multiples of the 128 lanes (the
+    running max/normalizer are kept lane-replicated and widened by
+    whole-lane repeats), S and Sk whole numbers of blocks, and the Q
+    block a whole number of ``dtype`` tiles."""
+    S, D = q_shape[-2], q_shape[-1]
+    Sk = k_shape[-2]
+    block_q = min(block_q, S)
+    block_k = min(block_k, Sk)
+    return not (D % _LANE or block_k % _LANE
+                or S % block_q or Sk % block_k
+                or block_q % _sublane(dtype))
+
+
 def flash_attention(q, k, v, scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128):
     """Blockwise attention, never materializing the (S, S) scores.
 
-    q/k/v: (..., S, D) with D a multiple of 128 and S a multiple of the
-    block sizes — otherwise the jnp reference runs.  Leading dims are
+    q/k/v: (..., S, D) in a shape :func:`flash_attention_available`
+    admits — otherwise the jnp reference runs.  Leading dims are
     flattened into the grid's outer axis; the kernel keeps a running
     max/normalizer/accumulator in VMEM scratch across K blocks (online
     softmax), so VMEM holds only (block_q + 2·block_k) × D floats.
@@ -130,13 +170,13 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
 
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    if not flash_attention_available(q.shape, k.shape, q.dtype,
+                                     block_q, block_k):
+        return flash_attention_reference(q, k, v, scale)
     S, D = q.shape[-2], q.shape[-1]
     Sk = k.shape[-2]
     block_q = min(block_q, S)
     block_k = min(block_k, Sk)
-    if (D % _LANE or S % block_q or Sk % block_k
-            or block_q % _SUBLANE or block_k % _SUBLANE):
-        return flash_attention_reference(q, k, v, scale)
     jax, pl, pltpu = _pl()
     lead = q.shape[:-2]
     B = int(np.prod(lead)) if lead else 1
@@ -150,31 +190,34 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
 
         @pl.when(ik == 0)
         def _init():
-            m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-            l_ref[:] = jnp.zeros_like(l_ref)
-            acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+            l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
         qb = q_ref[0].astype(jnp.float32)           # (bq, D)
         kb = k_ref[0].astype(jnp.float32)           # (bk, D)
         s = jax.lax.dot_general(
             qb, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
-        # m/l scratch stores the per-row stats broadcast across a full
-        # lane so every access stays (8,128)-tile aligned
-        m_prev = m_ref[:][:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        # the per-row running max / normalizer live replicated across
+        # one full lane width, (bq, 128): every load, store and
+        # elementwise op stays on whole (8,128) tiles, and widening to
+        # the (bq, bk) / (bq, D) operands is a whole-lane repeat — no
+        # single-lane extract into a 1-D vector anywhere
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])             # (bq, bk)
-        l_new = l_ref[:][:, 0] * corr + jnp.sum(p, axis=-1)
-        l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
+        p = jnp.exp(s - pltpu.repeat(m_new, block_k // _LANE, 1))
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * pltpu.repeat(corr, D // _LANE, 1) \
+            + jax.lax.dot_general(
+                p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
 
         @pl.when(ik == nk - 1)
         def _finish():
-            o_ref[0] = (acc_ref[:] / l_ref[:][:, 0][:, None]
+            o_ref[0] = (acc_ref[:] / pltpu.repeat(l_ref[:], D // _LANE, 1)
                         ).astype(o_ref.dtype)
 
     out = pl.pallas_call(

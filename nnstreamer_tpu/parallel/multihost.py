@@ -122,5 +122,12 @@ def _mesh_by_process(jax, devices, dcn_shape, ici_shape):
             raise ValueError(
                 f"hybrid_mesh: ici axes {tuple(ici_shape)} want {nici} "
                 f"devices per process, process {pi} has {len(local)}")
+        if len(local) > nici:
+            from ..utils.log import logw
+
+            logw("hybrid_mesh: ici axes %s use %d of process %d's %d "
+                 "local devices; devices %s stay idle",
+                 tuple(ici_shape), nici, pi, len(local),
+                 [d.id for d in local[nici:]])
         ordered.extend(local[:nici])
     return np.array(ordered).reshape(tuple(dcn_shape) + tuple(ici_shape))

@@ -36,7 +36,7 @@ import os
 import pickle
 import threading
 import time
-from typing import Any, Callable, Optional
+from typing import Any, Optional, Sequence
 
 from ..utils.log import logw
 
@@ -194,10 +194,17 @@ def _entry_path(dirpath: str, key: str) -> str:
     return os.path.join(dirpath, key + CACHE_SUFFIX)
 
 
-def load(key: str) -> Optional[Any]:
+def load(key: str, devices: Optional[Sequence[Any]] = None
+         ) -> Optional[Any]:
     """Deserialize one cached executable; None on miss OR any failure
     (corrupt pickle, truncated payload, version-skewed program — the
-    bad entry is removed best-effort and counted as an error)."""
+    bad entry is removed best-effort and counted as an error).
+
+    ``devices`` is the device list the executable was compiled for, in
+    assignment order (one device, or the mesh's flat device list).
+    ``deserialize_and_load`` otherwise loads onto EVERY device of the
+    backend, and a one-device program then dies at its first call on
+    any host with more than one device."""
     dirpath = cache_dir()
     if dirpath is None:
         return None
@@ -210,7 +217,9 @@ def load(key: str) -> Optional[Any]:
 
         with open(path, "rb") as f:
             payload, in_tree, out_tree = pickle.load(f)
-        compiled = _se.deserialize_and_load(payload, in_tree, out_tree)
+        compiled = _se.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=list(devices) if devices else None)
     except Exception as e:  # noqa: BLE001 - ANY load failure means
         # "treat as miss and recompile"; a cache can corrupt in every
         # way a filesystem can, and none of them may break serving
@@ -264,18 +273,19 @@ def store(key: str, compiled: Any) -> bool:
 
 def load_or_compile(key: Optional[str], lowered: Any,
                     stats_kind: str = "persist_hit",
-                    bucket: int = 0) -> Any:
+                    bucket: int = 0,
+                    devices: Optional[Sequence[Any]] = None) -> Any:
     """The one seam ``filters/jax_xla._aot_call`` drives: try the
     persistent cache, fall back to ``lowered.compile()``, store the
     fresh build for the next process.  A cache hit is recorded into
     CompileStats under ``persist_hit`` with the DESERIALIZE time as its
     seconds — the number the cold-start gate compares against the
-    trace+build cost it replaced."""
+    trace+build cost it replaced.  ``devices``: see :func:`load`."""
     from ..utils.stats import COMPILE_STATS
 
     if key is not None:
         t0 = time.perf_counter()
-        cached = load(key)
+        cached = load(key, devices)
         if cached is not None:
             COMPILE_STATS.record(stats_kind,
                                  time.perf_counter() - t0,

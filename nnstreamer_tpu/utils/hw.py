@@ -13,7 +13,7 @@ filter's ``accelerator=`` property selects among these
 
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Any, Dict, List
 
 
 def probe() -> Dict[str, List[dict]]:
@@ -55,3 +55,24 @@ def accelerator_available(kind: str) -> bool:
         return bool(jax.devices(kind))
     except RuntimeError:
         return False
+
+
+def require_devices(need: int, who: str, cpu_devices: int = 8) -> List[Any]:
+    """The default backend's devices, at least ``need`` of them, or a
+    RuntimeError naming what JAX found.  The CPU client is sized to
+    ``cpu_devices`` first, so a run that SAYS ``JAX_PLATFORMS=cpu``
+    (CI, tests) gets its virtual mesh; an accelerator backend with too
+    few chips is an error, never traded for CPU devices behind the
+    caller's back."""
+    import jax
+
+    try:
+        jax.config.update("jax_num_cpu_devices", cpu_devices)
+    except RuntimeError:
+        pass  # backend already initialized: its device count stands
+    devs = jax.devices()
+    if len(devs) < need:
+        raise RuntimeError(
+            f"{who}: needs {need} devices; JAX found {len(devs)} "
+            f"({devs[0].platform}, {devs[0].device_kind})")
+    return devs

@@ -653,6 +653,46 @@ def test_persistent_cache_hits_and_counts(tmp_path, monkeypatch):
         unregister_model(name)
 
 
+def test_cache_load_roundtrips_onto_the_executables_own_devices(
+        tmp_path, monkeypatch):
+    """A host with several devices (this suite's 8 virtual CPUs; a
+    four-chip TPU host) must load a persisted executable back onto the
+    devices it was compiled for: a one-device program onto that one
+    device — not the default device 0, and not every device of the
+    backend — and a mesh program onto its mesh."""
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("NNS_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    devs = jax.devices()
+    assert len(devs) >= 8
+    x = np.arange(8, dtype=np.float32)
+
+    one = devs[3]
+    lowered = jax.jit(lambda v: jnp.tanh(v) * 2.0).lower(
+        jax.ShapeDtypeStruct((8,), np.float32,
+                             sharding=jax.sharding.SingleDeviceSharding(
+                                 one)))
+    assert compilecache.store("one-device", lowered.compile())
+    loaded = compilecache.load("one-device", [one])
+    out = loaded(jax.device_put(x, one))
+    assert out.sharding.device_set == {one}
+    np.testing.assert_allclose(np.asarray(out), np.tanh(x) * 2.0,
+                               rtol=1e-6)
+
+    mesh = jax.sharding.Mesh(np.array(devs[4:8]), ("data",))
+    sh = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec("data"))
+    lowered = jax.jit(lambda v: v + 1.0, in_shardings=sh,
+                      out_shardings=sh).lower(
+        jax.ShapeDtypeStruct((8,), np.float32))
+    assert compilecache.store("mesh", lowered.compile())
+    loaded = compilecache.load("mesh", list(mesh.devices.flat))
+    out = loaded(jax.device_put(x, sh))
+    assert out.sharding.device_set == set(devs[4:8])
+    np.testing.assert_allclose(np.asarray(out), x + 1.0)
+
+
 def test_persistent_cache_corruption_falls_back(tmp_path, monkeypatch):
     monkeypatch.setenv("NNS_TPU_COMPILE_CACHE_DIR", str(tmp_path))
     name = _heavyish("_t_lc_pc2")

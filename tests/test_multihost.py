@@ -84,15 +84,20 @@ class TestMeshByProcess:
         assert [[d.id for d in row] for row in arr] == [[0, 1],
                                                         [10, 11]]
 
-    def test_local_prefix_when_more_devices_than_ici(self):
+    def test_local_prefix_when_more_devices_than_ici(self, caplog):
         import jax
 
         from nnstreamer_tpu.parallel.multihost import _mesh_by_process
 
-        arr = _mesh_by_process(jax, self._devs(2, 3), (2,), (2,))
+        with caplog.at_level("WARNING", logger="nnstreamer_tpu"):
+            arr = _mesh_by_process(jax, self._devs(2, 3), (2,), (2,))
         # 3 local devices, ici wants 2: the lowest-id prefix serves
         assert [[d.id for d in row] for row in arr] == [[0, 1],
                                                         [10, 11]]
+        # ... and the devices left idle are named, once per process
+        idle = [r.getMessage() for r in caplog.records
+                if "stay idle" in r.getMessage()]
+        assert len(idle) == 2 and "[2]" in idle[0] and "[12]" in idle[1]
 
     def test_wrong_process_count_raises(self):
         import jax

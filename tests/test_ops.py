@@ -73,6 +73,69 @@ class TestFlashAttention:
                                    rtol=1e-5)
 
 
+class TestKernelEligibility:
+    """The ``*_available`` predicates are the whole decision between a
+    Pallas kernel and its jnp reference: a shape Mosaic could refuse
+    must be turned away HERE, not by an exception caught somewhere."""
+
+    @staticmethod
+    def pallas_calls(fn, *args):
+        import jax
+
+        return str(jax.make_jaxpr(fn)(*args)).count("pallas_call[")
+
+    def test_scale_bias_cast_tiles_by_the_narrowest_dtype(self):
+        from nnstreamer_tpu.ops import scale_bias_cast_available as ok
+
+        # a tile is 8 rows of 4-byte, 16 of 2-byte, 32 of 1-byte elements
+        assert ok((8, 128), np.float32)
+        assert not ok((8, 128), np.uint8)        # a quarter of a u8 tile
+        assert ok((32, 128), np.uint8)
+        assert not ok((8, 128), np.int16)
+        assert ok((16, 128), np.int16)
+        # the OUTPUT tiles too: f32 -> bf16 needs 16 rows
+        import jax.numpy as jnp
+
+        assert not ok((8, 128), np.float32, jnp.bfloat16)
+        assert ok((16, 128), np.float32, jnp.bfloat16)
+        assert not ok((0, 128), np.float32)
+
+    def test_scale_bias_cast_takes_the_path_the_predicate_names(self):
+        for shape, kernel in (((32, 128), 1), ((8, 128), 0),
+                              ((96, 128), 1)):   # 96 rows: block 96
+            x = np.ones(shape, np.uint8)
+            assert self.pallas_calls(
+                lambda v: scale_bias_cast(v, 2.0, 1.0), x) == kernel
+            np.testing.assert_allclose(
+                np.asarray(scale_bias_cast(x, 2.0, 1.0)), 4.0)
+
+    def test_flash_attention_eligibility(self):
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.ops import flash_attention_available as ok
+
+        assert ok((4, 256, 128), (4, 256, 128), jnp.bfloat16)
+        assert ok((1, 128, 128), (1, 512, 128), np.float32)
+        assert not ok((1, 256, 64), (1, 256, 64), np.float32)    # D
+        assert not ok((1, 64, 128), (1, 64, 128), np.float32)    # K block
+        assert not ok((1, 200, 128), (1, 256, 128), np.float32)  # ragged S
+        # the Q block is whole tiles of the dtype: 8 rows do for f32,
+        # not for bf16
+        assert ok((1, 8, 128), (1, 128, 128), np.float32)
+        assert not ok((1, 8, 128), (1, 128, 128), jnp.bfloat16)
+
+    def test_flash_attention_takes_the_path_the_predicate_names(self):
+        rng = np.random.default_rng(4)
+        for sq, sk, kernel in ((128, 256, 1), (64, 64, 0)):
+            q = rng.standard_normal((1, sq, 128)).astype(np.float32)
+            k = rng.standard_normal((1, sk, 128)).astype(np.float32)
+            assert self.pallas_calls(flash_attention, q, k, k) == kernel
+            np.testing.assert_allclose(
+                np.asarray(flash_attention(q, k, k)),
+                np.asarray(flash_attention_reference(q, k, k)),
+                rtol=2e-2, atol=2e-3)
+
+
 class TestTransformAcceleration:
     """acceleration=true folds affine arithmetic chains into the kernel
     (the reference's Orc acceleration analog)."""

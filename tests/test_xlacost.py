@@ -176,16 +176,43 @@ def test_unknown_backend_exports_intensity_only():
                for s in _fam_samples(snap, "nns_executable_flops"))
 
 
-def test_hwspec_resolution():
-    assert hwspec.spec_for_platform("tpu") is hwspec.V5E
-    assert hwspec.spec_for_platform("cpu") is None
-    assert hwspec.spec_for_platform("???") is None
+def test_hwspec_resolution(monkeypatch):
+    """The peak table is keyed by ``device_kind``: a v5e chip reports
+    "TPU v5 lite"; a TPU kind nobody entered — like the CPU — has no
+    peaks and no price, never another generation's."""
+    assert hwspec.spec_for_device_kind("TPU v5 lite") is hwspec.V5E
+    assert hwspec.spec_for_device_kind("TPU v99") is None
+    assert hwspec.spec_for_device_kind("cpu") is None
+    assert hwspec.spec_for_device_kind(None) is None
     assert hwspec.V5E.ridge == pytest.approx(197e12 / 819e9)
+    monkeypatch.delenv("NNS_TPU_CHIP_HOUR_USD", raising=False)
+    assert hwspec.chip_hour_price("TPU v5 lite") == hwspec.V5E.chip_hour_usd
+    assert hwspec.chip_hour_price("TPU v99") == 0.0
+    assert hwspec.chip_hour_price(None) == 0.0
     prev = hwspec.set_override(hwspec.V5E)
     try:
-        assert hwspec.spec_for_platform("cpu") is hwspec.V5E
+        assert hwspec.spec_for_device_kind("cpu") is hwspec.V5E
     finally:
         hwspec.set_override(prev)
+
+
+def test_join_derives_utilization_from_the_rows_device_kind():
+    """The join reads the ``device_kind`` captured with the executable:
+    a row compiled for a v5e gets an MFU, the same row on an unlisted
+    TPU kind gets none — ``platform == "tpu"`` alone decides nothing."""
+    for model, elem, kind in (("xc_v5emodel", "xc_v5eelem", "TPU v5 lite"),
+                              ("xc_v99model", "xc_v99elem", "TPU v99")):
+        XLA_COST.record(model, 0, "tpu", "tpu",
+                        {"flops": 1e9, "bytes accessed": 1e6},
+                        device_kind=kind)
+        XLA_COST.map_source(elem, model)
+        observe_invoke_phases("element", elem, 1, 0.0, 1e-3, 0.0)
+    snap = REGISTRY.snapshot()
+    by_source = {s["labels"].get("source"): s["value"]
+                 for s in _fam_samples(snap, "nns_mfu")}
+    assert by_source["xc_v5eelem"] == pytest.approx(
+        1e9 / (1e-3 * hwspec.V5E.peak_flops), rel=1e-9)
+    assert "xc_v99elem" not in by_source
 
 
 # -- mesh attribution ---------------------------------------------------------

@@ -415,6 +415,7 @@ class BoundingBoxes(Decoder):
         on-device."""
         if not self._device_active():
             return None
+        import jax
         import jax.numpy as jnp
 
         from .boxutil import device_render_fn
@@ -433,7 +434,9 @@ class BoundingBoxes(Decoder):
             num = outs[3].reshape(b) if len(outs) > 3 \
                 else jnp.full((b,), n, jnp.int32)
             render = device_render_fn(b, n, out_h, out_w, conf)
-            canvas = render(boxes, classes, scores, num)
+            # the stage the device trace shows as nns.post/overlay
+            with jax.named_scope("overlay"):
+                canvas = render(boxes, classes, scores, num)
             return (canvas, *outs)
 
         # persistent AOT cache identity (runtime/compilecache.py):

@@ -27,6 +27,7 @@ from ..runtime.element import (
 )
 from ..runtime.events import Event, EventKind, Message, MessageKind
 from ..runtime.registry import register_element
+from ..utils import profile as _profile
 
 
 @register_element("appsrc")
@@ -88,17 +89,22 @@ class AppSink(SinkElement):
         self._q: "_q.Queue" = _q.Queue(maxsize=int(self.max_buffers))
 
     def render(self, buf: Buffer) -> None:
-        if self.drop:
+        with _profile.span(self.name, "render"):
             try:
                 self._q.put_nowait(buf)
+                return
             except _q.Full:
+                pass
+            if self.drop:
                 try:
                     self._q.get_nowait()
                 except _q.Empty:
                     pass
                 self._q.put_nowait(buf)
-        else:
-            self._q.put(buf)
+            else:
+                # back-pressure: the consumer has not pulled yet
+                with _profile.span(self.name, "render_wait"):
+                    self._q.put(buf)
 
     def pull(self, timeout: Optional[float] = None) -> Optional[Buffer]:
         try:

@@ -26,6 +26,7 @@ import numpy as np
 from ..core import Buffer, Tensor, TensorsSpec
 from ..runtime.element import NegotiationError, SourceElement
 from ..runtime.registry import register_element
+from ..utils import profile as _profile
 
 
 _stage_seed = itertools.count(1)
@@ -68,7 +69,8 @@ class DeviceSrc(SourceElement):
         return self.spec
 
     def start(self) -> None:
-        self._stage_pool()
+        with _profile.span(self.name, "stage", setup=True):
+            self._stage_pool()
         super().start()
 
     def _stage_pool(self) -> None:

@@ -62,6 +62,19 @@ def _device_ids_of(t: Tensor) -> tuple:
         return ()
 
 
+_NO_MARK = _nullcontext()
+
+
+def _frames_marked(bufs: Sequence[Buffer]):
+    """Device-trace correlation for one dispatch: the sampled frames'
+    trace ids show up as a ``nns:frames:<ids>`` TraceAnnotation on the
+    TensorBoard timeline.  One shared null context unless a
+    ``pipeline_trace`` capture is active."""
+    if _profile.trace_active():
+        return _profile.frame_annotation(_trace_ids(bufs))
+    return _NO_MARK
+
+
 def _trace_ids(bufs: Sequence[Buffer]) -> List[str]:
     """Obs trace ids riding a dispatch's buffers (usually empty: only
     1-in-N sampled frames carry a trace)."""
@@ -250,6 +263,9 @@ class TensorFilter(Element):
             self.subplugin = self._pool_entry.subplugin
         else:
             sp = cls()
+            # the sub-plugin's spans (place, dispatch, compile) carry
+            # this element's name
+            sp.trace_owner = self.name
             sp.configure(fprops)
             if self._fused_pre and hasattr(sp, "set_fused_pre"):
                 # fusion pass inlined upstream transform chains into this
@@ -522,14 +538,10 @@ class TensorFilter(Element):
         # what this element spends per dispatch, so the sampled invoke
         # latency (and its phase split) starts here
         sample, t0 = self._sample_gate()
-        inputs = [t.jax() if device else t.np() for t in tensors]
+        with _profile.span(self.name, "prep"):
+            inputs = [t.jax() if device else t.np() for t in tensors]
         t1 = time.monotonic()
-        if _profile.trace_active():
-            # device-trace correlation: the sampled frame's trace id
-            # shows up as a TraceAnnotation on the TensorBoard timeline
-            with _profile.frame_annotation(_trace_ids([buf])):
-                outputs = sp.invoke(inputs)
-        else:
+        with _frames_marked((buf,)):
             outputs = sp.invoke(inputs)
         if getattr(sp, "_donate", False):
             # donation consumed the device-resident inputs' HBM
@@ -607,8 +619,9 @@ class TensorFilter(Element):
                 rp.mesh, jax.sharding.PartitionSpec())
         except Exception:  # noqa: BLE001 - single-chip re-home fallback
             pass
-        out = _devch.stage_handoff(buf, target,
-                                   chan=("stage", self.name))
+        with _profile.span(self.name, "place"):
+            out = _devch.stage_handoff(buf, target,
+                                       chan=("stage", self.name))
         out.meta[_STAGE_META] = True
         _stagestat.record_handoff(
             self.pipeline.name if self.pipeline is not None else "",
@@ -638,7 +651,8 @@ class TensorFilter(Element):
         sample = (bool(self.latency) or self._invoke_seq == 1 or
                   now - self._last_sample_ts >= interval)
         if sample and self._last_out is not None:
-            block_all([self._last_out])
+            with _profile.span(self.name, "sample_fence"):
+                block_all([self._last_out])
         return sample, time.monotonic()
 
     def _record_dispatch(self, outs: List[Any], t0: float,
@@ -656,7 +670,8 @@ class TensorFilter(Element):
         read the latency was recorded from, so the cost-attribution
         phases partition the recorded latency exactly."""
         if sample:
-            block_all(outs)
+            with _profile.span(self.name, "sample_fence"):
+                block_all(outs)
             t2 = time.monotonic()
             self.invoke_stats.record(t2 - t0, frames=frames)
             self._last_sample_ts = t2
@@ -733,14 +748,14 @@ class TensorFilter(Element):
         from ..runtime.batching import pick_bucket
 
         sp = self.subplugin
-        frames = [self._pool_frame_inputs(buf) for buf in bufs]
-        bucket = pick_bucket(len(frames), self._buckets)
+        # a deadline flush runs on the coalescer's timer thread, under
+        # no chain span: the window's spans carry its number themselves
+        window = getattr(self._batcher, "window_seq", None)
+        with _profile.span(self.name, "prep", window):
+            frames = [self._pool_frame_inputs(buf) for buf in bufs]
+            bucket = pick_bucket(len(frames), self._buckets)
         t1 = time.monotonic()
-        # device-trace correlation: the window's sampled trace ids ride
-        # the dispatch as a TraceAnnotation (no-op without an active
-        # jax profiler capture — guarded to keep the hot path free)
-        with _profile.frame_annotation(_trace_ids(bufs)) \
-                if _profile.trace_active() else _nullcontext():
+        with _frames_marked(bufs):
             if getattr(sp, "SUPPORTS_BATCH", False):
                 outs = sp.invoke_batched(frames, bucket)
             else:
@@ -771,8 +786,9 @@ class TensorFilter(Element):
                 # closes its drain span
                 tracer.invoke_split([(self.name, b) for b in bufs],
                                     t0, t1, t2)
-        for buf, out in zip(bufs, outs):
-            self._pool_emit(buf, out)
+        with _profile.span(self.name, "demux", window):
+            for buf, out in zip(bufs, outs):
+                self._pool_emit(buf, out)
         if sample:
             # host-drain of the window: unbatch + per-frame wrap + the
             # downstream handoff of every frame demuxed above

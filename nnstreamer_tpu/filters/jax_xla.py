@@ -36,6 +36,7 @@ from ..obs import meshstat as _meshstat
 from ..obs import transfer as _xfer
 from ..obs import xlacost as _xlacost
 from ..runtime.events import Event, EventKind
+from ..utils import profile as _profile
 from ..utils.stats import COMPILE_STATS, DISPATCH_STATS
 from .api import FilterError, FilterProps, FilterSubplugin, SHARED_MODELS
 from .registry import register_filter
@@ -47,31 +48,39 @@ def _jax():
     return jax
 
 
-def _timed_first_call(fn: Callable, stats_key) -> Callable:
+def _timed_first_call(fn: Callable, stats_key, owner: str,
+                      lower: Callable) -> Callable:
     """Attribute the executable's FIRST invocation to its compile-stats
     row: ``jax.jit`` compiles lazily, so the first call is where XLA
     actually builds the program — timing only the trace/lower at the
-    compile site would miss almost all of the cold-start cost.  After
-    the first call the wrapper is one bool check per dispatch."""
+    compile site would miss almost all of the cold-start cost.  The
+    same call is the ``<owner>/first_call`` set-up span.  After the
+    first call the wrapper is one bool check per dispatch.  ``lower``
+    (traces and lowers the program again, for
+    ``JaxXlaFilter.executable_text``) rides on the wrapper: a thunk, so
+    that no IR stays pinned for the executable's lifetime."""
     done = [False]
 
     def wrapped(*args):
         if done[0]:
             return fn(*args)
         t0 = time.perf_counter()
-        out = fn(*args)
+        with _profile.span(owner, "first_call", setup=True):
+            out = fn(*args)
         if not done[0]:
             done[0] = True
             COMPILE_STATS.add_seconds(stats_key,
                                       time.perf_counter() - t0)
         return out
 
+    wrapped.lower = lower
     return wrapped
 
 
 def _aot_call(lowered, jitted: Callable, pkey: Optional[str] = None,
               bucket: int = 0,
-              devices: Optional[Sequence[Any]] = None) -> Callable:
+              devices: Optional[Sequence[Any]] = None,
+              owner: str = "jax-xla") -> Callable:
     """Serve dispatches from an already-traced ``Lowered``: AOT-compile
     it on first use so the whole path costs one trace.  A program the
     backend refuses to BUILD raises here, once, with the backend's own
@@ -106,7 +115,8 @@ def _aot_call(lowered, jitted: Callable, pkey: Optional[str] = None,
             from ..runtime import compilecache as _pcache
 
             compiled = state["c"] = _pcache.load_or_compile(
-                pkey, state["lowered"], bucket=bucket, devices=devices)
+                pkey, state["lowered"], bucket=bucket, devices=devices,
+                owner=owner)
             state.pop("lowered", None)
         try:
             return compiled(*args)
@@ -306,6 +316,10 @@ class JaxXlaFilter(FilterSubplugin):
         # compile is a "reload", not a "cold" start (set by
         # prepare_swap before configure)
         self._compile_kind: Optional[str] = None
+        # who the spans of this instance's work are named after
+        # (utils/profile.py): the owning element sets its own name, a
+        # serving pool its label
+        self.trace_owner = self.NAME
 
     def set_fused_pre(self, chains: list) -> None:
         """Install upstream transform op chains (runtime/fusion.py) to be
@@ -382,6 +396,30 @@ class JaxXlaFilter(FilterSubplugin):
                               for b, hm in
                               sorted(self._cache_by_bucket.items())},
             }
+
+    def executable_text(self, bucket: int = 0) -> str:
+        """The optimised HLO of one of this instance's programs as XLA
+        compiled it (``bucket`` 0: the single-frame, or whole-window
+        replay, program; else the micro-batch program of that bucket):
+        every instruction, fusions included, carries the ``nns.pre`` /
+        ``nns.model/<stage>`` / ``nns.post`` scope it came from in its
+        ``op_name`` metadata, which is how
+        ``utils/profile.stage_seconds`` names a device operation where
+        the trace itself does not.  Traces, lowers and compiles the
+        program again (a persistent compile cache makes that a load),
+        so it is for an operator's hand, not a hot path."""
+        c = self._compiled
+        if c is None:
+            raise FilterError("jax-xla: not configured")
+        fn = c.jitted
+        if bucket:
+            with self._batch_lock:
+                fn = self._batch_exec.get((c.in_spec, bucket)) or \
+                    self._batch_exec.get((c.in_spec, bucket, "stacked"))
+            if fn is None:
+                raise FilterError(
+                    f"jax-xla: no program of bucket {bucket} compiled yet")
+        return fn.lower().compile().as_text()
 
     def model_name(self) -> str:
         """Name of the model this instance serves ("" before
@@ -705,15 +743,23 @@ class JaxXlaFilter(FilterSubplugin):
         pre = self._pre_fns(in_spec) if self._pre_chains else None
         post = self._post_fns[0] if self._post_fns else None
 
+        scope = _jax().named_scope
+
         def normalized(*inputs):
+            # the stage vocabulary of the device trace
+            # (Documentation/observability.md): scopes are HLO metadata
+            # only and cost nothing at run time
             if pre is not None:
-                inputs = [g(x) for g, x in zip(pre, inputs)]
-            out = fn(*inputs)
+                with scope("nns.pre"):
+                    inputs = [g(x) for g, x in zip(pre, inputs)]
+            with scope("nns.model"):
+                out = fn(*inputs)
             out = tuple(out) if isinstance(out, (list, tuple)) else (out,)
             if post is not None:
                 # fused downstream epilogue (decoder device overlay):
                 # still ONE XLA program, one dispatch
-                out = tuple(post(*out))
+                with scope("nns.post"):
+                    out = tuple(post(*out))
             return out
 
         return normalized, pre is not None, post is not None
@@ -724,6 +770,7 @@ class JaxXlaFilter(FilterSubplugin):
         if self._compile_kind is not None:
             kind = self._compile_kind
         mesh = self._mesh
+        owner = self.trace_owner
         t_compile0 = time.perf_counter()
         normalized, with_pre, with_post = self._normalized_fn(model, in_spec)
         kw = {}
@@ -742,7 +789,8 @@ class JaxXlaFilter(FilterSubplugin):
         avals = [jax.ShapeDtypeStruct(t.shape, t.dtype.np_dtype)
                  for t in in_spec.tensors]
         try:
-            lowered = jitted.lower(*avals)
+            with _profile.span(owner, "trace_lower", setup=True):
+                lowered = jitted.lower(*avals)
         except Exception as e:
             raise FilterError(
                 f"jax-xla: model {model.name} rejects input {in_spec}: {e}"
@@ -767,11 +815,11 @@ class JaxXlaFilter(FilterSubplugin):
             # off this same lowering too, so a warm-cache process skips
             # the XLA build here exactly like on the bucket path
             fn = _aot_call(lowered, jitted, pkey=pkey, bucket=0,
-                           devices=self._exec_devices())
-        return _Compiled(_timed_first_call(fn, skey), in_spec, out_spec,
-                         with_pre=with_pre,
-                         with_post=with_post,
-                         in_shardings=in_shardings)
+                           devices=self._exec_devices(), owner=owner)
+        return _Compiled(_timed_first_call(
+            fn, skey, owner, lambda: jitted.lower(*avals)),
+            in_spec, out_spec, with_pre=with_pre, with_post=with_post,
+            in_shardings=in_shardings)
 
     def _input_sharding(self, tspec: TensorSpec):
         """Batch-shard an input over the placement's data axes when its
@@ -790,12 +838,18 @@ class JaxXlaFilter(FilterSubplugin):
             stages.append([chain.fn_for(sp) for sp in specs])
             specs = [chain.out_spec_of(sp) for sp in specs]
 
+        # one inner scope per fused transform, by element name
+        names = [getattr(chain, "scope", None) or "transform"
+                 for chain in self._pre_chains]
+        scope = _jax().named_scope
+
         def compose(i):
             fns = [st[i] for st in stages]
 
             def g(x):
-                for f in fns:
-                    x = f(x)
+                for name, f in zip(names, fns):
+                    with scope(name):
+                        x = f(x)
                 return x
 
             return g
@@ -868,7 +922,8 @@ class JaxXlaFilter(FilterSubplugin):
                     x if hasattr(x, "devices") and dev in x.devices()
                     else self._put_input(_jax(), x, dev)
                     for x in inputs]
-        out = c.jitted(*inputs)
+        with _profile.span(self.trace_owner, "dispatch"):
+            out = c.jitted(*inputs)
         DISPATCH_STATS.count("filter")
         if self._placement is not None:
             # per-shard attribution (obs/meshstat.py): the leading dim
@@ -882,21 +937,22 @@ class JaxXlaFilter(FilterSubplugin):
                 sharded=b % self._placement.data_axis_size == 0)
         return list(out)
 
-    @staticmethod
-    def _put_input(jax, x, where):
-        """``device_put`` one input to a device/sharding, counting the
-        move into the transfer ledger byte-exact: ``h2d`` for a host
-        array, ``d2d`` for an array that is already device-resident (a
-        reshard onto this executable's layout travels chip to chip,
-        never through the host)."""
-        if not _xfer.ACTIVE:
-            return jax.device_put(x, where)
-        t0 = time.perf_counter()
-        y = jax.device_put(x, where)
-        _xfer.record("d2d" if isinstance(x, jax.Array) else "h2d", "input",
-                     int(getattr(x, "nbytes", 0)),
-                     time.perf_counter() - t0)
-        return y
+    def _put_input(self, jax, x, where):
+        """``device_put`` one input to a device/sharding (the
+        ``<owner>/place`` span), counting the move into the transfer
+        ledger byte-exact: ``h2d`` for a host array, ``d2d`` for an
+        array that is already device-resident (a reshard onto this
+        executable's layout travels chip to chip, never through the
+        host)."""
+        with _profile.span(self.trace_owner, "place"):
+            if not _xfer.ACTIVE:
+                return jax.device_put(x, where)
+            t0 = time.perf_counter()
+            y = jax.device_put(x, where)
+            _xfer.record("d2d" if isinstance(x, jax.Array) else "h2d",
+                         "input", int(getattr(x, "nbytes", 0)),
+                         time.perf_counter() - t0)
+            return y
 
     def _record_mesh(self, slots: int, frames: int,
                      sharded: bool, local: bool = False) -> None:
@@ -951,15 +1007,22 @@ class JaxXlaFilter(FilterSubplugin):
             constraint = self._placement.window_sharding(bucket)
 
         def batched(*flat):
-            stacked = [jnp.stack([flat[i * nt + j] for i in range(bucket)])
-                       for j in range(nt)]
-            if constraint is not None:
-                stacked = [jax.lax.with_sharding_constraint(s, constraint)
-                           for s in stacked]
+            # nns.window: the stack and unstack around the per-frame
+            # stages, so that every operation of a window program has a
+            # stage in the device trace
+            with jax.named_scope("nns.window"):
+                stacked = [
+                    jnp.stack([flat[i * nt + j] for i in range(bucket)])
+                    for j in range(nt)]
+                if constraint is not None:
+                    stacked = [
+                        jax.lax.with_sharding_constraint(s, constraint)
+                        for s in stacked]
             outs = jax.vmap(normalized)(*stacked)
             per_frame = []
-            for i in range(bucket):
-                per_frame.extend(o[i] for o in outs)
+            with jax.named_scope("nns.window"):
+                for i in range(bucket):
+                    per_frame.extend(o[i] for o in outs)
             return tuple(per_frame)
 
         kw = {}
@@ -973,14 +1036,18 @@ class JaxXlaFilter(FilterSubplugin):
         # since lower() doesn't seed its cache)
         avals = [jax.ShapeDtypeStruct(t.shape, t.dtype.np_dtype)
                  for _ in range(bucket) for t in in_spec.tensors]
-        lowered = jitted.lower(*avals)
+        owner = self.trace_owner
+        with _profile.span(owner, "trace_lower", setup=True):
+            lowered = jitted.lower(*avals)
         self._capture_cost(model, lowered, bucket, avals)
         skey = COMPILE_STATS.record(
             "bucket", time.perf_counter() - t_compile0, bucket=bucket)
         fn = _aot_call(lowered, jitted,
                        pkey=self._persist_key(model, in_spec, bucket),
-                       bucket=bucket, devices=self._exec_devices())
-        return _timed_first_call(fn, skey)
+                       bucket=bucket, devices=self._exec_devices(),
+                       owner=owner)
+        return _timed_first_call(fn, skey, owner,
+                                 lambda: jitted.lower(*avals))
 
     def _compile_batched_stacked(self, model: ModelDef,
                                  in_spec: TensorsSpec, bucket: int):
@@ -1017,7 +1084,9 @@ class JaxXlaFilter(FilterSubplugin):
         avals = [jax.ShapeDtypeStruct((gbucket,) + tuple(t.shape),
                                       t.dtype.np_dtype)
                  for t in in_spec.tensors]
-        lowered = jitted.lower(*avals)
+        owner = self.trace_owner
+        with _profile.span(owner, "trace_lower", setup=True):
+            lowered = jitted.lower(*avals)
         self._capture_cost(model, lowered, gbucket, avals)
         skey = COMPILE_STATS.record(
             "bucket", time.perf_counter() - t_compile0, bucket=gbucket)
@@ -1027,8 +1096,10 @@ class JaxXlaFilter(FilterSubplugin):
         fn = _aot_call(lowered, jitted,
                        pkey=self._persist_key(
                            model, ("stacked", in_spec), gbucket),
-                       bucket=gbucket, devices=self._exec_devices())
-        return _timed_first_call(fn, skey)
+                       bucket=gbucket, devices=self._exec_devices(),
+                       owner=owner)
+        return _timed_first_call(fn, skey, owner,
+                                 lambda: jitted.lower(*avals))
 
     def _invoke_batched_stacked(self, frames: Sequence[Sequence[Any]],
                                 bucket: int, c: _Compiled,
@@ -1067,17 +1138,17 @@ class JaxXlaFilter(FilterSubplugin):
                 # the mesh attribution store
                 rows.extend(rows[-1:] * pad_rows)
             stacked.append(np.stack(rows))
-        if _xfer.ACTIVE:
-            per_frame = sum(int(a.nbytes) // bucket for a in stacked)
+        with _profile.span(self.trace_owner, "place"):
             t0 = time.perf_counter()
             arrs = rp.feed_window(stacked)
-            _xfer.record("h2d", "input", per_frame * n,
-                         time.perf_counter() - t0)
-            if pad_rows:
-                _xfer.record("h2d", "pad", per_frame * pad_rows)
-        else:
-            arrs = rp.feed_window(stacked)
-        out = jitted(*arrs)
+            if _xfer.ACTIVE:
+                per_frame = sum(int(a.nbytes) // bucket for a in stacked)
+                _xfer.record("h2d", "input", per_frame * n,
+                             time.perf_counter() - t0)
+                if pad_rows:
+                    _xfer.record("h2d", "pad", per_frame * pad_rows)
+        with _profile.span(self.trace_owner, "dispatch"):
+            out = jitted(*arrs)
         DISPATCH_STATS.count("filter")
         self._record_mesh(slots=bucket, frames=n, sharded=True,
                           local=True)
@@ -1185,7 +1256,8 @@ class JaxXlaFilter(FilterSubplugin):
                                 _xfer.record("h2d", "pad",
                                              int(x.nbytes))
                     flat.extend(last)
-        out = jitted(*flat)
+        with _profile.span(self.trace_owner, "dispatch"):
+            out = jitted(*flat)
         DISPATCH_STATS.count("filter")
         if self._mesh is not None:
             # window attribution: bucket slots over the data axis (pads

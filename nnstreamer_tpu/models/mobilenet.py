@@ -216,11 +216,15 @@ def mobilenet_v2_backbone(params: Params, x, train: bool = False,
     attach at intermediate strides the way the reference's detection
     pipelines consume `ssd_mobilenet_v2` feature maps.
     """
-    x = x.astype(dtype)
-    x = _conv_bn(params["stem"], x, stride=2, train=train, dtype=dtype)
+    # stage scopes of the device trace (Documentation/observability.md)
+    with jax.named_scope("backbone/stem"):
+        x = x.astype(dtype)
+        x = _conv_bn(params["stem"], x, stride=2, train=train, dtype=dtype)
     tapped = []
     for i, stride in enumerate(_v2_strides()):
-        x = _inverted_residual(params["blocks"][i], x, stride, train, dtype)
+        with jax.named_scope(f"backbone/block{i:02d}"):
+            x = _inverted_residual(params["blocks"][i], x, stride, train,
+                                   dtype)
         if i in taps:
             tapped.append(x)
     return x, tapped

@@ -178,49 +178,52 @@ def batched_nms(boxes, class_scores, max_out: int = 100,
     space pass ``score_thresh`` in the same space and ``fill=-inf`` so
     suppressed/padded slots stay below every survivor (``ssd_detect_apply``
     does this to skip materializing a full-resolution sigmoid)."""
-    fg = class_scores[:, 1:]                         # drop background
-    num_fg = fg.shape[-1]
-    k = min(pre_topk, boxes.shape[0])
-    _, idx = _preselect_top_k(fg.max(axis=-1), k)    # shared candidates
-    b = boxes[idx]                                   # (k,4)
-    s = fg[idx]                                      # (k,C-1)
-    overlap = (_iou_matrix(b) > iou_thresh) \
-        & ~jnp.eye(k, dtype=bool)                    # (k,k), no self
-    # Suppression ON DEMAND: a full (k,k,C) "who beats whom" tensor is
-    # the dominant postprocess cost (≈1.8 ms/batch at k=128, C=91 on
-    # v5e), but only pairs that can reach the output need their verdict.
-    # Preselect the top 4·max_out (candidate, class) pairs by raw score
-    # and test just those M pairs against all k candidates — an (M,k)
-    # compare instead of (k,k,C), ~100× less work.  Exactness: fast-NMS
-    # suppression is pairwise (no cascade), so each selected pair's
-    # verdict is exact; the output can only differ if >3·max_out of the
-    # best raw pairs are all suppressed — then slots beyond are filled
-    # from the next-best survivors of the M pool.
-    flat = s.reshape(-1)                             # candidate-major
-    m = min(max_out, flat.shape[0])  # tiny inputs: fewer slates than max_out
-    M = min(4 * max_out, flat.shape[0])
-    raw, fidx = jax.lax.top_k(flat, M)               # (M,) pair preselect
-    cand = fidx // num_fg                            # (M,) candidate row
-    ccls = fidx % num_fg                             # (M,) class column
-    sj = s.T[ccls]                                   # (M,k) class scores ∀j
-    # beats: overlapping candidate with strictly higher class score;
-    # EXACT ties broken by candidate rank (without a tie-break the
-    # symmetric comparison keeps both duplicates of an equal-score pair)
-    beats = (sj > raw[:, None]) | \
-        ((sj == raw[:, None]) & (jnp.arange(k)[None, :] < cand[:, None]))
-    suppressed = jnp.any(overlap[cand] & beats, axis=-1)   # (M,)
-    keep = (raw > score_thresh) & ~suppressed
-    kept = jnp.where(keep, raw, jnp.asarray(fill, s.dtype))
-    top_scores, sel = jax.lax.top_k(kept, m)         # final slate from M
-    out_b = b[cand[sel]]
-    out_s = top_scores
-    out_c = (ccls[sel] + 1).astype(jnp.int32)        # back to class ids
-    if m < max_out:                                  # fixed-shape contract
-        pad = max_out - m
-        out_b = jnp.pad(out_b, ((0, pad), (0, 0)))
-        out_s = jnp.pad(out_s, (0, pad), constant_values=fill)
-        out_c = jnp.pad(out_c, (0, pad))
-    return out_b, out_s, out_c
+    with jax.named_scope("topk"):
+        fg = class_scores[:, 1:]                     # drop background
+        num_fg = fg.shape[-1]
+        k = min(pre_topk, boxes.shape[0])
+        _, idx = _preselect_top_k(fg.max(axis=-1), k)  # shared candidates
+    with jax.named_scope("nms"):
+        b = boxes[idx]                                   # (k,4)
+        s = fg[idx]                                      # (k,C-1)
+        overlap = (_iou_matrix(b) > iou_thresh) \
+            & ~jnp.eye(k, dtype=bool)                    # (k,k), no self
+        # Suppression ON DEMAND: a full (k,k,C) "who beats whom" tensor is
+        # the dominant postprocess cost (≈1.8 ms/batch at k=128, C=91 on
+        # v5e), but only pairs that can reach the output need their verdict.
+        # Preselect the top 4·max_out (candidate, class) pairs by raw score
+        # and test just those M pairs against all k candidates — an (M,k)
+        # compare instead of (k,k,C), ~100× less work.  Exactness: fast-NMS
+        # suppression is pairwise (no cascade), so each selected pair's
+        # verdict is exact; the output can only differ if >3·max_out of the
+        # best raw pairs are all suppressed — then slots beyond are filled
+        # from the next-best survivors of the M pool.
+        flat = s.reshape(-1)                             # candidate-major
+        # tiny inputs: fewer slates than max_out
+        m = min(max_out, flat.shape[0])
+        M = min(4 * max_out, flat.shape[0])
+        raw, fidx = jax.lax.top_k(flat, M)               # (M,) pair preselect
+        cand = fidx // num_fg                            # (M,) candidate row
+        ccls = fidx % num_fg                             # (M,) class column
+        sj = s.T[ccls]                               # (M,k) class scores ∀j
+        # beats: overlapping candidate with strictly higher class score;
+        # EXACT ties broken by candidate rank (without a tie-break the
+        # symmetric comparison keeps both duplicates of an equal-score pair)
+        beats = (sj > raw[:, None]) | \
+            ((sj == raw[:, None]) & (jnp.arange(k)[None, :] < cand[:, None]))
+        suppressed = jnp.any(overlap[cand] & beats, axis=-1)   # (M,)
+        keep = (raw > score_thresh) & ~suppressed
+        kept = jnp.where(keep, raw, jnp.asarray(fill, s.dtype))
+        top_scores, sel = jax.lax.top_k(kept, m)         # final slate from M
+        out_b = b[cand[sel]]
+        out_s = top_scores
+        out_c = (ccls[sel] + 1).astype(jnp.int32)        # back to class ids
+        if m < max_out:                                  # fixed-shape contract
+            pad = max_out - m
+            out_b = jnp.pad(out_b, ((0, pad), (0, 0)))
+            out_s = jnp.pad(out_s, (0, pad), constant_values=fill)
+            out_c = jnp.pad(out_c, (0, pad))
+        return out_b, out_s, out_c
 
 
 # -- model -------------------------------------------------------------------
@@ -270,21 +273,25 @@ def ssd_mobilenet_v2_apply(params: Params, x, train: bool = False,
     feat, taps = mobilenet_v2_backbone(
         params["backbone"], x, train=train, dtype=dtype, taps=(_TAP_BLOCK,))
     maps = [taps[0], feat]
-    h = feat
-    for p in params["extras"]:
-        h = _conv_bn(p, h, stride=2, train=train, dtype=dtype)
-        maps.append(h)
+    # stage scopes of the device trace (Documentation/observability.md)
+    with jax.named_scope("extras"):
+        h = feat
+        for p in params["extras"]:
+            h = _conv_bn(p, h, stride=2, train=train, dtype=dtype)
+            maps.append(h)
     locs, clss = [], []
-    for fmap, head in zip(maps, params["heads"]):
-        n = fmap.shape[0]
-        loc = _conv_bn(head["loc"], fmap, stride=1, relu6=False, train=train,
-                       dtype=dtype)
-        cls = _conv_bn(head["cls"], fmap, stride=1, relu6=False, train=train,
-                       dtype=dtype)
-        locs.append(loc.reshape(n, -1, 4))
-        clss.append(cls.reshape(n, -1, num_classes))
-    return (jnp.concatenate(locs, axis=1).astype(jnp.float32),
-            jnp.concatenate(clss, axis=1).astype(cls_dtype))
+    with jax.named_scope("heads"):
+        for fmap, head in zip(maps, params["heads"]):
+            n = fmap.shape[0]
+            loc = _conv_bn(head["loc"], fmap, stride=1, relu6=False,
+                           train=train, dtype=dtype)
+            cls = _conv_bn(head["cls"], fmap, stride=1, relu6=False,
+                           train=train, dtype=dtype)
+            locs.append(loc.reshape(n, -1, 4))
+            clss.append(cls.reshape(n, -1, num_classes))
+    with jax.named_scope("decode"):
+        return (jnp.concatenate(locs, axis=1).astype(jnp.float32),
+                jnp.concatenate(clss, axis=1).astype(cls_dtype))
 
 
 def ssd_detect_apply(params: Params, x, anchors, max_out: int = 100,
@@ -304,14 +311,16 @@ def ssd_detect_apply(params: Params, x, anchors, max_out: int = 100,
     same contract as before."""
     loc, cls = ssd_mobilenet_v2_apply(params, x, dtype=dtype,
                                       cls_dtype=dtype)
-    boxes = decode_boxes(loc, jnp.asarray(anchors))
+    with jax.named_scope("decode"):
+        boxes = decode_boxes(loc, jnp.asarray(anchors))
     lt = float(np.log(score_thresh / (1.0 - score_thresh)))
     out_b, out_s, out_c = jax.vmap(
         lambda b, s: batched_nms(b, s, max_out=max_out,
                                  iou_thresh=iou_thresh,
                                  score_thresh=lt,
                                  fill=-np.inf))(boxes, cls)
-    return out_b, jax.nn.sigmoid(out_s.astype(jnp.float32)), out_c
+    with jax.named_scope("nms"):
+        return out_b, jax.nn.sigmoid(out_s.astype(jnp.float32)), out_c
 
 
 def ssd_detect_fn(params: Params, anchors, max_out: int = 100,

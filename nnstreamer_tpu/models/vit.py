@@ -98,21 +98,32 @@ def vit_apply(params: Params, x, heads: int = 2, dtype=None):
     if dtype is None:
         dtype = jnp.bfloat16
     patch = params["embed"]["w"].shape[0]
-    x = x.astype(dtype)
-    x = jax.lax.conv_general_dilated(
-        x, params["embed"]["w"].astype(dtype),
-        window_strides=(patch, patch), padding="VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    B, ph, pw, D = x.shape
-    x = x.reshape(B, ph * pw, D) + params["embed"]["b"].astype(dtype)
-    x = x + params["pos"].astype(dtype)
-    for block in params["blocks"]:
-        x = x + _attention(block, _ln(block["ln1"], x), heads, dtype)
-        h = _dense(block["mlp1"], _ln(block["ln2"], x), dtype)
-        x = x + _dense(block["mlp2"], jax.nn.gelu(h), dtype)
-    x = _ln(params["ln_f"], x).mean(axis=1)               # global pool
-    return _dense(params["head"], x,
-                  jnp.float32).astype(jnp.float32)
+    # stage scopes of the device trace (Documentation/observability.md)
+    scope = jax.named_scope
+    with scope("embed"):
+        x = x.astype(dtype)
+        x = jax.lax.conv_general_dilated(
+            x, params["embed"]["w"].astype(dtype),
+            window_strides=(patch, patch), padding="VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        B, ph, pw, D = x.shape
+        x = x.reshape(B, ph * pw, D) + params["embed"]["b"].astype(dtype)
+        x = x + params["pos"].astype(dtype)
+    for i, block in enumerate(params["blocks"]):
+        layer = f"layer{i:02d}"
+        with scope(layer + "/ln1"):
+            h = _ln(block["ln1"], x)
+        with scope(layer + "/attn"):
+            x = x + _attention(block, h, heads, dtype)
+        with scope(layer + "/ln2"):
+            h = _ln(block["ln2"], x)
+        with scope(layer + "/mlp"):
+            h = _dense(block["mlp1"], h, dtype)
+            x = x + _dense(block["mlp2"], jax.nn.gelu(h), dtype)
+    with scope("head"):
+        x = _ln(params["ln_f"], x).mean(axis=1)           # global pool
+        return _dense(params["head"], x,
+                      jnp.float32).astype(jnp.float32)
 
 
 def register_vit(name: str = "vit_s16", batch: int = 1,

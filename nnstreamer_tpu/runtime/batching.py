@@ -157,6 +157,9 @@ class MicroBatcher:
         self.flushes_deadline = 0
         self.flushes_forced = 0
         self.flushes_adaptive = 0
+        # windows flushed so far: the id every span of one window's
+        # flush shares (utils/profile.py; the flush functions read it)
+        self.window_seq = 0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -275,6 +278,7 @@ class MicroBatcher:
                     # holds the flush serial lock exactly like a real
                     # dispatch does
                     time.sleep(stall)
+            self.window_seq += 1
             self._flush_fn(batch)
         with self._cv:
             # wake the timer: the dispatch is done, so an adaptive

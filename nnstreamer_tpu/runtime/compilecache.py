@@ -274,24 +274,31 @@ def store(key: str, compiled: Any) -> bool:
 def load_or_compile(key: Optional[str], lowered: Any,
                     stats_kind: str = "persist_hit",
                     bucket: int = 0,
-                    devices: Optional[Sequence[Any]] = None) -> Any:
+                    devices: Optional[Sequence[Any]] = None,
+                    owner: str = "compilecache") -> Any:
     """The one seam ``filters/jax_xla._aot_call`` drives: try the
     persistent cache, fall back to ``lowered.compile()``, store the
     fresh build for the next process.  A cache hit is recorded into
     CompileStats under ``persist_hit`` with the DESERIALIZE time as its
     seconds — the number the cold-start gate compares against the
-    trace+build cost it replaced.  ``devices``: see :func:`load`."""
+    trace+build cost it replaced.  ``devices``: see :func:`load`.
+    The whole of it is the ``<owner>/load_or_compile`` set-up span,
+    noted ``hit`` or ``miss``."""
+    from ..utils import profile as _profile
     from ..utils.stats import COMPILE_STATS
 
-    if key is not None:
-        t0 = time.perf_counter()
-        cached = load(key, devices)
-        if cached is not None:
-            COMPILE_STATS.record(stats_kind,
-                                 time.perf_counter() - t0,
-                                 bucket=bucket)
-            return cached
-    compiled = lowered.compile()
-    if key is not None:
-        store(key, compiled)
-    return compiled
+    with _profile.span(owner, "load_or_compile", setup=True) as sp:
+        sp.note = "miss"
+        if key is not None:
+            t0 = time.perf_counter()
+            cached = load(key, devices)
+            if cached is not None:
+                sp.note = "hit"
+                COMPILE_STATS.record(stats_kind,
+                                     time.perf_counter() - t0,
+                                     bucket=bucket)
+                return cached
+        compiled = lowered.compile()
+        if key is not None:
+            store(key, compiled)
+        return compiled

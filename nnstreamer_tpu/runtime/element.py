@@ -356,10 +356,7 @@ class Element:
                     (tr,) if tr is not None else None)
             if tracer is not None:
                 tracer.pre_chain(self, buf)
-            if _profile.trace_active():
-                with _profile.annotate(self.name):
-                    self.chain(pad, buf)
-            else:
+            with _profile.span(self.name, None, buf.pts):
                 self.chain(pad, buf)
             if tracer is not None:
                 tracer.post_chain(self, buf)
@@ -550,7 +547,10 @@ class SourceElement(Element):
                 t0 = time.monotonic()
                 c0 = time.thread_time()
             try:
-                buf = self.create()
+                with _profile.span(self.name, "create") as created:
+                    buf = self.create()
+                    if buf is not None:
+                        created.window = buf.pts
             except StreamError as e:
                 self.post_error(e)
                 break
@@ -634,15 +634,17 @@ class SinkElement(Element):
     def _fence(self, arr) -> None:
         if arr is None:
             return
-        tracer = _hooks.tracer
-        if tracer is None:
-            arr.block_until_ready()
-            return
-        import time
+        # the host waiting for window N-1 on the device
+        with _profile.span(self.name, "fence"):
+            tracer = _hooks.tracer
+            if tracer is None:
+                arr.block_until_ready()
+                return
+            import time
 
-        t0 = time.monotonic()
-        arr.block_until_ready()
-        tracer.sink_fenced(self, time.monotonic() - t0)
+            t0 = time.monotonic()
+            arr.block_until_ready()
+            tracer.sink_fenced(self, time.monotonic() - t0)
 
     def render(self, buf: Buffer) -> None:
         raise NotImplementedError

@@ -116,6 +116,9 @@ def fuse_transform_filter(pipeline, enable: bool = True) -> int:
                 chain = up._opchain()
             except Exception:
                 break
+            # the stage scope of this transform inside the fused
+            # program (filters/jax_xla._pre_fns): nns.pre/<element>
+            chain.scope = up.name
             run.append((up, chain))
             up = up.sinkpads[0].peer.element
         if not run:

@@ -15,6 +15,8 @@ import time
 from typing import Dict, List, Optional, Union
 
 from ..obs import metrics as _metrics
+from ..utils import profile as _profile
+from ..utils.log import logi
 from .element import Element, NegotiationError, Pad, SourceElement
 from .events import Message, MessageKind
 
@@ -140,10 +142,12 @@ class Pipeline:
             # transform→filter→decoder segment into one XLA program and
             # record the FusedSegment descriptors (digests key the
             # persistent compile cache; names label dispatch counting)
-            fuse_pipeline(self, enable=self.fuse)
+            with _profile.span(self.name, "fuse", setup=True):
+                fuse_pipeline(self, enable=self.fuse)
             # Negotiation: sources fix their caps and propagate downstream.
-            for s in sources:
-                s.negotiate()
+            with _profile.span(self.name, "negotiate", setup=True):
+                for s in sources:
+                    s.negotiate()
             self._check_negotiated()
             self._n_sinks = sum(
                 1 for e in self.elements.values()
@@ -216,6 +220,8 @@ class Pipeline:
                 p.spec = None
             e._eos_seen.clear()
         self.playing = False
+        # what held a window up for 50 ms or more while nobody traced
+        _profile.report_slow(logi)
         return self
 
     def _check_links(self) -> None:

@@ -48,6 +48,7 @@ from ..obs import tenantstat as _tenantstat
 from ..obs import transfer as _xfer
 from ..obs.tracer import TRACE_META_KEY
 from ..utils import lockdep as _lockdep
+from ..utils import profile as _profile
 from ..utils.log import logw
 from ..utils.stats import InvokeStats
 from .admission import (
@@ -195,6 +196,11 @@ class PoolEntry:
         self.stats = InvokeStats()
         self._lock = threading.Lock()
         self._streams: Dict[int, Any] = {}  # id(owner) -> owner element
+        # the name the pool's spans carry (utils/profile.py), also on
+        # the shared instance's own (place, dispatch, compile)
+        self.trace_owner = self.label()
+        if hasattr(subplugin, "trace_owner"):
+            subplugin.trace_owner = self.trace_owner
         self.batcher: Optional[SharedBatcher] = None
         self.buckets: Tuple[int, ...] = (1,)
         self._batch_cfg: Optional[Tuple] = None
@@ -678,7 +684,8 @@ class PoolEntry:
             if sample and self._last_out is not None:
                 # drain the async backlog first, so t0→done times ONE
                 # window
-                block_all([self._last_out])
+                with _profile.span(self.trace_owner, "sample_fence"):
+                    block_all([self._last_out])
         lc = self._lifecycle
         if lc is not None and lc.canary_active:
             # canary split: the window partitions by the owners'
@@ -704,6 +711,12 @@ class PoolEntry:
             owners.setdefault(id(owner), [owner, 0])[1] += 1
         t0 = time.monotonic()
         bucket = len(items)
+        # the pool's spans, where LatencyTracer marks park / dispatch /
+        # demux: <pool>/window (frame prep, stack and pad: its self
+        # time) around the shared instance's own <pool>/dispatch, then
+        # <pool>/demux
+        name = self.trace_owner
+        window = getattr(self.batcher, "window_seq", None)
         try:
             ch = _chaos.plan
             if ch is not None:
@@ -717,17 +730,18 @@ class PoolEntry:
             # frame prep inside the guard: items already left the
             # pending queue, so ANY failure from here on loses the
             # window and must surface on every owner's bus
-            frames = [owner._pool_frame_inputs(buf)
-                      for owner, buf, _dl, _enq in items]
-            t1 = time.monotonic()  # host-prep done, device phase begins
-            if getattr(sp, "SUPPORTS_BATCH", False):
-                bucket = pick_bucket(len(frames), self.buckets)
-                outs = sp.invoke_batched(frames, bucket)
-            else:
-                # shared instance without a batched entry point: the
-                # window still coalesces (ordering, EOS semantics) but
-                # each frame dispatches separately
-                outs = [sp.invoke(list(f)) for f in frames]
+            with _profile.span(name, "window", window):
+                frames = [owner._pool_frame_inputs(buf)
+                          for owner, buf, _dl, _enq in items]
+                t1 = time.monotonic()  # host-prep done, device phase
+                if getattr(sp, "SUPPORTS_BATCH", False):
+                    bucket = pick_bucket(len(frames), self.buckets)
+                    outs = sp.invoke_batched(frames, bucket)
+                else:
+                    # shared instance without a batched entry point:
+                    # the window still coalesces (ordering, EOS
+                    # semantics) but each frame dispatches separately
+                    outs = [sp.invoke(list(f)) for f in frames]
         except Exception as e:  # noqa: BLE001 - a failed shared window
             # affects EVERY stream that parked a frame in it: the error
             # must land on each owner's bus, not only on whichever
@@ -758,7 +772,8 @@ class PoolEntry:
                     t.mark_donated()
         flat = [o for out in outs for o in out]
         if sample:
-            block_all(flat)
+            with _profile.span(name, "sample_fence", window):
+                block_all(flat)
             t2 = time.monotonic()
             self.stats.record(t2 - t0, frames=len(items),
                               streams=len(owners))
@@ -791,28 +806,29 @@ class PoolEntry:
         tstats = _tenantstat.ACTIVE
         label = self.label() if (tstats or sample) else ""
         tenants = self._tenants
-        for (owner, buf, _dl, enq), out in zip(items, outs):
-            if adm is not None:
-                # the admission controller's latency signal: window
-                # park → results demuxed (sampled windows blocked on
-                # the device above, so they include execution time;
-                # under overload the queueing term dominates either
-                # way — that's the term admission must react to)
-                lat = done - enq
-                adm.observe(lat)
-                if tstats:
-                    # per-tenant SLO attainment, graded on the SAME
-                    # per-frame latency the shed decision reads
-                    _tenantstat.record_latency(
-                        label, tenants.get(id(owner), "default"),
-                        lat, adm.slo_s)
-            try:
-                # the owner's flush context: push through ITS pads, so
-                # a broken downstream errors on ITS bus only
-                owner._pool_emit(buf, out)
-            except Exception as e:  # noqa: BLE001 - keep demuxing the
-                # other streams' frames of this window
-                owner.post_error(e)
+        with _profile.span(name, "demux", window):
+            for (owner, buf, _dl, enq), out in zip(items, outs):
+                if adm is not None:
+                    # the admission controller's latency signal: window
+                    # park → results demuxed (sampled windows blocked on
+                    # the device above, so they include execution time;
+                    # under overload the queueing term dominates either
+                    # way — that's the term admission must react to)
+                    lat = done - enq
+                    adm.observe(lat)
+                    if tstats:
+                        # per-tenant SLO attainment, graded on the SAME
+                        # per-frame latency the shed decision reads
+                        _tenantstat.record_latency(
+                            label, tenants.get(id(owner), "default"),
+                            lat, adm.slo_s)
+                try:
+                    # the owner's flush context: push through ITS pads, so
+                    # a broken downstream errors on ITS bus only
+                    owner._pool_emit(buf, out)
+                except Exception as e:  # noqa: BLE001 - keep demuxing the
+                    # other streams' frames of this window
+                    owner.post_error(e)
         if sample:
             # cost attribution: host-prep (t0→t1) / device (t1→t2) /
             # host-drain (t2→now: unbatch + per-owner demux) into the

@@ -1,72 +1,273 @@
-"""Profiling hooks: XLA/TPU traces with per-element annotation.
+"""The program's trace layer: phase spans on the host, stage scopes on the
+device, one capture that holds both.
 
 Parity target: the reference defers profiling to GStreamer ecosystem
 tooling — gst-instruments/gst-top, NNShark (/root/reference/tools/
 profiling/README.md) — plus its in-tree per-filter latency/throughput
 props.  The TPU-native substitute is the JAX profiler (SURVEY.md §7.7):
 ``pipeline_trace`` captures a TensorBoard-loadable trace of everything
-the pipeline dispatches (XLA kernels, host callbacks, transfers), and
-every element's chain runs under a ``TraceAnnotation`` carrying the
-element name, so per-element time shows up on the trace timeline the
-way gst-top attributes time per GstElement.
+the pipeline dispatches, and the runtime brackets its own work in
+:class:`span` s named ``<element>/<phase>`` (``el_net/dispatch``,
+``el_sink/fence``, ...; the vocabulary is in
+Documentation/observability.md), so a gap on the device timeline can be
+put down to what the host was doing in it.
+
+A span goes to two sinks:
+
+- the profiler's clock: a ``jax.profiler.TraceAnnotation`` of the same
+  name while a ``pipeline_trace`` capture is active, so it lies in the
+  same ``.xplane.pb`` as the device lines;
+- memory (``time.perf_counter_ns``), read back with :func:`spans`: every
+  set-up span always (with what jax reports of a program's build inside
+  it: ``jax/trace``, ``jax/lower``, ``jax/compile_or_load``,
+  ``jax/cache_load``), every per-window span while a capture is active,
+  and outside a capture only a per-window span that lasted
+  ``SLOW_NS`` or more (the slow list).  All three lists are bounded and
+  keep their newest spans.
+
+Inside the fused program the stages are ``jax.named_scope`` s
+(``nns.pre``, ``nns.model/<stage>``, ``nns.post``); :func:`stage_seconds`
+reduces a capture to device seconds per stage, and
+``python -m nnstreamer_tpu.utils.profile <xplane.pb>`` prints it.
 
 Usage::
 
-    from nnstreamer_tpu.utils.profile import pipeline_trace
+    from nnstreamer_tpu.utils.profile import pipeline_trace, spans
 
     with pipeline_trace("/tmp/nns-trace"):
         with pipeline:
             ... stream ...
-    # tensorboard --logdir /tmp/nns-trace
+    # tensorboard --logdir /tmp/nns-trace, or spans() / stage_seconds()
 
-Annotations are zero-cost when no trace is active; ``annotate`` is used
-by the runtime automatically.
+What it costs: with no capture a per-window span is one small object,
+two clock reads and a compare around the work: 0.87 us a span on the
+host of the one-chip machine (200,000 spans timed there, PR 24;
+``tests/test_profile_spans.py`` prints the figure of the host it runs
+on), some ten spans a window, so 9 us of an 18 ms window; PERF.md
+section 6 has the rates measured on the chip with and without.  Nothing
+is kept, no lock is taken and jax is not touched.  During a capture each
+span also enters a ``TraceAnnotation`` and appends one tuple under a
+lock.  ``NNS_TPU_OBS_DISABLE`` switches spans off altogether (no clock
+read).
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import re
 import threading
+import time
+from typing import Dict, List, NamedTuple, Optional
+
+from ..obs import hooks as _hooks
 
 _active = threading.Event()
 
+#: a per-window span this long is kept even when no capture is active
+SLOW_NS = 50_000_000
+SLOW_MAX = 1024
+CAPTURE_MAX = 1 << 18
+SETUP_MAX = 4096
 
-@contextlib.contextmanager
-def pipeline_trace(log_dir: str, create_perfetto_link: bool = False):
-    """Capture a JAX profiler trace of everything run inside."""
+
+class Span(NamedTuple):
+    """One kept span.  ``kind``: ``setup`` (always kept), ``window``
+    (kept because a capture was active), ``slow`` (kept because it
+    lasted ``SLOW_NS``) or ``trace`` (the capture itself:
+    ``trace/start``, ``trace/capture``, ``trace/stop``).  ``window`` is
+    the id every span of one window shares (``buf.pts`` in a replay
+    line, the batcher's window number in a pool); :func:`spans` fills it
+    in from the enclosing span where a site did not know it.  The span
+    that caused this one is the one it nests in on its ``thread``."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    thread: int
+    window: Optional[int]
+    kind: str
+    note: Optional[str]
+
+
+class _Recorder:
+    """The three bounded lists: each keeps its newest spans, so a
+    process that lives long still has its latest set-up, capture and
+    slow windows.  Appended to under the lock only: a site that keeps
+    nothing never gets here."""
+
+    def __init__(self, limits=None):
+        self.lock = threading.Lock()
+        limits = limits or {"setup": SETUP_MAX, "window": CAPTURE_MAX,
+                            "slow": SLOW_MAX}
+        self.lists: Dict[str, collections.deque] = {
+            which: collections.deque(maxlen=n)
+            for which, n in limits.items()}
+        self.dropped = 0
+        self.slow_kept = 0          # ever, for report_slow
+        self.slow_reported = 0
+
+    def keep(self, name, t0, t1, window, kind, note=None) -> None:
+        row = Span(name, t0, t1, threading.get_ident(), window, kind, note)
+        which = "setup" if kind == "trace" else kind
+        with self.lock:
+            rows = self.lists[which]
+            if len(rows) == rows.maxlen:
+                self.dropped += 1
+            rows.append(row)
+            if which == "slow":
+                self.slow_kept += 1
+
+    def clear(self) -> None:
+        with self.lock:
+            for rows in self.lists.values():
+                rows.clear()
+            self.dropped = self.slow_kept = self.slow_reported = 0
+
+
+_REC = _Recorder()
+
+
+class span:
+    """``with span(owner, phase, window):`` around one phase of one
+    element's work.  The name is ``owner/phase`` (``owner`` alone
+    without a phase) and is only built when the span is kept.
+    ``setup=True`` marks a span of pipeline set-up, kept always;
+    ``note`` (settable inside the block) rides along, e.g. ``hit`` or
+    ``miss`` on a compile-cache load."""
+
+    __slots__ = ("owner", "phase", "window", "setup", "note", "_t0", "_ann")
+
+    def __init__(self, owner: str, phase: Optional[str] = None,
+                 window: Optional[int] = None, setup: bool = False):
+        self.owner = owner
+        self.phase = phase
+        self.window = window
+        self.setup = setup
+        self.note = None
+        self._t0 = None
+        self._ann = None
+
+    @property
+    def name(self) -> str:
+        return self.owner if self.phase is None \
+            else self.owner + "/" + self.phase
+
+    def __enter__(self) -> "span":
+        if _hooks.DISABLED:
+            return self
+        if self.setup:
+            _watch_jax_compiles()
+            _tls.setup = getattr(_tls, "setup", 0) + 1
+        if _active.is_set():
+            import jax
+
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t0 = self._t0
+        if t0 is None:
+            return False
+        t1 = time.perf_counter_ns()
+        ann = self._ann
+        if ann is not None:
+            ann.__exit__(*exc)
+        if self.setup:
+            _tls.setup -= 1
+            _REC.keep(self.name, t0, t1, self.window, "setup", self.note)
+        elif ann is not None:
+            _REC.keep(self.name, t0, t1, self.window, "window", self.note)
+        elif t1 - t0 >= SLOW_NS:
+            _REC.keep(self.name, t0, t1, self.window, "slow", self.note)
+        return False
+
+
+#: the older name of :class:`span`, kept for callers outside the runtime
+annotate = span
+
+# -- what jax itself did inside a set-up span ---------------------------------
+
+_tls = threading.local()
+_watching = threading.Event()
+#: jax.monitoring's duration events of one program build, by the name of
+#: the set-up span each is kept as
+JAX_PARTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax/lower",
+    # a build, or the load of one from the persistent cache
+    "/jax/core/compile/backend_compile_duration": "jax/compile_or_load",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax/cache_load",
+}
+
+
+#: jax traces every jnp function a model calls on its own: hundreds of
+#: steps of microseconds a build, which say nothing
+JAX_PART_MIN_S = 0.01
+
+
+def _on_jax_duration(event: str, seconds: float, **kw) -> None:
+    """Keeps a build step jax reports while a set-up span is open on
+    this thread as a set-up span of its own (ending now, ``seconds``
+    long), so that ``<filter>/trace_lower`` and ``<filter>/first_call``
+    say what they spent their time on: tracing, lowering, compiling or
+    loading; what is left of ``first_call`` is the first execution."""
+    name = JAX_PARTS.get(event)
+    if name is None or seconds < JAX_PART_MIN_S \
+            or not getattr(_tls, "setup", 0):
+        return
+    end = time.perf_counter_ns()
+    _REC.keep(name, end - int(seconds * 1e9), end, None, "setup",
+              kw.get("fun_name"))
+
+
+def _watch_jax_compiles() -> None:
+    if _watching.is_set():
+        return
     import jax
 
-    jax.profiler.start_trace(log_dir,
-                             create_perfetto_link=create_perfetto_link)
+    with _REC.lock:             # once, whoever comes first
+        if _watching.is_set():
+            return
+        _watching.set()
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+
+@contextlib.contextmanager
+def pipeline_trace(log_dir: str, profiler_options=None):
+    """Capture a JAX profiler trace of everything run inside.
+    ``profiler_options`` (a ``jax.profiler.ProfileOptions``) is handed
+    to ``jax.profiler.start_trace`` only when given, so options a caller
+    bound onto ``start_trace`` itself stay in force.  The profiler's own
+    start and stop are kept as ``trace/start`` and ``trace/stop`` spans,
+    and the capture between them as ``trace/capture``."""
+    import jax
+
+    kw = {} if profiler_options is None \
+        else {"profiler_options": profiler_options}
+    keep = not _hooks.DISABLED
+    t0 = time.perf_counter_ns()
+    jax.profiler.start_trace(log_dir, **kw)
+    t1 = time.perf_counter_ns()
     _active.set()
     try:
         yield log_dir
     finally:
         _active.clear()
+        t2 = time.perf_counter_ns()
         jax.profiler.stop_trace()
+        t3 = time.perf_counter_ns()
+        if keep:
+            _REC.keep("trace/start", t0, t1, None, "trace", log_dir)
+            _REC.keep("trace/capture", t1, t2, None, "trace", log_dir)
+            _REC.keep("trace/stop", t2, t3, None, "trace", log_dir)
 
 
 def trace_active() -> bool:
     return _active.is_set()
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Per-element trace span; no-op unless a trace is being captured."""
-    if not _active.is_set():
-        yield
-        return
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-def step_marker(name: str, step: int) -> "contextlib.AbstractContextManager":
-    """StepTraceAnnotation for training loops (trainer element epochs)."""
-    import jax
-
-    return jax.profiler.StepTraceAnnotation(name, step_num=step)
 
 
 def frame_annotation(trace_ids) -> "contextlib.AbstractContextManager":
@@ -86,3 +287,239 @@ def frame_annotation(trace_ids) -> "contextlib.AbstractContextManager":
 
     return jax.profiler.TraceAnnotation(
         "nns:frames:" + ",".join(str(i) for i in trace_ids))
+
+
+# -- reading the spans back ---------------------------------------------------
+
+
+def spans() -> List[Span]:
+    """Every kept span, by start time.  A span whose site did not know
+    its window takes the id of the innermost span of the same thread
+    that encloses it."""
+    with _REC.lock:
+        rows = [s for part in _REC.lists.values() for s in part]
+    rows.sort(key=lambda s: (s.start_ns, -s.end_ns))
+    open_by_thread: Dict[int, List[Span]] = {}
+    out = []
+    for s in rows:
+        stack = open_by_thread.setdefault(s.thread, [])
+        while stack and stack[-1].end_ns < s.end_ns:
+            stack.pop()
+        if s.window is None and stack and stack[-1].window is not None:
+            s = s._replace(window=stack[-1].window)
+        stack.append(s)
+        out.append(s)
+    return out
+
+
+def dropped() -> int:
+    """Spans pushed out of a full list by newer ones."""
+    return _REC.dropped
+
+
+def clear() -> None:
+    """Forget every kept span (tests, and a process that runs one
+    measurement after another)."""
+    _REC.clear()
+
+
+def report_slow(log) -> int:
+    """Log, by name, the per-window spans of ``SLOW_NS`` or more kept
+    since the last report (``Pipeline.stop`` calls this); returns how
+    many there were."""
+    with _REC.lock:
+        fresh = _REC.slow_kept - _REC.slow_reported
+        rows = list(_REC.lists["slow"])[-fresh:] if fresh else []
+        _REC.slow_reported = _REC.slow_kept
+    by_name: Dict[str, List[int]] = {}
+    for s in rows:
+        by_name.setdefault(s.name, []).append(s.end_ns - s.start_ns)
+    for name, durs in sorted(by_name.items()):
+        log("slow span %s: %d over %d ms, %.1f ms in all, longest %.1f ms",
+            name, len(durs), SLOW_NS // 1_000_000, sum(durs) * 1e-6,
+            max(durs) * 1e-6)
+    return len(rows)
+
+
+# -- device stages ------------------------------------------------------------
+
+#: the root scopes of the fused program (filters/jax_xla._normalized_fn)
+STAGE_ROOT = "nns."
+NO_SCOPE = "(no nns scope)"
+NO_METADATA = "(no metadata)"
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r"metadata=\{[^}]*?op_name=\"([^\"]*)\"")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
+_CALLED = re.compile(
+    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+_WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
+_SCOPE = re.compile(r"^[\w.\-]+$")
+_EVENT_INSTR = re.compile(r"^%?([\w.\-]+)")
+
+
+def _unwrapped(part: str) -> str:
+    """``vmap(nms)`` → ``nms``: the scope a transformation wraps; an
+    inner ``jit(...)`` stays as it is (it is no scope)."""
+    while True:
+        m = _WRAPPED.match(part)
+        if m is None or m.group(1) in ("jit", "pjit"):
+            return part
+        part = m.group(2)
+
+
+def stage_of(op_name: str) -> str:
+    """``jit(f)/nns.model/vmap(nms)/jit(_where)/select_n`` →
+    ``nns.model/nms``: the scopes from the ``nns.`` root down, without
+    the primitive at the end, with transformations unwrapped (a bucket
+    program's root is ``vmap(nns.model)``) and anything under an inner
+    ``jit`` (or a name that is no scope's, such as an einsum's
+    subscripts) left out."""
+    parts = [_unwrapped(part) for part in op_name.split("/")]
+    for i, part in enumerate(parts):
+        if part.startswith(STAGE_ROOT):
+            break
+    else:
+        return NO_SCOPE
+    stage = [parts[i]]
+    for part in parts[i + 1:-1]:
+        if not _SCOPE.match(part):
+            break          # an inner jit, or a name jax put there itself
+        stage.append(part)
+    return "/".join(stage)
+
+
+def stage_map(executable_text: str) -> Dict[str, str]:
+    """Instruction name → stage, from an optimised HLO text
+    (``JaxXlaFilter.executable_text()``): the metadata every
+    instruction that came from the traced function, fusions included,
+    carries its scope in.  An instruction the compiler made itself (a
+    reduction it split, a copy it wrapped) has none and takes the stage
+    most instructions of the computations it calls have."""
+    own: Dict[str, Optional[str]] = {}
+    called: Dict[str, List[str]] = {}
+    members: Dict[str, List[str]] = {}
+    computation = None
+    for line in executable_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m is not None:
+            computation = m.group(1)
+            continue
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name = m.group(1)
+        op = _OP_NAME.search(line)
+        own[name] = stage_of(op.group(1)) if op else None
+        called[name] = _CALLED.findall(line)
+        members.setdefault(computation, []).append(name)
+
+    def resolve(name: str, depth: int = 0) -> Optional[str]:
+        if own.get(name) not in (None, NO_SCOPE) or depth > 8:
+            return own.get(name)
+        votes: Dict[str, int] = {}
+        for comp in called.get(name, ()):
+            for member in members.get(comp, ()):
+                stage = resolve(member, depth + 1)
+                if stage not in (None, NO_SCOPE):
+                    votes[stage] = votes.get(stage, 0) + 1
+        if votes:
+            return max(sorted(votes), key=votes.get)
+        return own.get(name)
+
+    out = {}
+    for name in own:
+        stage = resolve(name)
+        if stage is not None:
+            out[name] = stage
+    return out
+
+
+def _self_ns(events: list) -> list:
+    """[(event, ns not covered by events nested in it)] of one line."""
+    out, stack = [], []
+    for ev in sorted(events, key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][0][1] + stack[-1][0][2] <= ev[1]:
+            out.append(stack.pop())
+        if stack:
+            stack[-1][1] -= ev[2]
+        stack.append([ev, ev[2]])
+    out.extend(stack)
+    return [(ev, max(ns, 0.0)) for ev, ns in out]
+
+
+def stage_seconds(xplane_path: str,
+                  executable: Optional[str] = None) -> Dict[str, float]:
+    """Device seconds per stage of the fused program in one capture,
+    per chip (the mean over the device planes that ran anything).
+
+    A device operation is an event of a ``/device:`` plane's ``XLA Ops``
+    line, named by its whole instruction (the TPU), or any event with an
+    ``hlo_op`` stat (the CPU backend's thunk lines).  Neither backend
+    records the instruction's ``op_name`` with the event (checked on
+    the v5e and on the CPU, jax 0.9.0), so the stage is looked up by
+    instruction name in ``executable``, the program's optimised HLO
+    text; an operation not found there (or every one, without
+    ``executable``) is booked under ``(no metadata)``, one whose
+    op_name has no ``nns.`` scope under ``(no nns scope)``.  Nested
+    events count once (self time), so the stages sum to the union of
+    the operation intervals."""
+    from jax.profiler import ProfileData
+
+    by_name = stage_map(executable) if executable else {}
+    per_plane = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        device = plane.name.startswith("/device:")
+        totals: Dict[str, float] = {}
+        for line in plane.lines:
+            # of a device plane only the operations' own line: its
+            # "Async XLA Ops" line holds copies that overlap them
+            if device and not line.name.startswith("XLA Ops"):
+                continue
+            events = []
+            for ev in line.events:
+                if device:
+                    instr = _EVENT_INSTR.match(ev.name).group(1)
+                else:
+                    instr = {k: v for k, v in ev.stats}.get("hlo_op")
+                    if not instr:
+                        continue
+                events.append((by_name.get(str(instr), NO_METADATA),
+                               float(ev.start_ns), float(ev.duration_ns)))
+            for (stage, _s, _d), ns in _self_ns(events):
+                totals[stage] = totals.get(stage, 0.0) + ns * 1e-9
+        if totals:
+            per_plane.append(totals)
+    if not per_plane:
+        return {}
+    stages = sorted({k for t in per_plane for k in t})
+    return {k: sum(t.get(k, 0.0) for t in per_plane) / len(per_plane)
+            for k in stages}
+
+
+def main(argv=None) -> int:
+    import argparse
+
+    ap = argparse.ArgumentParser(
+        description="device seconds per stage of the fused program in a "
+                    "jax.profiler capture")
+    ap.add_argument("xplane", help="the capture's .xplane.pb")
+    ap.add_argument("--hlo", help="optimised HLO text of the program "
+                    "(JaxXlaFilter.executable_text()), for backends whose "
+                    "device events carry no op_name")
+    args = ap.parse_args(argv)
+    text = None
+    if args.hlo:
+        with open(args.hlo) as f:
+            text = f.read()
+    stages = stage_seconds(args.xplane, text)
+    total = sum(stages.values())
+    for stage, seconds in sorted(stages.items(), key=lambda kv: -kv[1]):
+        print(f"{seconds * 1e3:12.3f} ms {100 * seconds / total:6.2f} %  "
+              f"{stage}")
+    print(f"{total * 1e3:12.3f} ms 100.00 %  all device operations, per chip")
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -1,0 +1,443 @@
+"""The program's trace layer (``utils/profile.py``) on the CPU: what a
+span keeps and when, what ``pipeline_trace`` hands the profiler, the
+stage vocabulary inside the fused programs, and ``stage_seconds`` on a
+CPU capture.  No number here is a rate."""
+
+import glob
+import os
+import re
+import time
+import timeit
+
+import numpy as np
+import pytest
+
+from nnstreamer_tpu.obs import hooks
+from nnstreamer_tpu.utils import profile
+from nnstreamer_tpu.utils.profile import span, stage_of
+
+
+@pytest.fixture(autouse=True)
+def _clean():
+    profile.clear()
+    yield
+    profile._active.clear()
+    profile.clear()
+
+
+class _Annotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``."""
+
+    entered: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _Annotation.entered.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture
+def capture(monkeypatch):
+    """A capture that is active without a profiler behind it."""
+    import jax
+
+    _Annotation.entered = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    profile._active.set()
+    yield _Annotation.entered
+    profile._active.clear()
+
+
+# -- what a span keeps --------------------------------------------------------
+
+
+def test_nothing_kept_and_no_annotation_without_a_capture(monkeypatch):
+    import jax
+
+    def boom(*_a, **_k):
+        raise AssertionError("the profiler was touched with no capture")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    with span("el_net", "dispatch", 3):
+        pass
+    with span("el_net"):
+        pass
+    assert profile.spans() == []
+    assert profile.dropped() == 0
+
+
+def test_capture_keeps_every_span_under_its_name_on_both_clocks(capture):
+    with span("el_norm", None, 7):
+        with span("el_net", None, 7):
+            with span("el_net", "dispatch"):
+                pass
+    rows = profile.spans()
+    assert [s.name for s in rows] == ["el_norm", "el_net", "el_net/dispatch"]
+    assert capture == ["el_norm", "el_net", "el_net/dispatch"]
+    assert {s.kind for s in rows} == {"window"}
+    # the site that did not know its window takes its parent's
+    assert [s.window for s in rows] == [7, 7, 7]
+    assert len({s.thread for s in rows}) == 1
+    for outer, inner in zip(rows, rows[1:]):
+        assert outer.start_ns <= inner.start_ns <= inner.end_ns \
+            <= outer.end_ns
+
+
+@pytest.mark.parametrize("kind,seconds,kept", [
+    ("window", 0.0, 0),            # fast, no capture: gone
+    ("window", 0.06, 1),           # slow, no capture: the slow list
+    ("setup", 0.0, 1),             # set-up: always
+])
+def test_what_is_kept_outside_a_capture(kind, seconds, kept):
+    with span("el_src", "stage", setup=kind == "setup") as s:
+        s.note = "a note"
+        time.sleep(seconds)
+    rows = profile.spans()
+    assert len(rows) == kept
+    if kept:
+        assert rows[0].name == "el_src/stage" and rows[0].note == "a note"
+        assert rows[0].kind == ("setup" if kind == "setup" else "slow")
+        assert rows[0].end_ns - rows[0].start_ns >= seconds * 1e9
+
+
+def test_slow_threshold_is_fifty_milliseconds(monkeypatch):
+    assert profile.SLOW_NS == 50_000_000
+    ticks = iter([0, 49_999_999, 100, 100 + 50_000_000])
+    monkeypatch.setattr(profile.time, "perf_counter_ns", lambda: next(ticks))
+    with span("a", "x"):
+        pass
+    with span("b", "x"):
+        pass
+    assert [s.name for s in profile.spans()] == ["b/x"]
+
+
+@pytest.mark.parametrize("which", ["slow", "window", "setup"])
+def test_lists_are_bounded_and_keep_the_newest(monkeypatch, which):
+    assert (profile.SLOW_MAX, profile._REC.lists["slow"].maxlen) == \
+        (1024, 1024)
+    rec = profile._Recorder({"setup": 3, "window": 3, "slow": 3})
+    monkeypatch.setattr(profile, "_REC", rec)
+    for i in range(5):
+        rec.keep(f"s{i}", i, i + 1, None, which)
+    assert [s.name for s in profile.spans()] == ["s2", "s3", "s4"]
+    assert profile.dropped() == 2
+    profile.clear()
+    assert profile.spans() == [] and profile.dropped() == 0
+
+
+def test_jax_build_steps_are_kept_inside_a_set_up_span_only(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(profile, "JAX_PART_MIN_S", 0.0)
+
+    def f(x):
+        return jnp.tanh(x) * 3.0
+
+    jax.jit(f)(jnp.ones(7)).block_until_ready()     # outside: not kept
+    with span("el_net", "first_call", setup=True):
+        jax.jit(f)(jnp.ones(11)).block_until_ready()
+    rows = profile.spans()
+    outer = next(s for s in rows if s.name == "el_net/first_call")
+    parts = [s for s in rows if s.name.startswith("jax/")]
+    assert {"jax/trace", "jax/lower", "jax/compile_or_load"} <= {
+        s.name for s in parts}
+    for s in parts:
+        assert s.kind == "setup" and s.thread == outer.thread
+        assert s.end_ns <= outer.end_ns
+        assert s.end_ns - s.start_ns <= outer.end_ns - outer.start_ns
+    assert len(rows) == 1 + len(parts)
+
+
+def test_everything_off_under_the_kill_switch(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(hooks, "DISABLED", True)
+    monkeypatch.setattr(profile.time, "perf_counter_ns",
+                        lambda: pytest.fail("a clock was read"))
+    monkeypatch.setattr(
+        jax.profiler, "TraceAnnotation",
+        lambda *_a: pytest.fail("the profiler was touched"))
+    profile._active.set()
+    with span("el_net", "dispatch"):
+        pass
+    with span("el_src", "stage", setup=True):
+        pass
+    assert profile.spans() == []
+
+
+def test_report_slow_logs_each_name_once(monkeypatch):
+    rec = profile._Recorder()
+    monkeypatch.setattr(profile, "_REC", rec)
+    rec.keep("old", 0, 60_000_000, 1, "slow")
+    assert profile.report_slow(lambda *a: None) == 1
+    rec.keep("el_net/dispatch", 0, 60_000_000, 1, "slow")
+    rec.keep("el_net/dispatch", 0, 130_000_000, 2, "slow")
+    rec.keep("el_sink/fence", 0, 70_000_000, 2, "slow")
+    lines = []
+    assert profile.report_slow(lambda msg, *a: lines.append(msg % a)) == 3
+    assert len(lines) == 2
+    assert "el_net/dispatch: 2 over 50 ms, 190.0 ms in all, longest " \
+        "130.0 ms" in lines[0]
+    assert profile.report_slow(lambda msg, *a: lines.append(msg % a)) == 0
+
+
+def test_off_cost_of_a_span_is_well_under_a_microsecond_scale():
+    """Prints the cost with no capture (the module docstring quotes it);
+    asserts only an order of magnitude, the CI host being shared."""
+    def one():
+        with span("el_net", "dispatch", 1):
+            pass
+
+    n = 20000
+    per = min(timeit.repeat(one, number=n, repeat=5)) / n
+    print(f"span with no capture: {per * 1e6:.3f} us")
+    assert per < 20e-6
+    assert profile.spans() == []
+
+
+# -- pipeline_trace -----------------------------------------------------------
+
+
+@pytest.mark.parametrize("given", [False, True])
+def test_pipeline_trace_forwards_options_only_when_given(monkeypatch, given):
+    import jax
+
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **k: calls.append((a, k)))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    options = object()
+    kw = {"profiler_options": options} if given else {}
+    with profile.pipeline_trace("/tmp/x", **kw) as where:
+        assert where == "/tmp/x" and profile.trace_active()
+    assert not profile.trace_active()
+    assert calls == [(("/tmp/x",), kw)]
+    names = [s.name for s in profile.spans()]
+    assert sorted(names) == ["trace/capture", "trace/start", "trace/stop"]
+    by = {s.name: s for s in profile.spans()}
+    assert by["trace/start"].end_ns == by["trace/capture"].start_ns
+    assert by["trace/capture"].end_ns == by["trace/stop"].start_ns
+    assert by["trace/capture"].note == "/tmp/x"
+
+
+def test_removed_hooks_stay_removed():
+    assert not hasattr(profile, "step_marker")
+    import inspect
+
+    assert "create_perfetto_link" not in inspect.signature(
+        profile.pipeline_trace).parameters
+    # the names other callers import are still there
+    assert profile.annotate is profile.span
+    assert callable(profile.frame_annotation) and callable(
+        profile.trace_active)
+
+
+# -- the stage vocabulary -----------------------------------------------------
+
+
+@pytest.mark.parametrize("op_name,stage", [
+    ("jit(normalized)/nns.pre/el_norm/sub", "nns.pre/el_norm"),
+    ("jit(f)/nns.model/backbone/block03/conv_general_dilated",
+     "nns.model/backbone/block03"),
+    ("jit(f)/nns.model/vmap(nms)/jit(_where)/select_n", "nns.model/nms"),
+    ("jit(f)/nns.model/vmap(topk)/top_k", "nns.model/topk"),
+    ("jit(f)/nns.model/layer00/attn/...qd,...kd->...qk/dot_general",
+     "nns.model/layer00/attn"),
+    ("jit(f)/nns.model/heads/jit(relu6)/jit(clip)/max", "nns.model/heads"),
+    ("jit(f)/nns.model/reduce_sum", "nns.model"),
+    ("jit(f)/nns.post/overlay/transpose(jvp(inner))/mul",
+     "nns.post/overlay/inner"),
+    ("jit(f)/mul", profile.NO_SCOPE),
+])
+def test_stage_of(op_name, stage):
+    assert stage_of(op_name) == stage
+
+
+# appsink drop=true: a source that free-runs into a full appsink nobody
+# drains would block in render, and stop() would wait behind it
+SSD_LINE = ("device_src name=el_src num_buffers=-1 fps=1000000000 ! "
+            "tensor_transform name=el_norm mode=arithmetic "
+            "option=typecast:float32,add:-127.5,div:127.5 ! "
+            "tensor_filter name=el_net framework=jax-xla model={model} "
+            "{batch} ! tensor_decoder name=el_overlay mode=bounding_boxes "
+            "option1=mobilenet-ssd-postprocess option4=64:64 option5=64:64 "
+            "option7=device ! appsink name=el_sink max_buffers=4 drop=true")
+VIT_LINE = ("device_src name=el_src num_buffers=-1 fps=1000000000 ! "
+            "tensor_transform name=el_norm mode=arithmetic "
+            "option=typecast:float32,add:-127.5,div:127.5 ! "
+            "tensor_filter name=el_net framework=jax-xla model={model} "
+            "{batch} ! appsink name=el_sink max_buffers=4 drop=true")
+SSD_STAGES = {"nns.pre/el_norm", "nns.model/backbone/stem",
+              "nns.model/backbone/block00", "nns.model/backbone/block16",
+              "nns.model/extras", "nns.model/heads", "nns.model/decode",
+              "nns.model/topk", "nns.model/nms", "nns.post/overlay"}
+VIT_STAGES = {"nns.pre/el_norm", "nns.model/embed",
+              "nns.model/layer00/ln1", "nns.model/layer00/attn",
+              "nns.model/layer00/ln2", "nns.model/layer01/mlp",
+              "nns.model/head"}
+
+
+def _toy_models():
+    from nnstreamer_tpu.models.ssd import register_ssd
+    from nnstreamer_tpu.models.vit import register_vit
+
+    register_ssd("spans_toy_ssd", batch=2, size=64, max_out=10)
+    register_vit("spans_toy_vit", batch=2, image_size=32, dim=64, depth=2,
+                 heads=2, mlp_dim=128, num_classes=10)
+    register_ssd("spans_toy_ssd1", batch=1, size=64, max_out=10)
+    register_vit("spans_toy_vit1", batch=1, image_size=32, dim=64, depth=2,
+                 heads=2, mlp_dim=128, num_classes=10)
+
+
+def _program_text(line, shape, bucket):
+    from nnstreamer_tpu.runtime import parse_launch
+
+    rng = np.random.default_rng(5)
+    pipe = parse_launch(line)
+    pipe["el_src"].frames = [rng.integers(0, 255, shape, dtype=np.uint8)
+                             for _ in range(2)]
+    pipe["el_src"].pool_size = 2
+    pipe.start()
+    try:
+        for _ in range(max(bucket, 1) + 1):
+            assert pipe["el_sink"].pull(timeout=120) is not None
+        return pipe["el_net"].subplugin.executable_text(bucket)
+    finally:
+        pipe.stop()
+
+
+@pytest.mark.parametrize("model,line,shape,bucket,stages", [
+    ("spans_toy_ssd", SSD_LINE, (2, 64, 64, 3), 0, SSD_STAGES),
+    ("spans_toy_vit", VIT_LINE, (2, 32, 32, 3), 0, VIT_STAGES),
+    # the bucket compile of a micro-batching filter: no fusion pass
+    # there (nothing fused into a window program), the model's stages
+    ("spans_toy_ssd1", SSD_LINE, (1, 64, 64, 3), 2,
+     SSD_STAGES - {"nns.pre/el_norm", "nns.post/overlay"}),
+    ("spans_toy_vit1", VIT_LINE, (1, 32, 32, 3), 2,
+     VIT_STAGES - {"nns.pre/el_norm"}),
+])
+def test_optimised_hlo_carries_the_stage_of_every_instruction(
+        model, line, shape, bucket, stages):
+    _toy_models()
+    batch = f"batch={bucket} batch-timeout-ms=50" if bucket else ""
+    text = _program_text(line.format(model=model, batch=batch), shape,
+                         bucket)
+    seen = profile.stage_map(text)
+    assert stages <= set(seen.values())
+    # every instruction that has metadata is under an nns scope, apart
+    # from the parameters and the reducers' own little computations
+    # (named after the argument or the primitive alone)
+    for op_name in profile._OP_NAME.findall(text):
+        if stage_of(op_name) == profile.NO_SCOPE:
+            assert "/" not in op_name, op_name
+    # fusions carry it too: the device events of a chip are fusions
+    fusions = [name for name in seen if "fusion" in name]
+    assert fusions and all(seen[f].startswith("nns.") for f in fusions)
+
+
+# -- stage_seconds on a CPU capture -------------------------------------------
+
+
+def test_stage_seconds_sums_to_the_programs_busy_time(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    def f(x):
+        with jax.named_scope("nns.pre"):
+            with jax.named_scope("el_norm"):
+                x = (x - 1.0) * 2.0
+        with jax.named_scope("nns.model"):
+            with jax.named_scope("backbone/stem"):
+                x = jnp.tanh(x @ x.T)
+            with jax.named_scope("heads"):
+                x = jax.nn.softmax(x @ x, axis=-1)
+        return x
+
+    x = jnp.ones((256, 256))
+    g = jax.jit(f)
+    g(x).block_until_ready()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with profile.pipeline_trace(str(tmp_path), profiler_options=options):
+        for _ in range(4):
+            with span("el_net", "dispatch"):
+                g(x).block_until_ready()
+    path = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[0]
+    text = g.lower(x).compile().as_text()
+    stages = profile.stage_seconds(path, text)
+    assert {"nns.pre/el_norm", "nns.model/backbone/stem",
+            "nns.model/heads"} <= set(stages)
+    assert profile.NO_METADATA not in stages
+    # the same events, summed without the map: the program's busy time
+    busy = 0.0
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            busy += sum(ev.duration_ns for ev in line.events
+                        if "hlo_op" in dict(ev.stats)) * 1e-9
+    assert busy > 0
+    assert sum(stages.values()) == pytest.approx(busy, rel=1e-6)
+    # without the executable the CPU's events carry no op_name
+    assert set(profile.stage_seconds(path)) == {profile.NO_METADATA}
+    # the capture's own spans are on the profiler's clock too
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            names |= {ev.name for ev in line.events
+                      if ev.name.startswith("el_net")}
+    assert names == {"el_net/dispatch"}
+    assert profile.main([path, "--hlo", _write(tmp_path, text)]) == 0
+
+
+def _write(tmp_path, text):
+    path = os.path.join(str(tmp_path), "program.txt")
+    with open(path, "w") as f:
+        f.write(text)
+    return path
+
+
+def test_nested_device_events_count_once():
+    events = [("a", 0.0, 100.0), ("b", 10.0, 30.0), ("b", 50.0, 20.0),
+              ("c", 200.0, 50.0)]
+    got = {}
+    for (stage, _s, _d), ns in profile._self_ns(events):
+        got[stage] = got.get(stage, 0.0) + ns
+    assert got == {"a": 50.0, "b": 50.0, "c": 50.0}
+
+
+# -- the sites ----------------------------------------------------------------
+
+
+def test_a_traced_pipeline_names_its_phases_and_set_up(capture):
+    """One toy line under a capture: every phase span of the table in
+    Documentation/observability.md that this line has appears, nested in
+    its element's chain span, with the window id of its buffer."""
+    _toy_models()
+    _program_text(SSD_LINE.format(model="spans_toy_ssd", batch=""),
+                  (2, 64, 64, 3), 0)
+    rows = profile.spans()
+    names = {s.name for s in rows}
+    assert {"pipeline/fuse", "pipeline/negotiate", "el_net/trace_lower",
+            "el_net/first_call", "el_src/stage"} <= {
+        s.name for s in rows if s.kind == "setup"}
+    assert {"el_src/create", "el_norm", "el_net", "el_net/prep",
+            "el_net/dispatch", "el_net/sample_fence", "el_overlay",
+            "el_sink", "el_sink/fence", "el_sink/render"} <= names
+    window = [s for s in rows if s.kind == "window"]
+    by_window = {}
+    for s in window:
+        by_window.setdefault(s.window, set()).add(s.name)
+    assert {"el_norm", "el_net/dispatch", "el_sink/render"} <= by_window[1]
+    # the chain spans nest: el_norm holds el_net holds el_sink
+    one = {s.name: s for s in window if s.window == 1}
+    assert one["el_norm"].start_ns <= one["el_net"].start_ns \
+        <= one["el_sink"].start_ns <= one["el_sink"].end_ns \
+        <= one["el_net"].end_ns <= one["el_norm"].end_ns
+    assert re.fullmatch(r"el_\w+(/\w+)?", one["el_sink/render"].name)

@@ -682,13 +682,23 @@ def section_e(batch: int, size: int, num_classes: int, pool_batch: int,
     want = [host(one_chip(jax.device_put(f, devs[0]))) for f in frames]
     agree = {}
     try:
-        # 1. the composite over all four chips
-        got, _, info = run_composite(
+        # 1. the composite over all four chips, its frames staged where
+        #    the mesh filter reads them: no window is placed again
+        placed0 = LEDGER.totals(direction="d2d", reason="input")[1]
+        got, staged, info = run_composite(
             composite_launch(model, size, n_buffers, "mesh=data:4"),
             model, frames, n_buffers, "E: mesh")
         check(info["mesh"] is not None
               and set(info["mesh"].devices.flat) == set(devs),
               "E: mesh=data:4 is not laid over the four chips")
+        for i, arr in enumerate(staged):
+            check(len({s.device for s in arr.addressable_shards}) == 4,
+                  f"E: mesh: staged frame {i} does not sit a quarter on "
+                  "each chip")
+        placed = LEDGER.totals(direction="d2d", reason="input")[1] - placed0
+        check(placed == 0,
+              f"E: mesh: {placed} bytes placed again over {n_buffers} "
+              "windows (d2d.input), expected 0: placement not negotiated")
         dets = []
         for i, buf in enumerate(got):
             canvas, det = canvas_and_detections(buf)

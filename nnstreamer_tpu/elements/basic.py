@@ -166,6 +166,7 @@ class Queue(Element):
     decoder/sink stages."""
 
     FACTORY = "queue"
+    PASSES_BUFFERS = True
 
     def __init__(self, name=None, max_size_buffers: int = 16,
                  leaky: str = "", prefetch_host: bool = False, **props):
@@ -281,11 +282,14 @@ class Tee(Element):
     """1→N fan-out; each downstream branch receives every buffer."""
 
     FACTORY = "tee"
+    PASSES_BUFFERS = True
 
     def __init__(self, name=None, **props):
         super().__init__(name, **props)
         self.add_sink_pad()
         self._next = 0
+        # src pad name -> the layouts that branch asked for at start
+        self._wishes: dict = {}
 
     def request_pad(self, name: str) -> Optional[Pad]:
         if name in ("src_%u", "src"):
@@ -304,10 +308,32 @@ class Tee(Element):
         for sp in self.srcpads:
             self.push(buf, sp)
 
+    def handle_upstream_event(self, pad: Pad, event: Event) -> None:
+        if event.kind == EventKind.PLACEMENT:
+            # every branch reads the SAME buffer: what goes upstream is
+            # what they all asked for, as it stands after each request
+            self._wishes[pad.name] = event.data["layouts"]
+            event = Event.placement(self._common_wish())
+        super().handle_upstream_event(pad, event)
+
+    def _common_wish(self) -> tuple:
+        """Per tensor, the one layout every linked branch asked for, or
+        None where they differ; nothing while a branch has not asked."""
+        asked = [self._wishes.get(sp.name) for sp in self.srcpads
+                 if sp.peer is not None]
+        if any(w is None for w in asked):
+            return ()
+        return tuple(ws[0] if all(w == ws[0] for w in ws[1:]) else None
+                     for ws in zip(*asked))
+
+    def stop(self) -> None:
+        self._wishes.clear()
+
 
 @register_element("identity")
 class Identity(Element):
     FACTORY = "identity"
+    PASSES_BUFFERS = True
 
     def __init__(self, name=None, **props):
         super().__init__(name, **props)

@@ -312,6 +312,7 @@ class TensorFilter(Element):
             self._pool_attached = True
             return
         if b <= 1:
+            self._request_placement()
             return
         if self.invoke_dynamic:
             raise ValueError(
@@ -326,6 +327,20 @@ class TensorFilter(Element):
             flush_fn=self._invoke_microbatch, error_fn=self.post_error,
             name=self.name)
         self._batcher.start()
+
+    def _request_placement(self) -> None:
+        """Tell the upstream source where the plain ``invoke`` path
+        reads its inputs, so that it stages them there and no window is
+        placed again (sources start after every other element, with
+        negotiation and the fused prologue's recompile behind us, so
+        the executable's layout is final).  Sent only when every input
+        tensor reaches ``invoke`` as it arrives."""
+        if self.subplugin is None or self.invoke_dynamic \
+                or self._in_combi is not None:
+            return
+        layouts = self.subplugin.input_layouts()
+        if layouts and any(s is not None for s in layouts):
+            self.sinkpad.push_upstream_event(Event.placement(layouts))
 
     def stop(self) -> None:
         if self._pool_entry is not None:

@@ -322,6 +322,11 @@ class TensorTransform(TransformElement):
 
     FLEX_CACHE_MAX = 64
 
+    @property
+    def PASSES_BUFFERS(self) -> bool:  # noqa: N802 - Element's constant
+        # while fused, transform() returns the buffer it was given
+        return self._fused
+
     def _opchain(self) -> _OpChain:
         if self._chain_def is None:
             if not self.mode:

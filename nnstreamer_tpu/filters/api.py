@@ -126,6 +126,13 @@ class FilterSubplugin:
         raise FilterError(
             f"{self.NAME}: model cannot be reshaped to {in_spec}")
 
+    def input_layouts(self) -> Optional[tuple]:
+        """Where :meth:`invoke` reads its inputs, for a producer that
+        can stage them there (``Event.placement``): one
+        ``jax.sharding.Sharding`` or None ("no wish") per input tensor.
+        Default: no wish at all."""
+        return None
+
     # -- hot path ------------------------------------------------------------
 
     def invoke(self, inputs: Sequence[Any]) -> List[Any]:

@@ -896,6 +896,21 @@ class JaxXlaFilter(FilterSubplugin):
             self._batch_exec.clear()
         return c.in_spec, c.out_spec
 
+    def input_layouts(self) -> Optional[tuple]:
+        """The mesh executable's batch-sharded ``in_shardings``, which
+        :meth:`invoke` passes through untouched when an input already
+        has them.  None for an input it replicates (a producer staging
+        THAT would hold its buffers once per chip) and for one this
+        process cannot address alone; no wish at all without a mesh, or
+        when the executable donates its inputs (today it consumes the
+        placed copy, never the producer's own array)."""
+        c = self._compiled
+        if c is None or c.in_shardings is None or self._donate:
+            return None
+        return tuple(
+            None if s.is_fully_replicated or not s.is_fully_addressable
+            else s for s in c.in_shardings)
+
     # -- hot path ------------------------------------------------------------
 
     def invoke(self, inputs: Sequence[Any]) -> List[Any]:

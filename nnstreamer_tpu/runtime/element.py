@@ -139,6 +139,12 @@ class Element:
     # Factory name used by the registry / pipeline parser.
     FACTORY: str = ""
 
+    #: True on an element that hands every buffer on untouched (queue,
+    #: capsfilter, a fused tensor_transform).  Only such an element
+    #: passes a PLACEMENT request upstream: anything that computes on a
+    #: buffer must never be handed an array laid out for someone else.
+    PASSES_BUFFERS: bool = False
+
     def __init__(self, name: Optional[str] = None, **props):
         # Attributes the subclass assigned *before* chaining up are its
         # declared, settable properties (the GObject install_property
@@ -399,6 +405,8 @@ class Element:
             sp.push_event(event)
 
     def handle_upstream_event(self, pad: Pad, event: Event) -> None:
+        if event.kind == EventKind.PLACEMENT and not self.PASSES_BUFFERS:
+            return
         for p in self.sinkpads:
             p.push_upstream_event(event)
 

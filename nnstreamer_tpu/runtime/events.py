@@ -20,6 +20,7 @@ class EventKind(enum.Enum):
     FLUSH = "flush"
     SEGMENT = "segment"
     QOS_THROTTLE = "qos-throttle"  # upstream: requested max framerate
+    PLACEMENT = "placement"  # upstream, at start: where a consumer reads
     RELOAD_MODEL = "reload-model"  # custom: hot model swap
     EPOCH_COMPLETE = "epoch-complete"  # trainer notifications
     TRAINING_COMPLETE = "training-complete"
@@ -43,6 +44,16 @@ class Event:
     def qos_throttle(cls, rate: Fraction) -> "Event":
         """Ask upstream producers to cap their rate (frames/sec)."""
         return cls(EventKind.QOS_THROTTLE, {"rate": Fraction(rate)})
+
+    @classmethod
+    def placement(cls, layouts) -> "Event":
+        """Ask the upstream source to stage its buffers where this
+        consumer's executable reads them (parity: the GStreamer
+        ALLOCATION query).  ``layouts`` has one entry per input tensor:
+        the ``jax.sharding.Sharding`` the executable takes for it, or
+        ``None`` for "no wish".  Only elements that hand a buffer on
+        untouched pass it along (``Element.PASSES_BUFFERS``)."""
+        return cls(EventKind.PLACEMENT, {"layouts": tuple(layouts)})
 
     @classmethod
     def reload_model(cls, model: Any) -> "Event":

@@ -126,6 +126,7 @@ class CapsFilter(Element):
     """Pass-through element that constrains negotiation to its caps."""
 
     FACTORY = "capsfilter"
+    PASSES_BUFFERS = True
 
     def __init__(self, name=None, caps: Optional[Union[Caps, str]] = None,
                  **props):

@@ -202,6 +202,58 @@ def test_split_crossings_zero_and_handoff_row_byte_exact():
         p.stop()
 
 
+def test_first_stage_frames_are_staged_on_its_own_chips():
+    """Two plain stages behind ``device_src`` (chip_smoke.py section E's
+    split path): the FIRST stage asks the source for its input layout,
+    so its windows are staged on its own two chips and pass
+    ``_stage_ingress`` and ``invoke`` untouched; the second stage's
+    upstream is a filter, which drops the request, so the handoff
+    between the stages is as it was."""
+    from nnstreamer_tpu.elements.basic import TensorSink
+
+    pname, frames_n, window = "stagesplit_first", 6, (4, 8, 8, 3)
+    register_model("_t_stage_w", lambda x: x + 1.0,
+                   in_shapes=[window], in_dtypes=np.float32)
+    pool = [np.full(window, float(k), np.float32) for k in range(2)]
+    p = Pipeline(name=pname)
+    src = DeviceSrc(name="src", frames=pool, pool_size=2,
+                    num_buffers=frames_n)
+    a = TensorFilter(name="a", framework="jax-xla", model="_t_stage_w",
+                     mesh="data:2", devices="0-1")
+    b = TensorFilter(name="b", framework="jax-xla", model="_t_stage_w",
+                     mesh="data:2", devices="2-3")
+    out = TensorSink(name="out")
+    seen = []
+    out.connect(lambda buf: seen.append(buf.tensors[0].np()))
+    p.add(src, a, b, out)
+    p.link(src, a, b, out)
+    LEDGER.clear()
+    try:
+        with p:
+            assert p.wait_eos(timeout=120)
+            staged = [slot[0] for slot in src._pool]
+            want = a.subplugin._compiled.in_shardings[0]
+        assert all(sorted(d.id for d in s.devices()) == [0, 1]
+                   and want.is_equivalent_to(s.sharding, s.ndim)
+                   for s in staged)
+        rows = [(r["source"], r["direction"], r["reason"], r["count"])
+                for r in LEDGER.snapshot() if r["pipeline"] == pname
+                and r["reason"] in ("input", "handoff")]
+        # nothing placed into the first stage; one handoff a window
+        # into the second, and its reshard onto its own layout
+        assert ("a", "d2d", "input", frames_n) not in rows
+        assert not [r for r in rows if r[0] == "a"]
+        assert ("b", "d2d", "handoff", frames_n) in rows
+        assert STAGE_STATS.get(pname, "b")["frames"] == frames_n
+        assert STAGE_STATS.get(pname, "a") is None
+        assert len(seen) == frames_n
+        for i, got in enumerate(seen):
+            np.testing.assert_array_equal(
+                got, np.full(window, float(i % 2) + 2.0, np.float32))
+    finally:
+        unregister_model("_t_stage_w")
+
+
 def test_tensor_if_fifo_pts_concurrent_streams_mixed_offload():
     """Two concurrent streams route through tensor_if into ONE shared
     classifier pool on the 4-7 subset: per-stream FIFO order, pts and

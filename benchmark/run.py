@@ -35,6 +35,7 @@ if ROOT not in sys.path:  # `python3 benchmark/run.py` as well as `-m`
     sys.path.insert(0, ROOT)
 
 from benchmark import BenchmarkError  # noqa: E402
+from benchmark.inputs import tensors  # noqa: E402
 
 
 class Loader:
@@ -85,6 +86,62 @@ class Loader:
         if moved is None:
             return True
         return self.reports(self.entry("end_to_end", moved), cell)
+
+
+def cut_faults(cfg: dict, listed: list) -> list:
+    """What is wrong with a configuration file's stated cut, as text;
+    empty where it holds together.  ``reduced`` is the list
+    ``BENCHMARK.json`` gives, key for key.  A file that reduces
+    something names keys it has, gives the source's value for each
+    under ``published``, says under ``deployment`` how many chips share
+    a layer (``chips_per_layer``) and what this chip holds of one
+    (``this_chip``), and differs from ``published`` in no other key."""
+    reduced = cfg.get("reduced")
+    if reduced != listed:
+        return [f"reduced is {reduced!r}, BENCHMARK.json lists {listed!r}"]
+    if not reduced:
+        return []
+    faults = [f"reduced key {key!r} is not in the file"
+              for key in reduced if key not in cfg]
+    published = cfg.get("published")
+    if not isinstance(published, dict):
+        return faults + ["no `published` object (the source's value of "
+                         "each reduced key)"]
+    faults += [f"`published` lacks the reduced key {key!r}"
+               for key in reduced if key not in published]
+    faults += [f"{key!r} is {cfg[key]!r}, published {value!r}, and is "
+               "not listed in reduced"
+               for key, value in published.items()
+               if key not in reduced and key in cfg and cfg[key] != value]
+    deployment = cfg.get("deployment")
+    if not isinstance(deployment, dict) \
+            or not isinstance(deployment.get("chips_per_layer"), int) \
+            or deployment["chips_per_layer"] < 1 \
+            or not deployment.get("this_chip"):
+        faults.append("no `deployment` object with `chips_per_layer` (a "
+                      "whole number) and `this_chip` (what it holds)")
+    return faults
+
+
+def launch_line(work: dict, cfg: dict, mix: dict, **values) -> str:
+    """A cell's launch line with its placeholders filled in: from the
+    caller's ``values``, the mix's parameters, the configuration's own
+    ``launch_fields`` and, where the file has them, ``size``
+    (``image_size``) and ``transform`` - the first named wins."""
+    fields = {key: cfg[name] for key, name in (("size", "image_size"),
+                                               ("transform", "transform"))
+              if name in cfg}
+    fields.update(cfg.get("launch_fields", {}))
+    fields.update(mix)
+    fields.update(values)
+    try:
+        return work["launch"].format(**fields)
+    except KeyError as e:
+        raise BenchmarkError(
+            f"the launch line of {work.get('name')!r} asks for "
+            f"{{{e.args[0]}}}, which neither the mix, the caller nor "
+            f"{cfg.get('name')!r} (launch_fields, image_size, transform) "
+            "provides") from None
 
 
 def find_devices(chips: int, peaks: dict, require_chip: bool):
@@ -177,6 +234,8 @@ class Run:
         self.chips = int(cell["chips"])
         self.on_chip = on_chip
         self.model = loader.module("models", cfg["model"])
+        self.inputs = loader.module("inputs", cfg.get("inputs",
+                                                      "image_frames"))
         self.counters = Counters()
         self.t_start = T_PROCESS_START
         self.log = lambda msg: print(f"[bench] {msg}", flush=True)
@@ -185,11 +244,15 @@ class Run:
         return self.loader.module("weights", self.cfg["weights"]).make(
             self.cfg, self.seed)
 
+    def make_ring(self, slots: int, batch: int) -> list:
+        """``slots`` seeded input slots of ``batch`` frames each, from
+        the file the configuration names under ``inputs``."""
+        return self.inputs.make_ring(self.cfg, self.mix, self.seed,
+                                     int(slots), int(batch))
+
     def launch(self, **values) -> str:
         """The cell's launch line with its placeholders filled in."""
-        fields = {"size": self.cfg["image_size"],
-                  "transform": self.cfg["transform"], **self.mix, **values}
-        return self.workload["launch"].format(**fields)
+        return launch_line(self.workload, self.cfg, self.mix, **values)
 
 
 def _device_line(devices, peak: int) -> dict:
@@ -257,7 +320,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         run.log(f"check {row['name']}: {row['value']:.6g} "
                 f"(limit {row['limit']:.6g}) {'ok' if ok else 'FAIL'}")
     run.log(f"check took {time.perf_counter() - t0:.1f} s on "
-            f"{len(sample['frames'])} frames")
+            f"{len(tensors(sample['frames'])[0])} frames")
     run.log("in the window: compiles %d, aot_fallback %d, xla compiles %d"
             % (obs["window"]["compiles"], obs["window"]["aot_fallback"],
                obs["window"]["xla_compiles"]))
@@ -280,6 +343,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         device["window_s"] = obs["trace"]["window_s"]
         line["breakdown"] = {"device_ops": obs["trace"]["device_ops"][:10],
                              "idle_gaps": obs["trace"]["idle_gaps"][:10]}
+    # every number compared beside its limit; the line's last key
+    line["compared"] = {row["name"]: {"value": float(row["value"]),
+                                      "limit": float(row["limit"])}
+                        for row in numbers}
     return line
 
 
@@ -310,6 +377,9 @@ def main(argv=None) -> int:
         print(f"benchmark: {type(e).__name__}: {e}", file=sys.stderr)
         return 1
     print(json.dumps(line), flush=True)
+    for name, row in line["compared"].items():       # stderr's last lines
+        print(f"compared {name}: {row['value']:.6g} (limit "
+              f"{row['limit']:.6g})", file=sys.stderr)
     return 0
 
 

@@ -23,6 +23,8 @@ import os
 import re
 import time
 
+from benchmark.stages import stage_seconds
+
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -248,11 +250,16 @@ def reduce_trace(planes: list, chips: int, element_prefix: str = "",
     }
 
 
-def reduce_run(run) -> dict:
+def reduce_run(run, program_text: str | None = None) -> dict:
     """The reduction of the trace a run has just written under
     ``run.out_dir``.  On the chip the device planes are the TPU's; the
     CPU rehearsal (``rehearsal=True``) reads XLA's host thread-pool
-    lines in their place so that the whole path can be driven."""
+    lines in their place so that the whole path can be driven.
+
+    With ``program_text`` (the filter program's optimised HLO,
+    ``benchmark/stages.py`` ``program_text``) the result also holds
+    ``stage_s``: device seconds per ``nns.*`` stage of the fused program
+    over the whole capture, per chip, which sum to ``busy_s``."""
     prefix = run.workload.get("element_prefix", "el_")
     if run.on_chip:
         where = {"device_plane": DEVICE_PLANE, "ops_line": OPS_LINE}
@@ -263,7 +270,11 @@ def reduce_run(run) -> dict:
         chips = 1
     planes = load_xplane(find_xplane(run.out_dir), prefix,
                          where["device_plane"], where["ops_line"])
-    return reduce_trace(planes, chips, prefix, **where)
+    out = reduce_trace(planes, chips, prefix, **where)
+    out["stage_s"] = None if program_text is None else stage_seconds(
+        planes, program_text, chips, where["device_plane"],
+        where["ops_line"])
+    return out
 
 
 def describe(path: str, top: int = 8) -> None:

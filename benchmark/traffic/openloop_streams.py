@@ -30,7 +30,7 @@ import time
 import numpy as np
 
 from benchmark import BenchmarkError
-from benchmark.frames import make_ring
+from benchmark.inputs import sampled, tensors
 from benchmark.stats import GcWatch, settle_heap
 from benchmark.trace import reduce_run, trace_seconds, trace_steady_window
 
@@ -57,13 +57,12 @@ def run(run) -> dict:
 
     mix, cfg = run.mix, run.cfg
     n, fps = int(mix["streams"]), float(mix["fps"])
-    size = int(cfg["image_size"])
     warmup_s, drain_s = float(mix["warmup_s"]), float(mix["drain_s"])
     t0 = time.perf_counter()
     params = run.make_weights()
     model = f"bench_{cfg['name']}_frame_s{run.seed}"
     run.model.register(cfg, params, 1, model)
-    ring = make_ring(run.seed, int(mix["frame_ring"]), 1, size)
+    ring = run.make_ring(int(mix["frame_ring"]), 1)
     run.log(f"weights and {len(ring)} host frames made in "
             f"{time.perf_counter() - t0:.1f} s")
 
@@ -71,7 +70,9 @@ def run(run) -> dict:
     per_stream = int(np.ceil(total_s * fps)) + 1
     due = schedule(run.seed, n, fps, float(mix["jitter_ms"]), per_stream)
     order = np.argsort(due, axis=None, kind="stable")
-    spec = TensorsSpec.from_shapes([(1, size, size, 3)], np.uint8)
+    first = tensors(ring[0])
+    spec = TensorsSpec.from_shapes([a.shape for a in first],
+                                   [a.dtype for a in first])
     launch = run.launch(model=model)
     pipes = []
     for i in range(n):
@@ -176,8 +177,8 @@ def run(run) -> dict:
                 time.sleep(wait)
             frame = ring[(i * 7 + k) % len(ring)]
             try:
-                pipes[i]["el_src"].push_buffer(Buffer.of(frame, pts=k),
-                                               timeout=0.001)
+                pipes[i]["el_src"].push_buffer(
+                    Buffer.of(*tensors(frame), pts=k), timeout=0.001)
                 pushed_t[i, k] = time.perf_counter()
             except queue.Full:                   # refused at push
                 refused[i, k] = True
@@ -245,8 +246,8 @@ def run(run) -> dict:
         raise BenchmarkError(f"only {len(picks)} of {len(keep)} sampled "
                              "frames were delivered")
     sample = {
-        "frames": np.stack([ring[(i * 7 + k) % len(ring)][0]
-                            for i, k in picks]),
+        "frames": sampled(ring, [((i * 7 + k) % len(ring), 0)
+                                 for i, k in picks]),
         "served": {name: np.stack([np.asarray(kept[p][name])[0]
                                    for p in picks])
                    for name in kept[picks[0]]},
@@ -308,7 +309,8 @@ def _warm_buckets(run, pipes, pool, ring, Buffer) -> None:
         for b in missing:
             for i in range(min(b, n)):
                 pipes[i]["el_src"].push_buffer(
-                    Buffer.of(ring[i % len(ring)], pts=-1 - sent[i]))
+                    Buffer.of(*tensors(ring[i % len(ring)]),
+                              pts=-1 - sent[i]))
                 sent[i] += 1
             # wait for the burst to drain before the next
             while time.perf_counter() < deadline:

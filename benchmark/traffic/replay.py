@@ -30,7 +30,8 @@ import time
 import numpy as np
 
 from benchmark import BenchmarkError
-from benchmark.frames import make_ring
+from benchmark.inputs import sampled, tensors
+from benchmark.stages import program_text
 from benchmark.stats import GcWatch, settle_heap
 from benchmark.trace import reduce_run, trace_steady_window
 
@@ -38,28 +39,48 @@ PULL_TIMEOUT_S = 600.0
 
 
 def run(run) -> dict:
+    """Set-up (weights, model, ring, launch line), then :func:`stream`.
+    A kind with more set-up, such as state built before the window,
+    does its own and calls :func:`stream` likewise."""
     from nnstreamer_tpu.runtime import parse_launch
 
     mix, cfg = run.mix, run.cfg
     batch, slots = int(mix["batch"]), int(mix["ring_buffers"])
-    size = int(cfg["image_size"])
-    warm = int(mix.get("warmup_windows", 3))
-    open_at = warm + int(mix["sink_depth"]) + 2
 
     t0 = time.perf_counter()
     params = run.make_weights()
     model = f"bench_{cfg['name']}_b{batch}_s{run.seed}"
     run.model.register(cfg, params, batch, model)
-    ring = make_ring(run.seed, slots, batch, size)
+    ring = run.make_ring(slots, batch)
+    nbytes = sum(a.nbytes for slot in ring for a in tensors(slot))
     run.log(f"weights and a ring of {slots} x {batch} frames "
-            f"({sum(a.nbytes for a in ring) / 1e9:.2f} GB) made in "
+            f"({nbytes / 1e9:.2f} GB) made in "
             f"{time.perf_counter() - t0:.1f} s")
 
     t1 = time.perf_counter()
     pipe = parse_launch(run.launch(model=model))
-    src = pipe["el_src"]
+    obs = stream(run, pipe, ring, t1)
+    # free the program's state before the reference runs
+    del ring, pipe, params
+    run.model.unregister(model)
+    return obs
+
+
+def stream(run, pipe, ring, t1: float) -> dict:
+    """The second half of a replay run: stage ``ring`` through
+    ``pipe``'s ``device_src``, start, warm up, settle, open the window at
+    a fence, consume and count for ``run.seconds``, trace where asked,
+    stop, and sample what was served.  ``t1`` is when the launch line
+    was parsed (the log's clock).  Returns ``obs``; the caller frees
+    what it made (ring, weights, model) before the reference runs."""
+    mix = run.mix
+    batch, slots = int(mix["batch"]), len(ring)
+    warm = int(mix.get("warmup_windows", 3))
+    open_at = warm + int(mix["sink_depth"]) + 2
+    prefix = run.workload.get("element_prefix", "el_")
+    src = pipe[prefix + "src"]
     src.frames, src.pool_size = ring, len(ring)
-    sink = pipe["el_sink"]
+    sink = pipe[prefix + "sink"]
 
     state = {"open": None, "close": None, "windows": 0, "kept": [],
              "order_errors": 0, "pulled": 0, "snap_open": None, "fenced": [],
@@ -122,6 +143,7 @@ def run(run) -> dict:
 
     consumer = threading.Thread(target=consume, name="bench-consumer",
                                 daemon=True)
+    text = None
     pipe.start()
     run.log(f"ring staged and pipeline started in "
             f"{time.perf_counter() - t1:.1f} s")
@@ -138,6 +160,10 @@ def run(run) -> dict:
             trace_steady_window(
                 run, state["open"] + min(1.0, run.seconds / 4))
         done.wait(timeout=run.seconds + PULL_TIMEOUT_S)
+        if run.trace and state["close"] is not None:
+            # the window has closed and the stream runs on: the filter
+            # builds its program's text again, outside every reading
+            text = program_text(run, pipe)
     finally:
         # the consumer keeps draining until the pipeline has stopped
         pipe.stop()
@@ -147,7 +173,7 @@ def run(run) -> dict:
         raise state["error"]
     if state["close"] is None:
         raise BenchmarkError("the window did not close")
-    trace_obs = reduce_run(run) if run.trace else None
+    trace_obs = reduce_run(run, text) if run.trace else None
 
     window = run.counters.delta(state["snap_open"], state["snap_close"])
     gaps_ms = np.diff(np.asarray(state["fenced"])) * 1e3
@@ -183,9 +209,6 @@ def run(run) -> dict:
                            "value": float(state["order_errors"]),
                            "limit": 0.0}],
     }
-    # free the program's state before the reference runs
-    del ring, kept, state, src, sink, pipe, params
-    run.model.unregister(model)
     return obs
 
 
@@ -200,7 +223,7 @@ def _sample(run, ring, kept, batch, slots) -> dict:
             for _off, outs in kept]
     picks = [(int(rng.integers(len(kept))), int(rng.integers(batch)))
              for _ in range(want)]
-    frames = np.stack([ring[kept[w][0] % slots][r] for w, r in picks])
+    frames = sampled(ring, [(kept[w][0] % slots, r) for w, r in picks])
     served = {k: np.stack([host[w][k][r] for w, r in picks])
               for k in host[0]}
     return {"frames": frames, "served": served}

@@ -17,8 +17,8 @@ REPO = os.path.dirname(os.path.dirname(HERE))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from benchmark import stats, trace  # noqa: E402
-from benchmark.run import Loader  # noqa: E402
+from benchmark import BenchmarkError, stages, stats, trace  # noqa: E402
+from benchmark.run import Loader, cut_faults, launch_line  # noqa: E402
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     MANIFEST = json.load(_f)
@@ -56,7 +56,9 @@ def test_config_resolves(loader, config):
     assert entry["file"].startswith("benchmark/configs/")
     cfg = loader.config(config)
     assert cfg["name"] == config and cfg["source"] == entry["source"]
-    assert cfg["reduced"] == entry["reduced"] == []
+    # a cut is stated, not forbidden: what holds it together is below
+    assert cfg["reduced"] == entry["reduced"]
+    assert cut_faults(cfg, entry["reduced"]) == []
     assert cfg["assumed"] and cfg["limits"]
     # a configuration that is not its source's says so where it is named
     if "not_the_published_detector" in cfg:
@@ -81,8 +83,7 @@ def test_cell_resolves(loader, cell):
     assert work["chips"] == entry["chips"]
     assert callable(loader.module("traffic", mix["kind"]).run)
     cfg = loader.config(entry["config"])
-    line = work["launch"].format(model="m", size=cfg["image_size"],
-                                 transform=cfg["transform"], **mix)
+    line = launch_line(work, cfg, mix, model="m")
     prefix = work["element_prefix"]
     for element in ("src", "net", "sink"):
         assert f"name={prefix}{element}" in line
@@ -92,6 +93,76 @@ def test_cell_resolves(loader, cell):
            if loader.reports(m, cell)]
     assert "setup_s" in e2e and len(e2e) >= 2
     assert any(loader.reports(m, cell) for m in MANIFEST["per_layer"])
+
+
+# -- a stated cut ------------------------------------------------------------------
+
+CUT = {"name": "cut", "reduced": ["num_hidden_layers", "vocab_size"],
+       "num_hidden_layers": 5, "vocab_size": 25600, "hidden_size": 5120,
+       "published": {"num_hidden_layers": 60, "vocab_size": 102400,
+                     "hidden_size": 5120},
+       "deployment": {"chips_per_layer": 4,
+                      "this_chip": "a quarter of the vocabulary"}}
+
+
+def _without(cfg, *keys):
+    return {k: v for k, v in cfg.items() if k not in keys}
+
+
+def test_a_stated_cut_holds_together():
+    assert cut_faults(CUT, CUT["reduced"]) == []
+    assert cut_faults({"reduced": []}, []) == []
+    # the accepted configurations state none, and need nothing more
+    for entry in MANIFEST["configs"]:
+        assert entry["reduced"] == []
+
+
+@pytest.mark.parametrize("cfg,listed,says", [
+    (CUT, ["num_hidden_layers"], "BENCHMARK.json lists"),
+    (_without(CUT, "reduced"), CUT["reduced"], "BENCHMARK.json lists"),
+    (_without(CUT, "vocab_size"), CUT["reduced"],
+     "'vocab_size' is not in the file"),
+    (_without(CUT, "published"), CUT["reduced"], "no `published`"),
+    ({**CUT, "published": {"num_hidden_layers": 60}}, CUT["reduced"],
+     "`published` lacks the reduced key 'vocab_size'"),
+    ({**CUT, "hidden_size": 2560}, CUT["reduced"],
+     "'hidden_size' is 2560, published 5120"),
+    (_without(CUT, "deployment"), CUT["reduced"], "no `deployment`"),
+    ({**CUT, "deployment": {"chips_per_layer": 4}}, CUT["reduced"],
+     "no `deployment`"),
+    ({**CUT, "deployment": {"chips_per_layer": "four", "this_chip": "x"}},
+     CUT["reduced"], "no `deployment`"),
+], ids=["lists-differ", "file-lists-none", "key-absent", "no-published",
+        "published-lacks-key", "width-differs-unlisted", "no-deployment",
+        "deployment-holds-nothing", "chips-not-a-number"])
+def test_a_cut_that_does_not_hold_together_is_named(cfg, listed, says):
+    faults = cut_faults(cfg, listed)
+    assert faults and any(says in f for f in faults), faults
+
+
+# -- launch fields -----------------------------------------------------------------
+
+
+def test_launch_fields_come_from_the_files():
+    work = {"name": "c", "launch": "src fps={stamp} ! net model={model} "
+                                   "size={size} ! sink max={sink_depth}"}
+    cfg = {"name": "k", "image_size": 300, "launch_fields": {"stamp": 7}}
+    line = launch_line(work, cfg, {"sink_depth": 4}, model="m")
+    assert line == "src fps=7 ! net model=m size=300 ! sink max=4"
+    # the later named wins: caller over mix over launch_fields over size
+    assert launch_line({"launch": "{size} {stamp}"},
+                       {"image_size": 1, "launch_fields": {"size": 2,
+                                                           "stamp": 3}},
+                       {"stamp": 4}, stamp=5) == "2 5"
+    # a configuration with neither image_size nor transform is fine
+    assert launch_line({"launch": "net model={model}"}, {"name": "k"}, {},
+                       model="m") == "net model=m"
+
+
+def test_a_placeholder_nobody_provides_is_named():
+    work = {"name": "c", "launch": "net model={model} option={transform}"}
+    with pytest.raises(BenchmarkError, match=r"\{transform\}"):
+        launch_line(work, {"name": "tokens"}, {}, model="m")
 
 
 @pytest.mark.parametrize("metric",
@@ -220,6 +291,36 @@ def test_frames_from_seed():
     assert not np.array_equal(a[0], make_ring(2 ** 31 + 10, 3, 2, 60)[0])
 
 
+def test_image_frames_are_the_bytes_frames_makes(loader):
+    from benchmark.frames import make_ring
+    from benchmark.inputs import sampled, tensors
+
+    inputs = loader.module("inputs", "image_frames")
+    cfg, seed = {"image_size": 60}, 2 ** 31 + 9
+    ring = inputs.make_ring(cfg, {"batch": 99}, seed, 3, 2)
+    plain = make_ring(seed, 3, 2, 60)
+    assert len(ring) == 3
+    assert all(a.tobytes() == b.tobytes() and a.dtype == b.dtype
+               and a.shape == b.shape for a, b in zip(ring, plain))
+    # a slot is one array or a tuple of arrays; a sample keeps the shape
+    assert tensors(ring[0]) == (ring[0],)
+    picks = [(2, 1), (0, 0)]
+    got = sampled(ring, picks)
+    assert np.array_equal(got, np.stack([plain[2][1], plain[0][0]]))
+    pair = [(np.arange(4, dtype=np.int32).reshape(2, 2) + k,
+             np.full((2, 1), k, np.int32)) for k in range(3)]
+    tokens, positions = sampled(pair, picks)
+    assert tokens.tolist() == [[4, 5], [0, 1]]
+    assert positions.tolist() == [[2], [0]] and positions.dtype == np.int32
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in MANIFEST["configs"]])
+def test_accepted_configurations_name_no_inputs_file(loader, config):
+    """They get ``image_frames``: same frame bytes from the same seed."""
+    assert "inputs" not in loader.config(config)
+    assert callable(loader.module("inputs", "image_frames").make_ring)
+
+
 # -- the trace reduction, on a synthetic trace -----------------------------------
 
 
@@ -327,6 +428,148 @@ def test_per_window_program_time_and_roofline(loader):
     assert roof.read({"trace": None}) is None
     # a traffic kind whose windows vary gives no frames a window: no share
     assert roof.read({**obs, "frames_per_window": None}) is None
+
+
+# -- stage time ----------------------------------------------------------------------
+
+PROGRAM_TEXT = """HloModule jit_f
+
+%fused_a (p: f32[8]) -> f32[8] {
+  %p = f32[8] parameter(0)
+  ROOT %t = f32[8] tanh(%p), metadata={op_name="jit(f)/nns.model/backbone/block00/tanh"}
+}
+
+%fused_made (p: f32[8]) -> f32[8] {
+  %q = f32[8] parameter(0)
+  %m1 = f32[8] multiply(%q, %q), metadata={op_name="jit(f)/nns.model/vmap(nms)/jit(_where)/mul"}
+  ROOT %m2 = f32[8] add(%m1, %q), metadata={op_name="jit(f)/nns.model/vmap(nms)/add"}
+}
+
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8] parameter(0)
+  %fusion.1 = f32[8] fusion(%x), kind=kLoop, calls=%fused_a, metadata={op_name="jit(f)/nns.model/backbone/block00/tanh"}
+  %fusion.2 = f32[8] fusion(%fusion.1), kind=kLoop, calls=%fused_made
+  %attn.3 = f32[8] exponential(%fusion.2), metadata={op_name="jit(f)/nns.model/layer07/attn/exp"}
+  %outside.4 = f32[8] negate(%attn.3), metadata={op_name="jit(f)/neg"}
+  ROOT %copy.5 = f32[8] copy(%outside.4)
+}
+"""
+
+
+def test_stage_names_from_op_names_and_program_text():
+    of = stages.stage_of
+    assert of("jit(f)/nns.model/vmap(nms)/jit(_where)/select_n") \
+        == "nns.model/nms"
+    assert of("jit(f)/nns.model/layer07/attn/bhqk,bhkd->bhqd/dot_general") \
+        == "nns.model/layer07/attn"
+    assert of("jit(f)/nns.post/overlay/add") == "nns.post/overlay"
+    assert of("jit(f)/vmap(nns.model)/heads/conv") == "nns.model/heads"
+    assert of("jit(f)/mul") == stages.NO_SCOPE
+    by_name = stages.stage_map(PROGRAM_TEXT)
+    assert by_name["fusion.1"] == "nns.model/backbone/block00"
+    # no metadata of its own: the stage of what it calls
+    assert by_name["fusion.2"] == "nns.model/nms"
+    assert by_name["attn.3"] == "nns.model/layer07/attn"
+    assert by_name["outside.4"] == stages.NO_SCOPE
+    assert "copy.5" not in by_name
+
+
+def test_stage_seconds_on_a_synthetic_trace():
+    ms = 1e6
+    chip0 = [("%fusion.1 = f32[8] fusion(%x)", 0, 4 * ms),
+             ("%fusion.2 = f32[8] fusion(...)", 4 * ms, 2 * ms),
+             ("%attn.3 = f32[8] exponential(...)", 6 * ms, 3 * ms),
+             # nested in attn.3: counts once, for itself
+             ("%fusion.1 = f32[8] fusion(%x)", 7 * ms, 1 * ms),
+             ("%copy.5 = f32[8] copy(...)", 10 * ms, 1 * ms),
+             ("%outside.4 = f32[8] negate(...)", 11 * ms, 1 * ms)]
+    chip1 = [("%fusion.1 = f32[8] fusion(%x)", 0, 2 * ms)]
+    planes = [
+        {"name": "/device:TPU:1", "lines": [
+            {"name": "XLA Ops", "events": chip1}]},
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": chip0},
+            {"name": "XLA Modules", "events": [("jit_f(1)", 0, 12 * ms)]}]},
+        {"name": "/host:CPU", "lines": [
+            {"name": "nns:p:net", "events": [("el_net", 0, 12 * ms)]}]}]
+    one = stages.stage_seconds(planes, PROGRAM_TEXT, 1, "/device:TPU:",
+                               "XLA Ops")
+    assert one == {"nns.model/backbone/block00": pytest.approx(0.005),
+                   "nns.model/nms": pytest.approx(0.002),
+                   "nns.model/layer07/attn": pytest.approx(0.002),
+                   stages.NO_METADATA: pytest.approx(0.001),
+                   stages.NO_SCOPE: pytest.approx(0.001)}
+    # the stages sum to the union of the operation intervals
+    assert sum(one.values()) == pytest.approx(0.011)
+    # per chip: the mean over the chips the cell uses
+    two = stages.stage_seconds(planes, PROGRAM_TEXT, 2, "/device:TPU:",
+                               "XLA Ops")
+    assert two["nns.model/backbone/block00"] == pytest.approx(0.0035)
+    assert two["nns.model/nms"] == pytest.approx(0.001)
+    assert stages.stage_seconds(planes[2:], PROGRAM_TEXT, 1,
+                                "/device:TPU:", "XLA Ops") == {}
+
+
+def test_stage_ms_per_window_matches_by_prefix_and_last_part(loader):
+    read = loader.module("readers", "stage_ms_per_window").read
+    obs = {"trace": {"windows": 4, "stage_s": {
+        "nns.model/backbone/stem": 0.004, "nns.model/backbone/block00": 0.008,
+        "nns.model/heads": 0.002, "nns.model/nms": 0.001,
+        "nns.model/topk": 0.001, "nns.post/overlay": 0.002,
+        "nns.model/layer00/attn": 0.006, "nns.model/layer01/attn": 0.006,
+        "nns.model/layer00/mlp": 0.004, "nns.model/layer00/ln1": 0.001,
+        "(no metadata)": 0.5}}}
+    assert read(obs, starts=["nns.model/backbone"]) == pytest.approx(3.0)
+    assert read(obs, starts=["nns.model/decode", "nns.model/topk",
+                             "nns.model/nms", "nns.post"]) \
+        == pytest.approx(1.0)
+    assert read(obs, starts=["nns.model/layer"], ends=["/attn"]) \
+        == pytest.approx(3.0)
+    assert read(obs, starts=["nns.model/layer"], ends=["/mlp"]) \
+        == pytest.approx(1.0)
+    # nothing to read is nothing, never 0
+    assert read(obs, starts=["nns.model/extras"]) is None
+    assert read(obs, starts=["nns.model/layer"], ends=["/moe"]) is None
+    assert read({"trace": None}, starts=["nns."]) is None
+    assert read({}, starts=["nns."]) is None
+    assert read({"trace": {"windows": 4, "stage_s": None}},
+                starts=["nns."]) is None
+    assert read({"trace": {"windows": 0, "stage_s": {"nns.model/x": 1.0}}},
+                starts=["nns."]) is None
+
+
+STAGE_METRICS = ["backbone_ms_per_window", "postprocess_ms_per_window",
+                 "attn_ms_per_window", "mlp_ms_per_window"]
+
+
+@pytest.mark.parametrize("metric", STAGE_METRICS)
+def test_stage_metrics_are_data_files_of_one_reader(loader, metric):
+    spec = loader.json("layer_metrics", metric)
+    assert spec["name"] == metric
+    assert spec["reader"] == "stage_ms_per_window"
+    assert set(spec["args"]) <= {"starts", "ends"} and spec["args"]["starts"]
+    entry = loader.entry("per_layer", metric)
+    assert entry["source"] == "device_trace" and entry["unit"] == "ms"
+    assert entry["layer"] == "filter program"
+    assert entry["moves"] == "fps_per_chip" and entry["workloads"]
+
+
+def test_toy_root_drops_a_listed_cell_without_a_toy_twin(tmp_path):
+    sys.path.insert(0, HERE)
+    import toyroot
+
+    root = toyroot.build(str(tmp_path / "toy"))
+    toy = Loader(root)
+    listed = {m["name"]: m.get("workloads")
+              for m in toy.manifest["per_layer"]}
+    assert "ssd300.replay.mesh4" in Loader(REPO).entry(
+        "per_layer", "backbone_ms_per_window")["workloads"]
+    assert listed["backbone_ms_per_window"] == ["toy_ssd.replay"]
+    assert listed["attn_ms_per_window"] == ["toy_vit.replay"]
+    assert toy.reports(toy.entry("per_layer", "backbone_ms_per_window"),
+                       "toy_ssd.replay")
+    assert not toy.reports(toy.entry("per_layer", "attn_ms_per_window"),
+                           "toy_ssd.replay")
 
 
 def test_stalled_ms_is_the_time_beyond_the_median_interval(loader):

@@ -27,7 +27,7 @@ for _p in (REPO, HERE):
         sys.path.insert(0, _p)
 
 import toyroot  # noqa: E402
-from benchmark.run import Loader, run_cell  # noqa: E402
+from benchmark.run import Loader, cut_faults, run_cell  # noqa: E402
 
 SEED = 2 ** 31 + 20260927          # the driver's seeds pass 32 signed bits
 _RUNS: dict = {}
@@ -81,7 +81,49 @@ def test_cell_function_returns_the_contract_keys(root, cell, trace):
     else:
         assert {"fps_per_chip", "setup_s"} <= set(line["metrics"])
         assert "breakdown" not in line
+    # every number compared, beside its limit, as the line's last key
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == {n["name"] for n in
+                                     _run(root, cell, trace)[1]["numbers"]}
+    for row in line["compared"].values():
+        assert set(row) == {"value", "limit"}
+        assert row["value"] <= row["limit"]
     json.dumps(line)                                       # one JSON line
+
+
+def test_traced_rehearsals_read_their_own_stages_and_not_the_others(root):
+    """``stage_s`` of a traced run names the stages of the cell's own
+    fused program (the CPU's thunk events are named by instruction, like
+    the TPU's operations), and each cell reports the stage metrics that
+    list it.  The values are CPU time: counts of nothing."""
+    ssd, ssd_details = _run(root, "toy_ssd.replay", True)
+    vit, vit_details = _run(root, "toy_vit.replay", True)
+    ssd_stages = ssd_details["obs"]["trace"]["stage_s"]
+    vit_stages = vit_details["obs"]["trace"]["stage_s"]
+    assert {"nns.model/backbone/stem", "nns.model/backbone/block00",
+            "nns.model/heads", "nns.model/nms", "nns.model/topk",
+            "nns.post/overlay"} <= set(ssd_stages)
+    assert {"nns.model/embed", "nns.model/layer00/attn",
+            "nns.model/layer01/mlp", "nns.model/head"} <= set(vit_stages)
+    assert not any("/layer0" in name for name in ssd_stages)
+    assert not any("/backbone" in name for name in vit_stages)
+    for name in ("backbone_ms_per_window", "postprocess_ms_per_window"):
+        assert ssd["metrics"][name]["value"] > 0
+        assert ssd["metrics"][name]["unit"] == "ms"
+        assert name not in vit["metrics"]
+    for name in ("attn_ms_per_window", "mlp_ms_per_window"):
+        assert vit["metrics"][name]["value"] > 0
+        assert name not in ssd["metrics"]
+    # the parts are parts: no more than the program's time a window
+    for line, parts in ((ssd, ("backbone_ms_per_window",
+                               "postprocess_ms_per_window")),
+                        (vit, ("attn_ms_per_window", "mlp_ms_per_window"))):
+        whole = sum(line["metrics"][p]["value"] for p in parts)
+        assert whole <= 1.05 * line["metrics"]["program_ms_per_window"][
+            "value"]
+    # an untraced run asks for no program text and has no trace
+    _plain, plain_details = _run(root, "toy_ssd.replay", False)
+    assert plain_details["obs"]["trace"] is None
 
 
 def test_replay_counts_whole_windows_and_one_program_each(root):
@@ -180,6 +222,24 @@ def _command(cwd, *args):
         capture_output=True, text=True, timeout=300)
 
 
+def test_command_prints_the_line_last_and_the_numbers_compared(
+        monkeypatch, capsys):
+    from benchmark import run as harness
+
+    canned = {"correct": True, "attempted": 2, "failed": 0, "metrics": {},
+              "device": {}, "compared": {
+                  "logits_rel_l2": {"value": 0.0021, "limit": 0.014},
+                  "order_errors": {"value": 0.0, "limit": 0.0}}}
+    monkeypatch.setattr(harness, "run_cell", lambda *a, **kw: canned)
+    assert harness.main(["--workload", "c", "--seed", "1", "--seconds",
+                         "1"]) == 0
+    out, err = capsys.readouterr()
+    assert json.loads(out.splitlines()[-1]) == canned
+    assert err.splitlines()[-2:] == [
+        "compared logits_rel_l2: 0.0021 (limit 0.014)",
+        "compared order_errors: 0 (limit 0)"]
+
+
 def test_command_refuses_the_cpu_backend():
     out = _command(REPO, "--workload", "ssd300.replay", "--seed", str(SEED),
                    "--seconds", "1", "--trace", "0")
@@ -204,6 +264,23 @@ def test_command_refuses_a_directory_without_the_program(tmp_path):
 # -- added by files -----------------------------------------------------------------
 
 
+def _snapshot(directory: str) -> dict:
+    """{path: bytes} of every file under ``directory``."""
+    before = {}
+    for base, _dirs, files in os.walk(directory):
+        for name in files:
+            path = os.path.join(base, name)
+            with open(path, "rb") as f:
+                before[path] = f.read()
+    return before
+
+
+def _assert_unedited(before: dict) -> None:
+    for path, content in before.items():
+        with open(path, "rb") as f:
+            assert f.read() == content, f"{path} was edited"
+
+
 def test_cell_config_traffic_and_metric_are_added_as_files(root, tmp_path):
     """A later PR adds a configuration, a cell, a traffic kind and a
     per-layer metric as new files plus entries in BENCHMARK.json; the
@@ -211,12 +288,7 @@ def test_cell_config_traffic_and_metric_are_added_as_files(root, tmp_path):
     new = str(tmp_path / "added")
     shutil.copytree(root, new)
     bench = os.path.join(new, "benchmark")
-    before = {}
-    for base, _dirs, files in os.walk(bench):
-        for name in files:
-            path = os.path.join(base, name)
-            with open(path, "rb") as f:
-                before[path] = f.read()
+    before = _snapshot(bench)
     # a configuration: its file of sizes (the reference beside it is the
     # ViT's, named in the file)
     with open(os.path.join(bench, "configs", "toy_vit.json")) as f:
@@ -283,6 +355,82 @@ def test_cell_config_traffic_and_metric_are_added_as_files(root, tmp_path):
     other = run_cell("toy_vit.replay", SEED + 2, 0.4, True, root=new,
                      rehearsal=True)
     assert "added_metric" not in other["metrics"]
-    for path, content in before.items():
-        with open(path, "rb") as f:
-            assert f.read() == content, f"{path} was edited"
+    _assert_unedited(before)
+
+
+ADDED = os.path.join(HERE, "data", "added_tokens")
+
+
+def test_a_cut_token_configuration_is_added_as_files(root, tmp_path):
+    """The hard case, as a ``model_config`` PR would bring it: a
+    configuration with no ``image_size`` and a stated cut (``reduced``,
+    ``published``, ``deployment``), an ``inputs`` file that makes int32
+    token windows as a tuple of two arrays, its own weights, glue,
+    reference and costs, a launch line with no ``tensor_transform`` and a
+    field of the configuration's own, a stage metric as a data file, and
+    per-layer metrics that list the added cell alone.  All of it is files
+    (``tests/benchmark/data/added_tokens``) plus entries; the harness
+    takes it unedited and the run is ``correct``."""
+    new = str(tmp_path / "added")
+    shutil.copytree(root, new)
+    bench = os.path.join(new, "benchmark")
+    before = _snapshot(bench)
+    with open(os.path.join(ADDED, "manifest_entries.json")) as f:
+        entries = json.load(f)
+    for kind in sorted(os.listdir(ADDED)):
+        if not os.path.isdir(os.path.join(ADDED, kind)):
+            continue
+        for name in os.listdir(os.path.join(ADDED, kind)):
+            if name == "__pycache__":
+                continue
+            target = os.path.join(bench, kind, name)
+            assert not os.path.exists(target), f"{target} is there already"
+            shutil.copy(os.path.join(ADDED, kind, name), target)
+    with open(os.path.join(new, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    for section, rows in entries.items():
+        manifest[section] += rows
+    with open(os.path.join(new, "BENCHMARK.json"), "w") as f:
+        json.dump(manifest, f)
+
+    loader = Loader(new)
+    cfg = loader.config("added_tokens")
+    assert "image_size" not in cfg and "transform" not in cfg
+    assert cfg["reduced"] and cut_faults(
+        cfg, loader.entry("configs", "added_tokens")["reduced"]) == []
+    details: dict = {}
+    line = run_cell("added.tokens", SEED + 3, 0.4, True, root=new,
+                    rehearsal=True, details=details)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    # what went in and what the reference was handed: two int32 tensors
+    tokens, positions = details["frames"]
+    assert tokens.dtype == positions.dtype == np.int32
+    assert tokens.shape == positions.shape == (cfg["check_frames"], 1)
+    assert tokens.max() < cfg["vocab_size"]
+    assert "tensor_transform" not in loader.json("workloads",
+                                                 "added.tokens")["launch"]
+    # its own stage, by a data file of the stage reader; its own reader;
+    # and the metrics every cell reports
+    assert {"nns.model/layer00/mix", "nns.model/layer03/mix"} \
+        <= set(details["obs"]["trace"]["stage_s"])
+    assert line["metrics"]["added_mix_ms_per_window"]["value"] > 0
+    assert line["metrics"]["added_token_bytes_per_frame"] == {
+        "value": 8.0, "unit": "bytes"}
+    assert {"program_ms_per_window", "host_ms_per_window"} \
+        <= set(line["metrics"])
+    # the image cells' stage metrics list other cells: not read here
+    assert not {"backbone_ms_per_window", "attn_ms_per_window"} \
+        & set(line["metrics"])
+    # a cell that the new metrics do not list does not report them
+    other = run_cell("toy_vit.replay", SEED + 3, 0.4, True, root=new,
+                     rehearsal=True)
+    assert other["correct"] is True
+    assert not {"added_mix_ms_per_window",
+                "added_token_bytes_per_frame"} & set(other["metrics"])
+    # the control: the tables in float8 fail the cell's number
+    reference = loader.module("reference", cfg["reference"])
+    numbers = reference.control(cfg, SEED + 3, details["frames"])
+    assert [n["name"] for n in numbers if n["value"] > n["limit"]] \
+        == ["sum_abs_err"]
+    _assert_unedited(before)

@@ -687,6 +687,11 @@ class TensorFilter(Element):
         if sample:
             with _profile.span(self.name, "sample_fence"):
                 block_all(outs)
+                sp = self.subplugin
+                if sp is not None:
+                    # a stateful model's own counters ride in its state:
+                    # read here, where the stream is fenced anyway
+                    sp.fetch_counters()
             t2 = time.monotonic()
             self.invoke_stats.record(t2 - t0, frames=frames)
             self._last_sample_ts = t2

@@ -140,6 +140,12 @@ class FilterSubplugin:
         arrays out).  Must be thread-safe w.r.t. ``handle_event``."""
         raise NotImplementedError
 
+    def fetch_counters(self) -> None:
+        """Called by the owning element where its blocking stats sample
+        has fenced the stream anyway: a framework whose model keeps
+        counters on the device reads them here (jax-xla's stateful
+        models), never per invoke.  Default: nothing to read."""
+
     # -- events --------------------------------------------------------------
 
     def handle_event(self, event: Event) -> None:

@@ -37,7 +37,7 @@ from ..obs import transfer as _xfer
 from ..obs import xlacost as _xlacost
 from ..runtime.events import Event, EventKind
 from ..utils import profile as _profile
-from ..utils.stats import COMPILE_STATS, DISPATCH_STATS
+from ..utils.stats import COMPILE_STATS, DISPATCH_STATS, STATE_STATS
 from .api import FilterError, FilterProps, FilterSubplugin, SHARED_MODELS
 from .registry import register_filter
 
@@ -237,6 +237,162 @@ def get_model(name: str) -> Optional[ModelDef]:
         return _models.get(name)
 
 
+# -- stateful models ---------------------------------------------------------
+
+
+class StatefulModelDef(ModelDef):
+    """A model that owns device state between invokes
+    (``Documentation/stateful-models.md``): ``init_state(params) ->
+    state`` and one or more entry points ``fn(params, state, *inputs) ->
+    (state, outputs)``, each with the input schema it serves.  A filter
+    runs the entry point whose schema its negotiated input matches.
+    ``params`` and ``state`` are ARGUMENTS of the executable, never
+    closed over: the compiled program holds no weight literal and serves
+    every set of weights of the same shapes.  ``setup_entries`` names
+    the entry points that build state ahead of a stream (a prefill):
+    their calls are fenced inside a set-up span of their own name.
+    ``counters(state)`` picks the small unsigned counters the steps keep
+    in the state; ``counter_units(state)`` maps a published counter to
+    ``(raw counter, what one count stands for)``."""
+
+    def __init__(self, entries: Dict[str, Tuple[Callable, TensorsSpec]],
+                 params: Any, init_state: Callable, name: str,
+                 setup_entries: Sequence[str] = (),
+                 counters: Optional[Callable] = None,
+                 counter_units: Optional[Callable] = None):
+        if not entries:
+            raise ValueError("a stateful model needs an entry point")
+        self.entries = dict(entries)
+        super().__init__(None, params, next(iter(self.entries.values()))[1],
+                         name)
+        self.init_state = init_state
+        self.setup_entries = tuple(setup_entries)
+        self.counters = counters
+        self.counter_units = counter_units
+
+    def entry_for(self, in_spec: TensorsSpec) -> str:
+        """The entry point that serves ``in_spec``."""
+        for name, (_fn, spec) in self.entries.items():
+            if in_spec.is_compatible(spec):
+                return name
+        raise FilterError(
+            f"jax-xla: stateful model {self.name} has no entry point for "
+            f"input {in_spec}; it serves "
+            + "; ".join(f"{n}: {s}" for n, (_f, s) in self.entries.items()))
+
+
+def register_stateful_model(name: str, entries: Dict[str, Tuple],
+                            params: Any, init_state: Callable,
+                            setup_entries: Sequence[str] = (),
+                            counters: Optional[Callable] = None,
+                            counter_units: Optional[Callable] = None) -> str:
+    """Register a stateful model for ``model=name``.  ``entries`` maps
+    an entry point's name to ``(fn, in_shapes, in_dtypes)`` (or ``(fn,
+    TensorsSpec)``); the first is what an element negotiates by
+    default."""
+    table = {}
+    for ename, entry in entries.items():
+        fn, spec = entry[0], entry[1]
+        if not isinstance(spec, TensorsSpec):
+            spec = TensorsSpec.from_shapes(
+                spec, entry[2] if len(entry) > 2 else np.float32)
+        table[ename] = (fn, spec)
+    with _models_lock:
+        _models[name] = StatefulModelDef(
+            table, params, init_state, name, setup_entries=setup_entries,
+            counters=counters, counter_units=counter_units)
+    return name
+
+
+#: one jitted step per (entry function, device, argument shapes): two
+#: sets of weights of one model share their programs
+_stateful_programs: Dict[Tuple, Dict[str, Any]] = {}
+_stateful_lock = threading.Lock()
+
+
+def _tree_avals(tree) -> Any:
+    jax = _jax()
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+
+class _StateCell:
+    """The weights and the state of one stateful model on the device,
+    shared by every filter opened on it (one filter, or all the filters
+    of one ``shared-tensor-filter-key``): the state goes into each call
+    donated and comes back, under ``lock``; the last sharer to close
+    frees it."""
+
+    def __init__(self, model: StatefulModelDef, device, owner: str,
+                 table_key: Optional[str]):
+        jax = _jax()
+        self.model, self.device, self.table_key = model, device, table_key
+        self.lock = threading.Lock()
+        self.refs = 0
+        self.compiled: Dict[str, _Compiled] = {}
+        self._last: Dict[str, int] = {}
+        with _profile.span(owner, "state_init", setup=True):
+            t0 = time.perf_counter()
+            self.params = jax.device_put(model.params, device)
+            _xfer.record("h2d", "weights", _xfer.params_nbytes(model.params),
+                         time.perf_counter() - t0, source=model.name)
+            self.state = jax.device_put(model.init_state(self.params),
+                                        device)
+            jax.block_until_ready(self.state)
+        self.state_bytes = sum(
+            int(a.nbytes) for a in jax.tree_util.tree_leaves(self.state))
+        STATE_STATS.add("state_bytes", self.state_bytes)
+
+    def step(self, program: Callable, inputs: Sequence[Any]):
+        """One call of ``program(params, state, *inputs)``: the state is
+        donated to it and the returned one kept."""
+        with self.lock:
+            state, self.state = self.state, None
+            if state is None:
+                raise FilterError(
+                    f"jax-xla: the state of {self.model.name} was lost "
+                    "(freed, or consumed by a call that failed)")
+            self.state, out = program(self.params, state, *inputs)
+        return out
+
+    def fetch_counters(self) -> None:
+        """Read the counters the steps keep in the state (a fence: call
+        it at the stats-sample cadence, never per step) and add what
+        they gained since the last read to ``STATE_STATS``."""
+        model = self.model
+        if model.counters is None:
+            return
+        with self.lock:
+            if self.state is None:
+                return
+            raw = _jax().device_get(model.counters(self.state))
+            units = model.counter_units(self.state) \
+                if model.counter_units is not None else {}
+        gained = {}
+        for name, value in raw.items():
+            value = int(value)
+            gained[name] = (value - self._last.get(name, 0)) % (1 << 32)
+            self._last[name] = value
+        for name, (raw_name, unit) in units.items():
+            gained[name] = gained.get(raw_name, 0) * unit
+        for name, n in gained.items():
+            STATE_STATS.add(name, n)
+
+    def release(self) -> None:
+        with self.lock:
+            self.refs -= 1
+            if self.refs > 0:
+                return
+            state, self.state = self.state, None
+            self.compiled.clear()
+        if self.table_key is not None:
+            SHARED_MODELS.remove(self.table_key)
+        if state is not None:
+            for leaf in _jax().tree_util.tree_leaves(state):
+                leaf.delete()
+            STATE_STATS.add("state_bytes", -self.state_bytes)
+
+
 # -- the sub-plugin ----------------------------------------------------------
 
 
@@ -320,6 +476,9 @@ class JaxXlaFilter(FilterSubplugin):
         # (utils/profile.py): the owning element sets its own name, a
         # serving pool its label
         self.trace_owner = self.NAME
+        # the weights and state of a stateful model (None for every
+        # other model): what this instance's calls thread through
+        self._cell: Optional[_StateCell] = None
 
     def set_fused_pre(self, chains: list) -> None:
         """Install upstream transform op chains (runtime/fusion.py) to be
@@ -364,10 +523,17 @@ class JaxXlaFilter(FilterSubplugin):
             f"{self._placement.key if self._placement is not None else self._placement_key(props)}"
         if props.shared_key:
             shared = SHARED_MODELS.get(table_key)
+        if isinstance(shared, _StateCell):
+            self._open_stateful(shared.model, props, table_key, shared)
+            return
         if shared is not None:
             self._model, self._compiled = shared
             return
         self._model = self._resolve_model(props.model)
+        if isinstance(self._model, StatefulModelDef):
+            self._open_stateful(self._model, props,
+                                table_key if props.shared_key else None)
+            return
         in_spec = props.input_spec or self._model.in_spec
         if in_spec is None:
             raise FilterError(
@@ -379,6 +545,9 @@ class JaxXlaFilter(FilterSubplugin):
                 table_key, (self._model, self._compiled))
 
     def close(self) -> None:
+        cell, self._cell = self._cell, None
+        if cell is not None:
+            cell.release()      # the last sharer frees the state
         self._compiled = None
         self._model = None
         with self._batch_lock:
@@ -451,7 +620,8 @@ class JaxXlaFilter(FilterSubplugin):
         if model is None or model.params is None:
             return None
         placement = "mesh" if model._mesh_params else (
-            "device" if model._dev_params else "host")
+            "device" if model._dev_params or self._cell is not None
+            else "host")
         return {"bytes": _xfer.params_nbytes(model.params),
                 "placement": placement}
 
@@ -856,6 +1026,124 @@ class JaxXlaFilter(FilterSubplugin):
 
         return [compose(i) for i in range(len(in_spec.tensors))]
 
+    # -- stateful models -----------------------------------------------------
+
+    def _open_stateful(self, model: StatefulModelDef, props: FilterProps,
+                       table_key: Optional[str],
+                       cell: Optional[_StateCell] = None) -> None:
+        """Open on a stateful model: join the cell ``table_key`` names
+        in the shared-model table (one set of weights and one state for
+        every filter of one ``shared-tensor-filter-key``) or make one of
+        this instance's own, and pick the entry point the input schema
+        asks for."""
+        if self._mesh is not None:
+            raise FilterError(
+                f"jax-xla: stateful model {model.name} cannot be laid "
+                "over a mesh yet (mesh= shards the batch of a stateless "
+                "program; a sharded state has no placement rule)")
+        if cell is None:
+            cell = _StateCell(model, self._device, self.trace_owner,
+                              table_key)
+            if table_key is not None:
+                kept = SHARED_MODELS.insert(table_key, cell)
+                if kept is not cell:      # lost a race: use the winner's
+                    cell.refs = 1
+                    cell.table_key = None
+                    cell.release()
+                    cell = kept
+        with cell.lock:
+            cell.refs += 1
+        self._model, self._cell = model, cell
+        self._compiled = self._compile_stateful(
+            props.input_spec or model.in_spec)
+
+    def _compile_stateful(self, in_spec: TensorsSpec) -> _Compiled:
+        """The executable of the entry point that serves ``in_spec``:
+        ``step(params, state, *inputs) -> (state, outputs)`` with the
+        state donated.  Weights and state are arguments, so the program
+        is keyed by shapes alone and is shared by every cell (every set
+        of weights) of those shapes."""
+        jax = _jax()
+        cell, model, owner = self._cell, self._model, self.trace_owner
+        if self._pre_chains or self._post_fns:
+            raise FilterError(
+                f"jax-xla: stateful model {model.name} cannot take a "
+                "fused transform or decoder stage")
+        entry = model.entry_for(in_spec)
+        done = cell.compiled.get(entry)
+        if done is not None:
+            return done
+        fn, spec = model.entries[entry]
+        avals = [jax.ShapeDtypeStruct(t.shape, t.dtype.np_dtype)
+                 for t in spec.tensors]
+        p_avals, s_avals = _tree_avals(cell.params), _tree_avals(cell.state)
+        key = (fn, cell.device, str(p_avals), str(s_avals), spec)
+        with _stateful_lock:
+            prog = _stateful_programs.get(key)
+        if prog is None:
+            t0 = time.perf_counter()
+
+            def step(params, state, *inputs):
+                with jax.named_scope("nns.model"):
+                    state, out = fn(params, state, *inputs)
+                return state, (tuple(out) if isinstance(out, (list, tuple))
+                               else (out,))
+
+            jitted = jax.jit(step, donate_argnums=(1,))
+            try:
+                with _profile.span(owner, "trace_lower", setup=True):
+                    lowered = jitted.lower(p_avals, s_avals, *avals)
+            except Exception as e:
+                raise FilterError(
+                    f"jax-xla: stateful model {model.name} entry {entry} "
+                    f"rejects input {spec}: {e}") from e
+            out_avals = jax.tree_util.tree_leaves(lowered.out_info[1])
+            skey = COMPILE_STATS.record(
+                self._compile_kind or "cold", time.perf_counter() - t0)
+            self._capture_cost(model, lowered, 0, avals)
+            prog = {"call": _timed_first_call(
+                jitted, skey, owner,
+                lambda: jitted.lower(p_avals, s_avals, *avals)),
+                "out_spec": TensorsSpec.from_shapes(
+                    [o.shape for o in out_avals],
+                    [np.dtype(o.dtype) for o in out_avals])}
+            with _stateful_lock:
+                prog = _stateful_programs.setdefault(key, prog)
+        call = prog["call"]
+        if entry in model.setup_entries:
+            # an entry that builds state ahead of the stream: its span
+            # covers the device's work, so the call is fenced
+
+            def run(*inputs):
+                with _profile.span(owner, entry, setup=True):
+                    out = cell.step(call, inputs)
+                    jax.block_until_ready(out)
+                return out
+        else:
+            def run(*inputs):
+                return cell.step(call, inputs)
+
+        run.lower = call.lower
+        done = cell.compiled[entry] = _Compiled(run, spec, prog["out_spec"])
+        return done
+
+    def _refuse_swap_of_state(self) -> None:
+        if self._cell is not None:
+            raise FilterError(
+                f"jax-xla: model {self._model.name} is stateful: a hot "
+                "swap or RELOAD would have to say what becomes of the "
+                "state its steps built (kept under new weights, or "
+                "rebuilt), and nothing does yet; stop the pipeline and "
+                "start it on the new model")
+
+    def fetch_counters(self) -> None:
+        """Add what the state's counters gained to ``STATE_STATS``.  A
+        fence: the owning element calls it at its stats-sample cadence,
+        never per step.  Nothing for a stateless model."""
+        cell = self._cell
+        if cell is not None:
+            cell.fetch_counters()
+
     # -- model info ----------------------------------------------------------
 
     def get_model_info(self) -> Tuple[TensorsSpec, TensorsSpec]:
@@ -875,6 +1163,13 @@ class JaxXlaFilter(FilterSubplugin):
         actual reshape is rejected when other sharers still depend on
         the current schema (one pipeline must not recompile the model
         under another's feet)."""
+        if self._cell is not None:
+            # a stateful model is not reshaped: the schema picks one of
+            # its entry points
+            c = self._compile_stateful(in_spec)
+            with self._swap_lock:
+                self._compiled = c
+            return c.in_spec, c.out_spec
         if self._shared_refs > 0:
             with self._swap_lock:
                 c = self._compiled
@@ -1187,6 +1482,11 @@ class JaxXlaFilter(FilterSubplugin):
             model = self._model
         if c is None:
             raise FilterError("jax-xla: not configured")
+        if self._cell is not None:
+            raise FilterError(
+                f"jax-xla: stateful model {model.name} cannot be "
+                "micro-batched (batch>1 stacks frames of a stateless "
+                "program; its entry points take whole windows)")
         n = len(frames)
         if n == 0:
             return []
@@ -1312,6 +1612,7 @@ class JaxXlaFilter(FilterSubplugin):
         a serving pool."""
         if self.props is None:
             raise FilterError("jax-xla: not configured (nothing to swap)")
+        self._refuse_swap_of_state()
         import dataclasses as _dc
 
         cur = self._compiled
@@ -1390,6 +1691,7 @@ class JaxXlaFilter(FilterSubplugin):
     def handle_event(self, event: Event) -> None:
         if event.kind != EventKind.RELOAD_MODEL:
             return
+        self._refuse_swap_of_state()
         if self.props is None or not self.props.is_updatable:
             raise FilterError("jax-xla: model is not updatable")
         # double-buffered reload: the replacement (single-frame AND the

@@ -1,0 +1,688 @@
+"""DeepSeek-V2 as one chip's share of a layer divided over several chips.
+
+Written from the model's public ``config.json`` (``model_type``
+``deepseek_v2``): pre-norm residual layers, RMSNorm, no biases,
+multi-head latent attention (MLA) with a YaRN-scaled rotary part, a
+dense MLP in the leading layer(s) and, after them, ``n_routed_experts``
+routed experts chosen by ``group_limited_greedy`` beside shared experts.
+
+**The share.**  The model is told what it holds
+(:class:`DeepSeekV2Config`): heads ``[head0, head0 + heads)``, routed
+experts ``[expert0, expert0 + experts)`` (whole groups of the router, so
+that a chip is a device of the paper's device-limited routing) and
+vocabulary rows ``[vocab0, vocab0 + vocab)``.  It routes over ALL the
+published experts, computes the held experts' part for the tokens routed to them (no token is
+dropped and there is no capacity factor: tokens are sorted by expert and
+the product runs over blocks of one expert's rows each), adds what every
+chip computes alike (the shared experts, the dense MLP) and passes that
+partial sum on; attention's output is the held heads' partial sum through
+their rows of ``W_o``; logits are over the slice.  Nothing stands in for
+the absent chips or their exchange.
+
+**Two paths through one set of weights.**  The state is the latent cache
+alone: per layer ``[streams, positions, row]``, a row a token's ``(c_kv,
+k_r)`` after norm and rotation, 576 values, padded with zeros to whole
+lanes (640: the TPU's compiler lays a ``[.., positions, 576]`` array out
+with positions minor, and every product over it then copied the whole
+cache), over positions rounded up to whole lanes.  :func:`prefill` runs a
+chunk of ONE stream through the expanded form of MLA (keys and values rebuilt
+from the latent rows, blocked over the cache with a running softmax, so
+no ``[heads, chunk, positions]`` score tensor exists) and writes the
+chunk's rows; :func:`decode` runs one token of EVERY stream through the
+absorbed form (``q~ = q_nope W_kvb[k]^T``, scores and values straight on
+the latent rows: ``ops/kernels.py`` ``latent_decode_attention``, one pass
+over the cache).  Positions come with the frame; rows beyond a
+stream's position are masked, so a stale or padded row is never read.
+
+Stage scopes (``Documentation/observability.md``): ``embed``,
+``layerNN/attn`` (``.../attn/cache_write`` inside it), ``layerNN/mlp``
+(dense layers), ``layerNN/moe/router|dispatch|experts|combine|shared``,
+``head``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Dict, Tuple
+
+import numpy as np
+
+try:
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+except ImportError:  # pragma: no cover
+    jax = jnp = lax = None
+
+from ..ops.kernels import latent_decode_attention
+
+Params = dict
+NEG = -1e30
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepSeekV2Config:
+    """The published sizes, and beside them what is HELD here.  Every
+    width is the source's; ``layers``, ``heads``, ``experts`` and
+    ``vocab`` (with their offsets) are the share."""
+
+    hidden_size: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    n_routed_experts: int          # the router's width (published)
+    n_shared_experts: int
+    n_group: int
+    topk_group: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    first_k_dense_replace: int
+    rms_norm_eps: float
+    rope_theta: float
+    rope_factor: float
+    rope_original_max: int
+    rope_beta_fast: float
+    rope_beta_slow: float
+    rope_mscale: float
+    rope_mscale_all_dim: float
+    layers: int                    # held: the leading layers of the model
+    heads: int
+    head0: int
+    experts: int
+    expert0: int
+    vocab: int
+    vocab0: int
+
+    @classmethod
+    def from_dict(cls, cfg: dict) -> "DeepSeekV2Config":
+        """From a ``config.json`` as published, or from a chip's share
+        of one: then ``num_hidden_layers``, ``num_attention_heads``,
+        ``n_routed_experts`` and ``vocab_size`` count what is held,
+        ``published`` gives the source's values (the router's width is
+        ``published.n_routed_experts``) and ``share`` the offsets
+        ``head0`` / ``expert0`` / ``vocab0`` (0 where absent)."""
+        published = cfg.get("published", {})
+        share = cfg.get("share", {})
+        rope = cfg["rope_scaling"]
+        if cfg.get("topk_method", "group_limited_greedy") \
+                != "group_limited_greedy" \
+                or cfg.get("scoring_func", "softmax") != "softmax":
+            raise ValueError("deepseek_v2: only group_limited_greedy "
+                             "routing over softmax scores is written")
+        out = cls(
+            hidden_size=int(cfg["hidden_size"]),
+            intermediate_size=int(cfg["intermediate_size"]),
+            moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+            q_lora_rank=int(cfg["q_lora_rank"]),
+            kv_lora_rank=int(cfg["kv_lora_rank"]),
+            qk_nope_head_dim=int(cfg["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(cfg["qk_rope_head_dim"]),
+            v_head_dim=int(cfg["v_head_dim"]),
+            n_routed_experts=int(published.get("n_routed_experts",
+                                               cfg["n_routed_experts"])),
+            n_shared_experts=int(cfg["n_shared_experts"]),
+            n_group=int(cfg["n_group"]),
+            topk_group=int(cfg["topk_group"]),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            routed_scaling_factor=float(cfg["routed_scaling_factor"]),
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", False)),
+            first_k_dense_replace=int(cfg["first_k_dense_replace"]),
+            rms_norm_eps=float(cfg["rms_norm_eps"]),
+            rope_theta=float(cfg["rope_theta"]),
+            rope_factor=float(rope["factor"]),
+            rope_original_max=int(rope["original_max_position_embeddings"]),
+            rope_beta_fast=float(rope["beta_fast"]),
+            rope_beta_slow=float(rope["beta_slow"]),
+            rope_mscale=float(rope["mscale"]),
+            rope_mscale_all_dim=float(rope["mscale_all_dim"]),
+            layers=int(cfg["num_hidden_layers"]),
+            heads=int(cfg["num_attention_heads"]),
+            head0=int(share.get("head0", 0)),
+            experts=int(cfg["n_routed_experts"]),
+            expert0=int(share.get("expert0", 0)),
+            vocab=int(cfg["vocab_size"]),
+            vocab0=int(share.get("vocab0", 0)))
+        if out.norm_topk_prob:
+            raise ValueError("deepseek_v2: norm_topk_prob is not written")
+        per_group = out.n_routed_experts // out.n_group
+        if out.n_routed_experts % out.n_group or out.experts % per_group \
+                or out.expert0 % per_group:
+            raise ValueError(
+                "deepseek_v2: the held experts must be whole groups of "
+                f"{per_group} (expert0 {out.expert0}, held {out.experts})")
+        return out
+
+    @property
+    def latent(self) -> int:
+        """Values a token keeps in a layer's cache: ``c_kv`` and ``k_r``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def row(self) -> int:
+        """Width of a cache row: ``latent`` padded to whole lanes."""
+        return -(-self.latent // 128) * 128
+
+    @property
+    def q_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.first_k_dense_replace
+
+
+# -- YaRN rotary embedding ----------------------------------------------------
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(cfg: DeepSeekV2Config) -> Tuple[int, int]:
+    """The rope dimensions between which the frequencies are blended."""
+    dim = cfg.qk_rope_head_dim
+
+    def correction(rotations: float) -> float:
+        return dim * math.log(cfg.rope_original_max
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(correction(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction(cfg.rope_beta_slow)), dim - 1)
+    return low, high
+
+
+def yarn_inv_freq(cfg: DeepSeekV2Config) -> np.ndarray:
+    """``[rope/2]`` inverse frequencies: ``1/theta^(2i/d)`` where the
+    wavelength is short, that divided by ``factor`` where it is long,
+    blended linearly between the two correction dimensions."""
+    dim = cfg.qk_rope_head_dim
+    extra = 1.0 / cfg.rope_theta ** (np.arange(0, dim, 2,
+                                               dtype=np.float64) / dim)
+    inter = extra / cfg.rope_factor
+    low, high = yarn_correction_range(cfg)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp                      # 1: not interpolated
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def attn_scale(cfg: DeepSeekV2Config) -> float:
+    """``s`` of the scores: ``q_head_dim^-1/2 * mscale(factor,
+    mscale_all_dim)^2``."""
+    m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return cfg.q_head_dim ** -0.5 * m * m
+
+
+def rope_scale(cfg: DeepSeekV2Config) -> float:
+    """What cos and sin are scaled by: ``mscale / mscale_all_dim`` form."""
+    return yarn_mscale(cfg.rope_factor, cfg.rope_mscale) \
+        / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+
+
+def _cos_sin(cfg: DeepSeekV2Config, positions):
+    angle = positions.astype(jnp.float32)[..., None] \
+        * jnp.asarray(yarn_inv_freq(cfg))
+    scale = rope_scale(cfg)
+    return jnp.cos(angle) * scale, jnp.sin(angle) * scale
+
+
+def _rope(x, cos, sin):
+    """Rotate pairs ``(i, i + d/2)`` of the last axis (the half-split
+    layout the published code permutes into before it rotates); ``cos``
+    and ``sin`` broadcast against ``x[..., :d/2]``."""
+    half = x.shape[-1] // 2
+    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+# -- small parts --------------------------------------------------------------
+
+
+def _rms(x, gain, eps):
+    x32 = x.astype(jnp.float32)
+    out = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (out * gain).astype(x.dtype)
+
+
+def _mm(x, w):
+    """``x @ w`` in the weights' type with float32 accumulation."""
+    return jnp.matmul(x, w, preferred_element_type=jnp.float32,
+                      precision=_precision(w))
+
+
+def _precision(w):
+    return lax.Precision.HIGHEST if w.dtype == jnp.float32 else None
+
+
+def _mlp(p, x):
+    h = jax.nn.silu(_mm(x, p["gate"])) * _mm(x, p["up"])
+    return _mm(h.astype(x.dtype), p["down"]).astype(x.dtype)
+
+
+# -- routing ------------------------------------------------------------------
+
+
+def route(cfg: DeepSeekV2Config, x, router):
+    """``group_limited_greedy`` over ALL the published experts, in
+    float32: ``(idx [N, k] int32, weight [N, k] float32)``."""
+    logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    p = jax.nn.softmax(logits, axis=-1)
+    n = p.shape[0]
+    group = p.reshape(n, cfg.n_group, -1).max(axis=-1)
+    _, best = lax.top_k(group, cfg.topk_group)
+    kept = jnp.zeros((n, cfg.n_group), bool).at[
+        jnp.arange(n)[:, None], best].set(True)
+    masked = jnp.where(jnp.repeat(kept, cfg.n_routed_experts // cfg.n_group,
+                                  axis=1), p, 0.0)
+    weight, idx = lax.top_k(masked, cfg.num_experts_per_tok)
+    return idx.astype(jnp.int32), weight * cfg.routed_scaling_factor
+
+
+def _block_rows(n_tokens: int) -> int:
+    """Rows of one block of the grouped product: a block holds rows of
+    ONE expert, so a larger block reads that expert's weights for more
+    rows, and a smaller one pads less."""
+    return int(min(256, -(-n_tokens // 8) * 8))
+
+
+def dispatch(cfg: DeepSeekV2Config, idx, n_tokens: int):
+    """Sort the (token, expert) pairs that fall on a HELD expert by
+    expert and lay each expert's rows out in whole blocks.  Returns the
+    plan of the grouped product: per padded row the token it holds
+    (``n_tokens`` = none), per pair the row its result lands in (the
+    last row = none: an expert held elsewhere), per block its expert,
+    the number of blocks in use and the tokens each held expert got."""
+    k, held = cfg.num_experts_per_tok, cfg.experts
+    blk = _block_rows(n_tokens)
+    pairs = n_tokens * k
+    rows = -(-pairs // blk) * blk + held * blk
+    local = idx.reshape(-1) - cfg.expert0
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(local, stable=True)
+    sorted_e = local[order]
+    counts = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
+    padded = (counts[:held] + blk - 1) // blk * blk
+    pad_end = jnp.cumsum(padded)
+    first = jnp.cumsum(counts) - counts           # of each expert, sorted
+    rank = jnp.arange(pairs, dtype=jnp.int32) - first[sorted_e]
+    here = sorted_e < held
+    dest_sorted = jnp.where(
+        here, (pad_end - padded)[jnp.minimum(sorted_e, held - 1)] + rank,
+        rows)
+    row_token = jnp.full((rows,), n_tokens, jnp.int32).at[dest_sorted].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    dest = jnp.zeros((pairs,), jnp.int32).at[order].set(dest_sorted)
+    block_expert = jnp.minimum(jnp.searchsorted(
+        pad_end, jnp.arange(rows // blk, dtype=jnp.int32) * blk,
+        side="right"), held - 1).astype(jnp.int32)
+    return {"row_token": row_token, "dest": dest.reshape(n_tokens, k),
+            "block_expert": block_expert, "blocks": pad_end[-1] // blk,
+            "counts": counts[:held], "blk": blk, "rows": rows}
+
+
+def grouped_experts(p, x, plan):
+    """The held experts' MLPs on the rows ``plan`` lays out, a block (of
+    one expert's rows) at a time: ``[rows + 1, hidden]``, the last row
+    zero.  Only the blocks in use are computed, so the work follows the
+    tokens routed here, not the held experts."""
+    blk, rows = plan["blk"], plan["rows"]
+    x_pad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+
+    def body(b, out):
+        e = plan["block_expert"][b]
+        tok = lax.dynamic_slice(plan["row_token"], (b * blk,), (blk,))
+        xb = x_pad[tok]
+        h = jax.nn.silu(_mm(xb, p["gate"][e])) * _mm(xb, p["up"][e])
+        ob = _mm(h.astype(x.dtype), p["down"][e]).astype(x.dtype)
+        return lax.dynamic_update_slice(out, ob, (b * blk, 0))
+
+    return lax.fori_loop(0, plan["blocks"], body,
+                         jnp.zeros((rows + 1, x.shape[1]), x.dtype))
+
+
+def moe_parts(cfg: DeepSeekV2Config, p, x):
+    """``(routed, shared, counts)``: the held experts' weighted part for
+    the tokens routed to them (float32), what every chip computes alike,
+    and how many tokens each held expert got."""
+    n = x.shape[0]
+    with jax.named_scope("router"):
+        idx, weight = route(cfg, x, p["router"])
+    with jax.named_scope("dispatch"):
+        plan = dispatch(cfg, idx, n)
+    with jax.named_scope("experts"):
+        out = grouped_experts(p["experts"], x, plan)
+    with jax.named_scope("combine"):
+        routed = jnp.sum(out[plan["dest"]].astype(jnp.float32)
+                         * weight[..., None], axis=1)
+    with jax.named_scope("shared"):
+        shared = _mlp(p["shared"], x)
+    return routed, shared, plan["counts"]
+
+
+# -- attention ----------------------------------------------------------------
+
+
+def _queries(cfg: DeepSeekV2Config, p, x, cos, sin):
+    """``(q_nope [N, heads, nope], q_rope [N, heads, rope])`` rotated."""
+    c_q = _rms(_mm(x, p["q_a"]).astype(x.dtype), p["q_a_norm"],
+               cfg.rms_norm_eps)
+    q = _mm(c_q, p["q_b"]).astype(x.dtype).reshape(
+        x.shape[0], cfg.heads, cfg.q_head_dim)
+    q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+    return q_nope, _rope(q_rope, cos[:, None], sin[:, None])
+
+
+def _latent_rows(cfg: DeepSeekV2Config, p, x, cos, sin, dtype):
+    """What the cache keeps of each token: ``[c_kv | k_r | 0..]``,
+    normed and rotated, ``cfg.row`` wide."""
+    kv = _mm(x, p["kv_a"]).astype(x.dtype)
+    c_kv = _rms(kv[:, :cfg.kv_lora_rank], p["kv_a_norm"], cfg.rms_norm_eps)
+    k_r = _rope(kv[:, cfg.kv_lora_rank:], cos, sin)
+    pad = jnp.zeros((x.shape[0], cfg.row - cfg.latent), x.dtype)
+    return jnp.concatenate([c_kv, k_r, pad], axis=-1).astype(dtype)
+
+
+def _kv_b(cfg: DeepSeekV2Config, p):
+    """``W_kvb`` as ``[latent rank, heads, nope + v]``."""
+    return p["kv_b"].reshape(cfg.kv_lora_rank, cfg.heads,
+                             cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+
+def attn_prefill(cfg: DeepSeekV2Config, p, x, cache, slot, start,
+                 key_block: int = 1024):
+    """Expanded MLA over a chunk ``x [C, hidden]`` of stream ``slot``
+    whose first token is at ``start``: writes the chunk's latent rows,
+    then attends to rows ``[0, start + C)`` block by block.  Returns the
+    held heads' partial output and the cache."""
+    c = x.shape[0]
+    positions = start + jnp.arange(c, dtype=jnp.int32)
+    cos, sin = _cos_sin(cfg, positions)
+    q_nope, q_rope = _queries(cfg, p, x, cos, sin)
+    with jax.named_scope("cache_write"):
+        rows = _latent_rows(cfg, p, x, cos, sin, cache.dtype)
+        cache = lax.dynamic_update_slice(cache, rows[None], (slot, start, 0))
+    # a chunk starts at a multiple of its own length (the caller's
+    # contract), so whole key blocks never reach beyond start + C
+    kb = math.gcd(int(key_block), c)
+    w_kvb, scale = _kv_b(cfg, p), attn_scale(cfg)
+    hp = _precision(p["kv_b"])
+
+    def body(j, carry):
+        m, l, acc = carry
+        blk = lax.dynamic_slice(cache, (slot, j * kb, 0),
+                                (1, kb, cfg.row))[0].astype(x.dtype)
+        blk_r = blk[:, cfg.kv_lora_rank:cfg.latent]
+        blk = blk[:, :cfg.kv_lora_rank]
+        kv = jnp.einsum("kr,rhd->khd", blk, w_kvb,
+                        preferred_element_type=jnp.float32,
+                        precision=hp).astype(x.dtype)
+        k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
+        s = jnp.einsum("chd,khd->hck", q_nope, k_nope,
+                       preferred_element_type=jnp.float32, precision=hp) \
+            + jnp.einsum("chd,kd->hck", q_rope, blk_r,
+                         preferred_element_type=jnp.float32, precision=hp)
+        key_pos = j * kb + jnp.arange(kb, dtype=jnp.int32)
+        s = jnp.where(key_pos[None, None, :] <= positions[None, :, None],
+                      s * scale, NEG)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        prob = jnp.exp(s - m_new[..., None])
+        l = l * alpha + prob.sum(axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hck,khd->hcd", prob.astype(x.dtype), v,
+            preferred_element_type=jnp.float32, precision=hp)
+        return m_new, l, acc
+
+    blocks = (start + c + kb - 1) // kb
+    m0 = jnp.full((cfg.heads, c), NEG, jnp.float32)
+    _, l, acc = lax.fori_loop(
+        0, blocks, body,
+        (m0, jnp.zeros_like(m0),
+         jnp.zeros((cfg.heads, c, cfg.v_head_dim), jnp.float32)))
+    o = (acc / l[..., None]).astype(x.dtype)
+    o = o.transpose(1, 0, 2).reshape(c, cfg.heads * cfg.v_head_dim)
+    return _mm(o, p["o"]).astype(x.dtype), cache
+
+
+def attn_decode(cfg: DeepSeekV2Config, p, x, cache, positions):
+    """Absorbed MLA for one token of every stream: ``x [B, hidden]``,
+    stream ``b`` at ``positions[b]``.  Writes each stream's row, then
+    scores and values straight on the latent rows up to its position."""
+    b = x.shape[0]
+    cos, sin = _cos_sin(cfg, positions)
+    q_nope, q_rope = _queries(cfg, p, x, cos, sin)
+    with jax.named_scope("cache_write"):
+        rows = _latent_rows(cfg, p, x, cos, sin, cache.dtype)
+        cache = cache.at[jnp.arange(b), positions].set(rows)
+    w_kvb = _kv_b(cfg, p)
+    hp = _precision(p["kv_b"])
+    q_abs = jnp.einsum("bhd,rhd->bhr", q_nope,
+                       w_kvb[..., :cfg.qk_nope_head_dim],
+                       preferred_element_type=jnp.float32,
+                       precision=hp).astype(x.dtype)
+    q_cat = jnp.concatenate([q_abs, q_rope, jnp.zeros(
+        (b, cfg.heads, cfg.row - cfg.latent), x.dtype)], axis=-1)
+    o_lat = latent_decode_attention(q_cat, cache, positions,
+                                    cfg.kv_lora_rank, attn_scale(cfg))
+    o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(x.dtype),
+                   w_kvb[..., cfg.qk_nope_head_dim:],
+                   preferred_element_type=jnp.float32,
+                   precision=hp).astype(x.dtype)
+    return _mm(o.reshape(b, -1), p["o"]).astype(x.dtype), cache
+
+
+# -- the model ----------------------------------------------------------------
+
+
+def _layers(cfg: DeepSeekV2Config, params, x, caches, attend):
+    """Every held layer on ``x [N, hidden]``; ``attend(layer params,
+    normed x, cache) -> (partial output, cache)``.  Returns the stream,
+    the caches and the tokens each held expert of each expert layer
+    got (``[expert layers, held]``)."""
+    caches, counts = list(caches), []
+    for i, layer in enumerate(params["layers"]):
+        with jax.named_scope(f"layer{i:02d}"):
+            # a branch's scope holds its norm and its residual add, so
+            # that the fusions XLA roots there are booked to the branch
+            with jax.named_scope("attn"):
+                a, caches[i] = attend(
+                    layer["attn"], _rms(x, layer["attn_norm"],
+                                        cfg.rms_norm_eps), caches[i])
+                x = x + a
+            if cfg.is_dense(i):
+                with jax.named_scope("mlp"):
+                    x = x + _mlp(layer["mlp"], _rms(
+                        x, layer["mlp_norm"], cfg.rms_norm_eps))
+            else:
+                with jax.named_scope("moe"):
+                    routed, shared, got = moe_parts(
+                        cfg, layer["moe"],
+                        _rms(x, layer["mlp_norm"], cfg.rms_norm_eps))
+                    x = x + (routed + shared.astype(jnp.float32)
+                             ).astype(x.dtype)
+                counts.append(got)
+    return x, caches, jnp.stack(counts) if counts else \
+        jnp.zeros((0, cfg.experts), jnp.int32)
+
+
+def _embed(cfg: DeepSeekV2Config, params, ids):
+    with jax.named_scope("embed"):
+        return params["embed"][ids - cfg.vocab0]
+
+
+def _head(cfg: DeepSeekV2Config, params, x):
+    """Logits over the held slice of the vocabulary, float32, and the
+    greedy id (global) beside them."""
+    with jax.named_scope("head"):
+        logits = _mm(_rms(x, params["final_norm"], cfg.rms_norm_eps),
+                     params["head"])
+        return logits, (jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        + cfg.vocab0)
+
+
+def init_state(cfg: DeepSeekV2Config, params, streams: int, positions: int,
+               dtype=None) -> dict:
+    """The state a filter owns between invokes: the latent cache of the
+    held layers, and the counters the steps add to (``uint32``: the
+    reader takes differences, so a wrap costs nothing)."""
+    dtype = dtype or params["embed"].dtype
+    # whole lanes of positions (a block of the decode kernel keeps its
+    # scores' positions on the lane axis); rows never written are masked
+    positions = -(-int(positions) // 128) * 128
+    # one buffer a leaf: the state is donated leaf by leaf
+    return {"cache": [jnp.zeros((streams, positions, cfg.row), dtype)
+                      for _ in range(cfg.layers)],
+            "counters": {name: jnp.zeros((), jnp.uint32) for name in (
+                "steps", "cache_rows_read", "experts_touched",
+                "expert_hits")}}
+
+
+def counters(state: dict) -> dict:
+    return state["counters"]
+
+
+def counter_units(cfg: DeepSeekV2Config, state: dict) -> dict:
+    """What one count of each counter stands for.  ``cache_rows_read``
+    counts latent rows of ONE layer; a row is read in every layer."""
+    row = cfg.latent * state["cache"][0].dtype.itemsize
+    return {"cache_bytes_read": ("cache_rows_read", row * cfg.layers)}
+
+
+def prefill(cfg: DeepSeekV2Config, params, state, ids, slot, start):
+    """A chunk of ONE stream: ``ids [C]``, ``slot [1]``, ``start [1]``
+    (all int32).  Writes rows ``[start, start + C)`` of the stream's
+    cache; serves the logits and greedy id after the chunk's last
+    token.  A chunk padded beyond its prompt writes rows that every
+    later step masks or overwrites."""
+    slot, start = slot[0], start[0]
+    x = _embed(cfg, params, ids)
+    x, caches, _ = _layers(
+        cfg, params, x, state["cache"],
+        lambda p, h, cache: attn_prefill(cfg, p, h, cache, slot, start))
+    logits, greedy = _head(cfg, params, x[-1:])
+    return {"cache": caches, "counters": state["counters"]}, \
+        (logits, greedy)
+
+
+def decode(cfg: DeepSeekV2Config, params, state, ids, positions):
+    """One token of EVERY stream: ``ids [B]``, ``positions [B]`` int32.
+    Serves ``logits [B, vocab held]`` float32 and the greedy ids."""
+    x = _embed(cfg, params, ids)
+    x, caches, got = _layers(
+        cfg, params, x, state["cache"],
+        lambda p, h, cache: attn_decode(cfg, p, h, cache, positions))
+    logits, greedy = _head(cfg, params, x)
+    old = state["counters"]
+    new = {"steps": old["steps"] + jnp.uint32(1),
+           "cache_rows_read": old["cache_rows_read"]
+           + jnp.sum(positions + 1).astype(jnp.uint32),
+           "experts_touched": old["experts_touched"]
+           + jnp.sum(got > 0).astype(jnp.uint32),
+           "expert_hits": old["expert_hits"]
+           + jnp.sum(got).astype(jnp.uint32)}
+    return {"cache": caches, "counters": new}, (logits, greedy)
+
+
+# -- weights of the right shapes, and registration ----------------------------
+
+
+def param_shapes(cfg: DeepSeekV2Config) -> dict:
+    """The pytree of ``(shape, role)`` a weights maker fills: matrices
+    carry the role their init gain is looked up by, vectors ``norm``."""
+    h, qr, kr = cfg.hidden_size, cfg.q_lora_rank, cfg.kv_lora_rank
+    nh, f = cfg.heads, cfg.moe_intermediate_size
+    attn = {"q_a": ((h, qr), "q_a"), "q_a_norm": ((qr,), "norm"),
+            "q_b": ((qr, nh * cfg.q_head_dim), "q_b"),
+            "kv_a": ((h, cfg.latent), "kv_a"),
+            "kv_a_norm": ((kr,), "norm"),
+            "kv_b": ((kr, nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                     "kv_b"),
+            "o": ((nh * cfg.v_head_dim, h), "o")}
+
+    def mlp(width, down="down"):
+        return {"gate": ((h, width), "gate"), "up": ((h, width), "up"),
+                "down": ((width, h), down)}
+
+    layers = []
+    for i in range(cfg.layers):
+        layer = {"attn_norm": ((h,), "norm"), "attn": dict(attn),
+                 "mlp_norm": ((h,), "norm")}
+        if cfg.is_dense(i):
+            layer["mlp"] = mlp(cfg.intermediate_size)
+        else:
+            e = cfg.experts
+            layer["moe"] = {
+                "router": ((h, cfg.n_routed_experts), "router"),
+                "experts": {"gate": ((e, h, f), "gate"),
+                            "up": ((e, h, f), "up"),
+                            "down": ((e, f, h), "expert_down")},
+                "shared": mlp(f * cfg.n_shared_experts)}
+        layers.append(layer)
+    return {"embed": ((cfg.vocab, h), "embed"), "layers": layers,
+            "final_norm": ((h,), "norm"), "head": ((h, cfg.vocab), "head")}
+
+
+def init_params(cfg: DeepSeekV2Config, key, dtype=None) -> Params:
+    """Seeded weights of the right shapes: matrices N(0, 1/fan_in)
+    (residual branches halved), norm gains 1.  For tests and examples;
+    a deployment loads its own."""
+    dtype = dtype or jnp.bfloat16
+    if isinstance(key, int):
+        key = jax.random.PRNGKey(key)
+    leaves, treedef = jax.tree_util.tree_flatten(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)
+        and isinstance(x[1], str))
+    out = []
+    for n, (shape, role) in enumerate(leaves):
+        if role == "norm":
+            out.append(jnp.ones(shape, jnp.float32))
+            continue
+        fan_in = 1 if role == "embed" else shape[-2]
+        gain = 0.5 if role in ("o", "down", "expert_down") else 1.0
+        out.append((jax.random.normal(jax.random.fold_in(key, n), shape)
+                    * (gain / fan_in) ** 0.5).astype(dtype))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@functools.lru_cache(maxsize=8)
+def entries(cfg: DeepSeekV2Config, streams: int, positions: int,
+            chunk: int) -> Dict[str, Any]:
+    """What :func:`register` hands ``register_stateful_model``: the two
+    entry points with their input schemas, and ``init_state``.  Cached
+    by the sizes, so that two sets of weights of one configuration share
+    their programs."""
+    i32 = np.int32
+    return {
+        "entries": {
+            "decode": (functools.partial(decode, cfg),
+                       [(streams,), (streams,)], i32),
+            "prefill": (functools.partial(prefill, cfg),
+                        [(chunk,), (1,), (1,)], i32)},
+        "setup_entries": ("prefill",),
+        "init_state": functools.partial(init_state, cfg, streams=streams,
+                                        positions=positions),
+        "counters": counters,
+        "counter_units": functools.partial(counter_units, cfg)}
+
+
+def register(name: str, cfg: DeepSeekV2Config, params: Params, streams: int,
+             positions: int, chunk: int) -> str:
+    """Register ``params`` as the stateful model ``name`` for
+    ``tensor_filter framework=jax-xla model=<name>``: a filter whose
+    negotiated input is ``(ids[chunk], slot[1], start[1])`` prefills,
+    one whose input is ``(ids[streams], positions[streams])`` decodes;
+    two filters with one ``shared-tensor-filter-key`` work on one
+    cache."""
+    from ..filters.jax_xla import register_stateful_model
+
+    return register_stateful_model(
+        name, params=params, **entries(cfg, streams, positions, chunk))

@@ -3,16 +3,21 @@
 Where the reference hand-vectorizes with Orc SIMD kernels
 (/root/reference/gst/nnstreamer/elements/nnstreamer-orc.orc), this
 package holds hand-written TPU kernels for the ops worth owning below
-XLA: the streaming normalize/typecast prologue and the flash-attention
-block kernel behind long-context attention.  Every kernel has a jnp
-reference implementation; callers fall back automatically when shapes
-don't tile or Pallas is unavailable.
+XLA: the streaming normalize/typecast prologue, the flash-attention
+block kernel behind long-context attention, and the one-pass decode
+attention over a latent cache.  Every kernel has a jnp
+reference implementation; the first two say through their
+``*_available`` rule when a caller should use it instead, the third
+refuses a shape it cannot take.
 """
 
 from .kernels import (
     flash_attention,
     flash_attention_available,
     flash_attention_reference,
+    latent_decode_attention,
+    latent_decode_attention_refusal,
+    latent_decode_attention_reference,
     scale_bias_cast,
     scale_bias_cast_available,
 )
@@ -21,4 +26,6 @@ __all__ = [
     "scale_bias_cast", "scale_bias_cast_available",
     "flash_attention", "flash_attention_available",
     "flash_attention_reference",
+    "latent_decode_attention", "latent_decode_attention_refusal",
+    "latent_decode_attention_reference",
 ]

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels: fused normalize/typecast + flash attention.
+"""Pallas TPU kernels: fused normalize/typecast, flash attention and
+latent decode attention.
 
 Parity/role:
 - ``scale_bias_cast`` is the tensor_transform arithmetic prologue
@@ -13,8 +14,12 @@ Parity/role:
   single-chip engine under long-context sequence parallelism
   (parallel/collectives.ring_attention rotates K/V blocks between chips
   with the same math).
+- ``latent_decode_attention`` is absorbed latent attention of one token
+  a stream over a latent cache (``models/deepseek_v2.py``'s decode
+  step): one pass over the cache, blocks beyond a stream's position
+  skipped.
 
-Both compile natively on TPU (Mosaic) and run under the Pallas
+All compile natively on TPU (Mosaic) and run under the Pallas
 interpreter on CPU backends (tests).  A shape that does not meet the
 tiling constraints — lane dim a multiple of 128, sublane dim a multiple
 of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32 of
@@ -238,3 +243,154 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
         interpret=_interpret(),
     )(qf, kf, vf)
     return out.reshape(*lead, S, D) if lead else out[0]
+
+
+# -- latent decode attention --------------------------------------------------
+
+
+def latent_block(positions: int, want: int = 1024) -> int:
+    """Positions of one cache block of :func:`latent_decode_attention`:
+    the largest divisor of ``positions`` up to ``want`` that is whole
+    lanes (a block's scores keep positions on the lane axis), or 0
+    where there is none."""
+    for rows in range(min(want, positions) // _LANE * _LANE, 0, -_LANE):
+        if positions % rows == 0:
+            return rows
+    return 0
+
+
+def latent_decode_attention_refusal(q_shape, cache_shape,
+                                    rank: int) -> Optional[str]:
+    """Why :func:`latent_decode_attention` cannot take these shapes, or
+    None: the row width whole lanes and the same on both sides, ``rank``
+    within it, and a block that divides the cache's positions."""
+    if len(q_shape) != 3 or len(cache_shape) != 3 \
+            or q_shape[0] != cache_shape[0]:
+        return f"q {tuple(q_shape)} and cache {tuple(cache_shape)} are " \
+               "not [B, heads, width] and [B, positions, width]"
+    width = q_shape[2]
+    if width != cache_shape[2] or width % _LANE or not 0 < rank <= width:
+        return f"row width {width} (cache {cache_shape[2]}) must be whole " \
+               f"lanes of {_LANE} and hold the {rank} values"
+    if not latent_block(cache_shape[1]):
+        return f"{cache_shape[1]} cache positions are not whole lanes " \
+               f"of {_LANE}"
+    return None
+
+
+def latent_decode_attention_reference(q, cache, positions, rank: int,
+                                      scale: float):
+    """The kernel's mathematics in jnp: every head's scores against
+    every cached row up to the stream's position, softmax in float32,
+    values the rows' first ``rank`` entries."""
+    import jax
+    import jax.numpy as jnp
+
+    hp = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
+    cache = cache.astype(q.dtype)
+    s = jnp.einsum("bhl,btl->bht", q, cache,
+                   preferred_element_type=jnp.float32, precision=hp)
+    t = jnp.arange(cache.shape[1], dtype=jnp.int32)
+    s = jnp.where(t[None, None, :] <= positions[:, None, None],
+                  s * scale, -1e30)
+    prob = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bht,btl->bhl", prob.astype(q.dtype), cache,
+                      preferred_element_type=jnp.float32,
+                      precision=hp)[..., :rank]
+
+
+def latent_decode_attention(q, cache, positions, rank: int, scale: float):
+    """Absorbed latent attention of one token a stream over a latent
+    cache: ``q [B, heads, width]`` (each head's absorbed query and its
+    rotary part side by side, zeros to the width), ``cache [B,
+    positions, width]`` (a token's ``rank`` latent values, its rotary
+    key, zeros to the width), ``positions [B]`` int32.  Returns ``[B,
+    heads, rank]`` float32: softmax(q cache^T * scale) over rows
+    ``0..positions[b]``, times the rows' first ``rank`` entries.
+
+    One pass over the cache: a block of rows is read once into VMEM and
+    serves both products (the scores contract over the row's whole
+    width, the values take its first ``rank`` lanes), with a running
+    max, normaliser and accumulator across blocks.  Blocks beyond a
+    stream's position are neither computed nor fetched: their index map
+    repeats the last block in use, and a repeated block is not copied
+    again.  (XLA's own two products read every position of the cache
+    twice, and one whose rows are not whole lanes it copies whole first;
+    ``PERF.md`` has the chip's readings.)  Heads are padded to whole
+    tiles here; a shape :func:`latent_decode_attention_refusal` names
+    is an error, there is no second path."""
+    import jax.numpy as jnp
+
+    refusal = latent_decode_attention_refusal(q.shape, cache.shape, rank)
+    if refusal:
+        raise ValueError(f"latent_decode_attention: {refusal}")
+    jax, pl, pltpu = _pl()
+    b, held, width = q.shape
+    # whole tiles of heads: padded heads score zero everywhere and are
+    # cut off again; values that are not whole lanes come out of the
+    # whole row
+    heads = -(-held // _sublane(q.dtype)) * _sublane(q.dtype)
+    if heads != held:
+        q = jnp.pad(q, ((0, 0), (0, heads - held), (0, 0)))
+    values = rank if rank % _LANE == 0 else width
+    rows = latent_block(cache.shape[1])
+    blocks = cache.shape[1] // rows
+
+    def kernel(pos_ref, q_ref, k_ref, o_ref, m_ref, l_ref, acc_ref):
+        j = pl.program_id(1)
+        pos = pos_ref[pl.program_id(0)]
+
+        @pl.when(j == 0)
+        def _init():
+            m_ref[:] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+            l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        @pl.when(j * rows <= pos)
+        def _block():
+            kb = k_ref[0]                                  # (rows, width)
+            s = jax.lax.dot_general(
+                q_ref[0], kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)        # (heads, rows)
+            at = j * rows + jax.lax.broadcasted_iota(
+                jnp.int32, (heads, rows), 1)
+            s = jnp.where(at <= pos, s * scale, -1e30)
+            # running max / normaliser replicated across a lane width,
+            # as in flash_attention above
+            m_prev = m_ref[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, :1])
+            l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[:] = acc_ref[:] * corr[:, :1] + jax.lax.dot_general(
+                p.astype(kb.dtype), kb[:, :values], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[:] = m_new
+
+        @pl.when(j == blocks - 1)
+        def _finish():
+            o_ref[0] = acc_ref[:] / l_ref[:, :1]
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(b, blocks),
+        in_specs=[
+            pl.BlockSpec((1, heads, width), lambda i, j, pos: (i, 0, 0)),
+            pl.BlockSpec((1, rows, width), lambda i, j, pos: (
+                i, jnp.minimum(j, pos[i] // rows), 0)),
+        ],
+        out_specs=pl.BlockSpec((1, heads, values),
+                               lambda i, j, pos: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((heads, _LANE), jnp.float32),     # running max
+            pltpu.VMEM((heads, _LANE), jnp.float32),     # normaliser
+            pltpu.VMEM((heads, values), jnp.float32),    # accumulator
+        ])
+    out = pl.pallas_call(
+        kernel, grid_spec=grid,
+        out_shape=jax.ShapeDtypeStruct((b, heads, values), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="latent_decode_attention",
+        interpret=_interpret(),
+    )(positions.astype(jnp.int32), q, cache.astype(q.dtype))
+    return out[:, :held, :rank]

@@ -338,3 +338,34 @@ class DispatchStats:
 
 #: process-wide dispatch accounting (bench gate: dispatches_per_frame)
 DISPATCH_STATS = DispatchStats()
+
+
+class StateStats:
+    """Process-wide totals of what stateful models count about their own
+    state (``filters/jax_xla.py`` ``_StateCell``): ``state_bytes`` (a
+    level: bytes of device state alive now) and the counters a model's
+    steps keep in its state, added up whenever the owning element's
+    stats sample reads them (``steps``, and for a model with a latent
+    cache and routed experts ``cache_bytes_read``, ``experts_touched``,
+    ``expert_hits``; ``Documentation/observability.md``)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._totals: dict = {}
+
+    def add(self, name: str, n: int) -> None:
+        with self._lock:
+            self._totals[name] = self._totals.get(name, 0) + int(n)
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._totals)
+
+    def reset(self) -> None:
+        """Tests only."""
+        with self._lock:
+            self._totals.clear()
+
+
+#: process-wide accounting of stateful models' state
+STATE_STATS = StateStats()

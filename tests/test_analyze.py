@@ -54,17 +54,6 @@ def above_info(diags):
 # -- crafted elements used to reach the rarer codes --------------------------
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _cleanup_test_factories():
-    yield
-    from nnstreamer_tpu.runtime import registry
-
-    with registry._lock:
-        for name in ("_t_anycaps", "_t_reject"):
-            registry._factories.pop(name, None)
-
-
-@register_element("_t_anycaps")
 class _AnyCapsElement(TransformElement):
     """Proposes wildcard caps: downstream fixation must fail (NNS202)."""
 
@@ -77,7 +66,6 @@ class _AnyCapsElement(TransformElement):
         return buf
 
 
-@register_element("_t_reject")
 class _RejectElement(TransformElement):
     """caps_negotiated always rejects (NNS204)."""
 
@@ -88,6 +76,23 @@ class _RejectElement(TransformElement):
 
     def transform(self, buf):
         return buf
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _test_factories():
+    """The two crafted elements exist only while this module's tests
+    run: registered when the module is merely imported (every xdist
+    worker imports it while collecting), they stayed in the registry of
+    the workers that never ran this fixture's clean-up, and
+    ``tests/test_docs.py`` then found two factories without a page."""
+    from nnstreamer_tpu.runtime import registry
+
+    register_element("_t_anycaps")(_AnyCapsElement)
+    register_element("_t_reject")(_RejectElement)
+    yield
+    with registry._lock:
+        for name in ("_t_anycaps", "_t_reject"):
+            registry._factories.pop(name, None)
 
 
 # -- known-bad corpus: one pipeline per diagnostic code ----------------------

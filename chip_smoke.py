@@ -12,7 +12,7 @@ pipeline with the same function jitted directly on the same frames.
        bounding_boxes option7=device ! appsink — one fused program
     B  the serving path: four appsrc streams of host uint8 frames into
        one share-model pool (ModelPool / SharedBatcher), batch 8
-    C  the Pallas kernels: the ViT pipeline with flash attention, and a
+    C  the Pallas kernels: the ViT pipeline with its attention kernel, and a
        standalone tensor_transform backend=pallas — Mosaic, not the
        interpreter and not the jnp fallback
     D  the fence: block_until_ready, a host fetch and a tensor_sink's
@@ -438,14 +438,21 @@ def section_b(streams: int, frames_per_stream: int, batch: int, size: int,
 
 def section_c(batch: int, image: int, patch: int, dim: int, depth: int,
               heads: int, mlp: int, num_classes: int,
-              frame_shape: tuple, n_buffers: int = 2) -> dict:
+              frame_shape: tuple, n_buffers: int = 2,
+              short: tuple = (196, 12, 64)) -> dict:
+    """``short`` is the second attention shape proved: positions, heads
+    and head size of a ViT whose attention takes ``short_attention``
+    (ViT-B/16's own unless a test says otherwise)."""
     import jax
     import jax.numpy as jnp
 
     from nnstreamer_tpu.core import Buffer, TensorsSpec
     from nnstreamer_tpu.filters.jax_xla import get_model, unregister_model
-    from nnstreamer_tpu.models.vit import register_vit
-    from nnstreamer_tpu.ops import flash_attention, flash_attention_reference
+    from nnstreamer_tpu.models.vit import register_vit, vit_apply, vit_init
+    from nnstreamer_tpu.ops import (flash_attention,
+                                    flash_attention_reference,
+                                    short_attention,
+                                    short_attention_reference)
     from nnstreamer_tpu.ops.kernels import _interpret
     from nnstreamer_tpu.runtime import parse_launch
 
@@ -515,6 +522,38 @@ def section_c(batch: int, image: int, patch: int, dim: int, depth: int,
           f"C: flash_attention differs from the jnp reference by "
           f"{attn_diff:.4f}")
 
+    # the short-sequence kernel at the shape and dtype ViT-B/16 hands
+    # it, [B, 196, 2304] bf16: one call a layer in the model's program,
+    # and the kernel against its jnp reference
+    seq, n_heads, dh = short
+    side = int(round(seq ** 0.5))
+    check(side * side == seq, f"C: {seq} positions are no square of patches")
+    params = jax.eval_shape(lambda: vit_init(
+        jax.random.PRNGKey(0), image_size=side * patch, patch=patch,
+        dim=n_heads * dh, depth=depth, heads=n_heads, mlp_dim=mlp,
+        num_classes=num_classes))
+    calls, mosaic = kernel_evidence(
+        lambda p, x: vit_apply(p, x, heads=n_heads), params,
+        jax.ShapeDtypeStruct((batch, side * patch, side * patch, 3),
+                             np.float32))
+    check(calls == depth and mosaic == compiled_for_chip,
+          f"C: a ViT of {seq} positions and {n_heads} heads of {dh} holds "
+          f"{calls} pallas_call(s) for {depth} attention layers, "
+          f"mosaic={mosaic}")
+    qkv = jnp.asarray(rng.standard_normal((batch, seq, 3 * n_heads * dh)),
+                      jnp.bfloat16)
+
+    o = host(jax.jit(lambda x: short_attention(x, n_heads))(qkv))
+    o_ref = host(jax.jit(
+        lambda x: short_attention_reference(x, n_heads))(qkv))
+    check(np.isfinite(o).all(), "C: non-finite short_attention output")
+    short_diff = float(np.max(np.abs(o - o_ref)))
+    print(f"C: short_attention at {qkv.shape} {qkv.dtype}: largest "
+          f"difference from the jnp reference {short_diff:.5f}", flush=True)
+    check(np.allclose(o, o_ref, rtol=2e-2, atol=2e-2),
+          f"C: short_attention differs from the jnp reference by "
+          f"{short_diff:.4f}")
+
     # the standalone Pallas transform on a host uint8 frame
     frame = rng.integers(0, 256, frame_shape, dtype=np.uint8)
     p = parse_launch(
@@ -539,7 +578,8 @@ def section_c(batch: int, image: int, patch: int, dim: int, depth: int,
         np.asarray(out), want, rtol=1e-6, atol=1e-6),
         "C: Pallas transform differs from numpy")
     return {"mosaic": compiled_for_chip,
-            "attention_max_abs_diff": round(attn_diff, 5)}
+            "attention_max_abs_diff": round(attn_diff, 5),
+            "short_attention_max_abs_diff": round(short_diff, 5)}
 
 
 # -- D: the fence ---------------------------------------------------------------

@@ -2,9 +2,13 @@
 
 The reference ships CNN-era test models only; this family extends the
 zoo with the attention-based architecture class and is the in-tree user
-of the Pallas flash-attention kernel (``ops.flash_attention``) — patch
-sequences are exactly the workload the blockwise kernel and the ring
-attention sequence-parallel path (parallel/collectives.py) exist for.
+of the Pallas attention kernels.  ``_attention`` picks the core from the
+shape alone: a patch sequence short enough for one key block (ViT-B/16's
+196 positions with heads of 64, and every other size the zoo registers)
+takes ``ops.short_attention`` on the qkv projection's own layout; a
+longer one is split into heads for the blockwise ``ops.flash_attention``
+— the single-chip engine under the ring-attention sequence-parallel path
+(parallel/collectives.py) — which keeps its own jnp fallback.
 
 Functional pytree style matching models/mobilenet.py: ``vit_init`` →
 params dict, ``vit_apply(params, x)`` jittable, bf16 compute with f32
@@ -78,18 +82,29 @@ def vit_init(key, image_size: int = 224, patch: int = 16, dim: int = 256,
 
 
 def _attention(block, x, heads: int, dtype):
-    from ..ops import flash_attention
+    """qkv projection, attention core, output projection.  The core is
+    chosen from the shape alone: a sequence short enough for one key
+    block takes ``short_attention`` on the projection's own layout;
+    anything else is split into heads for ``flash_attention`` (which
+    keeps its own rule and its jnp fallback)."""
+    from ..ops import (flash_attention, short_attention,
+                       short_attention_available)
 
     B, S, D = x.shape
     qkv = _dense(block["qkv"], x, dtype)                  # (B,S,3D)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    dh = D // heads
+    if short_attention_available(qkv.shape, heads, qkv.dtype):
+        # straight in the caller's scope: the call's device time is
+        # booked to the layer's ``attn`` stage
+        o = short_attention(qkv, heads)                   # (B,S,D)
+    else:
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        dh = D // heads
 
-    def split(t):  # (B,S,D) → (B,H,S,dh)
-        return t.reshape(B, S, heads, dh).transpose(0, 2, 1, 3)
+        def split(t):  # (B,S,D) → (B,H,S,dh)
+            return t.reshape(B, S, heads, dh).transpose(0, 2, 1, 3)
 
-    o = flash_attention(split(q), split(k), split(v))
-    o = o.transpose(0, 2, 1, 3).reshape(B, S, D)
+        o = flash_attention(split(q), split(k), split(v))
+        o = o.transpose(0, 2, 1, 3).reshape(B, S, D)
     return _dense(block["proj"], o, dtype)
 
 
@@ -131,12 +146,13 @@ def register_vit(name: str = "vit_s16", batch: int = 1,
                  heads: int = 2, seed: int = 0, **kw) -> str:
     """Register a ViT in the filter model registry.
 
-    Default ``heads=2`` keeps the head dim at dim/heads = 128 so the
-    Pallas flash-attention kernel's tiling check (head dim % 128 == 0,
-    ops/kernels.py) passes.  The kernel additionally needs the patch
-    sequence length ((image_size/patch)²) to be a multiple of its query
-    block (128): 224/16 → 196 patches falls back to the jnp reference;
-    use ``image_size=256`` (256 patches) for the full kernel path.
+    Attention runs in a Pallas kernel where the head size dim/heads is
+    64 (an even number of heads) or a multiple of 128 and ``dim`` is a
+    multiple of 128; the patch sequence ((image_size/patch)²) need not
+    tile (``ops.short_attention_available`` is the rule; 224/16 → 196
+    patches with heads of 64 is ViT-B/16's own shape).  Default
+    ``heads=2`` keeps the head dim of the default ``dim`` at 128.  Any
+    other head size takes the jnp reference.
     """
     from ..filters.jax_xla import register_model
 

@@ -4,11 +4,13 @@ Where the reference hand-vectorizes with Orc SIMD kernels
 (/root/reference/gst/nnstreamer/elements/nnstreamer-orc.orc), this
 package holds hand-written TPU kernels for the ops worth owning below
 XLA: the streaming normalize/typecast prologue, the flash-attention
-block kernel behind long-context attention, and the one-pass decode
-attention over a latent cache.  Every kernel has a jnp
-reference implementation; the first two say through their
-``*_available`` rule when a caller should use it instead, the third
-refuses a shape it cannot take.
+block kernel behind long-context attention, whole-sequence attention
+for short sequences, and the one-pass decode attention over a latent
+cache.  Every kernel has a jnp reference implementation; the first two
+say through their ``*_available`` rule when a caller should use it
+instead (and take it themselves), the last two refuse a shape they
+cannot take, ``short_attention`` with an ``*_available`` rule for the
+caller to ask first.
 """
 
 from .kernels import (
@@ -20,12 +22,17 @@ from .kernels import (
     latent_decode_attention_reference,
     scale_bias_cast,
     scale_bias_cast_available,
+    short_attention,
+    short_attention_available,
+    short_attention_reference,
 )
 
 __all__ = [
     "scale_bias_cast", "scale_bias_cast_available",
     "flash_attention", "flash_attention_available",
     "flash_attention_reference",
+    "short_attention", "short_attention_available",
+    "short_attention_reference",
     "latent_decode_attention", "latent_decode_attention_refusal",
     "latent_decode_attention_reference",
 ]

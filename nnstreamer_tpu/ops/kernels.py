@@ -1,5 +1,5 @@
-"""Pallas TPU kernels: fused normalize/typecast, flash attention and
-latent decode attention.
+"""Pallas TPU kernels: fused normalize/typecast, flash attention, short
+attention and latent decode attention.
 
 Parity/role:
 - ``scale_bias_cast`` is the tensor_transform arithmetic prologue
@@ -14,18 +14,27 @@ Parity/role:
   single-chip engine under long-context sequence parallelism
   (parallel/collectives.ring_attention rotates K/V blocks between chips
   with the same math).
+- ``short_attention`` is whole-sequence attention for sequences short
+  enough that the whole key range is one block (a ViT's few hundred
+  patches): a frame with all its heads a grid step, read from the qkv
+  projection's ``(B, S, 3·D)`` and written as the ``(B, S, D)`` the
+  output projection reads, scores in VMEM only.  ``S`` need not tile:
+  it pads and masks inside.
 - ``latent_decode_attention`` is absorbed latent attention of one token
   a stream over a latent cache (``models/deepseek_v2.py``'s decode
   step): one pass over the cache, blocks beyond a stream's position
   skipped.
 
 All compile natively on TPU (Mosaic) and run under the Pallas
-interpreter on CPU backends (tests).  A shape that does not meet the
-tiling constraints — lane dim a multiple of 128, sublane dim a multiple
-of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32 of
-1-byte elements) — takes the jnp reference: the ``*_available``
-predicates are the whole eligibility rule, so the fallback is a
-decision made here, never an exception caught somewhere.
+interpreter on CPU backends (tests).  ``scale_bias_cast`` and
+``flash_attention`` take their jnp reference for a shape that does not
+meet the tiling constraints — lane dim a multiple of 128, sublane dim a
+multiple of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32
+of 1-byte elements); ``short_attention`` and ``latent_decode_attention``
+refuse a shape they cannot take and leave the choice to the caller.
+Either way the ``*_available`` / ``*_refusal`` predicates are the whole
+eligibility rule, so the fallback is a decision made here, never an
+exception caught somewhere.
 """
 
 from __future__ import annotations
@@ -243,6 +252,155 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
         interpret=_interpret(),
     )(qf, kf, vf)
     return out.reshape(*lead, S, D) if lead else out[0]
+
+
+# -- short attention ----------------------------------------------------------
+
+# what one grid step of short_attention may hold in VMEM: its blocks,
+# double buffered, and the float32 scores the unrolled heads keep alive
+# (counted at six a step: a head's scores, probabilities and their cast,
+# and the next head's already in flight).  The chip's scoped default is
+# 16 MiB and the rest is the compiler's; every shape this admits of a
+# grid of widths, heads and lengths compiled for a v5e, and the first
+# one past it (768 wide, 512 positions) ran out of VMEM.
+_SHORT_VMEM_BUDGET = 10 << 20
+
+
+def _short_rows(positions: int, dtype) -> tuple:
+    """(query rows, key rows) of a step's blocks: queries whole tiles of
+    ``dtype``, keys whole lanes (they are the scores' lane axis)."""
+    tile = _sublane(dtype)
+    return -(-positions // tile) * tile, -(-positions // _LANE) * _LANE
+
+
+def short_attention_available(qkv_shape, heads: int, dtype) -> bool:
+    """Kernel eligibility for a ``(B, S, 3·D)`` qkv projection of
+    ``heads`` heads: bfloat16 or float32; head size 64 (two heads fill
+    one 128-lane block) or whole lanes; ``D`` whole lanes; and ``S``
+    short enough that a frame's q, k, v and o blocks (double buffered)
+    and the float32 scores in flight fit ``_SHORT_VMEM_BUDGET`` (up to
+    384 positions at ViT-B/16's width).  ``S`` itself may be ragged: the
+    kernel pads and masks."""
+    if len(qkv_shape) != 3 or heads <= 0 or qkv_shape[2] % 3:
+        return False
+    if np.dtype(dtype) not in (np.dtype("bfloat16"), np.dtype(np.float32)):
+        return False
+    S, D = qkv_shape[1], qkv_shape[2] // 3
+    if S <= 0 or D % heads or D % _LANE:
+        return False
+    dh = D // heads
+    if dh % _LANE and not (dh == _LANE // 2 and heads % 2 == 0):
+        return False
+    rows_q, rows_k = _short_rows(S, dtype)
+    blocks = 2 * (2 * rows_q + 2 * rows_k) * D * np.dtype(dtype).itemsize
+    scores = 6 * rows_q * rows_k * 4
+    return blocks + scores <= _SHORT_VMEM_BUDGET
+
+
+def short_attention_reference(qkv, heads: int,
+                              scale: Optional[float] = None):
+    """jnp reference on the same layout: split the projection, heads to
+    the front, :func:`flash_attention_reference`, heads back."""
+    import jax.numpy as jnp
+
+    B, S, D = qkv.shape[0], qkv.shape[1], qkv.shape[2] // 3
+
+    def split(t):
+        return t.reshape(B, S, heads, D // heads).transpose(0, 2, 1, 3)
+
+    o = flash_attention_reference(
+        *map(split, jnp.split(qkv, 3, axis=-1)), scale)
+    return o.transpose(0, 2, 1, 3).reshape(B, S, D)
+
+
+def short_attention(qkv, heads: int, scale: Optional[float] = None):
+    """Whole-sequence attention for short sequences, read where the qkv
+    projection wrote it: ``qkv (B, S, 3·D)`` (q, k, v side by side,
+    each ``heads`` contiguous slices of ``D/heads`` lanes) to ``o (B, S,
+    D)``, the layout the output projection reads — no head split, no
+    transpose either side.
+
+    One grid step takes a frame with all its heads.  The whole key range
+    is one block, so the softmax is the plain one in float32 (row
+    maximum, ``exp``, row sum; no running statistics), and scores and
+    probabilities never leave VMEM.  Products take the operands as they
+    are (bf16 on the MXU for bf16 activations) and accumulate in
+    float32; the probabilities are cast to the activations' dtype for
+    ``p v`` and the row sum divides the product.  Heads of 64 are worked
+    in pairs on their shared 128-lane block: a head's scores contract
+    over the whole block against keys whose other half is zeroed, and
+    its ``p v`` keeps its own half of the lanes — the matrix unit is as
+    busy as with 64-lane slices and nothing is shuffled across lanes.
+
+    ``S`` need not tile: the blocks overhang the array (queries to whole
+    tiles, keys to whole lanes); key columns beyond ``S`` are masked to
+    -inf before the maximum, value rows beyond ``S`` are zeroed (what
+    lies there is not defined, and 0 x NaN is NaN), and query rows
+    beyond ``S`` are never written.  A shape
+    :func:`short_attention_available` refuses is an error: the caller
+    chooses."""
+    import jax.numpy as jnp
+
+    if not short_attention_available(qkv.shape, heads, qkv.dtype):
+        raise ValueError(
+            f"short_attention: {tuple(qkv.shape)} {qkv.dtype} with {heads} "
+            "heads is not a shape short_attention_available admits")
+    jax, pl, pltpu = _pl()
+    B, S, D = qkv.shape[0], qkv.shape[1], qkv.shape[2] // 3
+    dh = D // heads
+    if scale is None:
+        scale = dh ** -0.5
+    width = max(dh, _LANE)            # lanes worked at a time
+    rows_q, rows_k = _short_rows(S, qkv.dtype)
+    nt = (((1,), (1,)), ((), ()))     # q k^T without a transpose
+
+    def kernel(q_ref, k_ref, v_ref, o_ref):
+        key_ok = jax.lax.broadcasted_iota(
+            jnp.int32, (rows_q, rows_k), 1) < S
+        row_ok = jax.lax.broadcasted_iota(
+            jnp.int32, (rows_k, width), 0) < S
+        lane_k = jax.lax.broadcasted_iota(jnp.int32, (rows_k, width), 1)
+        lane_q = jax.lax.broadcasted_iota(jnp.int32, (rows_q, width), 1)
+        for g in range(D // width):
+            lanes = slice(g * width, (g + 1) * width)
+            q, k, v = q_ref[0, :, lanes], k_ref[0, :, lanes], \
+                v_ref[0, :, lanes]
+            if rows_k != S:
+                v = jnp.where(row_ok, v, jnp.zeros_like(v))
+            o = None
+            for j in range(width // dh):
+                kj = k if width == dh else jnp.where(
+                    (lane_k >= j * dh) & (lane_k < (j + 1) * dh),
+                    k, jnp.zeros_like(k))
+                s = jax.lax.dot_general(
+                    q, kj, nt, preferred_element_type=jnp.float32) * scale
+                if rows_k != S:
+                    s = jnp.where(key_ok, s, -jnp.inf)
+                p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+                norm = jnp.sum(p, axis=-1, keepdims=True)
+                oj = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32) / norm
+                o = oj if o is None else jnp.where(
+                    lane_q < j * dh, o, oj)
+            o_ref[0, :, lanes] = o.astype(o_ref.dtype)
+
+    # no ``name=``: it would open a scope of its own, and the caller's
+    # scope is the stage this call's device time is booked to
+    return pl.pallas_call(
+        kernel,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, rows_q, D), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, rows_k, D), lambda b: (b, 0, 1)),
+            pl.BlockSpec((1, rows_k, D), lambda b: (b, 0, 2)),
+        ],
+        out_specs=pl.BlockSpec((1, rows_q, D), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, S, D), qkv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=_interpret(),
+    )(qkv, qkv, qkv)
 
 
 # -- latent decode attention --------------------------------------------------

@@ -51,19 +51,23 @@ def test_section_b_serving_path():
 
 def test_section_c_kernels_engage_interpreted():
     # 64/4 = 16 patches a side -> 256 positions, head dim 128: the
-    # smallest ViT whose attention is kernel-eligible
+    # blockwise kernel's smallest shape (the ViT itself takes
+    # short_attention there); beside it ViT-B/16's 196 positions with
+    # heads of 64
     facts = chip_smoke.section_c(batch=2, image=64, patch=4, dim=128,
                                  depth=2, heads=1, mlp=256, num_classes=10,
-                                 frame_shape=(32, 128, 3))
+                                 frame_shape=(32, 128, 3),
+                                 short=(196, 2, 64))
     assert facts["mosaic"] is False            # CPU: the interpreter
+    assert facts["short_attention_max_abs_diff"] < 0.02
 
 
 def test_section_c_catches_a_silent_jnp_fallback():
-    # 64/16 = 4 patches a side -> 16 positions: not kernel-eligible,
-    # the ViT quietly runs flash_attention_reference — which is exactly
-    # what the section exists to refuse
+    # one head of 64 neither pairs up on a lane block nor fills one: no
+    # kernel is eligible, the ViT quietly runs flash_attention_reference
+    # — which is exactly what the section exists to refuse
     with pytest.raises(chip_smoke.SmokeError, match="jnp fallback"):
-        chip_smoke.section_c(batch=2, image=64, patch=16, dim=128,
+        chip_smoke.section_c(batch=2, image=64, patch=16, dim=64,
                              depth=1, heads=1, mlp=256, num_classes=10,
                              frame_shape=(32, 128, 3))
 

@@ -1,5 +1,6 @@
-"""Pallas kernels (ops/): fused scale/bias/cast and flash attention.
-On non-TPU backends the kernels run under the Pallas interpreter."""
+"""Pallas kernels (ops/): fused scale/bias/cast, flash attention and
+short attention.  On non-TPU backends the kernels run under the Pallas
+interpreter."""
 
 import numpy as np
 import pytest
@@ -8,6 +9,9 @@ from nnstreamer_tpu.ops import (
     flash_attention,
     flash_attention_reference,
     scale_bias_cast,
+    short_attention,
+    short_attention_available,
+    short_attention_reference,
 )
 
 
@@ -73,6 +77,91 @@ class TestFlashAttention:
                                    rtol=1e-5)
 
 
+class TestShortAttention:
+    """``short_attention`` against its jnp reference (the split-heads
+    path ``models/vit.py`` takes without the kernel).  The ragged cases
+    carry NaN wherever a block overhangs the array (the interpreter
+    fills what is not there with NaN, pinned below), so a key column
+    left unmasked or a value row left unzeroed makes the output NaN."""
+
+    @pytest.mark.parametrize("batch,positions,heads,size,dtype,tol", [
+        # ViT-B/16's own: 196 positions, 12 heads of 64, bfloat16
+        (2, 196, 12, 64, "bfloat16", 2e-2),
+        # float32 toys: ragged with paired heads, ragged with whole-lane
+        # heads, a single query tile, and one that tiles exactly
+        (2, 50, 4, 64, "float32", 2e-5),
+        (1, 16, 2, 128, "float32", 2e-5),
+        (1, 8, 2, 64, "float32", 2e-5),
+        (2, 128, 2, 64, "float32", 2e-5),
+        (2, 256, 1, 128, "bfloat16", 2e-2),
+    ])
+    def test_matches_reference(self, batch, positions, heads, size, dtype,
+                               tol):
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(positions)
+        qkv = jnp.asarray(rng.standard_normal(
+            (batch, positions, 3 * heads * size)), dtype)
+        assert short_attention_available(qkv.shape, heads, qkv.dtype)
+        o = short_attention(qkv, heads)
+        assert o.shape == (batch, positions, heads * size)
+        assert o.dtype == qkv.dtype
+        got = np.asarray(o, np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(
+            got, np.asarray(short_attention_reference(qkv, heads), np.float32),
+            rtol=tol, atol=tol)
+
+    def test_what_overhangs_the_array_reads_nan_here(self):
+        """The premise of the ragged cases above."""
+        import jax
+        from jax.experimental import pallas as pl
+
+        def kernel(x_ref, o_ref):
+            o_ref[...] = x_ref[...]
+
+        x = np.ones((5, 128), np.float32)
+        out = pl.pallas_call(
+            kernel, grid=(1,),
+            in_specs=[pl.BlockSpec((8, 128), lambda i: (0, 0))],
+            out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((8, 128), np.float32),
+            interpret=True)(x)
+        assert np.isnan(np.asarray(out)[5:]).all()
+        assert (np.asarray(out)[:5] == 1).all()
+
+    def test_masked_keys_would_change_the_answer(self):
+        """Zeros where the NaN were are no better: 60 more key columns
+        scoring 0 take their share of every row's probability."""
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(9)
+        qkv = jnp.asarray(rng.standard_normal((1, 196, 3 * 128)), "float32")
+        padded = jnp.pad(qkv, ((0, 0), (0, 60), (0, 0)))
+        right = np.asarray(short_attention(qkv, 2))
+        wrong = np.asarray(short_attention(padded, 2))[:, :196]
+        assert np.abs(right - wrong).max() > 0.05
+        np.testing.assert_allclose(
+            right, np.asarray(short_attention_reference(qkv, 2)),
+            rtol=2e-5, atol=2e-5)
+
+    def test_scale_is_the_callers(self):
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(10)
+        qkv = jnp.asarray(rng.standard_normal((1, 24, 3 * 128)), "float32")
+        with_scale = np.asarray(short_attention(qkv, 1, scale=0.3))
+        np.testing.assert_allclose(
+            with_scale, np.asarray(short_attention_reference(qkv, 1, 0.3)),
+            rtol=2e-5, atol=2e-5)
+        assert np.abs(with_scale
+                      - np.asarray(short_attention(qkv, 1))).max() > 1e-2
+
+    def test_a_refused_shape_is_an_error(self):
+        with pytest.raises(ValueError, match="short_attention_available"):
+            short_attention(np.zeros((1, 16, 3 * 96), np.float32), 1)
+
+
 class TestKernelEligibility:
     """The ``*_available`` predicates are the whole decision between a
     Pallas kernel and its jnp reference: a shape Mosaic could refuse
@@ -123,6 +212,54 @@ class TestKernelEligibility:
         # not for bf16
         assert ok((1, 8, 128), (1, 128, 128), np.float32)
         assert not ok((1, 8, 128), (1, 128, 128), jnp.bfloat16)
+
+    @pytest.mark.parametrize("shape,heads,dtype,short,flash", [
+        # ViT-B/16 as the benchmark runs it, and in float32
+        ((64, 196, 2304), 12, "bfloat16", True, False),
+        ((64, 196, 2304), 12, "float32", True, False),
+        # whole-lane heads; bench.py's ViT (256 positions, heads of 128)
+        # is short too although the blockwise kernel could take it
+        ((2, 16, 768), 2, "bfloat16", True, False),
+        ((64, 256, 1536), 4, "bfloat16", True, True),
+        # the VMEM rule at ViT-B/16's width: 384 positions fit, 512
+        # do not (Mosaic ran out of VMEM there when it was let through)
+        ((2, 384, 2304), 12, "bfloat16", True, False),
+        ((2, 512, 2304), 12, "bfloat16", False, False),
+        # too long for one key block: the blockwise kernel's
+        ((4, 1024, 2304), 6, "bfloat16", False, True),
+        # ... or nobody's (heads of 64): jnp
+        ((4, 1000, 2304), 12, "bfloat16", False, False),
+        # heads that neither pair up on a lane block nor fill one
+        ((2, 196, 2304), 24, "bfloat16", False, False),    # 32
+        ((2, 196, 3 * 192), 3, "bfloat16", False, False),  # D 192
+        ((2, 196, 3 * 96), 1, "float32", False, False),    # 96
+        # dtypes the kernel was not written for
+        ((2, 196, 2304), 12, "float16", False, False),
+        ((2, 196, 2304), 12, "int8", False, False),
+        # not a qkv projection
+        ((2, 196, 2305), 12, "bfloat16", False, False),
+        ((196, 2304), 12, "bfloat16", False, False),
+    ])
+    def test_short_attention_eligibility(self, shape, heads, dtype, short,
+                                         flash):
+        """What ``short_attention`` takes, what it leaves to
+        ``flash_attention``, and what still falls to jnp."""
+        from nnstreamer_tpu.ops import flash_attention_available
+
+        assert short_attention_available(shape, heads, dtype) is short
+        if len(shape) == 3 and shape[2] % (3 * heads) == 0:
+            split = (shape[0], heads, shape[1], shape[2] // 3 // heads)
+            assert flash_attention_available(split, split, dtype) is flash
+
+    def test_short_attention_takes_the_path_the_predicate_names(self):
+        rng = np.random.default_rng(5)
+        qkv = rng.standard_normal((1, 20, 3 * 128)).astype(np.float32)
+        assert self.pallas_calls(
+            lambda x: short_attention(x, 2), qkv) == 1
+        # one call whatever the heads: a step takes a frame, not a head
+        wide = rng.standard_normal((2, 20, 3 * 512)).astype(np.float32)
+        assert self.pallas_calls(
+            lambda x: short_attention(x, 8), wide) == 1
 
     def test_flash_attention_takes_the_path_the_predicate_names(self):
         rng = np.random.default_rng(4)

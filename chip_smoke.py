@@ -3,9 +3,10 @@
 
 Drives the system's main path once, through the entry points a user
 calls (``parse_launch``, ``register_model``, element properties), on a
-directly attached TPU, at the sizes ``bench.py`` uses, with random
-weights made from a seed.  Every section compares what came out of the
-pipeline with the same function jitted directly on the same frames.
+directly attached TPU, at the sizes below (``SSD_*``, ``VIT_*``), with
+random weights made from a seed.  Every section compares what came out
+of the pipeline with the same function jitted directly on the same
+frames.
 
     A  device-resident composite: device_src ! transform ! jax-xla SSD
        (300x300, 91 classes, width 1.0, batch 256, bf16 weights) !
@@ -28,6 +29,7 @@ starts outlives it.  ``tests/test_chip_smoke.py`` runs these same
 section functions at a toy size on the CPU.
 """
 
+import functools
 import json
 import os
 import threading
@@ -39,10 +41,16 @@ import numpy as np
 #: arithmetic for the directly jitted references
 NORM = "typecast:float32,add:-127.5,div:127.5"
 SEED = 20260926
-#: bounding_boxes' default confidence threshold (bench.py renders with it)
+#: bounding_boxes' default confidence threshold
 CONF = 0.25
-#: detections per frame the bench SSD emits (bench._register_ssd_pp)
+#: detections per frame the smoke SSD emits (_register_ssd_pp)
 MAX_OUT = 10
+#: the chip run's sizes: the SSD composite's window and frame side, and
+#: a ViT at which the blockwise flash-attention kernel engages (head dim
+#: 512/4 = 128, (256/16)^2 = 256 positions: whole 128-tiles both)
+SSD_BATCH, SSD_SIZE = 256, 300
+VIT_BATCH, VIT_SIZE, VIT_PATCH, VIT_DIM = 64, 256, 16, 512
+VIT_DEPTH, VIT_HEADS, VIT_MLP = 6, 4, 2048
 #: The SSD runs bf16 activations through ~50 layers of random weights.
 #: Two programs that tile a convolution differently (batch 1 against a
 #: window of 8, a 64-frame shard against 256 frames) round differently;
@@ -314,6 +322,48 @@ def run_streams(launch: str, filter_name: str, spec, frames, what: str,
     return outs, facts
 
 
+# -- the SSD every section registers ------------------------------------------
+
+
+@functools.cache
+def _ssd_params_anchors(size: int, num_classes: int):
+    """The SSD's weights and anchors, made ONCE per process and size:
+    the sections register the same model under different names and
+    batches."""
+    import jax
+
+    from nnstreamer_tpu.models.params_io import weights_to_bf16
+    from nnstreamer_tpu.models.ssd import ssd_anchors, ssd_mobilenet_v2_init
+
+    fs = tuple(int(np.ceil(size / s)) for s in (16, 32, 64, 128, 256, 512))
+    return (weights_to_bf16(ssd_mobilenet_v2_init(
+                jax.random.PRNGKey(0), num_classes=num_classes)),
+            ssd_anchors(size, fs))
+
+
+def _register_ssd_pp(name: str, batch: int, size: int, num_classes: int):
+    """Register the composite SSD with outputs in the reference
+    postprocess wire order (boxes, classes, scores, num) that the
+    bounding_boxes mobilenet-ssd-postprocess decoder consumes
+    (parity: mobilenetssdpp.cc)."""
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.filters.jax_xla import register_model
+    from nnstreamer_tpu.models.ssd import ssd_detect_apply
+
+    params, anchors = _ssd_params_anchors(size, num_classes)
+
+    def detect(p, x):
+        boxes, scores, classes = ssd_detect_apply(p, x, anchors,
+                                                  max_out=MAX_OUT)
+        num = jnp.sum((scores > CONF).astype(jnp.int32), axis=-1)
+        return boxes, classes, scores, num
+
+    register_model(name, detect, params=params,
+                   in_shapes=[(batch, size, size, 3)],
+                   in_dtypes=np.float32)
+
+
 # -- A: device-resident composite ---------------------------------------------
 
 
@@ -321,7 +371,6 @@ def section_a(batch: int, size: int, num_classes: int,
               n_buffers: int = 4, n_pool: int = 2) -> dict:
     import jax
 
-    from nnstreamer_tpu.bench import _register_ssd_pp
     from nnstreamer_tpu.decoders.boxutil import device_render_fn
     from nnstreamer_tpu.filters.jax_xla import unregister_model
 
@@ -374,7 +423,6 @@ def section_b(streams: int, frames_per_stream: int, batch: int, size: int,
               num_classes: int) -> dict:
     import jax
 
-    from nnstreamer_tpu.bench import _register_ssd_pp
     from nnstreamer_tpu.core import TensorsSpec
     from nnstreamer_tpu.filters.jax_xla import unregister_model
 
@@ -691,7 +739,6 @@ def section_e(batch: int, size: int, num_classes: int, pool_batch: int,
               frames_per_stream: int, n_buffers: int = 2) -> dict:
     import jax
 
-    from nnstreamer_tpu.bench import _register_ssd_pp
     from nnstreamer_tpu.core import TensorsSpec
     from nnstreamer_tpu.decoders.boxutil import device_render_fn
     from nnstreamer_tpu.filters.jax_xla import (
@@ -855,7 +902,6 @@ def main() -> None:
             f"{dev.platform!r} ({dev.device_kind}, {count} device(s)); "
             "this script only runs on a TPU")
 
-    from nnstreamer_tpu import bench
     from nnstreamer_tpu.obs.hwspec import spec_for_device_kind
     from nnstreamer_tpu.ops.kernels import _interpret
 
@@ -884,17 +930,17 @@ def main() -> None:
           f"; kernels: Mosaic; SSD preselect: approx top-k", flush=True)
 
     sections = [
-        ("A", lambda: section_a(bench.SSD_BATCH, bench.SSD_SIZE, 91)),
-        ("B", lambda: section_b(4, 24, 8, bench.SSD_SIZE, 91)),
+        ("A", lambda: section_a(SSD_BATCH, SSD_SIZE, 91)),
+        ("B", lambda: section_b(4, 24, 8, SSD_SIZE, 91)),
         ("C", lambda: section_c(
-            bench.VIT_BATCH, bench.VIT_SIZE, bench.VIT_PATCH, bench.VIT_DIM,
-            bench.VIT_DEPTH, bench.VIT_HEADS, bench.VIT_MLP, 1000,
-            (bench.VIT_SIZE, bench.VIT_SIZE, 3))),
+            VIT_BATCH, VIT_SIZE, VIT_PATCH, VIT_DIM,
+            VIT_DEPTH, VIT_HEADS, VIT_MLP, 1000,
+            (VIT_SIZE, VIT_SIZE, 3))),
         ("D", lambda: section_d(4096, 0.5, spec.peak_flops)),
     ]
     if count >= 4:
         sections.append(
-            ("E", lambda: section_e(bench.SSD_BATCH, bench.SSD_SIZE, 91,
+            ("E", lambda: section_e(SSD_BATCH, SSD_SIZE, 91,
                                     8, 16)))
     meter = CompileMeter()
     for name, run in sections:

@@ -130,8 +130,8 @@ def prof_env_problems() -> List[Diagnostic]:
                 "NNS518",
                 f"NNS_TPU_PROF={hz:g} Hz exceeds {MAX_PROF_HZ:g} Hz: "
                 "each tick walks every thread's whole stack — at this "
-                "rate the profiler is no longer low-overhead "
-                "(the --hostprof bench gates < 3%)", hint=_PROF_HINT))
+                "rate the profiler is no longer low-overhead",
+                hint=_PROF_HINT))
     return diags
 
 

@@ -97,8 +97,7 @@ def _aot_call(lowered, jitted: Callable, pkey: Optional[str] = None,
     program from ``NNS_TPU_COMPILE_CACHE_DIR`` (counted as a
     ``persist_hit`` compile) onto ``devices`` — the executable's own
     device list — before paying the XLA build, and a fresh build is
-    serialized back for the next process, measured by ``bench.py
-    --lifecycle``."""
+    serialized back for the next process."""
     # the Lowered (traced jaxpr + IR) lives in state, not the closure's
     # free variables, so it can be dropped the moment the executable is
     # resolved — a long-running serving process must not pin megabytes

@@ -29,12 +29,6 @@ one subsystem (Documentation/observability.md):
   batch/stream occupancy per pipeline and per pool — plus LINK rows
   for the edge links and a COMPILE section (XLA compile telemetry),
   aggregated across a fleet of ``--connect`` endpoints.
-- :mod:`.benchgate` — the continuous-bench regression gate:
-  ``bench.py --history`` appends normalized run records to
-  ``BENCH_history.jsonl`` and ``nns-bench-diff`` compares the latest
-  record against a committed per-metric-tolerance baseline
-  (pass/regression/missing-baseline — the CI gate) or, with
-  ``--against``, any two history records.
 - :mod:`.transfer` — the byte-exact host↔device transfer ledger:
   every crossing at the jax seams counted with exact ``nbytes``,
   labeled ``{pipeline, source, direction, reason}``, exported as
@@ -64,7 +58,7 @@ one subsystem (Documentation/observability.md):
   breakers — every decision audited (ring + ``nns_control_*`` export,
   snapshot-v6 ``control`` table, ``nns-top`` CONTROL section,
   ``/healthz`` summary) and the fault → alert → actuation →
-  recovered-SLO loop gated as MTTR (``bench.py --mttr``).
+  knob-restored loop pinned by ``tests/test_control.py``.
 """
 
 from __future__ import annotations

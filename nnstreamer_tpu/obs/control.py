@@ -592,8 +592,8 @@ class Controller:
 
     def _record(self, decision: dict, now: float) -> dict:
         """EVERY decision — applied or rejected — lands in the audit
-        ring AND the exported counter (the bench gate asserts the two
-        counts equal), is gauged when it moved a knob, and is noted +
+        ring AND the exported counter (``tests/test_control.py`` asserts
+        the two counts equal), is gauged when it moved a knob, and is noted +
         dumped by the flight recorder."""
         decision = dict(decision, ts=now, wall=time.time())
         with self._alock:

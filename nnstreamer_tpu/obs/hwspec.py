@@ -1,10 +1,10 @@
 """Hardware peak table — the denominator of every utilization figure.
 
 MFU and HBM-bandwidth utilization are ratios against *hardware* peaks.
-This module is the one source of truth: ``bench.py`` imports its
-constants from here, and the scrape-time MFU join (:mod:`.xlacost`)
-resolves the spec of the device an executable was compiled for through
-:func:`spec_for_device_kind`.
+This module is the program's one table: the scrape-time MFU join
+(:mod:`.xlacost`) resolves the spec of the device an executable was
+compiled for through :func:`spec_for_device_kind`, and ``chip_smoke.py``
+refuses a device that is not in it.
 
 The table is keyed by ``jax.Device.device_kind`` — the string the
 runtime reports for the silicon — not by the platform tag: every TPU
@@ -50,9 +50,6 @@ class HwSpec:
 #: deployment's own price goes in NNS_TPU_CHIP_HOUR_USD.
 V5E = HwSpec(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
              ici_bw=200e9, chip_hour_usd=1.20)
-
-#: the scaling projection's ICI figure (bench.py, tests/test_scaling_model.py)
-V5E_ICI_BYTES_PER_S = V5E.ici_bw
 
 #: ``jax.Device.device_kind`` -> spec.  A v5e chip reports
 #: "TPU v5 lite".  Anything absent — CPU included — is unknown

@@ -1,8 +1,6 @@
 """Per-shard mesh attribution — who on the mesh actually did the work.
 
-``MESH_SCALING.json`` showed the sharded filter collapsing to 50%
-weak-scaling efficiency at n=2 with only a hand-written note guessing
-why: nothing recorded how frames were split across shards, how many
+Nothing used to record how frames were split across shards, how many
 micro-batch slots were padding, or even what topology a dispatch ran
 over.  This module closes that gap: every mesh dispatch (the jax-xla
 single-frame mesh path, ``invoke_batched`` windows with a sharding

@@ -22,7 +22,7 @@ Outputs:
 - :meth:`MetricsRegistry.exposition` — Prometheus text format 0.0.4;
 - :meth:`MetricsRegistry.snapshot` — one JSON-able dict with both the
   flat metric families and a structured per-pipeline/per-pool view
-  (what ``nns-top`` renders and ``bench.py --metrics`` embeds);
+  (what ``nns-top`` renders);
 - :func:`serve_metrics` — a stdlib-http endpoint (``/metrics`` text,
   ``/json`` snapshot).  Setting ``NNS_TPU_METRICS_PORT`` serves the
   global registry automatically when the first pipeline starts, so any

@@ -34,8 +34,8 @@ reads and feed per-element accumulators, exported as
 ``nns_element_cpu_seconds_total`` / ``nns_element_run_seconds_total`` /
 ``nns_element_wait_seconds_total`` and the snapshot-v10 ``profile``
 table.  Unlike sampling this is exact: per-element cpu_seconds sum to
-the process CPU delta (minus unaccounted threads) — the
-``bench.py --hostprof`` attribution-exactness gate.
+the process CPU delta (minus unaccounted threads) —
+``tests/test_prof.py::test_cpu_sum_stays_within_process_time``.
 
 **Deep profiles** (:data:`DEEP`).  ``NNS_TPU_PROF_DEEP_DIR`` arms
 alert-triggered capture episodes: on a watch rule's rising edge
@@ -325,8 +325,8 @@ class SamplingProfiler:
         self.errors_total = 0
         self.runnable_last = 0
         self.gil_waiters = 0
-        #: the sampler's OWN cpu time — the deterministic overhead
-        #: bound bench.py --hostprof reports next to the A/B figure
+        #: the sampler's OWN cpu time — the deterministic bound on
+        #: what sampling costs
         self.self_cpu_s = 0.0
 
     # -- lifecycle -----------------------------------------------------------

@@ -7,8 +7,8 @@ device→device continuation over the device channel
 (``edge/devicechannel.py`` slot deposit/take + ``jax.device_put`` onto
 the destination stage's chips), tagged ``d2d`` on the transfer ledger
 so the ``crossings_per_frame == 0.0`` invariant extends across stages.
-This module is the stage-level view of that flow — the numbers the
-cascade bench gates and the nns-top STAGE section renders:
+This module is the stage-level view of that flow — the numbers
+``tests/test_stagesplit.py`` pins and the nns-top STAGE section renders:
 
 - **handoff rows** (one per receiving stage filter): frames and exact
   bytes that crossed INTO the stage from another subset, the canonical
@@ -19,7 +19,7 @@ cascade bench gates and the nns-top STAGE section renders:
 - **offload rows** (one per routing ``tensor_if``): how many frames the
   conditional cascade sent down the offload (heavy-stage) branch vs
   kept local — ``nns_cascade_offload_ratio`` is offloaded/total, the
-  fraction the seeded-predicate bench pins exactly.
+  fraction the seeded predicate of that test pins exactly.
 
 Pulled by the metrics registry at scrape time like every other
 collected stat: the snapshot's ``stages`` table (v8), the

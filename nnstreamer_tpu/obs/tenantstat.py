@@ -15,8 +15,8 @@ on the SAME ``t1``/``t2`` clock reads the pool's
 integer nanoseconds and partitioned with the residual assigned to the
 window's largest tenant — so the sum over tenants of attributed
 device time equals the pool's total with zero drift, dispatch after
-dispatch (``exactness()`` exposes both integer accumulators; the
-capacity bench and the unit test pin their equality).  Dollars are
+dispatch (``exactness()`` exposes both integer accumulators;
+``tests/test_tenant.py`` pins their equality).  Dollars are
 derived at scrape time — device-seconds × the
 :func:`~nnstreamer_tpu.obs.hwspec.chip_hour_price` figure
 (``NNS_TPU_CHIP_HOUR_USD`` overridable) — never stored, so a price
@@ -139,8 +139,8 @@ class TenantStats:
 
     def exactness(self, pool: str) -> Tuple[int, int]:
         """``(sum over tenants of attributed device-ns, pool total
-        device-ns)`` — equal by construction; the exactness test and
-        the capacity bench assert it stays that way."""
+        device-ns)`` — equal by construction; ``tests/test_tenant.py``
+        asserts it stays that way."""
         with self._lock:
             tenant_ns = sum(r.device_ns for (p, _t), r
                             in self._rows.items() if p == str(pool))

@@ -2,10 +2,8 @@
 
 PR 7 attributed dispatch *time* (host-prep / device / host-drain) but
 not *movement*: nothing could say where bytes cross the host/device
-boundary or how many crossings a frame pays, even though the composite
-bench's latency floor is host-roundtrip-dominated (ROADMAP item 3).
-This module is the measurement substrate the device-resident-dataflow
-rework will be judged against.
+boundary or how many crossings a frame pays.  This module is the
+measurement the device-resident dataflow is judged against.
 
 Every host→device and device→host crossing at the jax seams records
 into the process-wide :data:`LEDGER`:
@@ -42,8 +40,7 @@ trace ``xfer`` sub-spans via the trace dicts the context carries.
 
 The whole subsystem obeys the global observability kill switch
 (``NNS_TPU_OBS_DISABLE``, :func:`nnstreamer_tpu.obs.hooks.obs_disabled`)
-and can be toggled programmatically with :func:`set_enabled` — the
-on/off A/B the transfer bench gates the <3% overhead claim with.
+and can be toggled programmatically with :func:`set_enabled`.
 """
 
 from __future__ import annotations
@@ -151,7 +148,7 @@ class TransferLedger:
                direction: Optional[str] = None,
                reason: Optional[str] = None) -> Tuple[int, int]:
         """(count, bytes) summed over rows matching the given labels —
-        the bench/test accounting helper."""
+        the accounting helper of the tests and ``chip_smoke.py``."""
         count = nbytes = 0
         with self._lock:
             for (pl, _src, d, r), row in self._rows.items():

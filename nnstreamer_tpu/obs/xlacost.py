@@ -2,17 +2,15 @@
 
 The observability stack (PRs 4–8) can say *where* time goes — host
 phases, device phases, transfers — but not whether the device time is
-any *good*: ``bench.py`` computed FLOPs/MFU/roofline one-shot from
-``compiled.cost_analysis()`` and none of it reached the registry.  This
-module makes model efficiency first-class telemetry:
+any *good*.  This module makes model efficiency first-class
+telemetry:
 
 - **Capture** — :func:`capture` is called at the existing
   ``_compile`` / ``_compile_batched`` seams in ``filters/jax_xla.py``
   with the jit *lowering* of every executable.  ``Lowered.
   cost_analysis()`` runs XLA's HLO cost analysis without paying a
-  second device compile (measured: ~1 ms vs a full recompile), and its
-  flops / "bytes accessed" figures are the same computation-intrinsic
-  numbers the bench's one-shot roofline reads.  Rows are keyed
+  second device compile, and its flops / "bytes accessed" figures are
+  computation-intrinsic.  Rows are keyed
   ``(source, bucket)`` — ``source`` is the model name, ``bucket`` the
   micro-batch bucket (0 for the single-frame executable) — and a
   recompile (reshape/reload) overwrites its row: the gauges always
@@ -57,8 +55,8 @@ ACTIVE = not _hooks.DISABLED
 def cost_of(stage) -> dict:
     """The raw ``cost_analysis()`` dict of a jax ``Lowered`` /
     ``Compiled`` stage, list-unwrapped; ``{}`` when the backend doesn't
-    support cost analysis.  The one extraction helper ``bench.py`` and
-    the capture seam share (satellite: one source of truth)."""
+    support cost analysis.  The capture seam's one extraction
+    helper."""
     try:
         ca = stage.cost_analysis()
     except Exception:  # noqa: BLE001 - backend-dependent API surface

@@ -13,11 +13,11 @@ everything that makes two compiles interchangeable::
      jax version, jaxlib version, backend platform)
 
 A fresh process/host with a warm cache *deserializes* the executable
-instead of tracing and building it — measured 10-80x cheaper on the
-bench models — and every load is counted into CompileStats under the
-new ``persist_hit`` kind, so the cold-start win is an exportable
-number (``nns_compiles_total{kind="persist_hit"}``) the
-``bench.py --lifecycle`` gate asserts against its own ground truth.
+instead of tracing and building it, and every load is counted into
+CompileStats under the ``persist_hit`` kind, so the cold-start win is
+an exportable number (``nns_compiles_total{kind="persist_hit"}``) that
+``tests/test_lifecycle.py::test_persistent_cache_hits_and_counts``
+holds to the executables actually loaded.
 
 Failure policy: the cache can only ever make things faster, never
 wronger or broken.  A corrupt/truncated/version-skewed entry fails the
@@ -53,7 +53,7 @@ _warned_dirs: set = set()
 
 class CacheStats:
     """Process-wide persistent-cache accounting, pulled like every
-    other collected stat (the lifecycle bench asserts
+    other collected stat (``tests/test_lifecycle.py`` asserts
     ``hits == executables loaded``)."""
 
     def __init__(self):

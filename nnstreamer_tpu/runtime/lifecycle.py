@@ -21,9 +21,8 @@ of the serving pool:
   executable compiled and first-called) while the old executable keeps
   serving; :meth:`VersionManager.swap` flips atomically at a *window
   boundary* (the batcher's flush serialization lock) — zero dropped
-  frames, and the measured flip stall is a pointer swap bounded well
-  under one window deadline (:attr:`VersionManager.last_swap_stall_s`,
-  gated by ``bench.py --lifecycle``).
+  frames (``tests/test_lifecycle.py``), and the flip stall is a pointer
+  swap under that lock (:attr:`VersionManager.last_swap_stall_s`).
 
 - **Canarying with automatic verdict**: ``canary=<tag>:1/N``
   (pool-level ``tensor_filter`` property, or the ``canary`` actuator)

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives for this checkout.
 
-One rule, used by ``chip_smoke.py`` and ``bench.py``: when
+One rule, used by ``chip_smoke.py`` and ``benchmark/run.py``: when
 ``JAX_COMPILATION_CACHE_DIR`` is set the operator has placed the cache
 and JAX reads the variable itself, so nothing is set in code; otherwise
 the cache is ``<checkout>/.jax_cache`` (git-ignored).  The path is part

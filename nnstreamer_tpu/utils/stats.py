@@ -306,11 +306,12 @@ class DispatchStats:
     This is the denominator-side witness of the fusion work
     (runtime/fusion.py): a fused transform→filter→decoder window is
     exactly ONE ``filter`` launch, while the unfused pipeline pays one
-    launch per stage.  ``bench.py --composite`` gates
-    ``dispatches_per_frame`` on a delta of :attr:`total` over a counted
-    number of windows — which only works if every site that hands a
-    program to XLA bumps the counter, so keep the call sites in sync
-    with the ``site`` names above.  One short lock per dispatch; a
+    launch per stage.  ``benchmark/readers/dispatches_per_window.py``
+    and ``tests/test_fusion.py::test_fused_window_is_one_dispatch`` read
+    a delta of the sites' sum over a counted number of windows — which
+    only works if every site that hands a program to XLA bumps the
+    counter, so keep the call sites in sync with the ``site`` names
+    above.  One short lock per dispatch; a
     dispatch costs orders of magnitude more than the bump."""
 
     def __init__(self):
@@ -331,12 +332,12 @@ class DispatchStats:
             return dict(self._sites)
 
     def reset(self) -> None:
-        """Tests/bench only."""
+        """Tests only."""
         with self._lock:
             self._sites.clear()
 
 
-#: process-wide dispatch accounting (bench gate: dispatches_per_frame)
+#: process-wide dispatch accounting (the benchmark's dispatches_per_window)
 DISPATCH_STATS = DispatchStats()
 
 

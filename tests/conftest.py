@@ -1,6 +1,6 @@
 """Test harness config: run on CPU with 8 virtual devices so multi-chip
 sharding paths are exercised without TPU hardware (the driver separately
-dry-runs the multichip path; bench.py runs on the real chip)."""
+dry-runs the multichip path; the benchmark runs on the real chip)."""
 
 import os
 import sys

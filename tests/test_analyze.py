@@ -1448,7 +1448,7 @@ def test_bus_post_vs_remove_watch_race():
         # a delivery that STARTED before the removal may still be
         # draining the old snapshot; one more post must not see it
         bus.post(Message(MessageKind.ELEMENT, "after-remove"))
-        assert not any(m.src == "after-remove" for m in late_calls), \
+        assert not any(m.source == "after-remove" for m in late_calls), \
             "handler called by a post issued after remove_watch"
     stop.set()
     for t in posters:

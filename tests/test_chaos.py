@@ -527,11 +527,35 @@ def _query_client_pipe(host, port, **kw):
     p = Pipeline(name="chaos-qp")
     src = AppSrc(name="src", spec=SPEC, max_buffers=256)
     kw.setdefault("timeout", 10000)
+    kw.setdefault("connect_type", "inproc")
     cli = make("tensor_query_client", el_name="cli", host=host, port=port,
-               connect_type="inproc", **kw)
+               **kw)
     snk = AppSink(name="out", max_buffers=256)
     p.add(src, cli, snk).link(src, cli, snk)
     return p, src, cli, snk
+
+
+def _pump_closed_loop(src, cli, snk, n, outstanding):
+    """Push ``n`` frames (value = pts = index) with at most
+    ``outstanding`` unanswered, so that every frame reaches the wire
+    (a burst would be shed at max-request before a fault plan ever saw
+    it) and a fault schedule counted in frames is deterministic.
+    Returns what was delivered once every frame is delivered, timed out
+    or dropped."""
+    got, sent = [], 0
+    deadline = time.monotonic() + 60
+    while len(got) + cli.timeouts + cli.dropped < n and \
+            time.monotonic() < deadline:
+        while sent < n and sent - len(got) - cli.timeouts \
+                - cli.dropped < outstanding:
+            src.push_buffer(Buffer.of(
+                np.full((1, 4), float(sent), np.float32), pts=sent))
+            sent += 1
+        b = snk.pull(timeout=0.25)
+        if b is not None:
+            got.append(b)
+    assert sent == n
+    return got
 
 
 class TestQueryFaults:
@@ -589,23 +613,7 @@ class TestQueryFaults:
                 chaos="seed=4;disconnect:every=9,dir=tx")
             n = 24
             with p:
-                # closed-loop pacing (in-flight stays under
-                # max-request): every frame actually reaches the wire,
-                # so the every=9 disconnect schedule is deterministic
-                got = []
-                deadline = time.monotonic() + 60
-                sent = 0
-                while len(got) + cli.timeouts + cli.dropped < n and \
-                        time.monotonic() < deadline:
-                    while sent < n and sent - len(got) - cli.timeouts \
-                            - cli.dropped < 3:
-                        src.push_buffer(Buffer.of(
-                            np.full((1, 4), float(sent), np.float32),
-                            pts=sent))
-                        sent += 1
-                    b = snk.pull(timeout=0.25)
-                    if b is not None:
-                        got.append(b)
+                got = _pump_closed_loop(src, cli, snk, n, 3)
                 src.end_of_stream()
                 assert p.wait_eos(timeout=30)
                 got.extend(iter(lambda: snk.pull(timeout=0.1), None))
@@ -634,22 +642,7 @@ class TestQueryFaults:
                 chaos="seed=9;drop:every=7,dir=tx")
             n = 21
             with p:
-                # closed-loop pacing so every frame reaches the wire
-                # (a burst would be shed at max-request before the
-                # fault plan ever saw it)
-                got = 0
-                sent = 0
-                deadline = time.monotonic() + 60
-                while got + cli.timeouts + cli.dropped < n and \
-                        time.monotonic() < deadline:
-                    while sent < n and \
-                            sent - got - cli.timeouts - cli.dropped < 2:
-                        src.push_buffer(Buffer.of(
-                            np.full((1, 4), float(sent), np.float32),
-                            pts=sent))
-                        sent += 1
-                    if snk.pull(timeout=0.25) is not None:
-                        got += 1
+                got = len(_pump_closed_loop(src, cli, snk, n, 2))
                 src.end_of_stream()
                 assert p.wait_eos(timeout=30)
                 got += sum(1 for _ in iter(
@@ -711,6 +704,58 @@ class TestQueryFaults:
                     np.full((1, 4), 2.0 * float(b.pts), np.float32))
         finally:
             srv.stop()
+
+    @pytest.mark.parametrize("plan, symptom", [
+        ("seed=5;partition:ms=300,every=10,match=cli", "timeouts"),
+        ("seed=6;corrupt:every=5,dir=tx,match=cli", "bad_frames"),
+        ("seed=7;reorder:every=4,dir=tx,match=cli", "timeouts"),
+    ], ids=["partition", "corrupt", "reorder"])
+    def test_wire_fault_over_tcp_accounts_every_frame(self, plan,
+                                                      symptom):
+        """The wire faults the tests above do not drive end to end,
+        over a loopback socket (there are no wire bytes to corrupt on
+        the inproc transport): the client reaches EOS, every frame sent
+        is delivered or counted (timed out, or dropped at max-request),
+        what is delivered pairs with its own request, and the fault
+        shows in a counter of the link (a request the server refused as
+        corrupt, or one overtaken by a later request, times out)."""
+        from nnstreamer_tpu.filters.custom import register_custom_easy
+        from nnstreamer_tpu.obs.metrics import REGISTRY
+
+        register_custom_easy("chaos_wire_x2", lambda xs: [xs[0] * 2.0],
+                             in_spec=SPEC, out_spec=SPEC)
+        srv = Pipeline(name="chaos-wire-srv")
+        qsrc = make("tensor_query_serversrc", el_name="qsrc",
+                    connect_type="tcp", host="127.0.0.1", port=0, id=95)
+        flt = make("tensor_filter", el_name="f", framework="custom-easy",
+                   model="chaos_wire_x2")
+        qsink = make("tensor_query_serversink", el_name="qsink", id=95)
+        srv.add(qsrc, flt, qsink).link(qsrc, flt, qsink)
+        n = 24
+        with srv:
+            p, src, cli, snk = _query_client_pipe(
+                "127.0.0.1", qsrc.port, connect_type="tcp", timeout=600,
+                max_request=3,
+                caps="other/tensors,format=static,num_tensors=1,"
+                     "dimensions=4:1,types=float32")
+            with p:
+                installed = chaos.install_plan(FaultPlan.parse(plan))
+                got = _pump_closed_loop(src, cli, snk, n, 3)
+                # the drain to EOS is not itself under the fault
+                chaos.uninstall_plan()
+                src.end_of_stream()
+                assert p.wait_eos(timeout=30)
+                got.extend(iter(lambda: snk.pull(timeout=0.1), None))
+                links = [r for r in REGISTRY.snapshot()["links"]
+                         if r["link"] in ("cli", "qsrc")]
+        assert installed.total_injected > 0
+        assert len(got) + cli.timeouts + cli.dropped >= n
+        assert got, "the stream did not live through the fault"
+        for b in got:
+            np.testing.assert_array_equal(
+                b.tensors[0].np(),
+                np.full((1, 4), 2.0 * float(b.pts), np.float32))
+        assert sum(r[symptom] for r in links) > 0
 
 
 # -- self-healing links (mqtt + edge pub/sub) ---------------------------------

@@ -2,7 +2,7 @@
 TPU, and its section functions — the very ones the chip run calls, with
 their sizes as arguments — pass at a toy size with the Pallas kernels
 interpreted, so the control flow is proven before chip time is spent.
-Plus the compile-cache placement rule both it and ``bench.py`` use.
+Plus the compile-cache placement rule both it and the benchmark use.
 """
 
 import os

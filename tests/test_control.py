@@ -519,6 +519,121 @@ def test_controller_cooldown_no_target_and_guard_outcomes():
     w2.stop()
 
 
+def _series_snap(kind, families, pools=None):
+    """One watch snapshot: ``families`` maps a family name to its
+    ``(labels, value)`` samples."""
+    return {"pools": pools or [], "metrics": {
+        name: {"name": name, "kind": kind, "help": "",
+               "samples": [{"labels": labels, "value": value,
+                            "name": name + ("_bucket" if "le" in labels
+                                            else "")}
+                           for labels, value in samples]}
+        for name, samples in families.items()}}
+
+
+@pytest.mark.parametrize("fault", ["window-collapse", "slo-burn",
+                                   "breaker-stuck"])
+def test_playbook_mends_the_fault_its_rule_names(fault):
+    """Fault, alert, actuation, knob back where it belongs, one case a
+    remediation beside the stalled window above: a window steered down
+    to one frame a dispatch (the rule sees dispatches == frames and the
+    playbook reverts the knob), the same under an SLO (the burn rule
+    steps the window back open and tightens the shed ramp), and a
+    breaker left open (the rule forces the half-open probe).  The
+    series the rules read are fed by hand on a fake clock, labelled as
+    the live target exports them; every decision is in the audit ring
+    and the exported counter alike."""
+    p, e = _pool_pipe("mend", slo_ms=50.0)
+    p.start()
+    before = _counter_total()
+    try:
+        entry = e["flt"].pool
+        pool = {"pool": entry.label()}
+        for act in entry.actuators().values():
+            act.cooldown_s = 0.0
+        if fault == "breaker-stuck":
+            pol = RetryPolicy(name="lnk-stuck", fail_threshold=2,
+                              open_s=30.0)
+            pol.actuators()["breaker"].cooldown_s = 0.0
+            pol.failure(RuntimeError("x"))
+            pol.failure(RuntimeError("x"))
+            assert pol.state == OPEN
+        else:
+            entry.actuators()["max-batch"].actuate(1.0)
+            assert entry.batcher.max_batch == 1
+        link = {"link": "lnk-stuck", "peer": "p", "kind": "edge"}
+        rules, playbooks, snap = {
+            "window-collapse": (
+                [AlertRule(name="dispatch-amplification",
+                           kind="threshold",
+                           metric="nns_pool_dispatches_total",
+                           per="nns_pool_frames_total", op=">=",
+                           value=0.7)],
+                [Playbook(name="widen-window",
+                          rule="dispatch-amplification", kind="pool",
+                          actuator="max-batch", action="revert",
+                          cooldown_s=0.1)],
+                lambda t: _series_snap("counter", {
+                    "nns_pool_dispatches_total": [(pool, 40.0 * t)],
+                    "nns_pool_frames_total": [(pool, 40.0 * t)]})),
+            "slo-burn": (
+                [AlertRule(name="slo-burn", kind="slo_burn",
+                           metric="nns_admission_latency_seconds",
+                           fast_s=3.0, slow_s=10.0, budget=0.05,
+                           burn=2.0)],
+                [Playbook(name="widen-window", rule="slo-burn",
+                          kind="pool", actuator="max-batch",
+                          action="step", value=3.0, cooldown_s=0.1),
+                 Playbook(name="tighten-admission", rule="slo-burn",
+                          kind="pool", actuator="ramp-start",
+                          action="set", value=0.5, cooldown_s=0.1)],
+                # every observation between 100 ms and 1 s: over the
+                # pool's 50 ms
+                lambda t: _series_snap("histogram", {
+                    "nns_admission_latency_seconds": [
+                        (dict(pool, le=le), 50.0 * t * over)
+                        for le, over in (("0.01", 0), ("0.1", 0),
+                                         ("1", 1), ("+Inf", 1))]},
+                    pools=[{"pool": entry.label(),
+                            "admission": {"slo_ms": 50.0}}])),
+            "breaker-stuck": (
+                [AlertRule(name="breaker-open", kind="threshold",
+                           metric="nns_edge_breaker_state", op=">=",
+                           value="open")],
+                [Playbook(name="redial-link", rule="breaker-open",
+                          kind="link", actuator="breaker", action="set",
+                          value=1.0, cooldown_s=0.1)],
+                lambda t: _series_snap("gauge", {
+                    "nns_edge_breaker_state": [(link, 2.0)]})),
+        }[fault]
+        clock = {"t": 0}
+        w = Watch(rules=rules, interval_s=0.02, source=lambda: [
+            {"endpoint": "local", "error": None,
+             "snap": snap(clock["t"])}])
+        ctl = Controller(playbooks=playbooks, watch=w, interval_s=0.02)
+        for t in range(1, 7):
+            clock["t"] = t
+            w.sample_once(float(t))
+        assert any(a["rule"] == rules[0].name and a["firing"]
+                   for a in w.alerts())
+        decisions = ctl.tick()
+        assert {(d["playbook"], d["outcome"]) for d in decisions} == {
+            (pb.name, "reverted" if pb.action == "revert" else "applied")
+            for pb in playbooks}
+        if fault == "breaker-stuck":
+            assert pol.state == HALF_OPEN
+        else:
+            assert entry.batcher.max_batch == 4
+        if fault == "slo-burn":
+            assert entry.admission.ramp_start == pytest.approx(0.5)
+        assert ctl.actions_total == len(ctl.audit) == len(playbooks)
+        assert _counter_total() - before == ctl.actions_total
+        ctl.stop()
+        w.stop()
+    finally:
+        p.stop()
+
+
 def test_controller_strictly_inert_when_disabled(monkeypatch):
     p, e = _pool_pipe("inert")
     p.start()

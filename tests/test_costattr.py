@@ -1,6 +1,5 @@
 """Performance observability (ISSUE 7): dispatch cost attribution,
-compile & executable-cache telemetry, and the continuous-bench
-regression gate.
+and compile & executable-cache telemetry.
 
 - phase-split exactness: host-prep + device + host-drain partitions the
   dispatch at shared clock reads, and prep + device IS the recorded
@@ -9,15 +8,12 @@ regression gate.
   ``_compile`` / ``_compile_batched`` calls across the cold, reshape,
   reload and bucket paths;
 - executable-cache export: a warm re-run scrapes ZERO new misses;
-- ``nns_bench_diff`` verdicts (pass / regression / missing-baseline)
-  against golden history/baseline fixtures;
 - the admission controller's p99 derives from the registry's exported
   latency histogram (private window only as detached-registry
   fallback).
 """
 
 import io
-import json
 import threading
 import time
 
@@ -29,7 +25,6 @@ from nnstreamer_tpu.elements.basic import AppSink, AppSrc, Queue
 from nnstreamer_tpu.elements.filter import TensorFilter
 from nnstreamer_tpu.filters.api import FilterProps
 from nnstreamer_tpu.filters.jax_xla import JaxXlaFilter, register_model
-from nnstreamer_tpu.obs import benchgate
 from nnstreamer_tpu.obs.metrics import (
     ADMISSION_LATENCY_BUCKETS,
     REGISTRY,
@@ -366,104 +361,7 @@ def test_pool_admission_feeds_registry_histogram():
     assert counts and max(counts) >= 8
 
 
-# -- bench history + regression gate -----------------------------------------
-
-
-def _history_line(scenario="batching", **scalars):
-    base = {"value": 4.5, "dispatch_reduction": 8.0,
-            "coalescing": True}
-    base.update(scalars)
-    return {"scenario": scenario, "time": 1.0, "git_sha": "deadbeef",
-            "unit": "x", "scalars": base,
-            "registry_digest": "sha256:0"}
-
-
-def _baseline_doc():
-    return {"scenario": "batching", "metrics": {
-        "value": {"baseline": 4.5, "tolerance": 0.5,
-                  "direction": "higher"},
-        "dispatch_reduction": {"baseline": 8.0, "tolerance": 0.5},
-        "coalescing": {"baseline": 1, "tolerance": 0.0},
-    }}
-
-
-def test_bench_diff_verdicts(tmp_path):
-    hist = tmp_path / "hist.jsonl"
-    basef = tmp_path / "base.json"
-    basef.write_text(json.dumps(_baseline_doc()))
-
-    # missing history record
-    out = io.StringIO()
-    rc = benchgate.main(["--history", str(hist), "--scenario",
-                         "batching", "--baseline", str(basef)], out=out)
-    assert rc == 2 and "missing-baseline" in out.getvalue()
-
-    # pass
-    with open(hist, "a") as f:
-        f.write(json.dumps(_history_line()) + "\n")
-    out = io.StringIO()
-    rc = benchgate.main(["--history", str(hist), "--scenario",
-                         "batching", "--baseline", str(basef),
-                         "--json"], out=out)
-    doc = json.loads(out.getvalue())
-    assert rc == 0 and doc["verdict"] == "pass"
-    assert all(c["ok"] for c in doc["checks"])
-
-    # doctored regression record (latest wins)
-    with open(hist, "a") as f:
-        f.write(json.dumps(_history_line(
-            value=1.0, dispatch_reduction=1.0)) + "\n")
-    out = io.StringIO()
-    rc = benchgate.main(["--history", str(hist), "--scenario",
-                         "batching", "--baseline", str(basef),
-                         "--json"], out=out)
-    doc = json.loads(out.getvalue())
-    assert rc == 1 and doc["verdict"] == "regression"
-    bad = {c["metric"] for c in doc["checks"] if not c["ok"]}
-    assert bad == {"value", "dispatch_reduction"}
-
-    # missing baseline file
-    rc = benchgate.main(["--history", str(hist), "--scenario",
-                         "batching", "--baseline",
-                         str(tmp_path / "nope.json")], out=io.StringIO())
-    assert rc == 2
-
-
-def test_bench_diff_lower_is_better_and_raw_result_baseline(tmp_path):
-    hist = tmp_path / "hist.jsonl"
-    with open(hist, "a") as f:
-        f.write(json.dumps(_history_line(
-            scenario="edge", value=120.0)) + "\n")
-    base = tmp_path / "base.json"
-    # lower-is-better metric (e.g. RTT µs): 120 vs 100 at 10% -> fail
-    base.write_text(json.dumps({"metrics": {
-        "value": {"baseline": 100.0, "tolerance": 0.10,
-                  "direction": "lower"}}}))
-    rc = benchgate.main(["--history", str(hist), "--scenario", "edge",
-                         "--baseline", str(base)], out=io.StringIO())
-    assert rc == 1
-    # a raw bench result as baseline: its `value` compared higher-better
-    base.write_text(json.dumps({"value": 110.0, "unit": "x"}))
-    rc = benchgate.main(["--history", str(hist), "--scenario", "edge",
-                         "--baseline", str(base)], out=io.StringIO())
-    assert rc == 0
-
-
-def test_append_history_record_shape(tmp_path):
-    hist = tmp_path / "h.jsonl"
-    result = {"metric": "m", "value": 2.5, "unit": "x", "frames": 64,
-              "coalescing": True, "note": "text dropped",
-              "curve": {"nested": "dropped"}}
-    rec = benchgate.append_history("batching", result, path=str(hist))
-    assert rec["scenario"] == "batching"
-    assert rec["scalars"] == {"value": 2.5, "frames": 64,
-                              "coalescing": True}
-    assert rec["registry_digest"].startswith("sha256:")
-    # round-trips through the reader, unparseable lines skipped
-    with open(hist, "a") as f:
-        f.write("{truncated\n")
-    assert benchgate.latest_record(str(hist), "batching")["scalars"] \
-        == rec["scalars"]
+# -- rendering -----------------------------------------------------------------
 
 
 def test_nns_top_renders_dev_host_and_compile(capsys):

@@ -284,6 +284,7 @@ def test_link_byte_counters_exact():
     assert cli_row["rtt"]["count"] == n
     assert cli_row["rtt"]["mean_us"] > 0
     assert cli_row["inflight"] == 0 and cli_row["timeouts"] == 0
+    assert cli_row["reconnects"] == 0
     # the server side mirrors the link (rx of queries, tx of replies)
     srv_row = rows[("query-server", "qsrc")]
     assert srv_row["rx_bytes"] == tx_truth

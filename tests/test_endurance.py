@@ -49,8 +49,8 @@ class TestSoak:
             "soak_scale", lambda xs: [xs[0] * 2.0],
             in_spec=spec, out_spec=spec)
         # no XLA elements: the soak exercises the RUNTIME (threads,
-        # queues, pads) hermetically — device throughput is bench.py's
-        # job
+        # queues, pads) hermetically — device throughput is the
+        # benchmark's job
         p = parse_launch(
             "appsrc name=src max_buffers=256 ! "
             "tensor_filter framework=custom-easy model=soak_scale ! "

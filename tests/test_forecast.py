@@ -132,7 +132,7 @@ def test_trending_away_never_fires():
 def test_mad_gate_suppresses_insignificant_trend():
     """A slope buried in the residual noise band must not fire even
     when its extrapolation crosses inside the horizon — this is the
-    zero-false-positive property the capacity bench pins end to end."""
+    zero-false-positive property."""
     noise = [0.0, 5.0, -5.0, 3.0, -4.0, 4.0, -3.0, 2.0] * 2
     pts = [(float(t), 0.02 * t + noise[t]) for t in range(16)]
     fit = fit_trend(pts)

@@ -107,6 +107,20 @@ class TestFusionCorrectness:
         assert not np.allclose(fused.tensors[0].np(), raw.tensors[0].np())
 
 
+def _two_boxes(x):
+    """A deterministic toy detector: 2 boxes per frame."""
+    import jax.numpy as jnp
+
+    b = x.shape[0]
+    boxes = jnp.tile(jnp.asarray(
+        [[0.1, 0.1, 0.5, 0.5], [0.4, 0.4, 0.9, 0.9]],
+        jnp.float32)[None], (b, 1, 1))
+    classes = jnp.tile(jnp.asarray([1.0, 2.0])[None], (b, 1))
+    scores = jnp.tile(jnp.asarray([0.9, 0.8])[None], (b, 1))
+    num = jnp.full((b,), 2, jnp.int32)
+    return boxes, classes, scores, num
+
+
 class TestDecoderOverlayFusion:
     """Filter→decoder fusion (round-3 verdict #10): the bounding-box
     device overlay compiles INTO the filter's program — one dispatch
@@ -115,20 +129,7 @@ class TestDecoderOverlayFusion:
 
     @pytest.fixture
     def detect_model(self):
-        import jax.numpy as jnp
-
-        def fn(x):
-            # deterministic toy detector: 2 boxes per frame
-            b = x.shape[0]
-            boxes = jnp.tile(jnp.asarray(
-                [[0.1, 0.1, 0.5, 0.5], [0.4, 0.4, 0.9, 0.9]],
-                jnp.float32)[None], (b, 1, 1))
-            classes = jnp.tile(jnp.asarray([1.0, 2.0])[None], (b, 1))
-            scores = jnp.tile(jnp.asarray([0.9, 0.8])[None], (b, 1))
-            num = jnp.full((b,), 2, jnp.int32)
-            return boxes, classes, scores, num
-
-        name = register_model("fusion_detect", fn,
+        name = register_model("fusion_detect", _two_boxes,
                               in_shapes=[(2, 16, 16, 3)],
                               in_dtypes=np.float32)
         yield name
@@ -555,3 +556,105 @@ class TestFusedChainPersistCache:
         sp.close()
         after = compilecache.CACHE_STATS.snapshot()
         assert after["stores"] == before["stores"]
+
+
+# -- one dispatch a window ----------------------------------------------------
+
+#: windows streamed before the count opens, and windows counted
+WARMUP, WINDOWS = 2, 6
+
+_TRANSFORM = ("tensor_transform name=norm mode=arithmetic "
+              "option=typecast:float32,add:-127.5,div:127.5 ! ")
+_DECODER = ("tensor_decoder name=overlay mode=bounding_boxes "
+            "option1=mobilenet-ssd-postprocess option4=16:16 option5=16:16 "
+            "option7=device ! ")
+
+
+def _toy_detector(name):
+    register_model(name, _two_boxes, in_shapes=[(4, 16, 16, 3)],
+                   in_dtypes=np.float32)
+    return [np.full((4, 16, 16, 3), k, np.uint8) for k in range(2)]
+
+
+def _toy_classifier(name):
+    import jax.numpy as jnp
+
+    w = np.linspace(-1, 1, 8 * 3 * 5, dtype=np.float32).reshape(-1, 5)
+    register_model(name, lambda p, x: jnp.dot(x.reshape(4, -1), p),
+                   params=w, in_shapes=[(4, 8, 3)], in_dtypes=np.float32)
+    return [np.full((4, 8, 3), k, np.uint8) for k in range(2)]
+
+
+def _toy_stateful(name):
+    from tests.test_stateful_filter import ONES, _register
+
+    _register(name)
+    return [ONES]
+
+
+#: the chain shapes of the benchmark's four cells (the SSD line, the ViT
+#: line, the dsv2 line, the SSD line over a mesh): model maker, what
+#: stands before and after the filter, the filter's own properties
+_CELL_CHAINS = {
+    "transform-filter-decoder": (_toy_detector, _TRANSFORM, _DECODER, ""),
+    "transform-filter": (_toy_classifier, _TRANSFORM, "", ""),
+    "stateful-filter": (_toy_stateful, "", "", ""),
+    "transform-filter-decoder-mesh2": (_toy_detector, _TRANSFORM, _DECODER,
+                                       " mesh=data:2"),
+}
+
+
+@pytest.mark.parametrize("chain", sorted(_CELL_CHAINS))
+def test_fused_window_is_one_dispatch(chain):
+    """What ``dispatches_per_window`` = 1.00 on the chip rests on,
+    counted as ``benchmark/readers/dispatches_per_window.py`` counts
+    it: every launch site of ``DISPATCH_STATS``, after the warm-up
+    windows, over windows that were fenced.  The sink's callback runs
+    on the streaming thread, so at the render of window k exactly the
+    dispatches of windows 0..k have been counted."""
+    import jax
+
+    from nnstreamer_tpu.obs.transfer import LEDGER
+    from nnstreamer_tpu.runtime import parse_launch
+    from nnstreamer_tpu.utils.stats import COMPILE_STATS, DISPATCH_STATS
+
+    make_model, pre, post, mesh = _CELL_CHAINS[chain]
+    if mesh and jax.device_count() < 2:
+        pytest.skip("needs two (virtual) devices")
+    model = "fusion_" + chain.replace("-", "_")
+    frames = make_model(model)
+    marks = []      # one reading a window, taken as it is rendered
+
+    def on_window(buf):
+        jax.block_until_ready([t.jax() for t in buf.tensors])
+        marks.append((DISPATCH_STATS.snapshot(),
+                      COMPILE_STATS.total_compiles,
+                      LEDGER.totals(reason="input")[0]
+                      + LEDGER.totals(reason="drain")[0]))
+
+    try:
+        p = parse_launch(
+            f"device_src name=src num_buffers={WARMUP + WINDOWS} ! {pre}"
+            f"tensor_filter name=net framework=jax-xla model={model}{mesh}"
+            f" ! {post}tensor_sink name=out")
+        p["src"].frames, p["src"].pool_size = frames, len(frames)
+        p["out"].connect(on_window)
+        with p:
+            assert p.wait_eos(timeout=120)
+            segments = [(s.filter, s.transforms, s.decoder)
+                        for s in p.fused_segments]
+            assert (p["net"].subplugin._mesh is not None) == bool(mesh)
+    finally:
+        unregister_model(model)
+    assert len(marks) == WARMUP + WINDOWS
+    (d0, c0, x0), (d1, c1, x1) = marks[WARMUP - 1], marks[-1]
+    delta = {site: d1[site] - d0.get(site, 0) for site in d1
+             if d1[site] != d0.get(site, 0)}
+    # all sites: a transform or a decoder that launched its own program
+    # would show beside the filter's
+    assert delta == {"filter": WINDOWS}
+    assert c1 == c0, "a program was built inside the counted windows"
+    assert x1 == x0, "a counted window crossed between host and device"
+    # what stands around the filter went into its one segment
+    assert segments == ([("net", ("norm",), "overlay" if post else None)]
+                        if pre else [])

@@ -3,16 +3,11 @@
 Pins the SEMANTICS of the latency/throughput numbers, not just their
 signs: the ``latency_us``/``throughput`` element props (parity:
 /root/reference/tests/nnstreamer_latency/unittest_latency.cc and the
-property contract in tensor_filter_common.c:982-996) and the bench's
-probe-bracketing derivation (bench.derive_latency_stats) that turns
-raw e2e timings + transport-probe floors into the published
-p50/p99/floor report.
+property contract in tensor_filter_common.c:982-996).
 """
 
-import numpy as np
 import pytest
 
-from nnstreamer_tpu.bench import derive_latency_stats
 from nnstreamer_tpu.utils.stats import InvokeStats
 
 # -- InvokeStats props ---------------------------------------------------------
@@ -66,63 +61,3 @@ class TestInvokeStatsProps:
         assert first is not None and first > 0
         # unchanged latency: below threshold, no re-report
         assert st.latency_to_report() is None
-
-
-# -- bench derivation ----------------------------------------------------------
-
-
-class TestDeriveLatencyStats:
-    def test_pure_device_no_link(self):
-        # zero-floor probes: device excess IS the latency
-        lats = [2.0, 2.2, 1.8, 2.0, 2.1, 1.9, 2.0, 2.0]
-        r = derive_latency_stats(lats, [0.0] * len(lats))
-        assert r["p99_frame_latency_note"] == "device-dominated"
-        assert r["tail_excluded_frames"] == 0
-        assert r["p50_device_ms"] == pytest.approx(2.0, abs=0.01)
-        assert r["p50_frame_latency_ms"] == pytest.approx(2.0, abs=0.01)
-        assert r["latency_probe_floor_ms"] == 0.0
-
-    def test_link_dominated_annotation(self):
-        # 90 ms of link under every frame, ~2 ms device time: the floor
-        # exceeds device p50 → link-dominated, and device percentiles
-        # recover the ~2 ms
-        floors = [90.0] * 10
-        lats = [92.0, 92.1, 91.9, 92.0, 92.2, 91.8, 92.0, 92.1, 91.9,
-                92.0]
-        r = derive_latency_stats(lats, floors)
-        assert r["p99_frame_latency_note"] == "link-dominated"
-        assert r["p50_device_ms"] == pytest.approx(2.0, abs=0.1)
-        assert r["latency_probe_floor_ms"] == pytest.approx(90.0)
-        # raw percentiles keep the transport (honest reporting)
-        assert r["p50_frame_latency_ms"] == pytest.approx(92.0, abs=0.1)
-
-    def test_burst_frames_excluded_from_device_tail(self):
-        # one frame hit by a 500 ms burst that neither probe saw:
-        # excluded from device percentiles, counted
-        floors = [10.0] * 10
-        lats = [12.0] * 9 + [510.0]
-        r = derive_latency_stats(lats, floors)
-        assert r["tail_excluded_frames"] == 1
-        assert r["p99_device_ms"] == pytest.approx(2.0, abs=0.1)
-        # raw p99 still shows the burst (nothing hidden)
-        assert r["p99_frame_latency_ms"] > 400.0
-
-    def test_exclusion_threshold_is_3x_median_plus_1ms(self):
-        floors = [0.0] * 9
-        # median excess = 2.0 → threshold 7.0: 6.9 kept, 7.1 dropped
-        lats = [2.0] * 7 + [6.9, 7.1]
-        r = derive_latency_stats(lats, floors)
-        assert r["tail_excluded_frames"] == 1
-
-    def test_negative_excess_clamped(self):
-        # probe slower than the frame (jitter): excess clamps at 0
-        r = derive_latency_stats([5.0, 5.0, 5.0, 5.0],
-                                 [6.0, 6.0, 6.0, 6.0])
-        assert r["p50_device_ms"] == 0.0
-        assert r["tail_excluded_frames"] == 0
-
-    def test_floor_is_median_of_probes(self):
-        lats = [10.0] * 5
-        floors = [1.0, 2.0, 3.0, 4.0, 100.0]
-        r = derive_latency_stats(lats, floors)
-        assert r["latency_probe_floor_ms"] == pytest.approx(3.0)

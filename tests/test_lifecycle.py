@@ -279,6 +279,73 @@ def test_pool_reload_hot_swaps_with_no_frame_loss():
     assert len(MODEL_POOL) == 0
 
 
+def test_hot_swap_under_load_drops_no_frame_and_flips_once():
+    """The swap lands between two windows while three streams keep
+    pushing: every frame pushed is delivered, in its stream's order,
+    and a stream's outputs change version once and never back."""
+    pipes = []
+    for i in range(3):
+        p, e = _pool_pipe(f"lc-load-{i}", batch=4, timeout_ms=2.0)
+        p.start()
+        pipes.append((p, e))
+    try:
+        entry = pipes[0][1]["flt"].pool
+        flowing, swapped = threading.Event(), threading.Event()
+        pushed = [0, 0, 0]
+
+        def produce(k, src):
+            tail = 0        # frames pushed once the swap has returned
+            while tail < 16 and pushed[k] < 4000:
+                src.push_buffer(Buffer.of(np.zeros(SHAPE, np.float32),
+                                          pts=pushed[k]), timeout=10.0)
+                pushed[k] += 1
+                tail += swapped.is_set()
+                if pushed[k] == 8:
+                    flowing.set()
+                time.sleep(0.001)
+            src.end_of_stream()
+
+        def consume(sink, out):
+            while True:
+                b = sink.pull(timeout=0.2)
+                if b is not None:
+                    out.append(b)
+                elif done.is_set():
+                    return
+
+        done = threading.Event()
+        outs = [[], [], []]
+        threads = [threading.Thread(target=produce, args=(k, e["src"]))
+                   for k, (_p, e) in enumerate(pipes)]
+        drains = [threading.Thread(target=consume,
+                                   args=(e["sink"], outs[k]))
+                  for k, (_p, e) in enumerate(pipes)]
+        for t in threads + drains:
+            t.start()
+        assert flowing.wait(timeout=30)
+        entry.reload_model("_t_lc_v2", version="v2")   # mid-stream
+        swapped.set()
+        for t in threads:
+            t.join(timeout=60)
+            assert not t.is_alive()
+        for p, _e in pipes:
+            assert p.wait_eos(timeout=30)
+        done.set()
+        for t in drains:
+            t.join(timeout=30)
+            assert not t.is_alive()
+        for k, got in enumerate(outs):
+            assert [b.pts for b in got] == list(range(pushed[k]))
+            vals = _vals(got)
+            flip = vals.index(3.0)
+            assert flip > 0
+            assert vals == [1.0] * flip + [3.0] * (len(vals) - flip)
+        assert entry.lifecycle.swaps == 1
+    finally:
+        for p, _e in pipes:
+            p.stop()
+
+
 def test_reload_event_routes_through_pool_and_respects_updatable():
     from nnstreamer_tpu.runtime.events import Event, EventKind
 
@@ -396,6 +463,64 @@ def test_canary_rollback_restores_baseline_only_serving():
             _push_n(e["src"], 4)
             got = _pull_all(e["sink"], 4)
             assert set(_vals(got)) == {1.0}  # baseline x+1 on zeros
+    finally:
+        for p, _e in pipes:
+            p.stop()
+
+
+def test_canary_comparator_alert_rolls_back_through_the_playbook():
+    """The automatic verdict, end to end but for the clock: while the
+    canary's latency sits beside the baseline's nothing fires; at four
+    times the baseline's the comparator rule fires and the rollback
+    playbook, aimed by the alert's own pool label, ends the canary."""
+    from nnstreamer_tpu.obs.control import Controller, Playbook
+    from nnstreamer_tpu.obs.watch import AlertRule, Watch
+
+    pipes = _canary_rig(n_pipes=2, canary="next:1/2")
+    try:
+        entry = pipes[0][1]["flt"].pool
+        entry.reload_model("_t_lc_v2", version="v2")
+        lc = entry.lifecycle
+        assert lc.canary_active
+        for act in lc.actuators().values():
+            act.cooldown_s = 0.0
+        ratio = {"v": 1.0}
+
+        def gauge(name, value):
+            return {"name": name, "kind": "gauge", "help": "",
+                    "samples": [{"labels": {"pool": entry.label()},
+                                 "value": value}]}
+
+        w = Watch(rules=[AlertRule(
+            name="canary-regressed", kind="threshold",
+            metric="nns_model_canary_latency_us",
+            per="nns_model_baseline_latency_us", op=">", value=3.0,
+            severity="critical")], interval_s=0.02, source=lambda: [
+            {"endpoint": "local", "error": None, "snap": {
+                "pools": [], "metrics": {
+                    "nns_model_canary_latency_us": gauge(
+                        "nns_model_canary_latency_us",
+                        100.0 * ratio["v"]),
+                    "nns_model_baseline_latency_us": gauge(
+                        "nns_model_baseline_latency_us", 100.0)}}}])
+        ctl = Controller(playbooks=[Playbook(
+            name="canary-rollback", rule="canary-regressed",
+            kind="model", actuator="rollback", action="set", value=1.0,
+            cooldown_s=0.1)], watch=w, interval_s=0.02)
+        for t in (1.0, 2.0, 3.0):
+            assert w.sample_once(t) == []
+        assert ctl.tick() == [] and lc.canary_active
+        ratio["v"] = 4.0
+        fired = w.sample_once(4.0) + w.sample_once(5.0)
+        assert [ev["rule"] for ev in fired] == ["canary-regressed"]
+        assert [(d["playbook"], d["outcome"]) for d in ctl.tick()] \
+            == [("canary-rollback", "applied")]
+        assert not lc.canary_active and lc.rollbacks == 1
+        for _p, e in pipes:
+            _push_n(e["src"], 4)
+            assert set(_vals(_pull_all(e["sink"], 4))) == {1.0}
+        ctl.stop()
+        w.stop()
     finally:
         for p, _e in pipes:
             p.stop()

@@ -217,7 +217,7 @@ class TestKernelEligibility:
         # ViT-B/16 as the benchmark runs it, and in float32
         ((64, 196, 2304), 12, "bfloat16", True, False),
         ((64, 196, 2304), 12, "float32", True, False),
-        # whole-lane heads; bench.py's ViT (256 positions, heads of 128)
+        # whole-lane heads; chip_smoke.py's ViT (256 positions, heads of 128)
         # is short too although the blockwise kernel could take it
         ((2, 16, 768), 2, "bfloat16", True, False),
         ((64, 256, 1536), 4, "bfloat16", True, True),

@@ -174,6 +174,7 @@ def test_tick_attributes_samples_through_registry():
             time.sleep(0.01)
         sampled = sp.tick()
         assert sampled >= 1 and sp.ticks_total == 1
+        assert sp.errors_total == 0
         assert sp.element_samples().get(("pipeA", "q0"), 0) >= 1
         labels = {label for label, _ in sp._table}
         assert "pipeA:q0" in labels  # pipeline:element, not tid-...
@@ -306,7 +307,7 @@ def test_run_wait_split_on_crafted_element():
 
 
 def test_cpu_sum_stays_within_process_time():
-    """The attribution-exactness invariant the --hostprof bench gates:
+    """The attribution-exactness invariant:
     summed per-element thread CPU can never exceed the process-wide
     ``time.process_time()`` delta over the same window."""
     before = {(r["pipeline"], r["element"]): r["cpu_s"]

@@ -136,6 +136,57 @@ def test_concurrent_streams_fifo_pts_and_cross_stream_coalescing():
     assert len(MODEL_POOL) == 0
 
 
+def test_mesh_pool_window_crosses_streams_and_splits_evenly():
+    """A pool over ``mesh=data:2``: with coalescing paused, two frames
+    from each of two streams park in ONE window; resumed, it is one
+    dispatch of four frames, two a shard, no pad slot — and each
+    stream gets its own frames back."""
+    import jax
+
+    from nnstreamer_tpu.obs.meshstat import MESH_STATS
+
+    if jax.device_count() < 2:
+        pytest.skip("needs two (virtual) devices")
+    pipes = []
+    for s in range(2):
+        p = Pipeline(name=f"p_mesh{s}")
+        src = AppSrc(name="src", spec=SPEC, max_buffers=8)
+        flt = TensorFilter(name="net", framework="jax-xla",
+                           model="_t_serving", batch=4,
+                           batch_timeout_ms=50.0, batch_buckets="4",
+                           share_model=True, mesh="data:2")
+        sink = AppSink(name="out", max_buffers=8)
+        p.add(src, flt, sink).link(src, flt, sink)
+        p.start()
+        pipes.append((p, src, flt, sink))
+    try:
+        entry = pipes[0][2].pool
+        row0 = dict(MESH_STATS.get("_t_serving") or {})
+        pause = entry.actuators()["coalescing"]
+        pause.actuate(0.0)
+        for i in range(2):
+            for s, (_p, src, _f, _k) in enumerate(pipes):
+                src.push_buffer(_frame(s, i))
+        deadline = time.monotonic() + 10
+        while entry.batcher.pending < 4 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert entry.batcher.pending == 4
+        pause.revert()
+        for s, (_p, _src, _f, sink) in enumerate(pipes):
+            _check_stream(_pull_all(sink, 2), s)
+        st = entry.stats
+        assert (st.total_invoke_num, st.total_frame_num) == (1, 4)
+        assert st.avg_stream_occupancy == 2.0
+        row = MESH_STATS.get("_t_serving")
+        assert row["shards"] == 2 and row["imbalance"] == 0.0
+        assert row["dispatches"] - row0.get("dispatches", 0) == 1
+        assert row["frames"] - row0.get("frames", 0) == 4
+        assert row["pad_slots"] - row0.get("pad_slots", 0) == 0
+    finally:
+        for p, *_ in pipes:
+            p.stop()
+
+
 # -- pool lifecycle edges ----------------------------------------------------
 
 

@@ -81,7 +81,7 @@ def _drain(sink):
         out.append(b)
 
 
-# -- the miniature cascade: bench.py's topology at test scale ----------------
+# -- the miniature cascade --------------------------------------------------
 
 
 def _cascade(tag, split, frames_n):

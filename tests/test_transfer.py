@@ -5,8 +5,8 @@ weight-placement accounting, pad-slot crossings, residency tagging and
 the tracer's crossings-per-frame figure, Chrome-trace xfer sub-spans,
 device-memory accounting (CPU-backend graceful fallback included),
 flight-recorder trigger paths (element error, breaker open, admission
-hard-shed, /dump endpoint), the snapshot-v6 shape, nns-top XFER/DEVICE
-rendering, and the nns-bench-diff ``--against`` record-vs-record mode.
+hard-shed, /dump endpoint), the snapshot-v6 shape and nns-top
+XFER/DEVICE rendering.
 """
 
 import json
@@ -491,59 +491,3 @@ def test_nns_top_renders_xfer_and_devicemem():
     row = [ln for ln in out.splitlines() if "net" in ln][0]
     # 640 B over 1 s, 10 crossings over 10 frames
     assert "640" in row and "1.00" in row
-
-
-# -- nns-bench-diff --against -------------------------------------------------
-
-
-def test_bench_diff_against_record(tmp_path, capsys):
-    from nnstreamer_tpu.obs.benchgate import main as diff_main
-
-    hist = tmp_path / "h.jsonl"
-    recs = [
-        {"scenario": "s", "git_sha": "aaa111", "time": 1,
-         "scalars": {"value": 10.0, "fps": 100.0}},
-        {"scenario": "s", "git_sha": "bbb222", "time": 2,
-         "scalars": {"value": 9.95, "fps": 99.0}},
-        {"scenario": "other", "git_sha": "ccc333", "time": 3,
-         "scalars": {"value": 1.0}},
-    ]
-    with open(hist, "w") as f:
-        for r in recs:
-            f.write(json.dumps(r) + "\n")
-    # latest (bbb222) vs first (index 0): within default tolerance
-    rc = diff_main(["--history", str(hist), "--scenario", "s",
-                    "--against", "0"])
-    assert rc == 0
-    # sha-prefix selector + explicit --record, tight tolerance → fail
-    rc = diff_main(["--history", str(hist), "--scenario", "s",
-                    "--against", "aaa", "--record", "-1",
-                    "--tolerance", "0.001"])
-    assert rc == 1
-    # selector that matches nothing → missing baseline (exit 2)
-    rc = diff_main(["--history", str(hist), "--scenario", "s",
-                    "--against", "deadbeef"])
-    assert rc == 2
-    # --baseline and --against are mutually exclusive
-    with pytest.raises(SystemExit):
-        diff_main(["--history", str(hist), "--scenario", "s",
-                   "--against", "0", "--baseline", "x.json"])
-    capsys.readouterr()
-
-
-def test_bench_diff_exact_direction():
-    """direction=exact regresses on a move EITHER way — the
-    crossings-per-frame gate (an analytically-known figure, so an
-    increase is as much a regression as a drop)."""
-    from nnstreamer_tpu.obs.benchgate import diff
-
-    base = {"metrics": {"value": {"baseline": 1.0, "tolerance": 0.0,
-                                  "direction": "exact"}}}
-
-    def verdict(v):
-        return diff({"scenario": "s", "scalars": {"value": v}},
-                    base)["verdict"]
-
-    assert verdict(1.0) == "pass"
-    assert verdict(2.0) == "regression"   # extra crossing slipped in
-    assert verdict(0.0) == "regression"   # crossings no longer counted

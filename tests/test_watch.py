@@ -175,6 +175,66 @@ def test_default_pack_lints_clean():
         assert lint_rule(r) == [], (r.name, lint_rule(r))
 
 
+_LINK = {"kind": "query", "link": "qcli", "peer": "srv"}
+
+
+def _rtt_snap(fast, slow):
+    """``nns_edge_rtt_seconds`` with ``fast`` round trips under 1 ms and
+    ``slow`` ones between 10 and 100 ms, cumulative."""
+    return _hist_snap((fast, fast, fast + slow, fast + slow),
+                      name="nns_edge_rtt_seconds", labels=_LINK)
+
+
+#: rule of the default pack -> (snapshot while healthy, snapshot once
+#: the fault shows), each a function of the tick: one symptom per fault
+#: class the pack must alarm on (a lost request, a reconnect, a frame
+#: the codec refused, a slow link, a slow pool, an errored dispatch)
+_SYMPTOMS = {
+    "edge-timeouts": (
+        lambda t: _counter_snap("nns_edge_timeouts_total", 0.0, _LINK),
+        lambda t: _counter_snap("nns_edge_timeouts_total", 3.0, _LINK)),
+    "edge-reconnect-flap": (
+        lambda t: _counter_snap("nns_edge_reconnects_total", 0.0, _LINK),
+        lambda t: _counter_snap("nns_edge_reconnects_total", 1.0, _LINK)),
+    "edge-bad-frames": (
+        lambda t: _counter_snap("nns_edge_bad_frames_total", 0.0, _LINK),
+        lambda t: _counter_snap("nns_edge_bad_frames_total", 2.0, _LINK)),
+    "edge-rtt-drift": (
+        lambda t: _rtt_snap(10 * t, 0),
+        lambda t: _rtt_snap(10 * 14, 10 * (t - 14))),
+    "pool-latency-drift": (
+        lambda t: _gauge_snap("nns_pool_latency_us", 100.0 + t % 3,
+                              {"pool": "jax-xla:m"}),
+        lambda t: _gauge_snap("nns_pool_latency_us", 80000.0,
+                              {"pool": "jax-xla:m"})),
+    "element-errors": (
+        lambda t: _counter_snap("nns_element_errors_total", 0.0,
+                                {"pipeline": "p", "element": "net"}),
+        lambda t: _counter_snap("nns_element_errors_total", 1.0,
+                                {"pipeline": "p", "element": "net"})),
+}
+
+
+@pytest.mark.parametrize("rule", sorted(_SYMPTOMS))
+def test_default_pack_alarms_on_the_symptom_and_not_before(rule):
+    """The pack as shipped, nothing tuned: fourteen healthy ticks on a
+    fake clock fire nothing, and the first ticks that show the symptom
+    fire the rule that names it."""
+    healthy, faulty = _SYMPTOMS[rule]
+    state = {"t": 0}
+    w = Watch(rules=default_rules(), registry=MetricsRegistry(),
+              source=_src(lambda: (healthy if state["t"] <= 14
+                                   else faulty)(state["t"])))
+    for t in range(1, 15):
+        state["t"] = t
+        assert w.sample_once(float(t)) == [], f"alarm at healthy tick {t}"
+    fired = []
+    for t in range(15, 18):
+        state["t"] = t
+        fired += [ev["rule"] for ev in w.sample_once(float(t))]
+    assert rule in fired
+
+
 # -- series store -------------------------------------------------------------
 
 
@@ -491,15 +551,15 @@ def test_histogram_bucket_layout_change_resyncs_clean():
 # -- slo_burn rules -----------------------------------------------------------
 
 
-def _hist_snap(cums, pools=None):
+def _hist_snap(cums, pools=None, name="nns_admission_latency_seconds",
+               labels=None):
     samples = []
     for le, c in zip(("0.001", "0.01", "0.1", "+Inf"), cums):
-        samples.append({"labels": {"pool": "p", "le": le}, "value": c,
-                        "name": "nns_admission_latency_seconds_bucket"})
+        samples.append({"labels": dict(labels or {"pool": "p"}, le=le),
+                        "value": c, "name": name + "_bucket"})
     return {"pools": pools or [],
-            "metrics": {"nns_admission_latency_seconds": {
-                "name": "nns_admission_latency_seconds",
-                "kind": "histogram", "help": "", "samples": samples}}}
+            "metrics": {name: {"name": name, "kind": "histogram",
+                               "help": "", "samples": samples}}}
 
 
 def test_burn_histogram_mode_with_pool_slo_hint():

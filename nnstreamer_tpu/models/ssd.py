@@ -143,11 +143,15 @@ def nms_single(boxes, scores, max_out: int = 100, iou_thresh: float = 0.5,
 
 
 def _preselect_top_k(scores, k: int):
-    """Candidate preselect: on TPU use the hardware-accelerated
-    approximate top-k (ApproxTopK HLO — avoids a full sort of the anchor
-    axis; recall ≥ the default 0.95 target, fine for a preselect that is
-    already a heuristic); elsewhere exact ``lax.top_k``.  The backend
-    check (``ops.kernels.on_tpu``, shared with the Pallas kernels)
+    """Candidate preselect: ``approx_max_k`` where programs compile for a
+    TPU, exact ``lax.top_k`` elsewhere.  Both return their values in
+    non-increasing order, which ``_best_pairs`` relies on.  At the SSD's
+    3000 anchors and k=128 the v5e's compiler lowers the approximate form
+    to one full sort of the anchor axis (0.18 ms a window of 256; a recall
+    of 0.95 at that k needs more partial results than there are inputs):
+    the values are the exact top-k's, and equal values come in an order
+    of its own, not lower index first (PERF.md section 6, PR 30).  The
+    backend check (``ops.kernels.on_tpu``, shared with the Pallas kernels)
     happens at trace time, matching where the computation compiles for
     the common (default-device) case."""
     from ..ops.kernels import on_tpu
@@ -157,9 +161,68 @@ def _preselect_top_k(scores, k: int):
     return jax.lax.top_k(scores, k)
 
 
+_PRE_TOPK = 128  # shared candidates per image, whoever selects them
+
+
+def _best_pairs(s, M: int):
+    """Exact top-``M`` (candidate, class) pairs of ``s`` (k, C-1), flat
+    candidate-major, from the first ``min(M, k)`` rows alone.  Rows arrive
+    in non-increasing order of their best class (``_preselect_top_k``
+    returns them sorted), so a pair in row r >= M has M pairs before it —
+    the best of each earlier row — with a value at least its own and a
+    lower flat index, and ``lax.top_k`` breaks ties by lower index: it is
+    never among the M.  Values and indices are those of a top-k over all
+    k rows (tests/test_ssd_candidates.py holds the lemma to that)."""
+    return jax.lax.top_k(s[:min(M, s.shape[0])].reshape(-1), M)
+
+
+def _suppress_candidates(b, s, max_out: int, iou_thresh: float,
+                         score_thresh: float, fill: float):
+    """Fast matrix NMS over one image's k candidates: ``b`` (k,4) boxes
+    and ``s`` (k,C-1) foreground scores, in the preselect's order.  The
+    part every caller shares, wherever its candidates came from."""
+    k, num_fg = s.shape
+    overlap = (_iou_matrix(b) > iou_thresh) \
+        & ~jnp.eye(k, dtype=bool)                        # (k,k), no self
+    # Suppression ON DEMAND: a full (k,k,C) "who beats whom" tensor would
+    # dominate the postprocess, but only pairs that can reach the output
+    # need their verdict.  Preselect the top 4·max_out (candidate, class)
+    # pairs by raw score and test just those M pairs against all k
+    # candidates — an (M,k) compare instead of (k,k,C), ~100× less work.
+    # Exactness: fast-NMS suppression is pairwise (no cascade), so each
+    # selected pair's verdict is exact; the output can only differ if
+    # >3·max_out of the best raw pairs are all suppressed — then slots
+    # beyond are filled from the next-best survivors of the M pool.
+    # tiny inputs: fewer slates than max_out
+    m = min(max_out, k * num_fg)
+    M = min(4 * max_out, k * num_fg)
+    raw, fidx = _best_pairs(s, M)                        # (M,) pair preselect
+    cand = fidx // num_fg                                # (M,) candidate row
+    ccls = fidx % num_fg                                 # (M,) class column
+    sj = s.T[ccls]                                   # (M,k) class scores ∀j
+    # beats: overlapping candidate with strictly higher class score;
+    # EXACT ties broken by candidate rank (without a tie-break the
+    # symmetric comparison keeps both duplicates of an equal-score pair)
+    beats = (sj > raw[:, None]) | \
+        ((sj == raw[:, None]) & (jnp.arange(k)[None, :] < cand[:, None]))
+    suppressed = jnp.any(overlap[cand] & beats, axis=-1)       # (M,)
+    keep = (raw > score_thresh) & ~suppressed
+    kept = jnp.where(keep, raw, jnp.asarray(fill, s.dtype))
+    top_scores, sel = jax.lax.top_k(kept, m)             # final slate from M
+    out_b = b[cand[sel]]
+    out_s = top_scores
+    out_c = (ccls[sel] + 1).astype(jnp.int32)            # back to class ids
+    if m < max_out:                                      # fixed-shape contract
+        pad = max_out - m
+        out_b = jnp.pad(out_b, ((0, pad), (0, 0)))
+        out_s = jnp.pad(out_s, (0, pad), constant_values=fill)
+        out_c = jnp.pad(out_c, (0, pad))
+    return out_b, out_s, out_c
+
+
 def batched_nms(boxes, class_scores, max_out: int = 100,
                 iou_thresh: float = 0.5, score_thresh: float = 0.25,
-                pre_topk: int = 128, fill: float = 0.0):
+                pre_topk: int = _PRE_TOPK, fill: float = 0.0):
     """Class-aware matrix NMS over (A,4) boxes and (A,C) scores (class 0
     = background, skipped as in the reference decoder).  Returns
     (boxes (max_out,4), scores (max_out,), classes (max_out,) int32).
@@ -176,54 +239,18 @@ def batched_nms(boxes, class_scores, max_out: int = 100,
     Scores need not be probabilities: any monotonic score space works
     (ranking and thresholding are order-only).  When scoring in logit
     space pass ``score_thresh`` in the same space and ``fill=-inf`` so
-    suppressed/padded slots stay below every survivor (``ssd_detect_apply``
-    does this to skip materializing a full-resolution sigmoid)."""
+    suppressed/padded slots stay below every survivor.
+
+    This entry takes an ASSEMBLED score tensor (YOLO, any caller that has
+    one); ``ssd_detect_apply`` picks the same candidates from its heads'
+    maps without assembling it, and both share ``_suppress_candidates``."""
     with jax.named_scope("topk"):
         fg = class_scores[:, 1:]                     # drop background
-        num_fg = fg.shape[-1]
         k = min(pre_topk, boxes.shape[0])
         _, idx = _preselect_top_k(fg.max(axis=-1), k)  # shared candidates
     with jax.named_scope("nms"):
-        b = boxes[idx]                                   # (k,4)
-        s = fg[idx]                                      # (k,C-1)
-        overlap = (_iou_matrix(b) > iou_thresh) \
-            & ~jnp.eye(k, dtype=bool)                    # (k,k), no self
-        # Suppression ON DEMAND: a full (k,k,C) "who beats whom" tensor is
-        # the dominant postprocess cost (≈1.8 ms/batch at k=128, C=91 on
-        # v5e), but only pairs that can reach the output need their verdict.
-        # Preselect the top 4·max_out (candidate, class) pairs by raw score
-        # and test just those M pairs against all k candidates — an (M,k)
-        # compare instead of (k,k,C), ~100× less work.  Exactness: fast-NMS
-        # suppression is pairwise (no cascade), so each selected pair's
-        # verdict is exact; the output can only differ if >3·max_out of the
-        # best raw pairs are all suppressed — then slots beyond are filled
-        # from the next-best survivors of the M pool.
-        flat = s.reshape(-1)                             # candidate-major
-        # tiny inputs: fewer slates than max_out
-        m = min(max_out, flat.shape[0])
-        M = min(4 * max_out, flat.shape[0])
-        raw, fidx = jax.lax.top_k(flat, M)               # (M,) pair preselect
-        cand = fidx // num_fg                            # (M,) candidate row
-        ccls = fidx % num_fg                             # (M,) class column
-        sj = s.T[ccls]                               # (M,k) class scores ∀j
-        # beats: overlapping candidate with strictly higher class score;
-        # EXACT ties broken by candidate rank (without a tie-break the
-        # symmetric comparison keeps both duplicates of an equal-score pair)
-        beats = (sj > raw[:, None]) | \
-            ((sj == raw[:, None]) & (jnp.arange(k)[None, :] < cand[:, None]))
-        suppressed = jnp.any(overlap[cand] & beats, axis=-1)   # (M,)
-        keep = (raw > score_thresh) & ~suppressed
-        kept = jnp.where(keep, raw, jnp.asarray(fill, s.dtype))
-        top_scores, sel = jax.lax.top_k(kept, m)         # final slate from M
-        out_b = b[cand[sel]]
-        out_s = top_scores
-        out_c = (ccls[sel] + 1).astype(jnp.int32)        # back to class ids
-        if m < max_out:                                  # fixed-shape contract
-            pad = max_out - m
-            out_b = jnp.pad(out_b, ((0, pad), (0, 0)))
-            out_s = jnp.pad(out_s, (0, pad), constant_values=fill)
-            out_c = jnp.pad(out_c, (0, pad))
-        return out_b, out_s, out_c
+        return _suppress_candidates(boxes[idx], fg[idx], max_out,
+                                    iou_thresh, score_thresh, fill)
 
 
 # -- model -------------------------------------------------------------------
@@ -257,19 +284,10 @@ def ssd_mobilenet_v2_init(key, num_classes: int = 91,
     return params
 
 
-def ssd_mobilenet_v2_apply(params: Params, x, train: bool = False,
-                           dtype=jnp.bfloat16, cls_dtype=jnp.float32):
-    """``x``: (N, H, W, 3) normalized NHWC → (loc (N,A,4), cls (N,A,C))
-    raw head outputs in float32 (the "model output" schema the decoder layer
-    consumes, matching the reference's filter/decoder split).
-
-    ``cls_dtype`` controls the dtype of the big (N,A,C) class tensor only:
-    the fused detect path keeps it bfloat16 (halves the dominant HBM
-    traffic of the postprocess); the default float32 preserves the wire
-    schema for reference-shaped loc/cls pipelines.  loc stays float32
-    either way — box regression wants the mantissa, and at (N,A,4) it is
-    ~23× smaller than cls."""
-    num_classes = params["num_classes"]
+def _ssd_head_maps(params: Params, x, train: bool, dtype):
+    """Backbone, extras and heads: the six maps' raw head outputs where
+    the convolutions leave them, ``locs[i]`` (N,h,w,A·4) and ``clss[i]``
+    (N,h,w,A·C) with channel = anchor·C + class."""
     feat, taps = mobilenet_v2_backbone(
         params["backbone"], x, train=train, dtype=dtype, taps=(_TAP_BLOCK,))
     maps = [taps[0], feat]
@@ -282,16 +300,61 @@ def ssd_mobilenet_v2_apply(params: Params, x, train: bool = False,
     locs, clss = [], []
     with jax.named_scope("heads"):
         for fmap, head in zip(maps, params["heads"]):
-            n = fmap.shape[0]
-            loc = _conv_bn(head["loc"], fmap, stride=1, relu6=False,
-                           train=train, dtype=dtype)
-            cls = _conv_bn(head["cls"], fmap, stride=1, relu6=False,
-                           train=train, dtype=dtype)
-            locs.append(loc.reshape(n, -1, 4))
-            clss.append(cls.reshape(n, -1, num_classes))
+            locs.append(_conv_bn(head["loc"], fmap, stride=1, relu6=False,
+                                 train=train, dtype=dtype))
+            clss.append(_conv_bn(head["cls"], fmap, stride=1, relu6=False,
+                                 train=train, dtype=dtype))
+    return locs, clss
+
+
+def ssd_mobilenet_v2_apply(params: Params, x, train: bool = False,
+                           dtype=jnp.bfloat16, cls_dtype=jnp.float32):
+    """``x``: (N, H, W, 3) normalized NHWC → (loc (N,A,4), cls (N,A,C))
+    raw head outputs in float32 (the "model output" schema the decoder layer
+    consumes, matching the reference's filter/decoder split).
+
+    The (N,A,C) tensor is this entry's OUTPUT, so here it is assembled;
+    the fused detect path (``ssd_detect_apply``) never builds it.
+    ``cls_dtype`` is its dtype; loc is float32 — box regression wants the
+    mantissa, and at (N,A,4) it is ~23× smaller than cls."""
+    num_classes = params["num_classes"]
+    locs, clss = _ssd_head_maps(params, x, train, dtype)
+    n = x.shape[0]
+    with jax.named_scope("heads"):
+        locs = [loc.reshape(n, -1, 4) for loc in locs]
+        clss = [cls.reshape(n, -1, num_classes) for cls in clss]
     with jax.named_scope("decode"):
         return (jnp.concatenate(locs, axis=1).astype(jnp.float32),
                 jnp.concatenate(clss, axis=1).astype(cls_dtype))
+
+
+def _map_candidates(clss, num_classes: int, k: int):
+    """Candidates first, for ONE image: from the six class maps (h,w,A·C)
+    to the ``k`` candidates' anchor indices (k,) and foreground scores
+    (k,C-1) — value for value the ``idx`` and ``cls[:, 1:][idx]`` of the
+    assembled (A,C) tensor, which is never written.  Anchor index =
+    cell·A + anchor over the maps' cells in order (``ssd_anchors``)."""
+    A, C = _ANCHORS_PER_CELL, num_classes
+    with jax.named_scope("topk"):
+        # every anchor's best foreground class, reduced inside the A·C
+        # channels by anchor segment: one read of the heads' output.
+        # Static slices, not masks: a masked maximum fuses into the class
+        # convolution's epilogue on the v5e, and that convolution then
+        # rounds a few logits a window differently (PERF.md section 6)
+        best = jnp.concatenate([
+            jnp.stack([jnp.max(c[..., a * C + 1:(a + 1) * C], axis=-1)
+                       for a in range(A)], axis=-1).reshape(-1)
+            for c in clss])                               # (cells·A,)
+        _, idx = _preselect_top_k(best, k)                # shared candidates
+    with jax.named_scope("decode"):
+        # the candidates' cells' rows (the cell axis concatenates without
+        # reshaping the minor dimension), then each row's own anchor
+        rows = jnp.concatenate([c.reshape(-1, A * C) for c in clss])[idx // A]
+        s = rows[:, 1:C]
+        for a in range(1, A):       # static lane slices and a select
+            s = jnp.where((idx % A == a)[:, None],
+                          rows[:, a * C + 1:(a + 1) * C], s)
+    return idx, s
 
 
 def ssd_detect_apply(params: Params, x, anchors, max_out: int = 100,
@@ -302,23 +365,40 @@ def ssd_detect_apply(params: Params, x, anchors, max_out: int = 100,
     an argument (not a closure) so callers can device-place the weight
     pytree instead of baking it into the HLO as literals.
 
-    The postprocess runs in LOGIT space on the bfloat16 class tensor:
-    sigmoid is monotonic, so ranking/thresholding on raw logits (with the
-    threshold mapped through logit()) selects the same detections while
-    skipping a full-resolution (N,A,C) float32 sigmoid materialization —
-    the probability sigmoid is applied only to the ``max_out`` survivors.
-    Suppressed/padded slots carry -inf logits → exactly 0.0 scores, the
-    same contract as before."""
-    loc, cls = ssd_mobilenet_v2_apply(params, x, dtype=dtype,
-                                      cls_dtype=dtype)
+    The postprocess touches only candidates: the shared top-k runs on
+    each anchor's best class, reduced from the heads' maps where they lie,
+    and only the chosen cells' class rows are gathered and sliced to their
+    anchor (``_map_candidates``); the (N,A,C) tensor of the wire schema is
+    never assembled.  The detections are those of ``batched_nms`` over
+    the assembled tensors, to the bit.
+
+    It runs in LOGIT space on the bfloat16 class maps: sigmoid is
+    monotonic, so ranking/thresholding on raw logits (with the threshold
+    mapped through logit()) selects the same detections, and the
+    probability sigmoid is applied only to the ``max_out`` survivors.
+    Suppressed/padded slots carry -inf logits → exactly 0.0 scores."""
+    locs, clss = _ssd_head_maps(params, x, False, dtype)
+    n = x.shape[0]
     with jax.named_scope("decode"):
-        boxes = decode_boxes(loc, jnp.asarray(anchors))
+        # all A boxes, as ever: (N,A,4) is 1/23 of the class tensor, and
+        # decoding before the gather keeps the anchor table a constant
+        # operand, which XLA folds the coder's scales into — gathered
+        # anchors round differently in the last bit
+        loc = jnp.concatenate([m.reshape(n, -1, 4) for m in locs], axis=1)
+        boxes = decode_boxes(loc.astype(jnp.float32), jnp.asarray(anchors))
+    k = min(_PRE_TOPK, boxes.shape[1])
     lt = float(np.log(score_thresh / (1.0 - score_thresh)))
-    out_b, out_s, out_c = jax.vmap(
-        lambda b, s: batched_nms(b, s, max_out=max_out,
-                                 iou_thresh=iou_thresh,
-                                 score_thresh=lt,
-                                 fill=-np.inf))(boxes, cls)
+
+    # a Python int: the filter device-places the pytree, leaves and all
+    num_classes = int(params["num_classes"])
+
+    def per_image(boxes, clss):
+        idx, s = _map_candidates(clss, num_classes, k)
+        with jax.named_scope("nms"):
+            return _suppress_candidates(boxes[idx], s, max_out, iou_thresh,
+                                        lt, -np.inf)
+
+    out_b, out_s, out_c = jax.vmap(per_image)(boxes, clss)
     with jax.named_scope("nms"):
         return out_b, jax.nn.sigmoid(out_s.astype(jnp.float32)), out_c
 

@@ -252,7 +252,10 @@ class StatefulModelDef(ModelDef):
     their calls are fenced inside a set-up span of their own name.
     ``counters(state)`` picks the small unsigned counters the steps keep
     in the state; ``counter_units(state)`` maps a published counter to
-    ``(raw counter, what one count stands for)``."""
+    ``(raw counter, what one count stands for)``, or to a list of such
+    pairs that add up (a state of several kinds of cache publishes each
+    kind's bytes and their sum).  The state is any pytree of arrays:
+    its leaves may differ in shape from layer to layer."""
 
     def __init__(self, entries: Dict[str, Tuple[Callable, TensorsSpec]],
                  params: Any, init_state: Callable, name: str,
@@ -372,8 +375,11 @@ class _StateCell:
             value = int(value)
             gained[name] = (value - self._last.get(name, 0)) % (1 << 32)
             self._last[name] = value
-        for name, (raw_name, unit) in units.items():
-            gained[name] = gained.get(raw_name, 0) * unit
+        published = {
+            name: sum(gained.get(raw_name, 0) * unit for raw_name, unit
+                      in ([terms] if isinstance(terms, tuple) else terms))
+            for name, terms in units.items()}
+        gained.update(published)
         for name, n in gained.items():
             STATE_STATS.add(name, n)
 

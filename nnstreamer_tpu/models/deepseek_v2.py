@@ -13,7 +13,8 @@ that a chip is a device of the paper's device-limited routing) and
 vocabulary rows ``[vocab0, vocab0 + vocab)``.  It routes over ALL the
 published experts, computes the held experts' part for the tokens routed to them (no token is
 dropped and there is no capacity factor: tokens are sorted by expert and
-the product runs over blocks of one expert's rows each), adds what every
+the product runs over blocks of one expert's rows each; ``models/moe.py``,
+which ``smallthinker.py`` runs too), adds what every
 chip computes alike (the shared experts, the dense MLP) and passes that
 partial sum on; attention's output is the held heads' partial sum through
 their rows of ``W_o``; logits are over the slice.  Nothing stands in for
@@ -57,6 +58,7 @@ except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
 from ..ops.kernels import latent_decode_attention
+from . import moe
 
 Params = dict
 NEG = -1e30
@@ -246,20 +248,7 @@ def _rope(x, cos, sin):
 # -- small parts --------------------------------------------------------------
 
 
-def _rms(x, gain, eps):
-    x32 = x.astype(jnp.float32)
-    out = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (out * gain).astype(x.dtype)
-
-
-def _mm(x, w):
-    """``x @ w`` in the weights' type with float32 accumulation."""
-    return jnp.matmul(x, w, preferred_element_type=jnp.float32,
-                      precision=_precision(w))
-
-
-def _precision(w):
-    return lax.Precision.HIGHEST if w.dtype == jnp.float32 else None
+_rms, _mm, _precision = moe.rms, moe.mm, moe.precision
 
 
 def _mlp(p, x):
@@ -287,66 +276,13 @@ def route(cfg: DeepSeekV2Config, x, router):
     return idx.astype(jnp.int32), weight * cfg.routed_scaling_factor
 
 
-def _block_rows(n_tokens: int) -> int:
-    """Rows of one block of the grouped product: a block holds rows of
-    ONE expert, so a larger block reads that expert's weights for more
-    rows, and a smaller one pads less."""
-    return int(min(256, -(-n_tokens // 8) * 8))
-
-
 def dispatch(cfg: DeepSeekV2Config, idx, n_tokens: int):
-    """Sort the (token, expert) pairs that fall on a HELD expert by
-    expert and lay each expert's rows out in whole blocks.  Returns the
-    plan of the grouped product: per padded row the token it holds
-    (``n_tokens`` = none), per pair the row its result lands in (the
-    last row = none: an expert held elsewhere), per block its expert,
-    the number of blocks in use and the tokens each held expert got."""
-    k, held = cfg.num_experts_per_tok, cfg.experts
-    blk = _block_rows(n_tokens)
-    pairs = n_tokens * k
-    rows = -(-pairs // blk) * blk + held * blk
-    local = idx.reshape(-1) - cfg.expert0
-    local = jnp.where((local >= 0) & (local < held), local, held)
-    order = jnp.argsort(local, stable=True)
-    sorted_e = local[order]
-    counts = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
-    padded = (counts[:held] + blk - 1) // blk * blk
-    pad_end = jnp.cumsum(padded)
-    first = jnp.cumsum(counts) - counts           # of each expert, sorted
-    rank = jnp.arange(pairs, dtype=jnp.int32) - first[sorted_e]
-    here = sorted_e < held
-    dest_sorted = jnp.where(
-        here, (pad_end - padded)[jnp.minimum(sorted_e, held - 1)] + rank,
-        rows)
-    row_token = jnp.full((rows,), n_tokens, jnp.int32).at[dest_sorted].set(
-        (order // k).astype(jnp.int32), mode="drop")
-    dest = jnp.zeros((pairs,), jnp.int32).at[order].set(dest_sorted)
-    block_expert = jnp.minimum(jnp.searchsorted(
-        pad_end, jnp.arange(rows // blk, dtype=jnp.int32) * blk,
-        side="right"), held - 1).astype(jnp.int32)
-    return {"row_token": row_token, "dest": dest.reshape(n_tokens, k),
-            "block_expert": block_expert, "blocks": pad_end[-1] // blk,
-            "counts": counts[:held], "blk": blk, "rows": rows}
+    """The plan of the grouped product over the experts held here
+    (``models/moe.py`` ``dispatch``)."""
+    return moe.dispatch(idx, n_tokens, cfg.expert0, cfg.experts)
 
 
-def grouped_experts(p, x, plan):
-    """The held experts' MLPs on the rows ``plan`` lays out, a block (of
-    one expert's rows) at a time: ``[rows + 1, hidden]``, the last row
-    zero.  Only the blocks in use are computed, so the work follows the
-    tokens routed here, not the held experts."""
-    blk, rows = plan["blk"], plan["rows"]
-    x_pad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
-
-    def body(b, out):
-        e = plan["block_expert"][b]
-        tok = lax.dynamic_slice(plan["row_token"], (b * blk,), (blk,))
-        xb = x_pad[tok]
-        h = jax.nn.silu(_mm(xb, p["gate"][e])) * _mm(xb, p["up"][e])
-        ob = _mm(h.astype(x.dtype), p["down"][e]).astype(x.dtype)
-        return lax.dynamic_update_slice(out, ob, (b * blk, 0))
-
-    return lax.fori_loop(0, plan["blocks"], body,
-                         jnp.zeros((rows + 1, x.shape[1]), x.dtype))
+grouped_experts = moe.grouped_experts          # gated by SiLU, its default
 
 
 def moe_parts(cfg: DeepSeekV2Config, p, x):
@@ -361,8 +297,7 @@ def moe_parts(cfg: DeepSeekV2Config, p, x):
     with jax.named_scope("experts"):
         out = grouped_experts(p["experts"], x, plan)
     with jax.named_scope("combine"):
-        routed = jnp.sum(out[plan["dest"]].astype(jnp.float32)
-                         * weight[..., None], axis=1)
+        routed = moe.combine(out, plan, weight)
     with jax.named_scope("shared"):
         shared = _mlp(p["shared"], x)
     return routed, shared, plan["counts"]

@@ -1,0 +1,120 @@
+"""Routed experts as the token models run them (``deepseek_v2.py``,
+``smallthinker.py``): the plan that sorts (token, expert) pairs by
+expert, the product over blocks of one expert's rows, and the weighted
+sum back to tokens.  No token is dropped and there is no capacity
+factor; only the blocks in use are computed, so the work follows the
+tokens routed to the experts HELD here (``[expert0, expert0 +
+experts)`` of the router's width), not how many are held.
+
+What differs between the models is a parameter of the call: which
+experts are held, and the gated activation (``silu`` or ``relu``).  How
+a model routes (groups, scaling, normalisation, what the router reads)
+stays with the model.
+
+Also the small parts both models are made of: RMSNorm with float32
+statistics, and a product in the weights' type accumulated in float32.
+"""
+
+from __future__ import annotations
+
+try:
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+except ImportError:  # pragma: no cover
+    jax = jnp = lax = None
+
+
+def activation(name: str):
+    """The gate's nonlinearity of ``act(x W_gate) * (x W_up)``."""
+    try:
+        return {"silu": jax.nn.silu, "relu": jax.nn.relu}[name]
+    except KeyError:
+        raise ValueError(f"gated activation {name!r}: only silu and relu "
+                         "are written") from None
+
+
+def rms(x, gain, eps):
+    x32 = x.astype(jnp.float32)
+    out = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (out * gain).astype(x.dtype)
+
+
+def precision(w):
+    return lax.Precision.HIGHEST if w.dtype == jnp.float32 else None
+
+
+def mm(x, w):
+    """``x @ w`` in the weights' type with float32 accumulation."""
+    return jnp.matmul(x, w, preferred_element_type=jnp.float32,
+                      precision=precision(w))
+
+
+def block_rows(n_tokens: int) -> int:
+    """Rows of one block of the grouped product: a block holds rows of
+    ONE expert, so a larger block reads that expert's weights for more
+    rows, and a smaller one pads less."""
+    return int(min(256, -(-n_tokens // 8) * 8))
+
+
+def dispatch(idx, n_tokens: int, expert0: int, held: int):
+    """Sort the (token, expert) pairs of ``idx [n_tokens, k]`` that fall
+    on a HELD expert by expert and lay each expert's rows out in whole
+    blocks.  Returns the plan of the grouped product: per padded row the
+    token it holds (``n_tokens`` = none), per pair the row its result
+    lands in (the last row = none: an expert held elsewhere), per block
+    its expert, the number of blocks in use and the tokens each held
+    expert got."""
+    k = idx.shape[1]
+    blk = block_rows(n_tokens)
+    pairs = n_tokens * k
+    rows = -(-pairs // blk) * blk + held * blk
+    local = idx.reshape(-1) - expert0
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(local, stable=True)
+    sorted_e = local[order]
+    counts = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
+    padded = (counts[:held] + blk - 1) // blk * blk
+    pad_end = jnp.cumsum(padded)
+    first = jnp.cumsum(counts) - counts           # of each expert, sorted
+    rank = jnp.arange(pairs, dtype=jnp.int32) - first[sorted_e]
+    here = sorted_e < held
+    dest_sorted = jnp.where(
+        here, (pad_end - padded)[jnp.minimum(sorted_e, held - 1)] + rank,
+        rows)
+    row_token = jnp.full((rows,), n_tokens, jnp.int32).at[dest_sorted].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    dest = jnp.zeros((pairs,), jnp.int32).at[order].set(dest_sorted)
+    block_expert = jnp.minimum(jnp.searchsorted(
+        pad_end, jnp.arange(rows // blk, dtype=jnp.int32) * blk,
+        side="right"), held - 1).astype(jnp.int32)
+    return {"row_token": row_token, "dest": dest.reshape(n_tokens, k),
+            "block_expert": block_expert, "blocks": pad_end[-1] // blk,
+            "counts": counts[:held], "blk": blk, "rows": rows}
+
+
+def grouped_experts(p, x, plan, act: str = "silu"):
+    """The held experts' gated MLPs on the rows ``plan`` lays out, a
+    block (of one expert's rows) at a time: ``[rows + 1, hidden]``, the
+    last row zero.  Only the blocks in use are computed, so the work
+    follows the tokens routed here, not the held experts."""
+    blk, rows = plan["blk"], plan["rows"]
+    gate = activation(act)
+    x_pad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+
+    def body(b, out):
+        e = plan["block_expert"][b]
+        tok = lax.dynamic_slice(plan["row_token"], (b * blk,), (blk,))
+        xb = x_pad[tok]
+        h = gate(mm(xb, p["gate"][e])) * mm(xb, p["up"][e])
+        ob = mm(h.astype(x.dtype), p["down"][e]).astype(x.dtype)
+        return lax.dynamic_update_slice(out, ob, (b * blk, 0))
+
+    return lax.fori_loop(0, plan["blocks"], body,
+                         jnp.zeros((rows + 1, x.shape[1]), x.dtype))
+
+
+def combine(out, plan, weight):
+    """Each token's weighted sum of its pairs' rows, float32."""
+    return jnp.sum(out[plan["dest"]].astype(jnp.float32)
+                   * weight[..., None], axis=1)

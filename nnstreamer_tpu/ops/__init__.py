@@ -5,11 +5,12 @@ Where the reference hand-vectorizes with Orc SIMD kernels
 package holds hand-written TPU kernels for the ops worth owning below
 XLA: the streaming normalize/typecast prologue, the flash-attention
 block kernel behind long-context attention, whole-sequence attention
-for short sequences, and the one-pass decode attention over a latent
+for short sequences, the one-pass decode attention over a latent
+cache, and grouped-query decode attention over a ring or a dense K/V
 cache.  Every kernel has a jnp reference implementation; the first two
 say through their ``*_available`` rule when a caller should use it
-instead (and take it themselves), the last two refuse a shape they
-cannot take, ``short_attention`` with an ``*_available`` rule for the
+instead (and take it themselves), the others refuse a shape they
+cannot take, with an ``*_available`` or ``*_refusal`` rule for the
 caller to ask first.
 """
 
@@ -17,6 +18,9 @@ from .kernels import (
     flash_attention,
     flash_attention_available,
     flash_attention_reference,
+    gqa_decode_attention,
+    gqa_decode_attention_refusal,
+    gqa_decode_attention_reference,
     latent_decode_attention,
     latent_decode_attention_refusal,
     latent_decode_attention_reference,
@@ -35,4 +39,6 @@ __all__ = [
     "short_attention_reference",
     "latent_decode_attention", "latent_decode_attention_refusal",
     "latent_decode_attention_reference",
+    "gqa_decode_attention", "gqa_decode_attention_refusal",
+    "gqa_decode_attention_reference",
 ]

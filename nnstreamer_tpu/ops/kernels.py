@@ -1,5 +1,5 @@
 """Pallas TPU kernels: fused normalize/typecast, flash attention, short
-attention and latent decode attention.
+attention, latent decode attention and grouped-query decode attention.
 
 Parity/role:
 - ``scale_bias_cast`` is the tensor_transform arithmetic prologue
@@ -24,14 +24,21 @@ Parity/role:
   a stream over a latent cache (``models/deepseek_v2.py``'s decode
   step): one pass over the cache, blocks beyond a stream's position
   skipped.
+- ``gqa_decode_attention`` is grouped-query attention of one token a
+  stream over separate K and V caches (``models/smallthinker.py``'s
+  decode step), the cache a ring a stream wraps around or a dense array
+  it grows into: a block of K and of V is read once for all the query
+  heads of its group, and only the blocks that hold a position of the
+  stream's window are fetched.
 
 All compile natively on TPU (Mosaic) and run under the Pallas
 interpreter on CPU backends (tests).  ``scale_bias_cast`` and
 ``flash_attention`` take their jnp reference for a shape that does not
 meet the tiling constraints — lane dim a multiple of 128, sublane dim a
 multiple of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32
-of 1-byte elements); ``short_attention`` and ``latent_decode_attention``
-refuse a shape they cannot take and leave the choice to the caller.
+of 1-byte elements); ``short_attention``, ``latent_decode_attention``
+and ``gqa_decode_attention`` refuse a shape they cannot take and leave
+the choice to the caller.
 Either way the ``*_available`` / ``*_refusal`` predicates are the whole
 eligibility rule, so the fallback is a decision made here, never an
 exception caught somewhere.
@@ -552,3 +559,173 @@ def latent_decode_attention(q, cache, positions, rank: int, scale: float):
         interpret=_interpret(),
     )(positions.astype(jnp.int32), q, cache.astype(q.dtype))
     return out[:, :held, :rank]
+
+
+# -- grouped-query decode attention -------------------------------------------
+
+
+def gqa_decode_attention_refusal(q_shape, k_shape, v_shape, window: int,
+                                 block: int = 1024) -> Optional[str]:
+    """Why :func:`gqa_decode_attention` cannot take these shapes, or
+    None: ``q [B, groups, heads a group, d]`` beside ``k`` and ``v``
+    ``[B, groups, positions, d]``, ``d`` whole lanes, and a block of
+    whole lanes that divides the caches' positions."""
+    if len(q_shape) != 4 or len(k_shape) != 4 \
+            or tuple(k_shape) != tuple(v_shape) \
+            or tuple(q_shape[:2]) != tuple(k_shape[:2]) \
+            or q_shape[3] != k_shape[3]:
+        return f"q {tuple(q_shape)}, k {tuple(k_shape)} and v " \
+               f"{tuple(v_shape)} are not [B, groups, heads, d] and " \
+               "twice [B, groups, positions, d]"
+    if q_shape[3] % _LANE:
+        return f"head size {q_shape[3]} is not whole lanes of {_LANE}"
+    if not latent_block(k_shape[2], block):
+        return f"{k_shape[2]} cache positions are not whole lanes " \
+               f"of {_LANE}"
+    if window < 1:
+        return f"a window of {window} positions"
+    return None
+
+
+def gqa_decode_attention_reference(q, k, v, positions, window: int,
+                                   scale: float):
+    """The kernel's mathematics in jnp, and the path a model takes for
+    a shape the kernel refuses.  Slot ``s`` of a cache of ``T``
+    positions holds, for a stream at ``p``, position ``p - (p - s) mod
+    T`` (the newest one that falls on it); it counts where that lies in
+    ``[max(0, p - window + 1), p]``.  A dense cache is the case ``T``
+    larger than every position, where the rule reads ``s <= p``."""
+    import jax
+    import jax.numpy as jnp
+
+    hp = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
+    t = k.shape[2]
+    s = jnp.einsum("bgqd,bgtd->bgqt", q, k.astype(q.dtype),
+                   preferred_element_type=jnp.float32, precision=hp)
+    at = positions[:, None] - (positions[:, None]
+                               - jnp.arange(t, dtype=jnp.int32)[None]) % t
+    valid = at >= jnp.maximum(positions[:, None] - window + 1, 0)
+    s = jnp.where(valid[:, None, None, :], s * scale, -1e30)
+    prob = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bgqt,bgtd->bgqd", prob.astype(q.dtype),
+                      v.astype(q.dtype),
+                      preferred_element_type=jnp.float32, precision=hp)
+
+
+def gqa_decode_attention(q, k, v, positions, window: int, scale: float,
+                         block: int = 1024):
+    """Grouped-query attention of one token a stream: ``q [B, groups,
+    heads a group, d]``, ``k`` and ``v`` ``[B, groups, T, d]``,
+    ``positions [B]`` int32.  Position ``p`` of a stream lives in slot
+    ``p % T``: a ring where ``T`` is less than the stream's length (it
+    must be at least ``window``), a dense cache where it never wraps.
+    Returns ``[B, groups, heads a group, d]`` float32: softmax(q k^T *
+    scale) over the positions ``max(0, p - window + 1) .. p``, times v.
+
+    One pass: a grid step reads one block of K and of V (all groups)
+    into VMEM, once for every query head of a group, with a running
+    max, normaliser and accumulator across blocks.  Only blocks that
+    hold a position of the stream's window are fetched, in the order of
+    their positions (so a ring is walked from its oldest block round to
+    its newest): the steps left over repeat the last block in use, and
+    a repeated block is not copied again.  A window that does not start
+    on a block's edge costs one block more than it holds; blocks of
+    1,024 positions still read fastest on the chip at 32 streams, a
+    window of 4,096 in a ring of 6,144 (0.46 ms; 512: 0.48, 2,048: 0.55,
+    256: 0.75) and a dense cache of 16,384 (1.20; 1.36, 1.24, 2.23)
+    alike, a grid step's cost against the fifth block (``PERF.md``).
+    Heads are padded to whole tiles here; a shape
+    :func:`gqa_decode_attention_refusal` names is an error."""
+    import jax.numpy as jnp
+
+    refusal = gqa_decode_attention_refusal(q.shape, k.shape, v.shape,
+                                           window, block)
+    if refusal:
+        raise ValueError(f"gqa_decode_attention: {refusal}")
+    jax, pl, pltpu = _pl()
+    b, groups, held, d = q.shape
+    per = -(-held // _sublane(q.dtype)) * _sublane(q.dtype)
+    if per != held:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, per - held), (0, 0)))
+    total = k.shape[2]
+    rows = latent_block(total, block)
+    ring = total // rows
+    steps = min(ring, (window - 1) // rows + 2)
+
+    def span(pos):
+        """The block the oldest position of ``max(0, pos - window + 1)
+        .. pos`` falls in (counted by position, before the ring folds
+        it) and how many blocks hold one of them."""
+        first = jnp.maximum(pos - window + 1, 0) // rows
+        return first, jnp.minimum(pos // rows - first + 1, ring)
+
+    def kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
+        j = pl.program_id(1)
+        pos = pos_ref[pl.program_id(0)]
+        first, need = span(pos)
+
+        @pl.when(j == 0)
+        def _init():
+            m_ref[:] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+            l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        @pl.when(j < need)
+        def _block():
+            # a slot holds the newest position that falls on it: this
+            # turn of the ring up to the stream's slot, the turn before
+            # beyond it
+            slot = (first + j) % ring * rows + jax.lax.broadcasted_iota(
+                jnp.int32, (per, rows), 1)
+            at = slot + pos // total * total \
+                - jnp.where(slot > pos % total, total, 0)
+            valid = (at >= 0) & (at > pos - window)
+            for g in range(groups):
+                kb, vb = k_ref[0, g], v_ref[0, g]          # (rows, d)
+                s = jax.lax.dot_general(
+                    q_ref[0, g], kb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)    # (per, rows)
+                s = jnp.where(valid, s * scale, -1e30)
+                m_prev = m_ref[g]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new[:, :1])
+                l_ref[g] = l_ref[g] * corr + jnp.sum(p, axis=-1,
+                                                     keepdims=True)
+                acc_ref[g] = acc_ref[g] * corr[:, :1] + jax.lax.dot_general(
+                    p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[g] = m_new
+
+        @pl.when(j == steps - 1)
+        def _finish():
+            o_ref[0] = acc_ref[:] / l_ref[:, :, :1]
+
+    def cache_block(i, j, pos):
+        first, need = span(pos[i])
+        return i, 0, (first + jnp.minimum(j, need - 1)) % ring, 0
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(b, steps),
+        in_specs=[
+            pl.BlockSpec((1, groups, per, d), lambda i, j, pos: (i, 0, 0, 0)),
+            pl.BlockSpec((1, groups, rows, d), cache_block),
+            pl.BlockSpec((1, groups, rows, d), cache_block),
+        ],
+        out_specs=pl.BlockSpec((1, groups, per, d),
+                               lambda i, j, pos: (i, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((groups, per, _LANE), jnp.float32),   # running max
+            pltpu.VMEM((groups, per, _LANE), jnp.float32),   # normaliser
+            pltpu.VMEM((groups, per, d), jnp.float32),       # accumulator
+        ])
+    out = pl.pallas_call(
+        kernel, grid_spec=grid,
+        out_shape=jax.ShapeDtypeStruct((b, groups, per, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="gqa_decode_attention",
+        interpret=_interpret(),
+    )(positions.astype(jnp.int32), q, k.astype(q.dtype), v.astype(q.dtype))
+    return out[:, :, :held]

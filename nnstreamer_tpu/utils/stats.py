@@ -346,9 +346,12 @@ class StateStats:
     state (``filters/jax_xla.py`` ``_StateCell``): ``state_bytes`` (a
     level: bytes of device state alive now) and the counters a model's
     steps keep in its state, added up whenever the owning element's
-    stats sample reads them (``steps``, and for a model with a latent
-    cache and routed experts ``cache_bytes_read``, ``experts_touched``,
-    ``expert_hits``; ``Documentation/observability.md``)."""
+    stats sample reads them (``steps``, and for a model with a cache
+    and routed experts ``cache_bytes_read``, ``experts_touched``,
+    ``expert_hits``; a model with two kinds of cache tells them apart,
+    ``window_bytes_read`` of its rings and ``full_bytes_read`` of its
+    dense caches, and ``cache_bytes_read`` is their sum;
+    ``Documentation/observability.md``)."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -374,6 +374,99 @@ def test_sorted_expert_product_drops_nothing(toy, files, case):
         assert int(plan["blocks"]) == 0 and not np.asarray(got).any()
 
 
+def _dispatch_before_the_move(cfg, idx, n_tokens):
+    """``deepseek_v2.dispatch`` as it stood before the expert code moved
+    to ``models/moe.py``, kept here word for word."""
+    k, held = cfg.num_experts_per_tok, cfg.experts
+    blk = int(min(256, -(-n_tokens // 8) * 8))
+    pairs = n_tokens * k
+    rows = -(-pairs // blk) * blk + held * blk
+    local = idx.reshape(-1) - cfg.expert0
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(local, stable=True)
+    sorted_e = local[order]
+    counts = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
+    padded = (counts[:held] + blk - 1) // blk * blk
+    pad_end = jnp.cumsum(padded)
+    first = jnp.cumsum(counts) - counts
+    rank = jnp.arange(pairs, dtype=jnp.int32) - first[sorted_e]
+    here = sorted_e < held
+    dest_sorted = jnp.where(
+        here, (pad_end - padded)[jnp.minimum(sorted_e, held - 1)] + rank,
+        rows)
+    row_token = jnp.full((rows,), n_tokens, jnp.int32).at[dest_sorted].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    dest = jnp.zeros((pairs,), jnp.int32).at[order].set(dest_sorted)
+    block_expert = jnp.minimum(jnp.searchsorted(
+        pad_end, jnp.arange(rows // blk, dtype=jnp.int32) * blk,
+        side="right"), held - 1).astype(jnp.int32)
+    return {"row_token": row_token, "dest": dest.reshape(n_tokens, k),
+            "block_expert": block_expert, "blocks": pad_end[-1] // blk,
+            "counts": counts[:held], "blk": blk, "rows": rows}
+
+
+def _experts_before_the_move(p, x, plan):
+    """``deepseek_v2.grouped_experts`` as it stood before the move."""
+    from jax import lax
+
+    blk, rows = plan["blk"], plan["rows"]
+    x_pad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+
+    def mm(a, w):
+        return jnp.matmul(a, w, preferred_element_type=jnp.float32,
+                          precision=lax.Precision.HIGHEST
+                          if w.dtype == jnp.float32 else None)
+
+    def body(b, out):
+        e = plan["block_expert"][b]
+        tok = lax.dynamic_slice(plan["row_token"], (b * blk,), (blk,))
+        xb = x_pad[tok]
+        h = jax.nn.silu(mm(xb, p["gate"][e])) * mm(xb, p["up"][e])
+        ob = mm(h.astype(x.dtype), p["down"][e]).astype(x.dtype)
+        return lax.dynamic_update_slice(out, ob, (b * blk, 0))
+
+    return lax.fori_loop(0, plan["blocks"], body,
+                         jnp.zeros((rows + 1, x.shape[1]), x.dtype))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_shared_expert_module_gives_the_same_bits(toy, files, dtype):
+    """The sort-by-expert plan, the block loop and the combine moved to
+    ``models/moe.py`` (which ``smallthinker.py`` runs with ReLU and all
+    its experts held): through it ``moe_parts`` gives, bit for bit, what
+    the code gave where it stood, and ReLU is another function."""
+    from nnstreamer_tpu.models import moe
+
+    cfg = dsv2.DeepSeekV2Config.from_dict(toy)
+    p = files["weights"].make(toy, SEED)["layers"][2]["moe"]
+    if dtype == "float32":
+        p = _f32(p)
+    x = jax.random.normal(jax.random.PRNGKey(4), (40, cfg.hidden_size),
+                          p["shared"]["gate"].dtype)
+    routed, _shared, counts = jax.jit(
+        lambda p, x: dsv2.moe_parts(cfg, p, x))(p, x)
+
+    def before(p, x):
+        idx, weight = dsv2.route(cfg, x, p["router"])
+        plan = _dispatch_before_the_move(cfg, idx, x.shape[0])
+        out = _experts_before_the_move(p["experts"], x, plan)
+        return jnp.sum(out[plan["dest"]].astype(jnp.float32)
+                       * weight[..., None], axis=1), plan
+
+    want, plan = jax.jit(before)(p, x)
+    assert np.array_equal(np.asarray(routed), np.asarray(want))
+    assert np.array_equal(np.asarray(counts), np.asarray(plan["counts"]))
+    mine = dsv2.dispatch(cfg, dsv2.route(cfg, x, p["router"])[0], 40)
+    for key in ("row_token", "dest", "block_expert", "blocks", "counts"):
+        assert np.array_equal(np.asarray(mine[key]), np.asarray(plan[key]))
+    relu = moe.grouped_experts(p["experts"], x, mine, "relu")
+    assert not np.array_equal(
+        np.asarray(relu), np.asarray(dsv2.grouped_experts(p["experts"], x,
+                                                          mine)))
+    with pytest.raises(ValueError, match="gelu"):
+        moe.grouped_experts(p["experts"], x, mine, "gelu")
+
+
 def test_router_is_group_limited_and_unnormalised(toy, files):
     cfg = dsv2.DeepSeekV2Config.from_dict(toy)
     router = _f32(files["weights"].make(toy, SEED))["layers"][1]["moe"]["router"]

@@ -150,6 +150,56 @@ def test_two_filters_of_one_key_share_one_state(model):
         JaxXlaFilter._placement_key(add.props or FilterProps()))) is None
 
 
+@pytest.mark.parametrize("units,want", [
+    ({"ring_bytes": ("ring_rows", 8)}, {"ring_bytes": 24}),
+    ({"ring_bytes": ("ring_rows", 8), "dense_bytes": ("dense_rows", 16),
+      "cache_bytes": [("ring_rows", 8), ("dense_rows", 16)]},
+     {"ring_bytes": 24, "dense_bytes": 112, "cache_bytes": 136}),
+], ids=["one-counter", "two-kinds-and-their-sum"])
+def test_a_state_of_several_kinds_of_leaf_counts_each_kind(units, want):
+    """A state whose leaves differ in shape from layer to layer (a ring
+    beside a dense cache) is one state: donated, kept and freed whole;
+    a published counter may be one raw counter by its unit or several
+    that add up."""
+    SHARED_MODELS.clear()
+    STATE_STATS.reset()
+    _stateful_programs.clear()
+
+    def init_state(params):
+        return {"cache": [{"k": jnp.zeros((2, 3, 4)), "v": jnp.zeros((2, 3, 4))},
+                          {"k": jnp.zeros((2, 7, 4)), "v": jnp.zeros((2, 7, 4))}],
+                "n": {"ring_rows": jnp.zeros((), jnp.uint32),
+                      "dense_rows": jnp.zeros((), jnp.uint32)}}
+
+    def step(params, state, x):
+        cache = [{"k": c["k"] + x[0], "v": c["v"]} for c in state["cache"]]
+        n = {"ring_rows": state["n"]["ring_rows"] + jnp.uint32(3),
+             "dense_rows": state["n"]["dense_rows"] + jnp.uint32(7)}
+        return {"cache": cache, "n": n}, cache[1]["k"][0, :, 0]
+
+    name = "two_kinds_toy"
+    register_stateful_model(
+        name, params={"w": jnp.ones((4,))}, init_state=init_state,
+        entries={"step": (step, [(1,)], np.float32)},
+        counters=lambda state: state["n"],
+        counter_units=lambda state: units)
+    try:
+        sp = _open(name)
+        old = sp._cell.state["cache"][0]["k"]
+        assert np.allclose(sp.invoke([np.ones(1, np.float32)])[0], 1.0)
+        assert old.is_deleted()
+        assert sp._cell.state_bytes == 2 * 4 * (24 + 56) + 8
+        sp.fetch_counters()
+        stats = STATE_STATS.snapshot()
+        assert stats["ring_rows"] == 3 and stats["dense_rows"] == 7
+        assert {k: stats[k] for k in want} == want
+        sp.close()
+        assert STATE_STATS.snapshot()["state_bytes"] == 0
+    finally:
+        unregister_model(name)
+        SHARED_MODELS.clear()
+
+
 def test_reload_and_hot_swap_refuse(model):
     sp = _open(model())
     with pytest.raises(FilterError, match="stateful"):

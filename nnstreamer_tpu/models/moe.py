@@ -24,6 +24,9 @@ try:
 except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
+from ..ops import kernels
+from ..utils import profile as _profile
+
 
 def activation(name: str):
     """The gate's nonlinearity of ``act(x W_gate) * (x W_up)``."""
@@ -97,7 +100,37 @@ def grouped_experts(p, x, plan, act: str = "silu"):
     """The held experts' gated MLPs on the rows ``plan`` lays out, a
     block (of one expert's rows) at a time: ``[rows + 1, hidden]``, the
     last row zero.  Only the blocks in use are computed, so the work
-    follows the tokens routed here, not the held experts."""
+    follows the tokens routed here, not the held experts; a row no
+    pair's ``dest`` points at holds anything.
+
+    One algorithm, two programs, chosen from the shapes: the pipelined
+    kernel (``ops/kernels.py`` ``grouped_gated_product``: the next
+    block's matrices stream in while this one's are multiplied) wherever
+    its refusal has nothing to say, :func:`grouped_experts_loop`
+    everywhere else.  The set-up span this is traced under says which
+    (``utils/profile.py`` ``note``)."""
+    gate = activation(act)
+    w = p["gate"]
+    refusal = kernels.grouped_gated_product_refusal(
+        x.shape, w.shape, p["down"].shape,
+        {x.dtype, w.dtype, p["up"].dtype, p["down"].dtype}, plan["blk"])
+    shapes = f"grouped_experts {plan['blk']} rows x {plan['rows'] // plan['blk']} " \
+             f"blocks, {tuple(w.shape)} {w.dtype.name}"
+    if refusal:
+        _profile.note(f"{shapes}: the loop ({refusal})")
+        return grouped_experts_loop(p, x, plan, act)
+    tile = kernels.grouped_tile(w.shape[1], w.shape[2], w.dtype)
+    _profile.note(f"{shapes}: the kernel, tiles of {tile}")
+    return kernels.grouped_gated_product(
+        x, w, p["up"], p["down"], plan["row_token"], plan["block_expert"],
+        plan["blocks"], plan["blk"], gate)
+
+
+def grouped_experts_loop(p, x, plan, act: str = "silu"):
+    """:func:`grouped_experts` as XLA's own ``while``: an iteration a
+    block in use, each ending before the next begins.  The program of
+    every shape the kernel refuses, and what the kernel is tested
+    against; every row of a block not in use is zero here."""
     blk, rows = plan["blk"], plan["rows"]
     gate = activation(act)
     x_pad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])

@@ -6,8 +6,10 @@ package holds hand-written TPU kernels for the ops worth owning below
 XLA: the streaming normalize/typecast prologue, the flash-attention
 block kernel behind long-context attention, whole-sequence attention
 for short sequences, the one-pass decode attention over a latent
-cache, and grouped-query decode attention over a ring or a dense K/V
-cache.  Every kernel has a jnp reference implementation; the first two
+cache, grouped-query decode attention over a ring or a dense K/V
+cache, and the routed experts' grouped product.  Every kernel has a jnp
+reference implementation (the grouped product's is the loop in
+``models/moe.py``); the first two
 say through their ``*_available`` rule when a caller should use it
 instead (and take it themselves), the others refuse a shape they
 cannot take, with an ``*_available`` or ``*_refusal`` rule for the
@@ -21,6 +23,9 @@ from .kernels import (
     gqa_decode_attention,
     gqa_decode_attention_refusal,
     gqa_decode_attention_reference,
+    grouped_gated_product,
+    grouped_gated_product_refusal,
+    grouped_tile,
     latent_decode_attention,
     latent_decode_attention_refusal,
     latent_decode_attention_reference,
@@ -41,4 +46,6 @@ __all__ = [
     "latent_decode_attention_reference",
     "gqa_decode_attention", "gqa_decode_attention_refusal",
     "gqa_decode_attention_reference",
+    "grouped_gated_product", "grouped_gated_product_refusal",
+    "grouped_tile",
 ]

@@ -1,5 +1,6 @@
 """Pallas TPU kernels: fused normalize/typecast, flash attention, short
-attention, latent decode attention and grouped-query decode attention.
+attention, latent decode attention, grouped-query decode attention and
+the routed experts' grouped product.
 
 Parity/role:
 - ``scale_bias_cast`` is the tensor_transform arithmetic prologue
@@ -30,15 +31,20 @@ Parity/role:
   it grows into: a block of K and of V is read once for all the query
   heads of its group, and only the blocks that hold a position of the
   stream's window are fetched.
+- ``grouped_gated_product`` is the gated MLPs of the experts a decode
+  step's tokens were routed to (``models/moe.py`` ``grouped_experts``):
+  one call whose grid walks the plan's blocks with the block's expert
+  prefetched, so the next expert's matrices stream in while this one's
+  are multiplied.
 
 All compile natively on TPU (Mosaic) and run under the Pallas
 interpreter on CPU backends (tests).  ``scale_bias_cast`` and
 ``flash_attention`` take their jnp reference for a shape that does not
 meet the tiling constraints — lane dim a multiple of 128, sublane dim a
 multiple of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32
-of 1-byte elements); ``short_attention``, ``latent_decode_attention``
-and ``gqa_decode_attention`` refuse a shape they cannot take and leave
-the choice to the caller.
+of 1-byte elements); ``short_attention``, ``latent_decode_attention``,
+``gqa_decode_attention`` and ``grouped_gated_product`` refuse a shape
+they cannot take and leave the choice to the caller.
 Either way the ``*_available`` / ``*_refusal`` predicates are the whole
 eligibility rule, so the fallback is a decision made here, never an
 exception caught somewhere.
@@ -729,3 +735,203 @@ def gqa_decode_attention(q, k, v, positions, window: int, scale: float,
         interpret=_interpret(),
     )(positions.astype(jnp.int32), q, k.astype(q.dtype), v.astype(q.dtype))
     return out[:, :, :held]
+
+
+# -- the routed experts' grouped product --------------------------------------
+
+#: fast memory both buffers of a grid step's three weight tiles may take;
+#: the call states it (and its rows' part) through ``vmem_limit_bytes``.
+#: Alone the kernel reads a little faster the larger its tiles; inside a
+#: decode step, 24 MiB read faster than 32 and 48 (``PERF.md`` section
+#: 5: what the kernel leaves, the compiler prefetches weights into)
+_GROUPED_VMEM_BUDGET = 24 << 20
+
+
+def grouped_tile(hidden: int, inter: int, dtype,
+                 budget: int = _GROUPED_VMEM_BUDGET) -> int:
+    """Columns of the intermediate width a grid step of
+    :func:`grouped_gated_product` takes: the largest divisor of
+    ``inter`` of whole lanes whose three tiles (``[hidden, tile]``
+    twice, ``[tile, hidden]``), twice for the pipeline's two buffers,
+    fit ``budget``; 0 where none does."""
+    size = np.dtype(dtype).itemsize
+    for tile in range(inter // _LANE * _LANE, 0, -_LANE):
+        if inter % tile == 0 and 6 * hidden * tile * size <= budget:
+            return tile
+    return 0
+
+
+def grouped_gated_product_refusal(x_shape, gate_shape, down_shape, dtypes,
+                                  blk: int) -> Optional[str]:
+    """Why :func:`grouped_gated_product` cannot take these shapes, or
+    None: ``x [tokens, hidden]`` beside ``gate`` (and ``up``)
+    ``[experts, hidden, inter]`` and ``down [experts, inter, hidden]``,
+    all of ONE type (``dtypes``: the set of theirs), bf16 or float32,
+    both widths whole lanes, a block of whole sublanes, and a tile of
+    the intermediate width that fits."""
+    names = sorted(np.dtype(d).name for d in dtypes)
+    if names not in (["bfloat16"], ["float32"]):
+        return f"operands of {', '.join(names)}: all bfloat16 or all float32"
+    dtype = names[0]
+    if len(x_shape) != 2 or len(gate_shape) != 3 \
+            or tuple(down_shape) != (gate_shape[0], gate_shape[2],
+                                     gate_shape[1]) \
+            or x_shape[1] != gate_shape[1]:
+        return f"x {tuple(x_shape)}, gate {tuple(gate_shape)} and down " \
+               f"{tuple(down_shape)} are not [tokens, hidden], [experts, " \
+               "hidden, inter] and [experts, inter, hidden]"
+    hidden, inter = gate_shape[1], gate_shape[2]
+    if hidden % _LANE or inter % _LANE:
+        return f"widths {hidden} and {inter} are not whole lanes of {_LANE}"
+    if blk < 1 or blk % _sublane(dtype):
+        return f"a block of {blk} rows is not whole tiles of " \
+               f"{_sublane(dtype)}"
+    if not grouped_tile(hidden, inter, dtype):
+        return f"no tile of [{hidden}, {inter}] fits " \
+               f"{_GROUPED_VMEM_BUDGET >> 20} MiB twice"
+    return None
+
+
+def grouped_gated_product(x, gate, up, down, row_token, block_expert,
+                          blocks, blk: int, act, tile: Optional[int] = None):
+    """The gated MLPs of the experts a plan of ``models/moe.py``
+    ``dispatch`` lays rows out for, as one call: ``x [tokens, hidden]``,
+    ``gate`` and ``up`` ``[experts, hidden, inter]``, ``down [experts,
+    inter, hidden]`` all of one type, ``row_token [rows]`` (the token a
+    row holds, ``tokens`` = none), ``block_expert [rows / blk]``,
+    ``blocks`` (how many of them are in use), ``act`` the gate's
+    nonlinearity.  Returns ``[rows + 1, hidden]``: row ``r`` of a block
+    in use is ``(act(x_t gate_e) * (x_t up_e)) down_e`` of its token and
+    its block's expert, products accumulated in float32, the hidden
+    activation rounded to ``x``'s type before ``down``, the row rounded
+    once; the last row is zero; rows of blocks NOT in use are not
+    written and hold anything.
+
+    The grid walks (block, tile of the intermediate width) with
+    ``block_expert`` and ``blocks`` prefetched, so the weight operands'
+    index maps choose the expert and the pipeline fetches the next
+    step's three tiles while this step multiplies: a block of 32 rows
+    is nothing to compute, the call streams the touched experts'
+    matrices once.  Steps beyond the blocks in use name the tiles the
+    last block in use named (a repeated block is not copied again) and
+    compute nothing; one step more writes the zero row.  A shape
+    :func:`grouped_gated_product_refusal` names is an error: the caller
+    chooses."""
+    import jax.numpy as jnp
+
+    refusal = grouped_gated_product_refusal(
+        x.shape, gate.shape, down.shape,
+        {x.dtype, gate.dtype, up.dtype, down.dtype}, blk)
+    if refusal:
+        raise ValueError(f"grouped_gated_product: {refusal}")
+    jax, pl, pltpu = _pl()
+    hidden, inter = gate.shape[1], gate.shape[2]
+    tile = tile or grouped_tile(hidden, inter, x.dtype)
+    tiles, grid_blocks = inter // tile, row_token.shape[0] // blk
+    hp = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+    tokens = x.shape[0]
+    # up to a block of tokens (``dispatch`` caps a block at 256 rows)
+    # the kernel holds them whole and picks a block's rows out of them
+    # itself, by a product with ones where a row holds a token (exact:
+    # one term a row; a row of no token is zero); more tokens are laid
+    # out as the plan's rows before the call
+    inside = tokens <= blk
+    if inside:
+        row_operands = [row_token.reshape(-1, 1), x]
+        row_specs = [(blk, 1), (tokens, hidden)]
+    else:
+        row_operands = [jnp.concatenate(
+            [x, jnp.zeros((1, hidden), x.dtype)])[row_token]]
+        row_specs = [(blk, hidden)]
+
+    def mm(a, w):
+        return jax.lax.dot_general(a, w, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32,
+                                   precision=hp)
+
+    def kernel(expert_ref, blocks_ref, *refs):
+        *row_refs, gate_ref, up_ref, down_ref, o_ref, acc_ref = refs
+        b, t = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(b < blocks_ref[0])
+        def _product():
+            if inside:
+                token_ref, x_ref = row_refs
+                holds = jax.lax.broadcasted_iota(
+                    jnp.int32, (blk, tokens), 1) == token_ref[...]
+                xb = mm(holds.astype(x.dtype), x_ref[...]).astype(x.dtype)
+            else:
+                xb = row_refs[0][...]
+            h = act(mm(xb, gate_ref[...])) * mm(xb, up_ref[...])
+            part = mm(h.astype(xb.dtype), down_ref[...])
+            if tiles == 1:
+                o_ref[...] = part.astype(o_ref.dtype)
+                return
+
+            @pl.when(t == 0)
+            def _first():
+                acc_ref[...] = part
+
+            @pl.when(t > 0)
+            def _next():
+                acc_ref[...] += part
+
+            @pl.when(t == tiles - 1)
+            def _write():
+                o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+        @pl.when(b == grid_blocks)
+        def _zero_row():
+            o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    def at(b, t, count):
+        """The (block, tile) whose operands step ``(b, t)`` names: its
+        own, or beyond the blocks in use the last ones named."""
+        live = b < count[0]
+        return jnp.where(live, b, jnp.maximum(count[0] - 1, 0)), \
+            jnp.where(live, t, tiles - 1)
+
+    def row_block(b, t, expert, count):
+        return at(b, t, count)[0], 0
+
+    def out_block(b, t, expert, count):
+        return jnp.where(b == grid_blocks, b, at(b, t, count)[0]), 0
+
+    def in_tile(b, t, expert, count):
+        block, column = at(b, t, count)
+        return expert[block], 0, column
+
+    def out_tile(b, t, expert, count):
+        block, column = at(b, t, count)
+        return expert[block], column, 0
+
+    size = np.dtype(x.dtype).itemsize
+    # the weights' tiles twice; the rows in and out twice, their float32
+    # sum and a step's part of it; the hidden activation's float32 parts
+    vmem = 6 * hidden * tile * size + blk * hidden * (4 * size + 8) \
+        + 4 * blk * tile * 4 + (4 << 20)
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(grid_blocks + 1, tiles),
+        in_specs=[
+            pl.BlockSpec(row_specs[0], row_block),
+            *[pl.BlockSpec(whole, lambda b, t, expert, count: (0, 0))
+              for whole in row_specs[1:]],
+            pl.BlockSpec((None, hidden, tile), in_tile),
+            pl.BlockSpec((None, hidden, tile), in_tile),
+            pl.BlockSpec((None, tile, hidden), out_tile),
+        ],
+        out_specs=pl.BlockSpec((blk, hidden), out_block),
+        scratch_shapes=[pltpu.VMEM((blk, hidden), jnp.float32)])
+    # no ``name=``: the caller's scope (``.../moe/experts``) is the stage
+    # this call's device time is booked to, as with ``short_attention``
+    return pl.pallas_call(
+        kernel, grid_spec=grid,
+        out_shape=jax.ShapeDtypeStruct((row_token.shape[0] + 1, hidden),
+                                       x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=_interpret(),
+    )(block_expert.astype(jnp.int32),
+      jnp.reshape(blocks, (1,)).astype(jnp.int32), *row_operands, gate, up,
+      down)

@@ -136,7 +136,8 @@ class span:
     ``note`` (settable inside the block) rides along, e.g. ``hit`` or
     ``miss`` on a compile-cache load."""
 
-    __slots__ = ("owner", "phase", "window", "setup", "note", "_t0", "_ann")
+    __slots__ = ("owner", "phase", "window", "setup", "note", "_t0", "_ann",
+                 "_said")
 
     def __init__(self, owner: str, phase: Optional[str] = None,
                  window: Optional[int] = None, setup: bool = False):
@@ -147,6 +148,7 @@ class span:
         self.note = None
         self._t0 = None
         self._ann = None
+        self._said = None
 
     @property
     def name(self) -> str:
@@ -158,7 +160,9 @@ class span:
             return self
         if self.setup:
             _watch_jax_compiles()
-            _tls.setup = getattr(_tls, "setup", 0) + 1
+            if not hasattr(_tls, "setup"):
+                _tls.setup = []       # the set-up spans open on this thread
+            _tls.setup.append(self)
         if _active.is_set():
             import jax
 
@@ -176,7 +180,12 @@ class span:
         if ann is not None:
             ann.__exit__(*exc)
         if self.setup:
-            _tls.setup -= 1
+            _tls.setup.pop()
+            if self._said:
+                said = "; ".join(text if n == 1 else f"{text} (x{n})"
+                                 for text, n in self._said.items())
+                self.note = said if self.note is None \
+                    else f"{self.note}; {said}"
             _REC.keep(self.name, t0, t1, self.window, "setup", self.note)
         elif ann is not None:
             _REC.keep(self.name, t0, t1, self.window, "window", self.note)
@@ -187,6 +196,20 @@ class span:
 
 #: the older name of :class:`span`, kept for callers outside the runtime
 annotate = span
+
+
+def note(text: str) -> None:
+    """Adds ``text`` to the note of the innermost set-up span open on
+    this thread; a text said again is counted, not repeated.  For code
+    that runs deep inside a span it cannot see: a model function says
+    here, while ``<filter>/trace_lower`` traces it, which program it
+    chose for a shape.  Outside a set-up span it does nothing."""
+    open_here = getattr(_tls, "setup", None)
+    if open_here:
+        inner = open_here[-1]
+        if inner._said is None:
+            inner._said = {}
+        inner._said[text] = inner._said.get(text, 0) + 1
 
 # -- what jax itself did inside a set-up span ---------------------------------
 
@@ -216,7 +239,7 @@ def _on_jax_duration(event: str, seconds: float, **kw) -> None:
     loading; what is left of ``first_call`` is the first execution."""
     name = JAX_PARTS.get(event)
     if name is None or seconds < JAX_PART_MIN_S \
-            or not getattr(_tls, "setup", 0):
+            or not getattr(_tls, "setup", None):
         return
     end = time.perf_counter_ns()
     _REC.keep(name, end - int(seconds * 1e9), end, None, "setup",

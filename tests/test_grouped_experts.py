@@ -1,0 +1,235 @@
+"""The routed experts' grouped product as one kernel
+(``ops/kernels.py`` ``grouped_gated_product``) against the loop it
+stands in for (``models/moe.py`` ``grouped_experts_loop``), which path
+``grouped_experts`` takes for a shape, and the stage the call's device
+time is booked to.  The kernel runs under the Pallas interpreter here;
+``tests/test_tpu_compile.py`` compiles it for the chip."""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from nnstreamer_tpu.models import deepseek_v2 as dsv2
+from nnstreamer_tpu.models import moe
+from nnstreamer_tpu.models import smallthinker as st
+from nnstreamer_tpu.ops import kernels
+from nnstreamer_tpu.utils import profile
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HELD, ROUTED, K, HIDDEN, INTER = 4, 8, 2, 128, 256
+
+
+def _experts(dtype, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return {name: (jax.random.normal(key, shape) * shape[1] ** -0.5)
+            .astype(dtype)
+            for key, name, shape in zip(
+                keys, ("gate", "up", "down"),
+                [(HELD, HIDDEN, INTER)] * 2 + [(HELD, INTER, HIDDEN)])}
+
+
+def _routes(case, n):
+    """``idx [n, K]`` over ROUTED experts of which the first HELD are
+    held here."""
+    idx = jax.random.randint(jax.random.PRNGKey(7), (n, K), 0, ROUTED)
+    if case == "all-on-one":
+        return jnp.full_like(idx, 1).at[:, 1:].set(HELD + 1)
+    if case == "two-blocks-of-one":    # both of a token's pairs on it
+        return jnp.full_like(idx, 2)
+    if case == "none-held":
+        return HELD + idx % (ROUTED - HELD)
+    if case == "few-blocks":           # one expert in use of four held
+        return jnp.where(idx < HELD, 3, idx)
+    return idx
+
+
+def _both(dtype, act, case, tile):
+    """The kernel's and the loop's ``[rows + 1, hidden]`` and the plan."""
+    n = {"one-token": 1, "a-prefill-chunk": 264}.get(case, 32)
+    p = _experts(dtype)
+    x = jax.random.normal(jax.random.PRNGKey(1), (n, HIDDEN)).astype(dtype)
+    plan = moe.dispatch(_routes(case, n), n, 0, HELD)
+    got = kernels.grouped_gated_product(
+        x, p["gate"], p["up"], p["down"], plan["row_token"],
+        plan["block_expert"], plan["blocks"], plan["blk"],
+        moe.activation(act), tile=tile)
+    return got, moe.grouped_experts_loop(p, x, plan, act), plan
+
+
+CASES = [(dtype, act, case, tile)
+         for dtype in ("bfloat16", "float32")
+         for act in ("silu", "relu")
+         for case in ("seeded", "all-on-one", "two-blocks-of-one",
+                      "none-held", "few-blocks", "one-token",
+                      # more tokens than a block (256 rows): the rows are
+                      # laid out before the call, not picked in it
+                      "a-prefill-chunk")
+         for tile in (None, 128)
+         # a block of one token is 8 rows: half a tile of bf16, which the
+         # kernel refuses (the refusal test below has it)
+         if not (case == "one-token" and dtype == "bfloat16")]
+
+
+@pytest.mark.parametrize("dtype,act,case,tile", CASES)
+def test_the_kernel_is_the_loop(dtype, act, case, tile):
+    """Every row of a block in use and every token's weighted sum: bit
+    for bit where the intermediate width is one tile (the same
+    products, accumulated and rounded at the same points), within
+    float32 accumulation's tolerance where it is two (the down
+    projection summed tile by tile).  The row ``rows`` reads zero."""
+    got, want, plan = _both(jnp.dtype(dtype), act, case, tile)
+    blocks, grid = int(plan["blocks"]), plan["rows"] // plan["blk"]
+    assert got.shape == want.shape == (plan["rows"] + 1, HIDDEN)
+    assert got.dtype == want.dtype
+    assert blocks < grid                       # steps with nothing to do
+    assert blocks == {"all-on-one": 1, "two-blocks-of-one": 2,
+                      "none-held": 0, "few-blocks": 1}.get(case, blocks)
+    used = blocks * plan["blk"]
+    g = np.asarray(got, np.float32)
+    w = np.asarray(want, np.float32)
+    assert not g[plan["rows"]].any()
+    weight = jax.random.uniform(jax.random.PRNGKey(3), plan["dest"].shape)
+    sums = [np.asarray(moe.combine(out, plan, weight)) for out in (got, want)]
+    if tile is None:
+        assert kernels.grouped_tile(HIDDEN, INTER, dtype) == INTER
+        assert np.array_equal(g[:used], w[:used])
+        assert np.array_equal(*sums)
+    else:
+        tol = 2e-5 if dtype == "float32" else 2 ** -7
+        assert np.allclose(g[:used], w[:used], rtol=tol, atol=tol)
+        assert np.allclose(*sums, rtol=tol, atol=tol)
+    if case == "none-held":
+        assert not sums[0].any()
+
+
+@pytest.mark.parametrize("x,gate,dtypes,blk,says", [
+    ((32, 64), (4, 64, 256), {"float32"}, 32, "whole lanes"),
+    ((32, 128), (4, 128, 96), {"float32"}, 32, "whole lanes"),
+    ((8, 128), (4, 128, 256), {"bfloat16"}, 8, "whole tiles of 16"),
+    ((32, 128), (4, 128, 256), {"bfloat16", "float32"}, 32, "all bfloat16"),
+    ((32, 128), (4, 128, 256), {"float16"}, 32, "all bfloat16"),
+    ((32, 128), (4, 256, 256), {"float32"}, 32, "not [tokens, hidden]"),
+    ((32, 1 << 20), (4, 1 << 20, 128), {"float32"}, 32, "no tile"),
+])
+def test_a_refused_shape_keeps_the_loop(x, gate, dtypes, blk, says):
+    """What the kernel cannot take it says, the kernel itself raises
+    with it, and ``grouped_experts`` then IS the loop."""
+    down = (gate[0], gate[2], gate[1])
+    refusal = kernels.grouped_gated_product_refusal(x, gate, down, dtypes,
+                                                    blk)
+    assert says in refusal
+    if x[1] > 4096 or x[1] != gate[1] or len(dtypes) > 1 \
+            or "float16" in dtypes:
+        return                   # no operands the loop could take either
+    dtype = jnp.dtype(next(iter(dtypes)))
+    keys = jax.random.split(jax.random.PRNGKey(2), 4)
+    p = {"gate": jax.random.normal(keys[0], gate, dtype),
+         "up": jax.random.normal(keys[1], gate, dtype),
+         "down": jax.random.normal(keys[2], down, dtype)}
+    xs = jax.random.normal(keys[3], x, dtype)
+    plan = moe.dispatch(_routes("seeded", x[0]) % gate[0], x[0], 0, gate[0])
+    assert plan["blk"] == blk
+    with pytest.raises(ValueError, match="grouped_gated_product"):
+        kernels.grouped_gated_product(
+            xs, p["gate"], p["up"], p["down"], plan["row_token"],
+            plan["block_expert"], plan["blocks"], blk, jax.nn.silu)
+    text = str(jax.make_jaxpr(
+        lambda p, xs: moe.grouped_experts(p, xs, plan))(p, xs))
+    assert "pallas_call" not in text
+    assert np.array_equal(np.asarray(moe.grouped_experts(p, xs, plan)),
+                          np.asarray(moe.grouped_experts_loop(p, xs, plan)))
+
+
+def test_the_tile_follows_the_shapes():
+    """The largest divisor of the intermediate width, of whole lanes,
+    whose three tiles fit the budget twice: the two cells' experts."""
+    assert kernels.grouped_tile(2560, 768, jnp.bfloat16) == 768
+    assert kernels.grouped_tile(5120, 1536, jnp.bfloat16) == 384
+    assert kernels.grouped_tile(5120, 1536, jnp.float32) == 128
+    assert kernels.grouped_tile(5120, 1536, jnp.bfloat16, 8 << 20) == 128
+    assert kernels.grouped_tile(5120, 1536, jnp.bfloat16, 1 << 20) == 0
+
+
+def test_the_span_it_is_traced_under_says_which_path():
+    """``grouped_experts`` chooses at trace time, so the choice is a
+    note of the set-up span open around the trace (the filter's
+    ``trace_lower``), once for each distinct call with its count."""
+    p, x = _experts(jnp.float32), jnp.zeros((32, HIDDEN))
+    small = {k: v[:, :64, :64] for k, v in p.items()}
+    plan = moe.dispatch(_routes("seeded", 32), 32, 0, HELD)
+    profile.clear()
+    with profile.span("el_net", "trace_lower", setup=True):
+        for _ in range(2):
+            jax.make_jaxpr(lambda p, x: moe.grouped_experts(p, x, plan))(p, x)
+        jax.make_jaxpr(lambda p, x: moe.grouped_experts(p, x, plan))(
+            small, x[:, :64])
+    moe.grouped_experts(p, x, plan)          # no span open: says nothing
+    note = [s.note for s in profile.spans()
+            if s.name == "el_net/trace_lower"][-1]
+    assert "grouped_experts 32 rows x 6 blocks, (4, 128, 256) float32: " \
+           "the kernel, tiles of 256 (x2)" in note
+    assert "(4, 64, 64) float32: the loop (widths 64 and 64 are not whole " \
+           "lanes of 128)" in note
+
+
+def _toy(name, **changed):
+    with open(os.path.join(REPO, "tests", "benchmark", "data", name)) as f:
+        return {**json.load(f), **changed}
+
+
+def _decode_programs():
+    """Both models' decode steps at toy sizes with experts of whole
+    lanes, 16 streams in bf16: ``(name, jaxpr, expert layers)``."""
+    cfg = dsv2.DeepSeekV2Config.from_dict(_toy(
+        "toy_dsv2.json", hidden_size=128, moe_intermediate_size=128))
+    params = jax.eval_shape(lambda: dsv2.init_params(cfg, 0))
+    state = jax.eval_shape(lambda: dsv2.init_state(cfg, params, 16, 128))
+    ids = jax.ShapeDtypeStruct((16,), jnp.int32)
+    yield "dsv2", jax.make_jaxpr(
+        lambda p, s, i, at: dsv2.decode(cfg, p, s, i, at))(
+            params, state, ids, ids), [1, 2]
+    cfg = st.SmallThinkerConfig.from_dict(_toy(
+        "toy_smallthinker.json", hidden_size=128, moe_ffn_hidden_size=128))
+    params = jax.eval_shape(lambda: st.init_params(cfg, 0))
+    state = jax.eval_shape(lambda: st.init_state(cfg, params, 16, 32, 8))
+    yield "smallthinker", jax.make_jaxpr(
+        lambda p, s, i, at: st.decode(cfg, p, s, i, at))(
+            params, state, ids, ids), list(range(cfg.layers))
+
+
+def _calls(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _calls(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("reader", ["program", "benchmark"])
+def test_the_kernels_time_is_booked_to_the_experts_stage(reader):
+    """The call sits straight in the ``experts`` scope of its layer in
+    both models' decode programs, so the stage reader books its device
+    time to ``nns.model/layerNN/moe/experts``, which the two accepted
+    stage metrics (``experts_ms_per_window``,
+    ``relu_experts_ms_per_window``) sum."""
+    if reader == "program":
+        stage_of = profile.stage_of
+    else:
+        from benchmark.stages import stage_of
+    for name, program, layers in _decode_programs():
+        stages = [stage_of(f"jit(f)/nns.model/{eqn.source_info.name_stack}/"
+                           f"{eqn.primitive.name}")
+                  for eqn in _calls(program.jaxpr, [])]
+        experts = [s for s in stages if s.endswith("/moe/experts")]
+        assert experts == [f"nns.model/layer{i:02d}/moe/experts"
+                           for i in layers], (name, stages)
+        # the attention kernels keep a stage of their own, and nothing
+        # else is a kernel
+        assert len(stages) - len(experts) == len(
+            [s for s in stages if s.endswith("decode_attention")])

@@ -1,4 +1,4 @@
-"""The decode kernel of the language cells compiled for the chip, without
+"""The decode kernels of the language cells compiled for the chip, without
 the chip: the TPU's compiler is installed here and compiles for a v5e
 that is described and not attached, so what Mosaic refuses at the cell's
 real shapes (a slice off the tiling, more fast memory than a kernel may
@@ -53,3 +53,46 @@ def test_gqa_decode_attention_compiles_at_the_cells_shapes(
     assert "tpu_custom_call" in compiled.as_text()
     # no copy of a cache beside the kernel
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("tokens,held,hidden,inter,grid", [
+    (32, 40, 5120, 1536, 46), (32, 64, 2560, 768, 70),
+    (2048, 40, 5120, 1536, 88), (2048, 64, 2560, 768, 112)],
+    ids=["dsv2.decode16k", "smallthinker.decode16k",
+         "dsv2.prefill", "smallthinker.prefill"])
+def test_grouped_gated_product_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch, tokens, held, hidden, inter, grid):
+    """The routed experts' grouped product of both language cells, bf16,
+    as a Mosaic kernel: a decode step's blocks of 32 rows (40 experts of
+    5,120 x 1,536 on a grid of 46, 64 of 2,560 x 768 on a grid of 70)
+    and a prefill chunk's blocks of 256 rows (grids of 88 and 112), in
+    tiles of 384 and 768 columns (two buffers of three: 24 MB of fast
+    memory either way, which the call asks for)."""
+    from nnstreamer_tpu.models import moe
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    blk = moe.block_rows(tokens)
+    assert blk == min(tokens, 256)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    assert kernels.grouped_gated_product_refusal(
+        (tokens, hidden), (held, hidden, inter), (held, inter, hidden),
+        {jnp.dtype(jnp.bfloat16)}, blk) is None
+    assert kernels.grouped_tile(hidden, inter, jnp.bfloat16) \
+        == 768 * 2560 // hidden
+    fn = jax.jit(functools.partial(kernels.grouped_gated_product, blk=blk,
+                                   act=jax.nn.silu))
+    compiled = fn.lower(shape((tokens, hidden)),
+                        shape((held, hidden, inter)),
+                        shape((held, hidden, inter)),
+                        shape((held, inter, hidden)),
+                        shape((grid * blk,), jnp.int32),
+                        shape((grid,), jnp.int32),
+                        shape((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # no copy of the experts beside the kernel: nothing at a decode
+    # step, a prefill chunk's rows laid out for the plan
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (1 << 20) + (grid * blk * hidden * 2 if tokens > blk else 0)

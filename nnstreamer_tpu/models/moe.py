@@ -1,17 +1,19 @@
 """Routed experts as the token models run them (``deepseek_v2.py``,
-``smallthinker.py``): the plan that sorts (token, expert) pairs by
-expert, the product over blocks of one expert's rows, and the weighted
-sum back to tokens.  No token is dropped and there is no capacity
-factor; only the blocks in use are computed, so the work follows the
-tokens routed to the experts HELD here (``[expert0, expert0 +
-experts)`` of the router's width), not how many are held.
+``smallthinker.py``, ``nemotron_h.py``): the plan that sorts (token,
+expert) pairs by expert, the product over blocks of one expert's rows,
+and the weighted sum back to tokens.  No token is dropped and there is
+no capacity factor; only the blocks in use are computed, so the work
+follows the tokens routed to the experts HELD here (``[expert0,
+expert0 + experts)`` of the router's width), not how many are held.
 
 What differs between the models is a parameter of the call: which
-experts are held, and the gated activation (``silu`` or ``relu``).  How
-a model routes (groups, scaling, normalisation, what the router reads)
+experts are held, and the form of one (``silu`` or ``relu``: gated,
+``act(x W_gate) * (x W_up)`` through ``W_down``, three matrices;
+``relu2``: ungated, ``relu(x W_up)^2`` through ``W_down``, two).  How a
+model routes (groups, scaling, normalisation, what the router reads)
 stays with the model.
 
-Also the small parts both models are made of: RMSNorm with float32
+Also the small parts the models are made of: RMSNorm with float32
 statistics, and a product in the weights' type accumulated in float32.
 """
 
@@ -28,13 +30,19 @@ from ..ops import kernels
 from ..utils import profile as _profile
 
 
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
 def activation(name: str):
-    """The gate's nonlinearity of ``act(x W_gate) * (x W_up)``."""
+    """``(act, gated)`` of an expert's form: gated, ``act(x W_gate) * (x
+    W_up)``, or not, ``act(x W_up)``."""
     try:
-        return {"silu": jax.nn.silu, "relu": jax.nn.relu}[name]
+        return {"silu": (jax.nn.silu, True), "relu": (jax.nn.relu, True),
+                "relu2": (_relu2, False)}[name]
     except KeyError:
-        raise ValueError(f"gated activation {name!r}: only silu and relu "
-                         "are written") from None
+        raise ValueError(f"expert activation {name!r}: only silu and relu "
+                         "(gated) and relu2 (ungated) are written") from None
 
 
 def rms(x, gain, eps):
@@ -97,11 +105,12 @@ def dispatch(idx, n_tokens: int, expert0: int, held: int):
 
 
 def grouped_experts(p, x, plan, act: str = "silu"):
-    """The held experts' gated MLPs on the rows ``plan`` lays out, a
-    block (of one expert's rows) at a time: ``[rows + 1, hidden]``, the
-    last row zero.  Only the blocks in use are computed, so the work
-    follows the tokens routed here, not the held experts; a row no
-    pair's ``dest`` points at holds anything.
+    """The held experts' MLPs (``p``: ``up`` and ``down`` and, for a
+    gated ``act``, ``gate``) on the rows ``plan`` lays out, a block (of
+    one expert's rows) at a time: ``[rows + 1, hidden]``, the last row
+    zero.  Only the blocks in use are computed, so the work follows the
+    tokens routed here, not the held experts; a row no pair's ``dest``
+    points at holds anything.
 
     One algorithm, two programs, chosen from the shapes: the pipelined
     kernel (``ops/kernels.py`` ``grouped_gated_product``: the next
@@ -109,21 +118,23 @@ def grouped_experts(p, x, plan, act: str = "silu"):
     its refusal has nothing to say, :func:`grouped_experts_loop`
     everywhere else.  The set-up span this is traced under says which
     (``utils/profile.py`` ``note``)."""
-    gate = activation(act)
-    w = p["gate"]
+    fn, gated = activation(act)
+    w, gate = p["up"], p["gate"] if gated else None
+    matrices = 3 if gated else 2
     refusal = kernels.grouped_gated_product_refusal(
         x.shape, w.shape, p["down"].shape,
-        {x.dtype, w.dtype, p["up"].dtype, p["down"].dtype}, plan["blk"])
+        {x.dtype} | {m.dtype for m in p.values()}, plan["blk"], matrices)
     shapes = f"grouped_experts {plan['blk']} rows x {plan['rows'] // plan['blk']} " \
              f"blocks, {tuple(w.shape)} {w.dtype.name}"
     if refusal:
         _profile.note(f"{shapes}: the loop ({refusal})")
         return grouped_experts_loop(p, x, plan, act)
-    tile = kernels.grouped_tile(w.shape[1], w.shape[2], w.dtype)
+    tile = kernels.grouped_tile(w.shape[1], w.shape[2], w.dtype,
+                                matrices=matrices)
     _profile.note(f"{shapes}: the kernel, tiles of {tile}")
     return kernels.grouped_gated_product(
-        x, w, p["up"], p["down"], plan["row_token"], plan["block_expert"],
-        plan["blocks"], plan["blk"], gate)
+        x, gate, w, p["down"], plan["row_token"], plan["block_expert"],
+        plan["blocks"], plan["blk"], fn)
 
 
 def grouped_experts_loop(p, x, plan, act: str = "silu"):
@@ -132,14 +143,17 @@ def grouped_experts_loop(p, x, plan, act: str = "silu"):
     every shape the kernel refuses, and what the kernel is tested
     against; every row of a block not in use is zero here."""
     blk, rows = plan["blk"], plan["rows"]
-    gate = activation(act)
+    fn, gated = activation(act)
     x_pad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
 
     def body(b, out):
         e = plan["block_expert"][b]
         tok = lax.dynamic_slice(plan["row_token"], (b * blk,), (blk,))
         xb = x_pad[tok]
-        h = gate(mm(xb, p["gate"][e])) * mm(xb, p["up"][e])
+        if gated:
+            h = fn(mm(xb, p["gate"][e])) * mm(xb, p["up"][e])
+        else:
+            h = fn(mm(xb, p["up"][e]))
         ob = mm(h.astype(x.dtype), p["down"][e]).astype(x.dtype)
         return lax.dynamic_update_slice(out, ob, (b * blk, 0))
 

@@ -1,0 +1,765 @@
+"""Nemotron-H (``model_type`` ``nemotron_h``: NVIDIA-Nemotron-3-Nano) as
+a stateful model of the element stream.
+
+Written from the model's public ``config.json``: one stack whose layers
+are chosen by the letters of ``hybrid_override_pattern``, each layer ONE
+mixer behind a pre-norm and a residual add and no separate MLP:
+
+``M``  Mamba-2.  ``[z | xBC | dt] = u W_in``; ``xBC`` through a causal
+       depthwise convolution (kernel ``conv_kernel``, with bias) and
+       SiLU, split into ``x [heads, head_dim]``, ``B`` and ``C``
+       ``[groups, state]`` (head ``h`` reads group ``h // (heads /
+       groups)``); ``delta = softplus(dt + dt_bias)``, ``a = exp(-delta
+       exp(A_log))``; per head ``S_t = a S_{t-1} + delta x_t (x) B_t``,
+       ``y_t = S_t C_t + D x_t``; ``y * silu(z)`` through an RMSNorm
+       over groups of ``d_inner / groups`` values, then ``W_out``.
+``E``  Mixture of experts: a sigmoid router over ALL the published
+       experts, the choice taken from the scores plus a correction
+       bias, the weights from the scores alone, normalised and scaled;
+       UNGATED experts ``relu(u W_up)^2 W_down`` (``models/moe.py``,
+       shared with ``deepseek_v2.py`` and ``smallthinker.py``) and one
+       shared expert of the same form, added unweighted.  Which experts
+       are HELD here is the share (``[expert0, expert0 + experts)``).
+``*``  Grouped-query attention over every position, no rotary and no
+       other positional signal: the state-space layers carry order.
+
+**A state that no position addresses.**  An attention layer keeps a K
+and a V row a position (``[streams, kv heads, positions, head_dim]``):
+a row written too far is overwritten before it is read, so a padded
+prefill chunk and a rewind to the prompt's end cost nothing there.  A
+Mamba-2 layer keeps ONE array a stream (``ssm [streams, heads,
+head_dim, state]`` float32) and the last ``conv_kernel - 1`` inputs of
+its convolution (``conv [streams, conv_kernel - 1, conv_dim]``), both
+overwritten by every token.  So (``Documentation/stateful-models.md``):
+
+* :func:`prefill` takes a fourth tensor, ``count``: the first ``count``
+  tokens of the chunk are real.  A padded token gets ``delta = 0``,
+  which is ``a = 1`` and no input (exact), and the convolution's state
+  is taken at ``count``.
+* beside each live state the filter keeps a SNAPSHOT of it at the
+  stream's prompt end (``ssm_snap``, ``conv_snap``; ``prompt_end
+  [streams]``): every prefill chunk leaves live state and snapshot alike
+  at ``start + count`` tokens, and chunks arrive in order.
+* :func:`decode` starts a stream whose position IS its ``prompt_end``
+  from the snapshot (it answers anew from the resident prompt) and any
+  other from its live state, which must be at ``last + 1``: a position
+  that is neither cannot be served by a recurrent state and is counted
+  (``position_faults``), not guessed at.  A step reads the snapshots of
+  the streams that restore in it and of no other.
+
+:func:`prefill` runs the recurrence chunked (``chunk_size`` tokens: the
+quadratic form inside a chunk, the carried state between chunks),
+:func:`decode` one step of it.
+
+Stage scopes (``Documentation/observability.md``): ``embed``, ``state``,
+``ssm_restore`` (the loop that copies snapshots), ``layerNN/mamba``
+(``.../in_proj``, ``.../conv``, ``.../scan`` or
+``.../step``, ``.../gate_norm``, ``.../out_proj``), ``layerNN/attn``
+(``.../cache_write``, ``.../gqa_decode_attention``),
+``layerNN/moe/router|dispatch|experts|combine|shared``, ``head``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Dict
+
+import numpy as np
+
+try:
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+except ImportError:  # pragma: no cover
+    jax = jnp = lax = None
+
+from ..ops import kernels
+from . import moe
+
+Params = dict
+NEG = -1e30
+KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    """The published sizes, and beside them what is HELD here:
+    ``pattern`` (the leading layers' letters), ``experts`` and ``vocab``
+    with their offsets.  Every width is the source's."""
+
+    hidden_size: int
+    pattern: str
+    mamba_heads: int
+    mamba_head_dim: int
+    groups: int
+    state_size: int
+    conv_kernel: int
+    chunk_size: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    expert_width: int
+    shared_width: int
+    n_routed_experts: int          # the router's width (published)
+    top_k: int
+    routed_scaling_factor: float
+    eps: float
+    max_positions: int
+    experts: int
+    expert0: int
+    vocab: int
+    vocab0: int
+
+    @classmethod
+    def from_dict(cls, cfg: dict) -> "NemotronHConfig":
+        """From a ``config.json`` as published, or from a chip's share
+        of one: then ``num_hidden_layers`` (the pattern's leading
+        letters), ``n_routed_experts`` and ``vocab_size`` count what is
+        held, ``published`` gives the source's values (the router's
+        width is ``published.n_routed_experts``) and ``share`` the
+        offsets ``expert0`` / ``vocab0`` (0 where absent)."""
+        published, share = cfg.get("published", {}), cfg.get("share", {})
+        depth = int(cfg["num_hidden_layers"])
+        pattern = str(cfg["hybrid_override_pattern"])
+        if len(pattern) < depth or set(pattern) - set(KINDS):
+            raise ValueError(f"nemotron_h: the pattern {pattern!r} does not "
+                             f"give {depth} layers of M, E and *")
+        if int(cfg.get("n_group", 1)) != 1 \
+                or int(cfg.get("topk_group", 1)) != 1:
+            raise ValueError("nemotron_h: a group-limited router is not "
+                             "written")
+        if cfg.get("mlp_hidden_act", "relu2") != "relu2" \
+                or cfg.get("mamba_hidden_act", "silu") != "silu" \
+                or not cfg.get("norm_topk_prob", True) \
+                or cfg.get("tie_word_embeddings", False):
+            raise ValueError("nemotron_h: only relu2 experts, a silu "
+                             "Mamba-2, renormalised kept weights and an "
+                             "untied head are written")
+        if any(cfg.get(k, False) for k in ("attention_bias", "mlp_bias",
+                                           "mamba_proj_bias", "use_bias")) \
+                or not cfg.get("use_conv_bias", True):
+            raise ValueError("nemotron_h: only the convolution has a bias")
+        out = cls(
+            hidden_size=int(cfg["hidden_size"]),
+            pattern=pattern[:depth],
+            mamba_heads=int(cfg["mamba_num_heads"]),
+            mamba_head_dim=int(cfg["mamba_head_dim"]),
+            groups=int(cfg["n_groups"]),
+            state_size=int(cfg["ssm_state_size"]),
+            conv_kernel=int(cfg["conv_kernel"]),
+            chunk_size=int(cfg["chunk_size"]),
+            heads=int(cfg["num_attention_heads"]),
+            kv_heads=int(cfg["num_key_value_heads"]),
+            head_dim=int(cfg["head_dim"]),
+            expert_width=int(cfg["moe_intermediate_size"]),
+            shared_width=int(cfg["moe_shared_expert_intermediate_size"])
+            * int(cfg.get("n_shared_experts", 1)),
+            n_routed_experts=int(published.get("n_routed_experts",
+                                               cfg["n_routed_experts"])),
+            top_k=int(cfg["num_experts_per_tok"]),
+            routed_scaling_factor=float(cfg["routed_scaling_factor"]),
+            eps=float(cfg["layer_norm_epsilon"]),
+            max_positions=int(cfg["max_position_embeddings"]),
+            experts=int(cfg["n_routed_experts"]),
+            expert0=int(share.get("expert0", 0)),
+            vocab=int(cfg["vocab_size"]),
+            vocab0=int(share.get("vocab0", 0)))
+        if out.heads % out.kv_heads or out.mamba_heads % out.groups \
+                or out.expert0 + out.experts > out.n_routed_experts:
+            raise ValueError(
+                f"nemotron_h: {out.heads} query heads over {out.kv_heads} "
+                f"key/value heads, {out.mamba_heads} Mamba-2 heads over "
+                f"{out.groups} groups, experts [{out.expert0}, "
+                f"{out.expert0 + out.experts}) of {out.n_routed_experts}")
+        return out
+
+    @property
+    def layers(self) -> int:
+        return len(self.pattern)
+
+    @property
+    def d_inner(self) -> int:
+        """Heads x head size, NOT ``expand`` x hidden."""
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """What the convolution runs over: ``x``, ``B`` and ``C``."""
+        return self.d_inner + 2 * self.groups * self.state_size
+
+    @property
+    def per_group(self) -> int:
+        """Query heads that read one key/value head."""
+        return self.heads // self.kv_heads
+
+    def count(self, kind: str) -> int:
+        return self.pattern.count(kind)
+
+
+# -- the router ---------------------------------------------------------------
+
+
+def route(cfg: NemotronHConfig, u, router, bias):
+    """Sigmoid scores over ALL the published experts in float32; the
+    ``top_k`` largest of ``score + bias`` are chosen, and weighted by
+    their scores alone, normalised to 1 and scaled: ``(idx [N, k] int32,
+    weight [N, k] float32)``."""
+    score = jax.nn.sigmoid(jnp.matmul(
+        u.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(score + bias, cfg.top_k)
+    kept = jnp.take_along_axis(score, idx, axis=-1)
+    weight = kept / jnp.sum(kept, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), weight * cfg.routed_scaling_factor
+
+
+def _shared_expert(p, x):
+    """One expert of the routed ones' form, dense: every token."""
+    act, _gated = moe.activation("relu2")
+    h = act(moe.mm(x, p["up"]))
+    return moe.mm(h.astype(x.dtype), p["down"]).astype(x.dtype)
+
+
+def moe_parts(cfg: NemotronHConfig, p, u):
+    """``(routed, shared, counts)``: the held experts' weighted part for
+    the tokens routed to them (float32), the shared expert (what every
+    chip computes alike), and how many tokens each held expert got."""
+    n = u.shape[0]
+    with jax.named_scope("router"):
+        idx, weight = route(cfg, u, p["router"], p["router_bias"])
+    with jax.named_scope("dispatch"):
+        plan = moe.dispatch(idx, n, cfg.expert0, cfg.experts)
+    with jax.named_scope("experts"):
+        out = moe.grouped_experts(p["experts"], u, plan, "relu2")
+    with jax.named_scope("combine"):
+        routed = moe.combine(out, plan, weight)
+    with jax.named_scope("shared"):
+        shared = _shared_expert(p["shared"], u)
+    return routed, shared, plan["counts"]
+
+
+# -- Mamba-2 ------------------------------------------------------------------
+
+
+def _in_proj(cfg: NemotronHConfig, p, u):
+    """``z`` and ``xBC`` in the stream's type, ``dt`` in float32."""
+    with jax.named_scope("in_proj"):
+        zxbcdt = moe.mm(u, p["in_proj"])
+        d, c = cfg.d_inner, cfg.conv_dim
+        return (zxbcdt[:, :d].astype(u.dtype),
+                zxbcdt[:, d:d + c].astype(u.dtype), zxbcdt[:, d + c:])
+
+
+def _ssm_inputs(cfg: NemotronHConfig, act):
+    """The convolution's output ``[..., conv_dim]`` (float32) as ``x
+    [..., groups, heads a group, head_dim]``, ``B`` and ``C`` ``[...,
+    groups, state]``."""
+    d, gn = cfg.d_inner, cfg.groups * cfg.state_size
+    lead = act.shape[:-1]
+    x = act[..., :d].reshape(*lead, cfg.groups, cfg.mamba_heads // cfg.groups,
+                             cfg.mamba_head_dim)
+    b = act[..., d:d + gn].reshape(*lead, cfg.groups, cfg.state_size)
+    c = act[..., d + gn:].reshape(*lead, cfg.groups, cfg.state_size)
+    return x, b, c
+
+
+def _by_group(cfg: NemotronHConfig, per_head):
+    """``[..., heads] -> [..., groups, heads a group]``."""
+    return per_head.reshape(*per_head.shape[:-1], cfg.groups,
+                            cfg.mamba_heads // cfg.groups)
+
+
+def _gate_norm_out(cfg: NemotronHConfig, p, y, z, dtype):
+    """``rms(y * silu(z))`` over groups of ``d_inner / groups`` values,
+    then ``W_out``."""
+    with jax.named_scope("gate_norm"):
+        n = y.shape[0]
+        g = (y.reshape(n, cfg.d_inner)
+             * jax.nn.silu(z.astype(jnp.float32))).reshape(n, cfg.groups, -1)
+        g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.eps)
+        g = (g.reshape(n, cfg.d_inner) * p["gate_norm"]).astype(dtype)
+    with jax.named_scope("out_proj"):
+        return moe.mm(g, p["out_proj"]).astype(dtype)
+
+
+def ssd_scan(cfg: NemotronHConfig, x, b, c, delta, a_log, state):
+    """The recurrence over ``T`` tokens of one stream, chunked: ``x [T,
+    groups, heads a group, head_dim]``, ``b`` and ``c`` ``[T, groups,
+    state]``, ``delta [T, heads]`` (0 for a token that is padding),
+    ``state [heads, head_dim, state]``, all float32.  Returns ``(y [T,
+    ...as x], the state after the last token)``.  Inside a chunk of
+    ``chunk_size`` tokens ``y`` is the quadratic form ``(C B^T * decay)
+    (delta x)``; between chunks the state is carried."""
+    hp = lax.Precision.HIGHEST
+    t, size = x.shape[0], cfg.chunk_size
+    pad = -t % size
+    if pad:
+        x, b, c, delta = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                          for v in (x, b, c, delta))
+    n = (t + pad) // size
+    g, r = cfg.groups, cfg.mamba_heads // cfg.groups
+    log_a = _by_group(cfg, -delta * jnp.exp(a_log))        # [T, g, r], <= 0
+    xd = (x * _by_group(cfg, delta)[..., None]).reshape(
+        n, size, g, r, cfg.mamba_head_dim)
+    b, c = (v.reshape(n, size, g, cfg.state_size) for v in (b, c))
+    # cs[.., l]: the log of the decay from the chunk's start through l
+    cs = jnp.cumsum(log_a.reshape(n, size, g, r), axis=1).transpose(0, 2, 3, 1)
+    seen = jnp.arange(size)[:, None] >= jnp.arange(size)[None, :]
+    decay = jnp.exp(jnp.where(seen, cs[..., :, None] - cs[..., None, :],
+                              -jnp.inf))                   # [n, g, r, l, s]
+    cb = jnp.einsum("clgn,csgn->cgls", c, b, precision=hp)
+    y = jnp.einsum("cgrls,csgrp->clgrp", cb[:, :, None] * decay, xd,
+                   precision=hp)
+    # what a chunk adds to the state, and what it leaves of the old one
+    to_end = jnp.exp(cs[..., -1:] - cs).transpose(0, 3, 1, 2)   # [n, s, g, r]
+    gained = jnp.einsum("csgrp,csgn->cgrpn", xd * to_end[..., None], b,
+                        precision=hp)
+    kept = jnp.exp(cs[..., -1])                                 # [n, g, r]
+
+    def carry(s, step):
+        keep, gain = step
+        return keep[..., None, None] * s + gain, s
+
+    s0 = state.reshape(g, r, cfg.mamba_head_dim, cfg.state_size)
+    last, starts = lax.scan(carry, s0, (kept, gained))
+    y = y + jnp.einsum("clgn,cgrpn->clgrp", c, starts, precision=hp) \
+        * jnp.exp(cs).transpose(0, 3, 1, 2)[..., None]
+    return y.reshape((t + pad,) + x.shape[1:])[:t], last.reshape(state.shape)
+
+
+def mamba_prefill(cfg: NemotronHConfig, p, u, st, slot, start, count):
+    """A chunk ``u [C, hidden]`` of stream ``slot`` whose first token is
+    at ``start`` and whose first ``count`` tokens are real.  Starts from
+    zeros where ``start`` is 0, else from the slot's live state; leaves
+    live state and snapshot at ``start + count`` tokens."""
+    size = u.shape[0]
+    z, xbc, dt = _in_proj(cfg, p, u)
+    fresh = start == 0
+    conv0 = jnp.where(fresh, 0, st["conv"][slot])
+    ssm0 = jnp.where(fresh, 0.0, st["ssm"][slot])
+    with jax.named_scope("conv"):
+        ext = jnp.concatenate([conv0, xbc])           # [K - 1 + C, conv_dim]
+        act = p["conv_b"] + sum(
+            ext[k:k + size].astype(jnp.float32) * p["conv_w"][k]
+            for k in range(cfg.conv_kernel))
+        x, b, c = _ssm_inputs(cfg, jax.nn.silu(act))
+        # the last K - 1 REAL inputs: rows count - (K - 1) .. count - 1
+        conv = lax.dynamic_slice_in_dim(ext, count, cfg.conv_kernel - 1)
+    with jax.named_scope("scan"):
+        real = jnp.arange(size) < count
+        delta = jnp.where(real[:, None],
+                          jax.nn.softplus(dt + p["dt_bias"]), 0.0)
+        y, ssm = ssd_scan(cfg, x, b, c, delta, p["A_log"], ssm0)
+        y = y + x * _by_group(cfg, p["D"])[..., None]
+        st = {"conv": st["conv"].at[slot].set(conv),
+              "conv_snap": st["conv_snap"].at[slot].set(conv),
+              "ssm": st["ssm"].at[slot].set(ssm),
+              "ssm_snap": st["ssm_snap"].at[slot].set(ssm)}
+    return _gate_norm_out(cfg, p, y, z, u.dtype), st
+
+
+def restored(mamba: list, restore) -> list:
+    """The ``M`` layers' states with the live state of every stream of
+    ``restore [B]`` overwritten by its snapshot, a stream at a time in
+    place: a step in which no stream restores reads no snapshot, and one
+    in which some do reads theirs alone."""
+    first = jnp.argsort(~restore)             # the restoring streams first
+
+    def one(i, live):
+        b = first[i]
+        return [{name: lax.dynamic_update_slice_in_dim(
+            now[name], lax.dynamic_slice_in_dim(st[name + "_snap"], b, 1),
+            b, 0) for name in ("conv", "ssm")}
+            for now, st in zip(live, mamba)]
+
+    live = lax.fori_loop(0, jnp.sum(restore), one,
+                         [{"conv": st["conv"], "ssm": st["ssm"]}
+                          for st in mamba])
+    return [dict(st, **now) for st, now in zip(mamba, live)]
+
+
+def mamba_decode(cfg: NemotronHConfig, p, u, st):
+    """One token of every stream, ``u [B, hidden]``, from the live
+    state, which is overwritten; the snapshot is kept."""
+    z, xbc, dt = _in_proj(cfg, p, u)
+    with jax.named_scope("conv"):
+        window = jnp.concatenate([st["conv"], xbc[:, None]], axis=1)
+        act = p["conv_b"] + jnp.sum(
+            window.astype(jnp.float32) * p["conv_w"], axis=1)
+        x, b, c = _ssm_inputs(cfg, jax.nn.silu(act))
+    with jax.named_scope("step"):
+        delta = _by_group(cfg, jax.nn.softplus(dt + p["dt_bias"]))
+        a = jnp.exp(-delta * _by_group(cfg, jnp.exp(p["A_log"])))
+        shape = (u.shape[0], cfg.groups, -1, cfg.mamba_head_dim,
+                 cfg.state_size)
+        ssm = a[..., None, None] * st["ssm"].reshape(shape) \
+            + (delta[..., None] * x)[..., None] * b[:, :, None, None, :]
+        y = jnp.sum(ssm * c[:, :, None, None, :], axis=-1) \
+            + x * _by_group(cfg, p["D"])[..., None]
+    st = dict(st, conv=window[:, 1:], ssm=ssm.reshape(st["ssm"].shape))
+    return _gate_norm_out(cfg, p, y, z, u.dtype), st
+
+
+# -- attention ----------------------------------------------------------------
+
+
+def _qkv(cfg: NemotronHConfig, p, u):
+    """``(q [N, kv heads, heads a group, d], k [N, kv heads, d], v)``:
+    query head ``i`` reads key/value head ``i // per_group``; nothing is
+    rotated."""
+    n, dt = u.shape[0], u.dtype
+    q = moe.mm(u, p["q"]).astype(dt).reshape(
+        n, cfg.kv_heads, cfg.per_group, cfg.head_dim)
+    k = moe.mm(u, p["k"]).astype(dt).reshape(n, cfg.kv_heads, cfg.head_dim)
+    v = moe.mm(u, p["v"]).astype(dt).reshape(n, cfg.kv_heads, cfg.head_dim)
+    return q, k, v
+
+
+def _out(p, o, dtype):
+    return moe.mm(o.astype(dtype).reshape(o.shape[0], -1), p["o"]) \
+        .astype(dtype)
+
+
+def attn_prefill(cfg: NemotronHConfig, p, u, cache, slot, start,
+                 key_block: int = 1024):
+    """A chunk ``u [C, hidden]`` of stream ``slot`` whose first token is
+    at ``start``: writes the chunk's K and V rows, then attends to the
+    stream's cache block by block with a running softmax, position ``p``
+    seeing ``0 .. p``.  A padded token's row lies beyond the prompt and
+    is overwritten by the answer before any step reads it."""
+    size, total = u.shape[0], cache["k"].shape[2]
+    positions = start + jnp.arange(size, dtype=jnp.int32)
+    q, k, v = _qkv(cfg, p, u)
+    with jax.named_scope("cache_write"):
+        cache = {
+            "k": cache["k"].at[slot, :, positions].set(
+                k.astype(cache["k"].dtype)),
+            "v": cache["v"].at[slot, :, positions].set(
+                v.astype(cache["v"].dtype))}
+    kb = math.gcd(int(key_block), total)
+    hp = moe.precision(p["q"])
+    scale = cfg.head_dim ** -0.5
+
+    def body(j, carry):
+        m, l, acc = carry
+        kj, vj = (lax.dynamic_slice(
+            cache[name], (slot, 0, j * kb, 0),
+            (1, cfg.kv_heads, kb, cfg.head_dim))[0].astype(u.dtype)
+            for name in ("k", "v"))
+        s = jnp.einsum("cgqd,gkd->gqck", q, kj,
+                       preferred_element_type=jnp.float32, precision=hp)
+        keys = j * kb + jnp.arange(kb, dtype=jnp.int32)
+        seen = keys[None, :] <= positions[:, None]
+        s = jnp.where(seen[None, None], s * scale, NEG)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        prob = jnp.exp(s - m_new[..., None])
+        l = l * alpha + prob.sum(axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "gqck,gkd->gqcd", prob.astype(u.dtype), vj,
+            preferred_element_type=jnp.float32, precision=hp)
+        return m_new, l, acc
+
+    # the first block holds position 0, which every query sees: a later
+    # block whose keys are all masked for a query adds nothing to it
+    blocks = jnp.minimum(total // kb, (start + size - 1 + kb) // kb)
+    m0 = jnp.full((cfg.kv_heads, cfg.per_group, size), NEG, jnp.float32)
+    _, l, acc = lax.fori_loop(
+        0, blocks, body,
+        (m0, jnp.zeros_like(m0),
+         jnp.zeros(m0.shape + (cfg.head_dim,), jnp.float32)))
+    o = (acc / l[..., None]).transpose(2, 0, 1, 3)             # [C, g, q, d]
+    return _out(p, o, u.dtype), cache
+
+
+def attn_decode(cfg: NemotronHConfig, p, u, cache, positions):
+    """One token of every stream: writes each stream's K and V row at
+    its position, then attends over ``0 .. position``
+    (``ops/kernels.py`` ``gqa_decode_attention`` with the whole cache as
+    its window, its ``jnp`` reference for a shape it refuses)."""
+    b, total = u.shape[0], cache["k"].shape[2]
+    q, k, v = _qkv(cfg, p, u)
+    with jax.named_scope("cache_write"):
+        where = (jnp.arange(b)[:, None], jnp.arange(cfg.kv_heads)[None, :],
+                 positions[:, None])
+        cache = {"k": cache["k"].at[where].set(k.astype(cache["k"].dtype)),
+                 "v": cache["v"].at[where].set(v.astype(cache["v"].dtype))}
+    scale = cfg.head_dim ** -0.5
+    if kernels.gqa_decode_attention_refusal(
+            q.shape, cache["k"].shape, cache["v"].shape, total) is None:
+        # the call names its own scope, `.../gqa_decode_attention`
+        attend = kernels.gqa_decode_attention
+    else:
+        attend = kernels.gqa_decode_attention_reference
+    o = attend(q, cache["k"], cache["v"], positions, total, scale)
+    return _out(p, o, u.dtype), cache
+
+
+# -- the model ----------------------------------------------------------------
+
+
+def _layers(cfg: NemotronHConfig, params, x, state, mamba, attend):
+    """Every held layer on ``x [N, hidden]``: ``mamba(layer params,
+    normed x, layer state) -> (output, layer state)`` and ``attend``
+    likewise on a layer's cache.  Returns the stream, the layers' new
+    states and the tokens each held expert of each ``E`` layer got."""
+    old = {"mamba": iter(state["mamba"]), "cache": iter(state["cache"])}
+    states = {"mamba": [], "cache": []}
+    counts = []
+    for i, (kind, layer) in enumerate(zip(cfg.pattern, params["layers"])):
+        # a mixer's scope holds its norm and its residual add, so that the
+        # fusions XLA roots there are booked to the mixer
+        with jax.named_scope(f"layer{i:02d}"), \
+                jax.named_scope(KINDS[kind]):
+            u = moe.rms(x, layer["norm"], cfg.eps)
+            if kind == "E":
+                routed, shared, got = moe_parts(cfg, layer, u)
+                counts.append(got)
+                y = (routed + shared.astype(jnp.float32)).astype(x.dtype)
+            else:
+                run, which = (mamba, "mamba") if kind == "M" \
+                    else (attend, "cache")
+                y, new = run(layer, u, next(old[which]))
+                states[which].append(new)
+            x = x + y
+    return x, states, jnp.stack(counts) if counts \
+        else jnp.zeros((0, cfg.experts), jnp.int32)
+
+
+def _embed(cfg: NemotronHConfig, params, ids):
+    with jax.named_scope("embed"):
+        return params["embed"][ids - cfg.vocab0]
+
+
+def _head(cfg: NemotronHConfig, params, x):
+    """Logits over the held rows of the vocabulary, float32, and the
+    greedy id (global) beside them."""
+    with jax.named_scope("head"):
+        logits = moe.mm(moe.rms(x, params["final_norm"], cfg.eps),
+                        params["head"])
+        return logits, (jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        + cfg.vocab0)
+
+
+COUNTERS = ("steps", "ssm_rows", "kv_rows_read", "experts_touched",
+            "expert_hits", "restores", "position_faults")
+
+
+def init_state(cfg: NemotronHConfig, params, streams: int, positions: int,
+               dtype=None) -> dict:
+    """The state a filter owns between invokes.  Per ``M`` layer the
+    recurrent state and the convolution's last inputs, live and as the
+    snapshot at the stream's prompt end; per ``*`` layer a K and a V
+    array of ``positions`` rows a stream; once, where each stream's
+    prompt ends and the position it was last fed (-1: nothing yet); and
+    the counters the steps add to (``uint32``: the reader takes
+    differences, so a wrap costs nothing).  One buffer a leaf: the
+    state is donated leaf by leaf."""
+    dtype = dtype or params["embed"].dtype
+    if positions > cfg.max_positions:
+        raise ValueError(f"nemotron_h: {positions} positions, the model "
+                         f"has {cfg.max_positions}")
+    ssm = (streams, cfg.mamba_heads, cfg.mamba_head_dim, cfg.state_size)
+    # the inputs' axis before the channels': 3 rows of 6,144 lanes, where
+    # [.., 6144, 3] would pad every channel's three values to a tile
+    conv = (streams, cfg.conv_kernel - 1, cfg.conv_dim)
+    kv = (streams, cfg.kv_heads, int(positions), cfg.head_dim)
+    return {
+        "mamba": [{"conv": jnp.zeros(conv, dtype),
+                   "conv_snap": jnp.zeros(conv, dtype),
+                   "ssm": jnp.zeros(ssm, jnp.float32),
+                   "ssm_snap": jnp.zeros(ssm, jnp.float32)}
+                  for _ in range(cfg.count("M"))],
+        "cache": [{"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
+                  for _ in range(cfg.count("*"))],
+        "prompt_end": jnp.zeros((streams,), jnp.int32),
+        "last": jnp.full((streams,), -1, jnp.int32),
+        "counters": {name: jnp.zeros((), jnp.uint32) for name in COUNTERS}}
+
+
+def counters(state: dict) -> dict:
+    return state["counters"]
+
+
+def counter_units(cfg: NemotronHConfig, state: dict) -> dict:
+    """What the raw counters stand for in bytes.  ``ssm_rows`` counts
+    the streams stepped in ONE ``M`` layer: a row is a stream's ``ssm``
+    and ``conv``, read and written.  ``kv_rows_read`` counts the rows in
+    use (``0 .. position``) of ONE ``*`` layer: a row is a token's K and
+    V."""
+    out = {}
+    if state["mamba"]:
+        first = state["mamba"][0]
+        row = sum(first[k][0].size * first[k].dtype.itemsize
+                  for k in ("ssm", "conv"))
+        out["ssm_bytes"] = ("ssm_rows", 2 * row * len(state["mamba"]))
+    if state["cache"]:
+        k = state["cache"][0]["k"]
+        row = 2 * cfg.kv_heads * cfg.head_dim * k.dtype.itemsize
+        kv = ("kv_rows_read", row * len(state["cache"]))
+        out.update(kv_bytes_read=kv, cache_bytes_read=kv)
+    return out
+
+
+def prefill(cfg: NemotronHConfig, params, state, ids, slot, start, count):
+    """A chunk of ONE stream: ``ids [C]``, ``slot [1]``, ``start [1]``,
+    ``count [1]`` (all int32); the first ``count`` ids are real.  Serves
+    the logits and greedy id after the chunk's last real token.  Chunks
+    of a stream arrive in order, so the last one leaves the snapshot at
+    the prompt's end."""
+    slot, start, count = slot[0], start[0], count[0]
+    x = _embed(cfg, params, ids)
+    x, states, _ = _layers(
+        cfg, params, x, state,
+        lambda p, u, st: mamba_prefill(cfg, p, u, st, slot, start, count),
+        lambda p, u, cache: attn_prefill(cfg, p, u, cache, slot, start))
+    logits, greedy = _head(cfg, params,
+                           lax.dynamic_slice_in_dim(x, count - 1, 1))
+    with jax.named_scope("state"):
+        end = start + count
+        new = dict(states,
+                   prompt_end=state["prompt_end"].at[slot].set(end),
+                   last=state["last"].at[slot].set(end - 1),
+                   counters=state["counters"])
+    return new, (logits, greedy)
+
+
+def decode(cfg: NemotronHConfig, params, state, ids, positions):
+    """One token of EVERY stream: ``ids [B]``, ``positions [B]`` int32.
+    Serves ``logits [B, vocab]`` float32 and the greedy ids.  A stream
+    at its ``prompt_end`` starts from its snapshot; any other must be at
+    ``last + 1``, or the step counts a position fault."""
+    with jax.named_scope("state"):
+        restore = positions == state["prompt_end"]
+        fault = ~restore & (positions != state["last"] + 1)
+    with jax.named_scope("ssm_restore"):
+        state = dict(state, mamba=restored(state["mamba"], restore))
+    x = _embed(cfg, params, ids)
+    x, states, got = _layers(
+        cfg, params, x, state,
+        lambda p, u, st: mamba_decode(cfg, p, u, st),
+        lambda p, u, cache: attn_decode(cfg, p, u, cache, positions))
+    logits, greedy = _head(cfg, params, x)
+    with jax.named_scope("state"):
+        gained = {"steps": 1, "ssm_rows": ids.shape[0],
+                  "kv_rows_read": jnp.sum(positions + 1),
+                  "experts_touched": jnp.sum(got > 0),
+                  "expert_hits": jnp.sum(got),
+                  "restores": jnp.sum(restore),
+                  "position_faults": jnp.sum(fault)}
+        new = dict(states, prompt_end=state["prompt_end"], last=positions,
+                   counters={
+                       name: state["counters"][name]
+                       + jnp.asarray(gained[name]).astype(jnp.uint32)
+                       for name in COUNTERS})
+    return new, (logits, greedy)
+
+
+# -- weights of the right shapes, and registration ----------------------------
+
+
+def param_shapes(cfg: NemotronHConfig) -> dict:
+    """The pytree of ``(shape, role)`` a weights maker fills: matrices
+    carry the role their init gain is looked up by, norm gains ``norm``,
+    and the Mamba-2 layer's small vectors their own names."""
+    h, f, e = cfg.hidden_size, cfg.expert_width, cfg.experts
+    d, heads = cfg.d_inner, cfg.mamba_heads
+    kinds = {
+        "M": {"norm": ((h,), "norm"),
+              "in_proj": ((h, d + cfg.conv_dim + heads), "in_proj"),
+              "conv_w": ((cfg.conv_kernel, cfg.conv_dim), "conv_w"),
+              "conv_b": ((cfg.conv_dim,), "conv_b"),
+              "dt_bias": ((heads,), "dt_bias"),
+              "A_log": ((heads,), "A_log"), "D": ((heads,), "D"),
+              "gate_norm": ((d,), "norm"),
+              "out_proj": ((d, h), "out_proj")},
+        "E": {"norm": ((h,), "norm"),
+              "router": ((h, cfg.n_routed_experts), "router"),
+              "router_bias": ((cfg.n_routed_experts,), "router_bias"),
+              "experts": {"up": ((e, h, f), "up"),
+                          "down": ((e, f, h), "expert_down")},
+              "shared": {"up": ((h, cfg.shared_width), "up"),
+                         "down": ((cfg.shared_width, h), "down")}},
+        "*": {"norm": ((h,), "norm"),
+              "q": ((h, cfg.heads * cfg.head_dim), "q"),
+              "k": ((h, cfg.kv_heads * cfg.head_dim), "k"),
+              "v": ((h, cfg.kv_heads * cfg.head_dim), "v"),
+              "o": ((cfg.heads * cfg.head_dim, h), "o")}}
+    return {"embed": ((cfg.vocab, h), "embed"),
+            "layers": [kinds[kind] for kind in cfg.pattern],
+            "final_norm": ((h,), "norm"), "head": ((h, cfg.vocab), "head")}
+
+
+def init_params(cfg: NemotronHConfig, key, dtype=None) -> Params:
+    """Seeded weights of the right shapes: matrices N(0, 1/fan_in)
+    (residual branches halved), norm gains and ``D`` 1, ``delta`` at rest
+    log-uniform in 0.001-0.1 and ``exp(A_log)`` in 1-2 (a head remembers
+    tens to a thousand tokens), a small router bias.  For tests and
+    examples; a deployment loads its own."""
+    dtype = dtype or jnp.bfloat16
+    if isinstance(key, int):
+        key = jax.random.PRNGKey(key)
+    leaves, treedef = jax.tree_util.tree_flatten(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)
+        and isinstance(x[1], str))
+    out = []
+    for n, (shape, role) in enumerate(leaves):
+        k = jax.random.fold_in(key, n)
+        if role in ("norm", "D"):
+            out.append(jnp.ones(shape, jnp.float32))
+        elif role == "dt_bias":
+            rest = jnp.exp(jax.random.uniform(
+                k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+            out.append(rest + jnp.log(-jnp.expm1(-rest)))   # softplus^-1
+        elif role == "A_log":
+            out.append(jnp.log(jax.random.uniform(k, shape, jnp.float32,
+                                                  1.0, 2.0)))
+        elif role in ("conv_b", "router_bias"):
+            out.append(0.1 * jax.random.normal(k, shape, jnp.float32))
+        elif role == "conv_w":
+            out.append(jax.random.normal(k, shape, jnp.float32)
+                       * shape[0] ** -0.5)
+        else:
+            fan_in = 1 if role == "embed" else shape[-2]
+            gain = 0.5 if role in ("o", "out_proj", "down",
+                                   "expert_down") else 1.0
+            out.append((jax.random.normal(k, shape)
+                        * (gain / fan_in) ** 0.5).astype(dtype))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@functools.lru_cache(maxsize=8)
+def entries(cfg: NemotronHConfig, streams: int, positions: int,
+            chunk: int) -> Dict[str, Any]:
+    """What :func:`register` hands ``register_stateful_model``: the two
+    entry points with their input schemas, and ``init_state``.  Cached
+    by the sizes, so that two sets of weights of one configuration share
+    their programs."""
+    i32 = np.int32
+    return {
+        "entries": {
+            "decode": (functools.partial(decode, cfg),
+                       [(streams,), (streams,)], i32),
+            "prefill": (functools.partial(prefill, cfg),
+                        [(chunk,), (1,), (1,), (1,)], i32)},
+        "setup_entries": ("prefill",),
+        "init_state": functools.partial(init_state, cfg, streams=streams,
+                                        positions=positions),
+        "counters": counters,
+        "counter_units": functools.partial(counter_units, cfg)}
+
+
+def register(name: str, cfg: NemotronHConfig, params: Params, streams: int,
+             positions: int, chunk: int) -> str:
+    """Register ``params`` as the stateful model ``name`` for
+    ``tensor_filter framework=jax-xla model=<name>``: a filter whose
+    negotiated input is ``(ids[chunk], slot[1], start[1], count[1])``
+    prefills, one whose input is ``(ids[streams], positions[streams])``
+    decodes; two filters with one ``shared-tensor-filter-key`` work on
+    one state (recurrent states, their snapshots and the caches)."""
+    from ..filters.jax_xla import register_stateful_model
+
+    return register_stateful_model(
+        name, params=params, **entries(cfg, streams, positions, chunk))

@@ -739,7 +739,8 @@ def gqa_decode_attention(q, k, v, positions, window: int, scale: float,
 
 # -- the routed experts' grouped product --------------------------------------
 
-#: fast memory both buffers of a grid step's three weight tiles may take;
+#: fast memory both buffers of a grid step's weight tiles (three for a
+#: gated expert, two for an ungated one) may take;
 #: the call states it (and its rows' part) through ``vmem_limit_bytes``.
 #: Alone the kernel reads a little faster the larger its tiles; inside a
 #: decode step, 24 MiB read faster than 32 and 48 (``PERF.md`` section
@@ -748,27 +749,31 @@ _GROUPED_VMEM_BUDGET = 24 << 20
 
 
 def grouped_tile(hidden: int, inter: int, dtype,
-                 budget: int = _GROUPED_VMEM_BUDGET) -> int:
+                 budget: int = _GROUPED_VMEM_BUDGET, matrices: int = 3) -> int:
     """Columns of the intermediate width a grid step of
     :func:`grouped_gated_product` takes: the largest divisor of
-    ``inter`` of whole lanes whose three tiles (``[hidden, tile]``
-    twice, ``[tile, hidden]``), twice for the pipeline's two buffers,
-    fit ``budget``; 0 where none does."""
+    ``inter`` of whole lanes whose ``matrices`` tiles (``[hidden,
+    tile]`` for ``up`` and, of a gated expert's three, ``gate``;
+    ``[tile, hidden]``), twice for the pipeline's two buffers, fit
+    ``budget``; 0 where none does."""
     size = np.dtype(dtype).itemsize
     for tile in range(inter // _LANE * _LANE, 0, -_LANE):
-        if inter % tile == 0 and 6 * hidden * tile * size <= budget:
+        if inter % tile == 0 \
+                and 2 * matrices * hidden * tile * size <= budget:
             return tile
     return 0
 
 
 def grouped_gated_product_refusal(x_shape, gate_shape, down_shape, dtypes,
-                                  blk: int) -> Optional[str]:
+                                  blk: int, matrices: int = 3
+                                  ) -> Optional[str]:
     """Why :func:`grouped_gated_product` cannot take these shapes, or
-    None: ``x [tokens, hidden]`` beside ``gate`` (and ``up``)
-    ``[experts, hidden, inter]`` and ``down [experts, inter, hidden]``,
-    all of ONE type (``dtypes``: the set of theirs), bf16 or float32,
-    both widths whole lanes, a block of whole sublanes, and a tile of
-    the intermediate width that fits."""
+    None: ``x [tokens, hidden]`` beside ``up`` (and, of a gated
+    expert's three ``matrices``, ``gate``: ``gate_shape`` is the shape
+    of either) ``[experts, hidden, inter]`` and ``down [experts, inter,
+    hidden]``, all of ONE type (``dtypes``: the set of theirs), bf16 or
+    float32, both widths whole lanes, a block of whole sublanes, and a
+    tile of the intermediate width that fits."""
     names = sorted(np.dtype(d).name for d in dtypes)
     if names not in (["bfloat16"], ["float32"]):
         return f"operands of {', '.join(names)}: all bfloat16 or all float32"
@@ -786,7 +791,7 @@ def grouped_gated_product_refusal(x_shape, gate_shape, down_shape, dtypes,
     if blk < 1 or blk % _sublane(dtype):
         return f"a block of {blk} rows is not whole tiles of " \
                f"{_sublane(dtype)}"
-    if not grouped_tile(hidden, inter, dtype):
+    if not grouped_tile(hidden, inter, dtype, matrices=matrices):
         return f"no tile of [{hidden}, {inter}] fits " \
                f"{_GROUPED_VMEM_BUDGET >> 20} MiB twice"
     return None
@@ -794,23 +799,24 @@ def grouped_gated_product_refusal(x_shape, gate_shape, down_shape, dtypes,
 
 def grouped_gated_product(x, gate, up, down, row_token, block_expert,
                           blocks, blk: int, act, tile: Optional[int] = None):
-    """The gated MLPs of the experts a plan of ``models/moe.py``
+    """The MLPs of the experts a plan of ``models/moe.py``
     ``dispatch`` lays rows out for, as one call: ``x [tokens, hidden]``,
     ``gate`` and ``up`` ``[experts, hidden, inter]``, ``down [experts,
     inter, hidden]`` all of one type, ``row_token [rows]`` (the token a
     row holds, ``tokens`` = none), ``block_expert [rows / blk]``,
-    ``blocks`` (how many of them are in use), ``act`` the gate's
+    ``blocks`` (how many of them are in use), ``act`` the
     nonlinearity.  Returns ``[rows + 1, hidden]``: row ``r`` of a block
     in use is ``(act(x_t gate_e) * (x_t up_e)) down_e`` of its token and
-    its block's expert, products accumulated in float32, the hidden
-    activation rounded to ``x``'s type before ``down``, the row rounded
-    once; the last row is zero; rows of blocks NOT in use are not
-    written and hold anything.
+    its block's expert, or, where ``gate`` is None (an UNGATED expert:
+    two matrices, two tiles a step), ``act(x_t up_e) down_e``; products
+    accumulated in float32, the hidden activation rounded to ``x``'s
+    type before ``down``, the row rounded once; the last row is zero;
+    rows of blocks NOT in use are not written and hold anything.
 
     The grid walks (block, tile of the intermediate width) with
     ``block_expert`` and ``blocks`` prefetched, so the weight operands'
     index maps choose the expert and the pipeline fetches the next
-    step's three tiles while this step multiplies: a block of 32 rows
+    step's weight tiles while this step multiplies: a block of 32 rows
     is nothing to compute, the call streams the touched experts'
     matrices once.  Steps beyond the blocks in use name the tiles the
     last block in use named (a repeated block is not copied again) and
@@ -819,14 +825,17 @@ def grouped_gated_product(x, gate, up, down, row_token, block_expert,
     chooses."""
     import jax.numpy as jnp
 
+    ins = [up] if gate is None else [gate, up]
     refusal = grouped_gated_product_refusal(
-        x.shape, gate.shape, down.shape,
-        {x.dtype, gate.dtype, up.dtype, down.dtype}, blk)
+        x.shape, up.shape, down.shape,
+        {x.dtype, down.dtype} | {w.dtype for w in ins}, blk,
+        len(ins) + 1)
     if refusal:
         raise ValueError(f"grouped_gated_product: {refusal}")
     jax, pl, pltpu = _pl()
-    hidden, inter = gate.shape[1], gate.shape[2]
-    tile = tile or grouped_tile(hidden, inter, x.dtype)
+    hidden, inter = up.shape[1], up.shape[2]
+    tile = tile or grouped_tile(hidden, inter, x.dtype,
+                                matrices=len(ins) + 1)
     tiles, grid_blocks = inter // tile, row_token.shape[0] // blk
     hp = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
     tokens = x.shape[0]
@@ -850,7 +859,8 @@ def grouped_gated_product(x, gate, up, down, row_token, block_expert,
                                    precision=hp)
 
     def kernel(expert_ref, blocks_ref, *refs):
-        *row_refs, gate_ref, up_ref, down_ref, o_ref, acc_ref = refs
+        row_refs, in_refs = refs[:-len(ins) - 3], refs[-len(ins) - 3:-3]
+        down_ref, o_ref, acc_ref = refs[-3:]
         b, t = pl.program_id(0), pl.program_id(1)
 
         @pl.when(b < blocks_ref[0])
@@ -862,7 +872,9 @@ def grouped_gated_product(x, gate, up, down, row_token, block_expert,
                 xb = mm(holds.astype(x.dtype), x_ref[...]).astype(x.dtype)
             else:
                 xb = row_refs[0][...]
-            h = act(mm(xb, gate_ref[...])) * mm(xb, up_ref[...])
+            h = act(mm(xb, in_refs[0][...]))
+            if gate is not None:
+                h = h * mm(xb, in_refs[1][...])
             part = mm(h.astype(xb.dtype), down_ref[...])
             if tiles == 1:
                 o_ref[...] = part.astype(o_ref.dtype)
@@ -908,16 +920,15 @@ def grouped_gated_product(x, gate, up, down, row_token, block_expert,
     size = np.dtype(x.dtype).itemsize
     # the weights' tiles twice; the rows in and out twice, their float32
     # sum and a step's part of it; the hidden activation's float32 parts
-    vmem = 6 * hidden * tile * size + blk * hidden * (4 * size + 8) \
-        + 4 * blk * tile * 4 + (4 << 20)
+    vmem = 2 * (len(ins) + 1) * hidden * tile * size \
+        + blk * hidden * (4 * size + 8) + 4 * blk * tile * 4 + (4 << 20)
     grid = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(grid_blocks + 1, tiles),
         in_specs=[
             pl.BlockSpec(row_specs[0], row_block),
             *[pl.BlockSpec(whole, lambda b, t, expert, count: (0, 0))
               for whole in row_specs[1:]],
-            pl.BlockSpec((None, hidden, tile), in_tile),
-            pl.BlockSpec((None, hidden, tile), in_tile),
+            *[pl.BlockSpec((None, hidden, tile), in_tile) for _ in ins],
             pl.BlockSpec((None, tile, hidden), out_tile),
         ],
         out_specs=pl.BlockSpec((blk, hidden), out_block),
@@ -933,5 +944,5 @@ def grouped_gated_product(x, gate, up, down, row_token, block_expert,
             vmem_limit_bytes=vmem),
         interpret=_interpret(),
     )(block_expert.astype(jnp.int32),
-      jnp.reshape(blocks, (1,)).astype(jnp.int32), *row_operands, gate, up,
+      jnp.reshape(blocks, (1,)).astype(jnp.int32), *row_operands, *ins,
       down)

@@ -350,7 +350,9 @@ class StateStats:
     and routed experts ``cache_bytes_read``, ``experts_touched``,
     ``expert_hits``; a model with two kinds of cache tells them apart,
     ``window_bytes_read`` of its rings and ``full_bytes_read`` of its
-    dense caches, and ``cache_bytes_read`` is their sum;
+    dense caches, and ``cache_bytes_read`` is their sum; a model with a
+    recurrent state adds ``ssm_bytes`` read and written, ``restores``
+    from its snapshots and ``position_faults``;
     ``Documentation/observability.md``)."""
 
     def __init__(self):

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from nnstreamer_tpu.models import deepseek_v2 as dsv2
 from nnstreamer_tpu.models import moe
+from nnstreamer_tpu.models import nemotron_h as nh
 from nnstreamer_tpu.models import smallthinker as st
 from nnstreamer_tpu.ops import kernels
 from nnstreamer_tpu.utils import profile
@@ -24,13 +25,17 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HELD, ROUTED, K, HIDDEN, INTER = 4, 8, 2, 128, 256
 
 
-def _experts(dtype, seed=0):
+def _experts(dtype, seed=0, act="silu", inter=INTER):
+    """An expert's matrices: three of a gated form, two of an ungated."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    names = ("gate", "up", "down") if moe.activation(act)[1] \
+        else ("", "up", "down")
     return {name: (jax.random.normal(key, shape) * shape[1] ** -0.5)
             .astype(dtype)
             for key, name, shape in zip(
-                keys, ("gate", "up", "down"),
-                [(HELD, HIDDEN, INTER)] * 2 + [(HELD, INTER, HIDDEN)])}
+                keys, names,
+                [(HELD, HIDDEN, inter)] * 2 + [(HELD, inter, HIDDEN)])
+            if name}
 
 
 def _routes(case, n):
@@ -51,19 +56,19 @@ def _routes(case, n):
 def _both(dtype, act, case, tile):
     """The kernel's and the loop's ``[rows + 1, hidden]`` and the plan."""
     n = {"one-token": 1, "a-prefill-chunk": 264}.get(case, 32)
-    p = _experts(dtype)
+    p = _experts(dtype, act=act)
     x = jax.random.normal(jax.random.PRNGKey(1), (n, HIDDEN)).astype(dtype)
     plan = moe.dispatch(_routes(case, n), n, 0, HELD)
     got = kernels.grouped_gated_product(
-        x, p["gate"], p["up"], p["down"], plan["row_token"],
+        x, p.get("gate"), p["up"], p["down"], plan["row_token"],
         plan["block_expert"], plan["blocks"], plan["blk"],
-        moe.activation(act), tile=tile)
+        moe.activation(act)[0], tile=tile)
     return got, moe.grouped_experts_loop(p, x, plan, act), plan
 
 
 CASES = [(dtype, act, case, tile)
          for dtype in ("bfloat16", "float32")
-         for act in ("silu", "relu")
+         for act in ("silu", "relu", "relu2")
          for case in ("seeded", "all-on-one", "two-blocks-of-one",
                       "none-held", "few-blocks", "one-token",
                       # more tokens than a block (256 rows): the rows are
@@ -145,9 +150,72 @@ def test_a_refused_shape_keeps_the_loop(x, gate, dtypes, blk, says):
                           np.asarray(moe.grouped_experts_loop(p, xs, plan)))
 
 
+@pytest.mark.parametrize("inter", [256, 192])
+@pytest.mark.parametrize("act", ["silu", "relu", "relu2"])
+def test_kernel_and_loop_are_the_per_token_product(act, inter):
+    """Gated and ungated alike, ``grouped_experts`` is each (token,
+    expert) pair's own product, written out pair by pair: through the
+    kernel at a width of whole lanes, through the loop at one that is
+    not (192 = a lane and a half, which the kernel refuses)."""
+    p = _experts(jnp.float32, act=act, inter=inter)
+    assert set(p) == ({"gate", "up", "down"} if act != "relu2"
+                      else {"up", "down"})
+    x = jax.random.normal(jax.random.PRNGKey(1), (24, HIDDEN))
+    idx = _routes("seeded", 24)
+    plan = moe.dispatch(idx, 24, 0, HELD)
+    weight = jax.random.uniform(jax.random.PRNGKey(3), idx.shape)
+    text = str(jax.make_jaxpr(
+        lambda p, x: moe.grouped_experts(p, x, plan, act))(p, x))
+    assert ("pallas_call" in text) == (inter == 256)
+    got = [np.asarray(moe.combine(fn(p, x, plan, act), plan, weight))
+           for fn in (moe.grouped_experts, moe.grouped_experts_loop)]
+    want = np.zeros((24, HIDDEN))
+    xs, ws = np.asarray(x, np.float64), np.asarray(weight, np.float64)
+    q = {k: np.asarray(v, np.float64) for k, v in p.items()}
+    for t in range(24):
+        for j, e in enumerate(np.asarray(idx)[t]):
+            if e >= HELD:
+                continue                    # an expert held elsewhere
+            up = xs[t] @ q["up"][e]
+            if act == "relu2":
+                h = np.maximum(up, 0) ** 2
+            else:
+                g = xs[t] @ q["gate"][e]
+                h = (g / (1 + np.exp(-g)) if act == "silu"
+                     else np.maximum(g, 0)) * up
+            want[t] += ws[t, j] * (h @ q["down"][e])
+    for out in got:
+        assert np.allclose(out, want, rtol=2e-5, atol=2e-5)
+
+
+def test_an_ungated_expert_streams_two_tiles_a_step():
+    """Two weight operands and not three with a dummy: the call's
+    operands are the plan's two scalars, the rows' two, ``up`` and
+    ``down``."""
+    p, x = _experts(jnp.float32, act="relu2"), jnp.zeros((32, HIDDEN))
+    plan = moe.dispatch(_routes("seeded", 32), 32, 0, HELD)
+    calls = {act: _calls(jax.make_jaxpr(
+        lambda p, x: moe.grouped_experts(p, x, plan, act))(q, x).jaxpr, [])
+        for act, q in (("relu2", p), ("relu", _experts(jnp.float32)))}
+    assert [len(c[0].invars) for c in calls.values()] == [6, 7]
+    with pytest.raises(ValueError, match="relu2"):
+        moe.activation("gelu")
+
+
 def test_the_tile_follows_the_shapes():
     """The largest divisor of the intermediate width, of whole lanes,
-    whose three tiles fit the budget twice: the two cells' experts."""
+    whose tiles (three of a gated expert, two of an ungated one) fit
+    the budget twice: the three cells' experts."""
+    # Nemotron-3-Nano's 1,856 columns stored as 1,920 = 15 lanes: tiles
+    # of 640; the published width has no tile of whole lanes at all
+    assert kernels.grouped_tile(2688, 1920, jnp.bfloat16, matrices=2) == 640
+    assert kernels.grouped_tile(2688, 1856, jnp.bfloat16, matrices=2) == 0
+    assert "whole lanes" in kernels.grouped_gated_product_refusal(
+        (128, 2688), (16, 2688, 1856), (16, 1856, 2688), {"bfloat16"}, 128,
+        matrices=2)
+    assert kernels.grouped_gated_product_refusal(
+        (128, 2688), (16, 2688, 1920), (16, 1920, 2688), {"bfloat16"}, 128,
+        matrices=2) is None
     assert kernels.grouped_tile(2560, 768, jnp.bfloat16) == 768
     assert kernels.grouped_tile(5120, 1536, jnp.bfloat16) == 384
     assert kernels.grouped_tile(5120, 1536, jnp.float32) == 128
@@ -183,7 +251,7 @@ def _toy(name, **changed):
 
 
 def _decode_programs():
-    """Both models' decode steps at toy sizes with experts of whole
+    """The three models' decode steps at toy sizes with experts of whole
     lanes, 16 streams in bf16: ``(name, jaxpr, expert layers)``."""
     cfg = dsv2.DeepSeekV2Config.from_dict(_toy(
         "toy_dsv2.json", hidden_size=128, moe_intermediate_size=128))
@@ -200,6 +268,13 @@ def _decode_programs():
     yield "smallthinker", jax.make_jaxpr(
         lambda p, s, i, at: st.decode(cfg, p, s, i, at))(
             params, state, ids, ids), list(range(cfg.layers))
+    cfg = nh.NemotronHConfig.from_dict(_toy(
+        "toy_nemotron3.json", hidden_size=128, moe_intermediate_size=128))
+    params = jax.eval_shape(lambda: nh.init_params(cfg, 0))
+    state = jax.eval_shape(lambda: nh.init_state(cfg, params, 16, 32))
+    yield "nemotron_h", jax.make_jaxpr(
+        lambda p, s, i, at: nh.decode(cfg, p, s, i, at))(
+            params, state, ids, ids), [1, 3, 6]
 
 
 def _calls(jaxpr, found):
@@ -214,10 +289,10 @@ def _calls(jaxpr, found):
 @pytest.mark.parametrize("reader", ["program", "benchmark"])
 def test_the_kernels_time_is_booked_to_the_experts_stage(reader):
     """The call sits straight in the ``experts`` scope of its layer in
-    both models' decode programs, so the stage reader books its device
-    time to ``nns.model/layerNN/moe/experts``, which the two accepted
-    stage metrics (``experts_ms_per_window``,
-    ``relu_experts_ms_per_window``) sum."""
+    three models' decode programs, so the stage reader books its device
+    time to ``nns.model/layerNN/moe/experts``, which the cells' stage
+    metrics (``experts_ms_per_window``, ``relu_experts_ms_per_window``,
+    ``relu2_experts_ms_per_window``) sum."""
     if reader == "program":
         stage_of = profile.stage_of
     else:

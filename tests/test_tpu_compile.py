@@ -96,3 +96,98 @@ def test_grouped_gated_product_compiles_at_the_cells_shapes(
     # step, a prefill chunk's rows laid out for the plan
     assert compiled.memory_analysis().temp_size_in_bytes \
         < (1 << 20) + (grid * blk * hidden * 2 if tokens > blk else 0)
+
+
+def test_the_hybrid_cells_kernels_compile_at_its_shapes(one_chip, monkeypatch):
+    """`nemotron3.decode4k`'s calls, bf16, as Mosaic kernels: attention
+    of 128 streams, 2 groups of 16 heads of 128 over a dense cache of
+    4,096; the UNGATED grouped product of 16 experts of 2,688 x 1,920
+    (the published 1,856 columns stored as 15 lanes) at a decode step's
+    block of 128 rows and a prefill chunk's blocks of 256, two tiles of
+    640 columns a step."""
+    from nnstreamer_tpu.models import moe
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    fn = jax.jit(functools.partial(kernels.gqa_decode_attention,
+                                   window=4096, scale=128 ** -0.5))
+    compiled = fn.lower(shape((128, 2, 16, 128)), shape((128, 2, 4096, 128)),
+                        shape((128, 2, 4096, 128)),
+                        shape((128,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    held, hidden, inter = 16, 2688, 1920
+    assert kernels.grouped_tile(hidden, inter, jnp.bfloat16, matrices=2) == 640
+    for tokens, grid in ((128, 6 + held), (2048, 48 + held)):
+        blk = moe.block_rows(tokens)
+        fn = jax.jit(functools.partial(
+            kernels.grouped_gated_product, blk=blk,
+            act=moe.activation("relu2")[0]), static_argnums=(1,))
+        compiled = fn.lower(shape((tokens, hidden)), None,
+                            shape((held, hidden, inter)),
+                            shape((held, inter, hidden)),
+                            shape((grid * blk,), jnp.int32),
+                            shape((grid,), jnp.int32),
+                            shape((), jnp.int32)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < (1 << 20) + (grid * blk * hidden * 2 if tokens > blk else 0)
+
+
+@pytest.mark.parametrize("entry,temp_mb", [("decode", 64), ("prefill", 768)])
+def test_the_hybrid_cells_programs_copy_no_recurrent_state(
+        one_chip, monkeypatch, entry, temp_mb):
+    """`nemotron3.decode4k`'s two programs at the cell's sizes (20
+    layers, 128 streams, 4,096 positions, chunks of 2,048; 4.0 GB of
+    weights and 6.5 GB of state as arguments): the state is updated in
+    the donated buffers.  One layer's recurrent state is 268 MB, so a
+    copy of it (a `lax.cond` around the step made nine, 2.4 GB) shows
+    in the temporaries."""
+    import json
+    import os
+
+    from nnstreamer_tpu.models import nemotron_h as nh
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "nemotron3_nano_share8.json")
+    with open(path) as f:
+        raw = json.load(f)
+    cfg = nh.NemotronHConfig.from_dict(raw)
+    stored = raw["expert_columns_stored"]
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: nh.init_params(cfg, 0))
+    for layer in params["layers"]:
+        if "experts" in layer:           # as the benchmark stores them
+            up, down = layer["experts"]["up"], layer["experts"]["down"]
+            layer["experts"] = {
+                "up": jax.ShapeDtypeStruct(up.shape[:2] + (stored,), up.dtype),
+                "down": jax.ShapeDtypeStruct(
+                    (down.shape[0], stored, down.shape[2]), down.dtype)}
+    state = jax.eval_shape(lambda: nh.init_state(cfg, params, 128, 4096))
+    nbytes = sum(a.size * a.dtype.itemsize
+                 for a in jax.tree_util.tree_leaves(state))
+    assert 6.5e9 < nbytes < 6.6e9
+
+    def i32(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+
+    fn, inputs = {"decode": (nh.decode, [i32(128), i32(128)]),
+                  "prefill": (nh.prefill, [i32(2048), i32(1), i32(1),
+                                           i32(1)])}[entry]
+    compiled = jax.jit(functools.partial(fn, cfg), donate_argnums=(1,)) \
+        .lower(on_chip(params), on_chip(state), *inputs).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= nbytes
+    assert memory.temp_size_in_bytes < temp_mb << 20
+    assert compiled.as_text().count("tpu_custom_call") >= 8 + (
+        3 if entry == "decode" else 0)

@@ -27,10 +27,13 @@ mixer behind a pre-norm and a residual add and no separate MLP:
 and a V row a position (``[streams, kv heads, positions, head_dim]``):
 a row written too far is overwritten before it is read, so a padded
 prefill chunk and a rewind to the prompt's end cost nothing there.  A
-Mamba-2 layer keeps ONE array a stream (``ssm [streams, heads,
-head_dim, state]`` float32) and the last ``conv_kernel - 1`` inputs of
-its convolution (``conv [streams, conv_kernel - 1, conv_dim]``), both
-overwritten by every token.  So (``Documentation/stateful-models.md``):
+Mamba-2 layer keeps ONE array a stream (``ssm [streams, groups, state,
+heads a group x head_dim]`` float32: the state axis before a group's
+values, so that the decode step's kernel finds a head's decay and
+``delta x`` as rows and ``y`` as a sum over sublanes) and the last
+``conv_kernel - 1`` inputs of its convolution (``conv [streams,
+conv_kernel - 1, conv_dim]``), both overwritten by every token.  So
+(``Documentation/stateful-models.md``):
 
 * :func:`prefill` takes a fourth tensor, ``count``: the first ``count``
   tokens of the chunk are real.  A padded token gets ``delta = 0``,
@@ -48,11 +51,20 @@ overwritten by every token.  So (``Documentation/stateful-models.md``):
   the streams that restore in it and of no other.
 
 :func:`prefill` runs the recurrence chunked (``chunk_size`` tokens: the
-quadratic form inside a chunk, the carried state between chunks),
-:func:`decode` one step of it.
+quadratic form inside a chunk, the carried state between chunks; the
+scan carries ``[heads, head_dim, state]`` and a chunk transposes its
+slot's state once at each end), :func:`decode` one step of it: where
+the state's shape allows (:func:`step_refusal`) ONE kernel a layer
+(``ops/kernels.py`` ``ssm_decode_step``) that reads a stream's state
+once, from its snapshot or live as the stream restores or not, updates
+it and reduces it to ``y`` in fast memory and writes it once over the
+live state; for every other shape the ``jnp`` step from the live state
+behind :func:`restored`, the loop that copies the restoring streams'
+snapshots first.
 
 Stage scopes (``Documentation/observability.md``): ``embed``, ``state``,
-``ssm_restore`` (the loop that copies snapshots), ``layerNN/mamba``
+``ssm_restore`` (the loop that copies snapshots: empty where the step
+is the kernel), ``layerNN/mamba``
 (``.../in_proj``, ``.../conv``, ``.../scan`` or
 ``.../step``, ``.../gate_norm``, ``.../out_proj``), ``layerNN/attn``
 (``.../cache_write``, ``.../gqa_decode_attention``),
@@ -76,6 +88,7 @@ except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
 from ..ops import kernels
+from ..utils import profile as _profile
 from . import moe
 
 Params = dict
@@ -338,7 +351,12 @@ def mamba_prefill(cfg: NemotronHConfig, p, u, st, slot, start, count):
     z, xbc, dt = _in_proj(cfg, p, u)
     fresh = start == 0
     conv0 = jnp.where(fresh, 0, st["conv"][slot])
-    ssm0 = jnp.where(fresh, 0.0, st["ssm"][slot])
+    g, r = cfg.groups, cfg.mamba_heads // cfg.groups
+    # the filter keeps [groups, state, heads a group x head_dim]; the scan
+    # carries [heads, head_dim, state]: one transposition at each end
+    ssm0 = jnp.where(fresh, 0.0, st["ssm"][slot]).reshape(
+        g, cfg.state_size, r, cfg.mamba_head_dim).transpose(0, 2, 3, 1) \
+        .reshape(cfg.mamba_heads, cfg.mamba_head_dim, cfg.state_size)
     with jax.named_scope("conv"):
         ext = jnp.concatenate([conv0, xbc])           # [K - 1 + C, conv_dim]
         act = p["conv_b"] + sum(
@@ -353,6 +371,8 @@ def mamba_prefill(cfg: NemotronHConfig, p, u, st, slot, start, count):
                           jax.nn.softplus(dt + p["dt_bias"]), 0.0)
         y, ssm = ssd_scan(cfg, x, b, c, delta, p["A_log"], ssm0)
         y = y + x * _by_group(cfg, p["D"])[..., None]
+        ssm = ssm.reshape(g, r, cfg.mamba_head_dim, cfg.state_size) \
+            .transpose(0, 3, 1, 2).reshape(st["ssm"].shape[1:])
         st = {"conv": st["conv"].at[slot].set(conv),
               "conv_snap": st["conv_snap"].at[slot].set(conv),
               "ssm": st["ssm"].at[slot].set(ssm),
@@ -364,7 +384,9 @@ def restored(mamba: list, restore) -> list:
     """The ``M`` layers' states with the live state of every stream of
     ``restore [B]`` overwritten by its snapshot, a stream at a time in
     place: a step in which no stream restores reads no snapshot, and one
-    in which some do reads theirs alone."""
+    in which some do reads theirs alone.  The path of the shapes
+    :func:`step_refusal` names: where the step is the kernel, the kernel
+    chooses a stream's source and this loop is not in the program."""
     first = jnp.argsort(~restore)             # the restoring streams first
 
     def one(i, live):
@@ -380,25 +402,49 @@ def restored(mamba: list, restore) -> list:
     return [dict(st, **now) for st, now in zip(mamba, live)]
 
 
-def mamba_decode(cfg: NemotronHConfig, p, u, st):
-    """One token of every stream, ``u [B, hidden]``, from the live
-    state, which is overwritten; the snapshot is kept."""
+def step_refusal(st: dict):
+    """Why one ``M`` layer's decode step is not ``ops/kernels.py``
+    ``ssm_decode_step`` for a layer state of these shapes, or None."""
+    return kernels.ssm_decode_step_refusal(
+        st["ssm"].shape, {st["ssm"].dtype, st["ssm_snap"].dtype})
+
+
+def mamba_decode(cfg: NemotronHConfig, p, u, st, restore):
+    """One token of every stream, ``u [B, hidden]``; the live state is
+    overwritten, the snapshot is kept.  One algorithm, two programs,
+    chosen from the state's shape (:func:`step_refusal`): the kernel,
+    which starts each stream of ``restore [B]`` from its snapshot and
+    every other from its live state; or, for a shape it refuses, the
+    ``jnp`` step from the live state, which :func:`decode` has run
+    through :func:`restored` first.  The set-up span this is traced
+    under says which (``utils/profile.py`` ``note``)."""
+    refusal = step_refusal(st)
+    shapes = f"mamba_decode {tuple(st['ssm'].shape)} " \
+             f"{st['ssm'].dtype.name}"
+    _profile.note(f"{shapes}: the jnp step behind the restore loop "
+                  f"({refusal})" if refusal else f"{shapes}: the kernel")
     z, xbc, dt = _in_proj(cfg, p, u)
     with jax.named_scope("conv"):
-        window = jnp.concatenate([st["conv"], xbc[:, None]], axis=1)
+        held = st["conv"] if refusal else jnp.where(
+            restore[:, None, None], st["conv_snap"], st["conv"])
+        window = jnp.concatenate([held, xbc[:, None]], axis=1)
         act = p["conv_b"] + jnp.sum(
             window.astype(jnp.float32) * p["conv_w"], axis=1)
         x, b, c = _ssm_inputs(cfg, jax.nn.silu(act))
     with jax.named_scope("step"):
         delta = _by_group(cfg, jax.nn.softplus(dt + p["dt_bias"]))
         a = jnp.exp(-delta * _by_group(cfg, jnp.exp(p["A_log"])))
-        shape = (u.shape[0], cfg.groups, -1, cfg.mamba_head_dim,
-                 cfg.state_size)
-        ssm = a[..., None, None] * st["ssm"].reshape(shape) \
-            + (delta[..., None] * x)[..., None] * b[:, :, None, None, :]
-        y = jnp.sum(ssm * c[:, :, None, None, :], axis=-1) \
-            + x * _by_group(cfg, p["D"])[..., None]
-    st = dict(st, conv=window[:, 1:], ssm=ssm.reshape(st["ssm"].shape))
+        # a head's decay over its lanes, beside its delta x
+        lanes = (u.shape[0], cfg.groups, -1)
+        a = jnp.broadcast_to(a[..., None], x.shape).reshape(lanes)
+        dx = (delta[..., None] * x).reshape(lanes)
+        if refusal:
+            ssm, y = kernels.ssm_decode_step_reference(st["ssm"], a, dx, b, c)
+        else:
+            ssm, y = kernels.ssm_decode_step(st["ssm"], st["ssm_snap"],
+                                             restore, a, dx, b, c)
+        y = y.reshape(x.shape) + x * _by_group(cfg, p["D"])[..., None]
+    st = dict(st, conv=window[:, 1:], ssm=ssm)
     return _gate_norm_out(cfg, p, y, z, u.dtype), st
 
 
@@ -561,7 +607,11 @@ def init_state(cfg: NemotronHConfig, params, streams: int, positions: int,
     if positions > cfg.max_positions:
         raise ValueError(f"nemotron_h: {positions} positions, the model "
                          f"has {cfg.max_positions}")
-    ssm = (streams, cfg.mamba_heads, cfg.mamba_head_dim, cfg.state_size)
+    # the state axis before a group's heads x head_dim: 512 lanes of
+    # [128 sublanes], so that a step's decay and delta x are rows and y is
+    # a sum over sublanes (ops/kernels.py ssm_decode_step)
+    ssm = (streams, cfg.groups, cfg.state_size,
+           cfg.mamba_heads // cfg.groups * cfg.mamba_head_dim)
     # the inputs' axis before the channels': 3 rows of 6,144 lanes, where
     # [.., 6144, 3] would pad every channel's three values to a tile
     conv = (streams, cfg.conv_kernel - 1, cfg.conv_dim)
@@ -635,11 +685,14 @@ def decode(cfg: NemotronHConfig, params, state, ids, positions):
         restore = positions == state["prompt_end"]
         fault = ~restore & (positions != state["last"] + 1)
     with jax.named_scope("ssm_restore"):
-        state = dict(state, mamba=restored(state["mamba"], restore))
+        # empty where the step is the kernel, which picks each stream's
+        # source itself
+        if any(step_refusal(st) for st in state["mamba"]):
+            state = dict(state, mamba=restored(state["mamba"], restore))
     x = _embed(cfg, params, ids)
     x, states, got = _layers(
         cfg, params, x, state,
-        lambda p, u, st: mamba_decode(cfg, p, u, st),
+        lambda p, u, st: mamba_decode(cfg, p, u, st, restore),
         lambda p, u, cache: attn_decode(cfg, p, u, cache, positions))
     logits, greedy = _head(cfg, params, x)
     with jax.named_scope("state"):

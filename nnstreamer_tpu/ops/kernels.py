@@ -1,6 +1,6 @@
 """Pallas TPU kernels: fused normalize/typecast, flash attention, short
-attention, latent decode attention, grouped-query decode attention and
-the routed experts' grouped product.
+attention, latent decode attention, grouped-query decode attention, the
+routed experts' grouped product and the Mamba-2 decode step.
 
 Parity/role:
 - ``scale_bias_cast`` is the tensor_transform arithmetic prologue
@@ -36,6 +36,12 @@ Parity/role:
   one call whose grid walks the plan's blocks with the block's expert
   prefetched, so the next expert's matrices stream in while this one's
   are multiplied.
+- ``ssm_decode_step`` is one token of the Mamba-2 recurrence for every
+  stream (``models/nemotron_h.py``'s decode step): a stream's float32
+  state is copied in once (from its snapshot or live, as a prefetched
+  flag says), updated and reduced to ``y`` in fast memory and copied
+  out once over the live state, a few streams a grid step, the copies
+  in and the copies out in separate phases.
 
 All compile natively on TPU (Mosaic) and run under the Pallas
 interpreter on CPU backends (tests).  ``scale_bias_cast`` and
@@ -43,8 +49,10 @@ interpreter on CPU backends (tests).  ``scale_bias_cast`` and
 meet the tiling constraints — lane dim a multiple of 128, sublane dim a
 multiple of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32
 of 1-byte elements); ``short_attention``, ``latent_decode_attention``,
-``gqa_decode_attention`` and ``grouped_gated_product`` refuse a shape
-they cannot take and leave the choice to the caller.
+``gqa_decode_attention``, ``grouped_gated_product`` and
+``ssm_decode_step`` refuse a shape they cannot take (``*_refusal`` says
+why: axes that do not fill tiles, a type the kernel is not written for,
+blocks over a fast-memory budget) and leave the choice to the caller.
 Either way the ``*_available`` / ``*_refusal`` predicates are the whole
 eligibility rule, so the fallback is a decision made here, never an
 exception caught somewhere.
@@ -946,3 +954,216 @@ def grouped_gated_product(x, gate, up, down, row_token, block_expert,
     )(block_expert.astype(jnp.int32),
       jnp.reshape(blocks, (1,)).astype(jnp.int32), *row_operands, *ins,
       down)
+
+
+# -- the Mamba-2 decode step --------------------------------------------------
+
+#: fast memory the two sets of state buffers of :func:`ssm_decode_step`
+#: may take (a set: the streams of one grid step, 4 x 2 MiB at 64 heads
+#: of 64 over a state of 128); the call states it through
+#: ``vmem_limit_bytes``.  Alone the kernel reads 0.812 / 0.793 / 0.789 ms
+#: a layer at 2 / 4 / 8 streams a step; inside the cell's decode step
+#: the step reads 16.32 / 16.06 / 16.33 ms (``PERF.md`` section 6: what
+#: a kernel leaves, the compiler prefetches weights into), so four
+_SSM_VMEM_BUDGET = 32 << 20
+_SSM_STREAMS_A_STEP = 4
+
+
+def ssm_step_streams(streams: int, stream_bytes: int) -> int:
+    """Streams a grid step of :func:`ssm_decode_step` takes: the largest
+    divisor of ``streams`` up to ``_SSM_STREAMS_A_STEP`` whose two sets
+    of buffers fit ``_SSM_VMEM_BUDGET``; 0 where one stream's do not."""
+    for k in range(min(_SSM_STREAMS_A_STEP, streams), 0, -1):
+        if streams % k == 0 and 2 * k * stream_bytes <= _SSM_VMEM_BUDGET:
+            return k
+    return 0
+
+
+def ssm_decode_step_refusal(state_shape, dtypes) -> Optional[str]:
+    """Why :func:`ssm_decode_step` cannot take a recurrent state of
+    ``state_shape`` (``[streams, groups, state, heads a group x
+    head_dim]``) whose operands have ``dtypes`` (the set of theirs), or
+    None: float32 throughout, the last axis whole lanes, the state axis
+    whole lanes too (it is transposed on the matrix unit), and two
+    buffers of a stream's state within ``_SSM_VMEM_BUDGET``."""
+    names = sorted(np.dtype(d).name for d in dtypes)
+    if names != ["float32"]:
+        return f"operands of {', '.join(names)}: the recurrence is float32"
+    if len(state_shape) != 4:
+        return f"a state {tuple(state_shape)} is not [streams, groups, " \
+               "state, heads a group x head_dim]"
+    streams, groups, state, lanes = state_shape
+    if lanes % _LANE or state % _LANE:
+        return f"a group's [{state}, {lanes}] of state is not whole lanes " \
+               f"of {_LANE} both ways"
+    if not ssm_step_streams(streams, groups * state * lanes * 4):
+        return f"a stream's state twice, {groups * state * lanes >> 17} " \
+               f"MiB, is over {_SSM_VMEM_BUDGET >> 20} MiB"
+    return None
+
+
+def ssm_decode_step_reference(ssm, a, dx, b, c):
+    """The kernel's mathematics in jnp on the LIVE state (no snapshot:
+    the caller has restored), and the path a model takes for a shape the
+    kernel refuses: ``ssm [B, groups, state, lanes]``, ``a`` and ``dx``
+    ``[B, groups, lanes]``, ``b`` and ``c`` ``[B, groups, state]``, all
+    float32.  Returns ``(a S + b (x) dx, its sum over the state axis
+    weighted by c)``."""
+    import jax.numpy as jnp
+
+    new = a[:, :, None, :] * ssm + b[..., None] * dx[:, :, None, :]
+    return new, jnp.sum(new * c[..., None], axis=2)
+
+
+def ssm_decode_step(ssm, snap, restore, a, dx, b, c):
+    """One token of the Mamba-2 recurrence for every stream, the state
+    read once and written once: ``ssm`` and ``snap`` ``[B, groups,
+    state, lanes]`` float32 (``lanes``: a group's heads x head_dim; the
+    live state and its snapshot), ``restore [B]`` (which streams start
+    from their snapshot), ``a`` and ``dx`` ``[B, groups, lanes]`` (the
+    decay and ``delta x``, a head's value repeated over its lanes),
+    ``b`` and ``c`` ``[B, groups, state]``.  Returns ``(S', y)``: ``S' =
+    a S + b (x) dx`` written over ``ssm`` (aliased: donate it), ``y [B,
+    groups, lanes] = sum_n S'[n] c[n]``; ``snap`` is only read.
+
+    Both states stay in HBM and the kernel copies them itself.  A grid
+    step takes a few streams (:func:`ssm_step_streams`): it starts the
+    copies of the NEXT step's streams, each from the stream's snapshot
+    or its live state as ``restore`` (prefetched) says and from no
+    other, updates this step's streams where they lie in fast memory
+    while those copies run, waits for them, and only then writes this
+    step's streams back and waits again: reads and writes of the state
+    never share the memory's time.  (Mixed, as a pipeline of blocked
+    operands or XLA's own fusion mixes them, the chip moves 655 GB/s;
+    alone it reads 739 and writes 656: ``PERF.md`` section 5.)  The
+    arithmetic is a sixth of the step and hides behind the reads.
+
+    With the state axis in the sublanes ``a`` and ``dx`` are rows, ``y``
+    is a sum of vregs and one sublane reduction a group, and only ``b``
+    and ``c`` are columns: their 16 rows a stream are transposed once by
+    a product with the identity (``HIGHEST``: exact) and a column is
+    spread over the lanes once for a group's lane tiles.  Float32 on the
+    vector unit throughout.  A shape :func:`ssm_decode_step_refusal`
+    names is an error: the caller chooses."""
+    import jax.numpy as jnp
+
+    refusal = ssm_decode_step_refusal(
+        ssm.shape, {v.dtype for v in (ssm, snap, a, dx, b, c)})
+    if refusal:
+        raise ValueError(f"ssm_decode_step: {refusal}")
+    streams, groups, state, lanes = ssm.shape
+    k = ssm_step_streams(streams, groups * state * lanes * 4)
+    call = _ssm_decode_step_call(streams, groups, state, lanes, k,
+                                 _interpret())
+    return call(restore.astype(jnp.int32), a, dx,
+                jnp.concatenate([b, c], axis=1), ssm, snap)
+
+
+@functools.lru_cache(maxsize=8)
+def _ssm_decode_step_call(streams: int, groups: int, state: int, lanes: int,
+                          k: int, interpret: bool):
+    """The jitted call of :func:`ssm_decode_step` for one shape, built
+    once: a model's layers share the function, so a program that steps
+    nine layers traces and lowers the kernel once."""
+    import jax.numpy as jnp
+
+    jax, pl, pltpu = _pl()
+    steps = streams // k
+
+    def kernel(restore_ref, a_ref, dx_ref, bc_ref, live_ref, snap_ref,
+               out_ref, y_ref, held, arrived, left):
+        step = pl.program_id(0)
+        here = step % 2                  # the set this step's streams are in
+
+        def fetch(of, j, into, wait=False):
+            """Start (or wait for) the copy of stream ``j`` of step
+            ``of`` into set ``into``, from the one source it starts
+            from."""
+            at = of * k + j
+            live = pltpu.make_async_copy(live_ref.at[at], held.at[into, j],
+                                         arrived.at[into, j])
+            if wait:                     # either source: the same bytes
+                live.wait()
+                return
+            anew = restore_ref[at] != 0
+            pl.when(anew)(pltpu.make_async_copy(
+                snap_ref.at[at], held.at[into, j], arrived.at[into, j]).start)
+            pl.when(jnp.logical_not(anew))(live.start)
+
+        @pl.when(step == 0)
+        def _first():
+            for j in range(k):
+                fetch(0, j, 0)
+            for j in range(k):
+                fetch(0, j, 0, wait=True)
+
+        @pl.when(step + 1 < steps)
+        def _next():
+            for j in range(k):
+                fetch(step + 1, j, 1 - here)
+
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (state, state), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (state, state), 1)
+               ).astype(jnp.float32)
+        for j in range(k):
+            columns = jax.lax.dot_general(               # [state, 2 groups]
+                eye, bc_ref[j], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
+            for g in range(groups):
+                # a group whole, [state, lanes]: in rows of 8 to 128 a
+                # pass the kernel reads the same, the copies bind it
+                new = a_ref[j, g:g + 1, :] * held[here, j, g] \
+                    + columns[:, g:g + 1] * dx_ref[j, g:g + 1, :]
+                held[here, j, g] = new
+                y_ref[j, g:g + 1, :] = jnp.sum(
+                    new * columns[:, groups + g:groups + g + 1], axis=0,
+                    keepdims=True)
+
+        @pl.when(step + 1 < steps)
+        def _arrived():
+            for j in range(k):
+                fetch(step + 1, j, 1 - here, wait=True)
+
+        back = [pltpu.make_async_copy(held.at[here, j],
+                                      out_ref.at[step * k + j], left.at[j])
+                for j in range(k)]
+        for copy in back:
+            copy.start()
+        for copy in back:
+            copy.wait()
+
+    def rows_of(height, width):
+        return pl.BlockSpec((k, height, width), lambda i, r: (i, 0, 0))
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(steps,),
+        in_specs=[rows_of(groups, lanes), rows_of(groups, lanes),
+                  rows_of(2 * groups, state),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   rows_of(groups, lanes)],
+        scratch_shapes=[pltpu.VMEM((2, k, groups, state, lanes), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2, k)),
+                        pltpu.SemaphoreType.DMA((k,))])
+    call = pl.pallas_call(
+        kernel, grid_spec=grid,
+        out_shape=[
+            jax.ShapeDtypeStruct((streams, groups, state, lanes), jnp.float32),
+            jax.ShapeDtypeStruct((streams, groups, lanes), jnp.float32)],
+        # operand 4 (after the one prefetched) is the live state
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * k * groups * state * lanes * 4 + (12 << 20)),
+        interpret=interpret)
+
+    # no ``name=``: it would open a scope of its own below the caller's
+    # (``.../mamba/step``), the stage this call's device time is booked
+    # to.  An inner jit is no scope, and names the instruction all the
+    # same: a trace's device operations read ``ssm_decode_step.N``
+    def ssm_decode_step(*operands):
+        return call(*operands)
+
+    return jax.jit(ssm_decode_step)

@@ -360,6 +360,10 @@ def test_stage_scopes_are_in_the_program_text(model):
                       "layer03/moe/dispatch", "layer06/moe/experts",
                       "layer06/moe/combine", "layer06/moe/shared"):
             assert f"nns.model/{scope}" in text, scope
+    # the toy's state (32 lanes over a state of 16) is a shape the step's
+    # kernel refuses: its decode takes the jnp step behind the restore
+    # loop (tests/test_ssm_step.py has the kernel's program without it)
+    assert "whole lanes" in nh.step_refusal(state["mamba"][0])
     assert "nns.model/ssm_restore" in decode
     assert "nns.model/ssm_restore" not in prefill
 

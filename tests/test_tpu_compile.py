@@ -137,6 +137,32 @@ def test_the_hybrid_cells_kernels_compile_at_its_shapes(one_chip, monkeypatch):
             < (1 << 20) + (grid * blk * hidden * 2 if tokens > blk else 0)
 
 
+def test_ssm_decode_step_compiles_at_the_cells_shapes(one_chip, monkeypatch):
+    """`nemotron3.decode4k`'s recurrent state of one layer, 128 streams
+    of 8 groups x a state of 128 x 512 lanes (8 heads of 64) float32, as
+    a Mosaic kernel: four streams' 2 MiB each a grid step, copied in
+    from the live state or the snapshot and back over the live state by
+    the kernel itself (two sets of buffers, 16.8 MB of fast memory,
+    which the call asks for)."""
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    state, row, column = (128, 8, 128, 512), (128, 8, 512), (128, 8, 128)
+    assert kernels.ssm_decode_step_refusal(
+        state, {jnp.dtype(jnp.float32)}) is None
+    assert kernels.ssm_step_streams(128, 8 * 128 * 512 * 4) == 4
+    compiled = jax.jit(kernels.ssm_decode_step, donate_argnums=(0,)).lower(
+        shape(state), shape(state), shape((128,), jnp.bool_), shape(row),
+        shape(row), shape(column), shape(column)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    # the new state is the live state's buffer; no copy of either beside it
+    assert memory.alias_size_in_bytes == 128 * 8 * 128 * 512 * 4
+    assert memory.temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("entry,temp_mb", [("decode", 64), ("prefill", 768)])
 def test_the_hybrid_cells_programs_copy_no_recurrent_state(
         one_chip, monkeypatch, entry, temp_mb):
@@ -145,7 +171,9 @@ def test_the_hybrid_cells_programs_copy_no_recurrent_state(
     weights and 6.5 GB of state as arguments): the state is updated in
     the donated buffers.  One layer's recurrent state is 268 MB, so a
     copy of it (a `lax.cond` around the step made nine, 2.4 GB) shows
-    in the temporaries."""
+    in the temporaries.  A decode step's nine state updates are the
+    kernel `ssm_decode_step`, each written over its layer's live
+    state."""
     import json
     import os
 
@@ -189,5 +217,11 @@ def test_the_hybrid_cells_programs_copy_no_recurrent_state(
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= nbytes
     assert memory.temp_size_in_bytes < temp_mb << 20
-    assert compiled.as_text().count("tpu_custom_call") >= 8 + (
-        3 if entry == "decode" else 0)
+    text = compiled.as_text()
+    # the experts' grouped product and, of a decode step, three
+    # attention calls and nine state updates
+    assert text.count("tpu_custom_call") >= 8 + (
+        3 + 9 if entry == "decode" else 0)
+    # the kernel picks each stream's source: no restore loop before the
+    # layers (the scope holds nothing at these shapes)
+    assert "ssm_restore" not in text

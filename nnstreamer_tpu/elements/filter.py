@@ -222,6 +222,13 @@ class TensorFilter(Element):
         gst_tensor_filter_common_open_fw, tensor_filter_common.c:2465)."""
         if self.subplugin is not None:
             return
+        # negotiation asks for the model's schema, so this runs inside
+        # <pipeline>/negotiate: weights and state go onto the device
+        # and the first program is traced here
+        with _profile.span(self.name, "open", setup=True):
+            self._open_fw()
+
+    def _open_fw(self) -> None:
         from ..filters.modeluri import resolve_model_uri_versioned
 
         # scheme-qualified model URIs (mlagent:// analog) resolve first,

@@ -179,9 +179,10 @@ class ModelDef:
             # there (the accelerator= property).
             t0 = time.perf_counter()
             self._dev_params[device] = _jax().device_put(self.params, device)
-            _xfer.record("h2d", "weights",
-                         _xfer.params_nbytes(self.params),
+            nbytes = _xfer.params_nbytes(self.params)
+            _xfer.record("h2d", "weights", nbytes,
                          time.perf_counter() - t0, source=self.name)
+            _profile.note(f"{nbytes} B of weights put on {device}")
         params = self._dev_params[device]
 
         def fn(*inputs):
@@ -202,9 +203,11 @@ class ModelDef:
 
             t0 = time.perf_counter()
             self._mesh_params[key] = shard_params(mesh, self.params, rules)
-            _xfer.record("h2d", "weights",
-                         _xfer.params_nbytes(self.params),
+            nbytes = _xfer.params_nbytes(self.params)
+            _xfer.record("h2d", "weights", nbytes,
                          time.perf_counter() - t0, source=self.name)
+            _profile.note(f"{nbytes} B of weights laid over "
+                          f"{dict(mesh.shape)}")
         params = self._mesh_params[key]
 
         def fn(*inputs):
@@ -218,11 +221,13 @@ def register_model(name: str, fn: Callable, params: Any = None,
                    in_shapes: Optional[Sequence] = None,
                    in_dtypes: Any = None) -> str:
     """Register a jittable callable as a named model for ``model=name``."""
-    if in_spec is None and in_shapes is not None:
-        in_spec = TensorsSpec.from_shapes(
-            in_shapes, in_dtypes if in_dtypes is not None else np.float32)
-    with _models_lock:
-        _models[name] = ModelDef(fn, params, in_spec, name)
+    with _profile.span(name, "register", setup=True):
+        if in_spec is None and in_shapes is not None:
+            in_spec = TensorsSpec.from_shapes(
+                in_shapes,
+                in_dtypes if in_dtypes is not None else np.float32)
+        with _models_lock:
+            _models[name] = ModelDef(fn, params, in_spec, name)
     return name
 
 
@@ -292,17 +297,19 @@ def register_stateful_model(name: str, entries: Dict[str, Tuple],
     an entry point's name to ``(fn, in_shapes, in_dtypes)`` (or ``(fn,
     TensorsSpec)``); the first is what an element negotiates by
     default."""
-    table = {}
-    for ename, entry in entries.items():
-        fn, spec = entry[0], entry[1]
-        if not isinstance(spec, TensorsSpec):
-            spec = TensorsSpec.from_shapes(
-                spec, entry[2] if len(entry) > 2 else np.float32)
-        table[ename] = (fn, spec)
-    with _models_lock:
-        _models[name] = StatefulModelDef(
-            table, params, init_state, name, setup_entries=setup_entries,
-            counters=counters, counter_units=counter_units)
+    with _profile.span(name, "register", setup=True):
+        table = {}
+        for ename, entry in entries.items():
+            fn, spec = entry[0], entry[1]
+            if not isinstance(spec, TensorsSpec):
+                spec = TensorsSpec.from_shapes(
+                    spec, entry[2] if len(entry) > 2 else np.float32)
+            table[ename] = (fn, spec)
+        with _models_lock:
+            _models[name] = StatefulModelDef(
+                table, params, init_state, name,
+                setup_entries=setup_entries, counters=counters,
+                counter_units=counter_units)
     return name
 
 
@@ -329,20 +336,26 @@ class _StateCell:
                  table_key: Optional[str]):
         jax = _jax()
         self.model, self.device, self.table_key = model, device, table_key
+        self.owner = owner
         self.lock = threading.Lock()
         self.refs = 0
         self.compiled: Dict[str, _Compiled] = {}
         self._last: Dict[str, int] = {}
-        with _profile.span(owner, "state_init", setup=True):
+        self._counters_built = False
+        with _profile.span(owner, "state_init", setup=True) as made:
             t0 = time.perf_counter()
             self.params = jax.device_put(model.params, device)
-            _xfer.record("h2d", "weights", _xfer.params_nbytes(model.params),
+            nbytes = _xfer.params_nbytes(model.params)
+            _xfer.record("h2d", "weights", nbytes,
                          time.perf_counter() - t0, source=model.name)
             self.state = jax.device_put(model.init_state(self.params),
                                         device)
             jax.block_until_ready(self.state)
-        self.state_bytes = sum(
-            int(a.nbytes) for a in jax.tree_util.tree_leaves(self.state))
+            self.state_bytes = sum(
+                int(a.nbytes)
+                for a in jax.tree_util.tree_leaves(self.state))
+            made.note = (f"{nbytes} B of weights put on {device}, "
+                         f"{self.state_bytes} B of state made")
         STATE_STATS.add("state_bytes", self.state_bytes)
 
     def step(self, program: Callable, inputs: Sequence[Any]):
@@ -364,6 +377,17 @@ class _StateCell:
         model = self.model
         if model.counters is None:
             return
+        if not self._counters_built:
+            # the first read builds the little programs that pick the
+            # counters out of the state (0.8 s of the first window where
+            # there are many): set-up, under a span of its own
+            self._counters_built = True
+            with _profile.span(self.owner, "counters_init", setup=True):
+                self._fetch_counters(model)
+            return
+        self._fetch_counters(model)
+
+    def _fetch_counters(self, model: StatefulModelDef) -> None:
         with self.lock:
             if self.state is None:
                 return
@@ -898,23 +922,29 @@ class JaxXlaFilter(FilterSubplugin):
                       avals) -> None:
         """Executable cost capture (obs/xlacost.py) off a jit lowering:
         static flops/bytes, keyed (model, bucket), with where — and on
-        what kind of device — the executable runs."""
-        _xlacost.capture(
-            model.name, lowered, bucket=bucket,
-            placement=self._placement_label(),
-            platform=self._platform(),
-            device_kind=self._exec_devices()[0].device_kind,
-            in_bytes=_avals_nbytes(avals),
-            out_bytes=_avals_nbytes(
-                _jax().tree_util.tree_leaves(lowered.out_info)))
+        what kind of device — the executable runs.  What the capture
+        costs every program build is the set-up span
+        ``<filter>/cost_capture``."""
+        with _profile.span(self.trace_owner, "cost_capture", setup=True):
+            _xlacost.capture(
+                model.name, lowered, bucket=bucket,
+                placement=self._placement_label(),
+                platform=self._platform(),
+                device_kind=self._exec_devices()[0].device_kind,
+                in_bytes=_avals_nbytes(avals),
+                out_bytes=_avals_nbytes(
+                    _jax().tree_util.tree_leaves(lowered.out_info)))
 
     def _normalized_fn(self, model: ModelDef, in_spec: TensorsSpec):
         """The per-frame computation as one traceable callable: fused
         transform prologue + model + fused decoder epilogue, outputs
         normalized to a tuple.  Shared by the single-frame compile and
         the per-bucket micro-batch compiles (which vmap it)."""
-        fn = model.mesh_fn(self._mesh, self._rules) \
-            if self._mesh is not None else model.flat_fn(self._device)
+        # a stateless model's share of <filter>/state_init: its weights
+        # go onto the device (or over the mesh) the first time only
+        with _profile.span(self.trace_owner, "state_init", setup=True):
+            fn = model.mesh_fn(self._mesh, self._rules) \
+                if self._mesh is not None else model.flat_fn(self._device)
         pre = self._pre_fns(in_spec) if self._pre_chains else None
         post = self._post_fns[0] if self._post_fns else None
 

@@ -612,6 +612,10 @@ class SinkElement(Element):
     surfaces HERE, on this sink's bus via ``_chain_guarded``, one
     window late at most.  EOS drains the retained window, so
     ``wait_eos()`` returning means every dispatched program finished.
+
+    A fence that held the thread for ``SLOW_NS`` or more says in its
+    span's note who was late (:meth:`_who_was_late`); the first fence
+    after a start closes ``<pipeline>/first_window``.
     """
 
     def __init__(self, name=None, **props):
@@ -627,6 +631,8 @@ class SinkElement(Element):
         # in HBM — the consumer's own data, about to be read anyway.)
         self._pending_fence: Optional[Any] = None
         self._fence_lock = threading.Lock()
+        # set by Pipeline.start(): the next fence is this start's first
+        self._first_fence_due = False
 
     def chain(self, pad: Pad, buf: Buffer) -> None:
         cur = None
@@ -643,16 +649,32 @@ class SinkElement(Element):
         if arr is None:
             return
         # the host waiting for window N-1 on the device
-        with _profile.span(self.name, "fence"):
+        with _profile.span(self.name, "fence") as waited:
+            waited.if_slow = self._who_was_late
             tracer = _hooks.tracer
             if tracer is None:
                 arr.block_until_ready()
-                return
-            import time
+            else:
+                import time
 
-            t0 = time.monotonic()
-            arr.block_until_ready()
-            tracer.sink_fenced(self, time.monotonic() - t0)
+                t0 = time.monotonic()
+                arr.block_until_ready()
+                tracer.sink_fenced(self, time.monotonic() - t0)
+        if self._first_fence_due:
+            self.pipeline._first_fenced()
+
+    def _who_was_late(self) -> str:
+        """The note of a fence that lasted ``SLOW_NS`` or more.  When
+        the fence on window N-1 returns, window N was dispatched a
+        whole window ago: it can be done already only if the host
+        stayed away for at least a window's time (descheduled, a
+        collection, a lock) and the chip has run dry; if it is still
+        running, the device took that long.  One non-blocking question
+        to the array, asked on a slow fence only."""
+        nxt = self._pending_fence
+        if nxt is None:
+            return "no next window"
+        return _profile.HOST_LATE if nxt.is_ready() else _profile.DEVICE_LATE
 
     def render(self, buf: Buffer) -> None:
         raise NotImplementedError

@@ -22,6 +22,7 @@ from fractions import Fraction
 from typing import List, Optional, Tuple, Union
 
 from ..core import Caps, CapsStruct
+from ..utils import profile as _profile
 from .element import Element, Pad, PadDirection
 from .pipeline import Pipeline
 from .registry import make, register_element
@@ -199,6 +200,11 @@ def _tokenize(desc: str) -> List[Tuple[str, int]]:
 
 def parse_launch(desc: str, pipeline: Optional[Pipeline] = None) -> Pipeline:
     pipe = pipeline or Pipeline()
+    with _profile.span(pipe.name, "parse", setup=True):
+        return _parse_into(pipe, desc)
+
+
+def _parse_into(pipe: Pipeline, desc: str) -> Pipeline:
     tokens = _tokenize(desc)
     if not tokens:
         raise ParseError("empty pipeline description")

@@ -17,7 +17,8 @@ from typing import Dict, List, Optional, Union
 from ..obs import metrics as _metrics
 from ..utils import profile as _profile
 from ..utils.log import logi
-from .element import Element, NegotiationError, Pad, SourceElement
+from .element import (Element, NegotiationError, Pad, SinkElement,
+                      SourceElement)
 from .events import Message, MessageKind
 
 
@@ -65,6 +66,11 @@ class Bus:
             return False
 
 
+#: ``Pipeline._first_window_from`` between the sinks being armed and
+#: ``start()`` returning
+_ARMED = 0
+
+
 class Pipeline:
     def __init__(self, name: str = "pipeline", fuse: bool = True):
         self.name = name
@@ -86,6 +92,11 @@ class Pipeline:
         self._first_error: Optional[Message] = None
         self._n_sinks = 0
         self._eos_sinks: set = set()
+        # <pipeline>/first_window: None, then _ARMED while start() runs,
+        # then when start() returned (time.perf_counter_ns), and None
+        # again once a sink's first fence has returned
+        self._first_window_from: Optional[int] = None
+        self._first_window_lock = threading.Lock()
         self.bus.add_watch(self._watch)
 
     # -- assembly ------------------------------------------------------------
@@ -123,6 +134,33 @@ class Pipeline:
     def start(self) -> "Pipeline":
         if self.playing:
             return self
+        # set-up is one tree: everything start() does lies under this
+        # span, and <pipeline>/first_window takes over where it ends
+        with _profile.span(self.name, "start", setup=True):
+            self._start()
+        with self._first_window_lock:
+            if self._first_window_from == _ARMED:   # nothing fenced yet
+                self._first_window_from = time.perf_counter_ns()
+        return self
+
+    def _first_fenced(self) -> None:
+        """A sink's first fence of this start has returned: the first
+        window is computed.  Kept once a start, as the set-up span
+        ``<pipeline>/first_window`` on the thread that fenced: it holds
+        what the streaming thread builds lazily (``trace_lower``,
+        ``load_or_compile``, ``first_call``) and the first execution.
+        Nothing is kept for a stream that was through its first fence
+        before ``start()`` returned."""
+        with self._first_window_lock:
+            since, self._first_window_from = self._first_window_from, None
+            for e in self.elements.values():
+                if isinstance(e, SinkElement):
+                    e._first_fence_due = False
+        if since:
+            _profile.keep_setup(self.name + "/first_window", since,
+                                time.perf_counter_ns())
+
+    def _start(self) -> None:
         # fresh terminal state for this run: a restarted pipeline must
         # not report the previous run's EOS/error from wait_eos()
         self._eos_evt.clear()
@@ -154,11 +192,16 @@ class Pipeline:
                 if not e.srcpads and e.sinkpads)
             # Start sinks/others before sources so data finds everything
             # live.
+            self._first_window_from = _ARMED
             for e in self.elements.values():
+                if isinstance(e, SinkElement):
+                    e._first_fence_due = True
                 if not isinstance(e, SourceElement):
-                    e.start()
+                    with _profile.span(e.name, "activate", setup=True):
+                        e.start()
             for s in sources:
-                s.start()
+                with _profile.span(s.name, "activate", setup=True):
+                    s.start()
         except Exception:
             # A failed transition must not leak what already opened:
             # filters acquired during negotiation hold process-global
@@ -200,7 +243,6 @@ class Pipeline:
         from ..obs import control as _control
 
         _control.maybe_start_from_env()
-        return self
 
     def stop(self) -> "Pipeline":
         _metrics.REGISTRY.unregister_pipeline(self)

@@ -23,7 +23,13 @@ A span goes to two sinks:
   ``jax/cache_load``), every per-window span while a capture is active,
   and outside a capture only a per-window span that lasted
   ``SLOW_NS`` or more (the slow list).  All three lists are bounded and
-  keep their newest spans.
+  keep their newest spans; :func:`spans_dropped` says how many each
+  pushed out.
+
+Set-up is one tree a thread: ``<model>/register``, ``<pipeline>/parse``,
+``<pipeline>/start`` (``fuse``, ``negotiate``, ``<element>/activate``
+inside it) and ``<pipeline>/first_window`` (from ``start()`` returning
+to the first fence at a sink, kept by hand through :func:`keep_setup`).
 
 Inside the fused program the stages are ``jax.named_scope`` s
 (``nns.pre``, ``nns.model/<stage>``, ``nns.post``); :func:`stage_seconds`
@@ -66,9 +72,16 @@ _active = threading.Event()
 
 #: a per-window span this long is kept even when no capture is active
 SLOW_NS = 50_000_000
+#: the note of a ``<sink>/fence`` span that lasted that long
+#: (``SinkElement._who_was_late``)
+HOST_LATE = "next window done: host late"
+DEVICE_LATE = "next window running: device late"
 SLOW_MAX = 1024
 CAPTURE_MAX = 1 << 18
-SETUP_MAX = 4096
+#: room for every set-up span of a process that builds state ahead of
+#: its stream, many times over: the benchmark's cell with most keeps
+#: 328 (256 prefill chunks and jax's parts of every build)
+SETUP_MAX = 1 << 14
 
 
 class Span(NamedTuple):
@@ -103,7 +116,7 @@ class _Recorder:
         self.lists: Dict[str, collections.deque] = {
             which: collections.deque(maxlen=n)
             for which, n in limits.items()}
-        self.dropped = 0
+        self.dropped = dict.fromkeys(self.lists, 0)
         self.slow_kept = 0          # ever, for report_slow
         self.slow_reported = 0
 
@@ -113,7 +126,7 @@ class _Recorder:
         with self.lock:
             rows = self.lists[which]
             if len(rows) == rows.maxlen:
-                self.dropped += 1
+                self.dropped[which] += 1
             rows.append(row)
             if which == "slow":
                 self.slow_kept += 1
@@ -122,7 +135,8 @@ class _Recorder:
         with self.lock:
             for rows in self.lists.values():
                 rows.clear()
-            self.dropped = self.slow_kept = self.slow_reported = 0
+            self.dropped = dict.fromkeys(self.lists, 0)
+            self.slow_kept = self.slow_reported = 0
 
 
 _REC = _Recorder()
@@ -134,10 +148,13 @@ class span:
     without a phase) and is only built when the span is kept.
     ``setup=True`` marks a span of pipeline set-up, kept always;
     ``note`` (settable inside the block) rides along, e.g. ``hit`` or
-    ``miss`` on a compile-cache load."""
+    ``miss`` on a compile-cache load.  ``if_slow`` (settable inside the
+    block) is called for the note of a per-window span only when it
+    lasted ``SLOW_NS`` or more: what is worth asking once a window was
+    held up, and not on every window."""
 
-    __slots__ = ("owner", "phase", "window", "setup", "note", "_t0", "_ann",
-                 "_said")
+    __slots__ = ("owner", "phase", "window", "setup", "note", "if_slow",
+                 "_t0", "_ann", "_said")
 
     def __init__(self, owner: str, phase: Optional[str] = None,
                  window: Optional[int] = None, setup: bool = False):
@@ -146,6 +163,7 @@ class span:
         self.window = window
         self.setup = setup
         self.note = None
+        self.if_slow = None
         self._t0 = None
         self._ann = None
         self._said = None
@@ -187,15 +205,23 @@ class span:
                 self.note = said if self.note is None \
                     else f"{self.note}; {said}"
             _REC.keep(self.name, t0, t1, self.window, "setup", self.note)
+        elif t1 - t0 >= SLOW_NS:
+            if self.if_slow is not None:
+                self.note = self.if_slow()
+            _REC.keep(self.name, t0, t1, self.window,
+                      "slow" if ann is None else "window", self.note)
         elif ann is not None:
             _REC.keep(self.name, t0, t1, self.window, "window", self.note)
-        elif t1 - t0 >= SLOW_NS:
-            _REC.keep(self.name, t0, t1, self.window, "slow", self.note)
         return False
 
 
-#: the older name of :class:`span`, kept for callers outside the runtime
-annotate = span
+def keep_setup(name: str, start_ns: int, end_ns: int,
+               note: Optional[str] = None) -> None:
+    """Keeps a set-up span timed by hand (``time.perf_counter_ns``), on
+    the calling thread: one whose two ends lie in different calls, such
+    as ``<pipeline>/first_window``."""
+    if not _hooks.DISABLED:
+        _REC.keep(name, start_ns, end_ns, None, "setup", note)
 
 
 def note(text: str) -> None:
@@ -242,8 +268,7 @@ def _on_jax_duration(event: str, seconds: float, **kw) -> None:
             or not getattr(_tls, "setup", None):
         return
     end = time.perf_counter_ns()
-    _REC.keep(name, end - int(seconds * 1e9), end, None, "setup",
-              kw.get("fun_name"))
+    keep_setup(name, end - int(seconds * 1e9), end, kw.get("fun_name"))
 
 
 def _watch_jax_compiles() -> None:
@@ -335,9 +360,12 @@ def spans() -> List[Span]:
     return out
 
 
-def dropped() -> int:
-    """Spans pushed out of a full list by newer ones."""
-    return _REC.dropped
+def spans_dropped() -> Dict[str, int]:
+    """Spans pushed out of each full list (``setup``, ``window``,
+    ``slow``) by newer ones: whoever adds spans up reads this beside
+    them, since a sum over a list that lost rows is short."""
+    with _REC.lock:
+        return dict(_REC.dropped)
 
 
 def clear() -> None:

@@ -584,6 +584,6 @@ def test_dispatch_carries_trace_id_to_annotation(monkeypatch):
         rid = tr.records()[0]["id"]
     finally:
         profile._active.clear()
-    # per-element annotate() spans record too; the frame marker is the
+    # per-element span() annotations record too; the frame marker is the
     # one carrying the trace id
     assert f"nns:frames:{rid}" in seen

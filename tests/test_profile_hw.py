@@ -12,16 +12,16 @@ from nnstreamer_tpu.runtime import Pipeline
 from nnstreamer_tpu.runtime.registry import make
 from nnstreamer_tpu.utils import hw
 from nnstreamer_tpu.utils.profile import (
-    annotate,
     pipeline_trace,
+    span,
     trace_active,
 )
 
 
 class TestProfile:
-    def test_annotate_noop_without_trace(self):
+    def test_span_noop_without_trace(self):
         assert not trace_active()
-        with annotate("x"):  # must not touch jax at all
+        with span("x"):  # must not touch jax at all
             pass
 
     def test_pipeline_trace_captures(self, tmp_path):

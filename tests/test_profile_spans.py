@@ -68,7 +68,7 @@ def test_nothing_kept_and_no_annotation_without_a_capture(monkeypatch):
     with span("el_net"):
         pass
     assert profile.spans() == []
-    assert profile.dropped() == 0
+    assert not any(profile.spans_dropped().values())
 
 
 def test_capture_keeps_every_span_under_its_name_on_both_clocks(capture):
@@ -125,9 +125,12 @@ def test_lists_are_bounded_and_keep_the_newest(monkeypatch, which):
     for i in range(5):
         rec.keep(f"s{i}", i, i + 1, None, which)
     assert [s.name for s in profile.spans()] == ["s2", "s3", "s4"]
-    assert profile.dropped() == 2
+    # read per list: the other two lost nothing
+    assert profile.spans_dropped() == {
+        "setup": 0, "window": 0, "slow": 0, which: 2}
     profile.clear()
-    assert profile.spans() == [] and profile.dropped() == 0
+    assert profile.spans() == []
+    assert not any(profile.spans_dropped().values())
 
 
 def test_jax_build_steps_are_kept_inside_a_set_up_span_only(monkeypatch):
@@ -232,8 +235,8 @@ def test_removed_hooks_stay_removed():
 
     assert "create_perfetto_link" not in inspect.signature(
         profile.pipeline_trace).parameters
+    assert not hasattr(profile, "annotate")      # PR 37: no caller
     # the names other callers import are still there
-    assert profile.annotate is profile.span
     assert callable(profile.frame_annotation) and callable(
         profile.trace_active)
 
@@ -295,7 +298,7 @@ def _toy_models():
                  heads=2, mlp_dim=128, num_classes=10)
 
 
-def _program_text(line, shape, bucket):
+def _toy_pipe(line, shape):
     from nnstreamer_tpu.runtime import parse_launch
 
     rng = np.random.default_rng(5)
@@ -303,6 +306,11 @@ def _program_text(line, shape, bucket):
     pipe["el_src"].frames = [rng.integers(0, 255, shape, dtype=np.uint8)
                              for _ in range(2)]
     pipe["el_src"].pool_size = 2
+    return pipe
+
+
+def _program_text(line, shape, bucket):
+    pipe = _toy_pipe(line, shape)
     pipe.start()
     try:
         for _ in range(max(bucket, 1) + 1):
@@ -441,3 +449,210 @@ def test_a_traced_pipeline_names_its_phases_and_set_up(capture):
         <= one["el_sink"].start_ns <= one["el_sink"].end_ns \
         <= one["el_net"].end_ns <= one["el_norm"].end_ns
     assert re.fullmatch(r"el_\w+(/\w+)?", one["el_sink/render"].name)
+
+
+# -- set-up as one tree (PR 37) -----------------------------------------------
+
+
+def _toy_start(pulls=3, restart=False):
+    """The toy SSD line parsed, started and pulled from; returns the
+    set-up spans kept, by name (a list each)."""
+    _toy_models()
+    pipe = _toy_pipe(SSD_LINE.format(model="spans_toy_ssd", batch=""),
+                     (2, 64, 64, 3))
+    for _ in range(2 if restart else 1):
+        pipe.start()
+        try:
+            for _ in range(pulls):
+                assert pipe["el_sink"].pull(timeout=120) is not None
+        finally:
+            pipe.stop()
+    by_name = {}
+    for s in profile.spans():
+        if s.kind == "setup":
+            by_name.setdefault(s.name, []).append(s)
+    return by_name
+
+
+@pytest.fixture(scope="module")
+def toy_set_up():
+    """The set-up spans of one start of the toy line, by name."""
+    profile.clear()
+    return _toy_start()
+
+
+def _inside(inner, outer):
+    return outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
+
+
+def test_set_up_has_its_root_spans(toy_set_up):
+    by_name = toy_set_up
+    assert {"spans_toy_ssd/register", "pipeline/parse", "pipeline/start",
+            "pipeline/first_window", "el_net/open", "el_net/cost_capture",
+            "el_net/activate", "el_src/activate", "el_sink/activate",
+            "el_norm/activate", "el_overlay/activate"} <= set(by_name)
+    register, parse, start, first = (
+        by_name[n][-1] for n in ("spans_toy_ssd/register", "pipeline/parse",
+                                 "pipeline/start", "pipeline/first_window"))
+    # one after the other on the application's thread, first_window on
+    # the thread that fenced
+    assert register.end_ns <= parse.start_ns <= parse.end_ns \
+        <= start.start_ns
+    assert register.thread == parse.thread == start.thread != first.thread
+
+
+@pytest.mark.parametrize("inner,outer", [
+    ("pipeline/fuse", "pipeline/start"),
+    ("pipeline/negotiate", "pipeline/start"),
+    ("el_net/activate", "pipeline/start"),
+    ("el_src/activate", "pipeline/start"),
+    ("el_src/stage", "el_src/activate"),
+    ("el_net/open", "pipeline/negotiate"),
+    ("el_net/trace_lower", "pipeline/negotiate"),
+    ("el_net/cost_capture", "pipeline/negotiate"),
+])
+def test_set_up_spans_nest(toy_set_up, inner, outer):
+    by_name = toy_set_up
+    assert by_name[inner] and len(by_name[outer]) == 1
+    for s in by_name[inner]:
+        assert _inside(s, by_name[outer][0]), (s, by_name[outer][0])
+        assert s.thread == by_name[outer][0].thread
+
+
+def test_first_window_runs_from_start_to_the_first_fence(monkeypatch):
+    from nnstreamer_tpu.runtime.element import SinkElement
+
+    fenced = []
+    plain = SinkElement._fence
+
+    def watched(self, arr):
+        plain(self, arr)
+        if arr is not None:
+            fenced.append(time.perf_counter_ns())
+
+    monkeypatch.setattr(SinkElement, "_fence", watched)
+    by_name = _toy_start(pulls=4)
+    (first,) = by_name["pipeline/first_window"]
+    (start,) = by_name["pipeline/start"]
+    assert len(fenced) >= 2
+    # from start() returning, to the first fence and not the second
+    assert start.end_ns <= first.start_ns
+    assert first.start_ns - start.end_ns < 50_000_000
+    assert first.end_ns <= fenced[0] < fenced[1]
+    assert first.end_ns > first.start_ns
+    # the lazy build of the streaming thread ends inside it
+    call = by_name["el_net/first_call"][-1]
+    assert call.thread == first.thread and call.end_ns <= first.end_ns
+
+
+def test_first_window_is_kept_once_a_start():
+    by_name = _toy_start(restart=True)
+    assert len(by_name["pipeline/start"]) == 2
+    assert len(by_name["pipeline/first_window"]) == 2
+    for start, first in zip(by_name["pipeline/start"],
+                            by_name["pipeline/first_window"]):
+        assert start.end_ns <= first.start_ns
+
+
+def test_no_first_window_for_a_stream_that_fences_nothing():
+    from nnstreamer_tpu.core import Buffer, TensorsSpec
+    from nnstreamer_tpu.elements.basic import AppSink, AppSrc
+    from nnstreamer_tpu.runtime import Pipeline
+
+    pipe = Pipeline()
+    src = AppSrc(name="src", spec=TensorsSpec.parse("4", "float32"))
+    sink = AppSink(name="out")
+    pipe.add(src, sink).link(src, sink)
+    with pipe:
+        for _ in range(3):
+            src.push_buffer(Buffer.of(np.ones(4, np.float32)))
+        src.end_of_stream()
+        assert pipe.wait_eos(timeout=60)
+    names = [s.name for s in profile.spans() if s.kind == "setup"]
+    assert "pipeline/start" in names       # host buffers: no fence
+    assert "pipeline/first_window" not in names
+
+
+def test_set_up_spans_dropped_reads_zero_then_what_overflowed(
+        toy_set_up, monkeypatch):
+    kept = sum(len(rows) for rows in toy_set_up.values())
+    assert kept > 10 and profile.spans_dropped()["setup"] == 0
+    assert profile.SETUP_MAX >= 1 << 14
+    rec = profile._Recorder({"setup": 8, "window": 8, "slow": 8})
+    kinds, plain = [], rec.keep
+    rec.keep = lambda *a, **k: (kinds.append(a[4]), plain(*a, **k))[1]
+    monkeypatch.setattr(profile, "_REC", rec)
+    _toy_start()
+    assert kinds.count("setup") > 8
+    assert profile.spans_dropped() == {
+        "setup": kinds.count("setup") - 8, "window": 0, "slow": 0}
+    assert len([s for s in profile.spans() if s.kind == "setup"]) == 8
+
+
+def test_keep_setup_is_off_under_the_kill_switch(monkeypatch):
+    profile.keep_setup("pipeline/first_window", 10, 20, "n")
+    (row,) = profile.spans()
+    assert (row.name, row.start_ns, row.end_ns, row.kind, row.note) == (
+        "pipeline/first_window", 10, 20, "setup", "n")
+    profile.clear()
+    monkeypatch.setattr(hooks, "DISABLED", True)
+    profile.keep_setup("pipeline/first_window", 10, 20)
+    assert profile.spans() == []
+
+
+# -- a slow fence says who was late -------------------------------------------
+
+
+class _Array:
+    """Stands in for a device array at the sink's fence."""
+
+    def __init__(self, ready, wait_s=0.0, asked=None):
+        self.ready, self.wait_s, self.asked = ready, wait_s, asked
+
+    def block_until_ready(self):
+        time.sleep(self.wait_s)
+
+    def is_ready(self):
+        if self.asked is not None:
+            self.asked.append(self)
+        return self.ready
+
+
+@pytest.mark.parametrize("next_ready,note", [
+    (True, profile.HOST_LATE), (False, profile.DEVICE_LATE),
+    (None, "no next window")])
+def test_a_slow_fence_says_who_was_late(next_ready, note):
+    from nnstreamer_tpu.elements.basic import AppSink
+
+    sink = AppSink(name="el_sink")
+    sink._pending_fence = None if next_ready is None \
+        else _Array(next_ready)
+    sink._fence(_Array(True, wait_s=0.06))
+    (row,) = profile.spans()
+    assert (row.name, row.kind, row.note) == ("el_sink/fence", "slow", note)
+    assert (profile.HOST_LATE, profile.DEVICE_LATE) == (
+        "next window done: host late", "next window running: device late")
+
+
+def test_a_fast_fence_asks_the_array_nothing(capture):
+    from nnstreamer_tpu.elements.basic import AppSink
+
+    asked = []
+    sink = AppSink(name="el_sink")
+    sink._pending_fence = _Array(True, asked=asked)
+    sink._fence(_Array(True))
+    (row,) = profile.spans()            # kept: a capture is on
+    assert (row.name, row.kind, row.note) == ("el_sink/fence", "window",
+                                              None)
+    assert asked == []
+
+
+def test_if_slow_is_called_on_a_slow_span_only(monkeypatch):
+    ticks = iter([0, 49_999_999, 100, 100 + 50_000_000])
+    monkeypatch.setattr(profile.time, "perf_counter_ns", lambda: next(ticks))
+    calls = []
+    for name in ("a", "b"):
+        with span(name, "x") as s:
+            s.if_slow = lambda name=name: calls.append(name) or "late"
+    assert calls == ["b"]
+    assert [(s.name, s.note) for s in profile.spans()] == [("b/x", "late")]

@@ -589,8 +589,8 @@ def _head(cfg: NemotronHConfig, params, x):
                         + cfg.vocab0)
 
 
-COUNTERS = ("steps", "ssm_rows", "kv_rows_read", "experts_touched",
-            "expert_hits", "restores", "position_faults")
+COUNTERS = ("steps", "ssm_rows", "kv_rows_read", "kv_rows_fetched",
+            "experts_touched", "expert_hits", "restores", "position_faults")
 
 
 def init_state(cfg: NemotronHConfig, params, streams: int, positions: int,
@@ -637,8 +637,9 @@ def counter_units(cfg: NemotronHConfig, state: dict) -> dict:
     """What the raw counters stand for in bytes.  ``ssm_rows`` counts
     the streams stepped in ONE ``M`` layer: a row is a stream's ``ssm``
     and ``conv``, read and written.  ``kv_rows_read`` counts the rows in
-    use (``0 .. position``) of ONE ``*`` layer: a row is a token's K and
-    V."""
+    use (``0 .. position``) of ONE ``*`` layer, ``kv_rows_fetched`` the
+    rows the decode attention copies for them (:func:`rows_fetched`): a
+    row is a token's K and V."""
     out = {}
     if state["mamba"]:
         first = state["mamba"][0]
@@ -648,9 +649,22 @@ def counter_units(cfg: NemotronHConfig, state: dict) -> dict:
     if state["cache"]:
         k = state["cache"][0]["k"]
         row = 2 * cfg.kv_heads * cfg.head_dim * k.dtype.itemsize
-        kv = ("kv_rows_read", row * len(state["cache"]))
-        out.update(kv_bytes_read=kv, cache_bytes_read=kv)
+        for did in ("read", "fetched"):
+            kv = (f"kv_rows_{did}", row * len(state["cache"]))
+            out.update({f"kv_bytes_{did}": kv, f"cache_bytes_{did}": kv})
     return out
+
+
+def rows_fetched(cfg: NemotronHConfig, caches, positions):
+    """Rows of ONE ``*`` layer's cache that :func:`attn_decode` reads in
+    for streams at ``positions`` (``ops/kernels.py``
+    ``gqa_decode_rows_fetched``: the kernel's live cells, or the whole
+    cache where it refuses the shape); 0 where no layer attends."""
+    if not caches:
+        return 0
+    b, _, total, d = shape = caches[0]["k"].shape
+    return kernels.gqa_decode_rows_fetched(
+        (b, cfg.kv_heads, cfg.per_group, d), shape, positions, total)
 
 
 def prefill(cfg: NemotronHConfig, params, state, ids, slot, start, count):
@@ -698,6 +712,8 @@ def decode(cfg: NemotronHConfig, params, state, ids, positions):
     with jax.named_scope("state"):
         gained = {"steps": 1, "ssm_rows": ids.shape[0],
                   "kv_rows_read": jnp.sum(positions + 1),
+                  "kv_rows_fetched": rows_fetched(cfg, states["cache"],
+                                                  positions),
                   "experts_touched": jnp.sum(got > 0),
                   "expert_hits": jnp.sum(got),
                   "restores": jnp.sum(restore),

@@ -345,6 +345,7 @@ def _head(cfg: SmallThinkerConfig, params, x):
 
 
 COUNTERS = ("steps", "window_rows_read", "full_rows_read",
+            "window_rows_fetched", "full_rows_fetched",
             "experts_touched", "expert_hits")
 
 
@@ -379,13 +380,18 @@ def counter_units(cfg: SmallThinkerConfig, state: dict) -> dict:
     """What the raw counters stand for in bytes.  ``window_rows_read``
     and ``full_rows_read`` count the rows IN USE of ONE layer of their
     kind (a stream past the window uses ``window`` rows of a ring,
-    whatever the ring holds); a row is a token's K and V."""
+    whatever the ring holds), ``*_rows_fetched`` the rows the decode
+    attention reads in for them (``ops/kernels.py``
+    ``gqa_decode_rows_fetched``); a row is a token's K and V."""
     row = cfg.row_values * state["cache"][0]["k"].dtype.itemsize
     rings = sum(cfg.window_layers)
-    window = ("window_rows_read", row * rings)
-    full = ("full_rows_read", row * (cfg.layers - rings))
-    return {"window_bytes_read": window, "full_bytes_read": full,
-            "cache_bytes_read": [window, full]}
+    out = {}
+    for did in ("read", "fetched"):
+        window = (f"window_rows_{did}", row * rings)
+        full = (f"full_rows_{did}", row * (cfg.layers - rings))
+        out.update({f"window_bytes_{did}": window, f"full_bytes_{did}": full,
+                    f"cache_bytes_{did}": [window, full]})
+    return out
 
 
 def prefill(cfg: SmallThinkerConfig, params, state, ids, slot, start):
@@ -413,9 +419,22 @@ def decode(cfg: SmallThinkerConfig, params, state, ids, positions):
         lambda i, p, h, cache: attn_decode(cfg, i, p, h, cache, positions))
     logits, greedy = _head(cfg, params, x)
     rows = positions + 1
+
+    def fetched(ring: bool):
+        """Rows :func:`attn_decode` reads in in ONE layer of a kind."""
+        if ring not in cfg.window_layers:
+            return 0
+        b, _, total, d = shape = \
+            caches[cfg.window_layers.index(ring)]["k"].shape
+        return kernels.gqa_decode_rows_fetched(
+            (b, cfg.kv_heads, cfg.per_group, d), shape, positions,
+            cfg.window if ring else total)
+
     gained = {"steps": 1,
               "window_rows_read": jnp.sum(jnp.minimum(rows, cfg.window)),
               "full_rows_read": jnp.sum(rows),
+              "window_rows_fetched": fetched(True),
+              "full_rows_fetched": fetched(False),
               "experts_touched": jnp.sum(got > 0),
               "expert_hits": jnp.sum(got)}
     new = {name: state["counters"][name]

@@ -27,10 +27,12 @@ Parity/role:
   skipped.
 - ``gqa_decode_attention`` is grouped-query attention of one token a
   stream over separate K and V caches (``models/smallthinker.py``'s
-  decode step), the cache a ring a stream wraps around or a dense array
-  it grows into: a block of K and of V is read once for all the query
-  heads of its group, and only the blocks that hold a position of the
-  stream's window are fetched.
+  decode step and ``models/nemotron_h.py``'s), the cache a ring a
+  stream wraps around or a dense array it grows into: the caches stay
+  in HBM and the kernel copies a stream's live rows itself, whole
+  chunks in the middle of a window and cells of 128 rows at its ends,
+  through a queue that runs on into the next stream; a chunk of K and
+  of V is read once for all the query heads of its group.
 - ``grouped_gated_product`` is the gated MLPs of the experts a decode
   step's tokens were routed to (``models/moe.py`` ``grouped_experts``):
   one call whose grid walks the plan's blocks with the block's expert
@@ -61,7 +63,7 @@ exception caught somewhere.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -575,15 +577,131 @@ def latent_decode_attention(q, cache, positions, rank: int, scale: float):
     return out[:, :held, :rank]
 
 
+# -- a decode kernel's walk over a stream's live rows --------------------------
+#
+# A decode attention kernel that leaves its cache in HBM and copies a
+# stream's live rows itself fetches by one unit and computes by another.
+# Rows are named on a LATTICE of whole lane tiles that divides the cache
+# (so every copy starts aligned and a ring's end falls between two
+# cells).  A stream's live cells, in the order of their positions, are
+# cut into ITEMS of a chunk's cells counted from the FIRST live cell:
+# every item is a whole chunk but the last, which is copied and computed
+# on in pieces of 2^k cells (at most one of each size, none of a row
+# not in use beyond its cell), and an item that runs over a ring's end
+# is copied cell by cell.  The items go through a queue of buffers whose
+# copies run on from one stream into the next.  What follows is the
+# walk's arithmetic (``jnp`` on scalars, traced or not):
+# :func:`gqa_decode_attention` issues its copies by it,
+# :func:`decode_rows_fetched` counts by it, and a kernel over another
+# cache (``latent_decode_attention``) can take it as it stands.
+
+#: rows of one lattice cell: a lane tile of scores
+_WALK_LATTICE = _LANE
+#: bytes of one chunk's copies (a row: K and V of every group) the plan
+#: aims at, and bytes of the queue's buffers together; ``PERF.md``
+#: section 6 (PR 39) has what other sizes read on the chip
+_WALK_CHUNK_BYTES = 2 << 20
+_WALK_QUEUE_BYTES = 8 << 20
+
+
+class WalkPlan(NamedTuple):
+    """How a decode kernel walks caches of ``total`` rows: ``chunk``
+    rows a buffer (whole cells; divides ``total``), ``slots`` buffers
+    (``slots - 1`` items in flight while one is computed on)."""
+    chunk: int
+    slots: int
+
+    @property
+    def cells(self) -> int:
+        """Cells of a chunk."""
+        return self.chunk // _WALK_LATTICE
+
+    @property
+    def pieces(self) -> tuple:
+        """The sizes, in cells, a partial item comes in, largest
+        first: the powers of two below a chunk's cells."""
+        return tuple(1 << k for k in reversed(range(
+            (self.cells - 1).bit_length())))
+
+
+def decode_walk_plan(total: int, row_bytes: int) -> WalkPlan:
+    """The plan for caches of ``total`` rows of ``row_bytes``, from what
+    a call can see: the largest chunk of whole cells that divides
+    ``total`` within ``_WALK_CHUNK_BYTES`` (and within half the cache,
+    so that a short cache still has a chunk to compute on while the next
+    one lands), and as many buffers as ``_WALK_QUEUE_BYTES`` hold (three
+    to eight)."""
+    lat = _WALK_LATTICE
+    most = max(min(_WALK_CHUNK_BYTES // row_bytes, total // 2), lat)
+    chunk = next(c for c in range(most // lat * lat, 0, -lat)
+                 if total % c == 0)
+    slots = min(max(_WALK_QUEUE_BYTES // (chunk * row_bytes), 3), 8)
+    return WalkPlan(chunk, slots)
+
+
+def walk_cells(pos, total: int, window: int):
+    """The lattice cells that hold a live row of a stream at ``pos``
+    (positions ``max(0, pos - window + 1) .. pos`` of a cache of
+    ``total`` slots, position ``p`` in slot ``p % total``): the first
+    one, counted by position before the ring folds it, and how many.
+    Never more than the cache has: a window within a cell of the
+    ring's length meets its own tail in one cell, which is copied
+    once."""
+    import jax.numpy as jnp
+
+    first = jnp.maximum(pos - window + 1, 0) // _WALK_LATTICE
+    return first, jnp.minimum(pos // _WALK_LATTICE - first + 1,
+                              total // _WALK_LATTICE)
+
+
+def walk_items(cells, plan: WalkPlan):
+    """Items (buffers' worth) that ``cells`` live cells come in: whole
+    chunks and, of what is left, one more."""
+    return (cells + plan.cells - 1) // plan.cells
+
+
+def walk_item(first, cells, item, total: int, plan: WalkPlan):
+    """Item ``item`` of the live cells ``first .. first + cells - 1``:
+    the cell of the cache it starts in (folded), how many cells it
+    holds, and whether it runs over the cache's end (a ring's: it is
+    copied cell by cell then)."""
+    import jax.numpy as jnp
+
+    ring = total // _WALK_LATTICE
+    start = (first + item * plan.cells) % ring
+    count = jnp.minimum(cells - item * plan.cells, plan.cells)
+    return start, count, start + count > ring
+
+
+def walk_piece(count, size: int):
+    """Of an item of ``count`` cells (fewer than a chunk's): whether it
+    has a piece of ``size`` cells (a power of two), and the cell of the
+    item the piece starts at (the larger pieces come first)."""
+    return (count & size) != 0, count - count % (2 * size)
+
+
+def decode_rows_fetched(positions, total: int, window: int):
+    """Rows of ONE cache (a token's K and V count as one row) that the
+    walk copies for streams at ``positions [B]``: every live cell
+    whole, so at most a cell less one row beyond the rows in use at
+    each end a window has inside the cache (two on a ring past its
+    window, one on a dense cache).  The kernel issues its copies by the
+    same cells; a scalar of ``positions``' type."""
+    import jax.numpy as jnp
+
+    _, cells = walk_cells(jnp.asarray(positions), total, window)
+    return jnp.sum(cells) * _WALK_LATTICE
+
+
 # -- grouped-query decode attention -------------------------------------------
 
 
-def gqa_decode_attention_refusal(q_shape, k_shape, v_shape, window: int,
-                                 block: int = 1024) -> Optional[str]:
+def gqa_decode_attention_refusal(q_shape, k_shape, v_shape,
+                                 window: int) -> Optional[str]:
     """Why :func:`gqa_decode_attention` cannot take these shapes, or
     None: ``q [B, groups, heads a group, d]`` beside ``k`` and ``v``
-    ``[B, groups, positions, d]``, ``d`` whole lanes, and a block of
-    whole lanes that divides the caches' positions."""
+    ``[B, groups, positions, d]``, ``d`` whole lanes, and caches of
+    whole lattice cells (lane tiles of positions)."""
     if len(q_shape) != 4 or len(k_shape) != 4 \
             or tuple(k_shape) != tuple(v_shape) \
             or tuple(q_shape[:2]) != tuple(k_shape[:2]) \
@@ -593,7 +711,7 @@ def gqa_decode_attention_refusal(q_shape, k_shape, v_shape, window: int,
                "twice [B, groups, positions, d]"
     if q_shape[3] % _LANE:
         return f"head size {q_shape[3]} is not whole lanes of {_LANE}"
-    if not latent_block(k_shape[2], block):
+    if k_shape[2] < _WALK_LATTICE or k_shape[2] % _WALK_LATTICE:
         return f"{k_shape[2]} cache positions are not whole lanes " \
                f"of {_LANE}"
     if window < 1:
@@ -626,8 +744,20 @@ def gqa_decode_attention_reference(q, k, v, positions, window: int,
                       preferred_element_type=jnp.float32, precision=hp)
 
 
-def gqa_decode_attention(q, k, v, positions, window: int, scale: float,
-                         block: int = 1024):
+def gqa_decode_rows_fetched(q_shape, k_shape, positions, window: int):
+    """Rows of ONE cache (a token's K and V count as one row) that a
+    model's decode attention reads in for streams at ``positions``: the
+    walk's live cells (:func:`decode_rows_fetched`) where
+    :func:`gqa_decode_attention` takes the shapes, every row of every
+    stream where it refuses them and the ``jnp`` mathematics reads the
+    caches whole."""
+    if gqa_decode_attention_refusal(q_shape, k_shape, k_shape,
+                                    window) is None:
+        return decode_rows_fetched(positions, k_shape[2], window)
+    return k_shape[0] * k_shape[2]
+
+
+def gqa_decode_attention(q, k, v, positions, window: int, scale: float):
     """Grouped-query attention of one token a stream: ``q [B, groups,
     heads a group, d]``, ``k`` and ``v`` ``[B, groups, T, d]``,
     ``positions [B]`` int32.  Position ``p`` of a stream lives in slot
@@ -636,69 +766,149 @@ def gqa_decode_attention(q, k, v, positions, window: int, scale: float,
     Returns ``[B, groups, heads a group, d]`` float32: softmax(q k^T *
     scale) over the positions ``max(0, p - window + 1) .. p``, times v.
 
-    One pass: a grid step reads one block of K and of V (all groups)
-    into VMEM, once for every query head of a group, with a running
-    max, normaliser and accumulator across blocks.  Only blocks that
-    hold a position of the stream's window are fetched, in the order of
-    their positions (so a ring is walked from its oldest block round to
-    its newest): the steps left over repeat the last block in use, and
-    a repeated block is not copied again.  A window that does not start
-    on a block's edge costs one block more than it holds; blocks of
-    1,024 positions still read fastest on the chip at 32 streams, a
-    window of 4,096 in a ring of 6,144 (0.46 ms; 512: 0.48, 2,048: 0.55,
-    256: 0.75) and a dense cache of 16,384 (1.20; 1.36, 1.24, 2.23)
-    alike, a grid step's cost against the fifth block (``PERF.md``).
+    One pass, and the kernel copies a stream's live rows itself: K and
+    V stay in HBM, the grid runs over streams, and a stream's rows come
+    in by the walk above (:func:`decode_walk_plan`): the live cells of
+    128 rows, in the order of their positions (a ring is walked from its
+    oldest cell round to its newest), a chunk at a time counted from the
+    FIRST live cell, so that every item is a whole chunk but the last,
+    which comes in (and is computed on) in at most one piece each of 4,
+    2 and 1 cells; an item that runs over a ring's end is copied cell by
+    cell.  The items pass through a queue of buffers whose copies run
+    ahead of the arithmetic and on into the next stream's first items,
+    so that no stream starts cold and none takes a step it has no rows
+    for.  Each item is read once for every query head of a group, with
+    a running max, normaliser and accumulator in float32; a row outside
+    the window is masked by its slot's position and adds exactly zero.
+    What the walk fetches beyond the rows in use is less than a cell at
+    each end (:func:`decode_rows_fetched`).
+
+    On the chip (the kernel alone, device ms a call, blocks of 1,024
+    through a ``BlockSpec`` before -> the walk; ``PERF.md`` section 6,
+    PR 39): 32 streams, 4 groups, a window of 4,096 in a ring of 6,144
+    0.461 -> 0.384 (fetched over used 1.25 -> 1.03); a dense cache of
+    16,384 at 8-16 k 1.184 -> 1.063; 128 streams, 2 groups, a dense
+    cache of 4,096 at 2-4 k 0.745 -> 0.580.  An update of the running
+    sums costs about as much at 128 rows as at 512, so the arithmetic
+    wants whole chunks: the same walk with chunks on the CACHE's lattice
+    and both ends of a window computed on cell by cell read 0.395, 1.062
+    and 0.675 (0.808 at chunks of 2,048); chunks of 512 read 0.419,
+    1.106 and 0.809; 3 to 8 buffers, 1 to 8 streams a grid step and
+    chunks of 1,024 or 2,048 read the same to 1 %.
     Heads are padded to whole tiles here; a shape
     :func:`gqa_decode_attention_refusal` names is an error."""
-    import jax.numpy as jnp
-
-    refusal = gqa_decode_attention_refusal(q.shape, k.shape, v.shape,
-                                           window, block)
+    refusal = gqa_decode_attention_refusal(q.shape, k.shape, v.shape, window)
     if refusal:
         raise ValueError(f"gqa_decode_attention: {refusal}")
-    jax, pl, pltpu = _pl()
+    plan = decode_walk_plan(
+        k.shape[2], 2 * q.shape[1] * q.shape[3] * np.dtype(q.dtype).itemsize)
+    return _gqa_decode_walk(q, k, v, positions, window, scale, plan)
+
+
+def _gqa_decode_walk(q, k, v, positions, window: int, scale: float,
+                     plan: WalkPlan):
+    """:func:`gqa_decode_attention` by an explicit ``plan`` (the tests
+    and the chip's sweeps choose theirs)."""
+    import jax
+    import jax.numpy as jnp
+
     b, groups, held, d = q.shape
     per = -(-held // _sublane(q.dtype)) * _sublane(q.dtype)
     if per != held:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, per - held), (0, 0)))
-    total = k.shape[2]
-    rows = latent_block(total, block)
-    ring = total // rows
-    steps = min(ring, (window - 1) // rows + 2)
+    call = _gqa_decode_walk_call(b, groups, per, d, k.shape[2], window,
+                                 float(scale), np.dtype(q.dtype).name, plan,
+                                 _interpret())
+    # the stage a trace books the kernel's time to, as the call's own
+    # name made it before the call sat in a jit (which is no scope)
+    with jax.named_scope("gqa_decode_attention"):
+        out = call(positions.astype(jnp.int32), q, k.astype(q.dtype),
+                   v.astype(q.dtype))
+    return out[:, :, :held]
 
-    def span(pos):
-        """The block the oldest position of ``max(0, pos - window + 1)
-        .. pos`` falls in (counted by position, before the ring folds
-        it) and how many blocks hold one of them."""
-        first = jnp.maximum(pos - window + 1, 0) // rows
-        return first, jnp.minimum(pos // rows - first + 1, ring)
 
-    def kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
-        j = pl.program_id(1)
-        pos = pos_ref[pl.program_id(0)]
-        first, need = span(pos)
+@functools.lru_cache(maxsize=16)
+def _gqa_decode_walk_call(b: int, groups: int, per: int, d: int, total: int,
+                          window: int, scale: float, dtype: str,
+                          plan: WalkPlan, interpret: bool):
+    """The jitted call of :func:`gqa_decode_attention` for one shape,
+    built once: a model's layers share the function, so a program that
+    attends in six layers traces and lowers the kernel once
+    (as :func:`_ssm_decode_step_call` does)."""
+    import jax.numpy as jnp
 
-        @pl.when(j == 0)
-        def _init():
-            m_ref[:] = jnp.full(m_ref.shape, -1e30, jnp.float32)
-            l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
-            acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+    jax, pl, pltpu = _pl()
+    lat, (rows, slots) = _WALK_LATTICE, plan
+    if total % rows or rows % lat or slots < 2:
+        raise ValueError(f"gqa_decode_attention: {plan} does not divide "
+                         f"caches of {total} rows")
+    ahead = slots - 1                    # items in flight beside the one
+    #                                      being computed on
 
-        @pl.when(j < need)
-        def _block():
+    def walk(pos):
+        """A stream's first live cell, its cells and its items."""
+        first, cells = walk_cells(pos, total, window)
+        return first, cells, walk_items(cells, plan)
+
+    def kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, kbuf, vbuf, arrived,
+               base_ref, m_ref, l_ref, acc_ref):
+        i = pl.program_id(0)                # the stream
+        pos = pos_ref[i]
+
+        def copies(stream, start, count, over, slot, wait=False):
+            """Start (or wait for) the copies of an item of ``stream``
+            (:func:`walk_item`) into buffer ``slot``."""
+
+            def copy(cell, offset, size):
+                """``size`` cells from ``cell`` of the cache to
+                ``offset`` cells into the buffer."""
+                for n, (ref, buf) in enumerate(((k_ref, kbuf),
+                                                (v_ref, vbuf))):
+                    dma = pltpu.make_async_copy(
+                        ref.at[stream, :, pl.ds(pl.multiple_of(
+                            cell * lat, lat), size * lat), :],
+                        buf.at[slot, :, pl.ds(pl.multiple_of(
+                            offset * lat, lat), size * lat), :],
+                        arrived.at[n, slot])
+                    dma.wait() if wait else dma.start()
+
+            whole = (count == plan.cells) & jnp.logical_not(over)
+            pl.when(whole)(lambda: copy(start, 0, plan.cells))
+
+            @pl.when(jnp.logical_not(whole | over))
+            def _pieces():
+                for size in plan.pieces:
+                    has, offset = walk_piece(count, size)
+                    pl.when(has)(functools.partial(
+                        copy, start + offset, offset, size))
+
+            @pl.when(over)
+            def _cells():
+                def cell(c, _):
+                    copy((start + c) % (total // lat), c, 1)
+                    return 0
+
+                jax.lax.fori_loop(0, count, cell, 0)
+
+        def update(slot, start, offset, size):
+            """The online softmax over ``size`` rows of buffer ``slot``
+            from row ``offset``; the buffer holds the cache's rows from
+            cell ``start`` on, round the ring's end."""
             # a slot holds the newest position that falls on it: this
             # turn of the ring up to the stream's slot, the turn before
             # beyond it
-            slot = (first + j) % ring * rows + jax.lax.broadcasted_iota(
-                jnp.int32, (per, rows), 1)
-            at = slot + pos // total * total \
-                - jnp.where(slot > pos % total, total, 0)
-            valid = (at >= 0) & (at > pos - window)
+            where = start * lat + offset + jax.lax.broadcasted_iota(
+                jnp.int32, (per, size), 1)
+            where = jnp.where(where >= total, where - total, where)
+            position = where + pos // total * total \
+                - jnp.where(where > pos % total, total, 0)
+            valid = (position >= 0) & (position > pos - window)
             for g in range(groups):
-                kb, vb = k_ref[0, g], v_ref[0, g]          # (rows, d)
+                kb = kbuf[slot, g, pl.ds(offset, size), :]   # (size, d)
+                vb = vbuf[slot, g, pl.ds(offset, size), :]
                 s = jax.lax.dot_general(
                     q_ref[0, g], kb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)    # (per, rows)
+                    preferred_element_type=jnp.float32)    # (per, size)
                 s = jnp.where(valid, s * scale, -1e30)
                 m_prev = m_ref[g]
                 m_new = jnp.maximum(m_prev,
@@ -712,37 +922,95 @@ def gqa_decode_attention(q, k, v, positions, window: int, scale: float,
                     preferred_element_type=jnp.float32)
                 m_ref[g] = m_new
 
-        @pl.when(j == steps - 1)
-        def _finish():
-            o_ref[0] = acc_ref[:] / l_ref[:, :, :1]
+        first, cells, items = walk(pos)
+        # the queue runs on into the next stream's first items
+        after = jnp.minimum(i + 1, b - 1)
+        first_n, cells_n, items_n = walk(pos_ref[after])
+        items_n = jnp.where(i + 1 < b, jnp.minimum(items_n, ahead), 0)
 
-    def cache_block(i, j, pos):
-        first, need = span(pos[i])
-        return i, 0, (first + jnp.minimum(j, need - 1)) % ring, 0
+        @pl.when(i == 0)
+        def _first():
+            base_ref[0] = 0
 
+        base = base_ref[0]          # the buffer of this stream's first item
+
+        def issue(t):
+            """Start item ``t`` of the queue, counted from this stream's
+            first: its own, then the next stream's."""
+            own = t < items
+            start, count, over = walk_item(
+                jnp.where(own, first, first_n),
+                jnp.where(own, cells, cells_n),
+                jnp.where(own, t, t - items), total, plan)
+            pl.when(own | (t - items < items_n))(
+                lambda: copies(jnp.where(own, i, after), start, count, over,
+                               (base + t) % slots))
+
+        def top_up(t, _):
+            # the stream before started this one's first items; nobody
+            # started the first stream's, nor what a stream of few items
+            # leaves of the queue
+            pl.when((i == 0) | (t >= items))(lambda: issue(t))
+            return 0
+
+        jax.lax.fori_loop(0, ahead, top_up, 0)
+        m_ref[:] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        def consume(j, _):
+            slot = (base + j) % slots
+            start, count, over = walk_item(first, cells, j, total, plan)
+            copies(i, start, count, over, slot, wait=True)
+            issue(j + ahead)
+            pl.when(count == plan.cells)(
+                lambda: update(slot, start, 0, rows))
+
+            @pl.when(count < plan.cells)
+            def _pieces():
+                for size in plan.pieces:
+                    has, offset = walk_piece(count, size)
+                    pl.when(has)(functools.partial(
+                        update, slot, start,
+                        pl.multiple_of(offset * lat, lat), size * lat))
+            return 0
+
+        jax.lax.fori_loop(0, items, consume, 0)
+        o_ref[0] = acc_ref[:] / l_ref[:, :, :1]
+        base_ref[0] = (base + items) % slots
+
+    buffer = (slots, groups, rows, d)
     grid = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(b, steps),
+        num_scalar_prefetch=1, grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, groups, per, d), lambda i, j, pos: (i, 0, 0, 0)),
-            pl.BlockSpec((1, groups, rows, d), cache_block),
-            pl.BlockSpec((1, groups, rows, d), cache_block),
+            pl.BlockSpec((1, groups, per, d), lambda i, pos: (i, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, groups, per, d),
-                               lambda i, j, pos: (i, 0, 0, 0)),
+                               lambda i, pos: (i, 0, 0, 0)),
         scratch_shapes=[
+            pltpu.VMEM(buffer, dtype), pltpu.VMEM(buffer, dtype),
+            pltpu.SemaphoreType.DMA((2, slots)),
+            pltpu.SMEM((1,), jnp.int32),                     # first buffer
             pltpu.VMEM((groups, per, _LANE), jnp.float32),   # running max
             pltpu.VMEM((groups, per, _LANE), jnp.float32),   # normaliser
             pltpu.VMEM((groups, per, d), jnp.float32),       # accumulator
         ])
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel, grid_spec=grid,
         out_shape=jax.ShapeDtypeStruct((b, groups, per, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * int(np.prod(buffer))
+            * np.dtype(dtype).itemsize + (4 << 20)),
         name="gqa_decode_attention",
-        interpret=_interpret(),
-    )(positions.astype(jnp.int32), q, k.astype(q.dtype), v.astype(q.dtype))
-    return out[:, :, :held]
+        interpret=interpret)
+
+    def gqa_decode_attention(*operands):
+        return call(*operands)
+
+    return jax.jit(gqa_decode_attention)
 
 
 # -- the routed experts' grouped product --------------------------------------

@@ -350,7 +350,12 @@ class StateStats:
     and routed experts ``cache_bytes_read``, ``experts_touched``,
     ``expert_hits``; a model with two kinds of cache tells them apart,
     ``window_bytes_read`` of its rings and ``full_bytes_read`` of its
-    dense caches, and ``cache_bytes_read`` is their sum; a model with a
+    dense caches, and ``cache_bytes_read`` is their sum; beside each
+    ``*_bytes_read`` of a grouped-query model stands ``*_bytes_fetched``
+    (``window_``, ``full_``, ``kv_`` and ``cache_bytes_fetched``): the
+    rows its decode attention reads in for the rows in use, so fetched
+    over read is what the kernel's schedule costs in bytes, while the
+    benchmark's rooflines count the rows in use; a model with a
     recurrent state adds ``ssm_bytes`` read and written, ``restores``
     from its snapshots and ``position_faults``;
     ``Documentation/observability.md``)."""

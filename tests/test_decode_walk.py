@@ -1,0 +1,291 @@
+"""The walk a decode attention kernel copies a stream's live rows by
+(``nnstreamer_tpu/ops/kernels.py``: ``decode_walk_plan``, ``walk_cells``,
+``walk_items``, ``walk_item``, ``walk_piece``, ``decode_rows_fetched``) and
+``gqa_decode_attention`` on it, interpreted on the CPU: the kernel
+against its ``jnp`` mathematics over rings, dense caches, groups, heads
+and the positions where the walk changes shape; the rows the plan names
+against a brute-force count; and the two models' counters of them.  No
+number here is a rate."""
+
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from nnstreamer_tpu.models import nemotron_h as nh  # noqa: E402
+from nnstreamer_tpu.models import smallthinker as st  # noqa: E402
+from nnstreamer_tpu.ops import kernels  # noqa: E402
+from nnstreamer_tpu.ops.kernels import WalkPlan  # noqa: E402
+
+LAT = 128                                   # the lattice: a lane tile
+
+# -- the kernel against its mathematics ----------------------------------------
+
+#: a ring of 768 wrapped three times, one stream in every cell of it, at
+#: a cell's first row, its last and between
+WRAP = [3 * 768 + c * LAT + r for c, r in enumerate((0, 127, 1, 64, 126, 5))]
+
+CASES = {
+    # the cell's ring layer (two of its four groups, five of its seven
+    # heads, float32), by the plan the call derives: chunks of 1,024
+    "ring-6144-window-4096": (
+        2, 5, 6144, 4096, None, "float32",
+        [0, 4095, 4096, 6143, 6144, 10000, 3 * 6144 - 1]),
+    "small-twin-ring-768-window-512": (
+        2, 5, 768, 512, WalkPlan(256, 3), "float32",
+        [0, 511, 512, 767, 768, 1000]),
+    "every-cell-of-the-ring-at-its-wrap": (
+        2, 5, 768, 512, WalkPlan(256, 4), "float32", WRAP),
+    "young-beside-old": (
+        2, 5, 768, 512, WalkPlan(384, 3), "float32", [100, 5000]),
+    "window-within-a-cell-of-the-ring": (
+        2, 5, 384, 300, WalkPlan(128, 3), "float32",
+        [299, 300, 383, 384, 500, 1151]),
+    "dense-sixteen-heads-bf16": (
+        2, 16, 1024, 1024, WalkPlan(512, 3), "bfloat16",
+        [0, 127, 128, 600, 1023]),
+    "dense-four-groups-five-heads": (
+        4, 5, 512, 512, WalkPlan(256, 3), "float32",
+        [0, 255, 256, 511]),
+    "dense-window-beyond-the-cache": (
+        2, 5, 512, 1 << 20, WalkPlan(256, 2), "float32",
+        [3, 300, 511]),
+    "chunk-of-one-cell": (
+        2, 7, 768, 512, WalkPlan(128, 5), "float32",
+        [511, 512, 900]),
+    "whole-cache-a-chunk": (
+        2, 7, 384, 256, WalkPlan(384, 2), "float32",
+        [5, 300, 1151]),
+    "five-streams-by-the-derived-plan": (
+        2, 5, 256, 256, None, "float32", [0, 17, 128, 200, 255]),
+    "six-streams-four-groups-bf16": (
+        4, 16, 512, 256, WalkPlan(256, 3), "bfloat16",
+        [0, 255, 256, 511, 512, 2000]),
+    "a-queue-longer-than-a-stream": (
+        2, 5, 768, 512, WalkPlan(256, 8), "float32",
+        [0, 130, 5000, 2, 767]),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_walk_is_the_reference(case):
+    groups, per, total, window, plan, dtype, at = CASES[case]
+    rng = np.random.default_rng(3)
+    b = len(at)
+    q = jnp.asarray(rng.normal(size=(b, groups, per, 128)), dtype)
+    k = jnp.asarray(rng.normal(size=(b, groups, total, 128)), dtype)
+    v = jnp.asarray(rng.normal(size=(b, groups, total, 128)), dtype)
+    at = jnp.asarray(at, jnp.int32)
+    if plan is None:
+        got = kernels.gqa_decode_attention(q, k, v, at, window, 0.09)
+    else:
+        got = kernels._gqa_decode_walk(q, k, v, at, window, 0.09, plan)
+    want = kernels.gqa_decode_attention_reference(q, k, v, at, window, 0.09)
+    assert got.shape == (b, groups, per, 128) and got.dtype == jnp.float32
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    assert np.allclose(np.asarray(got), np.asarray(want), atol=tol)
+
+
+# -- the plan a call derives ---------------------------------------------------------
+
+
+@pytest.mark.parametrize("total,row_bytes,plan", [
+    (6144, 2048, (1024, 4)), (16384, 2048, (1024, 4)),
+    (4096, 1024, (2048, 4)), (256, 2048, (128, 8)),
+    (384, 1024, (128, 8)), (768, 64 << 10, (128, 3)),
+], ids=["smallthinker-ring", "smallthinker-full", "nemotron3", "two-cells",
+        "three-cells", "wide-rows"])
+def test_the_plan_follows_what_the_call_sees(total, row_bytes, plan):
+    got = kernels.decode_walk_plan(total, row_bytes)
+    assert tuple(got) == plan
+    assert total % got.chunk == 0 and got.chunk % LAT == 0
+    assert got.slots >= 3 and got.pieces == tuple(
+        1 << k for k in reversed(range((got.cells - 1).bit_length())))
+
+
+def test_a_plan_that_does_not_divide_the_cache_is_an_error():
+    q = jnp.zeros((1, 1, 8, 128))
+    k = jnp.zeros((1, 1, 384, 128))
+    for plan in (WalkPlan(256, 3), WalkPlan(192, 3),
+                 WalkPlan(128, 1)):
+        with pytest.raises(ValueError, match="does not divide"):
+            kernels._gqa_decode_walk(q, k, k, jnp.zeros((1,), jnp.int32),
+                                     384, 1.0, plan)
+
+
+# -- the rows the plan names ------------------------------------------------------------
+
+
+def _named(pos: int, total: int, window: int, plan: WalkPlan):
+    """The cache rows the kernel's plan names for one stream, one entry
+    a copy: ``(first row, rows)``.  The pieces a partial item is copied
+    and computed on by lie end to end from its first cell."""
+    first, cells = (int(x) for x in kernels.walk_cells(pos, total, window))
+    lat, ring = LAT, total // LAT
+    copies = []
+    for item in range(int(kernels.walk_items(cells, plan))):
+        start, count, over = kernels.walk_item(first, cells, item, total,
+                                               plan)
+        start, count, over = int(start), int(count), bool(over)
+        assert 0 <= start < ring and 0 < count <= plan.cells
+        assert over == (start + count > ring)
+        pieces = [(int(offset), size) for size in plan.pieces
+                  for has, offset in [kernels.walk_piece(count, size)]
+                  if has]
+        if count == plan.cells:
+            pieces = [(0, count)]
+        # a partial item: at most one piece of a size, largest first,
+        # end to end from the item's first cell
+        assert [o for o, _ in pieces] \
+            == [sum(n for _, n in pieces[:i]) for i in range(len(pieces))]
+        assert sum(n for _, n in pieces) == count
+        if over:                        # cell by cell round the ring's end
+            copies += [((start + c) % ring * lat, lat) for c in range(count)]
+        else:
+            copies += [((start + o) * lat, n * lat) for o, n in pieces]
+    return copies
+
+
+@pytest.mark.parametrize("total,window,chunk,ends", [
+    (768, 512, 256, 2), (768, 512, 128, 2), (768, 512, 768, 2),
+    (768, 512, 384, 2), (384, 300, 128, 2), (512, 512, 256, 1),
+    (512, 1 << 20, 512, 1),
+], ids=["ring", "ring-chunk-of-a-cell", "ring-one-chunk",
+        "ring-chunk-of-three-cells", "window-within-a-cell-of-the-ring",
+        "dense", "dense-one-chunk"])
+def test_the_rows_fetched_are_the_rows_the_plan_names(total, window, chunk,
+                                                      ends):
+    """For every position of a small cache (a ring: three turns of it):
+    the ``jnp`` count equals the rows of the copies the chunk plan
+    names, which lie inside the cache on the lattice, name no row
+    twice, hold every row in use, and exceed the rows in use by less
+    than a cell at each end the window has inside the cache."""
+    plan = WalkPlan(chunk, 3)
+    last = 3 * total if window < total else total
+    positions = np.arange(last, dtype=np.int32)
+    # one count a stream: the function sums over the streams it is given
+    counted = np.asarray(jax.vmap(
+        lambda p: kernels.decode_rows_fetched(p[None], total, window))(
+            jnp.asarray(positions)))
+    assert int(kernels.decode_rows_fetched(positions, total, window)) \
+        == counted.sum()
+    for pos in positions:
+        copies = _named(int(pos), total, window, plan)
+        rows = [r for at, n in copies for r in range(at, at + n)]
+        assert all(at % LAT == 0 and at + n <= total for at, n in copies)
+        assert len(rows) == len(set(rows)) == counted[pos]
+        in_use = {p % total for p in range(max(0, pos - window + 1), pos + 1)}
+        assert in_use <= set(rows)
+        assert len(rows) - len(in_use) <= ends * (LAT - 1)
+        assert len(rows) <= total
+
+
+def test_the_cells_shapes_fetch_within_the_stated_shares():
+    """At the cells' shapes and positions the walk fetches, over the
+    rows in use: within 7 % on a ring of 6,144 read through a window of
+    4,096, 2 % on a dense cache of 16,384 at 8-16 k, 5 % on one of
+    4,096 at 2-4 k (the schedule by blocks of 1,024 it replaced: 25 %,
+    4 % and 17 %)."""
+    rng = np.random.default_rng(0)
+    for total, window, lo, hi, share in ((6144, 4096, 8192, 16384, 1.07),
+                                         (16384, 16384, 8192, 16384, 1.02),
+                                         (4096, 4096, 2048, 4096, 1.05)):
+        positions = rng.integers(lo, hi, 4096).astype(np.int32)
+        used = np.minimum(positions + 1, window).sum()
+        fetched = int(kernels.decode_rows_fetched(positions, total, window))
+        assert used <= fetched <= share * used
+
+
+# -- the models' counters ------------------------------------------------------------------
+
+
+def _counted(state) -> dict:
+    return {k: int(v) for k, v in state["counters"].items()}
+
+
+def test_smallthinker_counts_the_walk_once_a_layer_of_a_kind():
+    """Three layers at the published head size (a dense cache of 512
+    and two rings of 256 read through a window of 128) decode through
+    the kernel: one step adds the walk's rows of ONE ring to
+    ``window_rows_fetched`` and of ONE dense cache to
+    ``full_rows_fetched``, and the units count the layers."""
+    cfg = st.SmallThinkerConfig.from_dict({
+        "hidden_size": 64, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 128,
+        "moe_ffn_hidden_size": 32, "moe_num_primary_experts": 4,
+        "moe_num_active_primary_experts": 2, "sliding_window_size": 128,
+        "rope_theta": 1500000, "rms_norm_eps": 1e-6,
+        "max_position_embeddings": 512, "vocab_size": 32,
+        "num_hidden_layers": 3, "sliding_window_layout": [0, 1, 1],
+        "rope_layout": [0, 1, 1]})
+    params = st.init_params(cfg, 1, jnp.float32)
+    state = st.init_state(cfg, params, 2, 512, 128)
+    positions = np.array([130, 300], np.int32)
+    before = _counted(state)
+    state, _ = st.decode(cfg, params, state, np.array([3, 5], np.int32),
+                         positions)
+    after = _counted(state)
+    gained = {k: after[k] - before[k] for k in after}
+    assert gained["window_rows_read"] == 2 * 128
+    assert gained["full_rows_read"] == 131 + 301
+    # 3..130 and 173..300 of a ring of 256: two cells each; 0..130 and
+    # 0..300 of the dense cache: two cells and three
+    assert gained["window_rows_fetched"] == (2 + 2) * LAT \
+        == int(kernels.decode_rows_fetched(positions, 256, 128))
+    assert gained["full_rows_fetched"] == (2 + 3) * LAT \
+        == int(kernels.decode_rows_fetched(positions, 512, 512))
+    row = 2 * 2 * 128 * 4
+    units = st.counter_units(cfg, state)
+    assert units["window_bytes_fetched"] == ("window_rows_fetched", row * 2)
+    assert units["full_bytes_fetched"] == ("full_rows_fetched", row * 1)
+    assert units["cache_bytes_fetched"] == [units["window_bytes_fetched"],
+                                            units["full_bytes_fetched"]]
+
+
+def test_nemotron_h_counts_the_walk_once_an_attention_layer():
+    """The toy's seven layers with the published head size: its one
+    attention layer decodes through the kernel over a dense cache of
+    256, and a step adds the walk's rows of ONE layer to
+    ``kv_rows_fetched``."""
+    with open(os.path.join(REPO, "tests", "benchmark", "data",
+                           "toy_nemotron3.json")) as f:
+        toy = json.load(f)
+    cfg = nh.NemotronHConfig.from_dict(
+        dict(toy, head_dim=128, max_position_embeddings=256))
+    params = nh.init_params(cfg, jax.random.PRNGKey(1), jnp.float32)
+    state = nh.init_state(cfg, params, 3, 256)
+    positions = np.array([0, 127, 200], np.int32)
+    # as after prompts that ended one position earlier
+    state = dict(state, last=jnp.asarray(positions - 1),
+                 prompt_end=jnp.asarray(positions))
+    before = _counted(state)
+    state, _ = nh.decode(cfg, params, state,
+                         np.full(3, cfg.vocab0, np.int32), positions)
+    after = _counted(state)
+    assert after["kv_rows_read"] - before["kv_rows_read"] == 1 + 128 + 201
+    assert after["kv_rows_fetched"] - before["kv_rows_fetched"] \
+        == (1 + 1 + 2) * LAT \
+        == int(kernels.decode_rows_fetched(positions, 256, 256))
+    assert after["position_faults"] == 0
+    units = nh.counter_units(cfg, state)
+    assert units["kv_bytes_fetched"] == units["cache_bytes_fetched"] \
+        == ("kv_rows_fetched", 2 * 2 * 128 * 4 * 1)
+
+
+def test_a_refused_shape_fetches_the_cache_whole():
+    """Heads of 16 take the ``jnp`` mathematics, which reads every row
+    of every stream: that is what the counter says then."""
+    assert kernels.gqa_decode_rows_fetched(
+        (3, 2, 4, 16), (3, 2, 40, 16), np.array([1, 2, 3]), 8) == 3 * 40
+    assert int(kernels.gqa_decode_rows_fetched(
+        (3, 2, 4, 128), (3, 2, 256, 128), np.array([1, 2, 200]), 256)) \
+        == 4 * LAT

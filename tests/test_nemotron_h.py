@@ -215,6 +215,8 @@ def test_the_steps_count_what_they_touch(model, served):
     assert once["steps"] == STEPS and once["ssm_rows"] == STEPS * 3
     assert once["kv_rows_read"] == sum(n + j + 1 for n in LENGTHS
                                        for j in range(STEPS))
+    # heads of 16 take the jnp mathematics, which reads the cache whole
+    assert once["kv_rows_fetched"] == STEPS * len(LENGTHS) * POSITIONS
     # three expert layers, three experts a token, a quarter of 16 held
     assert 0 < once["expert_hits"] < STEPS * 3 * 3 * 3
     assert once["experts_touched"] <= min(once["expert_hits"],
@@ -226,6 +228,8 @@ def test_the_steps_count_what_they_touch(model, served):
     assert units["ssm_bytes"] == ("ssm_rows", 2 * row * 3)
     assert units["kv_bytes_read"] == units["cache_bytes_read"] \
         == ("kv_rows_read", 2 * 2 * 16 * 4 * 1)
+    assert units["kv_bytes_fetched"] == units["cache_bytes_fetched"] \
+        == ("kv_rows_fetched", 2 * 2 * 16 * 4 * 1)
 
 
 def test_a_position_the_state_cannot_serve_is_counted(model, served):
@@ -450,6 +454,8 @@ def test_two_launch_lines_prefill_and_decode_on_one_state(toy, files, model):
         assert stats["ssm_bytes"] == 8 * 3 * 2 * (ssm + conv)
         assert stats["kv_bytes_read"] == stats["cache_bytes_read"] \
             == 2 * sum(3 * (13 + j + 1) for j in range(4)) * 2 * 2 * 16 * 4
+        assert stats["kv_bytes_fetched"] == stats["cache_bytes_fetched"] \
+            == 8 * 3 * POSITIONS * 2 * 2 * 16 * 4
         assert stats["state_bytes"] == cell.state_bytes
         pre.stop()
         run.stop()

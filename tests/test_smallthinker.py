@@ -146,12 +146,19 @@ def test_the_steps_count_what_they_read(model, served):
         n + j + 1 for n in LENGTHS for j in range(STEPS))
     assert counters["expert_hits"] == STEPS * 3 * cfg.layers * cfg.top_k
     assert 0 < counters["experts_touched"] <= STEPS * cfg.layers * cfg.experts
+    # heads of 16 take the jnp mathematics, which reads a cache whole:
+    # the ring of 12 and all POSITIONS of a dense cache, every stream
+    assert counters["window_rows_fetched"] == STEPS * len(LENGTHS) * 12
+    assert counters["full_rows_fetched"] == STEPS * len(LENGTHS) * POSITIONS
     row = 2 * 2 * 16 * 4                     # K and V, float32 here
-    assert st.counter_units(cfg, served["state"]) == {
-        "window_bytes_read": ("window_rows_read", row * 4),
-        "full_bytes_read": ("full_rows_read", row * 2),
-        "cache_bytes_read": [("window_rows_read", row * 4),
-                             ("full_rows_read", row * 2)]}
+    units = {}
+    for did in ("read", "fetched"):
+        window = (f"window_rows_{did}", row * 4)
+        full = (f"full_rows_{did}", row * 2)
+        units.update({f"window_bytes_{did}": window,
+                      f"full_bytes_{did}": full,
+                      f"cache_bytes_{did}": [window, full]})
+    assert st.counter_units(cfg, served["state"]) == units
 
 
 @pytest.mark.parametrize("first,chunk", [
@@ -260,38 +267,42 @@ def test_the_configuration_is_read_as_published(toy):
 # -- the decode kernel --------------------------------------------------------------
 
 
-@pytest.mark.parametrize("groups,per,total,window,block,dtype,at", [
+@pytest.mark.parametrize("groups,per,total,window,chunk,dtype,at", [
     (2, 7, 384, 256, 128, "float32", [5, 300, 383 + 384 * 2]),
     (2, 3, 384, 200, 128, "float32", [199, 200, 201]),
     (4, 7, 512, 512, 128, "bfloat16", [0, 127, 128]),
     (1, 7, 256, 1 << 20, 256, "float32", [3, 255, 128]),
-    (2, 4, 256, 128, 512, "float32", [130, 300, 255]),
+    (2, 4, 256, 128, 256, "float32", [130, 300, 255]),
 ], ids=["ring-wrapped-twice", "window-off-the-blocks", "bf16-four-groups",
         "dense-cache", "ring-of-one-block"])
-def test_gqa_kernel_is_its_reference(groups, per, total, window, block,
+def test_gqa_kernel_is_its_reference(groups, per, total, window, chunk,
                                      dtype, at):
     """The Pallas kernel (interpreted on the CPU) against its jnp
-    mathematics: a stream inside the first block, one at the window's
+    mathematics: a stream inside the first chunk, one at the window's
     edge, one that has wrapped the ring twice; a window that starts
-    inside a block; a dense cache read up to the position."""
+    inside a chunk; a dense cache read up to the position.  By the plan
+    the call derives and by one of ``chunk`` rows a buffer
+    (``tests/test_decode_walk.py`` has the walk's own cases)."""
     rng = np.random.default_rng(2)
     q = jnp.asarray(rng.normal(size=(3, groups, per, 128)), dtype)
     k = jnp.asarray(rng.normal(size=(3, groups, total, 128)), dtype)
     v = jnp.asarray(rng.normal(size=(3, groups, total, 128)), dtype)
     at = jnp.asarray(at, jnp.int32)
     assert kernels.gqa_decode_attention_refusal(
-        q.shape, k.shape, v.shape, window, block) is None
-    got = kernels.gqa_decode_attention(q, k, v, at, window, 0.09, block)
+        q.shape, k.shape, v.shape, window) is None
+    got = kernels.gqa_decode_attention(q, k, v, at, window, 0.09)
     want = kernels.gqa_decode_attention_reference(q, k, v, at, window, 0.09)
     assert got.shape == (3, groups, per, 128) and got.dtype == jnp.float32
     tol = 2e-5 if dtype == "float32" else 2e-2
     assert np.allclose(np.asarray(got), np.asarray(want), atol=tol)
+    plan = kernels.WalkPlan(chunk, 3)
+    by_plan = kernels._gqa_decode_walk(q, k, v, at, window, 0.09, plan)
+    assert np.allclose(np.asarray(by_plan), np.asarray(want), atol=tol)
     # a slot beyond the window moves nothing: the stream at 300 of the
     # first case sees 45..300, so slot 44 may hold anything
     if window == 256:
         k2 = k.at[1, :, 44].set(1e4)
-        again = kernels.gqa_decode_attention(q, k2, v, at, window, 0.09,
-                                             block)
+        again = kernels.gqa_decode_attention(q, k2, v, at, window, 0.09)
         assert np.array_equal(np.asarray(again[1]), np.asarray(got[1]))
 
 
@@ -405,7 +416,7 @@ def test_two_launch_lines_prefill_and_decode_on_one_state(toy, files, model):
         shapes = {leaf.shape for leaf in jax.tree_util.tree_leaves(
             cell.state["cache"])}
         assert shapes == {(3, 2, POSITIONS, 16), (3, 2, 12, 16)}
-        assert cell.state_bytes == 5 * 4 \
+        assert cell.state_bytes == len(st.COUNTERS) * 4 \
             + 2 * 3 * 2 * 16 * 4 * (2 * POSITIONS + 4 * 12)
         for j, buf in enumerate(served):
             ref = files["reference"].forward_last(
@@ -420,6 +431,10 @@ def test_two_launch_lines_prefill_and_decode_on_one_state(toy, files, model):
             3 * (16 + j + 1) for j in range(4)) * row * 2
         assert stats["cache_bytes_read"] == stats["window_bytes_read"] \
             + stats["full_bytes_read"]
+        assert stats["window_bytes_fetched"] == 4 * 3 * 12 * row * 4
+        assert stats["full_bytes_fetched"] == 4 * 3 * POSITIONS * row * 2
+        assert stats["cache_bytes_fetched"] == stats["window_bytes_fetched"] \
+            + stats["full_bytes_fetched"]
         assert stats["state_bytes"] == cell.state_bytes
         pre.stop()
         run.stop()

@@ -1,5 +1,5 @@
 """Routed experts as the token models run them (``deepseek_v2.py``,
-``smallthinker.py``, ``nemotron_h.py``): the plan that sorts (token,
+``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``): the plan that sorts (token,
 expert) pairs by expert, the product over blocks of one expert's rows,
 and the weighted sum back to tokens.  No token is dropped and there is
 no capacity factor; only the blocks in use are computed, so the work
@@ -11,7 +11,8 @@ experts are held, and the form of one (``silu`` or ``relu``: gated,
 ``act(x W_gate) * (x W_up)`` through ``W_down``, three matrices;
 ``relu2``: ungated, ``relu(x W_up)^2`` through ``W_down``, two).  How a
 model routes (groups, scaling, normalisation, what the router reads)
-stays with the model.
+stays with the model; the sigmoid router two of them share is
+:func:`route_sigmoid`.
 
 Also the small parts the models are made of: RMSNorm with float32
 statistics, and a product in the weights' type accumulated in float32.
@@ -59,6 +60,21 @@ def mm(x, w):
     """``x @ w`` in the weights' type with float32 accumulation."""
     return jnp.matmul(x, w, preferred_element_type=jnp.float32,
                       precision=precision(w))
+
+
+def route_sigmoid(u, router, bias, top_k: int, scaling: float):
+    """Sigmoid scores over ALL the published experts in float32; the
+    ``top_k`` largest of ``score + bias`` are chosen, and weighted by
+    their scores alone, normalised to 1 and scaled by ``scaling``:
+    ``(idx [N, k] int32, weight [N, k] float32)``.  The router of
+    ``nemotron_h.py`` and ``exaone_moe.py`` (no group limit)."""
+    score = jax.nn.sigmoid(jnp.matmul(
+        u.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(score + bias, top_k)
+    kept = jnp.take_along_axis(score, idx, axis=-1)
+    weight = kept / jnp.sum(kept, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), weight * scaling
 
 
 def block_rows(n_tokens: int) -> int:

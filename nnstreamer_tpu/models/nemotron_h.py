@@ -89,10 +89,9 @@ except ImportError:  # pragma: no cover
 
 from ..ops import kernels
 from ..utils import profile as _profile
-from . import moe
+from . import attention, moe
 
 Params = dict
-NEG = -1e30
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
 
 
@@ -211,23 +210,6 @@ class NemotronHConfig:
         return self.pattern.count(kind)
 
 
-# -- the router ---------------------------------------------------------------
-
-
-def route(cfg: NemotronHConfig, u, router, bias):
-    """Sigmoid scores over ALL the published experts in float32; the
-    ``top_k`` largest of ``score + bias`` are chosen, and weighted by
-    their scores alone, normalised to 1 and scaled: ``(idx [N, k] int32,
-    weight [N, k] float32)``."""
-    score = jax.nn.sigmoid(jnp.matmul(
-        u.astype(jnp.float32), router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
-    _, idx = lax.top_k(score + bias, cfg.top_k)
-    kept = jnp.take_along_axis(score, idx, axis=-1)
-    weight = kept / jnp.sum(kept, axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), weight * cfg.routed_scaling_factor
-
-
 def _shared_expert(p, x):
     """One expert of the routed ones' form, dense: every token."""
     act, _gated = moe.activation("relu2")
@@ -241,7 +223,8 @@ def moe_parts(cfg: NemotronHConfig, p, u):
     chip computes alike), and how many tokens each held expert got."""
     n = u.shape[0]
     with jax.named_scope("router"):
-        idx, weight = route(cfg, u, p["router"], p["router_bias"])
+        idx, weight = moe.route_sigmoid(u, p["router"], p["router_bias"],
+                                        cfg.top_k, cfg.routed_scaling_factor)
     with jax.named_scope("dispatch"):
         plan = moe.dispatch(idx, n, cfg.expert0, cfg.experts)
     with jax.named_scope("experts"):
@@ -471,52 +454,11 @@ def _out(p, o, dtype):
 def attn_prefill(cfg: NemotronHConfig, p, u, cache, slot, start,
                  key_block: int = 1024):
     """A chunk ``u [C, hidden]`` of stream ``slot`` whose first token is
-    at ``start``: writes the chunk's K and V rows, then attends to the
-    stream's cache block by block with a running softmax, position ``p``
-    seeing ``0 .. p``.  A padded token's row lies beyond the prompt and
-    is overwritten by the answer before any step reads it."""
-    size, total = u.shape[0], cache["k"].shape[2]
-    positions = start + jnp.arange(size, dtype=jnp.int32)
-    q, k, v = _qkv(cfg, p, u)
-    with jax.named_scope("cache_write"):
-        cache = {
-            "k": cache["k"].at[slot, :, positions].set(
-                k.astype(cache["k"].dtype)),
-            "v": cache["v"].at[slot, :, positions].set(
-                v.astype(cache["v"].dtype))}
-    kb = math.gcd(int(key_block), total)
-    hp = moe.precision(p["q"])
-    scale = cfg.head_dim ** -0.5
-
-    def body(j, carry):
-        m, l, acc = carry
-        kj, vj = (lax.dynamic_slice(
-            cache[name], (slot, 0, j * kb, 0),
-            (1, cfg.kv_heads, kb, cfg.head_dim))[0].astype(u.dtype)
-            for name in ("k", "v"))
-        s = jnp.einsum("cgqd,gkd->gqck", q, kj,
-                       preferred_element_type=jnp.float32, precision=hp)
-        keys = j * kb + jnp.arange(kb, dtype=jnp.int32)
-        seen = keys[None, :] <= positions[:, None]
-        s = jnp.where(seen[None, None], s * scale, NEG)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        alpha = jnp.exp(m - m_new)
-        prob = jnp.exp(s - m_new[..., None])
-        l = l * alpha + prob.sum(axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "gqck,gkd->gqcd", prob.astype(u.dtype), vj,
-            preferred_element_type=jnp.float32, precision=hp)
-        return m_new, l, acc
-
-    # the first block holds position 0, which every query sees: a later
-    # block whose keys are all masked for a query adds nothing to it
-    blocks = jnp.minimum(total // kb, (start + size - 1 + kb) // kb)
-    m0 = jnp.full((cfg.kv_heads, cfg.per_group, size), NEG, jnp.float32)
-    _, l, acc = lax.fori_loop(
-        0, blocks, body,
-        (m0, jnp.zeros_like(m0),
-         jnp.zeros(m0.shape + (cfg.head_dim,), jnp.float32)))
-    o = (acc / l[..., None]).transpose(2, 0, 1, 3)             # [C, g, q, d]
+    at ``start``: ``models/attention.py`` ``full_prefill`` on this
+    model's q, k and v (nothing is rotated)."""
+    o, cache = attention.full_prefill(
+        lambda _positions: _qkv(cfg, p, u), u.shape[0], cache, slot, start,
+        moe.precision(p["q"]), key_block)
     return _out(p, o, u.dtype), cache
 
 

@@ -72,7 +72,7 @@ except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
 from ..ops import kernels
-from . import moe
+from . import attention, moe
 
 Params = dict
 NEG = -1e30
@@ -157,24 +157,6 @@ class SmallThinkerConfig:
 # -- small parts --------------------------------------------------------------
 
 
-def _cos_sin(cfg: SmallThinkerConfig, positions):
-    half = cfg.head_dim // 2
-    inv_freq = 1.0 / cfg.rope_theta ** (
-        np.arange(half, dtype=np.float64) / half)
-    angle = positions.astype(jnp.float32)[..., None] \
-        * jnp.asarray(inv_freq, jnp.float32)
-    return jnp.cos(angle), jnp.sin(angle)
-
-
-def _rope(x, cos, sin):
-    """Rotate pairs ``(i, i + d/2)`` of the last axis; ``cos`` and
-    ``sin`` broadcast against ``x[..., :d/2]``."""
-    half = x.shape[-1] // 2
-    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
 def route(cfg: SmallThinkerConfig, h, router):
     """The ``top_k`` largest logits of ``h W_r`` and the softmax over
     them, in float32: ``(idx [N, k] int32, weight [N, k] float32)``."""
@@ -193,9 +175,10 @@ def _qkv(cfg: SmallThinkerConfig, p, h, positions, rotary: bool):
     k = moe.mm(h, p["k"]).astype(dt).reshape(n, cfg.kv_heads, cfg.head_dim)
     v = moe.mm(h, p["v"]).astype(dt).reshape(n, cfg.kv_heads, cfg.head_dim)
     if rotary:
-        cos, sin = _cos_sin(cfg, positions)
-        q = _rope(q, cos[:, None, None], sin[:, None, None])
-        k = _rope(k, cos[:, None], sin[:, None])
+        cos, sin = attention.rope_angles(cfg.rope_theta, cfg.head_dim,
+                                         positions)
+        q = attention.rope(q, cos[:, None, None], sin[:, None, None])
+        k = attention.rope(k, cos[:, None], sin[:, None])
     return q, k, v
 
 

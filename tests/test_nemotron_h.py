@@ -259,7 +259,12 @@ def test_router_weights(model):
     router = jax.random.normal(keys[1], (cfg.hidden_size, 16)) * 0.2
     score = 1 / (1 + np.exp(-np.asarray(u, np.float64)
                             @ np.asarray(router, np.float64)))
-    idx, weight = nh.route(cfg, u, router, jnp.zeros(16))
+
+    def route(u, bias):
+        return moe.route_sigmoid(u, router, bias, cfg.top_k,
+                                 cfg.routed_scaling_factor)
+
+    idx, weight = route(u, jnp.zeros(16))
     want = np.argsort(-score, axis=-1)[:, :cfg.top_k]
     assert np.array_equal(np.sort(np.asarray(idx)), np.sort(want))
     kept = np.take_along_axis(score, np.asarray(idx), axis=-1)
@@ -271,7 +276,7 @@ def test_router_weights(model):
     low = np.argsort(score, axis=-1)[:, :cfg.top_k]
     for token in range(5):
         bias = jnp.zeros(16).at[low[token]].set(10.0)
-        idx, weight = nh.route(cfg, u[token:token + 1], router, bias)
+        idx, weight = route(u[token:token + 1], bias)
         assert set(np.asarray(idx)[0]) == set(low[token])
         kept = score[token][np.asarray(idx)[0]]
         assert np.allclose(np.asarray(weight)[0], 2.5 * kept / kept.sum(),

@@ -225,3 +225,111 @@ def test_the_hybrid_cells_programs_copy_no_recurrent_state(
     # the kernel picks each stream's source: no restore loop before the
     # layers (the scope holds nothing at these shapes)
     assert "ssm_restore" not in text
+
+
+def _kexaone(one_chip):
+    """`kexaone.decode16k`'s configuration, and its weights and state as
+    shapes on the described chip (9.1 GB and 4.5 GB)."""
+    import json
+    import os
+
+    from nnstreamer_tpu.models import exaone_moe as ex
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "kexaone_236b_share8.json")
+    with open(path) as f:
+        cfg = ex.ExaoneMoeConfig.from_dict(json.load(f))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: ex.init_params(cfg, 0))
+    state = jax.eval_shape(
+        lambda: ex.init_state(cfg, params, 32, 16384, 256))
+    return ex, cfg, on_chip(params), on_chip(state)
+
+
+def test_the_window128_cells_kernels_compile_at_its_shapes(one_chip,
+                                                            monkeypatch):
+    """`kexaone.decode16k`'s calls, bf16, as Mosaic kernels: attention
+    of 8 groups of 8 heads of 128 over a ring of 384 read through a
+    window of 128 (chunks of one cell) and over a dense cache of 16,384,
+    and the routed experts' gated product at hidden 6,144 x 2,048 in
+    tiles of 256 columns, for a decode step's 32 tokens and a prefill
+    chunk's 2,048."""
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    assert kernels.decode_walk_plan(384, 4096) == kernels.WalkPlan(128, 8)
+    assert kernels.decode_walk_plan(16384, 4096) == kernels.WalkPlan(512, 4)
+    for total, window in ((384, 128), (16384, 16384)):
+        fn = jax.jit(functools.partial(kernels.gqa_decode_attention,
+                                       window=window, scale=128 ** -0.5))
+        compiled = fn.lower(shape((32, 8, 8, 128)),
+                            shape((32, 8, total, 128)),
+                            shape((32, 8, total, 128)),
+                            shape((32,), jnp.int32)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert kernels.grouped_tile(6144, 2048, jnp.bfloat16) == 256
+    from nnstreamer_tpu.models import moe
+
+    for tokens in (32, 2048):
+        blk = moe.block_rows(tokens)
+        rows = -(-tokens * 8 // blk) * blk + 16 * blk
+
+        def product(x, gate, up, down, row_token, block_expert, blocks):
+            return kernels.grouped_gated_product(
+                x, gate, up, down, row_token, block_expert, blocks, blk,
+                jax.nn.silu)
+
+        compiled = jax.jit(product).lower(
+            shape((tokens, 6144)), shape((16, 6144, 2048)),
+            shape((16, 6144, 2048)), shape((16, 2048, 6144)),
+            shape((rows,), jnp.int32), shape((rows // blk,), jnp.int32),
+            shape((), jnp.int32)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("entry,temp_mb", [("decode", 64), ("prefill", 1536)])
+def test_the_window128_cells_programs_copy_no_cache(one_chip, monkeypatch,
+                                                    entry, temp_mb):
+    """`kexaone.decode16k`'s two programs at the cell's sizes (five
+    layers and the prediction module, 32 streams, 16,384 positions,
+    chunks of 2,048; 9.1 GB of weights and 4.5 GB of state as
+    arguments): the caches are updated in the donated buffers.  A full
+    cache is 1.07 GB a tensor, so a copy of one shows in the
+    temporaries, and so would a prefill chunk's scores against a whole
+    cache (`[64, 2048, 16384]` float32: 8.6 GB).  A decode step attends
+    through the kernel in all six caches and runs the routed experts'
+    grouped product in four layers and the module."""
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    ex, cfg, params, state = _kexaone(one_chip)
+    nbytes = sum(a.size * a.dtype.itemsize
+                 for a in jax.tree_util.tree_leaves(state))
+    assert 4.49e9 < nbytes < 4.51e9
+    rings = [c["k"].shape[2] for c in state["cache"]]
+    assert rings == [384, 384, 384, 16384, 384] and max(
+        r for r in rings if r < 16384) <= 512
+
+    def i32(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+
+    fn, inputs = {"decode": (ex.decode, [i32(32)] * 3),
+                  "prefill": (ex.prefill, [i32(2048), i32(2048), i32(1),
+                                           i32(1), i32(1)])}[entry]
+    compiled = jax.jit(functools.partial(fn, cfg), donate_argnums=(1,)) \
+        .lower(params, state, *inputs).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= nbytes
+    assert memory.temp_size_in_bytes < temp_mb << 20
+    # weights, state and temporaries together fit a chip's 16 GB
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.5e9
+    assert compiled.as_text().count("tpu_custom_call") >= 5 + (
+        6 if entry == "decode" else 0)

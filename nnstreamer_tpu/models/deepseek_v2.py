@@ -32,7 +32,7 @@ no ``[heads, chunk, positions]`` score tensor exists) and writes the
 chunk's rows; :func:`decode` runs one token of EVERY stream through the
 absorbed form (``q~ = q_nope W_kvb[k]^T``, scores and values straight on
 the latent rows: ``ops/kernels.py`` ``latent_decode_attention``, one pass
-over the cache).  Positions come with the frame; rows beyond a
+over a stream's live rows, which the kernel copies itself).  Positions come with the frame; rows beyond a
 stream's position are masked, so a stale or padded row is never read.
 
 Stage scopes (``Documentation/observability.md``): ``embed``,
@@ -57,7 +57,7 @@ try:
 except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
-from ..ops.kernels import latent_decode_attention
+from ..ops import kernels
 from . import moe
 
 Params = dict
@@ -406,8 +406,8 @@ def attn_decode(cfg: DeepSeekV2Config, p, x, cache, positions):
                        precision=hp).astype(x.dtype)
     q_cat = jnp.concatenate([q_abs, q_rope, jnp.zeros(
         (b, cfg.heads, cfg.row - cfg.latent), x.dtype)], axis=-1)
-    o_lat = latent_decode_attention(q_cat, cache, positions,
-                                    cfg.kv_lora_rank, attn_scale(cfg))
+    o_lat = kernels.latent_decode_attention(
+        q_cat, cache, positions, cfg.kv_lora_rank, attn_scale(cfg))
     o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(x.dtype),
                    w_kvb[..., cfg.qk_nope_head_dim:],
                    preferred_element_type=jnp.float32,
@@ -470,15 +470,15 @@ def init_state(cfg: DeepSeekV2Config, params, streams: int, positions: int,
     held layers, and the counters the steps add to (``uint32``: the
     reader takes differences, so a wrap costs nothing)."""
     dtype = dtype or params["embed"].dtype
-    # whole lanes of positions (a block of the decode kernel keeps its
-    # scores' positions on the lane axis); rows never written are masked
+    # whole lattice cells of positions (the decode kernel copies a
+    # stream's live rows by cells of 128); rows never written are masked
     positions = -(-int(positions) // 128) * 128
     # one buffer a leaf: the state is donated leaf by leaf
     return {"cache": [jnp.zeros((streams, positions, cfg.row), dtype)
                       for _ in range(cfg.layers)],
             "counters": {name: jnp.zeros((), jnp.uint32) for name in (
-                "steps", "cache_rows_read", "experts_touched",
-                "expert_hits")}}
+                "steps", "cache_rows_read", "cache_rows_fetched",
+                "experts_touched", "expert_hits")}}
 
 
 def counters(state: dict) -> dict:
@@ -487,9 +487,14 @@ def counters(state: dict) -> dict:
 
 def counter_units(cfg: DeepSeekV2Config, state: dict) -> dict:
     """What one count of each counter stands for.  ``cache_rows_read``
-    counts latent rows of ONE layer; a row is read in every layer."""
-    row = cfg.latent * state["cache"][0].dtype.itemsize
-    return {"cache_bytes_read": ("cache_rows_read", row * cfg.layers)}
+    counts the latent rows IN USE of ONE layer (``0 .. position``), a
+    row its ``latent`` values; ``cache_rows_fetched`` the rows the
+    decode kernel copies for them (``ops/kernels.py``
+    ``decode_rows_fetched``: every live cell whole), a row as the cache
+    holds it, padded to whole lanes.  A row is read in every layer."""
+    size = state["cache"][0].dtype.itemsize * cfg.layers
+    return {"cache_bytes_read": ("cache_rows_read", cfg.latent * size),
+            "cache_bytes_fetched": ("cache_rows_fetched", cfg.row * size)}
 
 
 def prefill(cfg: DeepSeekV2Config, params, state, ids, slot, start):
@@ -516,10 +521,13 @@ def decode(cfg: DeepSeekV2Config, params, state, ids, positions):
         cfg, params, x, state["cache"],
         lambda p, h, cache: attn_decode(cfg, p, h, cache, positions))
     logits, greedy = _head(cfg, params, x)
-    old = state["counters"]
+    old, total = state["counters"], caches[0].shape[1]
     new = {"steps": old["steps"] + jnp.uint32(1),
            "cache_rows_read": old["cache_rows_read"]
            + jnp.sum(positions + 1).astype(jnp.uint32),
+           "cache_rows_fetched": old["cache_rows_fetched"]
+           + kernels.decode_rows_fetched(positions, total, total).astype(
+               jnp.uint32),
            "experts_touched": old["experts_touched"]
            + jnp.sum(got > 0).astype(jnp.uint32),
            "expert_hits": old["expert_hits"]

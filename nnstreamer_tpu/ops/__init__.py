@@ -5,9 +5,11 @@ Where the reference hand-vectorizes with Orc SIMD kernels
 package holds hand-written TPU kernels for the ops worth owning below
 XLA: the streaming normalize/typecast prologue, the flash-attention
 block kernel behind long-context attention, whole-sequence attention
-for short sequences, the one-pass decode attention over a latent
-cache, grouped-query decode attention over a ring or a dense K/V
-cache, and the routed experts' grouped product.  Every kernel has a jnp
+for short sequences, the two decode attention kernels (over a latent
+cache, and grouped-query over a ring or a dense K/V cache: both leave
+their caches in HBM and walk a stream's live rows, copying them
+through one queue of buffers), the routed experts' grouped product and
+the Mamba-2 decode step.  Every kernel has a jnp
 reference implementation (the grouped product's is the loop in
 ``models/moe.py``); the first two
 say through their ``*_available`` rule when a caller should use it

@@ -22,17 +22,20 @@ Parity/role:
   output projection reads, scores in VMEM only.  ``S`` need not tile:
   it pads and masks inside.
 - ``latent_decode_attention`` is absorbed latent attention of one token
-  a stream over a latent cache (``models/deepseek_v2.py``'s decode
-  step): one pass over the cache, blocks beyond a stream's position
-  skipped.
+  a stream over a dense latent cache (``models/deepseek_v2.py``'s
+  decode step): one pass over a stream's live rows, a chunk read once
+  for both products.
 - ``gqa_decode_attention`` is grouped-query attention of one token a
   stream over separate K and V caches (``models/smallthinker.py``'s
-  decode step and ``models/nemotron_h.py``'s), the cache a ring a
-  stream wraps around or a dense array it grows into: the caches stay
-  in HBM and the kernel copies a stream's live rows itself, whole
-  chunks in the middle of a window and cells of 128 rows at its ends,
-  through a queue that runs on into the next stream; a chunk of K and
-  of V is read once for all the query heads of its group.
+  decode step, ``models/nemotron_h.py``'s and
+  ``models/exaone_moe.py``'s), the cache a ring a stream wraps around
+  or a dense array it grows into; a chunk of K and of V is read once
+  for all the query heads of its group.
+  Both decode kernels WALK: the caches stay in HBM and the kernel
+  copies a stream's live rows itself, whole chunks counted from the
+  first live cell and pieces of 2^k cells of 128 rows at the end,
+  through one queue of buffers (``_walk_stream``) that runs on into
+  the next stream, so no stream starts cold.
 - ``grouped_gated_product`` is the gated MLPs of the experts a decode
   step's tokens were routed to (``models/moe.py`` ``grouped_experts``):
   one call whose grid walks the plan's blocks with the block's expert
@@ -426,157 +429,6 @@ def short_attention(qkv, heads: int, scale: Optional[float] = None):
     )(qkv, qkv, qkv)
 
 
-# -- latent decode attention --------------------------------------------------
-
-
-def latent_block(positions: int, want: int = 1024) -> int:
-    """Positions of one cache block of :func:`latent_decode_attention`:
-    the largest divisor of ``positions`` up to ``want`` that is whole
-    lanes (a block's scores keep positions on the lane axis), or 0
-    where there is none."""
-    for rows in range(min(want, positions) // _LANE * _LANE, 0, -_LANE):
-        if positions % rows == 0:
-            return rows
-    return 0
-
-
-def latent_decode_attention_refusal(q_shape, cache_shape,
-                                    rank: int) -> Optional[str]:
-    """Why :func:`latent_decode_attention` cannot take these shapes, or
-    None: the row width whole lanes and the same on both sides, ``rank``
-    within it, and a block that divides the cache's positions."""
-    if len(q_shape) != 3 or len(cache_shape) != 3 \
-            or q_shape[0] != cache_shape[0]:
-        return f"q {tuple(q_shape)} and cache {tuple(cache_shape)} are " \
-               "not [B, heads, width] and [B, positions, width]"
-    width = q_shape[2]
-    if width != cache_shape[2] or width % _LANE or not 0 < rank <= width:
-        return f"row width {width} (cache {cache_shape[2]}) must be whole " \
-               f"lanes of {_LANE} and hold the {rank} values"
-    if not latent_block(cache_shape[1]):
-        return f"{cache_shape[1]} cache positions are not whole lanes " \
-               f"of {_LANE}"
-    return None
-
-
-def latent_decode_attention_reference(q, cache, positions, rank: int,
-                                      scale: float):
-    """The kernel's mathematics in jnp: every head's scores against
-    every cached row up to the stream's position, softmax in float32,
-    values the rows' first ``rank`` entries."""
-    import jax
-    import jax.numpy as jnp
-
-    hp = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
-    cache = cache.astype(q.dtype)
-    s = jnp.einsum("bhl,btl->bht", q, cache,
-                   preferred_element_type=jnp.float32, precision=hp)
-    t = jnp.arange(cache.shape[1], dtype=jnp.int32)
-    s = jnp.where(t[None, None, :] <= positions[:, None, None],
-                  s * scale, -1e30)
-    prob = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bht,btl->bhl", prob.astype(q.dtype), cache,
-                      preferred_element_type=jnp.float32,
-                      precision=hp)[..., :rank]
-
-
-def latent_decode_attention(q, cache, positions, rank: int, scale: float):
-    """Absorbed latent attention of one token a stream over a latent
-    cache: ``q [B, heads, width]`` (each head's absorbed query and its
-    rotary part side by side, zeros to the width), ``cache [B,
-    positions, width]`` (a token's ``rank`` latent values, its rotary
-    key, zeros to the width), ``positions [B]`` int32.  Returns ``[B,
-    heads, rank]`` float32: softmax(q cache^T * scale) over rows
-    ``0..positions[b]``, times the rows' first ``rank`` entries.
-
-    One pass over the cache: a block of rows is read once into VMEM and
-    serves both products (the scores contract over the row's whole
-    width, the values take its first ``rank`` lanes), with a running
-    max, normaliser and accumulator across blocks.  Blocks beyond a
-    stream's position are neither computed nor fetched: their index map
-    repeats the last block in use, and a repeated block is not copied
-    again.  (XLA's own two products read every position of the cache
-    twice, and one whose rows are not whole lanes it copies whole first;
-    ``PERF.md`` has the chip's readings.)  Heads are padded to whole
-    tiles here; a shape :func:`latent_decode_attention_refusal` names
-    is an error, there is no second path."""
-    import jax.numpy as jnp
-
-    refusal = latent_decode_attention_refusal(q.shape, cache.shape, rank)
-    if refusal:
-        raise ValueError(f"latent_decode_attention: {refusal}")
-    jax, pl, pltpu = _pl()
-    b, held, width = q.shape
-    # whole tiles of heads: padded heads score zero everywhere and are
-    # cut off again; values that are not whole lanes come out of the
-    # whole row
-    heads = -(-held // _sublane(q.dtype)) * _sublane(q.dtype)
-    if heads != held:
-        q = jnp.pad(q, ((0, 0), (0, heads - held), (0, 0)))
-    values = rank if rank % _LANE == 0 else width
-    rows = latent_block(cache.shape[1])
-    blocks = cache.shape[1] // rows
-
-    def kernel(pos_ref, q_ref, k_ref, o_ref, m_ref, l_ref, acc_ref):
-        j = pl.program_id(1)
-        pos = pos_ref[pl.program_id(0)]
-
-        @pl.when(j == 0)
-        def _init():
-            m_ref[:] = jnp.full(m_ref.shape, -1e30, jnp.float32)
-            l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
-            acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-        @pl.when(j * rows <= pos)
-        def _block():
-            kb = k_ref[0]                                  # (rows, width)
-            s = jax.lax.dot_general(
-                q_ref[0], kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # (heads, rows)
-            at = j * rows + jax.lax.broadcasted_iota(
-                jnp.int32, (heads, rows), 1)
-            s = jnp.where(at <= pos, s * scale, -1e30)
-            # running max / normaliser replicated across a lane width,
-            # as in flash_attention above
-            m_prev = m_ref[:]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            corr = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, :1])
-            l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[:] = acc_ref[:] * corr[:, :1] + jax.lax.dot_general(
-                p.astype(kb.dtype), kb[:, :values], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[:] = m_new
-
-        @pl.when(j == blocks - 1)
-        def _finish():
-            o_ref[0] = acc_ref[:] / l_ref[:, :1]
-
-    grid = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(b, blocks),
-        in_specs=[
-            pl.BlockSpec((1, heads, width), lambda i, j, pos: (i, 0, 0)),
-            pl.BlockSpec((1, rows, width), lambda i, j, pos: (
-                i, jnp.minimum(j, pos[i] // rows), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, heads, values),
-                               lambda i, j, pos: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((heads, _LANE), jnp.float32),     # running max
-            pltpu.VMEM((heads, _LANE), jnp.float32),     # normaliser
-            pltpu.VMEM((heads, values), jnp.float32),    # accumulator
-        ])
-    out = pl.pallas_call(
-        kernel, grid_spec=grid,
-        out_shape=jax.ShapeDtypeStruct((b, heads, values), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        name="latent_decode_attention",
-        interpret=_interpret(),
-    )(positions.astype(jnp.int32), q, cache.astype(q.dtype))
-    return out[:, :held, :rank]
-
-
 # -- a decode kernel's walk over a stream's live rows --------------------------
 #
 # A decode attention kernel that leaves its cache in HBM and copies a
@@ -590,10 +442,13 @@ def latent_decode_attention(q, cache, positions, rank: int, scale: float):
 # not in use beyond its cell), and an item that runs over a ring's end
 # is copied cell by cell.  The items go through a queue of buffers whose
 # copies run on from one stream into the next.  What follows is the
-# walk's arithmetic (``jnp`` on scalars, traced or not):
-# :func:`gqa_decode_attention` issues its copies by it,
-# :func:`decode_rows_fetched` counts by it, and a kernel over another
-# cache (``latent_decode_attention``) can take it as it stands.
+# walk's arithmetic (``jnp`` on scalars, traced or not) and, on it, the
+# queue as a kernel runs it for one stream (:func:`_walk_stream`).  Both
+# decode kernels walk: :func:`gqa_decode_attention` (K and V caches, a
+# ring or dense) and :func:`latent_decode_attention` (one dense latent
+# cache) hand the queue their caches, their buffers and the update of
+# their running sums, and :func:`decode_rows_fetched` counts by the
+# same cells.
 
 #: rows of one lattice cell: a lane tile of scores
 _WALK_LATTICE = _LANE
@@ -691,6 +546,346 @@ def decode_rows_fetched(positions, total: int, window: int):
 
     _, cells = walk_cells(jnp.asarray(positions), total, window)
     return jnp.sum(cells) * _WALK_LATTICE
+
+
+def _walk_plan_check(kernel: str, plan: WalkPlan, total: int):
+    """An explicit plan has to divide the cache into whole cells and
+    leave a buffer to copy into beside the one computed on."""
+    if total % plan.chunk or plan.chunk % _WALK_LATTICE or plan.slots < 2:
+        raise ValueError(f"{kernel}: {plan} does not divide caches of "
+                         f"{total} rows")
+
+
+def _walk_stream(pos_ref, pairs, arrived, base_ref, sums, o_ref, update, *,
+                 total: int, window: int, plan: WalkPlan, ring: bool):
+    """One grid step of a decode kernel on the walk (the grid runs over
+    streams, in order): stream ``i``'s items through the queue, the
+    running sums over them, and its output.
+
+    ``pairs`` are the ``(cache, buffers)`` an item is copied between (a
+    cache ``[streams, .., positions, width]`` left in HBM, its buffers
+    ``[slots, .., chunk, width]``; one copy a pair an item, signalled on
+    ``arrived[pair, slot]``), ``base_ref`` (SMEM) carries the buffer of
+    a stream's first item from one step to the next, ``sums`` are the
+    running max, normaliser and accumulator, and ``update(pos, slot,
+    start, offset, size)`` adds to them rows ``offset .. offset + size``
+    of buffer ``slot``, which holds the cache's rows from cell ``start``
+    on.  ``ring`` says whether an item can run over the cache's end: a
+    dense cache has no cell-by-cell copies in its program."""
+    import jax.numpy as jnp
+
+    jax, pl, pltpu = _pl()
+    lat, (rows, slots) = _WALK_LATTICE, plan
+    ahead = slots - 1                    # items in flight beside the one
+    #                                      being computed on
+    streams = pos_ref.shape[0]
+    m_ref, l_ref, acc_ref = sums
+
+    def walk(pos):
+        """A stream's first live cell, its cells and its items."""
+        first, cells = walk_cells(pos, total, window)
+        return first, cells, walk_items(cells, plan)
+
+    i = pl.program_id(0)                # the stream
+    pos = pos_ref[i]
+
+    def copies(stream, start, count, over, slot, wait=False):
+        """Start (or wait for) the copies of an item of ``stream``
+        (:func:`walk_item`) into buffer ``slot``."""
+
+        def copy(cell, offset, size):
+            """``size`` cells from ``cell`` of the cache to ``offset``
+            cells into the buffer."""
+            for n, (ref, buf) in enumerate(pairs):
+                between = (slice(None),) * (len(ref.shape) - 3)
+                dma = pltpu.make_async_copy(
+                    ref.at[(stream, *between, pl.ds(pl.multiple_of(
+                        cell * lat, lat), size * lat), slice(None))],
+                    buf.at[(slot, *between, pl.ds(pl.multiple_of(
+                        offset * lat, lat), size * lat), slice(None))],
+                    arrived.at[n, slot])
+                dma.wait() if wait else dma.start()
+
+        def pieces():
+            for size in plan.pieces:
+                has, offset = walk_piece(count, size)
+                pl.when(has)(functools.partial(
+                    copy, start + offset, offset, size))
+
+        def cells():
+            def cell(c, _):
+                copy((start + c) % (total // lat), c, 1)
+                return 0
+
+            jax.lax.fori_loop(0, count, cell, 0)
+
+        whole = count == plan.cells
+        if ring:
+            whole = whole & jnp.logical_not(over)
+        pl.when(whole)(lambda: copy(start, 0, plan.cells))
+        pl.when(jnp.logical_not(whole | over if ring else whole))(pieces)
+        if ring:
+            pl.when(over)(cells)
+
+    first, cells, items = walk(pos)
+    # the queue runs on into the next stream's first items
+    after = jnp.minimum(i + 1, streams - 1)
+    first_n, cells_n, items_n = walk(pos_ref[after])
+    items_n = jnp.where(i + 1 < streams, jnp.minimum(items_n, ahead), 0)
+
+    @pl.when(i == 0)
+    def _first():
+        base_ref[0] = 0
+
+    base = base_ref[0]          # the buffer of this stream's first item
+
+    def issue(t):
+        """Start item ``t`` of the queue, counted from this stream's
+        first: its own, then the next stream's."""
+        own = t < items
+        start, count, over = walk_item(
+            jnp.where(own, first, first_n),
+            jnp.where(own, cells, cells_n),
+            jnp.where(own, t, t - items), total, plan)
+        pl.when(own | (t - items < items_n))(
+            lambda: copies(jnp.where(own, i, after), start, count, over,
+                           (base + t) % slots))
+
+    def top_up(t, _):
+        # the stream before started this one's first items; nobody
+        # started the first stream's, nor what a stream of few items
+        # leaves of the queue
+        pl.when((i == 0) | (t >= items))(lambda: issue(t))
+        return 0
+
+    jax.lax.fori_loop(0, ahead, top_up, 0)
+    m_ref[:] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+    l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def consume(j, _):
+        slot = (base + j) % slots
+        start, count, over = walk_item(first, cells, j, total, plan)
+        copies(i, start, count, over, slot, wait=True)
+        issue(j + ahead)
+        pl.when(count == plan.cells)(
+            lambda: update(pos, slot, start, 0, rows))
+
+        @pl.when(count < plan.cells)
+        def _pieces():
+            for size in plan.pieces:
+                has, offset = walk_piece(count, size)
+                pl.when(has)(functools.partial(
+                    update, pos, slot, start,
+                    pl.multiple_of(offset * lat, lat), size * lat))
+        return 0
+
+    jax.lax.fori_loop(0, items, consume, 0)
+    o_ref[0] = acc_ref[:] / l_ref[..., :1]
+    base_ref[0] = (base + items) % slots
+
+
+# -- latent decode attention --------------------------------------------------
+
+
+def latent_decode_attention_refusal(q_shape, cache_shape,
+                                    rank: int) -> Optional[str]:
+    """Why :func:`latent_decode_attention` cannot take these shapes, or
+    None: the row width whole lanes and the same on both sides, ``rank``
+    within it, and a cache of whole lattice cells (lane tiles of
+    positions), at least one."""
+    if len(q_shape) != 3 or len(cache_shape) != 3 \
+            or q_shape[0] != cache_shape[0]:
+        return f"q {tuple(q_shape)} and cache {tuple(cache_shape)} are " \
+               "not [B, heads, width] and [B, positions, width]"
+    width = q_shape[2]
+    if width != cache_shape[2] or width % _LANE or not 0 < rank <= width:
+        return f"row width {width} (cache {cache_shape[2]}) must be whole " \
+               f"lanes of {_LANE} and hold the {rank} values"
+    if cache_shape[1] < _WALK_LATTICE or cache_shape[1] % _WALK_LATTICE:
+        return f"{cache_shape[1]} cache positions are not whole lattice " \
+               f"cells of {_WALK_LATTICE}"
+    return None
+
+
+def latent_decode_attention_reference(q, cache, positions, rank: int,
+                                      scale: float):
+    """The kernel's mathematics in jnp: every head's scores against
+    every cached row up to the stream's position, softmax in float32,
+    values the rows' first ``rank`` entries."""
+    import jax
+    import jax.numpy as jnp
+
+    hp = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
+    cache = cache.astype(q.dtype)
+    s = jnp.einsum("bhl,btl->bht", q, cache,
+                   preferred_element_type=jnp.float32, precision=hp)
+    t = jnp.arange(cache.shape[1], dtype=jnp.int32)
+    s = jnp.where(t[None, None, :] <= positions[:, None, None],
+                  s * scale, -1e30)
+    prob = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bht,btl->bhl", prob.astype(q.dtype), cache,
+                      preferred_element_type=jnp.float32,
+                      precision=hp)[..., :rank]
+
+
+def latent_decode_attention(q, cache, positions, rank: int, scale: float):
+    """Absorbed latent attention of one token a stream over a latent
+    cache: ``q [B, heads, width]`` (each head's absorbed query and its
+    rotary part side by side, zeros to the width), ``cache [B,
+    positions, width]`` (a token's ``rank`` latent values, its rotary
+    key, zeros to the width; dense: position ``p`` in row ``p``),
+    ``positions [B]`` int32.  Returns ``[B, heads, rank]`` float32:
+    softmax(q cache^T * scale) over rows ``0..positions[b]``, times the
+    rows' first ``rank`` entries.
+
+    One pass, and the kernel copies a stream's live rows itself: the
+    cache stays in HBM, the grid runs over streams, and a stream's rows
+    come in by the walk above (:func:`decode_walk_plan`; chunks of 1,280
+    rows in five buffers for caches of 16,640 rows of 640 bf16 values),
+    counted from the first live cell, the last item in pieces of 8, 4, 2
+    and 1 cells, through the queue :func:`gqa_decode_attention` runs
+    too (:func:`_walk_stream`): its copies run on into the next stream's
+    first items, so no stream starts cold.  An item is read once from
+    its buffer and serves both products (the scores contract over the
+    row's whole width, the values take its first ``rank`` lanes) in one
+    update of the running max, normaliser and accumulator; only an item
+    that reaches the stream's position masks rows.  What the walk
+    fetches beyond the rows in use is less than a cell a stream
+    (:func:`decode_rows_fetched` with ``window = positions``).  (XLA's
+    own two products read every position of the cache twice, and one
+    whose rows are not whole lanes it copies whole first.)
+
+    On the chip (the kernel alone at ``dsv2.decode16k``'s shapes, 32
+    streams of 32 heads at 8-16 k, device ms a call; ``PERF.md`` section
+    6, PR 41): blocks of 640 rows through a ``BlockSpec`` pipeline 0.845
+    -> the walk 0.703; its copies alone 0.700 (730 GB/s of rows as the
+    cache holds them, 640 values for 576 in use), its arithmetic alone
+    0.414: the copies bind, and 3 to 8 buffers and chunks of 640 rows
+    read the same to 0.4 %.  Inside the decode step a call reads 0.80
+    -> 0.678 (754 GB/s).
+    Heads are padded to whole tiles here; a shape
+    :func:`latent_decode_attention_refusal` names is an error, there is
+    no second path."""
+    refusal = latent_decode_attention_refusal(q.shape, cache.shape, rank)
+    if refusal:
+        raise ValueError(f"latent_decode_attention: {refusal}")
+    plan = decode_walk_plan(cache.shape[1],
+                            q.shape[2] * np.dtype(q.dtype).itemsize)
+    return _latent_decode_walk(q, cache, positions, rank, scale, plan)
+
+
+def _latent_decode_walk(q, cache, positions, rank: int, scale: float,
+                        plan: WalkPlan):
+    """:func:`latent_decode_attention` by an explicit ``plan`` (the
+    tests and the chip's sweeps choose theirs)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, held, width = q.shape
+    # whole tiles of heads: padded heads score zero everywhere and are
+    # cut off again; values that are not whole lanes come out of the
+    # whole row
+    heads = -(-held // _sublane(q.dtype)) * _sublane(q.dtype)
+    if heads != held:
+        q = jnp.pad(q, ((0, 0), (0, heads - held), (0, 0)))
+    values = rank if rank % _LANE == 0 else width
+    call = _latent_decode_walk_call(b, heads, width, values, cache.shape[1],
+                                    float(scale), np.dtype(q.dtype).name,
+                                    plan, _interpret())
+    # the stage a trace books the kernel's time to (a jit is no scope)
+    with jax.named_scope("latent_decode_attention"):
+        out = call(positions.astype(jnp.int32), q, cache.astype(q.dtype))
+    return out[:, :held, :rank]
+
+
+@functools.lru_cache(maxsize=16)
+def _latent_decode_walk_call(b: int, heads: int, width: int, values: int,
+                             total: int, scale: float, dtype: str,
+                             plan: WalkPlan, interpret: bool):
+    """The jitted call of :func:`latent_decode_attention` for one shape,
+    built once: a model's layers share the function, so a program that
+    attends in five layers traces and lowers the kernel once (as
+    :func:`_gqa_decode_walk_call` does)."""
+    import jax.numpy as jnp
+
+    jax, pl, pltpu = _pl()
+    lat, (rows, slots) = _WALK_LATTICE, plan
+    _walk_plan_check("latent_decode_attention", plan, total)
+
+    def kernel(pos_ref, q_ref, cache_ref, o_ref, buf, arrived, base_ref,
+               m_ref, l_ref, acc_ref):
+
+        def update(pos, slot, start, offset, size):
+            """The online softmax over ``size`` rows of buffer ``slot``
+            from row ``offset``: both products off the same rows."""
+
+            def add(masked: bool):
+                kb = buf[slot, pl.ds(offset, size), :]       # (size, width)
+                s = jax.lax.dot_general(
+                    q_ref[0], kb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if masked:                                # (heads, size)
+                    at = start * lat + offset + jax.lax.broadcasted_iota(
+                        jnp.int32, (heads, size), 1)
+                    s = jnp.where(at <= pos, s, -1e30)
+                # running max / normaliser replicated across a lane
+                # width, as in flash_attention above
+                m_prev = m_ref[:]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new[:, :1])
+                l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1,
+                                                     keepdims=True)
+                acc_ref[:] = acc_ref[:] * corr[:, :1] + jax.lax.dot_general(
+                    p.astype(kb.dtype), kb[:, :values],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[:] = m_new
+
+            if size < rows:       # a piece of the last item: it may
+                add(True)         # hold the position
+                return
+            # a whole chunk masks only where it reaches the position
+            reaches = start * lat + rows > pos + 1
+            pl.when(reaches)(lambda: add(True))
+            pl.when(jnp.logical_not(reaches))(lambda: add(False))
+
+        _walk_stream(pos_ref, ((cache_ref, buf),), arrived, base_ref,
+                     (m_ref, l_ref, acc_ref), o_ref, update,
+                     total=total, window=total, plan=plan, ring=False)
+
+    buffer = (slots, rows, width)
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, heads, width), lambda i, pos: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, heads, values), lambda i, pos: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM(buffer, dtype),
+            pltpu.SemaphoreType.DMA((1, slots)),
+            pltpu.SMEM((1,), jnp.int32),                 # first buffer
+            pltpu.VMEM((heads, _LANE), jnp.float32),     # running max
+            pltpu.VMEM((heads, _LANE), jnp.float32),     # normaliser
+            pltpu.VMEM((heads, values), jnp.float32),    # accumulator
+        ])
+    call = pl.pallas_call(
+        kernel, grid_spec=grid,
+        out_shape=jax.ShapeDtypeStruct((b, heads, values), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(np.prod(buffer))
+            * np.dtype(dtype).itemsize + (4 << 20)),
+        name="latent_decode_attention",
+        interpret=interpret)
+
+    def latent_decode_attention(*operands):
+        return call(*operands)
+
+    return jax.jit(latent_decode_attention)
 
 
 # -- grouped-query decode attention -------------------------------------------
@@ -839,58 +1034,12 @@ def _gqa_decode_walk_call(b: int, groups: int, per: int, d: int, total: int,
 
     jax, pl, pltpu = _pl()
     lat, (rows, slots) = _WALK_LATTICE, plan
-    if total % rows or rows % lat or slots < 2:
-        raise ValueError(f"gqa_decode_attention: {plan} does not divide "
-                         f"caches of {total} rows")
-    ahead = slots - 1                    # items in flight beside the one
-    #                                      being computed on
-
-    def walk(pos):
-        """A stream's first live cell, its cells and its items."""
-        first, cells = walk_cells(pos, total, window)
-        return first, cells, walk_items(cells, plan)
+    _walk_plan_check("gqa_decode_attention", plan, total)
 
     def kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, kbuf, vbuf, arrived,
                base_ref, m_ref, l_ref, acc_ref):
-        i = pl.program_id(0)                # the stream
-        pos = pos_ref[i]
 
-        def copies(stream, start, count, over, slot, wait=False):
-            """Start (or wait for) the copies of an item of ``stream``
-            (:func:`walk_item`) into buffer ``slot``."""
-
-            def copy(cell, offset, size):
-                """``size`` cells from ``cell`` of the cache to
-                ``offset`` cells into the buffer."""
-                for n, (ref, buf) in enumerate(((k_ref, kbuf),
-                                                (v_ref, vbuf))):
-                    dma = pltpu.make_async_copy(
-                        ref.at[stream, :, pl.ds(pl.multiple_of(
-                            cell * lat, lat), size * lat), :],
-                        buf.at[slot, :, pl.ds(pl.multiple_of(
-                            offset * lat, lat), size * lat), :],
-                        arrived.at[n, slot])
-                    dma.wait() if wait else dma.start()
-
-            whole = (count == plan.cells) & jnp.logical_not(over)
-            pl.when(whole)(lambda: copy(start, 0, plan.cells))
-
-            @pl.when(jnp.logical_not(whole | over))
-            def _pieces():
-                for size in plan.pieces:
-                    has, offset = walk_piece(count, size)
-                    pl.when(has)(functools.partial(
-                        copy, start + offset, offset, size))
-
-            @pl.when(over)
-            def _cells():
-                def cell(c, _):
-                    copy((start + c) % (total // lat), c, 1)
-                    return 0
-
-                jax.lax.fori_loop(0, count, cell, 0)
-
-        def update(slot, start, offset, size):
+        def update(pos, slot, start, offset, size):
             """The online softmax over ``size`` rows of buffer ``slot``
             from row ``offset``; the buffer holds the cache's rows from
             cell ``start`` on, round the ring's end."""
@@ -922,62 +1071,9 @@ def _gqa_decode_walk_call(b: int, groups: int, per: int, d: int, total: int,
                     preferred_element_type=jnp.float32)
                 m_ref[g] = m_new
 
-        first, cells, items = walk(pos)
-        # the queue runs on into the next stream's first items
-        after = jnp.minimum(i + 1, b - 1)
-        first_n, cells_n, items_n = walk(pos_ref[after])
-        items_n = jnp.where(i + 1 < b, jnp.minimum(items_n, ahead), 0)
-
-        @pl.when(i == 0)
-        def _first():
-            base_ref[0] = 0
-
-        base = base_ref[0]          # the buffer of this stream's first item
-
-        def issue(t):
-            """Start item ``t`` of the queue, counted from this stream's
-            first: its own, then the next stream's."""
-            own = t < items
-            start, count, over = walk_item(
-                jnp.where(own, first, first_n),
-                jnp.where(own, cells, cells_n),
-                jnp.where(own, t, t - items), total, plan)
-            pl.when(own | (t - items < items_n))(
-                lambda: copies(jnp.where(own, i, after), start, count, over,
-                               (base + t) % slots))
-
-        def top_up(t, _):
-            # the stream before started this one's first items; nobody
-            # started the first stream's, nor what a stream of few items
-            # leaves of the queue
-            pl.when((i == 0) | (t >= items))(lambda: issue(t))
-            return 0
-
-        jax.lax.fori_loop(0, ahead, top_up, 0)
-        m_ref[:] = jnp.full(m_ref.shape, -1e30, jnp.float32)
-        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-        def consume(j, _):
-            slot = (base + j) % slots
-            start, count, over = walk_item(first, cells, j, total, plan)
-            copies(i, start, count, over, slot, wait=True)
-            issue(j + ahead)
-            pl.when(count == plan.cells)(
-                lambda: update(slot, start, 0, rows))
-
-            @pl.when(count < plan.cells)
-            def _pieces():
-                for size in plan.pieces:
-                    has, offset = walk_piece(count, size)
-                    pl.when(has)(functools.partial(
-                        update, slot, start,
-                        pl.multiple_of(offset * lat, lat), size * lat))
-            return 0
-
-        jax.lax.fori_loop(0, items, consume, 0)
-        o_ref[0] = acc_ref[:] / l_ref[:, :, :1]
-        base_ref[0] = (base + items) % slots
+        _walk_stream(pos_ref, ((k_ref, kbuf), (v_ref, vbuf)), arrived,
+                     base_ref, (m_ref, l_ref, acc_ref), o_ref, update,
+                     total=total, window=window, plan=plan, ring=True)
 
     buffer = (slots, groups, rows, d)
     grid = pltpu.PrefetchScalarGridSpec(

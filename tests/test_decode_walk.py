@@ -4,8 +4,9 @@
 ``gqa_decode_attention`` on it, interpreted on the CPU: the kernel
 against its ``jnp`` mathematics over rings, dense caches, groups, heads
 and the positions where the walk changes shape; the rows the plan names
-against a brute-force count; and the two models' counters of them.  No
-number here is a rate."""
+against a brute-force count; and the three models' counters of them
+(``latent_decode_attention`` on the same walk against its mathematics:
+``tests/test_deepseek_v2.py``).  No number here is a rate."""
 
 import json
 import os
@@ -21,6 +22,7 @@ if REPO not in sys.path:
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from nnstreamer_tpu.models import deepseek_v2 as dsv2  # noqa: E402
 from nnstreamer_tpu.models import nemotron_h as nh  # noqa: E402
 from nnstreamer_tpu.models import smallthinker as st  # noqa: E402
 from nnstreamer_tpu.ops import kernels  # noqa: E402
@@ -102,8 +104,9 @@ def test_the_walk_is_the_reference(case):
     (6144, 2048, (1024, 4)), (16384, 2048, (1024, 4)),
     (4096, 1024, (2048, 4)), (256, 2048, (128, 8)),
     (384, 1024, (128, 8)), (768, 64 << 10, (128, 3)),
+    (16640, 1280, (1280, 5)),
 ], ids=["smallthinker-ring", "smallthinker-full", "nemotron3", "two-cells",
-        "three-cells", "wide-rows"])
+        "three-cells", "wide-rows", "dsv2-latent"])
 def test_the_plan_follows_what_the_call_sees(total, row_bytes, plan):
     got = kernels.decode_walk_plan(total, row_bytes)
     assert tuple(got) == plan
@@ -112,14 +115,33 @@ def test_the_plan_follows_what_the_call_sees(total, row_bytes, plan):
         1 << k for k in reversed(range((got.cells - 1).bit_length())))
 
 
-def test_a_plan_that_does_not_divide_the_cache_is_an_error():
+def test_the_latent_cells_plan_is_chunks_of_ten_cells_in_five_buffers():
+    """`dsv2.decode16k`'s caches, `[32, 16640, 640]` bf16: 130 cells, of
+    whose divisors ten is the most that 2 MiB hold (1.6 MB a chunk), so
+    a last item comes in pieces of 8, 4, 2 and 1 cells."""
+    plan = kernels.decode_walk_plan(16640, 640 * 2)
+    assert plan == WalkPlan(1280, 5) and plan.cells == 10
+    assert plan.pieces == (8, 4, 2, 1)
+    for count in range(1, plan.cells):
+        got = [(int(kernels.walk_piece(count, size)[1]), size)
+               for size in plan.pieces if kernels.walk_piece(count, size)[0]]
+        assert sum(size for _, size in got) == count
+        assert [at for at, _ in got] == [
+            sum(size for _, size in got[:i]) for i in range(len(got))]
+
+
+@pytest.mark.parametrize("kernel", ["gqa", "latent"])
+def test_a_plan_that_does_not_divide_the_cache_is_an_error(kernel):
     q = jnp.zeros((1, 1, 8, 128))
     k = jnp.zeros((1, 1, 384, 128))
+    at = jnp.zeros((1,), jnp.int32)
     for plan in (WalkPlan(256, 3), WalkPlan(192, 3),
                  WalkPlan(128, 1)):
         with pytest.raises(ValueError, match="does not divide"):
-            kernels._gqa_decode_walk(q, k, k, jnp.zeros((1,), jnp.int32),
-                                     384, 1.0, plan)
+            if kernel == "gqa":
+                kernels._gqa_decode_walk(q, k, k, at, 384, 1.0, plan)
+            else:
+                kernels._latent_decode_walk(q[0], k[0], at, 128, 1.0, plan)
 
 
 # -- the rows the plan names ------------------------------------------------------------
@@ -205,6 +227,31 @@ def test_the_cells_shapes_fetch_within_the_stated_shares():
         assert used <= fetched <= share * used
 
 
+def test_a_dense_cache_fetches_every_live_cell_whole():
+    """``window = total`` (the latent cache's call): never an item over
+    the cache's end, the cells ``0 .. pos // 128`` whole, so within one
+    cell of the rows in use a stream; at `dsv2.decode16k`'s positions
+    that is under 1 % over them."""
+    total = 1024
+    plan = WalkPlan(256, 3)
+    for pos in range(total):
+        first, cells = (int(x) for x in kernels.walk_cells(pos, total, total))
+        assert (first, cells) == (0, pos // LAT + 1)
+        assert not any(bool(kernels.walk_item(first, cells, item, total,
+                                              plan)[2])
+                       for item in range(int(kernels.walk_items(cells,
+                                                                plan))))
+        fetched = int(kernels.decode_rows_fetched(
+            np.array([pos], np.int32), total, total))
+        assert fetched == cells * LAT and 0 <= fetched - (pos + 1) < LAT
+    positions = np.random.default_rng(1).integers(
+        8192, 16640, 4096).astype(np.int32)
+    fetched = int(kernels.decode_rows_fetched(positions, 16640, 16640))
+    used = int((positions + 1).sum())
+    assert used <= fetched <= used + len(positions) * (LAT - 1)
+    assert fetched <= 1.01 * used
+
+
 # -- the models' counters ------------------------------------------------------------------
 
 
@@ -249,6 +296,36 @@ def test_smallthinker_counts_the_walk_once_a_layer_of_a_kind():
     assert units["full_bytes_fetched"] == ("full_rows_fetched", row * 1)
     assert units["cache_bytes_fetched"] == [units["window_bytes_fetched"],
                                             units["full_bytes_fetched"]]
+
+
+def test_deepseek_v2_counts_the_walk_once_a_step():
+    """The toy's three layers over latent caches of 384 positions
+    decode through the kernel: one step adds the walk's rows of ONE
+    layer to ``cache_rows_fetched``, and the units count the layers at
+    the row the cache HOLDS (padded to whole lanes) beside the rows in
+    use at the row's ``latent`` values."""
+    with open(os.path.join(REPO, "tests", "benchmark", "data",
+                           "toy_dsv2.json")) as f:
+        cfg = dsv2.DeepSeekV2Config.from_dict(json.load(f))
+    params = dsv2.init_params(cfg, jax.random.PRNGKey(1), jnp.float32)
+    state = dsv2.init_state(cfg, params, 3, 384)
+    positions = np.array([0, 127, 300], np.int32)
+    before = _counted(state)
+    state, _ = dsv2.decode(cfg, params, state,
+                           np.full(3, cfg.vocab0, np.int32), positions)
+    after = _counted(state)
+    assert after["steps"] - before["steps"] == 1
+    assert after["cache_rows_read"] - before["cache_rows_read"] \
+        == 1 + 128 + 301
+    assert after["cache_rows_fetched"] - before["cache_rows_fetched"] \
+        == (1 + 1 + 3) * LAT \
+        == int(kernels.decode_rows_fetched(positions, 384, 384))
+    units = dsv2.counter_units(cfg, state)
+    assert cfg.layers == 3 and cfg.row == LAT > cfg.latent
+    assert units["cache_bytes_read"] == (
+        "cache_rows_read", cfg.latent * 4 * cfg.layers)
+    assert units["cache_bytes_fetched"] == (
+        "cache_rows_fetched", cfg.row * 4 * cfg.layers)
 
 
 def test_nemotron_h_counts_the_walk_once_an_attention_layer():

@@ -106,9 +106,14 @@ def test_the_steps_count_what_they_read(served):
         * cfg.num_experts_per_tok
     assert 0 < counters["experts_touched"] <= min(
         counters["expert_hits"], STEPS * moe * cfg.experts)
+    # what the decode kernel copies for them: a cache of 128 positions
+    # is one lattice cell, whole, a stream a step
+    assert counters["cache_rows_fetched"] == STEPS * STREAMS * 128
     units = dsv2.counter_units(cfg, served["state"])
-    assert units == {"cache_bytes_read": (
-        "cache_rows_read", cfg.latent * 4 * cfg.layers)}
+    assert units == {
+        "cache_bytes_read": ("cache_rows_read", cfg.latent * 4 * cfg.layers),
+        "cache_bytes_fetched": ("cache_rows_fetched",
+                                cfg.row * 4 * cfg.layers)}
 
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 0.03)])
@@ -163,24 +168,52 @@ def test_absorbed_is_expanded(toy, files):
     assert cfg.row == 128 and not np.asarray(cache_a[1, :8, cfg.latent:]).any()
 
 
-@pytest.mark.parametrize("heads,width,rank,positions,dtype", [
-    (8, 256, 128, 512, "float32"),
-    (2, 128, 16, 128, "float32"),       # the toy: heads padded to a tile,
-    (3, 128, 24, 256, "bfloat16"),      # values out of the whole row
-], ids=["whole-tiles", "toy-f32", "toy-bf16"])
-def test_latent_kernel_is_its_reference(heads, width, rank, positions,
-                                        dtype):
+#: ``heads, width, rank, positions of the cache, dtype, plan, streams at``;
+#: without a plan the call derives its own.  The explicit plans put the
+#: positions where the walk changes shape, as ``tests/test_decode_walk.py``
+#: does for the sibling kernel.
+LATENT_CASES = {
+    "whole-tiles": (8, 256, 128, 512, "float32", None, [5, 300, 511]),
+    # the toy: heads padded to a tile, values out of the whole row
+    "toy-f32": (2, 128, 16, 128, "float32", None, [5, 108, 127]),
+    "toy-bf16": (3, 128, 24, 256, "bfloat16", None, [5, 172, 255]),
+    # in the first cell, on a chunk's last row, on a chunk's first row,
+    # at the cache's last row
+    "walk-a-chunks-ends": (8, 128, 128, 1024, "float32",
+                           kernels.WalkPlan(256, 3), [5, 255, 256, 1023]),
+    # chunks of ten cells, as the cell's: a last item of 8 + 1 cells, of
+    # 4 + 2 + 1, of one cell after a whole chunk, and two whole chunks
+    "walk-pieces-8-4-2-1": (8, 128, 128, 2560, "float32",
+                            kernels.WalkPlan(1280, 3),
+                            [1041, 868, 1285, 2559]),
+    # the queue runs on from a stream of one item into one of four (and
+    # from that into one of two); more buffers than a stream has items
+    "walk-one-item-before-many": (8, 128, 128, 1024, "float32",
+                                  kernels.WalkPlan(256, 4),
+                                  [3, 1000, 130, 700]),
+    "walk-one-stream": (8, 128, 128, 1024, "float32",
+                        kernels.WalkPlan(256, 3), [600]),
+    "walk-heads-padded-bf16": (5, 256, 128, 768, "bfloat16",
+                               kernels.WalkPlan(384, 2), [0, 383, 384, 767]),
+}
+
+
+@pytest.mark.parametrize("case", list(LATENT_CASES))
+def test_latent_kernel_is_its_reference(case):
     """The Pallas kernel (interpreted on the CPU) against its jnp
-    mathematics, with streams at different positions, one of them in
-    the first block and one at the cache's last row."""
+    mathematics, with streams at different positions."""
+    heads, width, rank, positions, dtype, plan, at = LATENT_CASES[case]
     rng = np.random.default_rng(2)
-    b = 3
+    b = len(at)
     q = jnp.asarray(rng.normal(size=(b, heads, width)), dtype)
     cache = jnp.asarray(rng.normal(size=(b, positions, width)), dtype)
-    at = jnp.array([5, positions // 2 + 44, positions - 1], jnp.int32)
+    at = jnp.asarray(at, jnp.int32)
     assert kernels.latent_decode_attention_refusal(
         q.shape, cache.shape, rank) is None
-    got = kernels.latent_decode_attention(q, cache, at, rank, 0.05)
+    if plan is None:
+        got = kernels.latent_decode_attention(q, cache, at, rank, 0.05)
+    else:
+        got = kernels._latent_decode_walk(q, cache, at, rank, 0.05, plan)
     want = kernels.latent_decode_attention_reference(q, cache, at, rank, 0.05)
     assert got.shape == (b, heads, rank) and got.dtype == jnp.float32
     assert np.allclose(np.asarray(got), np.asarray(want),
@@ -190,17 +223,17 @@ def test_latent_kernel_is_its_reference(heads, width, rank, positions,
 @pytest.mark.parametrize("q_shape,cache_shape,rank,says", [
     ((3, 8, 192), (3, 512, 192), 128, "whole lanes"),
     ((3, 8, 256), (3, 512, 128), 128, "whole lanes"),
-    ((3, 8, 256), (3, 100, 256), 128, "100 cache positions"),
+    ((3, 8, 256), (3, 100, 256), 128,
+     "100 cache positions are not whole lattice cells of 128"),
+    ((3, 8, 256), (3, 0, 256), 128, "0 cache positions"),
     ((3, 8, 256), (3, 512, 256), 384, "hold the 384 values"),
     ((3, 8, 256), (4, 512, 256), 128, "are not [B, heads, width]"),
 ], ids=["row-not-lanes", "widths-differ", "positions-not-lanes",
-        "rank-over-width", "streams-differ"])
+        "no-positions", "rank-over-width", "streams-differ"])
 def test_latent_kernel_refuses_with_an_error(q_shape, cache_shape, rank,
                                              says):
     """No second path: a shape the kernel cannot take is an error that
     says which rule it breaks."""
-    assert kernels.latent_block(512, want=128) == 128
-    assert kernels.latent_block(100) == 0
     with pytest.raises(ValueError, match="latent_decode_attention") as e:
         kernels.latent_decode_attention(
             jnp.zeros(q_shape), jnp.zeros(cache_shape),

@@ -55,6 +55,26 @@ def test_gqa_decode_attention_compiles_at_the_cells_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+def test_latent_decode_attention_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch):
+    """`dsv2.decode16k`'s call: 32 streams, 32 heads, caches of 16,640
+    rows of 640 bf16 values walked in chunks of 1,280 rows through five
+    buffers (8.2 MB of fast memory, stated by the call), as a Mosaic
+    kernel; one custom call, no copy of the cache beside it."""
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    assert kernels.decode_walk_plan(16640, 1280) == kernels.WalkPlan(1280, 5)
+    fn = jax.jit(functools.partial(kernels.latent_decode_attention,
+                                   rank=512, scale=0.1147))
+    compiled = fn.lower(shape((32, 32, 640)), shape((32, 16640, 640)),
+                        shape((32,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("tokens,held,hidden,inter,grid", [
     (32, 40, 5120, 1536, 46), (32, 64, 2560, 768, 70),
     (2048, 40, 5120, 1536, 88), (2048, 64, 2560, 768, 112)],

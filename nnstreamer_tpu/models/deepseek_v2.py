@@ -22,18 +22,15 @@ the absent chips or their exchange.
 
 **Two paths through one set of weights.**  The state is the latent cache
 alone: per layer ``[streams, positions, row]``, a row a token's ``(c_kv,
-k_r)`` after norm and rotation, 576 values, padded with zeros to whole
-lanes (640: the TPU's compiler lays a ``[.., positions, 576]`` array out
-with positions minor, and every product over it then copied the whole
-cache), over positions rounded up to whole lanes.  :func:`prefill` runs a
-chunk of ONE stream through the expanded form of MLA (keys and values rebuilt
-from the latent rows, blocked over the cache with a running softmax, so
-no ``[heads, chunk, positions]`` score tensor exists) and writes the
-chunk's rows; :func:`decode` runs one token of EVERY stream through the
-absorbed form (``q~ = q_nope W_kvb[k]^T``, scores and values straight on
-the latent rows: ``ops/kernels.py`` ``latent_decode_attention``, one pass
-over a stream's live rows, which the kernel copies itself).  Positions come with the frame; rows beyond a
-stream's position are masked, so a stale or padded row is never read.
+k_r)`` after norm and rotation, 576 values padded to 640
+(``models/mla.py``, which ``longcat_flash.py`` runs too, has the
+attention: what this model gives it is the YaRN rotation and the
+scores' scale).  :func:`prefill` runs a chunk of ONE stream through the
+expanded form of MLA and writes the chunk's rows; :func:`decode` runs
+one token of EVERY stream through the absorbed form (``ops/kernels.py``
+``latent_decode_attention``).  Positions come with the frame; rows
+beyond a stream's position are masked, so a stale or padded row is
+never read.
 
 Stage scopes (``Documentation/observability.md``): ``embed``,
 ``layerNN/attn`` (``.../attn/cache_write`` inside it), ``layerNN/mlp``
@@ -58,10 +55,9 @@ except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
 from ..ops import kernels
-from . import moe
+from . import mla, moe
 
 Params = dict
-NEG = -1e30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,6 +174,20 @@ class DeepSeekV2Config:
     def is_dense(self, layer: int) -> bool:
         return layer < self.first_k_dense_replace
 
+    # what ``models/mla.py`` reads beside the sizes: the YaRN rotation,
+    # the scores' scale, and no constant on the low-rank streams
+    q_lora_scale = kv_lora_scale = 1.0
+
+    @property
+    def score_scale(self) -> float:
+        return attn_scale(self)
+
+    def cos_sin(self, positions):
+        angle = positions.astype(jnp.float32)[..., None] \
+            * jnp.asarray(yarn_inv_freq(self))
+        scale = rope_scale(self)
+        return jnp.cos(angle) * scale, jnp.sin(angle) * scale
+
 
 # -- YaRN rotary embedding ----------------------------------------------------
 
@@ -228,27 +238,10 @@ def rope_scale(cfg: DeepSeekV2Config) -> float:
         / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
 
 
-def _cos_sin(cfg: DeepSeekV2Config, positions):
-    angle = positions.astype(jnp.float32)[..., None] \
-        * jnp.asarray(yarn_inv_freq(cfg))
-    scale = rope_scale(cfg)
-    return jnp.cos(angle) * scale, jnp.sin(angle) * scale
-
-
-def _rope(x, cos, sin):
-    """Rotate pairs ``(i, i + d/2)`` of the last axis (the half-split
-    layout the published code permutes into before it rotates); ``cos``
-    and ``sin`` broadcast against ``x[..., :d/2]``."""
-    half = x.shape[-1] // 2
-    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
 # -- small parts --------------------------------------------------------------
 
 
-_rms, _mm, _precision = moe.rms, moe.mm, moe.precision
+_rms, _mm = moe.rms, moe.mm
 
 
 def _mlp(p, x):
@@ -301,118 +294,6 @@ def moe_parts(cfg: DeepSeekV2Config, p, x):
     with jax.named_scope("shared"):
         shared = _mlp(p["shared"], x)
     return routed, shared, plan["counts"]
-
-
-# -- attention ----------------------------------------------------------------
-
-
-def _queries(cfg: DeepSeekV2Config, p, x, cos, sin):
-    """``(q_nope [N, heads, nope], q_rope [N, heads, rope])`` rotated."""
-    c_q = _rms(_mm(x, p["q_a"]).astype(x.dtype), p["q_a_norm"],
-               cfg.rms_norm_eps)
-    q = _mm(c_q, p["q_b"]).astype(x.dtype).reshape(
-        x.shape[0], cfg.heads, cfg.q_head_dim)
-    q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
-    return q_nope, _rope(q_rope, cos[:, None], sin[:, None])
-
-
-def _latent_rows(cfg: DeepSeekV2Config, p, x, cos, sin, dtype):
-    """What the cache keeps of each token: ``[c_kv | k_r | 0..]``,
-    normed and rotated, ``cfg.row`` wide."""
-    kv = _mm(x, p["kv_a"]).astype(x.dtype)
-    c_kv = _rms(kv[:, :cfg.kv_lora_rank], p["kv_a_norm"], cfg.rms_norm_eps)
-    k_r = _rope(kv[:, cfg.kv_lora_rank:], cos, sin)
-    pad = jnp.zeros((x.shape[0], cfg.row - cfg.latent), x.dtype)
-    return jnp.concatenate([c_kv, k_r, pad], axis=-1).astype(dtype)
-
-
-def _kv_b(cfg: DeepSeekV2Config, p):
-    """``W_kvb`` as ``[latent rank, heads, nope + v]``."""
-    return p["kv_b"].reshape(cfg.kv_lora_rank, cfg.heads,
-                             cfg.qk_nope_head_dim + cfg.v_head_dim)
-
-
-def attn_prefill(cfg: DeepSeekV2Config, p, x, cache, slot, start,
-                 key_block: int = 1024):
-    """Expanded MLA over a chunk ``x [C, hidden]`` of stream ``slot``
-    whose first token is at ``start``: writes the chunk's latent rows,
-    then attends to rows ``[0, start + C)`` block by block.  Returns the
-    held heads' partial output and the cache."""
-    c = x.shape[0]
-    positions = start + jnp.arange(c, dtype=jnp.int32)
-    cos, sin = _cos_sin(cfg, positions)
-    q_nope, q_rope = _queries(cfg, p, x, cos, sin)
-    with jax.named_scope("cache_write"):
-        rows = _latent_rows(cfg, p, x, cos, sin, cache.dtype)
-        cache = lax.dynamic_update_slice(cache, rows[None], (slot, start, 0))
-    # a chunk starts at a multiple of its own length (the caller's
-    # contract), so whole key blocks never reach beyond start + C
-    kb = math.gcd(int(key_block), c)
-    w_kvb, scale = _kv_b(cfg, p), attn_scale(cfg)
-    hp = _precision(p["kv_b"])
-
-    def body(j, carry):
-        m, l, acc = carry
-        blk = lax.dynamic_slice(cache, (slot, j * kb, 0),
-                                (1, kb, cfg.row))[0].astype(x.dtype)
-        blk_r = blk[:, cfg.kv_lora_rank:cfg.latent]
-        blk = blk[:, :cfg.kv_lora_rank]
-        kv = jnp.einsum("kr,rhd->khd", blk, w_kvb,
-                        preferred_element_type=jnp.float32,
-                        precision=hp).astype(x.dtype)
-        k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
-        s = jnp.einsum("chd,khd->hck", q_nope, k_nope,
-                       preferred_element_type=jnp.float32, precision=hp) \
-            + jnp.einsum("chd,kd->hck", q_rope, blk_r,
-                         preferred_element_type=jnp.float32, precision=hp)
-        key_pos = j * kb + jnp.arange(kb, dtype=jnp.int32)
-        s = jnp.where(key_pos[None, None, :] <= positions[None, :, None],
-                      s * scale, NEG)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        alpha = jnp.exp(m - m_new)
-        prob = jnp.exp(s - m_new[..., None])
-        l = l * alpha + prob.sum(axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "hck,khd->hcd", prob.astype(x.dtype), v,
-            preferred_element_type=jnp.float32, precision=hp)
-        return m_new, l, acc
-
-    blocks = (start + c + kb - 1) // kb
-    m0 = jnp.full((cfg.heads, c), NEG, jnp.float32)
-    _, l, acc = lax.fori_loop(
-        0, blocks, body,
-        (m0, jnp.zeros_like(m0),
-         jnp.zeros((cfg.heads, c, cfg.v_head_dim), jnp.float32)))
-    o = (acc / l[..., None]).astype(x.dtype)
-    o = o.transpose(1, 0, 2).reshape(c, cfg.heads * cfg.v_head_dim)
-    return _mm(o, p["o"]).astype(x.dtype), cache
-
-
-def attn_decode(cfg: DeepSeekV2Config, p, x, cache, positions):
-    """Absorbed MLA for one token of every stream: ``x [B, hidden]``,
-    stream ``b`` at ``positions[b]``.  Writes each stream's row, then
-    scores and values straight on the latent rows up to its position."""
-    b = x.shape[0]
-    cos, sin = _cos_sin(cfg, positions)
-    q_nope, q_rope = _queries(cfg, p, x, cos, sin)
-    with jax.named_scope("cache_write"):
-        rows = _latent_rows(cfg, p, x, cos, sin, cache.dtype)
-        cache = cache.at[jnp.arange(b), positions].set(rows)
-    w_kvb = _kv_b(cfg, p)
-    hp = _precision(p["kv_b"])
-    q_abs = jnp.einsum("bhd,rhd->bhr", q_nope,
-                       w_kvb[..., :cfg.qk_nope_head_dim],
-                       preferred_element_type=jnp.float32,
-                       precision=hp).astype(x.dtype)
-    q_cat = jnp.concatenate([q_abs, q_rope, jnp.zeros(
-        (b, cfg.heads, cfg.row - cfg.latent), x.dtype)], axis=-1)
-    o_lat = kernels.latent_decode_attention(
-        q_cat, cache, positions, cfg.kv_lora_rank, attn_scale(cfg))
-    o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(x.dtype),
-                   w_kvb[..., cfg.qk_nope_head_dim:],
-                   preferred_element_type=jnp.float32,
-                   precision=hp).astype(x.dtype)
-    return _mm(o.reshape(b, -1), p["o"]).astype(x.dtype), cache
 
 
 # -- the model ----------------------------------------------------------------
@@ -470,11 +351,8 @@ def init_state(cfg: DeepSeekV2Config, params, streams: int, positions: int,
     held layers, and the counters the steps add to (``uint32``: the
     reader takes differences, so a wrap costs nothing)."""
     dtype = dtype or params["embed"].dtype
-    # whole lattice cells of positions (the decode kernel copies a
-    # stream's live rows by cells of 128); rows never written are masked
-    positions = -(-int(positions) // 128) * 128
     # one buffer a leaf: the state is donated leaf by leaf
-    return {"cache": [jnp.zeros((streams, positions, cfg.row), dtype)
+    return {"cache": [mla.init_cache(cfg, streams, positions, dtype)
                       for _ in range(cfg.layers)],
             "counters": {name: jnp.zeros((), jnp.uint32) for name in (
                 "steps", "cache_rows_read", "cache_rows_fetched",
@@ -507,7 +385,7 @@ def prefill(cfg: DeepSeekV2Config, params, state, ids, slot, start):
     x = _embed(cfg, params, ids)
     x, caches, _ = _layers(
         cfg, params, x, state["cache"],
-        lambda p, h, cache: attn_prefill(cfg, p, h, cache, slot, start))
+        lambda p, h, cache: mla.attn_prefill(cfg, p, h, cache, slot, start))
     logits, greedy = _head(cfg, params, x[-1:])
     return {"cache": caches, "counters": state["counters"]}, \
         (logits, greedy)
@@ -519,7 +397,7 @@ def decode(cfg: DeepSeekV2Config, params, state, ids, positions):
     x = _embed(cfg, params, ids)
     x, caches, got = _layers(
         cfg, params, x, state["cache"],
-        lambda p, h, cache: attn_decode(cfg, p, h, cache, positions))
+        lambda p, h, cache: mla.attn_decode(cfg, p, h, cache, positions))
     logits, greedy = _head(cfg, params, x)
     old, total = state["counters"], caches[0].shape[1]
     new = {"steps": old["steps"] + jnp.uint32(1),

@@ -1,0 +1,171 @@
+"""Multi-head latent attention (MLA) as the token models run it
+(``deepseek_v2.py``, ``longcat_flash.py``): a token's queries through
+the low-rank ``q`` stream, what the cache keeps of it, and the two paths
+through one set of weights, the expanded form for a prefill chunk and
+the absorbed form for a decode step (``ops/kernels.py``
+``latent_decode_attention``).
+
+A cache is ``[streams, positions, row]``, a row a token's ``(c_kv,
+k_r)`` after norm and rotation, ``latent`` values padded with zeros to
+whole lanes (``row``: the TPU's compiler lays a ``[.., positions, 576]``
+array out with positions minor, and every product over it then copied
+the whole cache).  Rows beyond a stream's position are masked, so a
+stale or padded row is never read.
+
+What differs between the models is read off the configuration handed
+in, which names the sizes as the published configs do
+(``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+``v_head_dim``, ``rms_norm_eps``; ``heads`` those HELD, ``q_head_dim``,
+``latent`` and ``row`` derived) and says:
+
+``cos_sin(positions)``  the rotation's ``(cos, sin) [.., rope / 2]``,
+                        with whatever scaling of the frequencies the
+                        model has (YaRN, or none);
+``score_scale``         what the scores are multiplied by;
+``q_lora_scale``, ``kv_lora_scale``  a constant on the normed low-rank
+                        streams ``c_q`` and ``c_kv`` (1 where the model
+                        has none; ``k_r`` is never scaled).  It rides on
+                        the norm's gain, so the stream is rounded once.
+"""
+
+from __future__ import annotations
+
+import math
+
+try:
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+except ImportError:  # pragma: no cover
+    jax = jnp = lax = None
+
+from ..ops import kernels
+from . import moe
+from .attention import NEG, rope
+
+_rms, _mm, _precision = moe.rms, moe.mm, moe.precision
+
+
+def _gain(gain, scale: float):
+    return gain if scale == 1.0 else gain * jnp.float32(scale)
+
+
+def queries(cfg, p, x, cos, sin):
+    """``(q_nope [N, heads, nope], q_rope [N, heads, rope])`` rotated."""
+    c_q = _rms(_mm(x, p["q_a"]).astype(x.dtype),
+               _gain(p["q_a_norm"], cfg.q_lora_scale), cfg.rms_norm_eps)
+    q = _mm(c_q, p["q_b"]).astype(x.dtype).reshape(
+        x.shape[0], cfg.heads, cfg.q_head_dim)
+    q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+    return q_nope, rope(q_rope, cos[:, None], sin[:, None])
+
+
+def latent_rows(cfg, p, x, cos, sin, dtype):
+    """What the cache keeps of each token: ``[c_kv | k_r | 0..]``,
+    normed and rotated, ``cfg.row`` wide."""
+    kv = _mm(x, p["kv_a"]).astype(x.dtype)
+    c_kv = _rms(kv[:, :cfg.kv_lora_rank],
+                _gain(p["kv_a_norm"], cfg.kv_lora_scale), cfg.rms_norm_eps)
+    k_r = rope(kv[:, cfg.kv_lora_rank:], cos, sin)
+    pad = jnp.zeros((x.shape[0], cfg.row - cfg.latent), x.dtype)
+    return jnp.concatenate([c_kv, k_r, pad], axis=-1).astype(dtype)
+
+
+def kv_b(cfg, p):
+    """``W_kvb`` as ``[latent rank, heads, nope + v]``."""
+    return p["kv_b"].reshape(cfg.kv_lora_rank, cfg.heads,
+                             cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+
+def attn_prefill(cfg, p, x, cache, slot, start, key_block: int = 1024):
+    """Expanded MLA over a chunk ``x [C, hidden]`` of stream ``slot``
+    whose first token is at ``start``: writes the chunk's latent rows,
+    then attends to rows ``[0, start + C)`` block by block (keys and
+    values rebuilt from the latent rows, a running softmax, so no
+    ``[heads, chunk, positions]`` score tensor exists).  Returns the
+    held heads' partial output and the cache."""
+    c = x.shape[0]
+    positions = start + jnp.arange(c, dtype=jnp.int32)
+    cos, sin = cfg.cos_sin(positions)
+    q_nope, q_rope = queries(cfg, p, x, cos, sin)
+    with jax.named_scope("cache_write"):
+        rows = latent_rows(cfg, p, x, cos, sin, cache.dtype)
+        cache = lax.dynamic_update_slice(cache, rows[None], (slot, start, 0))
+    # a chunk starts at a multiple of its own length (the caller's
+    # contract), so whole key blocks never reach beyond start + C
+    kb = math.gcd(int(key_block), c)
+    w_kvb, scale = kv_b(cfg, p), cfg.score_scale
+    hp = _precision(p["kv_b"])
+
+    def body(j, carry):
+        m, l, acc = carry
+        blk = lax.dynamic_slice(cache, (slot, j * kb, 0),
+                                (1, kb, cfg.row))[0].astype(x.dtype)
+        blk_r = blk[:, cfg.kv_lora_rank:cfg.latent]
+        blk = blk[:, :cfg.kv_lora_rank]
+        kv = jnp.einsum("kr,rhd->khd", blk, w_kvb,
+                        preferred_element_type=jnp.float32,
+                        precision=hp).astype(x.dtype)
+        k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
+        s = jnp.einsum("chd,khd->hck", q_nope, k_nope,
+                       preferred_element_type=jnp.float32, precision=hp) \
+            + jnp.einsum("chd,kd->hck", q_rope, blk_r,
+                         preferred_element_type=jnp.float32, precision=hp)
+        key_pos = j * kb + jnp.arange(kb, dtype=jnp.int32)
+        s = jnp.where(key_pos[None, None, :] <= positions[None, :, None],
+                      s * scale, NEG)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        prob = jnp.exp(s - m_new[..., None])
+        l = l * alpha + prob.sum(axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hck,khd->hcd", prob.astype(x.dtype), v,
+            preferred_element_type=jnp.float32, precision=hp)
+        return m_new, l, acc
+
+    blocks = (start + c + kb - 1) // kb
+    m0 = jnp.full((cfg.heads, c), NEG, jnp.float32)
+    _, l, acc = lax.fori_loop(
+        0, blocks, body,
+        (m0, jnp.zeros_like(m0),
+         jnp.zeros((cfg.heads, c, cfg.v_head_dim), jnp.float32)))
+    o = (acc / l[..., None]).astype(x.dtype)
+    o = o.transpose(1, 0, 2).reshape(c, cfg.heads * cfg.v_head_dim)
+    return _mm(o, p["o"]).astype(x.dtype), cache
+
+
+def attn_decode(cfg, p, x, cache, positions):
+    """Absorbed MLA for one token of every stream: ``x [B, hidden]``,
+    stream ``b`` at ``positions[b]``.  Writes each stream's row, then
+    scores and values straight on the latent rows up to its position
+    (``q~ = q_nope W_kvb[k]^T``; one pass over a stream's live rows,
+    which the kernel copies itself)."""
+    b = x.shape[0]
+    cos, sin = cfg.cos_sin(positions)
+    q_nope, q_rope = queries(cfg, p, x, cos, sin)
+    with jax.named_scope("cache_write"):
+        rows = latent_rows(cfg, p, x, cos, sin, cache.dtype)
+        cache = cache.at[jnp.arange(b), positions].set(rows)
+    w_kvb = kv_b(cfg, p)
+    hp = _precision(p["kv_b"])
+    q_abs = jnp.einsum("bhd,rhd->bhr", q_nope,
+                       w_kvb[..., :cfg.qk_nope_head_dim],
+                       preferred_element_type=jnp.float32,
+                       precision=hp).astype(x.dtype)
+    q_cat = jnp.concatenate([q_abs, q_rope, jnp.zeros(
+        (b, cfg.heads, cfg.row - cfg.latent), x.dtype)], axis=-1)
+    o_lat = kernels.latent_decode_attention(
+        q_cat, cache, positions, cfg.kv_lora_rank, cfg.score_scale)
+    o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(x.dtype),
+                   w_kvb[..., cfg.qk_nope_head_dim:],
+                   preferred_element_type=jnp.float32,
+                   precision=hp).astype(x.dtype)
+    return _mm(o.reshape(b, -1), p["o"]).astype(x.dtype), cache
+
+
+def init_cache(cfg, streams: int, positions: int, dtype):
+    """One latent cache: whole lattice cells of positions (the decode
+    kernel copies a stream's live rows by cells of 128); rows never
+    written are masked."""
+    positions = -(-int(positions) // 128) * 128
+    return jnp.zeros((streams, positions, cfg.row), dtype)

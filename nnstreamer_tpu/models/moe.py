@@ -1,5 +1,6 @@
 """Routed experts as the token models run them (``deepseek_v2.py``,
-``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``): the plan that sorts (token,
+``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``,
+``longcat_flash.py``): the plan that sorts (token,
 expert) pairs by expert, the product over blocks of one expert's rows,
 and the weighted sum back to tokens.  No token is dropped and there is
 no capacity factor; only the blocks in use are computed, so the work
@@ -12,7 +13,11 @@ experts are held, and the form of one (``silu`` or ``relu``: gated,
 ``relu2``: ungated, ``relu(x W_up)^2`` through ``W_down``, two).  How a
 model routes (groups, scaling, normalisation, what the router reads)
 stays with the model; the sigmoid router two of them share is
-:func:`route_sigmoid`.
+:func:`route_sigmoid`, and the softmax router over real and
+zero-compute experts alike is :func:`route_softmax` (a pick on a
+zero-compute expert is a pair :func:`dispatch` drops like any expert
+held elsewhere, and :func:`zero_weight` of the token's own input, which
+every chip adds alike).
 
 Also the small parts the models are made of: RMSNorm with float32
 statistics, and a product in the weights' type accumulated in float32.
@@ -77,6 +82,29 @@ def route_sigmoid(u, router, bias, top_k: int, scaling: float):
     return idx.astype(jnp.int32), weight * scaling
 
 
+def route_softmax(u, router, bias, top_k: int, scaling: float):
+    """Softmax scores in float32 over ALL the router's outputs (the
+    published experts and, after them, the zero-compute ones); the
+    ``top_k`` largest of ``score + bias`` are chosen, and weighted by
+    their scores alone times ``scaling``, NOT normalised to 1: ``(idx
+    [N, k] int32, weight [N, k] float32)``.  The router of
+    ``longcat_flash.py``."""
+    score = jax.nn.softmax(jnp.matmul(
+        u.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    _, idx = lax.top_k(score + bias, top_k)
+    weight = jnp.take_along_axis(score, idx, axis=-1)
+    return idx.astype(jnp.int32), weight * scaling
+
+
+def zero_weight(idx, weight, n_real: int):
+    """``[N]`` float32: the summed weight of each token's picks on
+    zero-compute experts (``idx >= n_real``).  An identity expert
+    returns its input, so the picks add this times the token's input
+    and cost no product; every chip of a layer computes it alike."""
+    return jnp.sum(jnp.where(idx >= n_real, weight, 0.0), axis=-1)
+
+
 def block_rows(n_tokens: int) -> int:
     """Rows of one block of the grouped product: a block holds rows of
     ONE expert, so a larger block reads that expert's weights for more
@@ -118,6 +146,17 @@ def dispatch(idx, n_tokens: int, expert0: int, held: int):
     return {"row_token": row_token, "dest": dest.reshape(n_tokens, k),
             "block_expert": block_expert, "blocks": pad_end[-1] // blk,
             "counts": counts[:held], "blk": blk, "rows": rows}
+
+
+def one_group_plan(n_rows: int):
+    """The plan of ``n_rows`` rows that all belong to expert 0, as ONE
+    block: how a model runs a dense MLP through :func:`grouped_experts`
+    (its matrices given a leading axis of 1), so that the kernel's
+    pipeline streams them while it multiplies.  Row ``i`` of the result
+    is token ``i``; there is nothing to combine."""
+    return {"row_token": jnp.arange(n_rows, dtype=jnp.int32),
+            "block_expert": jnp.zeros((1,), jnp.int32),
+            "blocks": jnp.int32(1), "blk": n_rows, "rows": n_rows}
 
 
 def grouped_experts(p, x, plan, act: str = "silu"):

@@ -104,9 +104,9 @@ def test_the_walk_is_the_reference(case):
     (6144, 2048, (1024, 4)), (16384, 2048, (1024, 4)),
     (4096, 1024, (2048, 4)), (256, 2048, (128, 8)),
     (384, 1024, (128, 8)), (768, 64 << 10, (128, 3)),
-    (16640, 1280, (1280, 5)),
+    (16640, 1280, (1280, 5)), (4096, 1280, (1024, 6)),
 ], ids=["smallthinker-ring", "smallthinker-full", "nemotron3", "two-cells",
-        "three-cells", "wide-rows", "dsv2-latent"])
+        "three-cells", "wide-rows", "dsv2-latent", "longcat-latent"])
 def test_the_plan_follows_what_the_call_sees(total, row_bytes, plan):
     got = kernels.decode_walk_plan(total, row_bytes)
     assert tuple(got) == plan
@@ -128,6 +128,31 @@ def test_the_latent_cells_plan_is_chunks_of_ten_cells_in_five_buffers():
         assert sum(size for _, size in got) == count
         assert [at for at, _ in got] == [
             sum(size for _, size in got[:i]) for i in range(len(got))]
+
+
+def test_the_latent_kernel_at_64_heads_on_caches_of_4096_rows():
+    """`longcat.decode4k`'s call, `[128, 64, 640]` on `[128, 4096, 640]`
+    bf16 (chunks of 1,024 rows in six buffers: the plan follows the
+    cache's rows and a row's bytes, not the streams), interpreted here on
+    five of its 128 streams: two whole chunks, the first row of a third,
+    three whole, a fourth of 4 + 1 cells, the cache's last row."""
+    plan = kernels.decode_walk_plan(4096, 640 * 2)
+    assert plan == WalkPlan(1024, 6) and plan.pieces == (4, 2, 1)
+    at = [2047, 2048, 3071, 3072 + 513, 4095]
+    rng = np.random.default_rng(4)
+    q = jnp.asarray(rng.normal(size=(len(at), 64, 640)), jnp.bfloat16)
+    cache = jnp.asarray(rng.normal(size=(len(at), 4096, 640)), jnp.bfloat16)
+    assert kernels.latent_decode_attention_refusal(
+        (128, 64, 640), (128, 4096, 640), 512) is None
+    at = jnp.asarray(at, jnp.int32)
+    got = kernels.latent_decode_attention(q, cache, at, 512, 192 ** -0.5)
+    want = kernels.latent_decode_attention_reference(q, cache, at, 512,
+                                                     192 ** -0.5)
+    assert got.shape == (5, 64, 512) and got.dtype == jnp.float32
+    assert np.allclose(np.asarray(got), np.asarray(want), atol=2e-2)
+    # what the walk fetches for them: every live cell whole
+    assert int(kernels.decode_rows_fetched(at, 4096, 4096)) \
+        == sum((int(p) // 128 + 1) * 128 for p in at)
 
 
 @pytest.mark.parametrize("kernel", ["gqa", "latent"])

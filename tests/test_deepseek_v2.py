@@ -24,6 +24,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from benchmark.run import Loader  # noqa: E402
 from nnstreamer_tpu.models import deepseek_v2 as dsv2  # noqa: E402
+from nnstreamer_tpu.models import mla  # noqa: E402
 from nnstreamer_tpu.ops import kernels  # noqa: E402
 
 SEED = 11
@@ -152,13 +153,13 @@ def test_absorbed_is_expanded(toy, files):
     x = jax.random.normal(jax.random.PRNGKey(3), (8, cfg.hidden_size))
     cache = jnp.zeros((2, 128, cfg.row), jnp.float32)
     one = jnp.int32(1)
-    expanded, cache_a = dsv2.attn_prefill(cfg, p, x, cache, one,
+    expanded, cache_a = mla.attn_prefill(cfg, p, x, cache, one,
                                           jnp.int32(0))
     # the first seven rows cached, the eighth decoded in stream 1
-    _, cache_b = dsv2.attn_prefill(cfg, p, x.at[7].set(0.0), cache, one,
+    _, cache_b = mla.attn_prefill(cfg, p, x.at[7].set(0.0), cache, one,
                                    jnp.int32(0))
     both = jnp.stack([jnp.zeros_like(x[7]), x[7]])
-    absorbed, cache_b = dsv2.attn_decode(cfg, p, both, cache_b,
+    absorbed, cache_b = mla.attn_decode(cfg, p, both, cache_b,
                                          jnp.array([0, 7], jnp.int32))
     assert np.allclose(np.asarray(absorbed[1]), np.asarray(expanded[7]),
                        atol=2e-5)
@@ -319,7 +320,7 @@ def test_the_shares_add_up_to_the_uncut_layer(files, shares, layer):
     for cfg_one, p_one in parts:
         p = p_one["layers"][layer]
         cache = jnp.zeros((1, 8, cfg_one.row), jnp.float32)
-        part, _ = dsv2.attn_prefill(
+        part, _ = mla.attn_prefill(
             cfg_one, p["attn"], dsv2._rms(x, p["attn_norm"], 1e-6), cache,
             jnp.int32(0), jnp.int32(0))
         attn = attn + part

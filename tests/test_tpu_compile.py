@@ -353,3 +353,82 @@ def test_the_window128_cells_programs_copy_no_cache(one_chip, monkeypatch,
         < 15.5e9
     assert compiled.as_text().count("tpu_custom_call") >= 5 + (
         6 if entry == "decode" else 0)
+
+
+def test_latent_decode_attention_compiles_at_64_heads(one_chip, monkeypatch):
+    """`longcat.decode4k`'s call: 128 streams of 64 heads on caches of
+    4,096 rows of 640 bf16 values, walked in chunks of 1,024 rows
+    through six buffers (7.9 MB of fast memory, stated by the call), as
+    a Mosaic kernel; one custom call, no copy of the cache beside it."""
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    assert kernels.decode_walk_plan(4096, 1280) == kernels.WalkPlan(1024, 6)
+    fn = jax.jit(functools.partial(kernels.latent_decode_attention,
+                                   rank=512, scale=192 ** -0.5))
+    compiled = fn.lower(shape((128, 64, 640)), shape((128, 4096, 640)),
+                        shape((128,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("entry,temp_mb", [("decode", 96), ("prefill", 1536)])
+def test_the_two_cache_cells_programs_fit_the_chip(one_chip, monkeypatch,
+                                                   capsys, entry, temp_mb):
+    """`longcat.decode4k`'s two programs at the cell's sizes (four layers
+    of two latent-attention sub-blocks, 128 streams, 4,096 positions,
+    chunks of 2,048; 7.9 GB of weights and 5.4 GB of state as
+    arguments): the eight caches are updated in the donated buffers (one
+    is 671 MB, so a copy of one shows in the temporaries), weights, state
+    and temporaries fit the chip, and a decode step attends through the
+    kernel in all eight caches and runs the grouped product for the
+    routed experts of four layers and, as one group of one expert, for
+    the eight dense MLPs (a chunk's dense MLPs are XLA's products): 20
+    custom calls a decode step, 4 a chunk, none fallen back to the loop."""
+    import json
+    import os
+
+    from nnstreamer_tpu.models import longcat_flash as lc
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "longcat_flash_omni_share64.json")
+    with open(path) as f:
+        cfg = lc.LongCatFlashConfig.from_dict(json.load(f))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: lc.init_params(cfg, 0))
+    state = jax.eval_shape(lambda: lc.init_state(cfg, params, 128, 4096))
+
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(tree))
+
+    assert 7.92e9 < nbytes(params) < 7.94e9
+    assert 5.36e9 < nbytes(state) < 5.38e9
+    assert [c.shape for pair in state["cache"] for c in pair] \
+        == [(128, 4096, 640)] * 8
+
+    def i32(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+
+    fn, inputs = {"decode": (lc.decode, [i32(128), i32(128)]),
+                  "prefill": (lc.prefill, [i32(2048), i32(1), i32(1)])}[entry]
+    compiled = jax.jit(functools.partial(fn, cfg), donate_argnums=(1,)) \
+        .lower(on_chip(params), on_chip(state), *inputs).compile()
+    memory = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nlongcat.decode4k {entry}: {memory}")
+    assert memory.alias_size_in_bytes >= nbytes(state)
+    assert memory.temp_size_in_bytes < temp_mb << 20
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.5e9
+    assert compiled.as_text().count("tpu_custom_call") == 4 + (
+        8 + 8 if entry == "decode" else 0)

@@ -21,11 +21,13 @@ their rows of ``W_o``; logits are over the slice.  Nothing stands in for
 the absent chips or their exchange.
 
 **Two paths through one set of weights.**  The state is the latent cache
-alone: per layer ``[streams, positions, row]``, a row a token's ``(c_kv,
-k_r)`` after norm and rotation, 576 values padded to 640
-(``models/mla.py``, which ``longcat_flash.py`` runs too, has the
-attention: what this model gives it is the YaRN rotation and the
-scores' scale).  :func:`prefill` runs a chunk of ONE stream through the
+alone: per layer a token's ``(c_kv, k_r)`` after norm and rotation, 576
+values at the published sizes, two positions a row: ``[streams,
+positions / 2, 1152]`` (``models/mla.py``, which ``longcat_flash.py``
+runs too, has the attention and the row's form, which follows from
+``kv_lora_rank`` and ``qk_rope_head_dim`` alone: a rank that is not
+whole lanes keeps a row a position, padded to whole lanes; what this
+model gives it is the YaRN rotation and the scores' scale).  :func:`prefill` runs a chunk of ONE stream through the
 expanded form of MLA and writes the chunk's rows; :func:`decode` runs
 one token of EVERY stream through the absorbed form (``ops/kernels.py``
 ``latent_decode_attention``).  Positions come with the frame; rows
@@ -164,8 +166,9 @@ class DeepSeekV2Config:
 
     @property
     def row(self) -> int:
-        """Width of a cache row: ``latent`` padded to whole lanes."""
-        return -(-self.latent // 128) * 128
+        """Values a position takes in a cache as stored: ``latent``
+        where rows are packed, else padded to whole lanes."""
+        return mla.row_values(self)
 
     @property
     def q_head_dim(self) -> int:
@@ -369,7 +372,9 @@ def counter_units(cfg: DeepSeekV2Config, state: dict) -> dict:
     row its ``latent`` values; ``cache_rows_fetched`` the rows the
     decode kernel copies for them (``ops/kernels.py``
     ``decode_rows_fetched``: every live cell whole), a row as the cache
-    holds it, padded to whole lanes.  A row is read in every layer."""
+    holds it (``row``: the ``latent`` values themselves where rows are
+    packed, else padded to whole lanes).  A row is read in every
+    layer."""
     size = state["cache"][0].dtype.itemsize * cfg.layers
     return {"cache_bytes_read": ("cache_rows_read", cfg.latent * size),
             "cache_bytes_fetched": ("cache_rows_fetched", cfg.row * size)}
@@ -399,7 +404,7 @@ def decode(cfg: DeepSeekV2Config, params, state, ids, positions):
         cfg, params, x, state["cache"],
         lambda p, h, cache: mla.attn_decode(cfg, p, h, cache, positions))
     logits, greedy = _head(cfg, params, x)
-    old, total = state["counters"], caches[0].shape[1]
+    old, total = state["counters"], mla.cache_positions(cfg, caches[0])
     new = {"steps": old["steps"] + jnp.uint32(1),
            "cache_rows_read": old["cache_rows_read"]
            + jnp.sum(positions + 1).astype(jnp.uint32),

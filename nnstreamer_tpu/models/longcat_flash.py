@@ -45,9 +45,11 @@ MLPs, the zero-compute picks' term) and passes that partial sum on.
 Nothing stands in for the absent chips or their exchange.
 
 **State.**  Two latent caches a layer, ``state["cache"][layer][sub]``,
-each ``[streams, positions, row]``; :func:`prefill` writes and reads
-both through the expanded form, :func:`decode` through the absorbed
-form.  Counters beside them: ``steps``, ``cache_rows_read``,
+each a token's 576 values two positions a row, ``[streams, positions /
+2, 1152]``, at the published sizes (``models/mla.py`` has the row's
+form: a rank that is not whole lanes keeps a row a position, padded to
+whole lanes); :func:`prefill` writes and reads both through the
+expanded form, :func:`decode` through the absorbed form.  Counters beside them: ``steps``, ``cache_rows_read``,
 ``cache_rows_fetched``, ``experts_touched``, ``expert_hits`` and
 ``zero_picks``, the picks that fell on zero-compute experts (a step
 makes tokens x ``moe_topk`` x layers picks, a constant: no counter).
@@ -169,8 +171,9 @@ class LongCatFlashConfig:
 
     @property
     def row(self) -> int:
-        """Width of a cache row: ``latent`` padded to whole lanes."""
-        return -(-self.latent // 128) * 128
+        """Values a position takes in a cache as stored: ``latent``
+        where rows are packed, else padded to whole lanes."""
+        return mla.row_values(self)
 
     @property
     def q_head_dim(self) -> int:
@@ -341,7 +344,8 @@ def counter_units(cfg: LongCatFlashConfig, state: dict) -> dict:
     counts the latent rows IN USE of ONE cache (``0 .. position``), a
     row its ``latent`` values; ``cache_rows_fetched`` the rows the
     decode kernel copies for them (every live cell whole), a row as the
-    cache holds it, padded to whole lanes.  A row is read in both caches
+    cache holds it (``row``: the ``latent`` values themselves where rows
+    are packed, else padded to whole lanes).  A row is read in both caches
     of every layer."""
     size = state["cache"][0][0].dtype.itemsize * cfg.layers * SUBS
     return {"cache_bytes_read": ("cache_rows_read", cfg.latent * size),
@@ -372,7 +376,7 @@ def decode(cfg: LongCatFlashConfig, params, state, ids, positions):
         cfg, params, x, state["cache"],
         lambda p, h, cache: mla.attn_decode(cfg, p, h, cache, positions))
     logits, greedy = _head(cfg, params, x)
-    total = caches[0][0].shape[1]
+    total = mla.cache_positions(cfg, caches[0][0])
     gained = {
         "steps": 1,
         "cache_rows_read": jnp.sum(positions + 1),

@@ -5,18 +5,32 @@ through one set of weights, the expanded form for a prefill chunk and
 the absorbed form for a decode step (``ops/kernels.py``
 ``latent_decode_attention``).
 
-A cache is ``[streams, positions, row]``, a row a token's ``(c_kv,
-k_r)`` after norm and rotation, ``latent`` values padded with zeros to
-whole lanes (``row``: the TPU's compiler lays a ``[.., positions, 576]``
-array out with positions minor, and every product over it then copied
-the whole cache).  Rows beyond a stream's position are masked, so a
-stale or padded row is never read.
+A cache keeps of a token its ``(c_kv, k_r)`` after norm and rotation,
+``latent`` values, in the rows ``ops/kernels.py`` ``latent_cache_row``
+lays out for the configuration's sizes.  At the published ones
+(``kv_lora_rank`` 512, whole lane tiles, and ``qk_rope_head_dim`` 64,
+half of one) a cache is ``[streams, positions / 2, 1152]``: a row PACKS
+two neighbouring positions, ``[c_kv a | c_kv b | k_r a, k_r b]``, so it
+stores the 576 values of each and nothing else and every slice of it is
+whole tiles.  (The TPU's compiler lays a ``[.., positions, 576]`` array
+out with positions minor, and every product over it then copied the
+whole cache; a row a position padded to whole lanes took 640 values for
+576, a tenth of every fetch.)  A chunk's rows are packed before they
+are written and a key block is unpacked after it is sliced; a decode
+step reads the one row a stream its token falls in, places the token
+and writes the row back; the decode kernel works on the packed rows as
+they lie.  Nothing reshapes a cache: that is a copy of it.  Other sizes
+(a rank that is not whole lanes: the tests' toy configurations) keep a
+row a position, ``[c_kv | k_r | 0..]`` padded to whole lanes, through
+the same functions.  Positions beyond a stream's are masked, so a stale
+or padded one is never read.
 
 What differs between the models is read off the configuration handed
 in, which names the sizes as the published configs do
 (``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
 ``v_head_dim``, ``rms_norm_eps``; ``heads`` those HELD, ``q_head_dim``,
-``latent`` and ``row`` derived) and says:
+``latent`` and ``row``, the values a position takes as stored, derived:
+:func:`row_values`) and says:
 
 ``cos_sin(positions)``  the rotation's ``(cos, sin) [.., rope / 2]``,
                         with whatever scaling of the frequencies the
@@ -60,15 +74,27 @@ def queries(cfg, p, x, cos, sin):
     return q_nope, rope(q_rope, cos[:, None], sin[:, None])
 
 
+def _row(cfg) -> tuple:
+    """``(positions a cache row holds, the row's width)``."""
+    return kernels.latent_cache_row(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+
+
+def row_values(cfg) -> int:
+    """Values a position takes in a cache as stored (a configuration's
+    ``row``): its ``latent`` ones where rows are packed, else those
+    padded to whole lanes."""
+    per, width = _row(cfg)
+    return width // per
+
+
 def latent_rows(cfg, p, x, cos, sin, dtype):
-    """What the cache keeps of each token: ``[c_kv | k_r | 0..]``,
-    normed and rotated, ``cfg.row`` wide."""
+    """What the cache keeps of each token: ``[c_kv | k_r]``, normed and
+    rotated, ``cfg.latent`` wide."""
     kv = _mm(x, p["kv_a"]).astype(x.dtype)
     c_kv = _rms(kv[:, :cfg.kv_lora_rank],
                 _gain(p["kv_a_norm"], cfg.kv_lora_scale), cfg.rms_norm_eps)
     k_r = rope(kv[:, cfg.kv_lora_rank:], cos, sin)
-    pad = jnp.zeros((x.shape[0], cfg.row - cfg.latent), x.dtype)
-    return jnp.concatenate([c_kv, k_r, pad], axis=-1).astype(dtype)
+    return jnp.concatenate([c_kv, k_r], axis=-1).astype(dtype)
 
 
 def kv_b(cfg, p):
@@ -85,24 +111,33 @@ def attn_prefill(cfg, p, x, cache, slot, start, key_block: int = 1024):
     ``[heads, chunk, positions]`` score tensor exists).  Returns the
     held heads' partial output and the cache."""
     c = x.shape[0]
+    rank, per = cfg.kv_lora_rank, _row(cfg)[0]
     positions = start + jnp.arange(c, dtype=jnp.int32)
     cos, sin = cfg.cos_sin(positions)
     q_nope, q_rope = queries(cfg, p, x, cos, sin)
-    with jax.named_scope("cache_write"):
-        rows = latent_rows(cfg, p, x, cos, sin, cache.dtype)
-        cache = lax.dynamic_update_slice(cache, rows[None], (slot, start, 0))
     # a chunk starts at a multiple of its own length (the caller's
-    # contract), so whole key blocks never reach beyond start + C
+    # contract), so it starts a cache row, and whole key blocks never
+    # reach beyond start + C
     kb = math.gcd(int(key_block), c)
+    if kb % per:
+        raise ValueError(f"mla: key blocks of {kb} positions (a chunk of "
+                         f"{c}) are not whole cache rows of {per}")
+    with jax.named_scope("cache_write"):
+        rows = kernels.latent_pack(
+            latent_rows(cfg, p, x, cos, sin, cache.dtype), rank)
+        cache = lax.dynamic_update_slice(cache, rows[None],
+                                         (slot, start // per, 0))
     w_kvb, scale = kv_b(cfg, p), cfg.score_scale
     hp = _precision(p["kv_b"])
 
     def body(j, carry):
         m, l, acc = carry
-        blk = lax.dynamic_slice(cache, (slot, j * kb, 0),
-                                (1, kb, cfg.row))[0].astype(x.dtype)
-        blk_r = blk[:, cfg.kv_lora_rank:cfg.latent]
-        blk = blk[:, :cfg.kv_lora_rank]
+        blk = kernels.latent_unpack(lax.dynamic_slice(
+            cache, (slot, j * (kb // per), 0),
+            (1, kb // per, cache.shape[2]))[0], rank,
+            cfg.qk_rope_head_dim).astype(x.dtype)
+        blk_r = blk[:, rank:]
+        blk = blk[:, :rank]
         kv = jnp.einsum("kr,rhd->khd", blk, w_kvb,
                         preferred_element_type=jnp.float32,
                         precision=hp).astype(x.dtype)
@@ -136,24 +171,27 @@ def attn_prefill(cfg, p, x, cache, slot, start, key_block: int = 1024):
 
 def attn_decode(cfg, p, x, cache, positions):
     """Absorbed MLA for one token of every stream: ``x [B, hidden]``,
-    stream ``b`` at ``positions[b]``.  Writes each stream's row, then
-    scores and values straight on the latent rows up to its position
-    (``q~ = q_nope W_kvb[k]^T``; one pass over a stream's live rows,
-    which the kernel copies itself)."""
+    stream ``b`` at ``positions[b]``.  Writes each stream's token into
+    its row (``b`` rows read, the token placed, ``b`` rows written: a
+    packed row's other position stays), then scores and values straight
+    on the latent rows up to its position (``q~ = q_nope W_kvb[k]^T``;
+    one pass over a stream's live rows, which the kernel copies
+    itself)."""
     b = x.shape[0]
     cos, sin = cfg.cos_sin(positions)
     q_nope, q_rope = queries(cfg, p, x, cos, sin)
     with jax.named_scope("cache_write"):
         rows = latent_rows(cfg, p, x, cos, sin, cache.dtype)
-        cache = cache.at[jnp.arange(b), positions].set(rows)
+        at = (jnp.arange(b), positions // _row(cfg)[0])
+        cache = cache.at[at].set(kernels.latent_place(
+            cache[at], rows, positions, cfg.kv_lora_rank))
     w_kvb = kv_b(cfg, p)
     hp = _precision(p["kv_b"])
     q_abs = jnp.einsum("bhd,rhd->bhr", q_nope,
                        w_kvb[..., :cfg.qk_nope_head_dim],
                        preferred_element_type=jnp.float32,
                        precision=hp).astype(x.dtype)
-    q_cat = jnp.concatenate([q_abs, q_rope, jnp.zeros(
-        (b, cfg.heads, cfg.row - cfg.latent), x.dtype)], axis=-1)
+    q_cat = jnp.concatenate([q_abs, q_rope], axis=-1)
     o_lat = kernels.latent_decode_attention(
         q_cat, cache, positions, cfg.kv_lora_rank, cfg.score_scale)
     o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(x.dtype),
@@ -163,9 +201,15 @@ def attn_decode(cfg, p, x, cache, positions):
     return _mm(o.reshape(b, -1), p["o"]).astype(x.dtype), cache
 
 
+def cache_positions(cfg, cache) -> int:
+    """Positions a cache holds."""
+    return cache.shape[1] * _row(cfg)[0]
+
+
 def init_cache(cfg, streams: int, positions: int, dtype):
     """One latent cache: whole lattice cells of positions (the decode
-    kernel copies a stream's live rows by cells of 128); rows never
-    written are masked."""
+    kernel copies a stream's live rows by cells of 128), ``cfg.row``
+    values each; positions never written are masked."""
     positions = -(-int(positions) // 128) * 128
-    return jnp.zeros((streams, positions, cfg.row), dtype)
+    per, width = _row(cfg)
+    return jnp.zeros((streams, positions // per, width), dtype)

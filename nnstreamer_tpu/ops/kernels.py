@@ -22,9 +22,11 @@ Parity/role:
   output projection reads, scores in VMEM only.  ``S`` need not tile:
   it pads and masks inside.
 - ``latent_decode_attention`` is absorbed latent attention of one token
-  a stream over a dense latent cache (``models/deepseek_v2.py``'s
-  decode step): one pass over a stream's live rows, a chunk read once
-  for both products.
+  a stream over a dense latent cache (``models/mla.py``'s decode step,
+  for ``deepseek_v2.py`` and ``longcat_flash.py``): one pass over a
+  stream's live rows, a chunk read once for both products.  A row of
+  the cache packs two positions where the sizes allow
+  (``latent_cache_row``), and is scored as it lies.
 - ``gqa_decode_attention`` is grouped-query attention of one token a
   stream over separate K and V caches (``models/smallthinker.py``'s
   decode step, ``models/nemotron_h.py``'s and
@@ -557,7 +559,8 @@ def _walk_plan_check(kernel: str, plan: WalkPlan, total: int):
 
 
 def _walk_stream(pos_ref, pairs, arrived, base_ref, sums, o_ref, update, *,
-                 total: int, window: int, plan: WalkPlan, ring: bool):
+                 total: int, window: int, plan: WalkPlan, ring: bool,
+                 cell_rows: int = _WALK_LATTICE):
     """One grid step of a decode kernel on the walk (the grid runs over
     streams, in order): stream ``i``'s items through the queue, the
     running sums over them, and its output.
@@ -565,13 +568,16 @@ def _walk_stream(pos_ref, pairs, arrived, base_ref, sums, o_ref, update, *,
     ``pairs`` are the ``(cache, buffers)`` an item is copied between (a
     cache ``[streams, .., positions, width]`` left in HBM, its buffers
     ``[slots, .., chunk, width]``; one copy a pair an item, signalled on
-    ``arrived[pair, slot]``), ``base_ref`` (SMEM) carries the buffer of
+    ``arrived[pair, slot]``; a cell of the lattice is ``cell_rows`` rows
+    of either, fewer than its positions where a row packs several),
+    ``base_ref`` (SMEM) carries the buffer of
     a stream's first item from one step to the next, ``sums`` are the
     running max, normaliser and accumulator, and ``update(pos, slot,
     start, offset, size)`` adds to them rows ``offset .. offset + size``
     of buffer ``slot``, which holds the cache's rows from cell ``start``
     on.  ``ring`` says whether an item can run over the cache's end: a
-    dense cache has no cell-by-cell copies in its program."""
+    dense cache has no cell-by-cell copies in its program.  ``total``,
+    ``window``, the plan's chunk and ``update``'s rows count positions."""
     import jax.numpy as jnp
 
     jax, pl, pltpu = _pl()
@@ -600,9 +606,11 @@ def _walk_stream(pos_ref, pairs, arrived, base_ref, sums, o_ref, update, *,
                 between = (slice(None),) * (len(ref.shape) - 3)
                 dma = pltpu.make_async_copy(
                     ref.at[(stream, *between, pl.ds(pl.multiple_of(
-                        cell * lat, lat), size * lat), slice(None))],
+                        cell * cell_rows, cell_rows), size * cell_rows),
+                        slice(None))],
                     buf.at[(slot, *between, pl.ds(pl.multiple_of(
-                        offset * lat, lat), size * lat), slice(None))],
+                        offset * cell_rows, cell_rows), size * cell_rows),
+                        slice(None))],
                     arrived.at[n, slot])
                 dma.wait() if wait else dma.start()
 
@@ -686,24 +694,104 @@ def _walk_stream(pos_ref, pairs, arrived, base_ref, sums, o_ref, update, *,
 
 
 # -- latent decode attention --------------------------------------------------
+#
+# A latent cache keeps of a token its ``rank`` latent values ``c_kv`` and
+# the ``rope`` values of its rotary key ``k_r``.  Where ``rank`` is whole
+# lane tiles and ``rope`` divides one (512 and 64 in both published
+# models), a cache row PACKS the ``per = 128 / rope`` positions ``per * r
+# .. per * r + per - 1``: their latent parts side by side, then one lane
+# tile of their rotary keys, ``[c_kv 0 | .. | c_kv per-1 | k_r 0 .. k_r
+# per-1]``, so a row stores what its positions hold and nothing else and
+# every slice of it is whole tiles (1,152 values for two positions; a row
+# a position padded to whole lanes took 640 for 576).  Any other sizes
+# keep a row a position, ``[c_kv | k_r | 0..]`` padded to whole lanes.
+# :func:`latent_cache_row` is the rule, and the kernel, its reference and
+# ``models/mla.py`` (which writes the rows) all read the form off it.
+
+
+def latent_cache_row(rank: int, rope: int) -> tuple:
+    """``(per, width)``: the positions a row of a latent cache holds and
+    the row's width, for tokens of ``rank`` latent and ``rope`` rotary
+    values.  Packed where that stores fewer values than a padded row and
+    a lattice cell's ``128 / per`` rows are whole tiles of a 2-byte type
+    (``per`` at most 8)."""
+    if rank % _LANE == 0 and 16 <= rope < _LANE and _LANE % rope == 0:
+        per = _LANE // rope
+        return per, per * rank + _LANE
+    return 1, -(-(rank + rope) // _LANE) * _LANE
+
+
+def latent_pack(rows, rank: int):
+    """Tokens' ``[.., n, rank + rope]`` values ``(c_kv, k_r)`` as the
+    ``[.., n / per, width]`` cache rows that hold them, the first token
+    at a row's first position (``n`` whole rows)."""
+    import jax.numpy as jnp
+
+    *lead, n, latent = rows.shape
+    per, width = latent_cache_row(rank, latent - rank)
+    if per == 1:
+        return jnp.pad(rows, [(0, 0)] * (len(lead) + 1)
+                       + [(0, width - latent)])
+    return jnp.concatenate(
+        [rows[..., :rank].reshape(*lead, n // per, per * rank),
+         rows[..., rank:].reshape(*lead, n // per, _LANE)], axis=-1)
+
+
+def latent_unpack(rows, rank: int, rope: int):
+    """The ``[.., n * per, rank + rope]`` values ``(c_kv, k_r)`` of the
+    positions that the cache rows ``[.., n, width]`` hold, in order: the
+    inverse of :func:`latent_pack` on a block of rows (never on a whole
+    cache: it is a copy)."""
+    import jax.numpy as jnp
+
+    *lead, n, _ = rows.shape
+    per, _ = latent_cache_row(rank, rope)
+    if per == 1:
+        return rows[..., :rank + rope]
+    return jnp.concatenate(
+        [rows[..., :per * rank].reshape(*lead, n * per, rank),
+         rows[..., per * rank:].reshape(*lead, n * per, rope)], axis=-1)
+
+
+def latent_place(old, rows, positions, rank: int):
+    """The cache rows ``old [B, width]`` with the tokens ``rows [B, rank
+    + rope]`` at ``positions [B]`` put in their places: a packed row's
+    other positions stay as they were."""
+    import jax.numpy as jnp
+
+    rope = rows.shape[-1] - rank
+    per, _ = latent_cache_row(rank, rope)
+    if per == 1:
+        return latent_pack(rows, rank).astype(old.dtype)
+    mine = np.concatenate([np.arange(per * rank) // rank,
+                           np.arange(_LANE) // rope])
+    new = jnp.concatenate([jnp.tile(rows[:, :rank], (1, per)),
+                           jnp.tile(rows[:, rank:], (1, per))], axis=-1)
+    return jnp.where(mine[None] == (positions % per)[:, None],
+                     new.astype(old.dtype), old)
 
 
 def latent_decode_attention_refusal(q_shape, cache_shape,
                                     rank: int) -> Optional[str]:
     """Why :func:`latent_decode_attention` cannot take these shapes, or
-    None: the row width whole lanes and the same on both sides, ``rank``
-    within it, and a cache of whole lattice cells (lane tiles of
-    positions), at least one."""
+    None: the cache's rows as :func:`latent_cache_row` lays them out for
+    the queries' ``rank`` and rotary values, and a cache of whole
+    lattice cells (lane tiles of positions), at least one."""
     if len(q_shape) != 3 or len(cache_shape) != 3 \
             or q_shape[0] != cache_shape[0]:
         return f"q {tuple(q_shape)} and cache {tuple(cache_shape)} are " \
-               "not [B, heads, width] and [B, positions, width]"
-    width = q_shape[2]
-    if width != cache_shape[2] or width % _LANE or not 0 < rank <= width:
-        return f"row width {width} (cache {cache_shape[2]}) must be whole " \
-               f"lanes of {_LANE} and hold the {rank} values"
-    if cache_shape[1] < _WALK_LATTICE or cache_shape[1] % _WALK_LATTICE:
-        return f"{cache_shape[1]} cache positions are not whole lattice " \
+               "not [B, heads, width] and [B, rows, width]"
+    if not 0 < rank <= q_shape[2]:
+        return f"queries of {q_shape[2]} values do not hold the {rank} " \
+               "latent ones"
+    per, width = latent_cache_row(rank, q_shape[2] - rank)
+    if width != cache_shape[2]:
+        return f"queries of {q_shape[2]} values ({rank} latent) want " \
+               f"cache rows of {width} for {per} positions, whole lanes " \
+               f"of {_LANE}, not {cache_shape[2]}"
+    positions = cache_shape[1] * per
+    if positions < _WALK_LATTICE or positions % _WALK_LATTICE:
+        return f"{positions} cache positions are not whole lattice " \
                f"cells of {_WALK_LATTICE}"
     return None
 
@@ -711,13 +799,13 @@ def latent_decode_attention_refusal(q_shape, cache_shape,
 def latent_decode_attention_reference(q, cache, positions, rank: int,
                                       scale: float):
     """The kernel's mathematics in jnp: every head's scores against
-    every cached row up to the stream's position, softmax in float32,
-    values the rows' first ``rank`` entries."""
+    every cached position up to the stream's, softmax in float32,
+    values the positions' first ``rank`` entries."""
     import jax
     import jax.numpy as jnp
 
     hp = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
-    cache = cache.astype(q.dtype)
+    cache = latent_unpack(cache, rank, q.shape[2] - rank).astype(q.dtype)
     s = jnp.einsum("bhl,btl->bht", q, cache,
                    preferred_element_type=jnp.float32, precision=hp)
     t = jnp.arange(cache.shape[1], dtype=jnp.int32)
@@ -731,47 +819,65 @@ def latent_decode_attention_reference(q, cache, positions, rank: int,
 
 def latent_decode_attention(q, cache, positions, rank: int, scale: float):
     """Absorbed latent attention of one token a stream over a latent
-    cache: ``q [B, heads, width]`` (each head's absorbed query and its
-    rotary part side by side, zeros to the width), ``cache [B,
-    positions, width]`` (a token's ``rank`` latent values, its rotary
-    key, zeros to the width; dense: position ``p`` in row ``p``),
-    ``positions [B]`` int32.  Returns ``[B, heads, rank]`` float32:
-    softmax(q cache^T * scale) over rows ``0..positions[b]``, times the
-    rows' first ``rank`` entries.
+    cache: ``q [B, heads, rank + rope]`` (each head's absorbed query and
+    its rotary part side by side), ``cache [B, positions / per, width]``
+    (dense: position ``p`` in row ``p // per``, laid out as
+    :func:`latent_cache_row` says: two positions a row of 1,152 values
+    at ``rank`` 512 and ``rope`` 64, a position a row padded to whole
+    lanes where ``rank`` is not whole lanes), ``positions [B]`` int32.
+    Returns
+    ``[B, heads, rank]`` float32: softmax(q k^T * scale) over positions
+    ``0..positions[b]``, times the positions' latent values.
 
     One pass, and the kernel copies a stream's live rows itself: the
     cache stays in HBM, the grid runs over streams, and a stream's rows
-    come in by the walk above (:func:`decode_walk_plan`; chunks of 1,280
-    rows in five buffers for caches of 16,640 rows of 640 bf16 values),
+    come in by the walk above (:func:`decode_walk_plan` on the bytes a
+    position takes as stored: chunks of 1,664 positions in four buffers
+    for caches of 16,640, of 1,024 in seven for caches of 4,096),
     counted from the first live cell, the last item in pieces of 8, 4, 2
     and 1 cells, through the queue :func:`gqa_decode_attention` runs
     too (:func:`_walk_stream`): its copies run on into the next stream's
     first items, so no stream starts cold.  An item is read once from
-    its buffer and serves both products (the scores contract over the
-    row's whole width, the values take its first ``rank`` lanes) in one
-    update of the running max, normaliser and accumulator; only an item
-    that reaches the stream's position masks rows.  What the walk
-    fetches beyond the rows in use is less than a cell a stream
+    its buffer and serves both products in one update of the running
+    max, normaliser and accumulator.  A softmax does not care in which
+    order it meets its positions, so a packed row is never unpacked:
+    the positions ``per * r + h`` of an item's rows ``r`` are scored as
+    one part-block a ``h`` (the absorbed query against the ``h``-th
+    latent part, the rotary query, shifted to the ``h``-th key's lanes,
+    against the keys' tile), masked by their own positions, and the
+    values product takes each latent part as it lies.  Only an item that
+    reaches the stream's position masks.  What the walk fetches beyond
+    the positions in use is less than a cell a stream
     (:func:`decode_rows_fetched` with ``window = positions``).  (XLA's
     own two products read every position of the cache twice, and one
     whose rows are not whole lanes it copies whole first.)
 
     On the chip (the kernel alone at ``dsv2.decode16k``'s shapes, 32
     streams of 32 heads at 8-16 k, device ms a call; ``PERF.md`` section
-    6, PR 41): blocks of 640 rows through a ``BlockSpec`` pipeline 0.845
-    -> the walk 0.703; its copies alone 0.700 (730 GB/s of rows as the
-    cache holds them, 640 values for 576 in use), its arithmetic alone
-    0.414: the copies bind, and 3 to 8 buffers and chunks of 640 rows
-    read the same to 0.4 %.  Inside the decode step a call reads 0.80
-    -> 0.678 (754 GB/s).
+    6, PR 41, rows of 640 values a position): blocks of 640 rows through
+    a ``BlockSpec`` pipeline 0.845 -> the walk 0.703; its copies alone
+    0.700 (730 GB/s of rows as the cache held them, 640 values for 576
+    in use), its arithmetic alone 0.414: the copies bind, and 3 to 8
+    buffers and chunks of 640 rows read the same to 0.4 %.  Inside the
+    decode step a call read 0.80 -> 0.678 (754 GB/s).  Rows of 640
+    values a position -> two positions a row of 1,152 (PR 44, the same
+    streams, both trees in one call): 0.686 -> 0.618, copies alone 0.685
+    -> 0.616 (755 GB/s either way: time follows the bytes, 576/640),
+    arithmetic alone 0.397 -> 0.366; at ``longcat.decode4k``'s shapes
+    (128 streams of 64 heads at 2-4 k) 0.720 -> 0.669, copies alone
+    0.718 -> 0.667, arithmetic alone 0.546 -> 0.558: the copies bind at
+    64 heads too, and there the queries read and the output written, 29
+    MB a call, are 6 % of the bytes and do not shrink.  Inside the
+    decode steps a call reads 0.678 -> 0.609 and 0.688 -> 0.655.
     Heads are padded to whole tiles here; a shape
     :func:`latent_decode_attention_refusal` names is an error, there is
     no second path."""
     refusal = latent_decode_attention_refusal(q.shape, cache.shape, rank)
     if refusal:
         raise ValueError(f"latent_decode_attention: {refusal}")
-    plan = decode_walk_plan(cache.shape[1],
-                            q.shape[2] * np.dtype(q.dtype).itemsize)
+    per, width = latent_cache_row(rank, q.shape[2] - rank)
+    plan = decode_walk_plan(cache.shape[1] * per,
+                            width // per * np.dtype(q.dtype).itemsize)
     return _latent_decode_walk(q, cache, positions, rank, scale, plan)
 
 
@@ -782,17 +888,25 @@ def _latent_decode_walk(q, cache, positions, rank: int, scale: float,
     import jax
     import jax.numpy as jnp
 
-    b, held, width = q.shape
+    b, held, _ = q.shape
+    rope = q.shape[2] - rank
+    per, width = latent_cache_row(rank, rope)
     # whole tiles of heads: padded heads score zero everywhere and are
-    # cut off again; values that are not whole lanes come out of the
-    # whole row
+    # cut off again
     heads = -(-held // _sublane(q.dtype)) * _sublane(q.dtype)
-    if heads != held:
-        q = jnp.pad(q, ((0, 0), (0, heads - held), (0, 0)))
-    values = rank if rank % _LANE == 0 else width
-    call = _latent_decode_walk_call(b, heads, width, values, cache.shape[1],
-                                    float(scale), np.dtype(q.dtype).name,
-                                    plan, _interpret())
+    q = jnp.pad(q, ((0, 0), (0, heads - held), (0, 0)))
+    # beside the absorbed query: zeros to a padded row's width, or the
+    # rotary query once a position of a packed row, on that key's lanes
+    # of a tile of its own
+    lanes = [(0, width - q.shape[2])] if per == 1 else [
+        (h * rope, _LANE - (h + 1) * rope) for h in range(per)]
+    q = jnp.concatenate([q[..., :rank]] + [
+        jnp.pad(q[..., rank:], ((0, 0), (0, 0), part)) for part in lanes],
+        axis=-1)
+    call = _latent_decode_walk_call(b, heads, rank, per, width,
+                                    cache.shape[1] * per, float(scale),
+                                    np.dtype(q.dtype).name, plan,
+                                    _interpret())
     # the stage a trace books the kernel's time to (a jit is no scope)
     with jax.named_scope("latent_decode_attention"):
         out = call(positions.astype(jnp.int32), q, cache.astype(q.dtype))
@@ -800,49 +914,79 @@ def _latent_decode_walk(q, cache, positions, rank: int, scale: float,
 
 
 @functools.lru_cache(maxsize=16)
-def _latent_decode_walk_call(b: int, heads: int, width: int, values: int,
-                             total: int, scale: float, dtype: str,
-                             plan: WalkPlan, interpret: bool):
+def _latent_decode_walk_call(b: int, heads: int, rank: int, per: int,
+                             width: int, total: int, scale: float,
+                             dtype: str, plan: WalkPlan, interpret: bool):
     """The jitted call of :func:`latent_decode_attention` for one shape,
     built once: a model's layers share the function, so a program that
     attends in five layers traces and lowers the kernel once (as
-    :func:`_gqa_decode_walk_call` does)."""
+    :func:`_gqa_decode_walk_call` does).  ``total`` positions in rows of
+    ``width`` for ``per`` of them each."""
     import jax.numpy as jnp
 
     jax, pl, pltpu = _pl()
     lat, (rows, slots) = _WALK_LATTICE, plan
     _walk_plan_check("latent_decode_attention", plan, total)
+    packed = per > 1
+    # a padded row's values that are not whole lanes come out of the
+    # whole row
+    values = rank if rank % _LANE == 0 else width
+    q_width = rank + per * _LANE if packed else width
 
     def kernel(pos_ref, q_ref, cache_ref, o_ref, buf, arrived, base_ref,
                m_ref, l_ref, acc_ref):
 
         def update(pos, slot, start, offset, size):
-            """The online softmax over ``size`` rows of buffer ``slot``
-            from row ``offset``: both products off the same rows."""
+            """The online softmax over ``size`` positions of buffer
+            ``slot`` from position ``offset``: both products off the
+            same rows, a part-block a position of a row."""
+            n, row = size // per, offset // per
+            if not isinstance(offset, int):   # a piece: whole cells in
+                row = pl.multiple_of(row, lat // per)
+            at = pl.ds(row, n)
+
+            def keys(h: int):
+                """Part ``h``'s scores ``(heads, n)`` and its values."""
+                if not packed:
+                    kb = buf[slot, at, :]                    # (n, width)
+                    return jax.lax.dot_general(
+                        q_ref[0], kb, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32), kb[:, :values]
+                kb = buf[slot, at, pl.ds(h * rank, rank)]    # (n, rank)
+                s = jax.lax.dot_general(
+                    q_ref[0, :, pl.ds(0, rank)], kb,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return s + jax.lax.dot_general(
+                    q_ref[0, :, pl.ds(rank + h * _LANE, _LANE)],
+                    buf[slot, at, pl.ds(per * rank, _LANE)],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32), kb
 
             def add(masked: bool):
-                kb = buf[slot, pl.ds(offset, size), :]       # (size, width)
-                s = jax.lax.dot_general(
-                    q_ref[0], kb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                if masked:                                # (heads, size)
-                    at = start * lat + offset + jax.lax.broadcasted_iota(
-                        jnp.int32, (heads, size), 1)
-                    s = jnp.where(at <= pos, s, -1e30)
+                parts = [keys(h) for h in range(per)]
+                scores = [s * scale for s, _ in parts]
+                if masked:                                # (heads, n)
+                    first = start * lat + offset \
+                        + per * jax.lax.broadcasted_iota(
+                            jnp.int32, (heads, n), 1)
+                    scores = [jnp.where(first + h <= pos, s, -1e30)
+                              for h, s in enumerate(scores)]
                 # running max / normaliser replicated across a lane
                 # width, as in flash_attention above
-                m_prev = m_ref[:]
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=-1, keepdims=True))
+                m_prev = m_new = m_ref[:]
+                for s in scores:
+                    m_new = jnp.maximum(m_new,
+                                        jnp.max(s, axis=-1, keepdims=True))
                 corr = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new[:, :1])
-                l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1,
-                                                     keepdims=True)
-                acc_ref[:] = acc_ref[:] * corr[:, :1] + jax.lax.dot_general(
-                    p.astype(kb.dtype), kb[:, :values],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                m_ref[:] = m_new
+                l_new, acc = l_ref[:] * corr, acc_ref[:] * corr[:, :1]
+                for s, (_, vb) in zip(scores, parts):
+                    p = jnp.exp(s - m_new[:, :1])
+                    l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
+                    acc = acc + jax.lax.dot_general(
+                        p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                l_ref[:], acc_ref[:], m_ref[:] = l_new, acc, m_new
 
             if size < rows:       # a piece of the last item: it may
                 add(True)         # hold the position
@@ -854,13 +998,14 @@ def _latent_decode_walk_call(b: int, heads: int, width: int, values: int,
 
         _walk_stream(pos_ref, ((cache_ref, buf),), arrived, base_ref,
                      (m_ref, l_ref, acc_ref), o_ref, update,
-                     total=total, window=total, plan=plan, ring=False)
+                     total=total, window=total, plan=plan, ring=False,
+                     cell_rows=lat // per)
 
-    buffer = (slots, rows, width)
+    buffer = (slots, rows // per, width)
     grid = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, heads, width), lambda i, pos: (i, 0, 0)),
+            pl.BlockSpec((1, heads, q_width), lambda i, pos: (i, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, heads, values), lambda i, pos: (i, 0, 0)),

@@ -4,9 +4,12 @@
 ``gqa_decode_attention`` on it, interpreted on the CPU: the kernel
 against its ``jnp`` mathematics over rings, dense caches, groups, heads
 and the positions where the walk changes shape; the rows the plan names
-against a brute-force count; and the three models' counters of them
-(``latent_decode_attention`` on the same walk against its mathematics:
-``tests/test_deepseek_v2.py``).  No number here is a rate."""
+against a brute-force count; and the three models' counters of them.
+``latent_decode_attention`` walks the same way over a latent cache whose
+rows pack two positions at the published sizes (rank 512, rope 64) and
+hold one, padded to whole lanes, at others: both forms against the
+kernel's mathematics here, more of the padded form's shapes in
+``tests/test_deepseek_v2.py``.  No number here is a rate."""
 
 import json
 import os
@@ -105,8 +108,10 @@ def test_the_walk_is_the_reference(case):
     (4096, 1024, (2048, 4)), (256, 2048, (128, 8)),
     (384, 1024, (128, 8)), (768, 64 << 10, (128, 3)),
     (16640, 1280, (1280, 5)), (4096, 1280, (1024, 6)),
+    (16640, 1152, (1664, 4)), (4096, 1152, (1024, 7)),
 ], ids=["smallthinker-ring", "smallthinker-full", "nemotron3", "two-cells",
-        "three-cells", "wide-rows", "dsv2-latent", "longcat-latent"])
+        "three-cells", "wide-rows", "rows-of-640-in-16640",
+        "rows-of-640-in-4096", "dsv2-latent", "longcat-latent"])
 def test_the_plan_follows_what_the_call_sees(total, row_bytes, plan):
     got = kernels.decode_walk_plan(total, row_bytes)
     assert tuple(got) == plan
@@ -115,12 +120,16 @@ def test_the_plan_follows_what_the_call_sees(total, row_bytes, plan):
         1 << k for k in reversed(range((got.cells - 1).bit_length())))
 
 
-def test_the_latent_cells_plan_is_chunks_of_ten_cells_in_five_buffers():
-    """`dsv2.decode16k`'s caches, `[32, 16640, 640]` bf16: 130 cells, of
-    whose divisors ten is the most that 2 MiB hold (1.6 MB a chunk), so
-    a last item comes in pieces of 8, 4, 2 and 1 cells."""
-    plan = kernels.decode_walk_plan(16640, 640 * 2)
-    assert plan == WalkPlan(1280, 5) and plan.cells == 10
+def test_the_latent_cells_plan_is_chunks_of_thirteen_cells_in_four_buffers():
+    """`dsv2.decode16k`'s caches, `[32, 8320, 1152]` bf16, two positions
+    a row: 16,640 positions of 1,152 bytes as stored, 130 cells, of whose
+    divisors thirteen is the most that 2 MiB hold (1.9 MB a chunk of 832
+    rows), so a last item comes in pieces of 8, 4, 2 and 1 cells, the
+    last of them 64 rows."""
+    per, width = kernels.latent_cache_row(512, 64)
+    assert (per, width) == (2, 1152)
+    plan = kernels.decode_walk_plan(16640, width // per * 2)
+    assert plan == WalkPlan(1664, 4) and plan.cells == 13
     assert plan.pieces == (8, 4, 2, 1)
     for count in range(1, plan.cells):
         got = [(int(kernels.walk_piece(count, size)[1]), size)
@@ -130,29 +139,150 @@ def test_the_latent_cells_plan_is_chunks_of_ten_cells_in_five_buffers():
             sum(size for _, size in got[:i]) for i in range(len(got))]
 
 
-def test_the_latent_kernel_at_64_heads_on_caches_of_4096_rows():
-    """`longcat.decode4k`'s call, `[128, 64, 640]` on `[128, 4096, 640]`
-    bf16 (chunks of 1,024 rows in six buffers: the plan follows the
-    cache's rows and a row's bytes, not the streams), interpreted here on
-    five of its 128 streams: two whole chunks, the first row of a third,
-    three whole, a fourth of 4 + 1 cells, the cache's last row."""
-    plan = kernels.decode_walk_plan(4096, 640 * 2)
-    assert plan == WalkPlan(1024, 6) and plan.pieces == (4, 2, 1)
-    at = [2047, 2048, 3071, 3072 + 513, 4095]
+@pytest.mark.parametrize("rank,rope,row", [
+    (512, 64, (2, 1152)), (128, 32, (4, 640)), (256, 16, (8, 2176)),
+    (16, 8, (1, 128)), (24, 8, (1, 128)), (512, 128, (1, 640)),
+    (512, 96, (1, 640)), (128, 8, (1, 256)), (500, 64, (1, 640)),
+], ids=["published", "four-a-row", "eight-a-row", "toy-dsv2", "toy-longcat",
+        "rope-a-whole-tile", "rope-not-dividing-a-lane",
+        "cells-under-a-tile-of-rows", "rank-not-whole-lanes"])
+def test_a_latent_row_packs_where_every_slice_is_whole_tiles(rank, rope, row):
+    """The layout's rule, by sizes alone: positions a row and the row's
+    width.  Packed rows store ``rank + rope`` values a position; a
+    padded row's width is whole lanes."""
+    per, width = kernels.latent_cache_row(rank, rope)
+    assert (per, width) == row and width % LAT == 0
+    assert width == per * (rank + rope) if per > 1 else width >= rank + rope
+    rows = jnp.asarray(np.random.default_rng(0).normal(
+        size=(2, 16, rank + rope)), jnp.float32)
+    packed = kernels.latent_pack(rows, rank)
+    assert packed.shape == (2, 16 // per, width)
+    assert np.array_equal(np.asarray(
+        kernels.latent_unpack(packed, rank, rope)), np.asarray(rows))
+    # position p of a stream: row p // per, its latent part at lanes
+    # (p % per) * rank, its rotary key at per * rank + (p % per) * rope
+    for p in (0, 1, 5):
+        r, h = divmod(p, per)
+        assert np.array_equal(
+            np.asarray(packed[1, r, h * rank:(h + 1) * rank]),
+            np.asarray(rows[1, p, :rank]))
+        at = per * rank + h * rope
+        assert np.array_equal(np.asarray(packed[1, r, at:at + rope]),
+                              np.asarray(rows[1, p, rank:]))
+    # a token placed at a position leaves the row's other positions
+    old = packed[:, 1]
+    new = jnp.ones((2, rank + rope), jnp.float32)
+    at = np.array([per, 2 * per - 1], np.int32)
+    placed = kernels.latent_unpack(
+        kernels.latent_place(old, new, at, rank)[:, None], rank, rope)
+    want = np.asarray(rows[:, per:2 * per]).copy()
+    want[0, 0], want[1, per - 1] = 1.0, 1.0
+    assert np.array_equal(np.asarray(placed), want)
+
+
+#: ``heads, rank, queries' width (rank + rope), positions of the cache,
+#: dtype, plan, streams at``; without a plan the call derives its own.
+LATENT_WALKS = {
+    # `dsv2.decode16k`'s call, four of its streams: position 0, the last
+    # row of the plan's chunk of 1,664, the first of the next, the
+    # cache's last
+    "published-32-heads-16640-by-its-plan": (
+        32, 512, 576, 16640, "bfloat16", None, [0, 1663, 1664, 16639]),
+    # `longcat.decode4k`'s (chunks of 1,024 positions in seven buffers:
+    # the plan follows the cache's positions and a position's bytes,
+    # not the streams): two whole chunks, the first row of a third, three
+    # whole, a fourth of 4 + 1 cells, the cache's last row
+    "published-64-heads-4096-by-its-plan": (
+        64, 512, 576, 4096, "bfloat16", None,
+        [2047, 2048, 3071, 3072 + 513, 4095]),
+    # position 0, the first and the second position of a packed row, a
+    # lattice cell's last row and the next one's first, a chunk's last
+    # and first, the cache's last two
+    "published-32-heads-a-rows-both-positions": (
+        32, 512, 576, 1024, "float32", WalkPlan(256, 3),
+        [0, 1, 2, 3, 127, 128, 255, 256, 1022, 1023]),
+    # chunks of ten cells: a last item of 8 + 1 cells, of 4 + 2 + 1, of
+    # one cell (64 rows) after a whole chunk, and two whole chunks, at
+    # odd and even positions
+    "published-64-heads-pieces-8-4-2-1": (
+        64, 512, 576, 2560, "bfloat16", WalkPlan(1280, 3),
+        [1040, 1041, 868, 1285, 2559]),
+    "published-one-item-before-many-heads-padded": (
+        5, 512, 576, 1024, "float32", WalkPlan(256, 4), [3, 1000, 130, 700]),
+    "published-whole-cache-a-chunk": (
+        16, 512, 576, 384, "bfloat16", WalkPlan(384, 2), [4, 131, 383]),
+    "four-positions-a-row": (
+        8, 128, 160, 1024, "float32", WalkPlan(512, 3),
+        [0, 1, 2, 3, 4, 127, 128, 511, 512, 701, 1023]),
+    "eight-positions-a-row": (
+        8, 128, 144, 512, "float32", None, [0, 7, 8, 300, 511]),
+    # a row a position, padded to whole lanes: the toys' (values out of
+    # the whole row), and a rank of whole tiles beside a rotary part that
+    # does not divide a lane
+    "padded-toy-f32": (2, 16, 24, 256, "float32", None, [0, 1, 127, 128, 255]),
+    "padded-toy-bf16": (3, 16, 24, 384, "bfloat16", WalkPlan(128, 3),
+                        [5, 172, 383]),
+    "padded-queries-as-wide-as-the-row": (
+        3, 24, 128, 256, "float32", None, [5, 172, 255]),
+    "padded-rank-of-whole-tiles": (
+        8, 128, 256, 768, "float32", WalkPlan(384, 2), [0, 383, 384, 767]),
+}
+
+
+@pytest.mark.parametrize("case", list(LATENT_WALKS))
+def test_the_latent_walk_is_the_reference(case):
+    """The kernel (interpreted) against its mathematics on the rows as
+    the cache holds them, and that against the same mathematics on a
+    plain ``[streams, positions, values]`` array the packing never
+    touched."""
+    heads, rank, wide, total, dtype, plan, at = LATENT_WALKS[case]
+    per, width = kernels.latent_cache_row(rank, wide - rank)
     rng = np.random.default_rng(4)
-    q = jnp.asarray(rng.normal(size=(len(at), 64, 640)), jnp.bfloat16)
-    cache = jnp.asarray(rng.normal(size=(len(at), 4096, 640)), jnp.bfloat16)
+    b = len(at)
+    q = jnp.asarray(rng.normal(size=(b, heads, wide)), dtype)
+    plain = jnp.asarray(rng.normal(size=(b, total, wide)), dtype)
+    cache = kernels.latent_pack(plain, rank)
+    assert cache.shape == (b, total // per, width)
+    assert kernels.latent_decode_attention_refusal(
+        q.shape, cache.shape, rank) is None
+    at = jnp.asarray(at, jnp.int32)
+    scale = 192 ** -0.5
+    if plan is None:
+        got = kernels.latent_decode_attention(q, cache, at, rank, scale)
+    else:
+        got = kernels._latent_decode_walk(q, cache, at, rank, scale, plan)
+    want = kernels.latent_decode_attention_reference(q, cache, at, rank,
+                                                     scale)
+    assert got.shape == (b, heads, rank) and got.dtype == jnp.float32
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    assert np.allclose(np.asarray(got), np.asarray(want), atol=tol)
+    # the same mathematics on rows nothing packed: zeros to whole lanes
+    pad = ((0, 0), (0, 0), (0, -wide % LAT))
+    unpacked = kernels.latent_decode_attention_reference(
+        jnp.pad(q, pad), jnp.pad(plain, pad), at, rank, scale)
+    assert np.allclose(np.asarray(want), np.asarray(unpacked), atol=tol / 10)
+    # what the walk fetches for them: every live cell whole
+    assert int(kernels.decode_rows_fetched(at, total, total)) \
+        == sum((int(p) // 128 + 1) * 128 for p in at)
+
+
+def test_the_latent_kernel_at_64_heads_on_caches_of_4096_positions():
+    """`longcat.decode4k`'s call, `[128, 64, 576]` on `[128, 2048, 1152]`
+    bf16: the refusal function takes the cell's shapes, the plan is
+    chunks of 1,024 positions (512 rows) in seven buffers with pieces of
+    4, 2 and 1 cells, and the same call on the rows of 640 values a
+    position that the cache had before is an error that names the row
+    it wants."""
+    assert kernels.latent_decode_attention_refusal(
+        (128, 64, 576), (128, 2048, 1152), 512) is None
+    plan = kernels.decode_walk_plan(4096, 1152)
+    assert plan == WalkPlan(1024, 7) and plan.pieces == (4, 2, 1)
+    said = kernels.latent_decode_attention_refusal(
+        (128, 64, 576), (128, 4096, 640), 512)
+    assert "rows of 1152 for 2 positions" in said and "not 640" in said
+    # queries as wide as a padded row still meet a padded row
     assert kernels.latent_decode_attention_refusal(
         (128, 64, 640), (128, 4096, 640), 512) is None
-    at = jnp.asarray(at, jnp.int32)
-    got = kernels.latent_decode_attention(q, cache, at, 512, 192 ** -0.5)
-    want = kernels.latent_decode_attention_reference(q, cache, at, 512,
-                                                     192 ** -0.5)
-    assert got.shape == (5, 64, 512) and got.dtype == jnp.float32
-    assert np.allclose(np.asarray(got), np.asarray(want), atol=2e-2)
-    # what the walk fetches for them: every live cell whole
-    assert int(kernels.decode_rows_fetched(at, 4096, 4096)) \
-        == sum((int(p) // 128 + 1) * 128 for p in at)
 
 
 @pytest.mark.parametrize("kernel", ["gqa", "latent"])
@@ -167,6 +297,9 @@ def test_a_plan_that_does_not_divide_the_cache_is_an_error(kernel):
                 kernels._gqa_decode_walk(q, k, k, at, 384, 1.0, plan)
             else:
                 kernels._latent_decode_walk(q[0], k[0], at, 128, 1.0, plan)
+                kernels._latent_decode_walk(
+                    jnp.zeros((1, 8, 576)), jnp.zeros((1, 192, 1152)), at,
+                    512, 1.0, plan)
 
 
 # -- the rows the plan names ------------------------------------------------------------
@@ -323,15 +456,21 @@ def test_smallthinker_counts_the_walk_once_a_layer_of_a_kind():
                                             units["full_bytes_fetched"]]
 
 
-def test_deepseek_v2_counts_the_walk_once_a_step():
+@pytest.mark.parametrize("sizes", [{}, {"kv_lora_rank": 512,
+                                       "qk_rope_head_dim": 64}],
+                         ids=["toy", "published-latent-sizes"])
+def test_deepseek_v2_counts_the_walk_once_a_step(sizes):
     """The toy's three layers over latent caches of 384 positions
     decode through the kernel: one step adds the walk's rows of ONE
     layer to ``cache_rows_fetched``, and the units count the layers at
-    the row the cache HOLDS (padded to whole lanes) beside the rows in
-    use at the row's ``latent`` values."""
+    the values a position takes as the cache HOLDS it: the toy's 24
+    padded to a lane tile, beside the rows in use at ``latent`` values;
+    at the published rank and rotary size the 576 themselves, two
+    positions a row of 1,152, so fetched over used is the walk's
+    partial cells and nothing else."""
     with open(os.path.join(REPO, "tests", "benchmark", "data",
                            "toy_dsv2.json")) as f:
-        cfg = dsv2.DeepSeekV2Config.from_dict(json.load(f))
+        cfg = dsv2.DeepSeekV2Config.from_dict(dict(json.load(f), **sizes))
     params = dsv2.init_params(cfg, jax.random.PRNGKey(1), jnp.float32)
     state = dsv2.init_state(cfg, params, 3, 384)
     positions = np.array([0, 127, 300], np.int32)
@@ -346,7 +485,15 @@ def test_deepseek_v2_counts_the_walk_once_a_step():
         == (1 + 1 + 3) * LAT \
         == int(kernels.decode_rows_fetched(positions, 384, 384))
     units = dsv2.counter_units(cfg, state)
-    assert cfg.layers == 3 and cfg.row == LAT > cfg.latent
+    assert cfg.layers == 3
+    if sizes:
+        assert cfg.row == cfg.latent == 576
+        assert [c.shape for c in state["cache"]] == [(3, 192, 1152)] * 3
+    else:
+        assert cfg.row == LAT > cfg.latent == 24
+        assert [c.shape for c in state["cache"]] == [(3, 384, 128)] * 3
+    # a cache's bytes: streams x positions x the values a position takes
+    assert state["cache"][0].nbytes == 3 * 384 * cfg.row * 4
     assert units["cache_bytes_read"] == (
         "cache_rows_read", cfg.latent * 4 * cfg.layers)
     assert units["cache_bytes_fetched"] == (

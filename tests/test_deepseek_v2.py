@@ -52,11 +52,19 @@ def _f32(tree):
     return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
 
 
-@pytest.fixture(scope="module")
-def served(toy, files):
+#: the published ``kv_lora_rank`` and ``qk_rope_head_dim`` on the toy's
+#: few heads and layers: a cache row packs two positions there
+PUBLISHED_LATENT = {"kv_lora_rank": 512, "qk_rope_head_dim": 64}
+
+
+@pytest.fixture(scope="module", params=["toy", "published-latent-sizes"])
+def served(request, toy, files):
     """Every stream's 20 tokens: 12 prefilled in two chunks (the second
-    padded), then 8 decode steps through the cache, in float32.
+    padded), then 8 decode steps through the cache, in float32 (from
+    position 12 on, even and odd, across the third chunk's start at 16).
     ``[step][stream]`` logits, and the token ids fed."""
+    if request.param != "toy":
+        toy = dict(toy, **PUBLISHED_LATENT)
     cfg = dsv2.DeepSeekV2Config.from_dict(toy)
     params = _f32(files["weights"].make(toy, SEED))
     rng = np.random.default_rng(5)
@@ -80,17 +88,18 @@ def served(toy, files):
         assert np.array_equal(np.asarray(greedy),
                               np.asarray(lg).argmax(-1) + cfg.vocab0)
         logits.append(np.asarray(lg))
-    return {"logits": logits, "ids": ids, "state": state, "cfg": cfg}
+    return {"logits": logits, "ids": ids, "state": state, "cfg": cfg,
+            "raw": toy}
 
 
 @pytest.mark.parametrize("step", range(STEPS))
 def test_prefill_then_decode_is_the_reference_at_every_position(
-        toy, files, served, step):
+        files, served, step):
     """Prefill in two chunks, then decode steps through the cache,
     against the reference's full forward (expanded form, no cache) over
     the same history, at every decoded position."""
     histories = [served["ids"][r, :12 + step + 1] for r in range(STREAMS)]
-    ref = files["reference"].forward_last(toy, SEED, histories)
+    ref = files["reference"].forward_last(served["raw"], SEED, histories)
     got = served["logits"][step]
     assert np.abs(got - ref).max() <= 2e-5 * max(1.0, np.abs(ref).max())
 
@@ -115,6 +124,14 @@ def test_the_steps_count_what_they_read(served):
         "cache_bytes_read": ("cache_rows_read", cfg.latent * 4 * cfg.layers),
         "cache_bytes_fetched": ("cache_rows_fetched",
                                 cfg.row * 4 * cfg.layers)}
+    # a position as the cache holds it: the toy's 24 values in a lane
+    # tile, the published 576 in half a row of 1,152
+    packed = cfg.kv_lora_rank == 512
+    assert (cfg.latent, cfg.row) == ((576, 576) if packed else (24, 128))
+    for cache in served["state"]["cache"]:
+        assert cache.shape == ((STREAMS, 64, 1152) if packed
+                               else (STREAMS, 128, 128))
+        assert cache.nbytes == STREAMS * 128 * cfg.row * 4
 
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 0.03)])
@@ -144,14 +161,20 @@ def test_full_forward_is_the_reference(toy, files, dtype, tol):
     assert np.median(err) <= tol and err.max() <= 10 * tol, err
 
 
-def test_absorbed_is_expanded(toy, files):
+@pytest.mark.parametrize("sizes", [{}, PUBLISHED_LATENT],
+                         ids=["toy", "published-latent-sizes"])
+def test_absorbed_is_expanded(toy, files, sizes):
     """Both forms of latent attention are one function of the same
     weights: a token decoded through the absorbed form gives what the
-    expanded form gives for it as the last row of a chunk."""
+    expanded form gives for it as the last row of a chunk, and both
+    leave the same cache: a decode step's token in the second half of
+    a packed row beside the prefilled first, the other rows as they
+    were."""
+    toy = dict(toy, **sizes)
     cfg = dsv2.DeepSeekV2Config.from_dict(toy)
     p = _f32(files["weights"].make(toy, SEED))["layers"][1]["attn"]
     x = jax.random.normal(jax.random.PRNGKey(3), (8, cfg.hidden_size))
-    cache = jnp.zeros((2, 128, cfg.row), jnp.float32)
+    cache = mla.init_cache(cfg, 2, 128, jnp.float32)
     one = jnp.int32(1)
     expanded, cache_a = mla.attn_prefill(cfg, p, x, cache, one,
                                           jnp.int32(0))
@@ -163,10 +186,24 @@ def test_absorbed_is_expanded(toy, files):
                                          jnp.array([0, 7], jnp.int32))
     assert np.allclose(np.asarray(absorbed[1]), np.asarray(expanded[7]),
                        atol=2e-5)
-    assert np.allclose(np.asarray(cache_b[1, :8]), np.asarray(cache_a[1, :8]),
+    assert np.allclose(np.asarray(cache_b[1]), np.asarray(cache_a[1]),
                        atol=1e-6)
-    # a cache row is (c_kv, k_r) and zeros to whole lanes
-    assert cfg.row == 128 and not np.asarray(cache_a[1, :8, cfg.latent:]).any()
+    rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    rows = np.asarray(kernels.latent_unpack(cache_a[1], rank, rope))
+    assert rows.shape == (128, cfg.latent)
+    assert np.abs(rows[:8]).min(-1).max() > 0 and not rows[8:].any()
+    if sizes:
+        # two positions a row and nothing else in it
+        assert cache_a.shape == (2, 64, 2 * cfg.latent) and cfg.row == 576
+        assert np.array_equal(np.asarray(cache_a[1, 3, :rank]), rows[6, :rank])
+        assert np.array_equal(np.asarray(cache_a[1, 3, rank:2 * rank]),
+                              rows[7, :rank])
+        assert np.array_equal(np.asarray(cache_a[1, 3, 2 * rank:]),
+                              np.concatenate([rows[6, rank:], rows[7, rank:]]))
+    else:
+        # a cache row is (c_kv, k_r) and zeros to whole lanes
+        assert cfg.row == 128
+        assert not np.asarray(cache_a[1, :8, cfg.latent:]).any()
 
 
 #: ``heads, width, rank, positions of the cache, dtype, plan, streams at``;
@@ -222,15 +259,23 @@ def test_latent_kernel_is_its_reference(case):
 
 
 @pytest.mark.parametrize("q_shape,cache_shape,rank,says", [
-    ((3, 8, 192), (3, 512, 192), 128, "whole lanes"),
+    ((3, 8, 192), (3, 512, 192), 128,
+     "want cache rows of 384 for 2 positions, whole lanes of 128, not 192"),
+    ((3, 8, 200), (3, 512, 200), 128, "whole lanes"),
     ((3, 8, 256), (3, 512, 128), 128, "whole lanes"),
     ((3, 8, 256), (3, 100, 256), 128,
      "100 cache positions are not whole lattice cells of 128"),
     ((3, 8, 256), (3, 0, 256), 128, "0 cache positions"),
-    ((3, 8, 256), (3, 512, 256), 384, "hold the 384 values"),
+    ((3, 8, 256), (3, 512, 256), 384, "do not hold the 384 latent ones"),
     ((3, 8, 256), (4, 512, 256), 128, "are not [B, heads, width]"),
-], ids=["row-not-lanes", "widths-differ", "positions-not-lanes",
-        "no-positions", "rank-over-width", "streams-differ"])
+    ((3, 8, 576), (3, 512, 640), 512,
+     "want cache rows of 1152 for 2 positions"),
+    ((3, 8, 576), (3, 100, 1152), 512,
+     "200 cache positions are not whole lattice cells of 128"),
+], ids=["packed-queries-on-padded-rows", "row-not-lanes", "widths-differ",
+        "positions-not-lanes", "no-positions", "rank-over-width",
+        "streams-differ", "published-queries-on-rows-of-640",
+        "packed-positions-not-lanes"])
 def test_latent_kernel_refuses_with_an_error(q_shape, cache_shape, rank,
                                              says):
     """No second path: a shape the kernel cannot take is an error that

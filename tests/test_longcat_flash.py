@@ -25,6 +25,7 @@ import jax.numpy as jnp  # noqa: E402
 from benchmark.run import Loader  # noqa: E402
 from nnstreamer_tpu.models import longcat_flash as lc  # noqa: E402
 from nnstreamer_tpu.models import mla, moe  # noqa: E402
+from nnstreamer_tpu.ops import kernels  # noqa: E402
 
 SEED = 11
 CHUNK, POSITIONS = 8, 48
@@ -156,6 +157,66 @@ def test_both_caches_of_every_layer_are_written_and_differ(sound, toy):
                 assert np.abs(cache[row, :n, :cfg.latent]).min(-1).max() > 0
                 assert not cache[row, :, cfg.latent:].any()
         assert not np.allclose(pair[0][0, :9], pair[1][0, :9])
+
+
+#: the published ``kv_lora_rank`` and ``qk_rope_head_dim`` on the toy's
+#: few heads and layers: a cache row packs two positions there
+PUBLISHED_LATENT = {"kv_lora_rank": 512, "qk_rope_head_dim": 64}
+
+
+@pytest.fixture(scope="module")
+def packed(toy, files):
+    """The same three streams at the published latent sizes: prefilled
+    in chunks of 8 to 13, 24 and 9 tokens, then six decode steps from an
+    odd position across a chunk's start (13 .. 18), from an even one
+    that starts a chunk (24 .. 29) and from 9, in float32."""
+    raw = dict(toy, **PUBLISHED_LATENT)
+    model = _model(raw, _f32(files["weights"].make(raw, SEED)))
+    ids = _ids(model["cfg"], (3, max(LENGTHS) + STEPS + 1), 5)
+    state = _prefilled(model, ids)
+    prefilled = jax.tree_util.tree_map(np.asarray, state["cache"])
+    state, answer = _answer(model, state, ids)
+    return {"model": model, "ids": ids, "answer": answer,
+            "prefilled": prefilled, "state": jax.device_get(state),
+            "ref": _reference(files, raw, ids)}
+
+
+def test_prefill_then_decode_agrees_at_the_published_latent_sizes(packed):
+    assert _rel(packed["answer"], packed["ref"]) < F32_TOL
+
+
+def test_a_packed_cache_holds_two_positions_a_row_and_nothing_else(packed):
+    cfg = packed["model"]["cfg"]
+    rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    assert (cfg.latent, cfg.row) == (576, 576)
+    for before, after in zip(packed["prefilled"], packed["state"]["cache"]):
+        for was, cache in zip(before, after):
+            # streams x positions x 576 values: a lattice cell of 128
+            # positions is 64 rows
+            assert cache.shape == (3, 64, 1152)
+            assert cache.nbytes == 3 * 128 * 576 * 4
+            rows = np.asarray(kernels.latent_unpack(cache, rank, rope))
+            for row, n in enumerate(LENGTHS):
+                assert np.abs(rows[row, :n + STEPS]).min(-1).max() > 0
+                # a decode step's token went into its own half of a row:
+                # what the chunks wrote is what it was
+                assert np.array_equal(
+                    np.asarray(kernels.latent_unpack(
+                        was, rank, rope))[row, :n], rows[row, :n])
+    # a chunk padded beyond its prompt wrote to its end, no further
+    rows = np.asarray(kernels.latent_unpack(
+        packed["state"]["cache"][0][0], rank, rope))
+    assert not rows[:, 32:].any()
+    counters = packed["state"]["counters"]
+    assert counters["cache_rows_read"] == sum(
+        n + j + 1 for n in LENGTHS for j in range(STEPS))
+    assert counters["cache_rows_fetched"] == STEPS * 3 * 128
+    units = lc.counter_units(cfg, packed["state"])
+    caches = cfg.layers * lc.SUBS
+    assert units["cache_bytes_read"] == ("cache_rows_read",
+                                         cfg.latent * 4 * caches)
+    assert units["cache_bytes_fetched"] == ("cache_rows_fetched",
+                                            cfg.latent * 4 * caches)
 
 
 def test_bfloat16_in_place_of_float32_fails_the_float32_comparison(

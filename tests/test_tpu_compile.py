@@ -58,20 +58,23 @@ def test_gqa_decode_attention_compiles_at_the_cells_shapes(
 def test_latent_decode_attention_compiles_at_the_cells_shapes(
         one_chip, monkeypatch):
     """`dsv2.decode16k`'s call: 32 streams, 32 heads, caches of 16,640
-    rows of 640 bf16 values walked in chunks of 1,280 rows through five
-    buffers (8.2 MB of fast memory, stated by the call), as a Mosaic
-    kernel; one custom call, no copy of the cache beside it."""
+    positions, two a row of 1,152 bf16 values, walked in chunks of 1,664
+    positions (832 rows: six and a half lane tiles of scores a part)
+    through four buffers (7.7 MB of fast memory, stated by the call), a
+    last item's pieces down to 64 rows, as a Mosaic kernel; one custom
+    call, no copy of the cache beside it."""
     monkeypatch.setattr(kernels, "_interpret", lambda: False)
 
     def shape(dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    assert kernels.decode_walk_plan(16640, 1280) == kernels.WalkPlan(1280, 5)
+    assert kernels.latent_cache_row(512, 64) == (2, 1152)
+    assert kernels.decode_walk_plan(16640, 1152) == kernels.WalkPlan(1664, 4)
     fn = jax.jit(functools.partial(kernels.latent_decode_attention,
                                    rank=512, scale=0.1147))
-    compiled = fn.lower(shape((32, 32, 640)), shape((32, 16640, 640)),
+    compiled = fn.lower(shape((32, 32, 576)), shape((32, 8320, 1152)),
                         shape((32,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
@@ -357,78 +360,124 @@ def test_the_window128_cells_programs_copy_no_cache(one_chip, monkeypatch,
 
 def test_latent_decode_attention_compiles_at_64_heads(one_chip, monkeypatch):
     """`longcat.decode4k`'s call: 128 streams of 64 heads on caches of
-    4,096 rows of 640 bf16 values, walked in chunks of 1,024 rows
-    through six buffers (7.9 MB of fast memory, stated by the call), as
-    a Mosaic kernel; one custom call, no copy of the cache beside it."""
+    4,096 positions, two a row of 1,152 bf16 values, walked in chunks of
+    1,024 positions (512 rows) through seven buffers (8.3 MB of fast
+    memory, stated by the call), as a Mosaic kernel; one custom call, no
+    copy of the cache beside it."""
     monkeypatch.setattr(kernels, "_interpret", lambda: False)
 
     def shape(dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    assert kernels.decode_walk_plan(4096, 1280) == kernels.WalkPlan(1024, 6)
+    assert kernels.decode_walk_plan(4096, 1152) == kernels.WalkPlan(1024, 7)
     fn = jax.jit(functools.partial(kernels.latent_decode_attention,
                                    rank=512, scale=192 ** -0.5))
-    compiled = fn.lower(shape((128, 64, 640)), shape((128, 4096, 640)),
+    compiled = fn.lower(shape((128, 64, 576)), shape((128, 2048, 1152)),
                         shape((128,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("entry,temp_mb", [("decode", 96), ("prefill", 1536)])
-def test_the_two_cache_cells_programs_fit_the_chip(one_chip, monkeypatch,
-                                                   capsys, entry, temp_mb):
-    """`longcat.decode4k`'s two programs at the cell's sizes (four layers
-    of two latent-attention sub-blocks, 128 streams, 4,096 positions,
-    chunks of 2,048; 7.9 GB of weights and 5.4 GB of state as
-    arguments): the eight caches are updated in the donated buffers (one
-    is 671 MB, so a copy of one shows in the temporaries), weights, state
-    and temporaries fit the chip, and a decode step attends through the
-    kernel in all eight caches and runs the grouped product for the
-    routed experts of four layers and, as one group of one expert, for
-    the eight dense MLPs (a chunk's dense MLPs are XLA's products): 20
-    custom calls a decode step, 4 a chunk, none fallen back to the loop."""
+def _latent_cell(name: str):
+    """A latent-cache cell's configuration, entry points and sizes:
+    ``(cfg, params, state, {entry: (function, inputs' lengths)}, custom
+    calls a chunk, more a decode step)``."""
     import json
     import os
 
+    from nnstreamer_tpu.models import deepseek_v2 as dsv2
     from nnstreamer_tpu.models import longcat_flash as lc
 
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            name + ".json")) as f:
+        raw = json.load(f)
+    if name == "deepseek_v2_share4":
+        # five layers, four of them of routed experts: a decode step
+        # attends through the kernel in five caches
+        cfg = dsv2.DeepSeekV2Config.from_dict(raw)
+        params = jax.eval_shape(lambda: dsv2.init_params(
+            cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+        state = jax.eval_shape(lambda: dsv2.init_state(cfg, params, 32,
+                                                       16640))
+        return cfg, params, state, {
+            "decode": (dsv2.decode, (32, 32)),
+            "prefill": (dsv2.prefill, (2048, 1, 1))}, 4, 5
+    cfg = lc.LongCatFlashConfig.from_dict(raw)
+    params = jax.eval_shape(lambda: lc.init_params(cfg, 0))
+    state = jax.eval_shape(lambda: lc.init_state(cfg, params, 128, 4096))
+    return cfg, params, state, {
+        "decode": (lc.decode, (128, 128)),
+        "prefill": (lc.prefill, (2048, 1, 1))}, 4, 8 + 8
+
+
+@pytest.mark.parametrize("entry,temp_mb", [("decode", 96), ("prefill", 1536)])
+@pytest.mark.parametrize("cell", ["deepseek_v2_share4",
+                                  "longcat_flash_omni_share64"])
+def test_the_two_cache_cells_programs_fit_the_chip(one_chip, monkeypatch,
+                                                   capsys, cell, entry,
+                                                   temp_mb):
+    """Both latent-cache cells' two programs at the cells' sizes.
+    `dsv2.decode16k`: five layers, 32 streams, 16,640 positions, chunks
+    of 2,048; 9.3 GB of weights and 3.07 GB of state.  `longcat.decode4k`:
+    four layers of two latent-attention sub-blocks, 128 streams, 4,096
+    positions, chunks of 2,048; 7.9 GB of weights and 4.83 GB of state.
+    A cache is `[streams, positions / 2, 1152]`, 576 values a position
+    (613 MB and 604 MB each), and every cache is updated in the donated
+    buffers: neither a chunk's packed rows, nor the key blocks a chunk
+    unpacks, nor a decode step's read, place and write of one row a
+    stream copies or relays out a cache (one would show in the
+    temporaries), weights, state and temporaries fit the chip, and a
+    decode step attends through the kernel in every cache (five; eight)
+    beside the grouped products (four; four and, as one group of one
+    expert, the eight dense MLPs): 9 and 20 custom calls a decode step,
+    4 a chunk, none fallen back to the loop."""
     monkeypatch.setattr(kernels, "_interpret", lambda: False)
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "configs",
-        "longcat_flash_omni_share64.json")
-    with open(path) as f:
-        cfg = lc.LongCatFlashConfig.from_dict(json.load(f))
+    cfg, params, state, entries, chunk_calls, step_calls = _latent_cell(cell)
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip), tree)
 
-    params = jax.eval_shape(lambda: lc.init_params(cfg, 0))
-    state = jax.eval_shape(lambda: lc.init_state(cfg, params, 128, 4096))
-
     def nbytes(tree):
         return sum(a.size * a.dtype.itemsize
                    for a in jax.tree_util.tree_leaves(tree))
 
-    assert 7.92e9 < nbytes(params) < 7.94e9
-    assert 5.36e9 < nbytes(state) < 5.38e9
-    assert [c.shape for pair in state["cache"] for c in pair] \
-        == [(128, 4096, 640)] * 8
+    caches = [c for c in jax.tree_util.tree_leaves(state["cache"])]
+    if cell == "deepseek_v2_share4":
+        assert 9.28e9 < nbytes(params) < 9.30e9
+        assert [c.shape for c in caches] == [(32, 8320, 1152)] * 5
+    else:
+        assert 7.92e9 < nbytes(params) < 7.94e9
+        assert [c.shape for c in caches] == [(128, 2048, 1152)] * 8
+    # streams x positions x 576 values in bf16, and the counters
+    streams, rows, _ = caches[0].shape
+    assert nbytes(state) - 64 < len(caches) * streams * rows * 2 * 576 * 2 \
+        <= nbytes(state)
+    one_cache = nbytes(caches[0])
 
-    def i32(n):
-        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
-
-    fn, inputs = {"decode": (lc.decode, [i32(128), i32(128)]),
-                  "prefill": (lc.prefill, [i32(2048), i32(1), i32(1)])}[entry]
+    fn, lengths = entries[entry]
+    inputs = [jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+              for n in lengths]
     compiled = jax.jit(functools.partial(fn, cfg), donate_argnums=(1,)) \
         .lower(on_chip(params), on_chip(state), *inputs).compile()
     memory = compiled.memory_analysis()
     with capsys.disabled():
-        print(f"\nlongcat.decode4k {entry}: {memory}")
+        print(f"\n{cell} {entry}: {memory}")
     assert memory.alias_size_in_bytes >= nbytes(state)
     assert memory.temp_size_in_bytes < temp_mb << 20
+    if entry == "decode":
+        # nothing the size of a cache beside the caches
+        assert memory.temp_size_in_bytes < one_cache // 4
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 15.5e9
-    assert compiled.as_text().count("tpu_custom_call") == 4 + (
-        8 + 8 if entry == "decode" else 0)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == chunk_calls + (
+        step_calls if entry == "decode" else 0)
+    # no copy, transposition or relayout of a cache's shape
+    shape = "[" + ",".join(str(n) for n in caches[0].shape) + "]"
+    moved = [line.strip()[:120] for line in text.splitlines()
+             if "bf16" + shape in line.split(" = ", 1)[-1].split("(")[0]
+             and (" copy(" in line or " transpose(" in line)]
+    assert not moved, moved

@@ -12,7 +12,7 @@ experts ``[expert0, expert0 + experts)`` (whole groups of the router, so
 that a chip is a device of the paper's device-limited routing) and
 vocabulary rows ``[vocab0, vocab0 + vocab)``.  It routes over ALL the
 published experts, computes the held experts' part for the tokens routed to them (no token is
-dropped and there is no capacity factor: tokens are sorted by expert and
+dropped and there is no capacity factor: tokens are laid out by expert and
 the product runs over blocks of one expert's rows each; ``models/moe.py``,
 which ``smallthinker.py`` runs too), adds what every
 chip computes alike (the shared experts, the dense MLP) and passes that

@@ -1,7 +1,8 @@
 """Routed experts as the token models run them (``deepseek_v2.py``,
 ``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``,
-``longcat_flash.py``): the plan that sorts (token,
-expert) pairs by expert, the product over blocks of one expert's rows,
+``longcat_flash.py``): the plan that lays (token, expert) pairs out by
+expert (counts and ranks from a one-hot of the pairs' experts and its
+running sums: no sort), the product over blocks of one expert's rows,
 and the weighted sum back to tokens.  No token is dropped and there is
 no capacity factor; only the blocks in use are computed, so the work
 follows the tokens routed to the experts HELD here (``[expert0,
@@ -112,40 +113,57 @@ def block_rows(n_tokens: int) -> int:
     return int(min(256, -(-n_tokens // 8) * 8))
 
 
+#: The most ``rows x pairs`` cells :func:`dispatch` inverts ``dest`` over
+#: by a compare and a minimum (a decode step's plans: 0.2-3.9 M); a
+#: larger plan (a prefill chunk's: 300-650 M) takes the one scatter.
+ROW_TOKEN_COMPARE_CELLS = 1 << 23
+
+
 def dispatch(idx, n_tokens: int, expert0: int, held: int):
-    """Sort the (token, expert) pairs of ``idx [n_tokens, k]`` that fall
-    on a HELD expert by expert and lay each expert's rows out in whole
-    blocks.  Returns the plan of the grouped product: per padded row the
-    token it holds (``n_tokens`` = none), per pair the row its result
-    lands in (the last row = none: an expert held elsewhere), per block
-    its expert, the number of blocks in use and the tokens each held
-    expert got."""
+    """Lay the (token, expert) pairs of ``idx [n_tokens, k]`` that fall
+    on a HELD expert out by expert, each expert's rows in pair order and
+    in whole blocks.  Returns the plan of the grouped product: per padded
+    row the token it holds (``n_tokens`` = none), per pair the row its
+    result lands in (the last row = none: an expert held elsewhere), per
+    block its expert, the number of blocks in use and the tokens each
+    held expert got.
+
+    Nothing is sorted: a one-hot ``[held, pairs]`` of the pairs' experts
+    gives the counts as its row sums and a pair's rank among its
+    expert's pairs as the running sum along its row, so ``dest`` is
+    arithmetic a pair.  ``row_token`` is ``dest`` inverted: over at most
+    ``ROW_TOKEN_COMPARE_CELLS`` cells of ``rows x pairs`` by a compare
+    and a minimum, over more by the plan's one scatter; the static
+    shapes alone choose."""
     k = idx.shape[1]
     blk = block_rows(n_tokens)
     pairs = n_tokens * k
     rows = -(-pairs // blk) * blk + held * blk
     local = idx.reshape(-1) - expert0
-    local = jnp.where((local >= 0) & (local < held), local, held)
-    order = jnp.argsort(local, stable=True)
-    sorted_e = local[order]
-    counts = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
-    padded = (counts[:held] + blk - 1) // blk * blk
+    hot = (local[None, :] == jnp.arange(held, dtype=jnp.int32)[:, None]
+           ).astype(jnp.int32)
+    counts = jnp.sum(hot, axis=1)
+    padded = (counts + blk - 1) // blk * blk
     pad_end = jnp.cumsum(padded)
-    first = jnp.cumsum(counts) - counts           # of each expert, sorted
-    rank = jnp.arange(pairs, dtype=jnp.int32) - first[sorted_e]
-    here = sorted_e < held
-    dest_sorted = jnp.where(
-        here, (pad_end - padded)[jnp.minimum(sorted_e, held - 1)] + rank,
-        rows)
-    row_token = jnp.full((rows,), n_tokens, jnp.int32).at[dest_sorted].set(
-        (order // k).astype(jnp.int32), mode="drop")
-    dest = jnp.zeros((pairs,), jnp.int32).at[order].set(dest_sorted)
+    # a held pair's row: where its expert's rows start, and the pairs of
+    # that expert before it
+    row = (pad_end - padded)[:, None] + jnp.cumsum(hot, axis=1) - hot
+    dest = jnp.where((local >= 0) & (local < held),
+                     jnp.sum(hot * row, axis=0), rows)
+    token = jnp.arange(pairs, dtype=jnp.int32) // k
+    if rows * pairs <= ROW_TOKEN_COMPARE_CELLS:
+        row_token = jnp.min(jnp.where(
+            dest[None, :] == jnp.arange(rows, dtype=jnp.int32)[:, None],
+            token[None, :], n_tokens), axis=1)
+    else:
+        row_token = jnp.full((rows,), n_tokens, jnp.int32).at[dest].set(
+            token, mode="drop")
     block_expert = jnp.minimum(jnp.searchsorted(
         pad_end, jnp.arange(rows // blk, dtype=jnp.int32) * blk,
-        side="right"), held - 1).astype(jnp.int32)
+        side="right", method="compare_all"), held - 1).astype(jnp.int32)
     return {"row_token": row_token, "dest": dest.reshape(n_tokens, k),
             "block_expert": block_expert, "blocks": pad_end[-1] // blk,
-            "counts": counts[:held], "blk": blk, "rows": rows}
+            "counts": counts, "blk": blk, "rows": rows}
 
 
 def one_group_plan(n_rows: int):

@@ -3,10 +3,13 @@
 stands in for (``models/moe.py`` ``grouped_experts_loop``), which path
 ``grouped_experts`` takes for a shape, and the stage the call's device
 time is booked to.  The kernel runs under the Pallas interpreter here;
-``tests/test_tpu_compile.py`` compiles it for the chip."""
+``tests/test_tpu_compile.py`` compiles it for the chip.  Also the plan
+both run on (``moe.dispatch``) against the sort and the scatters it was
+made of until PR 45, kept here as its oracle."""
 
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -308,3 +311,146 @@ def test_the_kernels_time_is_booked_to_the_experts_stage(reader):
         # else is a kernel
         assert len(stages) - len(experts) == len(
             [s for s in stages if s.endswith("decode_attention")])
+
+
+def dispatch_by_sort(idx, n_tokens: int, expert0: int, held: int):
+    """``moe.dispatch`` as it was until PR 45, the oracle of the plan: a
+    stable sort of the pairs by expert, a scatter-add of the counts, and
+    two scatters of one element a pair (``row_token``, and the sort's
+    inverse for ``dest``)."""
+    k = idx.shape[1]
+    blk = moe.block_rows(n_tokens)
+    pairs = n_tokens * k
+    rows = -(-pairs // blk) * blk + held * blk
+    local = idx.reshape(-1) - expert0
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(local, stable=True)
+    sorted_e = local[order]
+    counts = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)
+    padded = (counts[:held] + blk - 1) // blk * blk
+    pad_end = jnp.cumsum(padded)
+    first = jnp.cumsum(counts) - counts           # of each expert, sorted
+    rank = jnp.arange(pairs, dtype=jnp.int32) - first[sorted_e]
+    here = sorted_e < held
+    dest_sorted = jnp.where(
+        here, (pad_end - padded)[jnp.minimum(sorted_e, held - 1)] + rank,
+        rows)
+    row_token = jnp.full((rows,), n_tokens, jnp.int32).at[dest_sorted].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    dest = jnp.zeros((pairs,), jnp.int32).at[order].set(dest_sorted)
+    block_expert = jnp.minimum(jnp.searchsorted(
+        pad_end, jnp.arange(rows // blk, dtype=jnp.int32) * blk,
+        side="right"), held - 1).astype(jnp.int32)
+    return {"row_token": row_token, "dest": dest.reshape(n_tokens, k),
+            "block_expert": block_expert, "blocks": pad_end[-1] // blk,
+            "counts": counts[:held], "blk": blk, "rows": rows}
+
+
+#: ``(tokens, k, expert0, held, router width)`` of a decode step's plan in
+#: the five token cells (``benchmark/configs``: the experts a chip holds
+#: of those the router scores).
+DECODE_PLANS = {
+    "dsv2.decode16k": (32, 6, 0, 40, 160),
+    "smallthinker.decode16k": (32, 6, 0, 64, 64),
+    "nemotron3.decode4k": (128, 6, 0, 16, 128),
+    "kexaone.decode16k": (32, 8, 0, 16, 128),
+    "longcat.decode4k": (128, 12, 0, 8, 768),
+}
+PLANS = dict(
+    DECODE_PLANS,
+    **{"a-prefill-chunk": (2048, 12, 0, 8, 768),
+       "a-prefill-chunk-of-40-held": (2048, 6, 0, 40, 160),
+       "one-token": (1, 6, 0, 40, 160),
+       # experts [40, 80) of 160: pairs below the first held one too
+       "expert0-above-0": (32, 6, 40, 40, 160),
+       "the-last-experts-held": (128, 6, 112, 16, 128),
+       # every pick is on an expert the router has and no chip holds
+       "picks-beyond-the-real-experts": (128, 12, 0, 8, 768),
+       "every-pair-on-one-expert": (128, 6, 0, 16, 128),
+       "every-token-twice-on-one-expert": (32, 6, 8, 16, 128),
+       "no-pair-held": (32, 8, 0, 16, 128),
+       "one-expert-held": (32, 6, 3, 1, 64)})
+
+
+def _picks(case):
+    """``idx [tokens, k]`` of a plan in ``PLANS``: seeded picks over the
+    router's width, bent to what the case's name says."""
+    n, k, expert0, held, width = PLANS[case]
+    idx = np.random.default_rng(45).integers(0, width, (n, k))
+    if case == "picks-beyond-the-real-experts":
+        idx = 512 + idx % 256
+        idx[1, :3] = [0, 7, 8]
+    elif case == "every-pair-on-one-expert":
+        idx[:] = expert0 + 5
+    elif case == "every-token-twice-on-one-expert":
+        idx[:, :2] = expert0 + 2
+    elif case == "no-pair-held":
+        idx = expert0 + held + idx % (width - held)
+    return jnp.asarray(idx, jnp.int32)
+
+
+@pytest.mark.parametrize("case,form", [
+    (case, form) for case, (n, k, *_) in PLANS.items()
+    for form in ("by-the-shapes", "compare", "scatter")
+    # rows x pairs of a prefill chunk (650 M cells) is the scatter's
+    # shape, and no test's
+    if not (form == "compare" and n * k > 4096)])
+def test_the_plan_is_the_sorted_plan_key_for_key(case, form, monkeypatch):
+    """Counts from a one-hot and ranks from its running sums give the
+    plan the stable sort gave, value for value, in either form of
+    ``row_token`` (the shapes choose one; both are held here wherever
+    the compare's ``rows x pairs`` cells fit a test)."""
+    n, k, expert0, held, _ = PLANS[case]
+    if form == "compare":
+        monkeypatch.setattr(moe, "ROW_TOKEN_COMPARE_CELLS", 1 << 62)
+    elif form == "scatter":
+        monkeypatch.setattr(moe, "ROW_TOKEN_COMPARE_CELLS", 0)
+    idx = _picks(case)
+    want = dispatch_by_sort(idx, n, expert0, held)
+    got = moe.dispatch(idx, n, expert0, held)
+    assert set(got) == set(want)
+    for key in ("blk", "rows"):
+        assert got[key] == want[key], key
+    for key in ("row_token", "dest", "block_expert", "blocks", "counts"):
+        assert got[key].dtype == want[key].dtype, key
+        assert np.array_equal(np.asarray(got[key]), np.asarray(want[key])), key
+    if case == "no-pair-held":
+        assert int(got["blocks"]) == 0
+        assert (np.asarray(got["row_token"]) == n).all()
+    if case == "every-pair-on-one-expert":
+        assert int(got["counts"][5]) == n * k
+
+
+def _plan_operations(case, dispatch=moe.dispatch):
+    """``{operation: count}`` of the sorts, scatters and gathers in the
+    lowered plan of ``case``: operations, not the words (a scatter's own
+    attributes say ``indices_are_sorted``)."""
+    n, k, expert0, held, _ = PLANS[case]
+    text = jax.jit(dispatch, static_argnums=(1, 2, 3)).lower(
+        _picks(case), n, expert0, held).as_text()
+    return {op: len(re.findall(rf'= "?stablehlo\.{op}"?[ (]', text))
+            for op in ("sort", "scatter", "gather")}
+
+
+@pytest.mark.parametrize("case", list(DECODE_PLANS))
+def test_a_decode_steps_plan_holds_no_sort_and_no_chain_of_scatters(case):
+    ops = _plan_operations(case)
+    assert ops["sort"] == 0 and ops["scatter"] <= 1, ops
+
+
+def test_the_static_shapes_alone_choose_how_row_token_is_made():
+    """A decode step's ``rows x pairs`` is compared and reduced; a
+    prefill chunk's (650 M cells) takes the plan's one scatter."""
+    for case in DECODE_PLANS:
+        assert _plan_operations(case)["scatter"] == 0, case
+    for case in ("a-prefill-chunk", "a-prefill-chunk-of-40-held"):
+        assert _plan_operations(case) == {"sort": 0, "scatter": 1,
+                                          "gather": 0}, case
+
+
+def test_the_oracle_of_the_plan_is_counted_as_the_chain_it_is():
+    """The count reads operations where they are (a count of 0 above is
+    not a pattern that matches nothing): the sorted plan's one sort,
+    three scatters and the gathers through the sorted order."""
+    ops = _plan_operations("longcat.decode4k", dispatch_by_sort)
+    assert ops["sort"] == 1 and ops["scatter"] == 3 and ops["gather"] >= 3

@@ -1,9 +1,11 @@
 """Grouped-query attention parts the token models share
 (``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``): the rotation
-of q and k, and a prefill chunk's attention over a stream's FULL cache.
-How a model makes its q, k and v (norms, rotation, which layers) and
-what it keeps of them stays with the model; the decode step's attention
-is ``ops/kernels.py`` ``gqa_decode_attention``.
+of q and k, a layer's K and V cache, a prefill chunk's attention over a
+stream's FULL cache, and the decode step on a full cache or a ring
+(``ops/kernels.py`` ``gqa_decode_attention`` where it takes the shapes,
+its ``jnp`` reference where it refuses them) with the rows it fetches.
+How a model makes its q, k and v (norms, rotation, which layers) stays
+with the model.
 """
 
 from __future__ import annotations
@@ -18,6 +20,9 @@ try:
     from jax import lax
 except ImportError:  # pragma: no cover
     jax = jnp = lax = None
+
+from ..ops import kernels
+from . import moe
 
 NEG = -1e30
 
@@ -39,6 +44,63 @@ def rope(x, cos, sin):
     a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
+
+
+def kv_cache(streams: int, kv_heads: int, total: int, head_dim: int,
+             dtype) -> dict:
+    """One layer's K and V, ``[streams, kv heads, total, head_dim]``
+    zeros (positions second to last: a product over them reads whole
+    rows and XLA adds no transposed copy).  One buffer a leaf: the
+    state is donated leaf by leaf."""
+    shape = (streams, kv_heads, int(total), head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def heads_out(p, o, dtype):
+    """Heads side by side through ``W_o``."""
+    return moe.mm(o.astype(dtype).reshape(o.shape[0], -1), p["o"]) \
+        .astype(dtype)
+
+
+def decode_step(q, k, v, cache, positions, window: int, scale: float):
+    """One token of every stream: ``q [B, kv heads, heads a group, d]``,
+    ``k`` and ``v`` ``[B, kv heads, d]``, stream ``b`` at
+    ``positions[b]``.  Writes each stream's K and V row in slot
+    ``position % T`` of its ``T`` (a ring where ``T`` is less than the
+    stream's length; on a cache of every position the remainder names
+    the position's own row), then attends over the slots that hold a
+    position of ``max(0, p - window + 1) .. p``: ``window`` is ``T`` for
+    a layer that sees every position.  Returns ``(o [B, kv heads, heads
+    a group, d] float32, cache)``."""
+    b, groups = q.shape[:2]
+    total = cache["k"].shape[2]
+    with jax.named_scope("cache_write"):
+        where = (jnp.arange(b)[:, None], jnp.arange(groups)[None, :],
+                 (positions % total)[:, None])
+        cache = {"k": cache["k"].at[where].set(k.astype(cache["k"].dtype)),
+                 "v": cache["v"].at[where].set(v.astype(cache["v"].dtype))}
+    if kernels.gqa_decode_attention_refusal(
+            q.shape, cache["k"].shape, cache["v"].shape, window) is None:
+        # the call names its own scope, `.../gqa_decode_attention`
+        attend = kernels.gqa_decode_attention
+    else:
+        attend = kernels.gqa_decode_attention_reference
+    return attend(q, cache["k"], cache["v"], positions, window, scale), cache
+
+
+def decode_rows_fetched(caches, per_group: int, positions, window=None):
+    """Rows of ONE of ``caches`` (the caches of one kind of layer, all
+    of one shape; a token's K and V count as one row) that
+    :func:`decode_step` reads in for streams at ``positions``
+    (``ops/kernels.py`` ``gqa_decode_rows_fetched``: the kernel's live
+    cells, or the whole cache where it refuses the shape); ``window``
+    None is a layer that sees every position; 0 where the model has no
+    layer of the kind."""
+    if not caches:
+        return 0
+    b, groups, total, d = shape = caches[0]["k"].shape
+    return kernels.gqa_decode_rows_fetched(
+        (b, groups, per_group, d), shape, positions, window or total)
 
 
 def full_prefill(qkv, size: int, cache, slot, start, hp,

@@ -43,7 +43,6 @@ Stage scopes (``Documentation/observability.md``): ``embed``,
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -58,6 +57,7 @@ except ImportError:  # pragma: no cover
 
 from ..ops import kernels
 from . import mla, moe
+from . import streams as stream
 
 Params = dict
 
@@ -348,6 +348,10 @@ def _head(cfg: DeepSeekV2Config, params, x):
                         + cfg.vocab0)
 
 
+COUNTERS = ("steps", "cache_rows_read", "cache_rows_fetched",
+            "experts_touched", "expert_hits")
+
+
 def init_state(cfg: DeepSeekV2Config, params, streams: int, positions: int,
                dtype=None) -> dict:
     """The state a filter owns between invokes: the latent cache of the
@@ -357,13 +361,7 @@ def init_state(cfg: DeepSeekV2Config, params, streams: int, positions: int,
     # one buffer a leaf: the state is donated leaf by leaf
     return {"cache": [mla.init_cache(cfg, streams, positions, dtype)
                       for _ in range(cfg.layers)],
-            "counters": {name: jnp.zeros((), jnp.uint32) for name in (
-                "steps", "cache_rows_read", "cache_rows_fetched",
-                "experts_touched", "expert_hits")}}
-
-
-def counters(state: dict) -> dict:
-    return state["counters"]
+            "counters": stream.zeros(COUNTERS)}
 
 
 def counter_units(cfg: DeepSeekV2Config, state: dict) -> dict:
@@ -404,18 +402,16 @@ def decode(cfg: DeepSeekV2Config, params, state, ids, positions):
         cfg, params, x, state["cache"],
         lambda p, h, cache: mla.attn_decode(cfg, p, h, cache, positions))
     logits, greedy = _head(cfg, params, x)
-    old, total = state["counters"], mla.cache_positions(cfg, caches[0])
-    new = {"steps": old["steps"] + jnp.uint32(1),
-           "cache_rows_read": old["cache_rows_read"]
-           + jnp.sum(positions + 1).astype(jnp.uint32),
-           "cache_rows_fetched": old["cache_rows_fetched"]
-           + kernels.decode_rows_fetched(positions, total, total).astype(
-               jnp.uint32),
-           "experts_touched": old["experts_touched"]
-           + jnp.sum(got > 0).astype(jnp.uint32),
-           "expert_hits": old["expert_hits"]
-           + jnp.sum(got).astype(jnp.uint32)}
-    return {"cache": caches, "counters": new}, (logits, greedy)
+    total = mla.cache_positions(cfg, caches[0])
+    gained = {"steps": 1,
+              "cache_rows_read": jnp.sum(positions + 1),
+              "cache_rows_fetched": kernels.decode_rows_fetched(
+                  positions, total, total),
+              "experts_touched": jnp.sum(got > 0),
+              "expert_hits": jnp.sum(got)}
+    return {"cache": caches,
+            "counters": stream.bump(state["counters"], gained)}, \
+        (logits, greedy)
 
 
 # -- weights of the right shapes, and registration ----------------------------
@@ -458,46 +454,21 @@ def param_shapes(cfg: DeepSeekV2Config) -> dict:
 
 
 def init_params(cfg: DeepSeekV2Config, key, dtype=None) -> Params:
-    """Seeded weights of the right shapes: matrices N(0, 1/fan_in)
-    (residual branches halved), norm gains 1.  For tests and examples;
-    a deployment loads its own."""
-    dtype = dtype or jnp.bfloat16
-    if isinstance(key, int):
-        key = jax.random.PRNGKey(key)
-    leaves, treedef = jax.tree_util.tree_flatten(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)
-        and isinstance(x[1], str))
-    out = []
-    for n, (shape, role) in enumerate(leaves):
-        if role == "norm":
-            out.append(jnp.ones(shape, jnp.float32))
-            continue
-        fan_in = 1 if role == "embed" else shape[-2]
-        gain = 0.5 if role in ("o", "down", "expert_down") else 1.0
-        out.append((jax.random.normal(jax.random.fold_in(key, n), shape)
-                    * (gain / fan_in) ** 0.5).astype(dtype))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    """Seeded weights of the right shapes (``models/streams.py``
+    ``seeded_params``): matrices N(0, 1/fan_in) (residual branches
+    halved), norm gains 1."""
+    return stream.seeded_params(param_shapes(cfg), key, dtype)
 
 
-@functools.lru_cache(maxsize=8)
 def entries(cfg: DeepSeekV2Config, streams: int, positions: int,
             chunk: int) -> Dict[str, Any]:
-    """What :func:`register` hands ``register_stateful_model``: the two
-    entry points with their input schemas, and ``init_state``.  Cached
-    by the sizes, so that two sets of weights of one configuration share
-    their programs."""
-    i32 = np.int32
-    return {
-        "entries": {
-            "decode": (functools.partial(decode, cfg),
-                       [(streams,), (streams,)], i32),
-            "prefill": (functools.partial(prefill, cfg),
-                        [(chunk,), (1,), (1,)], i32)},
-        "setup_entries": ("prefill",),
-        "init_state": functools.partial(init_state, cfg, streams=streams,
-                                        positions=positions),
-        "counters": counters,
-        "counter_units": functools.partial(counter_units, cfg)}
+    """What :func:`register` hands ``register_stateful_model``
+    (``models/streams.py`` ``entries``, cached by these arguments): the
+    two entry points with their input schemas, and ``init_state``."""
+    return stream.entries(
+        cfg, decode, ((streams,), (streams,)),
+        prefill, ((chunk,), (1,), (1,)), init_state, counter_units,
+        streams=streams, positions=positions)
 
 
 def register(name: str, cfg: DeepSeekV2Config, params: Params, streams: int,
@@ -508,7 +479,5 @@ def register(name: str, cfg: DeepSeekV2Config, params: Params, streams: int,
     one whose input is ``(ids[streams], positions[streams])`` decodes;
     two filters with one ``shared-tensor-filter-key`` work on one
     cache."""
-    from ..filters.jax_xla import register_stateful_model
-
-    return register_stateful_model(
-        name, params=params, **entries(cfg, streams, positions, chunk))
+    return stream.register(name, params,
+                           entries(cfg, streams, positions, chunk))

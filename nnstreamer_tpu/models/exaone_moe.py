@@ -62,10 +62,7 @@ and ``.../gqa_decode_attention`` inside), ``layerNN/mlp`` or
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, Tuple
-
-import numpy as np
 
 try:
     import jax
@@ -74,8 +71,8 @@ try:
 except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
-from ..ops import kernels
 from . import attention, moe
+from . import streams as stream
 
 Params = dict
 #: rows of one cell of the decode kernel's walk (``ops/kernels.py``
@@ -251,12 +248,6 @@ def _qkv(cfg: ExaoneMoeConfig, p, h, positions, rotary: bool):
     return q, k, v
 
 
-def _out(p, o, dtype):
-    """Heads side by side through ``W_o``."""
-    return moe.mm(o.astype(dtype).reshape(o.shape[0], -1), p["o"]) \
-        .astype(dtype)
-
-
 # -- attention ----------------------------------------------------------------
 
 
@@ -305,8 +296,8 @@ def window_prefill(cfg: ExaoneMoeConfig, p, h, cache, slot, start, count):
                      k.astype(cache["k"].dtype), mode="drop"),
                  "v": cache["v"].at[slot, :, at].set(
                      v.astype(cache["v"].dtype), mode="drop")}
-    return _out(p, o.reshape(c, cfg.kv_heads, cfg.per_group, cfg.head_dim),
-                dt), cache
+    return attention.heads_out(
+        p, o.reshape(c, cfg.kv_heads, cfg.per_group, cfg.head_dim), dt), cache
 
 
 def full_prefill(cfg: ExaoneMoeConfig, p, h, cache, slot, start):
@@ -317,30 +308,19 @@ def full_prefill(cfg: ExaoneMoeConfig, p, h, cache, slot, start):
     o, cache = attention.full_prefill(
         lambda positions: _qkv(cfg, p, h, positions, False), h.shape[0],
         cache, slot, start, moe.precision(p["q"]), key_block=512)
-    return _out(p, o, h.dtype), cache
+    return attention.heads_out(p, o, h.dtype), cache
 
 
 def attn_decode(cfg: ExaoneMoeConfig, ring: bool, p, h, cache, positions):
     """One token of every stream: ``h [B, hidden]``, stream ``b`` at
     ``positions[b]``.  Writes each stream's K and V row (slot ``position
-    % T``), then attends over the slots that hold a position it sees."""
-    b, total = h.shape[0], cache["k"].shape[2]
-    window = cfg.window if ring else total
+    % T``), then attends over the slots that hold a position it sees
+    (``models/attention.py`` ``decode_step``)."""
     q, k, v = _qkv(cfg, p, h, positions, ring)
-    with jax.named_scope("cache_write"):
-        where = (jnp.arange(b)[:, None], jnp.arange(cfg.kv_heads)[None, :],
-                 (positions % total)[:, None])
-        cache = {"k": cache["k"].at[where].set(k.astype(cache["k"].dtype)),
-                 "v": cache["v"].at[where].set(v.astype(cache["v"].dtype))}
-    if kernels.gqa_decode_attention_refusal(
-            q.shape, cache["k"].shape, cache["v"].shape, window) is None:
-        # the call names its own scope, `.../gqa_decode_attention`
-        attend = kernels.gqa_decode_attention
-    else:
-        attend = kernels.gqa_decode_attention_reference
-    o = attend(q, cache["k"], cache["v"], positions, window,
-               cfg.head_dim ** -0.5)
-    return _out(p, o, h.dtype), cache
+    o, cache = attention.decode_step(
+        q, k, v, cache, positions,
+        cfg.window if ring else cache["k"].shape[2], cfg.head_dim ** -0.5)
+    return attention.heads_out(p, o, h.dtype), cache
 
 
 # -- the model ----------------------------------------------------------------
@@ -437,23 +417,16 @@ def init_state(cfg: ExaoneMoeConfig, params, streams: int, positions: int,
                          f"has {cfg.max_positions}")
 
     def kv(total):
-        shape = (streams, cfg.kv_heads, int(total), cfg.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        return attention.kv_cache(streams, cfg.kv_heads, total, cfg.head_dim,
+                                  dtype)
 
     state = {"cache": [kv(cfg.ring(rewind) if ring else positions)
                        for ring in cfg.window_layers],
-             "prompt_end": jnp.zeros((streams,), jnp.int32),
-             "last": jnp.full((streams,), -1, jnp.int32),
-             "newest": jnp.full((streams,), -1, jnp.int32),
-             "counters": {name: jnp.zeros((), jnp.uint32)
-                          for name in COUNTERS}}
+             **stream.book(streams, newest=True),
+             "counters": stream.zeros(COUNTERS)}
     if cfg.mtp:
         state["mtp"] = kv(positions)
     return state
-
-
-def counters(state: dict) -> dict:
-    return state["counters"]
 
 
 def counter_units(cfg: ExaoneMoeConfig, state: dict) -> dict:
@@ -461,8 +434,8 @@ def counter_units(cfg: ExaoneMoeConfig, state: dict) -> dict:
     ``full_rows_read`` and ``mtp_rows_read`` count the rows IN USE of
     ONE cache of their kind (a stream past the window uses ``window``
     rows of a ring, whatever the ring holds), ``*_rows_fetched`` the
-    rows the decode attention reads in for them (``ops/kernels.py``
-    ``gqa_decode_rows_fetched``; the module's cache is fetched as a full
+    rows the decode attention reads in for them (``models/attention.py``
+    ``decode_rows_fetched``; the module's cache is fetched as a full
     layer's); a row is a token's K and V.  ``cache_bytes_*`` is the sum
     of the three kinds."""
     row = cfg.row_values * state["cache"][0]["k"].dtype.itemsize
@@ -497,10 +470,7 @@ def prefill(cfg: ExaoneMoeConfig, params, state, ids, next_ids, slot, start,
         cfg, params, state, ids, next_ids, attend,
         lambda x: lax.dynamic_slice_in_dim(x, count - 1, 1))
     with jax.named_scope("state"):
-        end = start + count
-        new.update(prompt_end=state["prompt_end"].at[slot].set(end),
-                   last=state["last"].at[slot].set(end - 1),
-                   newest=state["newest"].at[slot].set(end - 1),
+        new.update(stream.book_prefilled(state, slot, start + count),
                    counters=state["counters"])
     return new, out
 
@@ -517,9 +487,7 @@ def decode(cfg: ExaoneMoeConfig, params, state, ids, next_ids, positions):
         rings = [c["k"].shape[2] for c, ring
                  in zip(state["cache"], cfg.window_layers) if ring]
         room = min(rings) - cfg.window if rings else cfg.max_positions
-        fault = (positions != state["last"] + 1) & ~(
-            (positions == state["prompt_end"])
-            & (state["newest"] - positions <= room))
+        _, fault, book = stream.book_step(state, positions, room)
     new, out, (got, got_mtp) = _forward(
         cfg, params, state, ids, next_ids,
         lambda ring, p, h, cache: attn_decode(cfg, ring, p, h, cache,
@@ -527,36 +495,24 @@ def decode(cfg: ExaoneMoeConfig, params, state, ids, next_ids, positions):
         lambda x: x)
     with jax.named_scope("state"):
         rows = positions + 1
-
-        def fetched(ring: bool):
-            """Rows :func:`attn_decode` reads in in ONE cache of a kind
-            (the module's is a full layer's); 0 where there is none."""
-            caches = [c for c, is_ring in zip(new["cache"], cfg.window_layers)
-                      if is_ring == ring] + ([new["mtp"]]
-                                             if cfg.mtp and not ring else [])
-            if not caches:
-                return 0
-            b, _, total, d = shape = caches[0]["k"].shape
-            return kernels.gqa_decode_rows_fetched(
-                (b, cfg.kv_heads, cfg.per_group, d), shape, positions,
-                cfg.window if ring else total)
-
+        kinds = list(zip(new["cache"], cfg.window_layers))
+        rings = [c for c, ring in kinds if ring]
+        # the module's cache is a full layer's
+        fulls = [c for c, ring in kinds if not ring] \
+            + ([new["mtp"]] if cfg.mtp else [])
         gained = {"steps": 1,
                   "window_rows_read": jnp.sum(jnp.minimum(rows, cfg.window)),
-                  "window_rows_fetched": fetched(True),
+                  "window_rows_fetched": attention.decode_rows_fetched(
+                      rings, cfg.per_group, positions, cfg.window),
                   "full_rows_read": jnp.sum(rows),
-                  "full_rows_fetched": fetched(False),
+                  "full_rows_fetched": attention.decode_rows_fetched(
+                      fulls, cfg.per_group, positions),
                   "mtp_rows_read": jnp.sum(rows),
                   "experts_touched": jnp.sum(got > 0) + jnp.sum(got_mtp > 0),
                   "expert_hits": jnp.sum(got) + jnp.sum(got_mtp),
                   "mtp_experts_touched": jnp.sum(got_mtp > 0),
                   "position_faults": jnp.sum(fault)}
-        new.update(prompt_end=state["prompt_end"], last=positions,
-                   newest=jnp.maximum(state["newest"], positions),
-                   counters={
-                       name: state["counters"][name]
-                       + jnp.asarray(gained[name]).astype(jnp.uint32)
-                       for name in COUNTERS})
+        new.update(book, counters=stream.bump(state["counters"], gained))
     return new, out
 
 
@@ -606,52 +562,26 @@ def param_shapes(cfg: ExaoneMoeConfig) -> dict:
 
 
 def init_params(cfg: ExaoneMoeConfig, key, dtype=None) -> Params:
-    """Seeded weights of the right shapes: matrices N(0, 1/fan_in)
-    (residual branches halved), norm gains 1, a small router bias.  For
-    tests and examples; a deployment loads its own."""
-    dtype = dtype or jnp.bfloat16
-    if isinstance(key, int):
-        key = jax.random.PRNGKey(key)
-    leaves, treedef = jax.tree_util.tree_flatten(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)
-        and isinstance(x[1], str))
-    out = []
-    for n, (shape, role) in enumerate(leaves):
-        k = jax.random.fold_in(key, n)
-        if role in ("norm", "qk_norm"):
-            out.append(jnp.ones(shape, jnp.float32))
-        elif role == "router_bias":
-            out.append(0.1 * jax.random.normal(k, shape, jnp.float32))
-        else:
-            fan_in = 1 if role == "embed" else shape[-2]
-            gain = 0.5 if role in ("o", "down", "expert_down") else 1.0
-            out.append((jax.random.normal(k, shape)
-                        * (gain / fan_in) ** 0.5).astype(dtype))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    """Seeded weights of the right shapes (``models/streams.py``
+    ``seeded_params``): matrices N(0, 1/fan_in) (residual branches
+    halved), norm gains 1, a small router bias."""
+    return stream.seeded_params(
+        param_shapes(cfg), key, dtype, ones=("norm", "qk_norm"),
+        special={"router_bias": stream.normal_vector(0.1)})
 
 
-@functools.lru_cache(maxsize=8)
 def entries(cfg: ExaoneMoeConfig, streams: int, positions: int, chunk: int,
             rewind: int) -> Dict[str, Any]:
-    """What :func:`register` hands ``register_stateful_model``: the two
-    entry points with their input schemas, and ``init_state``.  Cached
-    by the sizes, so that two sets of weights of one configuration share
-    their programs."""
+    """What :func:`register` hands ``register_stateful_model``
+    (``models/streams.py`` ``entries``, cached by these arguments): the
+    two entry points with their input schemas, and ``init_state``."""
     if any(cfg.window_layers) and chunk % cfg.window:
         raise ValueError(f"exaone_moe: a prefill chunk of {chunk} tokens "
                          f"is not whole windows of {cfg.window}")
-    i32 = np.int32
-    return {
-        "entries": {
-            "decode": (functools.partial(decode, cfg),
-                       [(streams,), (streams,), (streams,)], i32),
-            "prefill": (functools.partial(prefill, cfg),
-                        [(chunk,), (chunk,), (1,), (1,), (1,)], i32)},
-        "setup_entries": ("prefill",),
-        "init_state": functools.partial(init_state, cfg, streams=streams,
-                                        positions=positions, rewind=rewind),
-        "counters": counters,
-        "counter_units": functools.partial(counter_units, cfg)}
+    return stream.entries(
+        cfg, decode, ((streams,),) * 3,
+        prefill, ((chunk,), (chunk,), (1,), (1,), (1,)), init_state,
+        counter_units, streams=streams, positions=positions, rewind=rewind)
 
 
 def register(name: str, cfg: ExaoneMoeConfig, params: Params, streams: int,
@@ -664,7 +594,5 @@ def register(name: str, cfg: ExaoneMoeConfig, params: Params, streams: int,
     one ``shared-tensor-filter-key`` work on one state (the rings, the
     full caches and the prediction module's cache).  ``rewind`` is the
     longest way back to its prompt's end a stream may be sent."""
-    from ..filters.jax_xla import register_stateful_model
-
-    return register_stateful_model(
-        name, params=params, **entries(cfg, streams, positions, chunk, rewind))
+    return stream.register(
+        name, params, entries(cfg, streams, positions, chunk, rewind))

@@ -64,10 +64,7 @@ and ``.../attn/latent_decode_attention`` inside), ``layerNN/s0/mlp``,
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict
-
-import numpy as np
 
 try:
     import jax
@@ -77,6 +74,7 @@ except ImportError:  # pragma: no cover
 
 from ..ops import kernels
 from . import mla, moe
+from . import streams as stream
 from .attention import rope_angles
 
 Params = dict
@@ -331,12 +329,7 @@ def init_state(cfg: LongCatFlashConfig, params, streams: int,
     # one buffer a leaf: the state is donated leaf by leaf
     return {"cache": [[mla.init_cache(cfg, streams, positions, dtype)
                        for _ in range(SUBS)] for _ in range(cfg.layers)],
-            "counters": {name: jnp.zeros((), jnp.uint32)
-                         for name in COUNTERS}}
-
-
-def counters(state: dict) -> dict:
-    return state["counters"]
+            "counters": stream.zeros(COUNTERS)}
 
 
 def counter_units(cfg: LongCatFlashConfig, state: dict) -> dict:
@@ -385,10 +378,9 @@ def decode(cfg: LongCatFlashConfig, params, state, ids, positions):
         "experts_touched": jnp.sum(got > 0),
         "expert_hits": jnp.sum(got),
         "zero_picks": zero_picks}
-    new = {name: state["counters"][name]
-           + jnp.asarray(gained[name]).astype(jnp.uint32)
-           for name in COUNTERS}
-    return {"cache": caches, "counters": new}, (logits, greedy)
+    return {"cache": caches,
+            "counters": stream.bump(state["counters"], gained)}, \
+        (logits, greedy)
 
 
 # -- weights of the right shapes, and registration ----------------------------
@@ -426,49 +418,23 @@ def param_shapes(cfg: LongCatFlashConfig) -> dict:
 
 
 def init_params(cfg: LongCatFlashConfig, key, dtype=None) -> Params:
-    """Seeded weights of the right shapes: matrices N(0, 1/fan_in)
-    (residual branches halved), norm gains 1, the correction bias 0.
-    For tests and examples; a deployment loads its own."""
-    dtype = dtype or jnp.bfloat16
-    if isinstance(key, int):
-        key = jax.random.PRNGKey(key)
-    leaves, treedef = jax.tree_util.tree_flatten(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)
-        and isinstance(x[1], str))
-    out = []
-    for n, (shape, role) in enumerate(leaves):
-        if role == "norm":
-            out.append(jnp.ones(shape, jnp.float32))
-            continue
-        if role == "router_bias":
-            out.append(jnp.zeros(shape, jnp.float32))
-            continue
-        fan_in = 1 if role == "embed" else shape[-2]
-        gain = 0.5 if role in ("o", "down", "expert_down") else 1.0
-        out.append((jax.random.normal(jax.random.fold_in(key, n), shape)
-                    * (gain / fan_in) ** 0.5).astype(dtype))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    """Seeded weights of the right shapes (``models/streams.py``
+    ``seeded_params``): matrices N(0, 1/fan_in) (residual branches
+    halved), norm gains 1, the correction bias 0."""
+    return stream.seeded_params(
+        param_shapes(cfg), key, dtype, special={
+            "router_bias": lambda _k, shape: jnp.zeros(shape, jnp.float32)})
 
 
-@functools.lru_cache(maxsize=8)
 def entries(cfg: LongCatFlashConfig, streams: int, positions: int,
             chunk: int) -> Dict[str, Any]:
-    """What :func:`register` hands ``register_stateful_model``: the two
-    entry points with their input schemas, and ``init_state``.  Cached
-    by the sizes, so that two sets of weights of one configuration share
-    their programs."""
-    i32 = np.int32
-    return {
-        "entries": {
-            "decode": (functools.partial(decode, cfg),
-                       [(streams,), (streams,)], i32),
-            "prefill": (functools.partial(prefill, cfg),
-                        [(chunk,), (1,), (1,)], i32)},
-        "setup_entries": ("prefill",),
-        "init_state": functools.partial(init_state, cfg, streams=streams,
-                                        positions=positions),
-        "counters": counters,
-        "counter_units": functools.partial(counter_units, cfg)}
+    """What :func:`register` hands ``register_stateful_model``
+    (``models/streams.py`` ``entries``, cached by these arguments): the
+    two entry points with their input schemas, and ``init_state``."""
+    return stream.entries(
+        cfg, decode, ((streams,), (streams,)),
+        prefill, ((chunk,), (1,), (1,)), init_state, counter_units,
+        streams=streams, positions=positions)
 
 
 def register(name: str, cfg: LongCatFlashConfig, params: Params,
@@ -479,7 +445,5 @@ def register(name: str, cfg: LongCatFlashConfig, params: Params,
     one whose input is ``(ids[streams], positions[streams])`` decodes;
     two filters with one ``shared-tensor-filter-key`` work on one
     state."""
-    from ..filters.jax_xla import register_stateful_model
-
-    return register_stateful_model(
-        name, params=params, **entries(cfg, streams, positions, chunk))
+    return stream.register(name, params,
+                           entries(cfg, streams, positions, chunk))

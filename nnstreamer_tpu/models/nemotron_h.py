@@ -74,11 +74,8 @@ is the kernel), ``layerNN/mamba``
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict
-
-import numpy as np
 
 try:
     import jax
@@ -90,6 +87,7 @@ except ImportError:  # pragma: no cover
 from ..ops import kernels
 from ..utils import profile as _profile
 from . import attention, moe
+from . import streams as stream
 
 Params = dict
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
@@ -446,11 +444,6 @@ def _qkv(cfg: NemotronHConfig, p, u):
     return q, k, v
 
 
-def _out(p, o, dtype):
-    return moe.mm(o.astype(dtype).reshape(o.shape[0], -1), p["o"]) \
-        .astype(dtype)
-
-
 def attn_prefill(cfg: NemotronHConfig, p, u, cache, slot, start,
                  key_block: int = 1024):
     """A chunk ``u [C, hidden]`` of stream ``slot`` whose first token is
@@ -459,30 +452,18 @@ def attn_prefill(cfg: NemotronHConfig, p, u, cache, slot, start,
     o, cache = attention.full_prefill(
         lambda _positions: _qkv(cfg, p, u), u.shape[0], cache, slot, start,
         moe.precision(p["q"]), key_block)
-    return _out(p, o, u.dtype), cache
+    return attention.heads_out(p, o, u.dtype), cache
 
 
 def attn_decode(cfg: NemotronHConfig, p, u, cache, positions):
     """One token of every stream: writes each stream's K and V row at
     its position, then attends over ``0 .. position``
-    (``ops/kernels.py`` ``gqa_decode_attention`` with the whole cache as
-    its window, its ``jnp`` reference for a shape it refuses)."""
-    b, total = u.shape[0], cache["k"].shape[2]
+    (``models/attention.py`` ``decode_step`` with the whole cache as its
+    window)."""
     q, k, v = _qkv(cfg, p, u)
-    with jax.named_scope("cache_write"):
-        where = (jnp.arange(b)[:, None], jnp.arange(cfg.kv_heads)[None, :],
-                 positions[:, None])
-        cache = {"k": cache["k"].at[where].set(k.astype(cache["k"].dtype)),
-                 "v": cache["v"].at[where].set(v.astype(cache["v"].dtype))}
-    scale = cfg.head_dim ** -0.5
-    if kernels.gqa_decode_attention_refusal(
-            q.shape, cache["k"].shape, cache["v"].shape, total) is None:
-        # the call names its own scope, `.../gqa_decode_attention`
-        attend = kernels.gqa_decode_attention
-    else:
-        attend = kernels.gqa_decode_attention_reference
-    o = attend(q, cache["k"], cache["v"], positions, total, scale)
-    return _out(p, o, u.dtype), cache
+    o, cache = attention.decode_step(q, k, v, cache, positions,
+                                     cache["k"].shape[2], cfg.head_dim ** -0.5)
+    return attention.heads_out(p, o, u.dtype), cache
 
 
 # -- the model ----------------------------------------------------------------
@@ -557,22 +538,16 @@ def init_state(cfg: NemotronHConfig, params, streams: int, positions: int,
     # the inputs' axis before the channels': 3 rows of 6,144 lanes, where
     # [.., 6144, 3] would pad every channel's three values to a tile
     conv = (streams, cfg.conv_kernel - 1, cfg.conv_dim)
-    kv = (streams, cfg.kv_heads, int(positions), cfg.head_dim)
     return {
         "mamba": [{"conv": jnp.zeros(conv, dtype),
                    "conv_snap": jnp.zeros(conv, dtype),
                    "ssm": jnp.zeros(ssm, jnp.float32),
                    "ssm_snap": jnp.zeros(ssm, jnp.float32)}
                   for _ in range(cfg.count("M"))],
-        "cache": [{"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
+        "cache": [attention.kv_cache(streams, cfg.kv_heads, positions,
+                                     cfg.head_dim, dtype)
                   for _ in range(cfg.count("*"))],
-        "prompt_end": jnp.zeros((streams,), jnp.int32),
-        "last": jnp.full((streams,), -1, jnp.int32),
-        "counters": {name: jnp.zeros((), jnp.uint32) for name in COUNTERS}}
-
-
-def counters(state: dict) -> dict:
-    return state["counters"]
+        **stream.book(streams), "counters": stream.zeros(COUNTERS)}
 
 
 def counter_units(cfg: NemotronHConfig, state: dict) -> dict:
@@ -580,8 +555,8 @@ def counter_units(cfg: NemotronHConfig, state: dict) -> dict:
     the streams stepped in ONE ``M`` layer: a row is a stream's ``ssm``
     and ``conv``, read and written.  ``kv_rows_read`` counts the rows in
     use (``0 .. position``) of ONE ``*`` layer, ``kv_rows_fetched`` the
-    rows the decode attention copies for them (:func:`rows_fetched`): a
-    row is a token's K and V."""
+    rows the decode attention copies for them (``models/attention.py``
+    ``decode_rows_fetched``): a row is a token's K and V."""
     out = {}
     if state["mamba"]:
         first = state["mamba"][0]
@@ -595,18 +570,6 @@ def counter_units(cfg: NemotronHConfig, state: dict) -> dict:
             kv = (f"kv_rows_{did}", row * len(state["cache"]))
             out.update({f"kv_bytes_{did}": kv, f"cache_bytes_{did}": kv})
     return out
-
-
-def rows_fetched(cfg: NemotronHConfig, caches, positions):
-    """Rows of ONE ``*`` layer's cache that :func:`attn_decode` reads in
-    for streams at ``positions`` (``ops/kernels.py``
-    ``gqa_decode_rows_fetched``: the kernel's live cells, or the whole
-    cache where it refuses the shape); 0 where no layer attends."""
-    if not caches:
-        return 0
-    b, _, total, d = shape = caches[0]["k"].shape
-    return kernels.gqa_decode_rows_fetched(
-        (b, cfg.kv_heads, cfg.per_group, d), shape, positions, total)
 
 
 def prefill(cfg: NemotronHConfig, params, state, ids, slot, start, count):
@@ -624,10 +587,7 @@ def prefill(cfg: NemotronHConfig, params, state, ids, slot, start, count):
     logits, greedy = _head(cfg, params,
                            lax.dynamic_slice_in_dim(x, count - 1, 1))
     with jax.named_scope("state"):
-        end = start + count
-        new = dict(states,
-                   prompt_end=state["prompt_end"].at[slot].set(end),
-                   last=state["last"].at[slot].set(end - 1),
+        new = dict(states, **stream.book_prefilled(state, slot, start + count),
                    counters=state["counters"])
     return new, (logits, greedy)
 
@@ -638,8 +598,7 @@ def decode(cfg: NemotronHConfig, params, state, ids, positions):
     at its ``prompt_end`` starts from its snapshot; any other must be at
     ``last + 1``, or the step counts a position fault."""
     with jax.named_scope("state"):
-        restore = positions == state["prompt_end"]
-        fault = ~restore & (positions != state["last"] + 1)
+        restore, fault, book = stream.book_step(state, positions)
     with jax.named_scope("ssm_restore"):
         # empty where the step is the kernel, which picks each stream's
         # source itself
@@ -654,17 +613,14 @@ def decode(cfg: NemotronHConfig, params, state, ids, positions):
     with jax.named_scope("state"):
         gained = {"steps": 1, "ssm_rows": ids.shape[0],
                   "kv_rows_read": jnp.sum(positions + 1),
-                  "kv_rows_fetched": rows_fetched(cfg, states["cache"],
-                                                  positions),
+                  "kv_rows_fetched": attention.decode_rows_fetched(
+                      states["cache"], cfg.per_group, positions),
                   "experts_touched": jnp.sum(got > 0),
                   "expert_hits": jnp.sum(got),
                   "restores": jnp.sum(restore),
                   "position_faults": jnp.sum(fault)}
-        new = dict(states, prompt_end=state["prompt_end"], last=positions,
-                   counters={
-                       name: state["counters"][name]
-                       + jnp.asarray(gained[name]).astype(jnp.uint32)
-                       for name in COUNTERS})
+        new = dict(states, **book,
+                   counters=stream.bump(state["counters"], gained))
     return new, (logits, greedy)
 
 
@@ -704,62 +660,38 @@ def param_shapes(cfg: NemotronHConfig) -> dict:
 
 
 def init_params(cfg: NemotronHConfig, key, dtype=None) -> Params:
-    """Seeded weights of the right shapes: matrices N(0, 1/fan_in)
-    (residual branches halved), norm gains and ``D`` 1, ``delta`` at rest
-    log-uniform in 0.001-0.1 and ``exp(A_log)`` in 1-2 (a head remembers
-    tens to a thousand tokens), a small router bias.  For tests and
-    examples; a deployment loads its own."""
-    dtype = dtype or jnp.bfloat16
-    if isinstance(key, int):
-        key = jax.random.PRNGKey(key)
-    leaves, treedef = jax.tree_util.tree_flatten(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)
-        and isinstance(x[1], str))
-    out = []
-    for n, (shape, role) in enumerate(leaves):
-        k = jax.random.fold_in(key, n)
-        if role in ("norm", "D"):
-            out.append(jnp.ones(shape, jnp.float32))
-        elif role == "dt_bias":
-            rest = jnp.exp(jax.random.uniform(
-                k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
-            out.append(rest + jnp.log(-jnp.expm1(-rest)))   # softplus^-1
-        elif role == "A_log":
-            out.append(jnp.log(jax.random.uniform(k, shape, jnp.float32,
-                                                  1.0, 2.0)))
-        elif role in ("conv_b", "router_bias"):
-            out.append(0.1 * jax.random.normal(k, shape, jnp.float32))
-        elif role == "conv_w":
-            out.append(jax.random.normal(k, shape, jnp.float32)
-                       * shape[0] ** -0.5)
-        else:
-            fan_in = 1 if role == "embed" else shape[-2]
-            gain = 0.5 if role in ("o", "out_proj", "down",
-                                   "expert_down") else 1.0
-            out.append((jax.random.normal(k, shape)
-                        * (gain / fan_in) ** 0.5).astype(dtype))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    """Seeded weights of the right shapes (``models/streams.py``
+    ``seeded_params``): matrices N(0, 1/fan_in) (residual branches
+    halved), norm gains and ``D`` 1, ``delta`` at rest log-uniform in
+    0.001-0.1 and ``exp(A_log)`` in 1-2 (a head remembers tens to a
+    thousand tokens), a small router bias."""
+
+    def dt_bias(k, shape):
+        rest = jnp.exp(jax.random.uniform(
+            k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+        return rest + jnp.log(-jnp.expm1(-rest))            # softplus^-1
+
+    return stream.seeded_params(
+        param_shapes(cfg), key, dtype, ones=("norm", "D"),
+        halved=("o", "out_proj", "down", "expert_down"),
+        special={"dt_bias": dt_bias,
+                 "A_log": lambda k, shape: jnp.log(jax.random.uniform(
+                     k, shape, jnp.float32, 1.0, 2.0)),
+                 "conv_w": lambda k, shape: jax.random.normal(
+                     k, shape, jnp.float32) * shape[0] ** -0.5,
+                 "conv_b": stream.normal_vector(0.1),
+                 "router_bias": stream.normal_vector(0.1)})
 
 
-@functools.lru_cache(maxsize=8)
 def entries(cfg: NemotronHConfig, streams: int, positions: int,
             chunk: int) -> Dict[str, Any]:
-    """What :func:`register` hands ``register_stateful_model``: the two
-    entry points with their input schemas, and ``init_state``.  Cached
-    by the sizes, so that two sets of weights of one configuration share
-    their programs."""
-    i32 = np.int32
-    return {
-        "entries": {
-            "decode": (functools.partial(decode, cfg),
-                       [(streams,), (streams,)], i32),
-            "prefill": (functools.partial(prefill, cfg),
-                        [(chunk,), (1,), (1,), (1,)], i32)},
-        "setup_entries": ("prefill",),
-        "init_state": functools.partial(init_state, cfg, streams=streams,
-                                        positions=positions),
-        "counters": counters,
-        "counter_units": functools.partial(counter_units, cfg)}
+    """What :func:`register` hands ``register_stateful_model``
+    (``models/streams.py`` ``entries``, cached by these arguments): the
+    two entry points with their input schemas, and ``init_state``."""
+    return stream.entries(
+        cfg, decode, ((streams,), (streams,)),
+        prefill, ((chunk,), (1,), (1,), (1,)), init_state, counter_units,
+        streams=streams, positions=positions)
 
 
 def register(name: str, cfg: NemotronHConfig, params: Params, streams: int,
@@ -770,7 +702,5 @@ def register(name: str, cfg: NemotronHConfig, params: Params, streams: int,
     prefills, one whose input is ``(ids[streams], positions[streams])``
     decodes; two filters with one ``shared-tensor-filter-key`` work on
     one state (recurrent states, their snapshots and the caches)."""
-    from ..filters.jax_xla import register_stateful_model
-
-    return register_stateful_model(
-        name, params=params, **entries(cfg, streams, positions, chunk))
+    return stream.register(name, params,
+                           entries(cfg, streams, positions, chunk))

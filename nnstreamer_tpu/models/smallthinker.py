@@ -58,11 +58,8 @@ experts|combine``, ``head``.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, Tuple
-
-import numpy as np
 
 try:
     import jax
@@ -71,8 +68,8 @@ try:
 except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
-from ..ops import kernels
 from . import attention, moe
+from . import streams as stream
 
 Params = dict
 NEG = -1e30
@@ -182,12 +179,6 @@ def _qkv(cfg: SmallThinkerConfig, p, h, positions, rotary: bool):
     return q, k, v
 
 
-def _out(p, o, dtype):
-    """Heads side by side through ``W_o``."""
-    return moe.mm(o.astype(dtype).reshape(o.shape[0], -1), p["o"]) \
-        .astype(dtype)
-
-
 # -- attention ----------------------------------------------------------------
 
 
@@ -248,32 +239,20 @@ def attn_prefill(cfg: SmallThinkerConfig, layer: int, p, h, cache, slot,
         (m0, jnp.zeros_like(m0), jnp.zeros(m0.shape + (cfg.head_dim,),
                                            jnp.float32)))
     o = (acc / l[..., None]).transpose(2, 0, 1, 3)        # [C, g, q, d]
-    return _out(p, o, h.dtype), cache
+    return attention.heads_out(p, o, h.dtype), cache
 
 
 def attn_decode(cfg: SmallThinkerConfig, layer: int, p, h, cache, positions):
     """One token of every stream: ``h [B, hidden]``, stream ``b`` at
     ``positions[b]``.  Writes each stream's K and V row, then attends
-    over the slots that hold a position of its window."""
-    b = h.shape[0]
-    total = cache["k"].shape[2]
-    window = cfg.window if cfg.window_layers[layer] else total
+    over the slots that hold a position of its window
+    (``models/attention.py`` ``decode_step``)."""
+    window = cfg.window if cfg.window_layers[layer] \
+        else cache["k"].shape[2]
     q, k, v = _qkv(cfg, p, h, positions, cfg.rope_layers[layer])
-    with jax.named_scope("cache_write"):
-        where = (jnp.arange(b)[:, None], jnp.arange(cfg.kv_heads)[None, :],
-                 (positions % total)[:, None])
-        cache = {"k": cache["k"].at[where].set(k.astype(cache["k"].dtype)),
-                 "v": cache["v"].at[where].set(v.astype(cache["v"].dtype))}
-    scale = cfg.head_dim ** -0.5
-    if kernels.gqa_decode_attention_refusal(
-            q.shape, cache["k"].shape, cache["v"].shape, window) is None:
-        # the call names its own scope, `.../gqa_decode_attention`
-        o = kernels.gqa_decode_attention(q, cache["k"], cache["v"],
-                                         positions, window, scale)
-    else:
-        o = kernels.gqa_decode_attention_reference(
-            q, cache["k"], cache["v"], positions, window, scale)
-    return _out(p, o, h.dtype), cache
+    o, cache = attention.decode_step(q, k, v, cache, positions, window,
+                                     cfg.head_dim ** -0.5)
+    return attention.heads_out(p, o, h.dtype), cache
 
 
 # -- the model ----------------------------------------------------------------
@@ -344,19 +323,10 @@ def init_state(cfg: SmallThinkerConfig, params, streams: int, positions: int,
         raise ValueError(f"smallthinker: {positions} positions, the model "
                          f"has {cfg.max_positions}")
 
-    def kv(total):
-        # one buffer a leaf: the state is donated leaf by leaf
-        shape = (streams, cfg.kv_heads, int(total), cfg.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-    return {"cache": [kv(cfg.ring(chunk) if ring else positions)
-                      for ring in cfg.window_layers],
-            "counters": {name: jnp.zeros((), jnp.uint32)
-                         for name in COUNTERS}}
-
-
-def counters(state: dict) -> dict:
-    return state["counters"]
+    return {"cache": [attention.kv_cache(
+                streams, cfg.kv_heads, cfg.ring(chunk) if ring else positions,
+                cfg.head_dim, dtype) for ring in cfg.window_layers],
+            "counters": stream.zeros(COUNTERS)}
 
 
 def counter_units(cfg: SmallThinkerConfig, state: dict) -> dict:
@@ -364,8 +334,8 @@ def counter_units(cfg: SmallThinkerConfig, state: dict) -> dict:
     and ``full_rows_read`` count the rows IN USE of ONE layer of their
     kind (a stream past the window uses ``window`` rows of a ring,
     whatever the ring holds), ``*_rows_fetched`` the rows the decode
-    attention reads in for them (``ops/kernels.py``
-    ``gqa_decode_rows_fetched``); a row is a token's K and V."""
+    attention reads in for them (``models/attention.py``
+    ``decode_rows_fetched``); a row is a token's K and V."""
     row = cfg.row_values * state["cache"][0]["k"].dtype.itemsize
     rings = sum(cfg.window_layers)
     out = {}
@@ -402,28 +372,20 @@ def decode(cfg: SmallThinkerConfig, params, state, ids, positions):
         lambda i, p, h, cache: attn_decode(cfg, i, p, h, cache, positions))
     logits, greedy = _head(cfg, params, x)
     rows = positions + 1
-
-    def fetched(ring: bool):
-        """Rows :func:`attn_decode` reads in in ONE layer of a kind."""
-        if ring not in cfg.window_layers:
-            return 0
-        b, _, total, d = shape = \
-            caches[cfg.window_layers.index(ring)]["k"].shape
-        return kernels.gqa_decode_rows_fetched(
-            (b, cfg.kv_heads, cfg.per_group, d), shape, positions,
-            cfg.window if ring else total)
-
+    rings = [c for c, ring in zip(caches, cfg.window_layers) if ring]
+    fulls = [c for c, ring in zip(caches, cfg.window_layers) if not ring]
     gained = {"steps": 1,
               "window_rows_read": jnp.sum(jnp.minimum(rows, cfg.window)),
               "full_rows_read": jnp.sum(rows),
-              "window_rows_fetched": fetched(True),
-              "full_rows_fetched": fetched(False),
+              "window_rows_fetched": attention.decode_rows_fetched(
+                  rings, cfg.per_group, positions, cfg.window),
+              "full_rows_fetched": attention.decode_rows_fetched(
+                  fulls, cfg.per_group, positions),
               "experts_touched": jnp.sum(got > 0),
               "expert_hits": jnp.sum(got)}
-    new = {name: state["counters"][name]
-           + jnp.asarray(gained[name]).astype(jnp.uint32)
-           for name in COUNTERS}
-    return {"cache": caches, "counters": new}, (logits, greedy)
+    return {"cache": caches,
+            "counters": stream.bump(state["counters"], gained)}, \
+        (logits, greedy)
 
 
 # -- weights of the right shapes, and registration ----------------------------
@@ -449,46 +411,21 @@ def param_shapes(cfg: SmallThinkerConfig) -> dict:
 
 
 def init_params(cfg: SmallThinkerConfig, key, dtype=None) -> Params:
-    """Seeded weights of the right shapes: matrices N(0, 1/fan_in)
-    (residual branches halved), norm gains 1.  For tests and examples;
-    a deployment loads its own."""
-    dtype = dtype or jnp.bfloat16
-    if isinstance(key, int):
-        key = jax.random.PRNGKey(key)
-    leaves, treedef = jax.tree_util.tree_flatten(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)
-        and isinstance(x[1], str))
-    out = []
-    for n, (shape, role) in enumerate(leaves):
-        if role == "norm":
-            out.append(jnp.ones(shape, jnp.float32))
-            continue
-        fan_in = 1 if role == "embed" else shape[-2]
-        gain = 0.5 if role in ("o", "expert_down") else 1.0
-        out.append((jax.random.normal(jax.random.fold_in(key, n), shape)
-                    * (gain / fan_in) ** 0.5).astype(dtype))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    """Seeded weights of the right shapes (``models/streams.py``
+    ``seeded_params``): matrices N(0, 1/fan_in) (residual branches
+    halved), norm gains 1."""
+    return stream.seeded_params(param_shapes(cfg), key, dtype)
 
 
-@functools.lru_cache(maxsize=8)
 def entries(cfg: SmallThinkerConfig, streams: int, positions: int,
             chunk: int) -> Dict[str, Any]:
-    """What :func:`register` hands ``register_stateful_model``: the two
-    entry points with their input schemas, and ``init_state``.  Cached
-    by the sizes, so that two sets of weights of one configuration share
-    their programs."""
-    i32 = np.int32
-    return {
-        "entries": {
-            "decode": (functools.partial(decode, cfg),
-                       [(streams,), (streams,)], i32),
-            "prefill": (functools.partial(prefill, cfg),
-                        [(chunk,), (1,), (1,)], i32)},
-        "setup_entries": ("prefill",),
-        "init_state": functools.partial(init_state, cfg, streams=streams,
-                                        positions=positions, chunk=chunk),
-        "counters": counters,
-        "counter_units": functools.partial(counter_units, cfg)}
+    """What :func:`register` hands ``register_stateful_model``
+    (``models/streams.py`` ``entries``, cached by these arguments): the
+    two entry points with their input schemas, and ``init_state``."""
+    return stream.entries(
+        cfg, decode, ((streams,), (streams,)),
+        prefill, ((chunk,), (1,), (1,)), init_state, counter_units,
+        streams=streams, positions=positions, chunk=chunk)
 
 
 def register(name: str, cfg: SmallThinkerConfig, params: Params, streams: int,
@@ -499,7 +436,5 @@ def register(name: str, cfg: SmallThinkerConfig, params: Params, streams: int,
     one whose input is ``(ids[streams], positions[streams])`` decodes;
     two filters with one ``shared-tensor-filter-key`` work on one state
     (the rings and the full caches)."""
-    from ..filters.jax_xla import register_stateful_model
-
-    return register_stateful_model(
-        name, params=params, **entries(cfg, streams, positions, chunk))
+    return stream.register(name, params,
+                           entries(cfg, streams, positions, chunk))

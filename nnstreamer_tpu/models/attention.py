@@ -1,5 +1,6 @@
 """Grouped-query attention parts the token models share
-(``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``): the rotation
+(``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``,
+``falcon_h1.py``): the rotation
 of q and k, a layer's K and V cache, a prefill chunk's attention over a
 stream's FULL cache, and the decode step on a full cache or a ring
 (``ops/kernels.py`` ``gqa_decode_attention`` where it takes the shapes,

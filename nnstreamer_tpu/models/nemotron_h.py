@@ -5,14 +5,11 @@ Written from the model's public ``config.json``: one stack whose layers
 are chosen by the letters of ``hybrid_override_pattern``, each layer ONE
 mixer behind a pre-norm and a residual add and no separate MLP:
 
-``M``  Mamba-2.  ``[z | xBC | dt] = u W_in``; ``xBC`` through a causal
-       depthwise convolution (kernel ``conv_kernel``, with bias) and
-       SiLU, split into ``x [heads, head_dim]``, ``B`` and ``C``
-       ``[groups, state]`` (head ``h`` reads group ``h // (heads /
-       groups)``); ``delta = softplus(dt + dt_bias)``, ``a = exp(-delta
-       exp(A_log))``; per head ``S_t = a S_{t-1} + delta x_t (x) B_t``,
-       ``y_t = S_t C_t + D x_t``; ``y * silu(z)`` through an RMSNorm
-       over groups of ``d_inner / groups`` values, then ``W_out``.
+``M``  Mamba-2 (``models/mamba2.py``, which ``falcon_h1.py`` runs too,
+       has the equations): the projection ``[z | xBC | dt] = u W_in``,
+       the causal depthwise convolution, the recurrence over
+       ``mamba_num_heads`` heads in ``n_groups`` groups, the gated
+       grouped norm, ``W_out``; no multiplier on its columns.
 ``E``  Mixture of experts: a sigmoid router over ALL the published
        experts, the choice taken from the scores plus a correction
        bias, the weights from the scores alone, normalised and scaled;
@@ -27,13 +24,10 @@ mixer behind a pre-norm and a residual add and no separate MLP:
 and a V row a position (``[streams, kv heads, positions, head_dim]``):
 a row written too far is overwritten before it is read, so a padded
 prefill chunk and a rewind to the prompt's end cost nothing there.  A
-Mamba-2 layer keeps ONE array a stream (``ssm [streams, groups, state,
-heads a group x head_dim]`` float32: the state axis before a group's
-values, so that the decode step's kernel finds a head's decay and
-``delta x`` as rows and ``y`` as a sum over sublanes) and the last
-``conv_kernel - 1`` inputs of its convolution (``conv [streams,
-conv_kernel - 1, conv_dim]``), both overwritten by every token.  So
-(``Documentation/stateful-models.md``):
+Mamba-2 layer keeps ONE array a stream (``ssm``) and the last
+``conv_kernel - 1`` inputs of its convolution (``conv``), both
+overwritten by every token (``models/mamba2.py`` has their shapes and
+why).  So (``Documentation/stateful-models.md``):
 
 * :func:`prefill` takes a fourth tensor, ``count``: the first ``count``
   tokens of the chunk are real.  A padded token gets ``delta = 0``,
@@ -50,17 +44,12 @@ conv_kernel - 1, conv_dim]``), both overwritten by every token.  So
   (``position_faults``), not guessed at.  A step reads the snapshots of
   the streams that restore in it and of no other.
 
-:func:`prefill` runs the recurrence chunked (``chunk_size`` tokens: the
-quadratic form inside a chunk, the carried state between chunks; the
-scan carries ``[heads, head_dim, state]`` and a chunk transposes its
-slot's state once at each end), :func:`decode` one step of it: where
-the state's shape allows (:func:`step_refusal`) ONE kernel a layer
-(``ops/kernels.py`` ``ssm_decode_step``) that reads a stream's state
-once, from its snapshot or live as the stream restores or not, updates
-it and reduces it to ``y`` in fast memory and writes it once over the
-live state; for every other shape the ``jnp`` step from the live state
-behind :func:`restored`, the loop that copies the restoring streams'
-snapshots first.
+:func:`prefill` runs the recurrence chunked (``mamba2.mamba_prefill``),
+:func:`decode` one step of it (``mamba2.mamba_decode``: where the
+state's shape allows, ONE kernel a layer, ``ops/kernels.py``
+``ssm_decode_step``; for every other shape the ``jnp`` step from the
+live state behind ``mamba2.restored``, the loop that copies the
+restoring streams' snapshots first).
 
 Stage scopes (``Documentation/observability.md``): ``embed``, ``state``,
 ``ssm_restore`` (the loop that copies snapshots: empty where the step
@@ -74,7 +63,6 @@ is the kernel), ``layerNN/mamba``
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Dict
 
 try:
@@ -84,9 +72,7 @@ try:
 except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
-from ..ops import kernels
-from ..utils import profile as _profile
-from . import attention, moe
+from . import attention, mamba2, moe
 from . import streams as stream
 
 Params = dict
@@ -190,14 +176,14 @@ class NemotronHConfig:
         return len(self.pattern)
 
     @property
-    def d_inner(self) -> int:
-        """Heads x head size, NOT ``expand`` x hidden."""
-        return self.mamba_heads * self.mamba_head_dim
-
-    @property
-    def conv_dim(self) -> int:
-        """What the convolution runs over: ``x``, ``B`` and ``C``."""
-        return self.d_inner + 2 * self.groups * self.state_size
+    def mamba(self) -> mamba2.Geometry:
+        """What ``models/mamba2.py`` reads: an ``M`` layer's sizes, and
+        no multiplier on the projection's columns."""
+        return mamba2.Geometry(
+            heads=self.mamba_heads, head_dim=self.mamba_head_dim,
+            groups=self.groups, state_size=self.state_size,
+            conv_kernel=self.conv_kernel, chunk_size=self.chunk_size,
+            eps=self.eps)
 
     @property
     def per_group(self) -> int:
@@ -232,201 +218,6 @@ def moe_parts(cfg: NemotronHConfig, p, u):
     with jax.named_scope("shared"):
         shared = _shared_expert(p["shared"], u)
     return routed, shared, plan["counts"]
-
-
-# -- Mamba-2 ------------------------------------------------------------------
-
-
-def _in_proj(cfg: NemotronHConfig, p, u):
-    """``z`` and ``xBC`` in the stream's type, ``dt`` in float32."""
-    with jax.named_scope("in_proj"):
-        zxbcdt = moe.mm(u, p["in_proj"])
-        d, c = cfg.d_inner, cfg.conv_dim
-        return (zxbcdt[:, :d].astype(u.dtype),
-                zxbcdt[:, d:d + c].astype(u.dtype), zxbcdt[:, d + c:])
-
-
-def _ssm_inputs(cfg: NemotronHConfig, act):
-    """The convolution's output ``[..., conv_dim]`` (float32) as ``x
-    [..., groups, heads a group, head_dim]``, ``B`` and ``C`` ``[...,
-    groups, state]``."""
-    d, gn = cfg.d_inner, cfg.groups * cfg.state_size
-    lead = act.shape[:-1]
-    x = act[..., :d].reshape(*lead, cfg.groups, cfg.mamba_heads // cfg.groups,
-                             cfg.mamba_head_dim)
-    b = act[..., d:d + gn].reshape(*lead, cfg.groups, cfg.state_size)
-    c = act[..., d + gn:].reshape(*lead, cfg.groups, cfg.state_size)
-    return x, b, c
-
-
-def _by_group(cfg: NemotronHConfig, per_head):
-    """``[..., heads] -> [..., groups, heads a group]``."""
-    return per_head.reshape(*per_head.shape[:-1], cfg.groups,
-                            cfg.mamba_heads // cfg.groups)
-
-
-def _gate_norm_out(cfg: NemotronHConfig, p, y, z, dtype):
-    """``rms(y * silu(z))`` over groups of ``d_inner / groups`` values,
-    then ``W_out``."""
-    with jax.named_scope("gate_norm"):
-        n = y.shape[0]
-        g = (y.reshape(n, cfg.d_inner)
-             * jax.nn.silu(z.astype(jnp.float32))).reshape(n, cfg.groups, -1)
-        g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.eps)
-        g = (g.reshape(n, cfg.d_inner) * p["gate_norm"]).astype(dtype)
-    with jax.named_scope("out_proj"):
-        return moe.mm(g, p["out_proj"]).astype(dtype)
-
-
-def ssd_scan(cfg: NemotronHConfig, x, b, c, delta, a_log, state):
-    """The recurrence over ``T`` tokens of one stream, chunked: ``x [T,
-    groups, heads a group, head_dim]``, ``b`` and ``c`` ``[T, groups,
-    state]``, ``delta [T, heads]`` (0 for a token that is padding),
-    ``state [heads, head_dim, state]``, all float32.  Returns ``(y [T,
-    ...as x], the state after the last token)``.  Inside a chunk of
-    ``chunk_size`` tokens ``y`` is the quadratic form ``(C B^T * decay)
-    (delta x)``; between chunks the state is carried."""
-    hp = lax.Precision.HIGHEST
-    t, size = x.shape[0], cfg.chunk_size
-    pad = -t % size
-    if pad:
-        x, b, c, delta = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
-                          for v in (x, b, c, delta))
-    n = (t + pad) // size
-    g, r = cfg.groups, cfg.mamba_heads // cfg.groups
-    log_a = _by_group(cfg, -delta * jnp.exp(a_log))        # [T, g, r], <= 0
-    xd = (x * _by_group(cfg, delta)[..., None]).reshape(
-        n, size, g, r, cfg.mamba_head_dim)
-    b, c = (v.reshape(n, size, g, cfg.state_size) for v in (b, c))
-    # cs[.., l]: the log of the decay from the chunk's start through l
-    cs = jnp.cumsum(log_a.reshape(n, size, g, r), axis=1).transpose(0, 2, 3, 1)
-    seen = jnp.arange(size)[:, None] >= jnp.arange(size)[None, :]
-    decay = jnp.exp(jnp.where(seen, cs[..., :, None] - cs[..., None, :],
-                              -jnp.inf))                   # [n, g, r, l, s]
-    cb = jnp.einsum("clgn,csgn->cgls", c, b, precision=hp)
-    y = jnp.einsum("cgrls,csgrp->clgrp", cb[:, :, None] * decay, xd,
-                   precision=hp)
-    # what a chunk adds to the state, and what it leaves of the old one
-    to_end = jnp.exp(cs[..., -1:] - cs).transpose(0, 3, 1, 2)   # [n, s, g, r]
-    gained = jnp.einsum("csgrp,csgn->cgrpn", xd * to_end[..., None], b,
-                        precision=hp)
-    kept = jnp.exp(cs[..., -1])                                 # [n, g, r]
-
-    def carry(s, step):
-        keep, gain = step
-        return keep[..., None, None] * s + gain, s
-
-    s0 = state.reshape(g, r, cfg.mamba_head_dim, cfg.state_size)
-    last, starts = lax.scan(carry, s0, (kept, gained))
-    y = y + jnp.einsum("clgn,cgrpn->clgrp", c, starts, precision=hp) \
-        * jnp.exp(cs).transpose(0, 3, 1, 2)[..., None]
-    return y.reshape((t + pad,) + x.shape[1:])[:t], last.reshape(state.shape)
-
-
-def mamba_prefill(cfg: NemotronHConfig, p, u, st, slot, start, count):
-    """A chunk ``u [C, hidden]`` of stream ``slot`` whose first token is
-    at ``start`` and whose first ``count`` tokens are real.  Starts from
-    zeros where ``start`` is 0, else from the slot's live state; leaves
-    live state and snapshot at ``start + count`` tokens."""
-    size = u.shape[0]
-    z, xbc, dt = _in_proj(cfg, p, u)
-    fresh = start == 0
-    conv0 = jnp.where(fresh, 0, st["conv"][slot])
-    g, r = cfg.groups, cfg.mamba_heads // cfg.groups
-    # the filter keeps [groups, state, heads a group x head_dim]; the scan
-    # carries [heads, head_dim, state]: one transposition at each end
-    ssm0 = jnp.where(fresh, 0.0, st["ssm"][slot]).reshape(
-        g, cfg.state_size, r, cfg.mamba_head_dim).transpose(0, 2, 3, 1) \
-        .reshape(cfg.mamba_heads, cfg.mamba_head_dim, cfg.state_size)
-    with jax.named_scope("conv"):
-        ext = jnp.concatenate([conv0, xbc])           # [K - 1 + C, conv_dim]
-        act = p["conv_b"] + sum(
-            ext[k:k + size].astype(jnp.float32) * p["conv_w"][k]
-            for k in range(cfg.conv_kernel))
-        x, b, c = _ssm_inputs(cfg, jax.nn.silu(act))
-        # the last K - 1 REAL inputs: rows count - (K - 1) .. count - 1
-        conv = lax.dynamic_slice_in_dim(ext, count, cfg.conv_kernel - 1)
-    with jax.named_scope("scan"):
-        real = jnp.arange(size) < count
-        delta = jnp.where(real[:, None],
-                          jax.nn.softplus(dt + p["dt_bias"]), 0.0)
-        y, ssm = ssd_scan(cfg, x, b, c, delta, p["A_log"], ssm0)
-        y = y + x * _by_group(cfg, p["D"])[..., None]
-        ssm = ssm.reshape(g, r, cfg.mamba_head_dim, cfg.state_size) \
-            .transpose(0, 3, 1, 2).reshape(st["ssm"].shape[1:])
-        st = {"conv": st["conv"].at[slot].set(conv),
-              "conv_snap": st["conv_snap"].at[slot].set(conv),
-              "ssm": st["ssm"].at[slot].set(ssm),
-              "ssm_snap": st["ssm_snap"].at[slot].set(ssm)}
-    return _gate_norm_out(cfg, p, y, z, u.dtype), st
-
-
-def restored(mamba: list, restore) -> list:
-    """The ``M`` layers' states with the live state of every stream of
-    ``restore [B]`` overwritten by its snapshot, a stream at a time in
-    place: a step in which no stream restores reads no snapshot, and one
-    in which some do reads theirs alone.  The path of the shapes
-    :func:`step_refusal` names: where the step is the kernel, the kernel
-    chooses a stream's source and this loop is not in the program."""
-    first = jnp.argsort(~restore)             # the restoring streams first
-
-    def one(i, live):
-        b = first[i]
-        return [{name: lax.dynamic_update_slice_in_dim(
-            now[name], lax.dynamic_slice_in_dim(st[name + "_snap"], b, 1),
-            b, 0) for name in ("conv", "ssm")}
-            for now, st in zip(live, mamba)]
-
-    live = lax.fori_loop(0, jnp.sum(restore), one,
-                         [{"conv": st["conv"], "ssm": st["ssm"]}
-                          for st in mamba])
-    return [dict(st, **now) for st, now in zip(mamba, live)]
-
-
-def step_refusal(st: dict):
-    """Why one ``M`` layer's decode step is not ``ops/kernels.py``
-    ``ssm_decode_step`` for a layer state of these shapes, or None."""
-    return kernels.ssm_decode_step_refusal(
-        st["ssm"].shape, {st["ssm"].dtype, st["ssm_snap"].dtype})
-
-
-def mamba_decode(cfg: NemotronHConfig, p, u, st, restore):
-    """One token of every stream, ``u [B, hidden]``; the live state is
-    overwritten, the snapshot is kept.  One algorithm, two programs,
-    chosen from the state's shape (:func:`step_refusal`): the kernel,
-    which starts each stream of ``restore [B]`` from its snapshot and
-    every other from its live state; or, for a shape it refuses, the
-    ``jnp`` step from the live state, which :func:`decode` has run
-    through :func:`restored` first.  The set-up span this is traced
-    under says which (``utils/profile.py`` ``note``)."""
-    refusal = step_refusal(st)
-    shapes = f"mamba_decode {tuple(st['ssm'].shape)} " \
-             f"{st['ssm'].dtype.name}"
-    _profile.note(f"{shapes}: the jnp step behind the restore loop "
-                  f"({refusal})" if refusal else f"{shapes}: the kernel")
-    z, xbc, dt = _in_proj(cfg, p, u)
-    with jax.named_scope("conv"):
-        held = st["conv"] if refusal else jnp.where(
-            restore[:, None, None], st["conv_snap"], st["conv"])
-        window = jnp.concatenate([held, xbc[:, None]], axis=1)
-        act = p["conv_b"] + jnp.sum(
-            window.astype(jnp.float32) * p["conv_w"], axis=1)
-        x, b, c = _ssm_inputs(cfg, jax.nn.silu(act))
-    with jax.named_scope("step"):
-        delta = _by_group(cfg, jax.nn.softplus(dt + p["dt_bias"]))
-        a = jnp.exp(-delta * _by_group(cfg, jnp.exp(p["A_log"])))
-        # a head's decay over its lanes, beside its delta x
-        lanes = (u.shape[0], cfg.groups, -1)
-        a = jnp.broadcast_to(a[..., None], x.shape).reshape(lanes)
-        dx = (delta[..., None] * x).reshape(lanes)
-        if refusal:
-            ssm, y = kernels.ssm_decode_step_reference(st["ssm"], a, dx, b, c)
-        else:
-            ssm, y = kernels.ssm_decode_step(st["ssm"], st["ssm_snap"],
-                                             restore, a, dx, b, c)
-        y = y.reshape(x.shape) + x * _by_group(cfg, p["D"])[..., None]
-    st = dict(st, conv=window[:, 1:], ssm=ssm)
-    return _gate_norm_out(cfg, p, y, z, u.dtype), st
 
 
 # -- attention ----------------------------------------------------------------
@@ -530,19 +321,8 @@ def init_state(cfg: NemotronHConfig, params, streams: int, positions: int,
     if positions > cfg.max_positions:
         raise ValueError(f"nemotron_h: {positions} positions, the model "
                          f"has {cfg.max_positions}")
-    # the state axis before a group's heads x head_dim: 512 lanes of
-    # [128 sublanes], so that a step's decay and delta x are rows and y is
-    # a sum over sublanes (ops/kernels.py ssm_decode_step)
-    ssm = (streams, cfg.groups, cfg.state_size,
-           cfg.mamba_heads // cfg.groups * cfg.mamba_head_dim)
-    # the inputs' axis before the channels': 3 rows of 6,144 lanes, where
-    # [.., 6144, 3] would pad every channel's three values to a tile
-    conv = (streams, cfg.conv_kernel - 1, cfg.conv_dim)
     return {
-        "mamba": [{"conv": jnp.zeros(conv, dtype),
-                   "conv_snap": jnp.zeros(conv, dtype),
-                   "ssm": jnp.zeros(ssm, jnp.float32),
-                   "ssm_snap": jnp.zeros(ssm, jnp.float32)}
+        "mamba": [mamba2.init_state(cfg.mamba, streams, dtype)
                   for _ in range(cfg.count("M"))],
         "cache": [attention.kv_cache(streams, cfg.kv_heads, positions,
                                      cfg.head_dim, dtype)
@@ -559,9 +339,7 @@ def counter_units(cfg: NemotronHConfig, state: dict) -> dict:
     ``decode_rows_fetched``): a row is a token's K and V."""
     out = {}
     if state["mamba"]:
-        first = state["mamba"][0]
-        row = sum(first[k][0].size * first[k].dtype.itemsize
-                  for k in ("ssm", "conv"))
+        row = mamba2.state_row_bytes(state["mamba"][0])
         out["ssm_bytes"] = ("ssm_rows", 2 * row * len(state["mamba"]))
     if state["cache"]:
         k = state["cache"][0]["k"]
@@ -582,7 +360,8 @@ def prefill(cfg: NemotronHConfig, params, state, ids, slot, start, count):
     x = _embed(cfg, params, ids)
     x, states, _ = _layers(
         cfg, params, x, state,
-        lambda p, u, st: mamba_prefill(cfg, p, u, st, slot, start, count),
+        lambda p, u, st: mamba2.mamba_prefill(cfg.mamba, p, u, st, slot, start,
+                                              count),
         lambda p, u, cache: attn_prefill(cfg, p, u, cache, slot, start))
     logits, greedy = _head(cfg, params,
                            lax.dynamic_slice_in_dim(x, count - 1, 1))
@@ -602,12 +381,13 @@ def decode(cfg: NemotronHConfig, params, state, ids, positions):
     with jax.named_scope("ssm_restore"):
         # empty where the step is the kernel, which picks each stream's
         # source itself
-        if any(step_refusal(st) for st in state["mamba"]):
-            state = dict(state, mamba=restored(state["mamba"], restore))
+        if any(mamba2.step_refusal(st) for st in state["mamba"]):
+            state = dict(state,
+                         mamba=mamba2.restored(state["mamba"], restore))
     x = _embed(cfg, params, ids)
     x, states, got = _layers(
         cfg, params, x, state,
-        lambda p, u, st: mamba_decode(cfg, p, u, st, restore),
+        lambda p, u, st: mamba2.mamba_decode(cfg.mamba, p, u, st, restore),
         lambda p, u, cache: attn_decode(cfg, p, u, cache, positions))
     logits, greedy = _head(cfg, params, x)
     with jax.named_scope("state"):
@@ -632,16 +412,8 @@ def param_shapes(cfg: NemotronHConfig) -> dict:
     carry the role their init gain is looked up by, norm gains ``norm``,
     and the Mamba-2 layer's small vectors their own names."""
     h, f, e = cfg.hidden_size, cfg.expert_width, cfg.experts
-    d, heads = cfg.d_inner, cfg.mamba_heads
     kinds = {
-        "M": {"norm": ((h,), "norm"),
-              "in_proj": ((h, d + cfg.conv_dim + heads), "in_proj"),
-              "conv_w": ((cfg.conv_kernel, cfg.conv_dim), "conv_w"),
-              "conv_b": ((cfg.conv_dim,), "conv_b"),
-              "dt_bias": ((heads,), "dt_bias"),
-              "A_log": ((heads,), "A_log"), "D": ((heads,), "D"),
-              "gate_norm": ((d,), "norm"),
-              "out_proj": ((d, h), "out_proj")},
+        "M": {"norm": ((h,), "norm"), **mamba2.param_shapes(cfg.mamba, h)},
         "E": {"norm": ((h,), "norm"),
               "router": ((h, cfg.n_routed_experts), "router"),
               "router_bias": ((cfg.n_routed_experts,), "router_bias"),
@@ -665,22 +437,11 @@ def init_params(cfg: NemotronHConfig, key, dtype=None) -> Params:
     halved), norm gains and ``D`` 1, ``delta`` at rest log-uniform in
     0.001-0.1 and ``exp(A_log)`` in 1-2 (a head remembers tens to a
     thousand tokens), a small router bias."""
-
-    def dt_bias(k, shape):
-        rest = jnp.exp(jax.random.uniform(
-            k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
-        return rest + jnp.log(-jnp.expm1(-rest))            # softplus^-1
-
     return stream.seeded_params(
         param_shapes(cfg), key, dtype, ones=("norm", "D"),
         halved=("o", "out_proj", "down", "expert_down"),
-        special={"dt_bias": dt_bias,
-                 "A_log": lambda k, shape: jnp.log(jax.random.uniform(
-                     k, shape, jnp.float32, 1.0, 2.0)),
-                 "conv_w": lambda k, shape: jax.random.normal(
-                     k, shape, jnp.float32) * shape[0] ** -0.5,
-                 "conv_b": stream.normal_vector(0.1),
-                 "router_bias": stream.normal_vector(0.1)})
+        special=dict(mamba2.seeded_laws(),
+                     router_bias=stream.normal_vector(0.1)))
 
 
 def entries(cfg: NemotronHConfig, streams: int, positions: int,

@@ -1,12 +1,13 @@
 """What a stream is to a token model, written once (``deepseek_v2.py``,
 ``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``,
-``longcat_flash.py``): the seeded weights tests run on, the counters a
+``longcat_flash.py``, ``falcon_h1.py``): the seeded weights tests run on, the counters a
 step adds to, the book of where each stream stands, and the table a
 model registers with the ``jax-xla`` filter.  A model file keeps its
 configuration, its layers, ``param_shapes`` with their roles,
 ``counter_units`` (what a row is IS the model) and its two entry
 points; the grouped-query decode step and its caches are in
-``attention.py``, latent attention in ``mla.py``, the experts in
+``attention.py``, latent attention in ``mla.py``, the Mamba-2 mixer
+with its recurrent state and snapshots in ``mamba2.py``, the experts in
 ``moe.py`` (``Documentation/stateful-models.md``, "Adding a token
 model").  The models import this module as ``stream``: ``streams`` is
 their word for how many streams a state holds.

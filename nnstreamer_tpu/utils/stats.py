@@ -357,7 +357,10 @@ class StateStats:
     over read is what the kernel's schedule costs in bytes, while the
     benchmark's rooflines count the rows in use; a model with a
     recurrent state adds ``ssm_bytes`` read and written, ``restores``
-    from its snapshots and ``position_faults``; a model whose router
+    from its snapshots and ``position_faults`` (a model whose EVERY
+    layer owns both kinds, ``falcon_h1.py``, counts every layer in
+    ``ssm_bytes`` and in ``kv_bytes_*`` alike, and keeps no expert
+    counter: it routes nothing); a model whose router
     also scores zero-compute experts adds ``zero_picks`` (the picks
     that cost no product; a step's picks are a constant, tokens x picks
     a token x layers, so ``zero_picks`` and ``expert_hits`` a frame over

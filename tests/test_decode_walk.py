@@ -75,6 +75,11 @@ CASES = {
     "six-streams-four-groups-bf16": (
         4, 16, 512, 256, WalkPlan(256, 3), "bfloat16",
         [0, 255, 256, 511, 512, 2000]),
+    # `falconh1.decode4k`'s layer: four groups of FIVE heads (a tile of
+    # 16 holds them), bf16, a dense cache of 4,096, the derived plan
+    "dense-4096-four-groups-five-heads-bf16": (
+        4, 5, 4096, 4096, None, "bfloat16",
+        [0, 2047, 2048, 3071, 3840, 4095]),
     "a-queue-longer-than-a-stream": (
         2, 5, 768, 512, WalkPlan(256, 8), "float32",
         [0, 130, 5000, 2, 767]),

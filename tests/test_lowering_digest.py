@@ -29,6 +29,7 @@ MODELS = {
     "nemotron_h": ("toy_nemotron3", LANES),
     "exaone_moe": ("toy_kexaone", dict(LANES, sliding_window=128)),
     "longcat_flash": ("toy_longcat", {}),
+    "falcon_h1": ("toy_falconh1", {}),
 }
 SIZES = {"streams": 8, "positions": 256, "chunk": 128}
 

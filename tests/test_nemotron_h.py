@@ -22,7 +22,7 @@ import jax.numpy as jnp  # noqa: E402
 from benchmark.run import Loader  # noqa: E402
 from nnstreamer_tpu.filters.api import SHARED_MODELS  # noqa: E402
 from nnstreamer_tpu.filters.jax_xla import unregister_model  # noqa: E402
-from nnstreamer_tpu.models import moe  # noqa: E402
+from nnstreamer_tpu.models import mamba2, moe  # noqa: E402
 from nnstreamer_tpu.models import nemotron_h as nh  # noqa: E402
 from nnstreamer_tpu.runtime import parse_launch  # noqa: E402
 from nnstreamer_tpu.utils.stats import STATE_STATS  # noqa: E402
@@ -149,7 +149,7 @@ def test_the_chunked_scan_is_the_recurrence(model, tokens, size):
                                        jnp.float32, 1.0, 2.0))
     s0 = jax.random.normal(keys[5], (cfg.mamba_heads, cfg.mamba_head_dim,
                                      cfg.state_size))
-    y, last = nh.ssd_scan(cfg, x, b, c, delta, a_log, s0)
+    y, last = mamba2.ssd_scan(cfg.mamba, x, b, c, delta, a_log, s0)
     s = np.asarray(s0, np.float64).reshape(g, r, cfg.mamba_head_dim, -1)
     for t in range(tokens):
         a = np.exp(-np.asarray(delta[t], np.float64)
@@ -224,7 +224,7 @@ def test_the_steps_count_what_they_touch(model, served):
     state = nh.init_state(cfg, model["params"], 3, POSITIONS)
     units = nh.counter_units(cfg, state)
     row = cfg.mamba_heads * cfg.mamba_head_dim * cfg.state_size * 4 \
-        + (cfg.conv_kernel - 1) * cfg.conv_dim * 4
+        + (cfg.conv_kernel - 1) * cfg.mamba.conv_dim * 4
     assert units["ssm_bytes"] == ("ssm_rows", 2 * row * 3)
     assert units["kv_bytes_read"] == units["cache_bytes_read"] \
         == ("kv_rows_read", 2 * 2 * 16 * 4 * 1)
@@ -321,7 +321,7 @@ def test_the_configuration_is_read_as_published(toy):
     cfg = nh.NemotronHConfig.from_dict(toy)
     assert cfg.pattern == "MEMEM*E" and cfg.layers == 7
     assert (cfg.count("M"), cfg.count("E"), cfg.count("*")) == (3, 3, 1)
-    assert cfg.d_inner == 64 and cfg.conv_dim == 64 + 2 * 2 * 16
+    assert cfg.mamba.d_inner == 64 and cfg.mamba.conv_dim == 64 + 2 * 2 * 16
     assert (cfg.n_routed_experts, cfg.experts, cfg.expert0) == (16, 4, 4)
     assert (cfg.vocab, cfg.vocab0, cfg.shared_width) == (32, 32, 48)
     shapes = nh.param_shapes(cfg)
@@ -372,7 +372,7 @@ def test_stage_scopes_are_in_the_program_text(model):
     # the toy's state (32 lanes over a state of 16) is a shape the step's
     # kernel refuses: its decode takes the jnp step behind the restore
     # loop (tests/test_ssm_step.py has the kernel's program without it)
-    assert "whole lanes" in nh.step_refusal(state["mamba"][0])
+    assert "whole lanes" in mamba2.step_refusal(state["mamba"][0])
     assert "nns.model/ssm_restore" in decode
     assert "nns.model/ssm_restore" not in prefill
 

@@ -14,6 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from nnstreamer_tpu.models import mamba2
 from nnstreamer_tpu.models import nemotron_h as nh
 from nnstreamer_tpu.ops import kernels
 
@@ -73,8 +74,8 @@ def test_the_kernel_is_the_jnp_step_behind_the_restore_loop(
     dx, b, c = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
                 for shape in ((streams, groups, lanes),
                               (streams, groups, n), (streams, groups, n)))
-    assert nh.step_refusal(layer) is None
-    start = nh.restored([layer], restore)[0]["ssm"]
+    assert mamba2.step_refusal(layer) is None
+    start = mamba2.restored([layer], restore)[0]["ssm"]
     want, want_y = kernels.ssm_decode_step_reference(start, a, dx, b, c)
     # what a stream that restores had live is never read
     stale = jnp.where(restore[:, None, None, None], jnp.nan, layer["ssm"])
@@ -89,6 +90,36 @@ def test_the_kernel_is_the_jnp_step_behind_the_restore_loop(
         assert _close(got[row], (from_snap if snapshot else from_live)[row])
         assert not _close(got[row],
                           (from_live if snapshot else from_snap)[row])
+
+
+@pytest.mark.parametrize("pattern", ["first_and_last", "none"])
+def test_the_kernel_at_a_state_of_two_lane_tiles(pattern):
+    """`falconh1.decode4k`'s geometry, 2 groups of a state of 256 (two
+    lane tiles: ``B | C`` is 4 rows of 256 a stream) over 2,048 lanes
+    (16 heads of 128), 4 MiB a stream, at 8 streams: four a grid step
+    take both sets of buffers to exactly the budget.  State and ``y``
+    against the ``jnp`` step from each stream's own source."""
+    streams, groups, n, lanes = shape = (8, 2, 256, 2048)
+    assert kernels.ssm_decode_step_refusal(
+        shape, {jnp.dtype(jnp.float32)}) is None
+    assert kernels.ssm_step_streams(streams, groups * n * lanes * 4) == 4
+    assert 2 * 4 * groups * n * lanes * 4 == kernels._SSM_VMEM_BUDGET
+    rng = np.random.default_rng(5)
+    live, snap = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+                  for _ in range(2))
+    restore = jnp.asarray((PATTERNS[pattern] * 2)[:streams], bool)
+    a = jnp.asarray(rng.uniform(0.5, 1.0, (streams, groups, lanes)),
+                    jnp.float32)
+    dx, b, c = (jnp.asarray(rng.standard_normal(dims), jnp.float32)
+                for dims in ((streams, groups, lanes),
+                             (streams, groups, n), (streams, groups, n)))
+    start = jnp.where(restore[:, None, None, None], snap, live)
+    want, want_y = kernels.ssm_decode_step_reference(start, a, dx, b, c)
+    stale = jnp.where(restore[:, None, None, None], jnp.nan, live)
+    got, y = jax.jit(kernels.ssm_decode_step)(stale, snap, restore, a, dx,
+                                              b, c)
+    # y sums 256 products a lane: float32 rounding in another order
+    assert _close(got, want) and _close(y, want_y, tol=2e-5)
 
 
 @pytest.mark.parametrize("pattern", sorted(PATTERNS))
@@ -162,7 +193,7 @@ def test_a_refused_shape_takes_the_jnp_step(toy, monkeypatch, sizes, dtype,
     state["mamba"] = [dict(layer, ssm=layer["ssm"].astype(dtype),
                            ssm_snap=layer["ssm_snap"].astype(dtype))
                       for layer in state["mamba"]]
-    assert reason in nh.step_refusal(state["mamba"][0])
+    assert reason in mamba2.step_refusal(state["mamba"][0])
 
     def gone(*a, **k):
         raise AssertionError("the kernel was asked")

@@ -481,3 +481,93 @@ def test_the_two_cache_cells_programs_fit_the_chip(one_chip, monkeypatch,
              if "bf16" + shape in line.split(" = ", 1)[-1].split("(")[0]
              and (" copy(" in line or " transpose(" in line)]
     assert not moved, moved
+
+
+def test_the_falcon_cells_kernels_compile_at_its_shapes(one_chip, monkeypatch):
+    """`falconh1.decode4k`'s two kernels as Mosaic calls: the state
+    update at `[128, 2, 256, 2048]` float32 (4 MiB a stream: four
+    streams a grid step take both sets of buffers to exactly the 32 MiB
+    budget; `B | C` is 4 rows of 256 a stream and the state axis two
+    lane tiles), and attention of 4 groups of FIVE heads (a tile of 16
+    holds them) over caches of 4,096.  (The dense MLPs are XLA's
+    products: `PERF.md` section 6, PR 47.)"""
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    state, row, column = (128, 2, 256, 2048), (128, 2, 2048), (128, 2, 256)
+    assert kernels.ssm_decode_step_refusal(
+        state, {jnp.dtype(jnp.float32)}) is None
+    assert kernels.ssm_step_streams(128, 2 * 256 * 2048 * 4) == 4
+    compiled = jax.jit(kernels.ssm_decode_step, donate_argnums=(0,)).lower(
+        shape(state), shape(state), shape((128,), jnp.bool_), shape(row),
+        shape(row), shape(column), shape(column)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 128 * 2 * 256 * 2048 * 4
+    assert memory.temp_size_in_bytes < 1 << 20
+
+    bf16 = functools.partial(shape, dtype=jnp.bfloat16)
+    fn = jax.jit(functools.partial(kernels.gqa_decode_attention,
+                                   window=4096, scale=128 ** -0.5))
+    compiled = fn.lower(bf16((128, 4, 5, 128)), bf16((128, 4, 4096, 128)),
+                        bf16((128, 4, 4096, 128)),
+                        shape((128,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("entry,temp_mb", [("decode", 96), ("prefill", 1536)])
+def test_the_falcon_cells_programs_fit_the_chip(one_chip, monkeypatch, entry,
+                                                temp_mb, capsys):
+    """`falconh1.decode4k`'s two programs at the cell's sizes (4 layers,
+    128 streams, 4,096 positions, chunks of 2,048; 4.1 GB of weights and
+    8.6 GB of state as arguments): both kinds of state are updated in
+    the donated buffers.  One layer's recurrent state is 537 MB and one
+    layer's K or V as much, so a copy of either shows in the
+    temporaries.  A decode step holds four state updates and four
+    attention calls as kernels."""
+    import json
+    import os
+
+    from nnstreamer_tpu.models import falcon_h1 as fh
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "falcon_h1_34b_stage4_vocab8.json")
+    with open(path) as f:
+        cfg = fh.FalconH1Config.from_dict(json.load(f))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: fh.init_params(cfg, 0))
+    state = jax.eval_shape(lambda: fh.init_state(cfg, params, 128, 4096))
+    held = [sum(a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(tree))
+            for tree in (params, state)]
+    assert 4.10e9 < held[0] < 4.13e9 and 8.60e9 < held[1] < 8.65e9
+
+    def i32(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+
+    fn, inputs = {"decode": (fh.decode, [i32(128), i32(128)]),
+                  "prefill": (fh.prefill, [i32(2048), i32(1), i32(1),
+                                           i32(1)])}[entry]
+    compiled = jax.jit(functools.partial(fn, cfg), donate_argnums=(1,)) \
+        .lower(on_chip(params), on_chip(state), *inputs).compile()
+    memory = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nfalconh1 {entry}: {memory}")
+    assert memory.alias_size_in_bytes >= held[1]
+    assert memory.temp_size_in_bytes < temp_mb << 20
+    # arguments and temporaries together leave the chip's 16 GB room
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 14.8e9
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= (8 if entry == "decode" else 0)
+    assert "ssm_restore" not in text

@@ -1,9 +1,10 @@
 """Multi-head latent attention (MLA) as the token models run it
 (``deepseek_v2.py``, ``longcat_flash.py``): a token's queries through
 the low-rank ``q`` stream, what the cache keeps of it, and the two paths
-through one set of weights, the expanded form for a prefill chunk and
-the absorbed form for a decode step (``ops/kernels.py``
-``latent_decode_attention``).
+through one set of weights, the expanded form for a prefill chunk
+(``ops/kernels.py`` ``latent_prefill_attention``, or its reference for a
+shape it refuses) and the absorbed form for a decode step
+(``latent_decode_attention``).
 
 A cache keeps of a token its ``(c_kv, k_r)`` after norm and rotation,
 ``latent`` values, in the rows ``ops/kernels.py`` ``latent_cache_row``
@@ -16,7 +17,8 @@ whole tiles.  (The TPU's compiler lays a ``[.., positions, 576]`` array
 out with positions minor, and every product over it then copied the
 whole cache; a row a position padded to whole lanes took 640 values for
 576, a tenth of every fetch.)  A chunk's rows are packed before they
-are written and a key block is unpacked after it is sliced; a decode
+are written (the prefill kernel scores them as they lie; its reference
+unpacks a key block after it is sliced); a decode
 step reads the one row a stream its token falls in, places the token
 and writes the row back; the decode kernel works on the packed rows as
 they lie.  Nothing reshapes a cache: that is a copy of it.  Other sizes
@@ -44,8 +46,6 @@ in, which names the sizes as the published configs do
 
 from __future__ import annotations
 
-import math
-
 try:
     import jax
     import jax.numpy as jnp
@@ -54,8 +54,9 @@ except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
 from ..ops import kernels
+from ..utils import profile as _profile
 from . import moe
-from .attention import NEG, rope
+from .attention import rope
 
 _rms, _mm, _precision = moe.rms, moe.mm, moe.precision
 
@@ -103,70 +104,43 @@ def kv_b(cfg, p):
                              cfg.qk_nope_head_dim + cfg.v_head_dim)
 
 
-def attn_prefill(cfg, p, x, cache, slot, start, key_block: int = 1024):
+def attn_prefill(cfg, p, x, cache, slot, start):
     """Expanded MLA over a chunk ``x [C, hidden]`` of stream ``slot``
-    whose first token is at ``start``: writes the chunk's latent rows,
-    then attends to rows ``[0, start + C)`` block by block (keys and
-    values rebuilt from the latent rows, a running softmax, so no
-    ``[heads, chunk, positions]`` score tensor exists).  Returns the
-    held heads' partial output and the cache."""
+    whose first token is at ``start`` (a multiple of ``C``): writes the
+    chunk's latent rows, then attends to rows ``[0, start + C)`` block
+    by block (keys and values rebuilt from the latent rows, a running
+    softmax, so no ``[heads, chunk, positions]`` score tensor exists).
+    Returns the held heads' partial output and the cache.
+
+    One algorithm, two programs, chosen from the shapes: the kernel
+    (``ops/kernels.py`` ``latent_prefill_attention``: a query block's
+    scores never leave fast memory) wherever its refusal has nothing to
+    say, its reference (XLA's own ``while`` over the key blocks)
+    everywhere else.  The set-up span this is traced under says which
+    (``utils/profile.py`` ``note``)."""
     c = x.shape[0]
     rank, per = cfg.kv_lora_rank, _row(cfg)[0]
     positions = start + jnp.arange(c, dtype=jnp.int32)
     cos, sin = cfg.cos_sin(positions)
     q_nope, q_rope = queries(cfg, p, x, cos, sin)
-    # a chunk starts at a multiple of its own length (the caller's
-    # contract), so it starts a cache row, and whole key blocks never
-    # reach beyond start + C
-    kb = math.gcd(int(key_block), c)
-    if kb % per:
-        raise ValueError(f"mla: key blocks of {kb} positions (a chunk of "
-                         f"{c}) are not whole cache rows of {per}")
     with jax.named_scope("cache_write"):
         rows = kernels.latent_pack(
             latent_rows(cfg, p, x, cos, sin, cache.dtype), rank)
         cache = lax.dynamic_update_slice(cache, rows[None],
                                          (slot, start // per, 0))
-    w_kvb, scale = kv_b(cfg, p), cfg.score_scale
-    hp = _precision(p["kv_b"])
-
-    def body(j, carry):
-        m, l, acc = carry
-        blk = kernels.latent_unpack(lax.dynamic_slice(
-            cache, (slot, j * (kb // per), 0),
-            (1, kb // per, cache.shape[2]))[0], rank,
-            cfg.qk_rope_head_dim).astype(x.dtype)
-        blk_r = blk[:, rank:]
-        blk = blk[:, :rank]
-        kv = jnp.einsum("kr,rhd->khd", blk, w_kvb,
-                        preferred_element_type=jnp.float32,
-                        precision=hp).astype(x.dtype)
-        k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
-        s = jnp.einsum("chd,khd->hck", q_nope, k_nope,
-                       preferred_element_type=jnp.float32, precision=hp) \
-            + jnp.einsum("chd,kd->hck", q_rope, blk_r,
-                         preferred_element_type=jnp.float32, precision=hp)
-        key_pos = j * kb + jnp.arange(kb, dtype=jnp.int32)
-        s = jnp.where(key_pos[None, None, :] <= positions[None, :, None],
-                      s * scale, NEG)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        alpha = jnp.exp(m - m_new)
-        prob = jnp.exp(s - m_new[..., None])
-        l = l * alpha + prob.sum(axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "hck,khd->hcd", prob.astype(x.dtype), v,
-            preferred_element_type=jnp.float32, precision=hp)
-        return m_new, l, acc
-
-    blocks = (start + c + kb - 1) // kb
-    m0 = jnp.full((cfg.heads, c), NEG, jnp.float32)
-    _, l, acc = lax.fori_loop(
-        0, blocks, body,
-        (m0, jnp.zeros_like(m0),
-         jnp.zeros((cfg.heads, c, cfg.v_head_dim), jnp.float32)))
-    o = (acc / l[..., None]).astype(x.dtype)
-    o = o.transpose(1, 0, 2).reshape(c, cfg.heads * cfg.v_head_dim)
-    return _mm(o, p["o"]).astype(x.dtype), cache
+    w_kvb = kv_b(cfg, p)
+    refusal = kernels.latent_prefill_attention_refusal(
+        q_nope.shape, q_rope.shape, cache.shape, w_kvb.shape,
+        {x.dtype, cache.dtype, w_kvb.dtype})
+    shapes = f"attn_prefill {c} x {cfg.heads} heads on {tuple(cache.shape)} " \
+             f"{cache.dtype.name}"
+    _profile.note(f"{shapes}: the jnp loop ({refusal})" if refusal
+                  else f"{shapes}: the kernel")
+    attend = kernels.latent_prefill_attention_reference if refusal \
+        else kernels.latent_prefill_attention
+    o = attend(q_nope, q_rope, cache, slot, start, w_kvb, cfg.score_scale)
+    return _mm(o.reshape(c, cfg.heads * cfg.v_head_dim),
+               p["o"]).astype(x.dtype), cache
 
 
 def attn_decode(cfg, p, x, cache, positions):

@@ -1,6 +1,7 @@
 """Pallas TPU kernels: fused normalize/typecast, flash attention, short
-attention, latent decode attention, grouped-query decode attention, the
-routed experts' grouped product and the Mamba-2 decode step.
+attention, latent decode attention, latent prefill attention,
+grouped-query decode attention, the routed experts' grouped product and
+the Mamba-2 decode step.
 
 Parity/role:
 - ``scale_bias_cast`` is the tensor_transform arithmetic prologue
@@ -27,6 +28,12 @@ Parity/role:
   stream's live rows, a chunk read once for both products.  A row of
   the cache packs two positions where the sizes allow
   (``latent_cache_row``), and is scored as it lies.
+- ``latent_prefill_attention`` is expanded latent attention of one
+  chunk of one stream over the same cache (``models/mla.py``'s prefill
+  chunk): the grid walks (head group, key block), a step rebuilds the
+  block's keys and values from the latent rows once and scores the
+  chunk's queries against them a block of rows at a time, so the scores
+  exist in fast memory only.
 - ``gqa_decode_attention`` is grouped-query attention of one token a
   stream over separate K and V caches (``models/smallthinker.py``'s
   decode step, ``models/nemotron_h.py``'s and
@@ -56,10 +63,11 @@ interpreter on CPU backends (tests).  ``scale_bias_cast`` and
 meet the tiling constraints — lane dim a multiple of 128, sublane dim a
 multiple of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32
 of 1-byte elements); ``short_attention``, ``latent_decode_attention``,
-``gqa_decode_attention``, ``grouped_gated_product`` and
-``ssm_decode_step`` refuse a shape they cannot take (``*_refusal`` says
-why: axes that do not fill tiles, a type the kernel is not written for,
-blocks over a fast-memory budget) and leave the choice to the caller.
+``latent_prefill_attention``, ``gqa_decode_attention``,
+``grouped_gated_product`` and ``ssm_decode_step`` refuse a shape they
+cannot take (``*_refusal`` says why: axes that do not fill tiles, a
+type the kernel is not written for, blocks over a fast-memory budget)
+and leave the choice to the caller.
 Either way the ``*_available`` / ``*_refusal`` predicates are the whole
 eligibility rule, so the fallback is a decision made here, never an
 exception caught somewhere.
@@ -1031,6 +1039,424 @@ def _latent_decode_walk_call(b: int, heads: int, rank: int, per: int,
         return call(*operands)
 
     return jax.jit(latent_decode_attention)
+
+
+# -- latent prefill attention -------------------------------------------------
+
+#: positions a key block of :func:`latent_prefill_attention` holds (a
+#: chunk shorter than that is one block)
+_PREFILL_KEY_BLOCK = 1024
+#: fast memory a grid step of :func:`latent_prefill_attention` may take:
+#: a head group's queries, output and running softmax whole, a key
+#: block's rows and what is rebuilt from them, a query block's scores
+#: (the call states it through ``vmem_limit_bytes``)
+_PREFILL_VMEM_BUDGET = 64 << 20
+#: heads a grid step takes and query rows a pass of the inner loop
+#: scores, where the budget allows (``PERF.md`` section 6, PR 48: the
+#: sweep on the chip)
+_PREFILL_HEADS_A_STEP = 2
+_PREFILL_QUERY_ROWS = 512
+
+
+def _prefill_key_block(c: int, key_block: int = _PREFILL_KEY_BLOCK) -> int:
+    """Positions a key block of a chunk of ``c`` tokens holds: whole
+    blocks tile the chunk, so that they end with it."""
+    return int(np.gcd(int(key_block), c))
+
+
+def _prefill_rope_lanes(rank: int, rope: int) -> int:
+    """Lanes the rotary keys of a cache row take: a packed row's one
+    tile, a padded row's whole tiles after the latent part."""
+    per, width = latent_cache_row(rank, rope)
+    return _LANE if per > 1 else width - rank
+
+
+def _prefill_vmem(c: int, group: int, tq: int, kb: int, rank: int,
+                  rope: int, nope: int, v: int, dtype) -> int:
+    """Bytes a grid step of :func:`latent_prefill_attention` holds."""
+    size = np.dtype(dtype).itemsize
+    per, width = latent_cache_row(rank, rope)
+    wide = nope + _prefill_rope_lanes(rank, rope)
+    blocks = 2 * size * (c * group * (per * wide + v)       # q and o
+                         + rank * group * (nope + v) + kb // per * width)
+    stats = 4 * group * c * (2 * _LANE + v)
+    rebuilt = size * kb * (wide + v)
+    # a pass's scores, probabilities and their cast in flight; the
+    # mask's offsets
+    scores = 4 * 4 * tq * kb + 4 * tq * kb // per
+    return blocks + stats + rebuilt + scores
+
+
+def latent_prefill_tiles(c: int, heads: int, rank: int, rope: int,
+                         nope: int, v: int, dtype) -> tuple:
+    """``(heads a grid step, query rows a pass)`` of
+    :func:`latent_prefill_attention` for a chunk of ``c`` tokens: the
+    largest divisors of ``heads`` and ``c`` up to ``_PREFILL_HEADS_A_STEP``
+    and ``_PREFILL_QUERY_ROWS`` (whole tiles of rows) whose step fits
+    ``_PREFILL_VMEM_BUDGET``; ``(0, 0)`` where none does."""
+    kb = _prefill_key_block(c)
+    tile = _sublane(dtype)
+    for group in range(min(_PREFILL_HEADS_A_STEP, heads), 0, -1):
+        if heads % group:
+            continue
+        for tq in range(min(_PREFILL_QUERY_ROWS, c) // tile * tile, 0, -tile):
+            if c % tq == 0 and _prefill_vmem(
+                    c, group, tq, kb, rank, rope, nope, v,
+                    dtype) <= _PREFILL_VMEM_BUDGET:
+                return group, tq
+    return 0, 0
+
+
+def latent_prefill_attention_refusal(q_nope_shape, q_rope_shape, cache_shape,
+                                     w_shape, dtypes) -> Optional[str]:
+    """Why :func:`latent_prefill_attention` cannot take these shapes, or
+    None: ``q_nope [C, heads, nope]`` and ``q_rope [C, heads, rope]``
+    beside ``w_kvb [rank, heads, nope + v]`` and a cache ``[streams,
+    rows, width]`` laid out as :func:`latent_cache_row` says, all of ONE
+    type (``dtypes``: the set of theirs), bf16 or float32; ``rank``,
+    ``nope`` and ``v`` whole lanes; a key block (1,024 positions, or the
+    chunk where it is shorter) whose every part (the positions ``per * r
+    + h`` of its rows) is whole lanes of scores; a cache that holds the
+    chunk; and a tiling that fits."""
+    names = sorted(np.dtype(d).name for d in dtypes)
+    if names not in (["bfloat16"], ["float32"]):
+        return f"operands of {', '.join(names)}: all bfloat16 or all float32"
+    if len(q_nope_shape) != 3 or len(q_rope_shape) != 3 \
+            or len(w_shape) != 3 or len(cache_shape) != 3 \
+            or tuple(q_nope_shape[:2]) != tuple(q_rope_shape[:2]) \
+            or w_shape[1] != q_nope_shape[1] \
+            or w_shape[2] <= q_nope_shape[2]:
+        return f"q_nope {tuple(q_nope_shape)}, q_rope " \
+               f"{tuple(q_rope_shape)}, w_kvb {tuple(w_shape)} and cache " \
+               f"{tuple(cache_shape)} are not [C, heads, nope], [C, heads, " \
+               "rope], [rank, heads, nope + v] and [streams, rows, width]"
+    (c, heads, nope), rope, rank = q_nope_shape, q_rope_shape[2], w_shape[0]
+    v = w_shape[2] - nope
+    if rank % _LANE or nope % _LANE or v % _LANE:
+        return f"widths {rank} (latent), {nope} (nope) and {v} (values) " \
+               f"are not whole lanes of {_LANE}"
+    per, width = latent_cache_row(rank, rope)
+    if width != cache_shape[2]:
+        return f"tokens of {rank} latent and {rope} rotary values want " \
+               f"cache rows of {width} for {per} positions, not " \
+               f"{cache_shape[2]}"
+    kb = _prefill_key_block(c)
+    if kb % (per * _LANE):
+        return f"a key block of {kb} positions (a chunk of {c}) is not " \
+               f"whole lanes of {_LANE} for each of a row's {per}"
+    if cache_shape[1] * per < c:
+        return f"a cache of {cache_shape[1] * per} positions does not " \
+               f"hold a chunk of {c}"
+    if not latent_prefill_tiles(c, heads, rank, rope, nope, v, names[0])[0]:
+        return f"no step of a chunk of {c} fits " \
+               f"{_PREFILL_VMEM_BUDGET >> 20} MiB"
+    return None
+
+
+def latent_prefill_attention_reference(q_nope, q_rope, cache, slot, start,
+                                       w_kvb, scale: float,
+                                       key_block: int = _PREFILL_KEY_BLOCK):
+    """The kernel's mathematics in jnp, and the path ``models/mla.py``
+    takes for a shape the kernel refuses: XLA's own ``while`` over the
+    key blocks up to ``start + C``, each block's keys and values rebuilt
+    from its rows (unpacked) and rounded to the queries' type, the
+    scores of the whole chunk against the block in float32, a running
+    softmax.  (On the chip each iteration writes and reads those scores
+    through HBM several times: ``PERF.md`` section 6, PR 48.)"""
+    import jax
+    import jax.numpy as jnp
+
+    c, heads, nope = q_nope.shape
+    rank, rope = w_kvb.shape[0], q_rope.shape[2]
+    per, _ = latent_cache_row(rank, rope)
+    hp = jax.lax.Precision.HIGHEST if w_kvb.dtype == jnp.float32 else None
+    # a chunk starts at a multiple of its own length (the caller's
+    # contract), so it starts a cache row, and whole key blocks never
+    # reach beyond start + C
+    kb = _prefill_key_block(c, key_block)
+    if kb % per:
+        raise ValueError(f"latent prefill: key blocks of {kb} positions (a "
+                         f"chunk of {c}) are not whole cache rows of {per}")
+    positions = start + jnp.arange(c, dtype=jnp.int32)
+
+    def body(j, carry):
+        m, l, acc = carry
+        blk = latent_unpack(jax.lax.dynamic_slice(
+            cache, (slot, j * (kb // per), 0),
+            (1, kb // per, cache.shape[2]))[0], rank, rope
+        ).astype(q_nope.dtype)
+        blk_r = blk[:, rank:]
+        blk = blk[:, :rank]
+        kv = jnp.einsum("kr,rhd->khd", blk, w_kvb,
+                        preferred_element_type=jnp.float32,
+                        precision=hp).astype(q_nope.dtype)
+        k_nope, v = jnp.split(kv, [nope], axis=-1)
+        s = jnp.einsum("chd,khd->hck", q_nope, k_nope,
+                       preferred_element_type=jnp.float32, precision=hp) \
+            + jnp.einsum("chd,kd->hck", q_rope, blk_r,
+                         preferred_element_type=jnp.float32, precision=hp)
+        key_pos = j * kb + jnp.arange(kb, dtype=jnp.int32)
+        s = jnp.where(key_pos[None, None, :] <= positions[None, :, None],
+                      s * scale, -1e30)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        prob = jnp.exp(s - m_new[..., None])
+        l = l * alpha + prob.sum(axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hck,khd->hcd", prob.astype(q_nope.dtype), v,
+            preferred_element_type=jnp.float32, precision=hp)
+        return m_new, l, acc
+
+    blocks = (start + c + kb - 1) // kb
+    m0 = jnp.full((heads, c), -1e30, jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        0, blocks, body,
+        (m0, jnp.zeros_like(m0),
+         jnp.zeros((heads, c, w_kvb.shape[2] - nope), jnp.float32)))
+    return (acc / l[..., None]).astype(q_nope.dtype).transpose(1, 0, 2)
+
+
+def latent_prefill_attention(q_nope, q_rope, cache, slot, start, w_kvb,
+                             scale: float):
+    """Expanded latent attention of one chunk of one stream over its
+    latent cache: ``q_nope [C, heads, nope]`` and ``q_rope [C, heads,
+    rope]`` (rotated), ``cache [streams, positions / per, width]`` as
+    :func:`latent_cache_row` lays it out, with the chunk's own rows
+    already written, ``slot`` and ``start`` (int32 scalars: the stream,
+    and the chunk's first position, a multiple of ``C``), ``w_kvb
+    [rank, heads, nope + v]``.  Returns ``[C, heads, v]`` in the
+    queries' type: each query's softmax over positions ``0..`` its own,
+    keys and values rebuilt from the latent rows by ``w_kvb``.
+
+    The mathematics is :func:`latent_prefill_attention_reference`'s: a
+    key block's keys and values are rounded to the queries' type, the
+    scores (nope and rotary part in one float32 accumulation) are scaled
+    and masked by ``key position <= query position``, the running
+    maximum, normaliser and accumulator are float32, the probabilities
+    are rounded to the queries' type for the value product and the
+    normaliser divides once at the end.  What differs is where the
+    scores live: a query block's ``[rows, key block]`` scores and
+    probabilities exist only in fast memory, where XLA's loop writes
+    ``[heads, C, key block]`` of them to HBM and reads them back several
+    times an iteration.
+
+    The grid walks (head group, key block).  A step holds its heads'
+    queries, output and running softmax for the WHOLE chunk (they stay
+    where they are while the key blocks pass), takes the block's rows
+    from the cache through the pipeline (``slot`` and ``start`` are
+    prefetched, so the index map picks the stream), rebuilds each
+    head's keys and values from them ONCE, and then scores the chunk's
+    queries against them a block of rows at a time.  The grid's extent
+    is every whole block of the cache; a chunk at ``start`` needs
+    ``(start + C) / 1024`` of them, and steps beyond compute nothing and
+    name the last live block's rows, so nothing is copied for them (as
+    :func:`grouped_gated_product` does for blocks not in use).  A query
+    block that lies wholly before a key block is skipped (every score
+    of it is masked: it adds exact zeros), one the diagonal passes
+    through is masked, every other is not.
+
+    A packed row is never unpacked (a softmax does not care in which
+    order it meets its positions): the positions ``per * r + h`` of the
+    block's rows ``r`` are part ``h``, rebuilt from the ``h``-th latent
+    part, and scored against the queries' ``[q_nope | q_rope on the
+    h-th key's lanes of the rotary tile]``, laid out once a chunk before
+    the call: the nope and the rotary product are ONE contraction on
+    the matrix unit.  A shape
+    :func:`latent_prefill_attention_refusal` names is an error: the
+    caller chooses."""
+    refusal = latent_prefill_attention_refusal(
+        q_nope.shape, q_rope.shape, cache.shape, w_kvb.shape,
+        {q_nope.dtype, q_rope.dtype, cache.dtype, w_kvb.dtype})
+    if refusal:
+        raise ValueError(f"latent_prefill_attention: {refusal}")
+    c, heads, nope = q_nope.shape
+    group, tq = latent_prefill_tiles(
+        c, heads, w_kvb.shape[0], q_rope.shape[2], nope,
+        w_kvb.shape[2] - nope, q_nope.dtype)
+    return _latent_prefill(q_nope, q_rope, cache, slot, start, w_kvb, scale,
+                           group, tq, _prefill_key_block(c))
+
+
+def _latent_prefill(q_nope, q_rope, cache, slot, start, w_kvb, scale: float,
+                    group: int, tq: int, kb: int):
+    """:func:`latent_prefill_attention` by an explicit tiling (the tests
+    and the chip's sweeps choose theirs): ``group`` heads a grid step,
+    ``tq`` query rows a pass, key blocks of ``kb`` positions."""
+    import jax
+    import jax.numpy as jnp
+
+    c, heads, nope = q_nope.shape
+    rank, rope = w_kvb.shape[0], q_rope.shape[2]
+    v = w_kvb.shape[2] - nope
+    per, _ = latent_cache_row(rank, rope)
+    lanes = _prefill_rope_lanes(rank, rope)
+    call = _latent_prefill_call(
+        c, heads, nope, rope, v, rank, cache.shape[1], float(scale),
+        np.dtype(q_nope.dtype).name, group, tq, kb, _interpret())
+    # the stage a trace books the kernel's time to (a jit is no scope),
+    # and with it the layout of its queries
+    with jax.named_scope("latent_prefill_attention"):
+        # a head's queries once a part of a row: beside the nope query
+        # the rotary one on that part's lanes of the rotary tile, zeros
+        # elsewhere
+        q = jnp.concatenate([
+            jnp.concatenate([q_nope, jnp.pad(q_rope, (
+                (0, 0), (0, 0), (h * rope, lanes - (h + 1) * rope)))],
+                axis=-1) for h in range(per)], axis=-1)
+        out = call(jnp.reshape(slot, (1,)).astype(jnp.int32),
+                   jnp.reshape(start, (1,)).astype(jnp.int32),
+                   q.reshape(c, -1), w_kvb.reshape(rank, -1), cache)
+    return out.reshape(c, heads, v)
+
+
+@functools.lru_cache(maxsize=16)
+def _latent_prefill_call(c: int, heads: int, nope: int, rope: int, v: int,
+                         rank: int, rows: int, scale: float, dtype: str,
+                         group: int, tq: int, kb: int, interpret: bool):
+    """The jitted call of :func:`latent_prefill_attention` for one
+    shape, built once: a model's layers share the function, so a
+    program that attends in eight sub-blocks traces and lowers the
+    kernel once (as :func:`_latent_decode_walk_call` does).  A cache of
+    ``rows`` rows."""
+    import jax.numpy as jnp
+
+    jax, pl, pltpu = _pl()
+    per, width = latent_cache_row(rank, rope)
+    wide = nope + _prefill_rope_lanes(rank, rope)
+    n = kb // per                        # rows, and a part's positions
+    blocks = rows // n                   # whole key blocks of the cache
+    hp = jax.lax.Precision.HIGHEST if dtype == "float32" else None
+    nt = (((1,), (1,)), ((), ()))        # q k^T without a transpose
+
+    def live(start_ref):
+        """Key blocks a chunk at ``start`` attends to."""
+        return jnp.clip((start_ref[0] + c) // kb, 1, blocks)
+
+    def kernel(slot_ref, start_ref, q_ref, w_ref, rows_ref, o_ref,
+               keys, values, ahead_ref, m_ref, l_ref, acc_ref):
+        j = pl.program_id(1)
+        start = start_ref[0]
+
+        @pl.when(j == 0)
+        def _first():
+            m_ref[...] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+            # how far a part's key lies ahead of a block's query, less
+            # what the two blocks' own places add: a masked pair then
+            # costs a comparison and a choice an element (built there,
+            # the two counters and their difference doubled its time)
+            ahead_ref[...] = per * jax.lax.broadcasted_iota(
+                jnp.int32, (tq, n), 1) - jax.lax.broadcasted_iota(
+                    jnp.int32, (tq, n), 0)
+
+        def attend(g: int, i, masked: bool):
+            """The online softmax of head ``g``'s query block ``i``
+            against the key block rebuilt in ``keys`` and ``values``."""
+            at = pl.ds(pl.multiple_of(i * tq, tq), tq)
+            scores = []
+            for h in range(per):
+                s = jax.lax.dot_general(
+                    q_ref[at, pl.ds((g * per + h) * wide, wide)], keys[h],
+                    nt, preferred_element_type=jnp.float32,
+                    precision=hp) * scale                      # (tq, n)
+                if masked:
+                    s = jnp.where(
+                        ahead_ref[...] <= start + i * tq - j * kb - h,
+                        s, -1e30)
+                scores.append(s)
+            # running max / normaliser replicated across a lane width,
+            # as in flash_attention above
+            m_prev = m_new = m_ref[g, at, :]
+            for s in scores:
+                m_new = jnp.maximum(m_new, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            l_new, acc = l_ref[g, at, :] * alpha, \
+                acc_ref[g, at, :] * alpha[:, :1]
+            for h, s in enumerate(scores):
+                p = jnp.exp(s - m_new[:, :1])
+                l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
+                acc = acc + jax.lax.dot_general(
+                    p.astype(values.dtype), values[h],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32, precision=hp)
+            m_ref[g, at, :], l_ref[g, at, :], acc_ref[g, at, :] = \
+                m_new, l_new, acc
+
+        @pl.when(j < live(start_ref))
+        def _block():
+            for h in range(per):         # the rotary keys: every head's
+                keys[h, :, nope:] = rows_ref[:, per * rank:]
+            for g in range(group):
+                for h in range(per):
+                    kv = jax.lax.dot_general(
+                        rows_ref[:, h * rank:(h + 1) * rank],
+                        w_ref[:, g * (nope + v):(g + 1) * (nope + v)],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                        precision=hp).astype(keys.dtype)
+                    keys[h, :, :nope] = kv[:, :nope]
+                    values[h] = kv[:, nope:]
+
+                def query_block(i, carry, g=g):
+                    # the block's first key against the query block's
+                    # last query, its last key against the first
+                    first = start + i * tq
+                    seen = j * kb <= first + tq - 1
+                    cut = j * kb + kb - 1 > first
+                    pl.when(seen & cut)(lambda: attend(g, i, True))
+                    pl.when(seen & jnp.logical_not(cut))(
+                        lambda: attend(g, i, False))
+                    return carry
+
+                jax.lax.fori_loop(0, c // tq, query_block, 0)
+
+        @pl.when(j == live(start_ref) - 1)
+        def _write():
+            for g in range(group):
+                o_ref[:, g * v:(g + 1) * v] = (
+                    acc_ref[g] / l_ref[g][:, :1]).astype(o_ref.dtype)
+
+    def heads_block(hg, j, slot, start):
+        return 0, hg
+
+    def key_block(hg, j, slot, start):
+        # beyond the live blocks the last one named: not copied again
+        return slot[0], jnp.minimum(j, live(start) - 1), 0
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(heads // group, blocks),
+        in_specs=[
+            pl.BlockSpec((c, group * per * wide), heads_block),
+            pl.BlockSpec((rank, group * (nope + v)), heads_block),
+            pl.BlockSpec((None, n, width), key_block),
+        ],
+        out_specs=pl.BlockSpec((c, group * v), heads_block),
+        scratch_shapes=[
+            pltpu.VMEM((per, n, wide), dtype),               # keys
+            pltpu.VMEM((per, n, v), dtype),                  # values
+            pltpu.VMEM((tq, n), jnp.int32),                  # key - query
+            pltpu.VMEM((group, c, _LANE), jnp.float32),      # running max
+            pltpu.VMEM((group, c, _LANE), jnp.float32),      # normaliser
+            pltpu.VMEM((group, c, v), jnp.float32),          # accumulator
+        ])
+    call = pl.pallas_call(
+        kernel, grid_spec=grid,
+        out_shape=jax.ShapeDtypeStruct((c, heads * v), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_prefill_vmem(c, group, tq, kb, rank, rope,
+                                           nope, v, dtype) + (8 << 20)),
+        interpret=interpret)
+
+    # no ``name=``: it would open a scope of its own below the caller's
+    # (``.../attn/latent_prefill_attention``), the stage this call's
+    # device time is booked to.  An inner jit is no scope, and names the
+    # instruction all the same
+    def latent_prefill_attention(*operands):
+        return call(*operands)
+
+    return jax.jit(latent_prefill_attention)
 
 
 # -- grouped-query decode attention -------------------------------------------

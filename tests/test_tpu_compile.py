@@ -378,10 +378,45 @@ def test_latent_decode_attention_compiles_at_64_heads(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("heads,streams,rows", [(32, 32, 8320),
+                                                (64, 128, 2048)],
+                         ids=["dsv2.decode16k", "longcat.decode4k"])
+def test_latent_prefill_attention_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch, heads, streams, rows):
+    """A prefill chunk's attention of both latent-cache cells: 2,048
+    queries of 32 or 64 heads against key blocks of 1,024 positions (512
+    packed rows of 1,152 bf16 values) of a cache of 16,640 or 4,096, two
+    heads a grid step and 512 query rows a pass (29 MB of fast memory,
+    stated by the call), as a Mosaic kernel; one custom call, no copy of
+    the cache beside it: the temporaries are the queries laid out once a
+    part of a row."""
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    operands = [shape((2048, heads, 128)), shape((2048, heads, 64)),
+                shape((streams, rows, 1152)), shape((), jnp.int32),
+                shape((), jnp.int32), shape((512, heads, 256))]
+    assert kernels.latent_prefill_attention_refusal(
+        *(o.shape for o in operands[:3]), operands[5].shape,
+        {jnp.dtype(jnp.bfloat16)}) is None
+    assert kernels.latent_prefill_tiles(
+        2048, heads, 512, 64, 128, 128, jnp.bfloat16) == (2, 512)
+    fn = jax.jit(functools.partial(kernels.latent_prefill_attention,
+                                   scale=0.1147))
+    compiled = fn.lower(*operands).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    # the queries twice a head (2 x 256 lanes), in and out of a relayout
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * 2048 * heads * 512 * 2 + (1 << 20)
+
+
 def _latent_cell(name: str):
     """A latent-cache cell's configuration, entry points and sizes:
-    ``(cfg, params, state, {entry: (function, inputs' lengths)}, custom
-    calls a chunk, more a decode step)``."""
+    ``(cfg, params, state, {entry: (function, inputs' lengths)}, the
+    grouped products' custom calls a chunk or a step, more a decode
+    step, more a chunk)``."""
     import json
     import os
 
@@ -402,13 +437,13 @@ def _latent_cell(name: str):
                                                        16640))
         return cfg, params, state, {
             "decode": (dsv2.decode, (32, 32)),
-            "prefill": (dsv2.prefill, (2048, 1, 1))}, 4, 5
+            "prefill": (dsv2.prefill, (2048, 1, 1))}, 4, 5, 5
     cfg = lc.LongCatFlashConfig.from_dict(raw)
     params = jax.eval_shape(lambda: lc.init_params(cfg, 0))
     state = jax.eval_shape(lambda: lc.init_state(cfg, params, 128, 4096))
     return cfg, params, state, {
         "decode": (lc.decode, (128, 128)),
-        "prefill": (lc.prefill, (2048, 1, 1))}, 4, 8 + 8
+        "prefill": (lc.prefill, (2048, 1, 1))}, 4, 8 + 8, 8
 
 
 @pytest.mark.parametrize("entry,temp_mb", [("decode", 96), ("prefill", 1536)])
@@ -430,10 +465,14 @@ def test_the_two_cache_cells_programs_fit_the_chip(one_chip, monkeypatch,
     temporaries), weights, state and temporaries fit the chip, and a
     decode step attends through the kernel in every cache (five; eight)
     beside the grouped products (four; four and, as one group of one
-    expert, the eight dense MLPs): 9 and 20 custom calls a decode step,
-    4 a chunk, none fallen back to the loop."""
+    expert, the eight dense MLPs): 9 and 20 custom calls a decode step.
+    A chunk attends through `latent_prefill_attention` in every cache
+    too (since PR 48): 9 and 12 custom calls a chunk, none fallen back
+    to the loop, whose `[heads, 2048, 1024]` float32 scores (268 and
+    537 MB) were the prefill programs' largest temporaries."""
     monkeypatch.setattr(kernels, "_interpret", lambda: False)
-    cfg, params, state, entries, chunk_calls, step_calls = _latent_cell(cell)
+    cfg, params, state, entries, product_calls, step_calls, chunk_calls = \
+        _latent_cell(cell)
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
@@ -473,8 +512,8 @@ def test_the_two_cache_cells_programs_fit_the_chip(one_chip, monkeypatch,
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 15.5e9
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == chunk_calls + (
-        step_calls if entry == "decode" else 0)
+    assert text.count("tpu_custom_call") == product_calls + (
+        step_calls if entry == "decode" else chunk_calls)
     # no copy, transposition or relayout of a cache's shape
     shape = "[" + ",".join(str(n) for n in caches[0].shape) + "]"
     moved = [line.strip()[:120] for line in text.splitlines()

@@ -1,17 +1,16 @@
 """Grouped-query attention parts the token models share
 (``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``,
 ``falcon_h1.py``): the rotation
-of q and k, a layer's K and V cache, a prefill chunk's attention over a
-stream's FULL cache, and the decode step on a full cache or a ring
-(``ops/kernels.py`` ``gqa_decode_attention`` where it takes the shapes,
-its ``jnp`` reference where it refuses them) with the rows it fetches.
+of q and k, a layer's K and V cache, a prefill chunk's attention and
+the decode step, each on a full cache or a ring (``ops/kernels.py``
+``gqa_prefill_attention`` and ``gqa_decode_attention`` where they take
+the shapes, their ``jnp`` references where they refuse them) with the
+rows a step fetches.
 How a model makes its q, k and v (norms, rotation, which layers) stays
 with the model.
 """
 
 from __future__ import annotations
-
-import math
 
 import numpy as np
 
@@ -23,6 +22,7 @@ except ImportError:  # pragma: no cover
     jax = jnp = lax = None
 
 from ..ops import kernels
+from ..utils import profile as _profile
 from . import moe
 
 NEG = -1e30
@@ -104,56 +104,66 @@ def decode_rows_fetched(caches, per_group: int, positions, window=None):
         (b, groups, per_group, d), shape, positions, window or total)
 
 
-def full_prefill(qkv, size: int, cache, slot, start, hp,
-                 key_block: int = 1024):
+def _write_rows(cache, slot, at, rows):
+    """``rows [C, kv heads, d]`` into the slots ``at`` of stream
+    ``slot`` of ``cache [streams, kv heads, T, d]``: the stream's own
+    ``[kv heads, T, d]`` taken out, written and put back where it lay.
+    (A scatter into the whole cache made XLA copy the cache into
+    another layout and back, twice a tensor a chunk, and a kernel wants
+    it in the layout it has: ``PERF.md`` section 6, PR 49.)"""
+    one = lax.dynamic_index_in_dim(cache, slot, 0, keepdims=False)
+    one = one.at[:, at].set(rows.transpose(1, 0, 2).astype(cache.dtype))
+    return lax.dynamic_update_index_in_dim(cache, one, slot, 0)
+
+
+def prefill(qkv, size: int, cache, slot, start, window: int, hp):
     """A chunk of ``size`` tokens of stream ``slot`` whose first token
-    is at ``start``, on a cache that holds EVERY position (``{"k",
-    "v"}`` of ``[streams, kv heads, positions, d]``).  ``qkv(positions)``
-    is the model's own ``(q [C, kv heads, heads a group, d], k [C, kv
-    heads, d], v)`` of the chunk.  Writes the chunk's K and V rows, then
-    attends to the stream's cache ``key_block`` rows at a time with a
-    running softmax, position ``p`` seeing ``0 .. p``.  Returns ``(o [C,
-    kv heads, heads a group, d] float32, cache)``.  A padded token's row
-    lies beyond the prompt and is overwritten by the answer before any
-    step reads it."""
+    is at ``start``, on one layer's cache (``{"k", "v"}`` of ``[streams,
+    kv heads, T, d]``): a ring where ``T`` is less than the stream's
+    length (``T >= window + size - 1``, so every position a query of the
+    chunk sees is still there once the chunk is written), a cache of
+    every position where it is not; ``window`` is ``T`` for a layer that
+    sees every position, as :func:`decode_step` has it.
+    ``qkv(positions)`` is the model's own ``(q [C, kv heads, heads a
+    group, d], k [C, kv heads, d], v)`` of the chunk.  Writes the
+    chunk's K and V rows in slots ``position % T``, then attends to the
+    stream's cache a block of keys at a time with a running softmax,
+    position ``p`` seeing ``max(0, p - window + 1) .. p``.  Returns ``(o
+    [C, kv heads, heads a group, d], cache)``; :func:`heads_out` rounds
+    ``o`` to the model's type first thing.  A padded token's row lies
+    beyond the prompt and is overwritten by the answer before any step
+    reads it.
+
+    One algorithm, two programs, chosen from the shapes: the kernel
+    (``ops/kernels.py`` ``gqa_prefill_attention``: a query block's
+    scores never leave fast memory) wherever its refusal has nothing to
+    say, its reference (XLA's own ``while`` over the key blocks)
+    everywhere else.  The set-up span this is traced under says which
+    (``utils/profile.py`` ``note``)."""
     total = cache["k"].shape[2]
     positions = start + jnp.arange(size, dtype=jnp.int32)
     q, k, v = qkv(positions)
-    groups, per, d = q.shape[1:]
     with jax.named_scope("cache_write"):
-        cache = {
-            "k": cache["k"].at[slot, :, positions].set(
-                k.astype(cache["k"].dtype)),
-            "v": cache["v"].at[slot, :, positions].set(
-                v.astype(cache["v"].dtype))}
-    kb = math.gcd(int(key_block), total)
-    scale = d ** -0.5
-
-    def body(j, carry):
-        m, l, acc = carry
-        kj, vj = (lax.dynamic_slice(
-            cache[name], (slot, 0, j * kb, 0),
-            (1, groups, kb, d))[0].astype(q.dtype)
-            for name in ("k", "v"))
-        s = jnp.einsum("cgqd,gkd->gqck", q, kj,
-                       preferred_element_type=jnp.float32, precision=hp)
-        keys = j * kb + jnp.arange(kb, dtype=jnp.int32)
-        seen = keys[None, :] <= positions[:, None]
-        s = jnp.where(seen[None, None], s * scale, NEG)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        alpha = jnp.exp(m - m_new)
-        prob = jnp.exp(s - m_new[..., None])
-        l = l * alpha + prob.sum(axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "gqck,gkd->gqcd", prob.astype(q.dtype), vj,
-            preferred_element_type=jnp.float32, precision=hp)
-        return m_new, l, acc
-
-    # the first block holds position 0, which every query sees: a later
-    # block whose keys are all masked for a query adds nothing to it
-    blocks = jnp.minimum(total // kb, (start + size - 1 + kb) // kb)
-    m0 = jnp.full((groups, per, size), NEG, jnp.float32)
-    _, l, acc = lax.fori_loop(
-        0, blocks, body,
-        (m0, jnp.zeros_like(m0), jnp.zeros(m0.shape + (d,), jnp.float32)))
-    return (acc / l[..., None]).transpose(2, 0, 1, 3), cache   # [C, g, q, d]
+        # a row beyond a cache of every position (a padded chunk's
+        # end) is dropped, not wrapped onto the stream's first rows
+        at = positions % total if window < total else positions
+        cache = {"k": _write_rows(cache["k"], slot, at, k),
+                 "v": _write_rows(cache["v"], slot, at, v)}
+    refusal = kernels.gqa_prefill_attention_refusal(
+        q.shape, cache["k"].shape, cache["v"].shape, window,
+        {q.dtype, cache["k"].dtype, cache["v"].dtype})
+    shapes = f"prefill {size} x {q.shape[1]} x {q.shape[2]} heads, a " \
+             f"window of {window} on {tuple(cache['k'].shape)} " \
+             f"{cache['k'].dtype.name}"
+    _profile.note(f"{shapes}: the jnp loop ({refusal})" if refusal
+                  else f"{shapes}: the kernel")
+    scale = q.shape[3] ** -0.5
+    if refusal:
+        o = kernels.gqa_prefill_attention_reference(
+            q, cache["k"], cache["v"], slot, start, window, scale,
+            precision=hp)
+    else:
+        # the call names its own scope, `.../gqa_prefill_attention`
+        o = kernels.gqa_prefill_attention(q, cache["k"], cache["v"], slot,
+                                          start, window, scale)
+    return o, cache

@@ -302,12 +302,11 @@ def window_prefill(cfg: ExaoneMoeConfig, p, h, cache, slot, start, count):
 
 def full_prefill(cfg: ExaoneMoeConfig, p, h, cache, slot, start):
     """A chunk of stream ``slot`` on a cache of every position
-    (``models/attention.py`` ``full_prefill``): nothing is rotated.
-    Blocks of 512 keys: 64 query heads of 2,048 tokens are 268 MB of
-    scores a block."""
-    o, cache = attention.full_prefill(
+    (``models/attention.py`` ``prefill``, every position seen): nothing
+    is rotated."""
+    o, cache = attention.prefill(
         lambda positions: _qkv(cfg, p, h, positions, False), h.shape[0],
-        cache, slot, start, moe.precision(p["q"]), key_block=512)
+        cache, slot, start, cache["k"].shape[2], moe.precision(p["q"]))
     return attention.heads_out(p, o, h.dtype), cache
 
 

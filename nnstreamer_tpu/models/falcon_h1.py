@@ -224,14 +224,13 @@ def _out(p, o, dtype):
         return attention.heads_out(p, o, dtype)
 
 
-def attn_prefill(cfg: FalconH1Config, p, z, cache, slot, start,
-                 key_block: int = 1024):
+def attn_prefill(cfg: FalconH1Config, p, z, cache, slot, start):
     """A chunk ``z [C, hidden]`` of stream ``slot`` whose first token is
-    at ``start``: ``models/attention.py`` ``full_prefill`` on this
-    model's q, k and v."""
-    o, cache = attention.full_prefill(
+    at ``start``: ``models/attention.py`` ``prefill`` on this model's q,
+    k and v, every position seen."""
+    o, cache = attention.prefill(
         lambda positions: _qkv(cfg, p, z, positions), z.shape[0], cache,
-        slot, start, moe.precision(p["q"]), key_block)
+        slot, start, cache["k"].shape[2], moe.precision(p["q"]))
     return _out(p, o, z.dtype), cache
 
 

@@ -235,14 +235,13 @@ def _qkv(cfg: NemotronHConfig, p, u):
     return q, k, v
 
 
-def attn_prefill(cfg: NemotronHConfig, p, u, cache, slot, start,
-                 key_block: int = 1024):
+def attn_prefill(cfg: NemotronHConfig, p, u, cache, slot, start):
     """A chunk ``u [C, hidden]`` of stream ``slot`` whose first token is
-    at ``start``: ``models/attention.py`` ``full_prefill`` on this
-    model's q, k and v (nothing is rotated)."""
-    o, cache = attention.full_prefill(
+    at ``start``: ``models/attention.py`` ``prefill`` on this model's q,
+    k and v (nothing is rotated), every position seen."""
+    o, cache = attention.prefill(
         lambda _positions: _qkv(cfg, p, u), u.shape[0], cache, slot, start,
-        moe.precision(p["q"]), key_block)
+        cache["k"].shape[2], moe.precision(p["q"]))
     return attention.heads_out(p, o, u.dtype), cache
 
 

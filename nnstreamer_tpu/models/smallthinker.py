@@ -45,20 +45,20 @@ with ``deepseek_v2.py``.
 
 :func:`prefill` runs a chunk of ONE stream (blocked over its cache with
 a running softmax, the exact window mask), :func:`decode` one token of
-EVERY stream (``ops/kernels.py`` ``gqa_decode_attention`` where it
-takes the shapes, its ``jnp`` reference where it refuses them: a head
-size that is not whole lanes, a toy ring).
+EVERY stream (``ops/kernels.py`` ``gqa_prefill_attention`` and
+``gqa_decode_attention`` where they take the shapes, their ``jnp``
+references where they refuse them: a head size that is not whole
+lanes, a toy ring).
 
 Stage scopes (``Documentation/observability.md``): ``embed``,
 ``layerNN/attn_window`` or ``layerNN/attn_full`` (``.../cache_write``
-and ``.../gqa_decode_attention`` inside), ``layerNN/moe/router|dispatch|
-experts|combine``, ``head``.
+and ``.../gqa_prefill_attention`` or ``.../gqa_decode_attention``
+inside), ``layerNN/moe/router|dispatch|experts|combine``, ``head``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Dict, Tuple
 
 try:
@@ -72,7 +72,6 @@ from . import attention, moe
 from . import streams as stream
 
 Params = dict
-NEG = -1e30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,62 +182,17 @@ def _qkv(cfg: SmallThinkerConfig, p, h, positions, rotary: bool):
 
 
 def attn_prefill(cfg: SmallThinkerConfig, layer: int, p, h, cache, slot,
-                 start, key_block: int = 1024):
+                 start):
     """A chunk ``h [C, hidden]`` of stream ``slot`` whose first token is
-    at ``start``: writes the chunk's K and V rows (slot ``position %
-    T``), then attends to the stream's cache block by block with a
-    running softmax under the layer's mask.  ``T >= window + C - 1`` in
-    a window layer, so every position a query of the chunk sees is
-    still in the ring once the chunk is written."""
-    c = h.shape[0]
-    total = cache["k"].shape[2]
-    window = cfg.window if cfg.window_layers[layer] else total
-    positions = start + jnp.arange(c, dtype=jnp.int32)
-    q, k, v = _qkv(cfg, p, h, positions, cfg.rope_layers[layer])
-    with jax.named_scope("cache_write"):
-        at = positions % total
-        cache = {"k": cache["k"].at[slot, :, at].set(k.astype(cache["k"].dtype)),
-                 "v": cache["v"].at[slot, :, at].set(v.astype(cache["v"].dtype))}
-    kb = math.gcd(int(key_block), total)
-    last = start + c - 1
-    hp = moe.precision(p["q"])
-    scale = cfg.head_dim ** -0.5
-
-    def body(j, carry):
-        m, l, acc = carry
-        kj = lax.dynamic_slice(
-            cache["k"], (slot, 0, j * kb, 0),
-            (1, cfg.kv_heads, kb, cfg.head_dim))[0].astype(h.dtype)
-        vj = lax.dynamic_slice(
-            cache["v"], (slot, 0, j * kb, 0),
-            (1, cfg.kv_heads, kb, cfg.head_dim))[0].astype(h.dtype)
-        s = jnp.einsum("cgqd,gkd->gqck", q, kj,
-                       preferred_element_type=jnp.float32, precision=hp)
-        # the newest position up to the chunk's end that falls on a slot
-        slots = j * kb + jnp.arange(kb, dtype=jnp.int32)
-        held = last - (last - slots) % total
-        seen = (held[None, :] >= 0) & (held[None, :] <= positions[:, None]) \
-            & (held[None, :] > positions[:, None] - window)
-        s = jnp.where(seen[None, None], s * scale, NEG)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        alpha = jnp.exp(m - m_new)
-        prob = jnp.exp(s - m_new[..., None])
-        l = l * alpha + prob.sum(axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "gqck,gkd->gqcd", prob.astype(h.dtype), vj,
-            preferred_element_type=jnp.float32, precision=hp)
-        return m_new, l, acc
-
-    # a block the cache has not reached holds nothing a query sees, and
-    # one whose every key is masked for a query adds to that query's sums
-    # only until a later block with a key it sees scales them to nothing
-    blocks = jnp.minimum(total // kb, (last + kb) // kb)
-    m0 = jnp.full((cfg.kv_heads, cfg.per_group, c), NEG, jnp.float32)
-    _, l, acc = lax.fori_loop(
-        0, blocks, body,
-        (m0, jnp.zeros_like(m0), jnp.zeros(m0.shape + (cfg.head_dim,),
-                                           jnp.float32)))
-    o = (acc / l[..., None]).transpose(2, 0, 1, 3)        # [C, g, q, d]
+    at ``start``: ``models/attention.py`` ``prefill`` on this model's q,
+    k and v under the layer's mask.  ``T >= window + C - 1`` in a window
+    layer, so every position a query of the chunk sees is still in the
+    ring once the chunk is written."""
+    window = cfg.window if cfg.window_layers[layer] \
+        else cache["k"].shape[2]
+    o, cache = attention.prefill(
+        lambda positions: _qkv(cfg, p, h, positions, cfg.rope_layers[layer]),
+        h.shape[0], cache, slot, start, window, moe.precision(p["q"]))
     return attention.heads_out(p, o, h.dtype), cache
 
 

@@ -1,7 +1,7 @@
 """Pallas TPU kernels: fused normalize/typecast, flash attention, short
 attention, latent decode attention, latent prefill attention,
-grouped-query decode attention, the routed experts' grouped product and
-the Mamba-2 decode step.
+grouped-query decode attention, grouped-query prefill attention, the
+routed experts' grouped product and the Mamba-2 decode step.
 
 Parity/role:
 - ``scale_bias_cast`` is the tensor_transform arithmetic prologue
@@ -45,6 +45,14 @@ Parity/role:
   first live cell and pieces of 2^k cells of 128 rows at the end,
   through one queue of buffers (``_walk_stream``) that runs on into
   the next stream, so no stream starts cold.
+- ``gqa_prefill_attention`` is grouped-query attention of one chunk of
+  one stream over the same caches (``models/attention.py``'s
+  ``prefill``, for ``smallthinker.py``, ``nemotron_h.py``,
+  ``falcon_h1.py`` and ``exaone_moe.py``): the grid walks (a key/value
+  head's group of query heads, key block) from the block with the
+  oldest position a query sees round a ring to the one with the chunk's
+  end, a block's K and V rows read once for all the heads of the step,
+  the scores in fast memory only.
 - ``grouped_gated_product`` is the gated MLPs of the experts a decode
   step's tokens were routed to (``models/moe.py`` ``grouped_experts``):
   one call whose grid walks the plan's blocks with the block's expert
@@ -64,7 +72,8 @@ meet the tiling constraints — lane dim a multiple of 128, sublane dim a
 multiple of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32
 of 1-byte elements); ``short_attention``, ``latent_decode_attention``,
 ``latent_prefill_attention``, ``gqa_decode_attention``,
-``grouped_gated_product`` and ``ssm_decode_step`` refuse a shape they
+``gqa_prefill_attention``, ``grouped_gated_product`` and
+``ssm_decode_step`` refuse a shape they
 cannot take (``*_refusal`` says why: axes that do not fill tiles, a
 type the kernel is not written for, blocks over a fast-memory budget)
 and leave the choice to the caller.
@@ -1059,9 +1068,10 @@ _PREFILL_QUERY_ROWS = 512
 
 
 def _prefill_key_block(c: int, key_block: int = _PREFILL_KEY_BLOCK) -> int:
-    """Positions a key block of a chunk of ``c`` tokens holds: whole
-    blocks tile the chunk, so that they end with it."""
-    return int(np.gcd(int(key_block), c))
+    """Positions a key block holds where whole blocks must tile ``c``
+    positions: a chunk of :func:`latent_prefill_attention`, so that the
+    blocks end with it; a cache of :func:`gqa_prefill_attention`."""
+    return int(np.gcd(int(key_block), int(c)))
 
 
 def _prefill_rope_lanes(rank: int, rope: int) -> int:
@@ -1087,24 +1097,32 @@ def _prefill_vmem(c: int, group: int, tq: int, kb: int, rank: int,
     return blocks + stats + rebuilt + scores
 
 
-def latent_prefill_tiles(c: int, heads: int, rank: int, rope: int,
-                         nope: int, v: int, dtype) -> tuple:
-    """``(heads a grid step, query rows a pass)`` of
-    :func:`latent_prefill_attention` for a chunk of ``c`` tokens: the
-    largest divisors of ``heads`` and ``c`` up to ``_PREFILL_HEADS_A_STEP``
-    and ``_PREFILL_QUERY_ROWS`` (whole tiles of rows) whose step fits
+def _prefill_tiles(c: int, heads: int, most: int, dtype, vmem) -> tuple:
+    """``(heads a grid step, query rows a pass)`` of a prefill kernel
+    for a chunk of ``c`` tokens: the largest divisors of ``heads`` and
+    ``c`` up to ``most`` and ``_PREFILL_QUERY_ROWS`` (whole tiles of
+    rows) whose step, ``vmem(heads a step, query rows)`` bytes, fits
     ``_PREFILL_VMEM_BUDGET``; ``(0, 0)`` where none does."""
-    kb = _prefill_key_block(c)
     tile = _sublane(dtype)
-    for group in range(min(_PREFILL_HEADS_A_STEP, heads), 0, -1):
+    for group in range(min(most, heads), 0, -1):
         if heads % group:
             continue
         for tq in range(min(_PREFILL_QUERY_ROWS, c) // tile * tile, 0, -tile):
-            if c % tq == 0 and _prefill_vmem(
-                    c, group, tq, kb, rank, rope, nope, v,
-                    dtype) <= _PREFILL_VMEM_BUDGET:
+            if c % tq == 0 and vmem(group, tq) <= _PREFILL_VMEM_BUDGET:
                 return group, tq
     return 0, 0
+
+
+def latent_prefill_tiles(c: int, heads: int, rank: int, rope: int,
+                         nope: int, v: int, dtype) -> tuple:
+    """``(heads a grid step, query rows a pass)`` of
+    :func:`latent_prefill_attention` for a chunk of ``c`` tokens
+    (:func:`_prefill_tiles`, up to ``_PREFILL_HEADS_A_STEP`` heads)."""
+    kb = _prefill_key_block(c)
+    return _prefill_tiles(
+        c, heads, _PREFILL_HEADS_A_STEP, dtype,
+        lambda group, tq: _prefill_vmem(c, group, tq, kb, rank, rope, nope,
+                                        v, dtype))
 
 
 def latent_prefill_attention_refusal(q_nope_shape, q_rope_shape, cache_shape,
@@ -1678,6 +1696,419 @@ def _gqa_decode_walk_call(b: int, groups: int, per: int, d: int, total: int,
         return call(*operands)
 
     return jax.jit(gqa_decode_attention)
+
+
+# -- grouped-query prefill attention ------------------------------------------
+
+#: query heads a grid step of :func:`gqa_prefill_attention` takes (a
+#: part of a key/value head's group, or all of it) where
+#: ``_PREFILL_VMEM_BUDGET`` allows (``PERF.md`` section 6, PR 49: the
+#: sweep on the chip)
+_GQA_PREFILL_HEADS_A_STEP = 8
+
+
+def _gqa_prefill_vmem(c: int, heads: int, tq: int, kb: int, d: int,
+                      dtype) -> int:
+    """Bytes a grid step of :func:`gqa_prefill_attention` holds."""
+    size = np.dtype(dtype).itemsize
+    blocks = 2 * size * (2 * heads * c * d + 2 * kb * d)   # q, o; k, v
+    stats = 4 * heads * c * (2 * _LANE + d)
+    # a pass's scores, probabilities and their cast in flight; the
+    # mask's offsets
+    scores = 4 * 4 * tq * kb + 4 * tq * kb
+    return blocks + stats + scores
+
+
+def gqa_prefill_tiles(c: int, per_group: int, d: int, dtype) -> tuple:
+    """``(query heads a grid step, query rows a pass)`` of
+    :func:`gqa_prefill_attention` for a chunk of ``c`` tokens and
+    ``per_group`` query heads a key/value head, against the largest key
+    block (:func:`_prefill_tiles`, up to ``_GQA_PREFILL_HEADS_A_STEP``
+    heads of the group)."""
+    return _prefill_tiles(
+        c, per_group, _GQA_PREFILL_HEADS_A_STEP, dtype,
+        lambda heads, tq: _gqa_prefill_vmem(c, heads, tq, _PREFILL_KEY_BLOCK,
+                                            d, dtype))
+
+
+def gqa_prefill_attention_refusal(q_shape, k_shape, v_shape, window: int,
+                                  dtypes) -> Optional[str]:
+    """Why :func:`gqa_prefill_attention` cannot take these shapes, or
+    None: ``q [C, kv heads, heads a group, d]`` beside ``k`` and ``v``
+    caches ``[streams, kv heads, T, d]``, all of ONE type (``dtypes``:
+    the set of theirs), bf16 or float32; ``d`` whole lanes; key blocks
+    (1,024 positions, or the largest part of that which divides ``T``)
+    of whole lanes of scores; ``window == T`` (a layer that sees every
+    position of a cache that holds them all) or a ring that holds a
+    window behind every query of the chunk (``T >= window + C - 1``);
+    and a tiling that fits."""
+    names = sorted(np.dtype(d).name for d in dtypes)
+    if names not in (["bfloat16"], ["float32"]):
+        return f"operands of {', '.join(names)}: all bfloat16 or all float32"
+    if len(q_shape) != 4 or len(k_shape) != 4 \
+            or tuple(k_shape) != tuple(v_shape) \
+            or q_shape[1] != k_shape[1] or q_shape[3] != k_shape[3]:
+        return f"q {tuple(q_shape)}, k {tuple(k_shape)} and v " \
+               f"{tuple(v_shape)} are not [C, kv heads, heads a group, d] " \
+               "and twice [streams, kv heads, T, d]"
+    c, _, per, d = q_shape
+    total = k_shape[2]
+    if d % _LANE:
+        return f"head size {d} is not whole lanes of {_LANE}"
+    kb = _prefill_key_block(total)
+    if kb % _LANE:
+        return f"a key block of {kb} positions (a cache of {total}) is " \
+               f"not whole lanes of {_LANE}"
+    if window < 1 or window > total:
+        return f"a window of {window} positions on a cache of {total}"
+    if window < total and total < window + c - 1:
+        return f"a ring of {total} positions does not hold a window of " \
+               f"{window} behind every query of a chunk of {c}"
+    if total < c:
+        return f"a cache of {total} positions does not hold a chunk of {c}"
+    if not gqa_prefill_tiles(c, per, d, names[0])[0]:
+        return f"no step of a chunk of {c} fits " \
+               f"{_PREFILL_VMEM_BUDGET >> 20} MiB in whole tiles of " \
+               f"{_sublane(names[0])} rows"
+    return None
+
+
+def _gqa_prefill_last(start, c: int, total: int, window: int):
+    """The last position a chunk of ``c`` tokens at ``start`` has
+    written: its own last, on a ring; on a cache of every position
+    (``window == total``) no further than the cache's last, what lies
+    beyond (a padded chunk's end) having been dropped."""
+    import jax.numpy as jnp
+
+    last = start + c - 1
+    return last if window < total else jnp.minimum(last, total - 1)
+
+
+def gqa_prefill_attention_reference(q, k_cache, v_cache, slot, start,
+                                    window: int, scale: float,
+                                    key_block: int = _PREFILL_KEY_BLOCK,
+                                    precision=None):
+    """The kernel's mathematics in jnp, and the path
+    ``models/attention.py`` ``prefill`` takes for a shape the kernel
+    refuses: XLA's own ``while`` over the key blocks of stream
+    ``slot``'s cache up to the chunk's end, the scores of the whole
+    chunk against a block in float32, a running softmax.  A slot ``s``
+    of a cache of ``T`` positions holds, once the chunk is written, the
+    newest position up to the chunk's last that falls on it (``last -
+    (last - s) mod T``: a ring where a stream is longer than ``T``, the
+    position ``s`` itself where it is not, :func:`_gqa_prefill_last`); a
+    query at ``p`` sees the
+    slots that hold a position of ``max(0, p - window + 1) .. p``.
+    Returns ``[C, kv heads, heads a group, d]`` float32.  (On the chip
+    each iteration writes and reads its scores through HBM several
+    times: ``PERF.md`` section 6, PRs 48 and 49.)"""
+    import jax
+    import jax.numpy as jnp
+
+    c, groups, per, d = q.shape
+    total = k_cache.shape[2]
+    kb = _prefill_key_block(total, key_block)
+    positions = start + jnp.arange(c, dtype=jnp.int32)
+    last = _gqa_prefill_last(start, c, total, window)
+
+    def body(j, carry):
+        m, l, acc = carry
+        kj, vj = (jax.lax.dynamic_slice(
+            cache, (slot, 0, j * kb, 0),
+            (1, groups, kb, d))[0].astype(q.dtype)
+            for cache in (k_cache, v_cache))
+        s = jnp.einsum("cgqd,gkd->gqck", q, kj,
+                       preferred_element_type=jnp.float32,
+                       precision=precision)
+        # the newest position up to the chunk's end that falls on a slot
+        slots = j * kb + jnp.arange(kb, dtype=jnp.int32)
+        held = last - (last - slots) % total
+        seen = (held[None, :] >= 0) & (held[None, :] <= positions[:, None])
+        if window < total:
+            seen &= held[None, :] > positions[:, None] - window
+        s = jnp.where(seen[None, None], s * scale, -1e30)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        prob = jnp.exp(s - m_new[..., None])
+        l = l * alpha + prob.sum(axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "gqck,gkd->gqcd", prob.astype(q.dtype), vj,
+            preferred_element_type=jnp.float32, precision=precision)
+        return m_new, l, acc
+
+    # a block the cache has not reached holds nothing a query sees, and
+    # one whose every key is masked for a query adds to that query's sums
+    # only until a later block with a key it sees scales them to nothing
+    blocks = jnp.minimum(total // kb, (last + kb) // kb)
+    m0 = jnp.full((groups, per, c), -1e30, jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        0, blocks, body,
+        (m0, jnp.zeros_like(m0), jnp.zeros(m0.shape + (d,), jnp.float32)))
+    return (acc / l[..., None]).transpose(2, 0, 1, 3)         # [C, g, q, d]
+
+
+def gqa_prefill_attention(q, k_cache, v_cache, slot, start, window: int,
+                          scale: float):
+    """Grouped-query attention of one chunk of one stream over its K
+    and V caches: ``q [C, kv heads, heads a group, d]`` (rotated where
+    the model rotates), caches ``[streams, kv heads, T, d]`` with the
+    chunk's own rows ALREADY written at ``position % T``, ``slot`` and
+    ``start`` (int32 scalars: the stream, and the chunk's first
+    position, ANY position), ``window`` static (``T`` for a layer that
+    sees every position).  Returns ``[C, kv heads, heads a group, d]``
+    in the queries' type: each query's softmax over the positions
+    ``max(0, p - window + 1) .. p``.
+
+    The mathematics is :func:`gqa_prefill_attention_reference`'s: keys,
+    values and queries in their own type, a (query block, key block)
+    pair's scores in one float32 accumulation, scaled, masked at -1e30;
+    the running maximum, normaliser and accumulator float32; the
+    probabilities rounded to the queries' type for the value product;
+    one division at the end (rounded to the queries' type, as
+    ``attention.heads_out`` rounds the loop's float32 first thing).
+    What differs is where the scores live: a query block's ``[rows, key
+    block]`` scores and probabilities exist only in fast memory, where
+    XLA's loop writes ``[kv heads, heads a group, C, key block]`` of
+    them to HBM and reads them back several times an iteration.
+
+    The grid walks (a key/value head's group, or a part of it where the
+    budget wants that; key block).  A step holds its heads' queries,
+    output and running softmax for the WHOLE chunk, takes the block's K
+    and V rows through the pipeline once for all of those heads
+    (``slot`` and ``start`` are prefetched, so the index map picks the
+    stream and the block), and scores a block of one head's query rows
+    at a time.  The blocks are walked in the order of their positions,
+    from the one that holds the oldest position a query of the chunk
+    sees (slot ``max(0, start - window + 1) % T``) round the ring to
+    the one with the chunk's end; the grid's extent is every block of
+    the cache, and steps beyond the live ones compute nothing and name
+    the last live block, so nothing is copied for them.
+
+    A slot ``s`` holds position ``last - (last - s) % T``.  Up to the
+    slot of ``last`` that is ``s`` plus one scalar, after it ``s`` plus
+    that scalar less ``T``: a block that lies on one side holds a run
+    of positions, and its pair with a query block is skipped where it
+    lies wholly after the diagonal or wholly before every query's
+    window, masked by ``key - query <= scalar`` where the diagonal
+    passes through it, by ``> scalar`` where the window's edge does,
+    and not at all where neither does.  The one block the ring's newest
+    position falls INSIDE holds two runs (only once the ring has
+    wrapped, and only for a chunk that does not end with a block: never
+    at the cells' starts, multiples of ``C``), and takes a general mask
+    with the slot's index in it, as does a pair both edges pass
+    through.  A shape :func:`gqa_prefill_attention_refusal` names is an
+    error: the caller chooses."""
+    refusal = gqa_prefill_attention_refusal(
+        q.shape, k_cache.shape, v_cache.shape, window,
+        {q.dtype, k_cache.dtype, v_cache.dtype})
+    if refusal:
+        raise ValueError(f"gqa_prefill_attention: {refusal}")
+    c, _, per, d = q.shape
+    heads, tq = gqa_prefill_tiles(c, per, d, q.dtype)
+    return _gqa_prefill(q, k_cache, v_cache, slot, start, window, scale,
+                        heads, tq, _prefill_key_block(k_cache.shape[2]))
+
+
+def _gqa_prefill(q, k_cache, v_cache, slot, start, window: int, scale: float,
+                 heads: int, tq: int, kb: int):
+    """:func:`gqa_prefill_attention` by an explicit tiling (the tests
+    and the chip's sweeps choose theirs): ``heads`` query heads a grid
+    step, ``tq`` query rows a pass, key blocks of ``kb`` positions."""
+    import jax
+    import jax.numpy as jnp
+
+    c, groups, per, d = q.shape
+    call = _gqa_prefill_call(
+        c, groups, per, d, k_cache.shape[2], int(window), float(scale),
+        np.dtype(q.dtype).name, heads, tq, kb, _interpret())
+    # the stage a trace books the kernel's time to (a jit is no scope),
+    # and with it the layout of its queries: a head's rows together
+    with jax.named_scope("gqa_prefill_attention"):
+        out = call(jnp.reshape(slot, (1,)).astype(jnp.int32),
+                   jnp.reshape(start, (1,)).astype(jnp.int32),
+                   q.transpose(1, 2, 0, 3).reshape(groups * per, c, d),
+                   k_cache, v_cache)
+        return out.reshape(groups, per, c, d).transpose(2, 0, 1, 3)
+
+
+@functools.lru_cache(maxsize=16)
+def _gqa_prefill_call(c: int, groups: int, per: int, d: int, total: int,
+                      window: int, scale: float, dtype: str, heads: int,
+                      tq: int, kb: int, interpret: bool):
+    """The jitted call of :func:`gqa_prefill_attention` for one shape,
+    built once: a model's layers share the function, so a program whose
+    eight layers attend on rings and full caches traces and lowers two
+    kernels (as :func:`_latent_prefill_call` does)."""
+    import jax.numpy as jnp
+
+    jax, pl, pltpu = _pl()
+    blocks = total // kb                 # key blocks of the cache
+    parts = per // heads                 # grid steps a key/value head takes
+    nq = c // tq
+    hp = jax.lax.Precision.HIGHEST if dtype == "float32" else None
+    nt = (((1,), (1,)), ((), ()))        # q k^T without a transpose
+
+    # the walk's arithmetic on scalars that are never negative, as lax
+    # primitives: every jnp operator is a jitted function of its own,
+    # and this is traced once an index map and once in the kernel
+    lax = jax.lax
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+
+    def last_of(start):
+        last = start + i32(c - 1)
+        return last if window < total else lax.min(last, i32(total - 1))
+
+    def walk(start):
+        """``(first, live)``: the block that holds the oldest position a
+        query of a chunk at ``start`` sees, and how many blocks lie from
+        it round to the one with the chunk's end."""
+        oldest = lax.max(start - i32(window - 1), i32(0))
+        at = lax.rem(oldest, i32(total))
+        first = lax.div(at, i32(kb))
+        return first, lax.min(i32(blocks), lax.div(
+            at + last_of(start) - oldest, i32(kb)) - first + i32(1))
+
+    def block_at(j, first, live):
+        # beyond the live blocks the last one named: not copied again
+        return lax.rem(first + lax.min(j, live - i32(1)), i32(blocks))
+
+    def kernel(slot_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
+               ahead_ref, m_ref, l_ref, acc_ref):
+        j = pl.program_id(1)
+        start = start_ref[0]
+        first, live = walk(start)
+        slot0 = block_at(j, first, live) * kb    # the block's first slot
+        last = last_of(start)
+        newest = lax.rem(last, i32(total))       # the slot ``last`` lies in
+        lap = last - newest                      # positions before this lap
+        # the ring's newest position inside the block and not at its
+        # end, behind it the positions of the lap before: two runs
+        split = newest - slot0
+        mixed = (lap >= total) & (split >= 0) & (split < kb - 1)
+        # the position the block's first slot holds
+        key0 = slot0 + lap - jnp.where(slot0 > newest, total, 0)
+
+        @pl.when(j == 0)
+        def _first():
+            m_ref[...] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+            # how far a key lies ahead of a query, less what the two
+            # blocks' own places add: a masked pair then costs a
+            # comparison and a choice an element
+            ahead_ref[...] = jax.lax.broadcasted_iota(
+                jnp.int32, (tq, kb), 1) - jax.lax.broadcasted_iota(
+                    jnp.int32, (tq, kb), 0)
+
+        def attend(g, i, mask):
+            """The online softmax of head ``g``'s query block ``i``
+            against the key block, under ``mask``."""
+            at = pl.ds(pl.multiple_of(i * tq, tq), tq)
+            s = jax.lax.dot_general(
+                q_ref[g, at, :], k_ref[...], nt,
+                preferred_element_type=jnp.float32,
+                precision=hp) * scale                          # (tq, kb)
+            # the block's first key lies ``gap`` behind the first query
+            gap = start + i * tq - key0
+            if mask == "diagonal":
+                s = jnp.where(ahead_ref[...] <= gap, s, -1e30)
+            elif mask == "window":
+                s = jnp.where(ahead_ref[...] > gap - window, s, -1e30)
+            elif mask == "general":
+                # the slots after ``newest`` are a lap behind
+                slots = jax.lax.broadcasted_iota(jnp.int32, (tq, kb), 1)
+                gaps = gap + jnp.where(slots > split,
+                                       jnp.where(mixed, total, 0), 0)
+                s = jnp.where((ahead_ref[...] <= gaps)
+                              & (ahead_ref[...] > gaps - window), s, -1e30)
+            # running max / normaliser replicated across a lane width,
+            # as in flash_attention above
+            m_prev = m_ref[g, at, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, :1])
+            l_ref[g, at, :] = l_ref[g, at, :] * alpha \
+                + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[g, at, :] = acc_ref[g, at, :] * alpha[:, :1] \
+                + jax.lax.dot_general(
+                    p.astype(v_ref.dtype), v_ref[...],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32, precision=hp)
+            m_ref[g, at, :] = m_new
+
+        @pl.when(j < live)
+        def _block():
+            def pair(r, carry):
+                g, i = r // nq, r % nq
+                # the block's first key against the query block's last
+                # query, its last key against the first query (and the
+                # first position of that query's window)
+                q0 = start + i * tq
+                seen = key0 <= q0 + tq - 1
+                diagonal = key0 + kb - 1 > q0
+                if window < total:
+                    # (a cache of every position has no window's edge
+                    # and no second run: its kernel holds neither mask)
+                    seen = seen & (key0 + kb - 1 > q0 - window) \
+                        & jnp.logical_not(mixed)
+                    edge = key0 <= q0 + tq - 1 - window
+                    pl.when(mixed | (seen & diagonal & edge))(
+                        lambda: attend(g, i, "general"))
+                    pl.when(seen & edge & jnp.logical_not(diagonal))(
+                        lambda: attend(g, i, "window"))
+                    seen = seen & jnp.logical_not(edge)
+                pl.when(seen & diagonal)(lambda: attend(g, i, "diagonal"))
+                pl.when(seen & jnp.logical_not(diagonal))(
+                    lambda: attend(g, i, None))
+                return carry
+
+            jax.lax.fori_loop(0, heads * nq, pair, 0)
+
+        @pl.when(j == live - 1)
+        def _write():
+            def head(g, carry):
+                o_ref[g] = (acc_ref[g] / l_ref[g][:, :1]).astype(o_ref.dtype)
+                return carry
+
+            jax.lax.fori_loop(0, heads, head, 0)
+
+    def heads_block(hg, j, slot, start):
+        return hg, 0, 0
+
+    def key_block(hg, j, slot, start):
+        return slot[0], hg // parts, block_at(j, *walk(start[0])), 0
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(groups * parts, blocks),
+        in_specs=[
+            pl.BlockSpec((heads, c, d), heads_block),
+            pl.BlockSpec((None, None, kb, d), key_block),
+            pl.BlockSpec((None, None, kb, d), key_block),
+        ],
+        out_specs=pl.BlockSpec((heads, c, d), heads_block),
+        scratch_shapes=[
+            pltpu.VMEM((tq, kb), jnp.int32),                 # key - query
+            pltpu.VMEM((heads, c, _LANE), jnp.float32),      # running max
+            pltpu.VMEM((heads, c, _LANE), jnp.float32),      # normaliser
+            pltpu.VMEM((heads, c, d), jnp.float32),          # accumulator
+        ])
+    call = pl.pallas_call(
+        kernel, grid_spec=grid,
+        out_shape=jax.ShapeDtypeStruct((groups * per, c, d), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_gqa_prefill_vmem(c, heads, tq, kb, d, dtype)
+            + (8 << 20)),
+        interpret=interpret)
+
+    # no ``name=``: it would open a scope of its own below the caller's
+    # (``.../attn/gqa_prefill_attention``), the stage this call's device
+    # time is booked to.  An inner jit is no scope, and names the
+    # instruction all the same
+    def gqa_prefill_attention(*operands):
+        return call(*operands)
+
+    return jax.jit(gqa_prefill_attention)
 
 
 # -- the routed experts' grouped product --------------------------------------

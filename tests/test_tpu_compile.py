@@ -186,9 +186,9 @@ def test_ssm_decode_step_compiles_at_the_cells_shapes(one_chip, monkeypatch):
     assert memory.temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("entry,temp_mb", [("decode", 64), ("prefill", 768)])
+@pytest.mark.parametrize("entry,temp_mb", [("decode", 64), ("prefill", 576)])
 def test_the_hybrid_cells_programs_copy_no_recurrent_state(
-        one_chip, monkeypatch, entry, temp_mb):
+        one_chip, monkeypatch, entry, temp_mb, capsys):
     """`nemotron3.decode4k`'s two programs at the cell's sizes (20
     layers, 128 streams, 4,096 positions, chunks of 2,048; 4.0 GB of
     weights and 6.5 GB of state as arguments): the state is updated in
@@ -196,7 +196,10 @@ def test_the_hybrid_cells_programs_copy_no_recurrent_state(
     copy of it (a `lax.cond` around the step made nine, 2.4 GB) shows
     in the temporaries.  A decode step's nine state updates are the
     kernel `ssm_decode_step`, each written over its layer's live
-    state."""
+    state.  A prefill chunk attends through `gqa_prefill_attention` in
+    its three caches (no loop over key blocks is left, and no copy of a
+    268 MB cache around the chunk's rows: 565 MB of temporaries where
+    the loops and the copies took 609)."""
     import json
     import os
 
@@ -238,13 +241,15 @@ def test_the_hybrid_cells_programs_copy_no_recurrent_state(
     compiled = jax.jit(functools.partial(fn, cfg), donate_argnums=(1,)) \
         .lower(on_chip(params), on_chip(state), *inputs).compile()
     memory = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nnemotron3 {entry}: {memory}")
     assert memory.alias_size_in_bytes >= nbytes
     assert memory.temp_size_in_bytes < temp_mb << 20
     text = compiled.as_text()
     # the experts' grouped product and, of a decode step, three
     # attention calls and nine state updates
     assert text.count("tpu_custom_call") >= 8 + (
-        3 + 9 if entry == "decode" else 0)
+        3 + 9 if entry == "decode" else 3)
     # the kernel picks each stream's source: no restore loop before the
     # layers (the scope holds nothing at these shapes)
     assert "ssm_restore" not in text
@@ -319,9 +324,9 @@ def test_the_window128_cells_kernels_compile_at_its_shapes(one_chip,
         assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("entry,temp_mb", [("decode", 64), ("prefill", 1536)])
+@pytest.mark.parametrize("entry,temp_mb", [("decode", 64), ("prefill", 640)])
 def test_the_window128_cells_programs_copy_no_cache(one_chip, monkeypatch,
-                                                    entry, temp_mb):
+                                                    entry, temp_mb, capsys):
     """`kexaone.decode16k`'s two programs at the cell's sizes (five
     layers and the prediction module, 32 streams, 16,384 positions,
     chunks of 2,048; 9.1 GB of weights and 4.5 GB of state as
@@ -330,7 +335,10 @@ def test_the_window128_cells_programs_copy_no_cache(one_chip, monkeypatch,
     temporaries, and so would a prefill chunk's scores against a whole
     cache (`[64, 2048, 16384]` float32: 8.6 GB).  A decode step attends
     through the kernel in all six caches and runs the routed experts'
-    grouped product in four layers and the module."""
+    grouped product in four layers and the module; a prefill chunk
+    attends through `gqa_prefill_attention` in the layer and the module
+    that see every position (620 MB of temporaries where the loop's
+    scores and two copies of each full cache took 1,408)."""
     monkeypatch.setattr(kernels, "_interpret", lambda: False)
     ex, cfg, params, state = _kexaone(one_chip)
     nbytes = sum(a.size * a.dtype.itemsize
@@ -349,13 +357,17 @@ def test_the_window128_cells_programs_copy_no_cache(one_chip, monkeypatch,
     compiled = jax.jit(functools.partial(fn, cfg), donate_argnums=(1,)) \
         .lower(params, state, *inputs).compile()
     memory = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nkexaone {entry}: {memory}")
     assert memory.alias_size_in_bytes >= nbytes
     assert memory.temp_size_in_bytes < temp_mb << 20
     # weights, state and temporaries together fit a chip's 16 GB
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 15.5e9
-    assert compiled.as_text().count("tpu_custom_call") >= 5 + (
-        6 if entry == "decode" else 0)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 5 + (
+        6 if entry == "decode" else 2)
+    assert entry == "decode" or " while(" not in text
 
 
 def test_latent_decode_attention_compiles_at_64_heads(one_chip, monkeypatch):
@@ -410,6 +422,99 @@ def test_latent_prefill_attention_compiles_at_the_cells_shapes(
     # the queries twice a head (2 x 256 lanes), in and out of a relayout
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 2 * 2048 * heads * 512 * 2 + (1 << 20)
+
+
+@pytest.mark.parametrize("per,streams,total,window,heads", [
+    (7, 32, 6144, 4096, 7), (7, 32, 16384, 16384, 7),
+    (16, 32, 16384, 16384, 8), (5, 128, 4096, 4096, 5),
+    (8, 128, 4096, 4096, 8)],
+    ids=["smallthinker.decode16k ring", "smallthinker.decode16k full",
+         "kexaone.decode16k", "falconh1.decode4k", "nemotron3.decode4k"])
+def test_gqa_prefill_attention_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch, per, streams, total, window, heads):
+    """A prefill chunk's attention of the four grouped-query cells:
+    2,048 queries of 28, 64, 20 or 32 heads (4 key/value heads of 7, 16,
+    5 or 8) against key blocks of 1,024 positions of a ring of 6,144
+    behind a window of 4,096 or of a cache of 4,096 or 16,384, a
+    key/value head's group a grid step (half of it at 16) and 512 query
+    rows a pass, as a Mosaic kernel; one custom call and no copy of a
+    cache beside it: the temporaries are the queries and the output
+    with a head's rows together."""
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    operands = [shape((2048, 4, per, 128)), shape((streams, 4, total, 128)),
+                shape((streams, 4, total, 128)), shape((), jnp.int32),
+                shape((), jnp.int32)]
+    assert kernels.gqa_prefill_attention_refusal(
+        *(o.shape for o in operands[:3]), window,
+        {jnp.dtype(jnp.bfloat16)}) is None
+    assert kernels.gqa_prefill_tiles(2048, per, 128, jnp.bfloat16) \
+        == (heads, 512)
+    fn = jax.jit(functools.partial(kernels.gqa_prefill_attention,
+                                   window=window, scale=128 ** -0.5))
+    compiled = fn.lower(*operands).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * 2048 * 4 * per * 128 * 2 + (1 << 20)
+
+
+def test_the_ring_cells_prefill_program_copies_no_cache(one_chip,
+                                                        monkeypatch, capsys):
+    """`smallthinker.decode16k`'s prefill program at the cell's sizes
+    (8 layers, 32 streams, 16,384 positions, chunks of 2,048; 7.9 GB of
+    weights and 4.6 GB of rings and full caches as arguments): a chunk
+    attends through `gqa_prefill_attention` in all eight layers (two
+    kernels are built, one for the six rings and one for the two full
+    caches) beside the eight grouped products, no loop over key blocks
+    is left, and the chunk's rows are written into the donated caches
+    where they lie (a scatter into a whole cache made two copies of each
+    of the sixteen tensors, 201 and 537 MB apiece: 338 MB of temporaries
+    where the loops' scores and those copies took 570)."""
+    import json
+    import os
+
+    from nnstreamer_tpu.models import smallthinker as st
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "smallthinker_21b_stage8.json")
+    with open(path) as f:
+        cfg = st.SmallThinkerConfig.from_dict(json.load(f))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: st.init_params(cfg, 0))
+    state = jax.eval_shape(
+        lambda: st.init_state(cfg, params, 32, 16384, 2048))
+    nbytes = sum(a.size * a.dtype.itemsize
+                 for a in jax.tree_util.tree_leaves(state))
+    assert 4.5e9 < nbytes < 4.7e9
+    assert sorted({c["k"].shape[2] for c in state["cache"]}) \
+        == [6144, 16384]
+
+    def i32(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+
+    kernels._gqa_prefill_call.cache_clear()
+    compiled = jax.jit(functools.partial(st.prefill, cfg),
+                       donate_argnums=(1,)) \
+        .lower(on_chip(params), on_chip(state), i32(2048), i32(1),
+               i32(1)).compile()
+    assert kernels._gqa_prefill_call.cache_info().misses == 2
+    memory = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nsmallthinker prefill: {memory}")
+    assert memory.alias_size_in_bytes >= nbytes
+    assert memory.temp_size_in_bytes < 384 << 20
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 16 and " while(" not in text
 
 
 def _latent_cell(name: str):
@@ -557,7 +662,7 @@ def test_the_falcon_cells_kernels_compile_at_its_shapes(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("entry,temp_mb", [("decode", 96), ("prefill", 1536)])
+@pytest.mark.parametrize("entry,temp_mb", [("decode", 96), ("prefill", 352)])
 def test_the_falcon_cells_programs_fit_the_chip(one_chip, monkeypatch, entry,
                                                 temp_mb, capsys):
     """`falconh1.decode4k`'s two programs at the cell's sizes (4 layers,
@@ -566,7 +671,9 @@ def test_the_falcon_cells_programs_fit_the_chip(one_chip, monkeypatch, entry,
     the donated buffers.  One layer's recurrent state is 537 MB and one
     layer's K or V as much, so a copy of either shows in the
     temporaries.  A decode step holds four state updates and four
-    attention calls as kernels."""
+    attention calls as kernels, a prefill chunk four attention calls
+    (333 MB of temporaries where the loops' scores and two copies of
+    each cache took 843)."""
     import json
     import os
 
@@ -608,5 +715,5 @@ def test_the_falcon_cells_programs_fit_the_chip(one_chip, monkeypatch, entry,
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 14.8e9
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= (8 if entry == "decode" else 0)
+    assert text.count("tpu_custom_call") >= (8 if entry == "decode" else 4)
     assert "ssm_restore" not in text

@@ -1,13 +1,19 @@
 """Grouped-query attention parts the token models share
 (``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``,
-``falcon_h1.py``): the rotation
+``falcon_h1.py``, ``phi4_flash.py``): the rotation
 of q and k, a layer's K and V cache, a prefill chunk's attention and
 the decode step, each on a full cache or a ring (``ops/kernels.py``
 ``gqa_prefill_attention`` and ``gqa_decode_attention`` where they take
 the shapes, their ``jnp`` references where they refuse them) with the
 rows a step fetches.
-How a model makes its q, k and v (norms, rotation, which layers) stays
-with the model.
+Writing a layer's rows and attending to a cache are two calls each
+(:func:`write_step` / :func:`attend_step`, :func:`write_chunk` /
+:func:`attend_chunk`), because a cache may have ONE writer and several
+readers (``phi4_flash.py``: seven layers attend to another layer's
+rows and write none); :func:`decode_step` and :func:`prefill` are their
+compositions, what a layer that owns its cache calls.
+How a model makes its q, k and v (norms, rotation, which layers, heads
+paired into one row) stays with the model.
 """
 
 from __future__ import annotations
@@ -63,30 +69,48 @@ def heads_out(p, o, dtype):
         .astype(dtype)
 
 
-def decode_step(q, k, v, cache, positions, window: int, scale: float):
-    """One token of every stream: ``q [B, kv heads, heads a group, d]``,
-    ``k`` and ``v`` ``[B, kv heads, d]``, stream ``b`` at
-    ``positions[b]``.  Writes each stream's K and V row in slot
-    ``position % T`` of its ``T`` (a ring where ``T`` is less than the
-    stream's length; on a cache of every position the remainder names
-    the position's own row), then attends over the slots that hold a
-    position of ``max(0, p - window + 1) .. p``: ``window`` is ``T`` for
-    a layer that sees every position.  Returns ``(o [B, kv heads, heads
-    a group, d] float32, cache)``."""
-    b, groups = q.shape[:2]
+def write_step(k, v, cache, positions):
+    """One token of every stream WRITTEN: ``k`` and ``v`` ``[B, kv
+    heads, d]`` into slot ``position % T`` of each stream's ``T`` (a
+    ring where ``T`` is less than the stream's length; on a cache of
+    every position the remainder names the position's own row).
+    Returns the cache; nothing is read."""
+    b, groups = k.shape[:2]
     total = cache["k"].shape[2]
     with jax.named_scope("cache_write"):
         where = (jnp.arange(b)[:, None], jnp.arange(groups)[None, :],
                  (positions % total)[:, None])
-        cache = {"k": cache["k"].at[where].set(k.astype(cache["k"].dtype)),
-                 "v": cache["v"].at[where].set(v.astype(cache["v"].dtype))}
+        return {"k": cache["k"].at[where].set(k.astype(cache["k"].dtype)),
+                "v": cache["v"].at[where].set(v.astype(cache["v"].dtype))}
+
+
+def attend_step(q, cache, positions, window: int, scale: float):
+    """One token of every stream ATTENDING to a cache that holds its
+    rows already, whoever wrote them (this layer's :func:`write_step`,
+    or another layer's: a layer that owns no state reads its writer's):
+    ``q [B, kv heads, heads a group, d]``, stream ``b`` at
+    ``positions[b]``, over the slots that hold a position of ``max(0, p
+    - window + 1) .. p``; ``window`` is ``T`` for a layer that sees
+    every position.  Returns ``o [B, kv heads, heads a group, d]``
+    float32: the kernel where it takes the shapes, its ``jnp``
+    mathematics where it refuses them."""
     if kernels.gqa_decode_attention_refusal(
             q.shape, cache["k"].shape, cache["v"].shape, window) is None:
         # the call names its own scope, `.../gqa_decode_attention`
         attend = kernels.gqa_decode_attention
     else:
         attend = kernels.gqa_decode_attention_reference
-    return attend(q, cache["k"], cache["v"], positions, window, scale), cache
+    return attend(q, cache["k"], cache["v"], positions, window, scale)
+
+
+def decode_step(q, k, v, cache, positions, window: int, scale: float):
+    """One token of every stream: ``q [B, kv heads, heads a group, d]``,
+    ``k`` and ``v`` ``[B, kv heads, d]``, stream ``b`` at
+    ``positions[b]``: :func:`write_step`, then :func:`attend_step` on
+    what it wrote.  Returns ``(o [B, kv heads, heads a group, d]
+    float32, cache)``."""
+    cache = write_step(k, v, cache, positions)
+    return attend_step(q, cache, positions, window, scale), cache
 
 
 def decode_rows_fetched(caches, per_group: int, positions, window=None):
@@ -116,7 +140,55 @@ def _write_rows(cache, slot, at, rows):
     return lax.dynamic_update_index_in_dim(cache, one, slot, 0)
 
 
-def prefill(qkv, size: int, cache, slot, start, window: int, hp):
+def write_chunk(k, v, cache, slot, positions, window: int):
+    """A chunk's rows WRITTEN: ``k`` and ``v`` ``[C, kv heads, d]`` of
+    stream ``slot`` at ``positions [C]`` into slots ``position % T`` of
+    a ring, the position itself on a cache of every position (``window
+    == T``), where a row beyond the cache (a padded chunk's end) is
+    dropped, not wrapped onto the stream's first rows.  Returns the
+    cache; nothing is read."""
+    total = cache["k"].shape[2]
+    with jax.named_scope("cache_write"):
+        at = positions % total if window < total else positions
+        return {"k": _write_rows(cache["k"], slot, at, k),
+                "v": _write_rows(cache["v"], slot, at, v)}
+
+
+def attend_chunk(q, cache, slot, start, window: int, hp, scale=None):
+    """A chunk ``q [C, kv heads, heads a group, d]`` of stream ``slot``
+    whose first token is at ``start`` ATTENDING to a cache that holds
+    the chunk's rows already, whoever wrote them: position ``p`` sees
+    ``max(0, p - window + 1) .. p``, a block of keys at a time with a
+    running softmax; ``scale`` is ``d^-1/2`` where none is given.
+    Returns ``o [C, kv heads, heads a group, d]``.
+
+    One algorithm, two programs, chosen from the shapes: the kernel
+    (``ops/kernels.py`` ``gqa_prefill_attention``: a query block's
+    scores never leave fast memory) wherever its refusal has nothing to
+    say, its reference (XLA's own ``while`` over the key blocks)
+    everywhere else.  The set-up span this is traced under says which
+    (``utils/profile.py`` ``note``)."""
+    refusal = kernels.gqa_prefill_attention_refusal(
+        q.shape, cache["k"].shape, cache["v"].shape, window,
+        {q.dtype, cache["k"].dtype, cache["v"].dtype})
+    shapes = f"prefill {q.shape[0]} x {q.shape[1]} x {q.shape[2]} heads, " \
+             f"a window of {window} on {tuple(cache['k'].shape)} " \
+             f"{cache['k'].dtype.name}"
+    _profile.note(f"{shapes}: the jnp loop ({refusal})" if refusal
+                  else f"{shapes}: the kernel")
+    if scale is None:
+        scale = q.shape[3] ** -0.5
+    if refusal:
+        return kernels.gqa_prefill_attention_reference(
+            q, cache["k"], cache["v"], slot, start, window, scale,
+            precision=hp)
+    # the call names its own scope, `.../gqa_prefill_attention`
+    return kernels.gqa_prefill_attention(q, cache["k"], cache["v"], slot,
+                                         start, window, scale)
+
+
+def prefill(qkv, size: int, cache, slot, start, window: int, hp,
+            scale=None):
     """A chunk of ``size`` tokens of stream ``slot`` whose first token
     is at ``start``, on one layer's cache (``{"k", "v"}`` of ``[streams,
     kv heads, T, d]``): a ring where ``T`` is less than the stream's
@@ -125,45 +197,13 @@ def prefill(qkv, size: int, cache, slot, start, window: int, hp):
     every position where it is not; ``window`` is ``T`` for a layer that
     sees every position, as :func:`decode_step` has it.
     ``qkv(positions)`` is the model's own ``(q [C, kv heads, heads a
-    group, d], k [C, kv heads, d], v)`` of the chunk.  Writes the
-    chunk's K and V rows in slots ``position % T``, then attends to the
-    stream's cache a block of keys at a time with a running softmax,
-    position ``p`` seeing ``max(0, p - window + 1) .. p``.  Returns ``(o
-    [C, kv heads, heads a group, d], cache)``; :func:`heads_out` rounds
-    ``o`` to the model's type first thing.  A padded token's row lies
-    beyond the prompt and is overwritten by the answer before any step
-    reads it.
-
-    One algorithm, two programs, chosen from the shapes: the kernel
-    (``ops/kernels.py`` ``gqa_prefill_attention``: a query block's
-    scores never leave fast memory) wherever its refusal has nothing to
-    say, its reference (XLA's own ``while`` over the key blocks)
-    everywhere else.  The set-up span this is traced under says which
-    (``utils/profile.py`` ``note``)."""
-    total = cache["k"].shape[2]
+    group, d], k [C, kv heads, d], v)`` of the chunk.
+    :func:`write_chunk`, then :func:`attend_chunk` on what it wrote.
+    Returns ``(o [C, kv heads, heads a group, d], cache)``;
+    :func:`heads_out` rounds ``o`` to the model's type first thing.  A
+    padded token's row lies beyond the prompt and is overwritten by the
+    answer before any step reads it."""
     positions = start + jnp.arange(size, dtype=jnp.int32)
     q, k, v = qkv(positions)
-    with jax.named_scope("cache_write"):
-        # a row beyond a cache of every position (a padded chunk's
-        # end) is dropped, not wrapped onto the stream's first rows
-        at = positions % total if window < total else positions
-        cache = {"k": _write_rows(cache["k"], slot, at, k),
-                 "v": _write_rows(cache["v"], slot, at, v)}
-    refusal = kernels.gqa_prefill_attention_refusal(
-        q.shape, cache["k"].shape, cache["v"].shape, window,
-        {q.dtype, cache["k"].dtype, cache["v"].dtype})
-    shapes = f"prefill {size} x {q.shape[1]} x {q.shape[2]} heads, a " \
-             f"window of {window} on {tuple(cache['k'].shape)} " \
-             f"{cache['k'].dtype.name}"
-    _profile.note(f"{shapes}: the jnp loop ({refusal})" if refusal
-                  else f"{shapes}: the kernel")
-    scale = q.shape[3] ** -0.5
-    if refusal:
-        o = kernels.gqa_prefill_attention_reference(
-            q, cache["k"], cache["v"], slot, start, window, scale,
-            precision=hp)
-    else:
-        # the call names its own scope, `.../gqa_prefill_attention`
-        o = kernels.gqa_prefill_attention(q, cache["k"], cache["v"], slot,
-                                          start, window, scale)
-    return o, cache
+    cache = write_chunk(k, v, cache, slot, positions, window)
+    return attend_chunk(q, cache, slot, start, window, hp, scale), cache

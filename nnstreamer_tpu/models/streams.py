@@ -1,13 +1,14 @@
 """What a stream is to a token model, written once (``deepseek_v2.py``,
 ``smallthinker.py``, ``nemotron_h.py``, ``exaone_moe.py``,
-``longcat_flash.py``, ``falcon_h1.py``): the seeded weights tests run on, the counters a
+``longcat_flash.py``, ``falcon_h1.py``, ``phi4_flash.py``): the seeded weights tests run on, the counters a
 step adds to, the book of where each stream stands, and the table a
 model registers with the ``jax-xla`` filter.  A model file keeps its
 configuration, its layers, ``param_shapes`` with their roles,
 ``counter_units`` (what a row is IS the model) and its two entry
 points; the grouped-query decode step and its caches are in
 ``attention.py``, latent attention in ``mla.py``, the Mamba-2 mixer
-with its recurrent state and snapshots in ``mamba2.py``, the experts in
+with its recurrent state and snapshots in ``mamba2.py``, the Mamba-1
+mixer in ``mamba1.py``, the experts in
 ``moe.py`` (``Documentation/stateful-models.md``, "Adding a token
 model").  The models import this module as ``stream``: ``streams`` is
 their word for how many streams a state holds.
@@ -109,14 +110,17 @@ def book(streams: int, newest: bool = False) -> dict:
     return out
 
 
-def book_prefilled(state: dict, slot, end) -> dict:
+def book_prefilled(state: dict, slot, end, newest=None) -> dict:
     """The book's entries after a prefill chunk that leaves stream
     ``slot`` at ``end`` tokens (chunks arrive in order, so the last one
-    leaves ``prompt_end`` at the prompt's end)."""
+    leaves ``prompt_end`` at the prompt's end); ``newest`` where the
+    chunk wrote rows beyond its last real token (a padded chunk on a
+    ring that takes its padding)."""
     out = {"prompt_end": state["prompt_end"].at[slot].set(end),
            "last": state["last"].at[slot].set(end - 1)}
     if "newest" in state:
-        out["newest"] = state["newest"].at[slot].set(end - 1)
+        out["newest"] = state["newest"].at[slot].set(
+            end - 1 if newest is None else newest)
     return out
 
 

@@ -1,7 +1,8 @@
 """Pallas TPU kernels: fused normalize/typecast, flash attention, short
 attention, latent decode attention, latent prefill attention,
 grouped-query decode attention, grouped-query prefill attention, the
-routed experts' grouped product and the Mamba-2 decode step.
+routed experts' grouped product, the Mamba-2 decode step and the
+Mamba-1 selective scan of a prefill chunk.
 
 Parity/role:
 - ``scale_bias_cast`` is the tensor_transform arithmetic prologue
@@ -64,6 +65,12 @@ Parity/role:
   flag says), updated and reduced to ``y`` in fast memory and copied
   out once over the live state, a few streams a grid step, the copies
   in and the copies out in separate phases.
+- ``selective_scan`` is the Mamba-1 recurrence over a prefill chunk of
+  one stream (``models/mamba1.py``, for ``phi4_flash.py``): the decay
+  is per (channel, state), so no chunked matrix form exists; a block of
+  channels' ``[state, channels]`` stays in vector registers over the
+  chunk's tokens and only ``delta``, ``delta x`` and ``y`` pass through
+  fast memory.
 
 All compile natively on TPU (Mosaic) and run under the Pallas
 interpreter on CPU backends (tests).  ``scale_bias_cast`` and
@@ -72,8 +79,8 @@ meet the tiling constraints — lane dim a multiple of 128, sublane dim a
 multiple of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32
 of 1-byte elements); ``short_attention``, ``latent_decode_attention``,
 ``latent_prefill_attention``, ``gqa_decode_attention``,
-``gqa_prefill_attention``, ``grouped_gated_product`` and
-``ssm_decode_step`` refuse a shape they
+``gqa_prefill_attention``, ``grouped_gated_product``,
+``ssm_decode_step`` and ``selective_scan`` refuse a shape they
 cannot take (``*_refusal`` says why: axes that do not fill tiles, a
 type the kernel is not written for, blocks over a fast-memory budget)
 and leave the choice to the caller.
@@ -476,6 +483,13 @@ _WALK_LATTICE = _LANE
 #: section 6 (PR 39) has what other sizes read on the chip
 _WALK_CHUNK_BYTES = 2 << 20
 _WALK_QUEUE_BYTES = 8 << 20
+#: rows a chunk has at least, where twice ``_WALK_CHUNK_BYTES`` hold
+#: them: an update of the running sums costs as much at 128 rows as at
+#: 512, so a WIDE row (ten K/V heads: 5,120 B) must not shrink the chunk
+#: to 256 rows.  On the chip (``PERF.md`` section 6, PR 50; 32 streams,
+#: 10 groups of 4 rows, a cache of 16,384 at 8-16 k): chunks of 256 rows
+#: 4.63 ms a call, of 512, 1,024 and 2,048 rows 2.77-2.79
+_WALK_CHUNK_ROWS = 512
 
 
 class WalkPlan(NamedTuple):
@@ -501,12 +515,17 @@ class WalkPlan(NamedTuple):
 def decode_walk_plan(total: int, row_bytes: int) -> WalkPlan:
     """The plan for caches of ``total`` rows of ``row_bytes``, from what
     a call can see: the largest chunk of whole cells that divides
-    ``total`` within ``_WALK_CHUNK_BYTES`` (and within half the cache,
-    so that a short cache still has a chunk to compute on while the next
-    one lands), and as many buffers as ``_WALK_QUEUE_BYTES`` hold (three
-    to eight)."""
+    ``total`` within ``_WALK_CHUNK_BYTES``, or within ``_WALK_CHUNK_ROWS``
+    rows where that is more and twice those bytes hold them (and within
+    half the cache, so that a short cache still has a chunk to compute
+    on while the next one lands), and as many buffers as
+    ``_WALK_QUEUE_BYTES`` hold (three to eight)."""
     lat = _WALK_LATTICE
-    most = max(min(_WALK_CHUNK_BYTES // row_bytes, total // 2), lat)
+    rows = _WALK_CHUNK_BYTES // row_bytes
+    if rows < _WALK_CHUNK_ROWS \
+            and _WALK_CHUNK_ROWS * row_bytes <= 2 * _WALK_CHUNK_BYTES:
+        rows = _WALK_CHUNK_ROWS
+    most = max(min(rows, total // 2), lat)
     chunk = next(c for c in range(most // lat * lat, 0, -lat)
                  if total % c == 0)
     slots = min(max(_WALK_QUEUE_BYTES // (chunk * row_bytes), 3), 8)
@@ -2533,3 +2552,161 @@ def _ssm_decode_step_call(streams: int, groups: int, state: int, lanes: int,
         return call(*operands)
 
     return jax.jit(ssm_decode_step)
+
+
+# -- the Mamba-1 selective scan of a prefill chunk ----------------------------
+
+#: channels a grid step of :func:`selective_scan` keeps (the widest of
+#: these that divides the channels: ``[16, 512]`` float32 is 8 vregs,
+#: carried through the loop over the tokens) and tokens a block
+_SCAN_CHANNELS = (512, 256, 128)
+_SCAN_TOKENS = 256
+
+
+def _scan_tiles(tokens: int, channels: int) -> tuple:
+    """``(tokens a block, channels a grid step)`` of
+    :func:`selective_scan`, 0 where none divides."""
+    cb = next((c for c in _SCAN_CHANNELS if channels % c == 0), 0)
+    tb = next((t for t in (_SCAN_TOKENS, 128, 64, 32, 16, 8)
+               if tokens % t == 0), 0)
+    return tb, cb
+
+
+def selective_scan_refusal(tokens: int, state_shape, dtypes) -> Optional[str]:
+    """Why :func:`selective_scan` cannot take a chunk of ``tokens`` on a
+    state ``[state, channels]`` whose operands have ``dtypes`` (the set
+    of theirs), or None: float32 throughout, whole tiles of 8 tokens,
+    the state axis whole sublanes and the channels whole lanes."""
+    names = sorted(np.dtype(d).name for d in dtypes)
+    if names != ["float32"]:
+        return f"operands of {', '.join(names)}: the recurrence is float32"
+    if len(state_shape) != 2:
+        return f"a state {tuple(state_shape)} is not [state, channels]"
+    state, channels = state_shape
+    tb, cb = _scan_tiles(tokens, channels)
+    if state % 8 or not cb:
+        return f"a state of [{state}, {channels}] is not whole tiles of " \
+               f"8 x {_LANE}"
+    if not tb:
+        return f"{tokens} tokens are not whole tiles of 8"
+    return None
+
+
+def selective_scan_reference(delta, dx, b, c, a, h0):
+    """The kernel's mathematics in jnp, a ``lax.scan`` a token, and the
+    path ``models/mamba1.py`` takes for a shape the kernel refuses:
+    ``delta`` and ``dx`` ``[T, channels]`` (``delta`` 0 for a token that
+    is padding, ``dx = delta x``), ``b`` and ``c`` ``[T, state]``, ``a``
+    and ``h0`` ``[state, channels]`` (``a = -exp(A_log)``), all float32.
+    ``h_t = exp(delta_t a) h_{t-1} + b_t (x) dx_t``, ``y_t = sum_n
+    h_t[n] c_t[n]``.  Returns ``(y [T, channels], h_T)``."""
+    import jax
+    import jax.numpy as jnp
+
+    def token(h, t):
+        d, x, bt, ct = t
+        h = jnp.exp(d[None, :] * a) * h + bt[:, None] * x[None, :]
+        return h, jnp.sum(h * ct[:, None], axis=0)
+
+    h, y = jax.lax.scan(token, h0, (delta, dx, b, c))
+    return y, h
+
+
+def selective_scan(delta, dx, b, c, a, h0):
+    """The Mamba-1 recurrence over a chunk of one stream, operands as
+    :func:`selective_scan_reference` has them.  The decay ``exp(delta_t
+    a)`` differs by channel AND state, so the chunked matrix form of
+    Mamba-2 (a scalar decay a head) does not exist; what is left is to
+    keep the state where the arithmetic is.  The grid walks (a block of
+    channels, a block of tokens): a step carries its channels' ``[state,
+    channels]`` through its tokens in vector registers (state in the
+    sublanes, channels in the lanes: ``delta_t`` and ``dx_t`` are rows,
+    ``y_t`` a sum over sublanes), 8 tokens a loop iteration, whose 8
+    rows of ``b`` and ``c`` are turned into columns by a product with
+    the identity (``HIGHEST``: exact), and only ``delta``, ``dx`` and
+    ``y`` pass through fast memory, once.  Between the token blocks of a
+    channel block the state waits in scratch.  A padded token (``delta``
+    and ``dx`` 0) leaves the state as it was: ``exp(0) h + 0``.  A shape
+    :func:`selective_scan_refusal` names is an error: the caller
+    chooses."""
+    refusal = selective_scan_refusal(
+        delta.shape[0], h0.shape,
+        {v.dtype for v in (delta, dx, b, c, a, h0)})
+    if refusal:
+        raise ValueError(f"selective_scan: {refusal}")
+    import jax.numpy as jnp
+
+    tokens, channels = delta.shape
+    tb, cb = _scan_tiles(tokens, channels)
+    call = _selective_scan_call(tokens, channels, h0.shape[0], tb, cb,
+                                _interpret())
+    return call(delta, dx, jnp.concatenate([b, c], axis=1), a, h0)
+
+
+@functools.lru_cache(maxsize=8)
+def _selective_scan_call(tokens: int, channels: int, state: int, tb: int,
+                         cb: int, interpret: bool):
+    """The jitted call of :func:`selective_scan` for one shape, built
+    once: a model's layers share the function."""
+    import jax.numpy as jnp
+
+    jax, pl, pltpu = _pl()
+    blocks = tokens // tb
+
+    def kernel(delta_ref, dx_ref, bc_ref, a_ref, h0_ref, y_ref, h_ref, held):
+        t = pl.program_id(1)
+
+        @pl.when(t == 0)
+        def _start():
+            held[...] = h0_ref[...]
+
+        a = a_ref[...]
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (2 * state, 2 * state), 0)
+               == jax.lax.broadcasted_iota(jnp.int32,
+                                           (2 * state, 2 * state), 1)
+               ).astype(jnp.float32)
+
+        def eight(i, h):
+            rows = pl.ds(pl.multiple_of(i * 8, 8), 8)
+            d8, x8 = delta_ref[rows, :], dx_ref[rows, :]
+            columns = jax.lax.dot_general(               # [2 state, 8]
+                eye, bc_ref[rows, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
+            ys = []
+            for s in range(8):
+                h = jnp.exp(d8[s:s + 1, :] * a) * h \
+                    + columns[:state, s:s + 1] * x8[s:s + 1, :]
+                ys.append(jnp.sum(h * columns[state:, s:s + 1], axis=0,
+                                  keepdims=True))
+            y_ref[rows, :] = jnp.concatenate(ys, axis=0)
+            return h
+
+        h = jax.lax.fori_loop(0, tb // 8, eight, held[...])
+        held[...] = h
+
+        @pl.when(t == blocks - 1)
+        def _end():
+            h_ref[...] = h
+
+    by_token = pl.BlockSpec((tb, cb), lambda ch, t: (t, ch))
+    by_channel = pl.BlockSpec((state, cb), lambda ch, t: (0, ch))
+    call = pl.pallas_call(
+        kernel, grid=(channels // cb, blocks),
+        in_specs=[by_token, by_token,
+                  pl.BlockSpec((tb, 2 * state), lambda ch, t: (t, 0)),
+                  by_channel, by_channel],
+        out_specs=[by_token, by_channel],
+        out_shape=[jax.ShapeDtypeStruct((tokens, channels), jnp.float32),
+                   jax.ShapeDtypeStruct((state, channels), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((state, cb), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret)
+
+    # no ``name=``: the call's device time is booked to the caller's
+    # stage (``.../mamba/scan``); the inner jit names the instruction
+    def selective_scan(*operands):
+        return call(*operands)
+
+    return jax.jit(selective_scan)

@@ -360,7 +360,11 @@ class StateStats:
     from its snapshots and ``position_faults`` (a model whose EVERY
     layer owns both kinds, ``falcon_h1.py``, counts every layer in
     ``ssm_bytes`` and in ``kv_bytes_*`` alike, and keeps no expert
-    counter: it routes nothing); a model whose router
+    counter: it routes nothing); a model in which ONE cache has one
+    writer and several readers, ``phi4_flash.py``, publishes
+    ``shared_kv_bytes_*`` (a row times its READERS) beside
+    ``ring_kv_bytes_*``, and from its prefill entry ``prefill_tokens``
+    and ``cross_tokens``; a model whose router
     also scores zero-compute experts adds ``zero_picks`` (the picks
     that cost no product; a step's picks are a constant, tokens x picks
     a token x layers, so ``zero_picks`` and ``expert_hits`` a frame over

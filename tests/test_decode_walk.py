@@ -80,6 +80,17 @@ CASES = {
     "dense-4096-four-groups-five-heads-bf16": (
         4, 5, 4096, 4096, None, "bfloat16",
         [0, 2047, 2048, 3071, 3840, 4095]),
+    # `phi4flash.decode16k`'s two calls: TEN K/V pairs of 128 with FOUR
+    # query rows a pair (`[q1 | 0]`, `[0 | q2]` of heads of 64), bf16,
+    # by the plans the calls derive from rows of 5,120 B: a ring of
+    # 1,536 read through a window of 512, and the one shared cache of
+    # 16,384, both in chunks of 512 rows (the least a chunk has where
+    # the bytes allow: 2 MiB alone would hold 256 of such rows)
+    "ring-1536-window-512-ten-pairs-four-rows-bf16": (
+        10, 4, 1536, 512, None, "bfloat16",
+        [0, 511, 512, 1535, 1536, 9000, 16383]),
+    "dense-16384-ten-pairs-four-rows-bf16": (
+        10, 4, 16384, 16384, None, "bfloat16", [8191, 16383]),
     "a-queue-longer-than-a-stream": (
         2, 5, 768, 512, WalkPlan(256, 8), "float32",
         [0, 130, 5000, 2, 767]),
@@ -114,9 +125,11 @@ def test_the_walk_is_the_reference(case):
     (384, 1024, (128, 8)), (768, 64 << 10, (128, 3)),
     (16640, 1280, (1280, 5)), (4096, 1280, (1024, 6)),
     (16640, 1152, (1664, 4)), (4096, 1152, (1024, 7)),
+    (16384, 5120, (512, 3)), (1536, 5120, (512, 3)),
 ], ids=["smallthinker-ring", "smallthinker-full", "nemotron3", "two-cells",
         "three-cells", "wide-rows", "rows-of-640-in-16640",
-        "rows-of-640-in-4096", "dsv2-latent", "longcat-latent"])
+        "rows-of-640-in-4096", "dsv2-latent", "longcat-latent",
+        "phi4flash-shared", "phi4flash-ring"])
 def test_the_plan_follows_what_the_call_sees(total, row_bytes, plan):
     got = kernels.decode_walk_plan(total, row_bytes)
     assert tuple(got) == plan
@@ -543,3 +556,43 @@ def test_a_refused_shape_fetches_the_cache_whole():
     assert int(kernels.gqa_decode_rows_fetched(
         (3, 2, 4, 128), (3, 2, 256, 128), np.array([1, 2, 200]), 256)) \
         == 4 * LAT
+
+
+def test_phi4_flash_counts_the_shared_cache_once_and_its_readers_in_the_unit():
+    """The toy's eight layers at heads of 64 (paired rows of 128): two
+    rings and ONE cache through the kernel.  A step adds the walk's rows
+    of one ring and of the cache ONCE; the units multiply the ring's by
+    the rings and the cache's by its readers (its writer and the one
+    cross layer of the toy)."""
+    from nnstreamer_tpu.models import phi4_flash as pf
+
+    with open(os.path.join(REPO, "tests", "benchmark", "data",
+                           "toy_phi4flash.json")) as f:
+        cfg = pf.Phi4FlashConfig.from_dict(json.load(f))
+    params = pf.init_params(cfg, jax.random.PRNGKey(1), jnp.float32)
+    state = pf.init_state(cfg, params, 3, 256, 96)
+    assert state["rings"][0]["k"].shape == (3, 2, 128, 128)
+    assert state["shared"]["k"].shape == (3, 2, 256, 128)
+    positions = np.array([0, 127, 200], np.int32)
+    state = dict(state, last=jnp.asarray(positions - 1),
+                 prompt_end=jnp.asarray(positions))
+    before = _counted(state)
+    state, _ = pf.decode(cfg, params, state, np.zeros(3, np.int32), positions)
+    after = _counted(state)
+    assert after["shared_rows_read"] - before["shared_rows_read"] \
+        == 1 + 128 + 201
+    assert after["ring_rows_read"] - before["ring_rows_read"] == 1 + 32 + 32
+    assert after["shared_rows_fetched"] - before["shared_rows_fetched"] \
+        == (1 + 1 + 2) * LAT \
+        == int(kernels.decode_rows_fetched(positions, 256, 256))
+    # a window of 32 at 127 and at 200 lies in one cell of the ring
+    assert after["ring_rows_fetched"] - before["ring_rows_fetched"] \
+        == int(kernels.decode_rows_fetched(positions, 128, 32)) == 3 * LAT
+    assert after["position_faults"] == 0
+    units = pf.counter_units(cfg, state)
+    row = 2 * 2 * 128 * 4
+    assert units["shared_kv_bytes_fetched"] == ("shared_rows_fetched",
+                                                row * 2)
+    assert units["ring_kv_bytes_fetched"] == ("ring_rows_fetched", row * 2)
+    assert units["cache_bytes_fetched"] == units["kv_bytes_fetched"] \
+        == [units["shared_kv_bytes_fetched"], units["ring_kv_bytes_fetched"]]

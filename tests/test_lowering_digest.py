@@ -1,7 +1,7 @@
 """``tools/lowering_digest.py``: the digest that judges a move of a token
 model's code says "the same program" for the same program, something
-else for another, and makes no weight on the way.  Toy twins of the five
-cells' configurations with heads, windows, caches and chunks of whole
+else for another, and makes no weight on the way.  Toy twins of the
+token cells' configurations with heads, windows, caches and chunks of whole
 lanes, so that the decode kernels are in the lowered text.  No case
 compares with a digest kept in the tree."""
 
@@ -30,6 +30,7 @@ MODELS = {
     "exaone_moe": ("toy_kexaone", dict(LANES, sliding_window=128)),
     "longcat_flash": ("toy_longcat", {}),
     "falcon_h1": ("toy_falconh1", {}),
+    "phi4_flash": ("toy_phi4flash", {}),
 }
 SIZES = {"streams": 8, "positions": 256, "chunk": 128}
 
@@ -113,6 +114,30 @@ def test_the_scopes_name_the_stages_the_metrics_read(tool, configs):
                   "layer03/attn_full/gqa_decode_attention",
                   "layer01/moe/router", "mtp/merge"):
         assert any(f"/nns.model/{stage}/" in path for path in paths), stage
+
+
+def test_state_that_owns_no_layer_lowers_under_the_readers_scopes(tool,
+                                                                 configs):
+    """``phi4_flash``: the layers above the shared cache's writer attend
+    (a kernel under their own scope) and write nothing."""
+    mod, cfg = tool.load("phi4_flash", configs["phi4_flash"])
+    args = tool.abstract_arguments(mod, cfg, _sizes("phi4_flash"))
+    _text, scopes = tool.lowered_text(*args["decode"])
+    paths = [line.rsplit(" ", 1)[0] for line in scopes.splitlines()]
+    for stage in ("layer00/mamba/step", "layer01/attn_window/cache_write",
+                  "layer05/attn_full/cache_write",
+                  "layer05/attn_full/gqa_decode_attention", "layer06/gmu",
+                  "layer07/attn_cross/gqa_decode_attention",
+                  "layer07/attn_cross/diff", "ssm_restore", "head"):
+        assert any(f"/nns.model/{stage}" in path for path in paths), stage
+    assert not any("attn_cross/cache_write" in path for path in paths)
+    _text, scopes = tool.lowered_text(*args["prefill"])
+    paths = [line.rsplit(" ", 1)[0] for line in scopes.splitlines()]
+    for stage in ("layer00/mamba/scan", "layer01/attn_window/"
+                  "gqa_prefill_attention", "layer05/attn_full/cache_write",
+                  "layer05/attn_full/gqa_decode_attention",
+                  "layer07/attn_cross/gqa_decode_attention"):
+        assert any(f"/nns.model/{stage}" in path for path in paths), stage
 
 
 def test_the_command_line_prints_one_line_an_entry(configs, tmp_path):

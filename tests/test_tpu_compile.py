@@ -717,3 +717,119 @@ def test_the_falcon_cells_programs_fit_the_chip(one_chip, monkeypatch, entry,
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= (8 if entry == "decode" else 4)
     assert "ssm_restore" not in text
+
+
+@pytest.mark.parametrize("streams", [32, 1], ids=["step", "one_token"])
+@pytest.mark.parametrize("total,window", [(16384, 16384), (1536, 512)],
+                         ids=["shared", "ring"])
+def test_the_paired_heads_decode_attention_compiles(one_chip, monkeypatch,
+                                                    streams, total, window):
+    """`phi4flash.decode16k`'s decode attention: heads of 64 handed over
+    as 10 K/V pairs of 128 with 4 query rows a pair (`[q1 | 0]`, `[0 |
+    q2]`), on the one shared cache of 16,384 and on a ring of 1,536 read
+    through a window of 512; 32 streams a step, and ONE stream (the
+    prefill's last token, which layer 17 and the layers above it run
+    on), as a Mosaic kernel with no copy of a cache beside it."""
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    q, kv = (streams, 10, 4, 128), (streams, 10, total, 128)
+    assert kernels.gqa_decode_attention_refusal(q, kv, kv, window) is None
+    fn = jax.jit(functools.partial(kernels.gqa_decode_attention,
+                                   window=window, scale=64 ** -0.5))
+    compiled = fn.lower(shape(q), shape(kv), shape(kv),
+                        shape((streams,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_the_paired_heads_prefill_and_the_scan_compile(one_chip, monkeypatch):
+    """`phi4flash.decode16k`'s two prefill kernels: a chunk of 1,024 on
+    a ring of 1,536 (a window of 512 behind every query: 512 + 1,023
+    rows) at 10 pairs x 4 rows x 128, and the Mamba-1 selective scan of
+    the chunk on `[16, 5120]` float32 (10 blocks of 512 channels, 4 of
+    256 tokens)."""
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    q, ring = (1024, 10, 4, 128), (32, 10, 1536, 128)
+    assert kernels.gqa_prefill_attention_refusal(
+        q, ring, ring, 512, {jnp.dtype(jnp.bfloat16)}) is None
+    fn = jax.jit(functools.partial(kernels.gqa_prefill_attention,
+                                   window=512, scale=64 ** -0.5))
+    compiled = fn.lower(shape(q), shape(ring), shape(ring),
+                        shape((), jnp.int32), shape((), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+    f32 = functools.partial(shape, dtype=jnp.float32)
+    assert kernels.selective_scan_refusal(
+        1024, (16, 5120), {jnp.dtype(jnp.float32)}) is None
+    assert kernels._scan_tiles(1024, 5120) == (256, 512)
+    compiled = jax.jit(kernels.selective_scan).lower(
+        f32((1024, 5120)), f32((1024, 5120)), f32((1024, 16)),
+        f32((1024, 16)), f32((16, 5120)), f32((16, 5120))).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("entry,temp_mb", [("decode", 64), ("prefill", 256)])
+def test_the_phi4flash_cells_programs_fit_the_chip(one_chip, monkeypatch,
+                                                   entry, temp_mb, capsys):
+    """`phi4flash.decode16k`'s two programs at the cell's sizes (the
+    whole model: 32 layers, 200,064 rows; 32 streams, 16,384 positions,
+    chunks of 1,024; 7.7 GB of weights and 4.9 GB of state as
+    arguments).  The one shared cache is 2.68 GB in two leaves: a copy
+    of either, or a transposed copy of the 1 GB embedding for the tied
+    head, shows in the temporaries.  A decode step holds 16 attention
+    calls (8 on rings, 8 on the ONE cache), a prefill chunk 8 prefill
+    attentions on rings, 9 scans and 8 one-token attentions on the
+    cache's rows of the chunk's stream."""
+    import json
+    import os
+
+    from nnstreamer_tpu.models import phi4_flash as pf
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "phi4_mini_flash_reasoning.json")
+    with open(path) as f:
+        raw = json.load(f)
+    cfg = pf.Phi4FlashConfig.from_dict(raw)
+    chunk = raw["serving"]["prefill_chunk"]
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: pf.init_params(cfg, 0))
+    state = jax.eval_shape(lambda: pf.init_state(cfg, params, 32, 16384,
+                                                 chunk))
+    held = [sum(a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(tree))
+            for tree in (params, state)]
+    assert 7.70e9 < held[0] < 7.72e9 and 4.85e9 < held[1] < 4.95e9
+
+    def i32(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+
+    fn, inputs = {"decode": (pf.decode, [i32(32), i32(32)]),
+                  "prefill": (pf.prefill, [i32(chunk), i32(1), i32(1),
+                                           i32(1)])}[entry]
+    compiled = jax.jit(functools.partial(fn, cfg), donate_argnums=(1,)) \
+        .lower(on_chip(params), on_chip(state), *inputs).compile()
+    memory = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nphi4flash {entry}: {memory}")
+    assert memory.alias_size_in_bytes >= held[1]
+    assert memory.temp_size_in_bytes < temp_mb << 20
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 14.0e9
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (16 if entry == "decode"
+                                             else 8 + 9 + 8)

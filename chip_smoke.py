@@ -196,14 +196,16 @@ def detections_agree(got, want, what: str) -> dict:
 def ssd_reference(model: str, device):
     """The registered detect function jitted directly on ``device``
     with the transform's arithmetic in front — what the pipelines'
-    results are compared with.  ``flat_fn`` hands back the weights the
-    filter placed on that device, so HBM holds one copy."""
+    results are compared with.  ``placed`` hands back the function and
+    the weights the filter placed on that device, so HBM holds one
+    copy; they are arguments of this program too."""
     import jax
 
     from nnstreamer_tpu.filters.jax_xla import get_model
 
-    fn = get_model(model).flat_fn(device)
-    return jax.jit(lambda x: fn(norm(x)))
+    fn, weights = get_model(model).placed(device)
+    return functools.partial(
+        jax.jit(lambda w, x: fn(w, norm(x))), weights)
 
 
 def kernel_evidence(fn, *avals):
@@ -527,10 +529,10 @@ def section_c(batch: int, image: int, patch: int, dim: int, depth: int,
             sp = p["vit"].subplugin
             check(bool(p["vit"]._fused_pre), "C: vit transform did not fuse")
             # the pipeline's own program, traced again: no compile
-            program, _, _ = sp._normalized_fn(sp._model,
-                                              sp._compiled.in_spec)
+            program = sp._compiled.program
             calls, mosaic = kernel_evidence(
-                program, jax.ShapeDtypeStruct(frames[0].shape, np.uint8))
+                program.fn, program.weights,
+                jax.ShapeDtypeStruct(frames[0].shape, np.uint8))
             staged = [slot[0] for slot in p["src"]._pool]
         check(calls == depth,
               f"C: the ViT program holds {calls} pallas_call(s) for "
@@ -538,8 +540,9 @@ def section_c(batch: int, image: int, patch: int, dim: int, depth: int,
         check(mosaic == compiled_for_chip,
               f"C: ViT lowering has the Mosaic call: {mosaic}; kernels "
               f"compiled for the chip: {compiled_for_chip}")
-        vit = get_model(model).flat_fn(dev)
-        direct = jax.jit(lambda x: vit(norm(x)))
+        vit, weights = get_model(model).placed(dev)
+        direct = functools.partial(
+            jax.jit(lambda w, x: vit(w, norm(x))), weights)
         for i, buf in enumerate(got):
             logits = buf.tensors[0].jax()
             on_devices(logits, [dev], f"C: vit buffer {i}")

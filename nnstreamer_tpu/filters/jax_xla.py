@@ -38,6 +38,7 @@ from ..obs import xlacost as _xlacost
 from ..runtime.events import Event, EventKind
 from ..utils import profile as _profile
 from ..utils.stats import COMPILE_STATS, DISPATCH_STATS, STATE_STATS
+from . import weightsplit as _weightsplit
 from .api import FilterError, FilterProps, FilterSubplugin, SHARED_MODELS
 from .registry import register_filter
 
@@ -97,7 +98,9 @@ def _aot_call(lowered, jitted: Callable, pkey: Optional[str] = None,
     program from ``NNS_TPU_COMPILE_CACHE_DIR`` (counted as a
     ``persist_hit`` compile) onto ``devices`` — the executable's own
     device list — before paying the XLA build, and a fresh build is
-    serialized back for the next process."""
+    serialized back for the next process.  The weights are among the
+    call's arguments, so an entry holds none and serves every set of
+    the key's schema."""
     # the Lowered (traced jaxpr + IR) lives in state, not the closure's
     # free variables, so it can be dropped the moment the executable is
     # resolved — a long-running serving process must not pin megabytes
@@ -169,51 +172,67 @@ class ModelDef:
         self._dev_params: Dict[Any, Any] = {}  # device → placed pytree
         self._mesh_params: Dict[Any, Any] = {}  # (mesh, rules) → pytree
 
-    def flat_fn(self, device=None) -> Callable:
+    def placed(self, device=None, mesh=None, rules=None
+               ) -> Tuple[Callable, Any]:
+        """``(fn, weights)``: the model as ``fn(weights, *inputs)`` and
+        the weights it takes, on ``device`` — or, given a mesh, laid
+        over it per the named ``rules`` (parallel.shard_params) — cached
+        per device / per (mesh, rules) so shared and hot-reloaded
+        instances don't transfer twice.  ``weights`` is the params
+        pytree's ARRAY leaves under their own paths; a leaf that is no
+        array (a Python int, float or string) is configuration: it stays
+        the value ``fn`` closes over, and the program is built for it.
+        The weights are arguments of whatever the caller compiles, never
+        closed over: host leaves would be baked into the HLO as
+        literals, and so would device arrays.  Committed to their place,
+        they also pin the computation there (the accelerator=
+        property).  A model without params takes ``None`` for them."""
         if self.params is None:
-            return self.fn
-        if device not in self._dev_params:
-            # Params must be device arrays before they are closed over:
-            # host (numpy) leaves would be baked into the HLO as literals.
-            # Committing them to ``device`` also pins the whole computation
-            # there (the accelerator= property).
+            return (lambda _weights, *inputs: self.fn(*inputs)), None
+        jax = _jax()
+        tree = jax.tree_util
+        cache, key = (self._dev_params, device) if mesh is None \
+            else (self._mesh_params, (mesh, rules))
+        if key not in cache:
             t0 = time.perf_counter()
-            self._dev_params[device] = _jax().device_put(self.params, device)
+            if mesh is None:
+                leaves, treedef = tree.tree_flatten(self.params)
+                put = iter(jax.device_put(
+                    [x for x in leaves if _is_weight(x)], device))
+                cache[key] = tree.tree_unflatten(treedef, [
+                    next(put) if _is_weight(x) else x for x in leaves])
+            else:
+                from ..parallel import shard_params
+
+                cache[key] = tree.tree_map(
+                    lambda was, put: put if _is_weight(was) else was,
+                    self.params, shard_params(mesh, self.params, rules))
             nbytes = _xfer.params_nbytes(self.params)
             _xfer.record("h2d", "weights", nbytes,
                          time.perf_counter() - t0, source=self.name)
-            _profile.note(f"{nbytes} B of weights put on {device}")
-        params = self._dev_params[device]
-
-        def fn(*inputs):
-            return self.fn(params, *inputs)
-
-        return fn
-
-    def mesh_fn(self, mesh, rules) -> Callable:
-        """Like :meth:`flat_fn` but params laid out over ``mesh`` per the
-        named ``rules`` (parallel.shard_params) — the multi-chip placement,
-        cached per (mesh, rules) so shared/hot-reloaded instances don't
-        re-transfer weights."""
-        if self.params is None:
-            return self.fn
-        key = (mesh, rules)
-        if key not in self._mesh_params:
-            from ..parallel import shard_params
-
-            t0 = time.perf_counter()
-            self._mesh_params[key] = shard_params(mesh, self.params, rules)
-            nbytes = _xfer.params_nbytes(self.params)
-            _xfer.record("h2d", "weights", nbytes,
-                         time.perf_counter() - t0, source=self.name)
-            _profile.note(f"{nbytes} B of weights laid over "
+            _profile.note(f"{nbytes} B of weights put on {device}"
+                          if mesh is None else
+                          f"{nbytes} B of weights laid over "
                           f"{dict(mesh.shape)}")
-        params = self._mesh_params[key]
+        leaves, treedef = tree.tree_flatten(cache[key])
+        at = [i for i, x in enumerate(leaves) if _is_weight(x)]
+        static = [None if _is_weight(x) else x for x in leaves]
+        inner = self.fn
 
-        def fn(*inputs):
-            return self.fn(params, *inputs)
+        def fn(weights, *inputs):
+            full = list(static)
+            for i, w in zip(at, tree.tree_leaves(weights)):
+                full[i] = w
+            return inner(tree.tree_unflatten(treedef, full), *inputs)
 
-        return fn
+        return fn, tree.tree_map(
+            lambda x: x if _is_weight(x) else None, cache[key])
+
+
+def _is_weight(leaf) -> bool:
+    """An array leaf of a params pytree is a weight; anything else (a
+    Python number, a string) is configuration."""
+    return hasattr(leaf, "shape") and hasattr(leaf, "dtype")
 
 
 def register_model(name: str, fn: Callable, params: Any = None,
@@ -425,11 +444,74 @@ class _StateCell:
 # -- the sub-plugin ----------------------------------------------------------
 
 
+class _Program:
+    """The per-frame computation of one (model, input schema) as every
+    window path compiles it: ``fn(weights, *inputs)``, outputs
+    normalized to a tuple, and the ``weights`` it takes now — what the
+    model's weights prologue (``filters/weightsplit.py``) made of the
+    placed params, on the device; empty for a model without params,
+    and over a mesh, where the weights are still closed over.
+    The executables built from ``fn`` hold no weight:
+    :meth:`rebound` gives the same program over another set of weights
+    of the same shapes, which is all a weights-only swap is."""
+
+    __slots__ = ("fn", "weights", "with_pre", "with_post", "_serves",
+                 "_make")
+
+    def __init__(self, fn, weights, with_pre, with_post, serves=None,
+                 make=None):
+        self.fn = fn
+        self.weights = weights
+        self.with_pre = with_pre
+        self.with_post = with_post
+        self._serves = serves   # (model fn, _params_schema)
+        self._make = make       # placed weights -> the program's weights
+
+    def avals(self) -> List[Any]:
+        """What an executable is lowered for in the weights' place:
+        their shapes, dtypes and shardings, and nothing of their
+        values."""
+        jax = _jax()
+        return [jax.ShapeDtypeStruct(w.shape, w.dtype, sharding=w.sharding)
+                for w in self.weights]
+
+    def over_frames(self, nt: int) -> Callable:
+        """``fn`` over a leading axis of its ``nt`` inputs: the weights
+        are every frame's."""
+        return _jax().vmap(self.fn, in_axes=(None,) + (0,) * nt)
+
+    def rebound(self, model: ModelDef, weights) -> Optional["_Program"]:
+        """This program over ``weights`` (``model``'s placed array
+        leaves): the prologue run on them, no trace and no build.  None
+        where they are another function's or differ in a shape, a dtype,
+        a sharding or a non-array leaf's value."""
+        if self._make is None or self._serves != (
+                model.fn, _params_schema(model.params, weights)):
+            return None
+        return _Program(self.fn, self._make(weights), self.with_pre,
+                        self.with_post, self._serves, self._make)
+
+
+def _params_schema(params, weights) -> Tuple:
+    """What a program is built for of its model's params: the tree, the
+    value of every leaf that is no array (the function closes over it)
+    and every placed array leaf's shape, dtype and sharding."""
+    tree = _jax().tree_util
+    leaves, treedef = tree.tree_flatten(params)
+    return (treedef, tuple(x for x in leaves if not _is_weight(x)),
+            tuple((w.shape, w.dtype, w.sharding)
+                  for w in tree.tree_leaves(weights)))
+
+
 class _Compiled:
     """One compiled schema-specialized executable + its I/O specs.
-    ``with_pre`` records whether a fused transform prologue was baked
-    in, so negotiation can detect a stale executable after the fusion
-    pass re-derives (e.g. the element was re-used unfused).
+    ``jitted(*inputs)`` runs ``exe(program.weights, *inputs)``: the
+    executable takes the weights as its leading argument and
+    ``program`` holds the ones this instance serves (``exe`` and
+    ``program`` are None for a stateful model, whose cell threads its
+    own).  ``with_pre`` records whether a fused transform prologue was
+    baked in, so negotiation can detect a stale executable after the
+    fusion pass re-derives (e.g. the element was re-used unfused).
     ``in_shardings`` (mesh path only) holds the per-input NamedSharding
     the executable was specialized to, so ``invoke`` can place incoming
     host/foreign arrays without a resharding surprise.  ``with_post``
@@ -437,17 +519,30 @@ class _Compiled:
     overlay fusion)."""
 
     __slots__ = ("jitted", "in_spec", "out_spec", "with_pre", "with_post",
-                 "in_shardings")
+                 "in_shardings", "exe", "program")
 
     def __init__(self, jitted, in_spec: TensorsSpec, out_spec: TensorsSpec,
                  with_pre: bool = False, with_post: bool = False,
-                 in_shardings=None):
+                 in_shardings=None, exe=None, program=None):
         self.jitted = jitted
         self.in_spec = in_spec
         self.out_spec = out_spec
         self.with_pre = with_pre
         self.with_post = with_post
         self.in_shardings = in_shardings
+        self.exe = exe
+        self.program = program
+
+    @classmethod
+    def bound(cls, exe, program: _Program, in_spec, out_spec,
+              in_shardings=None) -> "_Compiled":
+        """``exe`` (weights first) serving ``program``'s weights."""
+        def jitted(*inputs):
+            return exe(program.weights, *inputs)
+
+        jitted.lower = exe.lower
+        return cls(jitted, in_spec, out_spec, program.with_pre,
+                   program.with_post, in_shardings, exe, program)
 
 
 @register_filter
@@ -501,6 +596,10 @@ class JaxXlaFilter(FilterSubplugin):
         # compile is a "reload", not a "cold" start (set by
         # prepare_swap before configure)
         self._compile_kind: Optional[str] = None
+        # what serves on the instance a swap SHADOW stands in for (set
+        # by prepare_swap before configure): new weights of its shapes
+        # take its executables instead of building their own
+        self._donor: Optional[_Compiled] = None
         # who the spans of this instance's work are named after
         # (utils/profile.py): the owning element sets its own name, a
         # serving pool its label
@@ -935,22 +1034,31 @@ class JaxXlaFilter(FilterSubplugin):
                 out_bytes=_avals_nbytes(
                     _jax().tree_util.tree_leaves(lowered.out_info)))
 
-    def _normalized_fn(self, model: ModelDef, in_spec: TensorsSpec):
-        """The per-frame computation as one traceable callable: fused
-        transform prologue + model + fused decoder epilogue, outputs
-        normalized to a tuple.  Shared by the single-frame compile and
-        the per-bucket micro-batch compiles (which vmap it)."""
+    def _normalized_fn(self, model: ModelDef, in_spec: TensorsSpec
+                       ) -> _Program:
+        """The per-frame computation as one traceable callable, weights
+        first: fused transform prologue + model + fused decoder
+        epilogue, outputs normalized to a tuple.  Built once per
+        ``_compile`` and shared by the single-frame executable and the
+        per-bucket micro-batch ones (which vmap it over the frames).
+
+        A model that carries params (on one device: see below for a
+        mesh) is traced here once with its weights abstract and split (``filters/weightsplit.py``): what it
+        computes from weights alone is the weights prologue, a small
+        program of its own that runs inside ``<filter>/state_init``;
+        the rest, with the prologue's results as its leading argument,
+        is what the window paths compile."""
+        jax = _jax()
         # a stateless model's share of <filter>/state_init: its weights
         # go onto the device (or over the mesh) the first time only
         with _profile.span(self.trace_owner, "state_init", setup=True):
-            fn = model.mesh_fn(self._mesh, self._rules) \
-                if self._mesh is not None else model.flat_fn(self._device)
+            fn, placed = model.placed(self._device, self._mesh, self._rules)
         pre = self._pre_fns(in_spec) if self._pre_chains else None
         post = self._post_fns[0] if self._post_fns else None
 
-        scope = _jax().named_scope
+        scope = jax.named_scope
 
-        def normalized(*inputs):
+        def normalized(weights, *inputs):
             # the stage vocabulary of the device trace
             # (Documentation/observability.md): scopes are HLO metadata
             # only and cost nothing at run time
@@ -958,7 +1066,7 @@ class JaxXlaFilter(FilterSubplugin):
                 with scope("nns.pre"):
                     inputs = [g(x) for g, x in zip(pre, inputs)]
             with scope("nns.model"):
-                out = fn(*inputs)
+                out = fn(weights, *inputs)
             out = tuple(out) if isinstance(out, (list, tuple)) else (out,)
             if post is not None:
                 # fused downstream epilogue (decoder device overlay):
@@ -967,35 +1075,104 @@ class JaxXlaFilter(FilterSubplugin):
                     out = tuple(post(*out))
             return out
 
-        return normalized, pre is not None, post is not None
+        if placed is None or self._mesh is not None:
+            # Over a mesh the weights stay literals of the program, as
+            # they were: with the SSD's weights as arguments XLA fuses
+            # a chip's 64 frames of the backbone differently and
+            # ``ssd300.replay.mesh4`` lost 8 % of its rate (PERF.md
+            # section 6, PR 51; section 7 has what to try).  So a meshed
+            # filter's program still changes with its weights, and a
+            # swap there builds.
+            return _Program(
+                lambda _none, *inputs: normalized(placed, *inputs), [],
+                pre is not None, post is not None)
+        try:
+            with _profile.span(self.trace_owner, "trace_lower", setup=True):
+                parts = _weightsplit.trace(normalized, placed, *[
+                    jax.ShapeDtypeStruct(t.shape, t.dtype.np_dtype)
+                    for t in in_spec.tensors])
+        except jax.errors.ConcretizationTypeError as e:
+            import re
+
+            read = sorted(set(re.findall(r"weights((?:\[[^\]]+\])+)",
+                                         str(e))))
+            raise FilterError(
+                f"jax-xla: model {model.name} reads the VALUE of "
+                + (f"its weight{'s' if len(read) > 1 else ''} "
+                   f"{', '.join(read)}" if read else "an argument")
+                + " while it is traced (a shape, an index or a branch "
+                "taken from an array's contents).  Its weights are "
+                "arguments of the program, which is built for their "
+                "shapes alone: keep such a constant as a Python number "
+                f"in the params, or in the function's closure.  {e}"
+            ) from e
+        except Exception as e:
+            raise FilterError(
+                f"jax-xla: model {model.name} rejects input {in_spec}: {e}"
+            ) from e
+        with _profile.span(self.trace_owner, "state_init", setup=True):
+            weights = parts.make(placed)
+        return _Program(
+            parts.window, weights, pre is not None, post is not None,
+            serves=(model.fn, _params_schema(model.params, placed)),
+            make=parts.make)
+
+    def _reuse(self, model: ModelDef, in_spec: TensorsSpec
+               ) -> Optional[_Compiled]:
+        """The serving executable over ``model``'s weights, where a
+        swap shadow's model differs from the one that serves in its
+        weights' values alone (same function, shapes, dtypes, shardings
+        and fused stages): a placement and a run of the prologue, no
+        trace, no lowering and no build — counted as a ``reuse``."""
+        cur = self._donor
+        if cur is None or cur.program is None or cur.in_spec != in_spec \
+                or cur.with_pre != bool(self._pre_chains) \
+                or cur.with_post != bool(self._post_fns):
+            return None
+        t0 = time.perf_counter()
+        with _profile.span(self.trace_owner, "state_init", setup=True):
+            _fn, placed = model.placed(self._device, self._mesh, self._rules)
+            program = cur.program.rebound(model, placed)
+        if program is None:
+            return None
+        COMPILE_STATS.record("reuse", time.perf_counter() - t0)
+        return _Compiled.bound(cur.exe, program, cur.in_spec, cur.out_spec,
+                               cur.in_shardings)
 
     def _compile(self, model: ModelDef, in_spec: TensorsSpec,
                  kind: str = "cold") -> _Compiled:
         jax = _jax()
+        reused = self._reuse(model, in_spec)
+        if reused is not None:
+            return reused
         if self._compile_kind is not None:
             kind = self._compile_kind
         mesh = self._mesh
         owner = self.trace_owner
         t_compile0 = time.perf_counter()
-        normalized, with_pre, with_post = self._normalized_fn(model, in_spec)
+        program = self._normalized_fn(model, in_spec)
+        nt = in_spec.num_tensors
         kw = {}
         if self._donate:
-            kw["donate_argnums"] = tuple(range(in_spec.num_tensors))
+            # the inputs alone: the weights serve every call
+            kw["donate_argnums"] = tuple(range(1, 1 + nt))
         in_shardings = None
         if mesh is not None:
             in_shardings = tuple(
                 self._input_sharding(t) for t in in_spec.tensors)
-            kw["in_shardings"] = in_shardings
-        jitted = jax.jit(normalized, **kw)
+            kw["in_shardings"] = (
+                [w.sharding for w in program.weights], *in_shardings)
+        jitted = jax.jit(program.fn, **kw)
         # Infer output schema without running the device: the jit
         # LOWERING yields the out avals AND the executable's static
         # cost (HLO cost analysis — no XLA build, measured ~1 ms) in
         # one trace.
+        w_avals = program.avals()
         avals = [jax.ShapeDtypeStruct(t.shape, t.dtype.np_dtype)
                  for t in in_spec.tensors]
         try:
             with _profile.span(owner, "trace_lower", setup=True):
-                lowered = jitted.lower(*avals)
+                lowered = jitted.lower(w_avals, *avals)
         except Exception as e:
             raise FilterError(
                 f"jax-xla: model {model.name} rejects input {in_spec}: {e}"
@@ -1021,10 +1198,10 @@ class JaxXlaFilter(FilterSubplugin):
             # the XLA build here exactly like on the bucket path
             fn = _aot_call(lowered, jitted, pkey=pkey, bucket=0,
                            devices=self._exec_devices(), owner=owner)
-        return _Compiled(_timed_first_call(
-            fn, skey, owner, lambda: jitted.lower(*avals)),
-            in_spec, out_spec, with_pre=with_pre, with_post=with_post,
-            in_shardings=in_shardings)
+        return _Compiled.bound(
+            _timed_first_call(fn, skey, owner,
+                              lambda: jitted.lower(w_avals, *avals)),
+            program, in_spec, out_spec, in_shardings)
 
     def _input_sharding(self, tspec: TensorSpec):
         """Batch-shard an input over the placement's data axes when its
@@ -1325,13 +1502,15 @@ class JaxXlaFilter(FilterSubplugin):
 
     # -- micro-batched hot path ----------------------------------------------
 
-    def _compile_batched(self, model: ModelDef, in_spec: TensorsSpec,
+    def _compile_batched(self, model: ModelDef, c: _Compiled,
                          bucket: int):
-        """One executable per (in_spec, bucket): takes ``bucket`` frames'
-        tensors as flat args (frame-major), stacks each input along a new
-        leading micro-batch axis INSIDE the program, vmaps the per-frame
-        computation over it, and returns per-frame output tensors — so a
-        whole window is exactly one XLA dispatch, stack/unstack included.
+        """One executable per (in_spec, bucket) of ``c``'s program:
+        takes the weights, then ``bucket`` frames' tensors as flat args
+        (frame-major), stacks each input along a new leading micro-batch
+        axis INSIDE the program, vmaps the per-frame computation over
+        the frames (the weights are every frame's), and returns
+        per-frame output tensors — so a whole window is exactly one XLA
+        dispatch, stack/unstack included.
 
         Multi-chip: the micro-batch axis is sharded over the mesh's data
         axis (the same ``_data_axis`` the single-frame path batch-shards
@@ -1342,8 +1521,9 @@ class JaxXlaFilter(FilterSubplugin):
         import jax.numpy as jnp
 
         t_compile0 = time.perf_counter()
-        normalized, _, _ = self._normalized_fn(model, in_spec)
+        in_spec, program = c.in_spec, c.program
         nt = in_spec.num_tensors
+        over_frames = program.over_frames(nt)
         constraint = None
         if self._placement is not None:
             # the placement layer owns the divisibility rule: shard the
@@ -1351,7 +1531,7 @@ class JaxXlaFilter(FilterSubplugin):
             # window splits evenly, else leave it replicated
             constraint = self._placement.window_sharding(bucket)
 
-        def batched(*flat):
+        def batched(weights, *flat):
             # nns.window: the stack and unstack around the per-frame
             # stages, so that every operation of a window program has a
             # stage in the device trace
@@ -1363,7 +1543,7 @@ class JaxXlaFilter(FilterSubplugin):
                     stacked = [
                         jax.lax.with_sharding_constraint(s, constraint)
                         for s in stacked]
-            outs = jax.vmap(normalized)(*stacked)
+            outs = over_frames(weights, *stacked)
             per_frame = []
             with jax.named_scope("nns.window"):
                 for i in range(bucket):
@@ -1372,18 +1552,19 @@ class JaxXlaFilter(FilterSubplugin):
 
         kw = {}
         if self._donate:
-            kw["donate_argnums"] = tuple(range(bucket * nt))
+            kw["donate_argnums"] = tuple(range(1, 1 + bucket * nt))
         jitted = jax.jit(batched, **kw)
         # executable cost capture for this bucket's window program: ONE
         # trace — the capture's Lowered is also what serves dispatches
         # (AOT-compiled on the first call, so the XLA build stays lazy
         # and first-call-attributed; jit's own call path would re-trace
         # since lower() doesn't seed its cache)
+        w_avals = program.avals()
         avals = [jax.ShapeDtypeStruct(t.shape, t.dtype.np_dtype)
                  for _ in range(bucket) for t in in_spec.tensors]
         owner = self.trace_owner
         with _profile.span(owner, "trace_lower", setup=True):
-            lowered = jitted.lower(*avals)
+            lowered = jitted.lower(w_avals, *avals)
         self._capture_cost(model, lowered, bucket, avals)
         skey = COMPILE_STATS.record(
             "bucket", time.perf_counter() - t_compile0, bucket=bucket)
@@ -1392,11 +1573,11 @@ class JaxXlaFilter(FilterSubplugin):
                        bucket=bucket, devices=self._exec_devices(),
                        owner=owner)
         return _timed_first_call(fn, skey, owner,
-                                 lambda: jitted.lower(*avals))
+                                 lambda: jitted.lower(w_avals, *avals))
 
-    def _compile_batched_stacked(self, model: ModelDef,
-                                 in_spec: TensorsSpec, bucket: int):
-        """The mesh-placement window executable: takes ONE
+    def _compile_batched_stacked(self, model: ModelDef, c: _Compiled,
+                                 bucket: int):
+        """The mesh-placement window executable: takes the weights, then ONE
         ``(global_bucket, ...)`` stacked array per input tensor with
         the micro-batch axis sharded over the placement's data axes
         via ``in_shardings`` — each shard's bytes travel straight to
@@ -1410,28 +1591,30 @@ class JaxXlaFilter(FilterSubplugin):
         jax = _jax()
         rp = self._placement
         t_compile0 = time.perf_counter()
-        normalized, _, _ = self._normalized_fn(model, in_spec)
+        in_spec, program = c.in_spec, c.program
         nt = in_spec.num_tensors
         gbucket = bucket * rp.num_processes
         sharding = rp.batch_sharding()
+        over_frames = program.over_frames(nt)
 
-        def batched(*stacked):
-            outs = jax.vmap(normalized)(*stacked)
-            return tuple(outs)
+        def batched(weights, *stacked):
+            return tuple(over_frames(weights, *stacked))
 
         # out_shardings pinned to the batch sharding: the demux relies
         # on each process's rows being addressable locally
-        kw = {"in_shardings": (sharding,) * nt,
+        kw = {"in_shardings": ([w.sharding for w in program.weights],
+                               *(sharding,) * nt),
               "out_shardings": sharding}
         if self._donate:
-            kw["donate_argnums"] = tuple(range(nt))
+            kw["donate_argnums"] = tuple(range(1, 1 + nt))
         jitted = jax.jit(batched, **kw)
+        w_avals = program.avals()
         avals = [jax.ShapeDtypeStruct((gbucket,) + tuple(t.shape),
                                       t.dtype.np_dtype)
                  for t in in_spec.tensors]
         owner = self.trace_owner
         with _profile.span(owner, "trace_lower", setup=True):
-            lowered = jitted.lower(*avals)
+            lowered = jitted.lower(w_avals, *avals)
         self._capture_cost(model, lowered, gbucket, avals)
         skey = COMPILE_STATS.record(
             "bucket", time.perf_counter() - t_compile0, bucket=gbucket)
@@ -1444,11 +1627,12 @@ class JaxXlaFilter(FilterSubplugin):
                        bucket=gbucket, devices=self._exec_devices(),
                        owner=owner)
         return _timed_first_call(fn, skey, owner,
-                                 lambda: jitted.lower(*avals))
+                                 lambda: jitted.lower(w_avals, *avals))
 
     def _invoke_batched_stacked(self, frames: Sequence[Sequence[Any]],
                                 bucket: int, c: _Compiled,
-                                model: ModelDef) -> List[List[Any]]:
+                                model: ModelDef, execs: Dict
+                                ) -> List[List[Any]]:
         """Mesh-placement window dispatch: stack the window ONCE on the
         host (pad slots replay the last frame; ``np.stack`` copies, so
         donation can never consume a caller's buffer twice), place each
@@ -1461,13 +1645,12 @@ class JaxXlaFilter(FilterSubplugin):
         n = len(frames)
         key = (c.in_spec, bucket, "stacked")
         with self._batch_lock:
-            jitted = self._batch_exec.get(key)
+            jitted = execs.get(key)
             if jitted is not None:
                 self.batch_cache_hits += 1
                 self._cache_by_bucket.setdefault(bucket, [0, 0])[0] += 1
         if jitted is None:
-            jitted = self._compile_batched_stacked(model, c.in_spec,
-                                                   bucket)
+            jitted = self._compile_batched_stacked(model, c, bucket)
             with self._batch_lock:
                 self.batch_cache_misses += 1
                 self._cache_by_bucket.setdefault(bucket, [0, 0])[1] += 1
@@ -1493,7 +1676,7 @@ class JaxXlaFilter(FilterSubplugin):
                 if pad_rows:
                     _xfer.record("h2d", "pad", per_frame * pad_rows)
         with _profile.span(self.trace_owner, "dispatch"):
-            out = jitted(*arrs)
+            out = jitted(c.program.weights, *arrs)
         DISPATCH_STATS.count("filter")
         self._record_mesh(slots=bucket, frames=n, sharded=True,
                           local=True)
@@ -1511,10 +1694,13 @@ class JaxXlaFilter(FilterSubplugin):
         on — a buffer must not be donated twice) and their outputs are
         discarded."""
         with self._swap_lock:
-            # consistent (model, compiled) snapshot: a concurrent reload
-            # swaps both together under this lock
+            # consistent (model, compiled, bucket executables) snapshot:
+            # a concurrent reload swaps all three together under this
+            # lock, and an executable takes the weights of its own
+            # program
             c = self._compiled
             model = self._model
+            execs = self._batch_exec
         if c is None:
             raise FilterError("jax-xla: not configured")
         if self._cell is not None:
@@ -1539,15 +1725,16 @@ class JaxXlaFilter(FilterSubplugin):
             # Device-resident single-process frames keep the flat path
             # below — stacking them on the host would force a d2h
             # round-trip the program-side stack avoids.
-            return self._invoke_batched_stacked(frames, bucket, c, model)
+            return self._invoke_batched_stacked(frames, bucket, c, model,
+                                                execs)
         key = (c.in_spec, bucket)
         with self._batch_lock:
-            jitted = self._batch_exec.get(key)
+            jitted = execs.get(key)
             if jitted is not None:
                 self.batch_cache_hits += 1
                 self._cache_by_bucket.setdefault(bucket, [0, 0])[0] += 1
         if jitted is None:
-            jitted = self._compile_batched(model, c.in_spec, bucket)
+            jitted = self._compile_batched(model, c, bucket)
             with self._batch_lock:
                 self.batch_cache_misses += 1
                 self._cache_by_bucket.setdefault(bucket, [0, 0])[1] += 1
@@ -1607,7 +1794,7 @@ class JaxXlaFilter(FilterSubplugin):
                                              int(x.nbytes))
                     flat.extend(last)
         with _profile.span(self.trace_owner, "dispatch"):
-            out = jitted(*flat)
+            out = jitted(c.program.weights, *flat)
         DISPATCH_STATS.count("filter")
         if self._mesh is not None:
             # window attribution: bucket slots over the data axis (pads
@@ -1644,7 +1831,11 @@ class JaxXlaFilter(FilterSubplugin):
         pytree (dict) — the weights-only swap: the architecture (this
         instance's ``fn``) is kept and only the weights change, which is
         how ``trainers/checkpoint.py`` orbax checkpoints hot-load into
-        a serving pool."""
+        a serving pool.  Weights of the serving ones' shapes, dtypes and
+        shardings take the executables that serve (:meth:`_reuse`): the
+        shadow places them and runs the weights prologue, and nothing
+        is traced, lowered or built (a ``reuse`` in ``COMPILE_STATS``);
+        any other replacement builds its own programs (a ``reload``)."""
         if self.props is None:
             raise FilterError("jax-xla: not configured (nothing to swap)")
         self._refuse_swap_of_state()
@@ -1668,6 +1859,7 @@ class JaxXlaFilter(FilterSubplugin):
         shadow._pre_chains = self._pre_chains
         shadow._post_fns = self._post_fns
         shadow._compile_kind = "reload"
+        shadow._donor = cur
         props = _dc.replace(
             self.props, model=model,
             input_spec=cur.in_spec if cur is not None
@@ -1684,6 +1876,11 @@ class JaxXlaFilter(FilterSubplugin):
                 f"({cur.out_spec} -> {shadow._compiled.out_spec}) — a "
                 f"hot swap must preserve negotiated caps; restart the "
                 f"pipeline to change schemas")
+        if cur is not None and shadow._compiled.exe is cur.exe:
+            # the same programs over new weights: the bucket
+            # executables that serve take them too
+            with self._batch_lock:
+                shadow._batch_exec = dict(self._batch_exec)
         want = tuple(sorted(set(int(b) for b in buckets)
                             or self.hot_buckets()))
         if warm:
@@ -1715,10 +1912,9 @@ class JaxXlaFilter(FilterSubplugin):
         (model, compiled) under ``_swap_lock``, so no dispatch ever
         sees a torn pair; the lifecycle layer additionally flips at a
         window boundary so not even a window straddles the swap."""
-        with self._swap_lock:
+        with self._swap_lock, self._batch_lock:
             self._model = shadow._model
             self._compiled = shadow._compiled
-        with self._batch_lock:
             self._batch_exec = dict(shadow._batch_exec)
 
     # -- events --------------------------------------------------------------

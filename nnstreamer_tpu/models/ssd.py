@@ -389,7 +389,8 @@ def ssd_detect_apply(params: Params, x, anchors, max_out: int = 100,
     k = min(_PRE_TOPK, boxes.shape[1])
     lt = float(np.log(score_thresh / (1.0 - score_thresh)))
 
-    # a Python int: the filter device-places the pytree, leaves and all
+    # a Python int in the params: configuration, which the filter leaves
+    # as it is (only array leaves are weights, placed and traced)
     num_classes = int(params["num_classes"])
 
     def per_image(boxes, clss):

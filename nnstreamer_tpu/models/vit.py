@@ -56,9 +56,10 @@ def vit_init(key, image_size: int = 224, patch: int = 16, dim: int = 256,
         key = jax.random.PRNGKey(key)
     n_patches = (image_size // patch) ** 2
     keys = jax.random.split(key, depth * 4 + 3)
-    # NOTE: no python scalars in the pytree — the filter layer
-    # device-places every leaf, and traced scalars can't drive static
-    # shapes (patch derives from embed.w's shape; heads is a call arg)
+    # NOTE: no python scalars in the pytree are needed: patch derives
+    # from embed.w's shape and heads is a call arg (a Python number
+    # would stay configuration: the filter places and traces only the
+    # array leaves)
     params: Params = {
         "embed": {"w": jax.random.normal(
             keys[0], (patch, patch, 3, dim)) * np.sqrt(2.0 / (patch ** 2 * 3)),

@@ -11,8 +11,9 @@ into the process-wide :data:`LEDGER`:
 - ``Tensor.jax()`` uploads and ``Tensor.np()`` drains (core/buffer.py)
   — the residency conversions the pipeline hot path actually performs;
 - explicit ``device_put`` placement of inputs (filters/jax_xla.py
-  ``invoke``/``invoke_batched``) and of weights (``ModelDef.flat_fn`` /
-  ``mesh_fn``);
+  ``invoke``/``invoke_batched``) and of weights (``ModelDef.placed``,
+  for a device and for a mesh alike: once per device or per (mesh,
+  rules), whoever opens first);
 - micro-batch window feeds: host arrays handed to the batched
   executable (transferred by XLA's own arg handling — counted at the
   feed site with zero duration) and the pad-slot replays.

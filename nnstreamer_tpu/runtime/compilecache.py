@@ -142,12 +142,14 @@ def file_digest(path: str) -> str:
 def model_digest(model_def: Any) -> str:
     """Digest of a ModelDef-ish object.  File-backed models (``name``
     is an existing file) digest by CONTENT; in-process models digest by
-    name + function source (best effort) + the params tree's
-    shape/dtype schema.  In-process models are process-local by
-    construction (a fresh process re-registers them), so the schema
-    digest guards against shape skew — content skew under an unchanged
-    name and source is the caller's contract, as documented in
-    Documentation/lifecycle.md."""
+    name + function source (best effort) + the params tree's schema:
+    every array leaf's shape and dtype, and the VALUE of every leaf that
+    is no array (configuration the function closes over).  The weights'
+    values are no part of it: they are arguments of the executable
+    (``filters/jax_xla.py``), so one entry serves every set of weights
+    of the schema — on one device; a meshed filter's executable still
+    holds its weights, and content skew under an unchanged name and
+    source is the caller's contract there (Documentation/lifecycle.md)."""
     name = str(getattr(model_def, "name", "") or "")
     if name and os.path.isfile(name):
         try:
@@ -170,8 +172,10 @@ def model_digest(model_def: Any) -> str:
             import jax
 
             for leaf in jax.tree_util.tree_leaves(params):
-                h.update(str(getattr(leaf, "shape", ())).encode())
-                h.update(str(getattr(leaf, "dtype", "")).encode())
+                if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
+                    h.update(f"{leaf.shape}{leaf.dtype}".encode())
+                else:
+                    h.update(repr(leaf).encode())
         except Exception:  # noqa: BLE001 - schema digest is best effort
             pass
     return "obj:" + h.hexdigest()

@@ -236,7 +236,11 @@ class CompileStats:
     kind, bucket), where ``kind`` names the compile path — ``cold``
     (first configure), ``reshape`` (SET_INPUT_INFO recompile),
     ``reload`` (hot model swap), ``bucket`` (a micro-batch bucket
-    executable).  ``seconds`` accumulates the trace/lower time spent at
+    executable), ``persist_hit`` (an executable loaded from the
+    persistent AOT cache) and ``reuse`` (a weights-only swap of the
+    serving shapes: the executables that serve take the new weights, no
+    program is traced or built; its seconds are the placement and the
+    weights prologue's run).  ``seconds`` accumulates the trace/lower time spent at
     the compile site PLUS the executable's first invocation (jit
     compiles lazily — the first call is where XLA actually builds the
     program; on a non-trivial model that dwarfs the first execution).

@@ -19,3 +19,20 @@ try:
 except ImportError:
     sys.path.insert(0, os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _spans_end_with_their_module():
+    """The phase spans the program keeps are process-wide, and an xdist
+    worker runs one test file after another in one process: a file that
+    reads them (``tests/benchmark``'s toy rehearsals bound their set-up
+    by the process's start, and expect the pipeline's root spans to
+    cover what is named) must not find what the files before it opened
+    outside any pipeline.  Which files share a worker is the
+    scheduler's choice, so every file leaves none behind."""
+    yield
+    from nnstreamer_tpu.utils import profile
+
+    profile.clear()

@@ -578,7 +578,11 @@ def test_set_up_spans_dropped_reads_zero_then_what_overflowed(
     kept = sum(len(rows) for rows in toy_set_up.values())
     assert kept > 10 and profile.spans_dropped()["setup"] == 0
     assert profile.SETUP_MAX >= 1 << 14
-    rec = profile._Recorder({"setup": 8, "window": 8, "slow": 8})
+    # room for every per-window span: on a loaded machine a toy window
+    # can last the 50 ms that make its spans slow ones, and this test is
+    # about the set-up list
+    rec = profile._Recorder({"setup": 8, "window": 1 << 10,
+                             "slow": 1 << 10})
     kinds, plain = [], rec.keep
     rec.keep = lambda *a, **k: (kinds.append(a[4]), plain(*a, **k))[1]
     monkeypatch.setattr(profile, "_REC", rec)

@@ -233,10 +233,11 @@ def test_no_weight_literal_and_two_sets_of_weights_compile_once(model):
     two.close()
 
 
-def test_the_stateless_path_closes_weights_over_as_before():
-    """A stateless model's program is the parent's: the weights are
-    closed over the jitted function (constants of the HLO), and its
-    lowered text is that of the plain closure."""
+def test_a_stateless_model_takes_its_weights_as_arguments_too():
+    """A stateless model has joined the contract (PR 51): its program
+    takes the weights as arguments and holds no constant of their size;
+    what it computes from them alone (here their sum) is the weights
+    prologue's, computed once at open."""
     w = jnp.arange(WIDTH, dtype=jnp.float32)
 
     def fn(params, x):
@@ -248,19 +249,11 @@ def test_the_stateless_path_closes_weights_over_as_before():
         sp = _open("stateless_toy")
         assert sp._cell is None
         sp.fetch_counters()                      # nothing, and no error
-        placed = sp._model._dev_params[sp._device]
-
-        def parent(x):
-            with jax.named_scope("nns.model"):
-                out = fn(placed, x)
-            return (out,)
-
-        want = jax.jit(parent).lower(
-            jax.ShapeDtypeStruct((4,), np.float32)).as_text()
-        got = sp._compiled.jitted.lower().as_text()
-        strip = lambda t: re.sub(r"(jit_\w+|@\w+|loc\(.*\)|#loc.*)", "", t)  # noqa: E731
-        assert strip(got) == strip(want)
-        assert "parameter(1)" not in sp.executable_text()   # one argument
+        made = sp._compiled.program.weights
+        assert [(a.shape, float(a)) for a in made] == [((), float(w.sum()))]
+        text = sp.executable_text()
+        assert "parameter(1)" in text            # the sum and the input
+        assert "reduce(" not in text and f"f32[{WIDTH}]" not in text
         assert np.allclose(sp.invoke([ONES])[0], float(w.sum()))
         sp.close()
     finally:

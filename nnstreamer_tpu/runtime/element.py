@@ -614,8 +614,10 @@ class SinkElement(Element):
     ``wait_eos()`` returning means every dispatched program finished.
 
     A fence that held the thread for ``SLOW_NS`` or more says in its
-    span's note who was late (:meth:`_who_was_late`); the first fence
-    after a start closes ``<pipeline>/first_window``.
+    span's note who was late (:meth:`_who_was_late`), and like every
+    slow span leaves a ``Pause`` with what the process did meanwhile
+    (``utils/profile.py`` ``pauses()``); the first fence after a start
+    closes ``<pipeline>/first_window``.
     """
 
     def __init__(self, name=None, **props):
@@ -670,7 +672,14 @@ class SinkElement(Element):
         stayed away for at least a window's time (descheduled, a
         collection, a lock) and the chip has run dry; if it is still
         running, the device took that long.  One non-blocking question
-        to the array, asked on a slow fence only."""
+        to the array, asked on a slow fence only.  Which of the
+        candidates it was is not this note's to say: the span's
+        ``Pause`` (``utils/profile.py``) holds the collections that
+        overlap it and the CPU time this thread and the whole process
+        gained meanwhile, so a ``device late`` fence in which the
+        process gained next to none says the runtime's own threads
+        stood still with this one.  The note is compared for equality
+        by its readers and stays these two texts."""
         nxt = self._pending_fence
         if nxt is None:
             return "no next window"

@@ -134,6 +134,9 @@ class Pipeline:
     def start(self) -> "Pipeline":
         if self.playing:
             return self
+        # a pause is charged the collections that overlap it: watched
+        # from the first start of a process to the last stop
+        _profile.watch_collections(self)
         # set-up is one tree: everything start() does lies under this
         # span, and <pipeline>/first_window takes over where it ends
         with _profile.span(self.name, "start", setup=True):
@@ -262,7 +265,9 @@ class Pipeline:
                 p.spec = None
             e._eos_seen.clear()
         self.playing = False
-        # what held a window up for 50 ms or more while nobody traced
+        _profile.unwatch_collections(self)
+        # what held a window up for 50 ms or more, and what the process
+        # did meanwhile
         _profile.report_slow(logi)
         return self
 

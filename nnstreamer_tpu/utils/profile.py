@@ -26,6 +26,13 @@ A span goes to two sinks:
   keep their newest spans; :func:`spans_dropped` says how many each
   pushed out.
 
+A per-window span of ``SLOW_NS`` or more also leaves a :class:`Pause`,
+capture or not, in a bounded list of its own (:func:`pauses`): the CPU
+time the thread and the whole process gained between a baseline read
+before the span began and its end, the collections that overlap it, and
+the one-word ``cause`` those name (:func:`cause_of`).  Nothing of it
+reaches :func:`spans`: no span's name, kind or note says a cause.
+
 Set-up is one tree a thread: ``<model>/register``, ``<pipeline>/parse``,
 ``<pipeline>/start`` (``fuse``, ``negotiate``, ``<element>/activate``
 inside it) and ``<pipeline>/first_window`` (from ``start()`` returning
@@ -46,12 +53,16 @@ Usage::
     # tensorboard --logdir /tmp/nns-trace, or spans() / stage_seconds()
 
 What it costs: with no capture a per-window span is one small object,
-two clock reads and a compare around the work: 0.87 us a span on the
+two clock reads and two compares around the work (is it slow; is a
+baseline due): 0.87 us a span on the
 host of the one-chip machine (200,000 spans timed there, PR 24;
 ``tests/test_profile_spans.py`` prints the figure of the host it runs
 on), some ten spans a window, so 9 us of an 18 ms window; PERF.md
 section 6 has the rates measured on the chip with and without.  Nothing
-is kept, no lock is taken and jax is not touched.  During a capture each
+is kept, no lock is taken and jax is not touched.  At most once in
+``BASELINE_NS`` (100 ms) a thread, a span's exit reads the two CPU
+clocks for the baseline of the next pause (two system calls, no file,
+no lock).  During a capture each
 span also enters a ``TraceAnnotation`` and appends one tuple under a
 lock.  ``NNS_TPU_OBS_DISABLE`` switches spans off altogether (no clock
 read).
@@ -61,9 +72,11 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import re
 import threading
 import time
+import weakref
 from typing import Dict, List, NamedTuple, Optional
 
 from ..obs import hooks as _hooks
@@ -77,6 +90,14 @@ SLOW_NS = 50_000_000
 HOST_LATE = "next window done: host late"
 DEVICE_LATE = "next window running: device late"
 SLOW_MAX = 1024
+#: a thread's baseline (the reading that opens its next pause) is read
+#: again from a span's exit once it is this old
+BASELINE_NS = 100_000_000
+#: baselines a thread keeps: a slow span's enclosing spans end after it
+#: and began before it, so each needs an older one
+BASELINES_KEPT = 8
+#: the newest collections kept as intervals
+COLLECTIONS_KEPT = 64
 CAPTURE_MAX = 1 << 18
 #: room for every set-up span of a process that builds state ahead of
 #: its stream, many times over: the benchmark's cell with most keeps
@@ -103,16 +124,91 @@ class Span(NamedTuple):
     note: Optional[str]
 
 
+class Pause(NamedTuple):
+    """What the process did around one per-window span of ``SLOW_NS`` or
+    more.  ``start_ns`` and ``end_ns`` are the span's own, on the spans'
+    clock (``time.perf_counter_ns``, which a capture's
+    ``TraceAnnotation`` of the same span shares with the device lines);
+    ``note`` is the span's (a fence's says who was late) and ``window``
+    its window id, filled in from the enclosing pause as :func:`spans`
+    does.  ``deltas``: ``thread_cpu_ns`` (``time.thread_time_ns``: this
+    thread computed) and ``process_cpu_ns`` (``time.process_time_ns``:
+    every thread of the process, the runtime's and the application's
+    too) gained between the thread's baseline and the span's end;
+    ``gc_ns``, ``gc_collections``, ``gc_generation`` and
+    ``gc_other_thread`` are the collections that overlap the span
+    itself, on any thread (no ``gc_*`` key while no started pipeline has
+    them watched).  ``baseline_age_ns`` is how long before the span
+    began the baseline was read: the two CPU deltas cover that stretch
+    of ordinary work too; negative where the only baseline is from
+    inside the span, ``None`` (and no CPU delta) where the thread had
+    none.  ``cause`` is :func:`cause_of` of them."""
+
+    name: str
+    thread: int
+    window: Optional[int]
+    start_ns: int
+    end_ns: int
+    note: Optional[str]
+    baseline_age_ns: Optional[int]
+    deltas: Dict[str, int]
+    cause: str
+
+
+#: a pause's cause where nothing read accounts for half of it: every
+#: delta is in the record, so it still says what the pause was not
+UNEXPLAINED = "unexplained"
+
+
+def charges(deltas: Dict[str, int],
+            baseline_age_ns: Optional[int] = 0) -> Dict[str, int]:
+    """Nanoseconds of the span each cause can answer for: ``gc`` (the
+    collections overlapping it, by interval) and ``on_cpu`` (what the
+    thread SURELY computed inside it: its CPU time since the baseline
+    less the baseline's age, all of which it may have computed through
+    before the span began)."""
+    out = {}
+    if "gc_ns" in deltas:
+        out["gc"] = deltas["gc_ns"]
+    if "thread_cpu_ns" in deltas:
+        out["on_cpu"] = max(
+            0, deltas["thread_cpu_ns"] - max(baseline_age_ns or 0, 0))
+    return out
+
+
+def cause_of(length_ns: int, deltas: Dict[str, int],
+             baseline_age_ns: Optional[int] = 0) -> str:
+    """The rule, written once: of :func:`charges`, the largest, if it
+    covers at least half of the span's length; else ``unexplained``: the
+    thread neither computed nor stood behind a collection, so it waited
+    or was not run, and ``process_cpu_ns`` beside it says whether the
+    rest of the process stood still too.  ``on_cpu`` is for spans that
+    are not waits: a wait computes nothing.  What the operating system
+    and the machine count of a thread that is not run (run-queue delay,
+    a control group's throttling, steal) decides nothing yet: no host
+    the benchmark reaches shows those counters (PERF.md section 7)."""
+    timed = charges(deltas, baseline_age_ns)
+    if timed:
+        cause = max(timed, key=timed.get)
+        if 2 * timed[cause] >= length_ns:
+            return cause
+    return UNEXPLAINED
+
+
 class _Recorder:
     """The three bounded lists: each keeps its newest spans, so a
     process that lives long still has its latest set-up, capture and
     slow windows.  Appended to under the lock only: a site that keeps
-    nothing never gets here."""
+    nothing never gets here.  The pauses have a list of their own, which
+    :func:`spans` never reads."""
 
     def __init__(self, limits=None):
         self.lock = threading.Lock()
-        limits = limits or {"setup": SETUP_MAX, "window": CAPTURE_MAX,
-                            "slow": SLOW_MAX}
+        limits = dict(limits or {"setup": SETUP_MAX, "window": CAPTURE_MAX,
+                                 "slow": SLOW_MAX})
+        self.pauses: collections.deque = collections.deque(
+            maxlen=limits.pop("pause", SLOW_MAX))
+        self.pauses_dropped = 0
         self.lists: Dict[str, collections.deque] = {
             which: collections.deque(maxlen=n)
             for which, n in limits.items()}
@@ -131,12 +227,19 @@ class _Recorder:
             if which == "slow":
                 self.slow_kept += 1
 
+    def keep_pause(self, row: Pause) -> None:
+        with self.lock:
+            if len(self.pauses) == self.pauses.maxlen:
+                self.pauses_dropped += 1
+            self.pauses.append(row)
+
     def clear(self) -> None:
         with self.lock:
             for rows in self.lists.values():
                 rows.clear()
+            self.pauses.clear()
             self.dropped = dict.fromkeys(self.lists, 0)
-            self.slow_kept = self.slow_reported = 0
+            self.slow_kept = self.slow_reported = self.pauses_dropped = 0
 
 
 _REC = _Recorder()
@@ -210,8 +313,16 @@ class span:
                 self.note = self.if_slow()
             _REC.keep(self.name, t0, t1, self.window,
                       "slow" if ann is None else "window", self.note)
+            _keep_pause(self.name, t0, t1, self.window, self.note)
         elif ann is not None:
             _REC.keep(self.name, t0, t1, self.window, "window", self.note)
+            if t1 >= _gate_ns:
+                _gate(t1)
+        elif t1 >= _gate_ns:
+            # the fast path's one compare more, with a module global: a
+            # span that is not kept reads no clock of the process, and
+            # looks no further, unless the gate stands open
+            _gate(t1)
         return False
 
 
@@ -237,9 +348,162 @@ def note(text: str) -> None:
             inner._said = {}
         inner._said[text] = inner._said.get(text, 0) + 1
 
+# -- the pause ledger: what the process did around a slow span ---------------
+
+
+#: a thread's own, each made when first needed: ``setup`` (its open
+#: set-up spans) and ``baselines`` (its newest readings of the CPU
+#: clocks, for the pauses)
+_tls = threading.local()
+#: the gate a span's exit compares its clock read with, and when the
+#: slot of the opening that is on ends.  The gate opens once in
+#: ``BASELINE_NS`` and stands open for ``GATE_SLOT_NS`` from the first
+#: span through it, so that every thread that streams gets through; a
+#: module global costs that compare 20 ns of a span's 800, a
+#: thread-local 90
+_gate_ns = 0
+_slot_ends_ns = 0
+GATE_SLOT_NS = 5_000_000
+
+
+def _reading(t_ns: int) -> Dict[str, int]:
+    """The calling thread's cumulative readings of one instant, stamped
+    ``t_ns`` (the caller's clock read: a span's exit holds one)."""
+    return {"t_ns": t_ns, "thread_cpu_ns": time.thread_time_ns(),
+            "process_cpu_ns": time.process_time_ns()}
+
+
+def _baselines() -> List[Dict[str, int]]:
+    try:
+        return _tls.baselines
+    except AttributeError:
+        _tls.baselines = []
+        return _tls.baselines
+
+
+def _gate(t1: int) -> None:
+    """The gate stands open.  Reads this thread's baseline if its newest
+    is ``BASELINE_NS`` old, and shuts the gate for ``BASELINE_NS`` once
+    the slot is over: it never stays open for a thread that has ended
+    or idles.  A thread that exits no span inside a slot keeps its older
+    baseline (its age is in the record); no lock: two threads at once
+    cost at most a reading too many."""
+    global _gate_ns, _slot_ends_ns
+    known = _baselines()
+    if not known or t1 - known[-1]["t_ns"] >= BASELINE_NS:
+        known.append(_reading(t1))
+        del known[:-BASELINES_KEPT]
+    if _slot_ends_ns <= _gate_ns:       # the first through this opening
+        _slot_ends_ns = t1 + GATE_SLOT_NS
+    elif t1 >= _slot_ends_ns:
+        _gate_ns = t1 + BASELINE_NS
+
+
+class _Collections:
+    """ONE ``gc.callbacks`` entry, installed while anyone watches: it
+    runs only when a collection does and keeps (start, end, generation,
+    thread) of the newest ``COLLECTIONS_KEPT``, on the spans' clock.  A
+    collection holds the interpreter, so it stops every thread that runs
+    Python, whichever thread it was triggered on: a pause is charged the
+    ones that overlap it by interval."""
+
+    def __init__(self):
+        self.recent: collections.deque = collections.deque(
+            maxlen=COLLECTIONS_KEPT)
+        self._began: Optional[tuple] = None
+        self._owners: "weakref.WeakSet" = weakref.WeakSet()
+        self._lock = threading.Lock()
+
+    def _callback(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._began = (time.perf_counter_ns(), threading.get_ident())
+        elif self._began is not None:
+            (t0, thread), self._began = self._began, None
+            self.recent.append((t0, time.perf_counter_ns(),
+                                int(info.get("generation", -1)), thread))
+
+    @property
+    def installed(self) -> bool:
+        return self._callback in gc.callbacks
+
+    def watch(self, owner) -> None:
+        """Installs the callback for as long as ``owner`` (held weakly)
+        or any other watches."""
+        with self._lock:
+            self._owners.add(owner)
+            if not self.installed:
+                gc.callbacks.append(self._callback)
+
+    def unwatch(self, owner) -> None:
+        with self._lock:
+            self._owners.discard(owner)
+            if not self._owners and self.installed:
+                gc.callbacks.remove(self._callback)
+                self._began = None
+
+    def overlapping(self, t0: int, t1: int) -> list:
+        """[(start, end, generation, thread)] of the kept collections
+        that overlap ``[t0, t1]``, each clipped to it; one still running
+        counts up to ``t1``."""
+        rows = list(self.recent)
+        began = self._began
+        if began is not None:
+            rows.append((began[0], t1, -1, began[1]))
+        return [(max(a, t0), min(b, t1), gen, thread)
+                for a, b, gen, thread in rows if a < t1 and b > t0]
+
+
+_GC = _Collections()
+
+
+def _keep_pause(name: str, t0: int, t1: int, window: Optional[int],
+                note: Optional[str]) -> None:
+    """The reading that closes a slow span, against the newest baseline
+    from before it began; that reading is the next pause's baseline."""
+    me = threading.get_ident()
+    closed = _reading(t1)
+    known = _baselines()
+    opened = next((b for b in reversed(known) if b["t_ns"] <= t0),
+                  known[0] if known else None)
+    # whatever was read inside this span serves no later one: the spans
+    # around it began before it, the spans after it begin after ``closed``
+    while known and known[-1]["t_ns"] > t0:
+        known.pop()
+    known.append(closed)
+    del known[:-BASELINES_KEPT]
+    deltas, age = {}, None
+    if opened is not None:
+        age = t0 - opened["t_ns"]
+        deltas = {key: closed[key] - was for key, was in opened.items()
+                  if key != "t_ns"}
+    if _GC.installed:
+        during = _GC.overlapping(t0, t1)
+        deltas["gc_ns"] = sum(b - a for a, b, _g, _t in during)
+        deltas["gc_collections"] = len(during)
+        if during:
+            deltas["gc_generation"] = max(g for _a, _b, g, _t in during)
+            deltas["gc_other_thread"] = int(any(
+                t != me for _a, _b, _g, t in during))
+    _REC.keep_pause(Pause(name, me, window, t0, t1, note, age, deltas,
+                          cause_of(t1 - t0, deltas, age)))
+
+
+def watch_collections(owner) -> None:
+    """``Pipeline.start`` calls this: while any started pipeline lives,
+    ONE ``gc.callbacks`` entry keeps the newest collections' intervals,
+    so a pause is charged the ones that overlap it.  Never installed
+    under ``NNS_TPU_OBS_DISABLE``."""
+    if not _hooks.DISABLED:
+        _GC.watch(owner)
+
+
+def unwatch_collections(owner) -> None:
+    """``Pipeline.stop`` calls this; the last one removes the entry."""
+    _GC.unwatch(owner)
+
+
 # -- what jax itself did inside a set-up span ---------------------------------
 
-_tls = threading.local()
 _watching = threading.Event()
 #: jax.monitoring's duration events of one program build, by the name of
 #: the set-up span each is kept as
@@ -346,8 +610,21 @@ def spans() -> List[Span]:
     that encloses it."""
     with _REC.lock:
         rows = [s for part in _REC.lists.values() for s in part]
+    return _by_start_with_windows(rows)
+
+
+def pauses() -> List[Pause]:
+    """Every kept :class:`Pause`, by start time: one a per-window span
+    of ``SLOW_NS`` or more, nested ones too (a slow fence, and the chain
+    spans around it), whether a capture was on or not."""
+    with _REC.lock:
+        rows = list(_REC.pauses)
+    return _by_start_with_windows(rows)
+
+
+def _by_start_with_windows(rows: list) -> list:
     rows.sort(key=lambda s: (s.start_ns, -s.end_ns))
-    open_by_thread: Dict[int, List[Span]] = {}
+    open_by_thread: Dict[int, list] = {}
     out = []
     for s in rows:
         stack = open_by_thread.setdefault(s.thread, [])
@@ -368,6 +645,12 @@ def spans_dropped() -> Dict[str, int]:
         return dict(_REC.dropped)
 
 
+def pauses_dropped() -> int:
+    """Pauses pushed out of their full list by newer ones."""
+    with _REC.lock:
+        return _REC.pauses_dropped
+
+
 def clear() -> None:
     """Forget every kept span (tests, and a process that runs one
     measurement after another)."""
@@ -375,20 +658,36 @@ def clear() -> None:
 
 
 def report_slow(log) -> int:
-    """Log, by name, the per-window spans of ``SLOW_NS`` or more kept
-    since the last report (``Pipeline.stop`` calls this); returns how
-    many there were."""
+    """Log the per-window spans of ``SLOW_NS`` or more kept since the
+    last report, once a name and cause, with the time each delta of
+    their pauses gained in all (``Pipeline.stop`` calls this); returns
+    how many there were."""
     with _REC.lock:
         fresh = _REC.slow_kept - _REC.slow_reported
         rows = list(_REC.lists["slow"])[-fresh:] if fresh else []
         _REC.slow_reported = _REC.slow_kept
-    by_name: Dict[str, List[int]] = {}
+        found = {(p.thread, p.start_ns, p.end_ns): p for p in _REC.pauses}
+    groups: Dict[tuple, list] = {}
     for s in rows:
-        by_name.setdefault(s.name, []).append(s.end_ns - s.start_ns)
-    for name, durs in sorted(by_name.items()):
-        log("slow span %s: %d over %d ms, %.1f ms in all, longest %.1f ms",
+        pause = found.get((s.thread, s.start_ns, s.end_ns))
+        groups.setdefault((s.name, pause.cause if pause else None),
+                          []).append((s.end_ns - s.start_ns, pause))
+    for (name, cause), kept in sorted(groups.items(),
+                                      key=lambda kv: (kv[0][0], str(kv[0][1]))):
+        durs = [d for d, _p in kept]
+        said = ""
+        if cause is not None:
+            gained: Dict[str, int] = {}
+            for _d, pause in kept:
+                for key, ns in pause.deltas.items():
+                    if key.endswith("_ns"):
+                        gained[key[:-3]] = gained.get(key[:-3], 0) + ns
+            said = "; cause %s (%s)" % (cause, ", ".join(
+                f"{what} {ns * 1e-6:.1f} ms"
+                for what, ns in sorted(gained.items())) or "no baseline")
+        log("slow span %s: %d over %d ms, %.1f ms in all, longest %.1f ms%s",
             name, len(durs), SLOW_NS // 1_000_000, sum(durs) * 1e-6,
-            max(durs) * 1e-6)
+            max(durs) * 1e-6, said)
     return len(rows)
 
 

@@ -660,3 +660,461 @@ def test_if_slow_is_called_on_a_slow_span_only(monkeypatch):
             s.if_slow = lambda name=name: calls.append(name) or "late"
     assert calls == ["b"]
     assert [(s.name, s.note) for s in profile.spans()] == [("b/x", "late")]
+
+
+# -- the pause ledger: what the process did around a slow span (PR 53) -------
+
+MS = 1_000_000
+
+
+class _Host:
+    """Stands in for ``profile._reading``: counts the readings asked of
+    it and hands out made-up cumulative CPU clocks, each reading
+    ``gains`` ahead of the one before."""
+
+    def __init__(self, **gains_a_reading):
+        self.gains = gains_a_reading
+        self.reads = []
+        self.now = {}
+
+    def read(self, t_ns):
+        self.reads.append(t_ns)
+        for key, gain in self.gains.items():
+            self.now[key] = self.now.get(key, 0) + gain
+        return dict(self.now, t_ns=t_ns)
+
+
+@pytest.fixture
+def host(monkeypatch):
+    """Made-up CPU clocks, no baseline on this thread yet, the gate
+    open."""
+    fake = _Host(thread_cpu_ns=0, process_cpu_ns=0)
+    monkeypatch.setattr(profile, "_reading", fake.read)
+    monkeypatch.setattr(profile, "_gate_ns", 0)
+    monkeypatch.setattr(profile, "_slot_ends_ns", 0)
+    if hasattr(profile._tls, "baselines"):
+        monkeypatch.delattr(profile._tls, "baselines")
+    yield fake
+    if hasattr(profile._tls, "baselines"):
+        del profile._tls.baselines
+
+
+def _benchmark_spans():
+    """The benchmark's span arithmetic (``benchmark/spans.py``), from
+    the checkout's root."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import spans as bench_spans
+
+    return bench_spans
+
+
+def _clock(monkeypatch, ticks):
+    ticks = iter(ticks)
+    monkeypatch.setattr(profile.time, "perf_counter_ns", lambda: next(ticks))
+
+
+@pytest.mark.parametrize("deltas,age,cause", [
+    ({"gc_ns": 70 * MS, "gc_generation": 2, "gc_other_thread": 1,
+      "thread_cpu_ns": 2 * MS}, 0, "gc"),
+    ({"thread_cpu_ns": 99 * MS, "process_cpu_ns": 99 * MS}, 0, "on_cpu"),
+    # the larger of two that each cover half
+    ({"thread_cpu_ns": 80 * MS, "gc_ns": 60 * MS}, 0, "on_cpu"),
+    # busy BEFORE the span, for all the record can tell: the baseline is
+    # 60 ms old, so only 39 ms were surely computed inside the span
+    ({"thread_cpu_ns": 99 * MS, "gc_ns": 0}, 60 * MS, profile.UNEXPLAINED),
+    ({"thread_cpu_ns": 160 * MS, "gc_ns": 0}, 100 * MS, "on_cpu"),
+    # a stale baseline (a thread that met no slot of the gate) proves
+    # nothing of the span
+    ({"thread_cpu_ns": 900 * MS, "gc_ns": 0}, 5000 * MS,
+     profile.UNEXPLAINED),
+    # the only baseline is from inside the span: all of it counts
+    ({"thread_cpu_ns": 70 * MS}, -20 * MS, "on_cpu"),
+    # a cause present and still short of half: what it was NOT stays
+    ({"gc_ns": 49 * MS, "gc_collections": 1, "thread_cpu_ns": MS,
+      "process_cpu_ns": 3 * MS}, 0, profile.UNEXPLAINED),
+    # nobody watched the collections: no key, no guess
+    ({"thread_cpu_ns": MS, "process_cpu_ns": 40 * MS}, 0,
+     profile.UNEXPLAINED),
+    # the thread had no baseline
+    ({}, None, profile.UNEXPLAINED),
+])
+def test_the_cause_rule_on_made_up_deltas(deltas, age, cause):
+    assert profile.cause_of(100 * MS, deltas, age) == cause
+    timed = profile.charges(deltas, age)
+    assert set(timed) <= {"gc", "on_cpu"}
+    assert ("gc" in timed) == ("gc_ns" in deltas)
+    assert all(0 <= ns for ns in timed.values())
+
+
+@pytest.mark.parametrize("next_ready,note", [
+    (True, profile.HOST_LATE), (False, profile.DEVICE_LATE)])
+def test_a_slow_fence_keeps_a_pause_and_its_note_says_what_it_said(
+        host, next_ready, note):
+    from nnstreamer_tpu.elements.basic import AppSink
+
+    bench_spans = _benchmark_spans()
+
+    host.gains = {"thread_cpu_ns": 2 * MS, "process_cpu_ns": 5 * MS}
+    with span("warm"):                  # the thread's baseline
+        pass
+    sink = AppSink(name="el_sink")
+    sink._pending_fence = _Array(next_ready)
+    with span("el_sink", None, 41):
+        sink._fence(_Array(True, wait_s=0.06))
+    fence, chain = profile.pauses()[1], profile.pauses()[0]
+    assert (chain.name, fence.name) == ("el_sink", "el_sink/fence")
+    assert fence.note == note and fence.cause == profile.UNEXPLAINED
+    assert fence.window == 41            # from the pause around it
+    assert fence.deltas == {"thread_cpu_ns": 2 * MS,
+                            "process_cpu_ns": 5 * MS}
+    assert 0 <= fence.baseline_age_ns < 50 * MS
+    # the spans say what they said before the ledger: no cause in a
+    # note, no new kind, and the readers' filter still finds the fence
+    rows = profile.spans()
+    assert [(s.name, s.kind, s.note) for s in rows] == [
+        ("el_sink", "slow", None), ("el_sink/fence", "slow", note)]
+    (inner,) = bench_spans.innermost_slow(rows, 0)
+    assert inner.name == "el_sink/fence" and inner.note == note
+    assert (inner.start_ns, inner.end_ns) == (fence.start_ns, fence.end_ns)
+    # the span around the fence began before the fence's baseline was
+    # stale: it is measured from the same reading, not from the fence's
+    assert chain.deltas["process_cpu_ns"] == 2 * 5 * MS
+    assert chain.baseline_age_ns >= 0
+
+
+def test_a_fast_span_reads_no_clock_but_its_own_two(host, monkeypatch):
+    _clock(monkeypatch, range(0, 10 ** 9, 1000))      # 1 us a clock read
+    for what in ("thread_time_ns", "process_time_ns"):
+        monkeypatch.setattr(profile.time, what,
+                            lambda what=what: pytest.fail(what))
+    monkeypatch.setattr(os, "open", lambda *a, **k: pytest.fail("a file"))
+    for _ in range(1000):                # 2 ms of spans
+        with span("el_net", "dispatch", 1):
+            pass
+    # the thread's first exit read its baseline; no other span did
+    assert len(host.reads) == 1
+    assert profile.pauses() == [] and profile.spans() == []
+
+
+def test_the_baseline_is_read_at_most_once_in_100_ms_a_thread(
+        host, monkeypatch):
+    assert profile.BASELINE_NS == 100 * MS
+    _clock(monkeypatch, range(0, 10 ** 12, 5 * MS))   # a span of 5 ms each
+    for _ in range(100):                 # 1 s of spans, none slow
+        with span("el_net", "dispatch"):
+            pass
+    assert 9 <= len(host.reads) <= 11
+    assert all(b - a >= profile.BASELINE_NS
+               for a, b in zip(host.reads, host.reads[1:]))
+    assert len(profile._tls.baselines) <= profile.BASELINES_KEPT
+    # another thread has baselines of its own, at the same pace: its
+    # first span waits for the gate, as every span does
+    import threading
+
+    mine = list(profile._tls.baselines)
+
+    def other():
+        for _ in range(60):              # 0.6 s of spans
+            with span("el_q", "push"):
+                pass
+        theirs.extend(profile._tls.baselines)
+
+    before, theirs = len(host.reads), []
+    t = threading.Thread(target=other)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive() and 5 <= len(host.reads) - before <= 7
+    assert len(theirs) == len(host.reads) - before
+    assert profile._tls.baselines == mine
+
+
+def test_the_gate_shuts_after_its_slot_whoever_has_ended_or_idles(
+        host, monkeypatch):
+    """The fast path's one compare stays one compare: no thread that
+    ended, and none that makes a span a second, keeps the gate open for
+    the others.  Of 2,000 span exits in 4 s only those inside a slot
+    (5 ms in 100) look past the compare."""
+    import threading
+
+    _clock(monkeypatch, range(0, 10 ** 13, MS))       # 1 ms a clock read
+    through = []
+    gate = profile._gate
+    monkeypatch.setattr(profile, "_gate",
+                        lambda t1: through.append(t1) or gate(t1))
+
+    def other():
+        with span("el_q", "push"):
+            pass
+
+    t = threading.Thread(target=other)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive()
+    for _ in range(2000):
+        with span("el_net", "dispatch"):
+            pass
+    assert len(through) <= 200 and len(host.reads) <= 2 + 40
+    # shut, and ahead of the clock, once the last slot was over
+    assert profile._gate_ns > through[-1] >= profile._slot_ends_ns
+
+
+def test_many_threads_share_the_gate_and_each_keeps_its_own_pace(host):
+    """More threads than cores, each making spans for a quarter of a
+    second under a short switch interval: no thread reads more than one
+    baseline in 100 ms, each reading lands in its own thread's list and
+    nowhere else, nothing raises.  (A thread that meets no slot of the
+    gate in so short a life has none yet: its first pause then has no
+    CPU delta, and closes with the baseline of its second.)"""
+    import sys
+    import threading
+
+    counts, errors = {}, []
+    go = threading.Event()
+
+    def stream():
+        try:
+            go.wait(timeout=30)
+            until = time.perf_counter() + 0.25
+            while time.perf_counter() < until:
+                with span("el_net", "dispatch"):
+                    pass
+            counts[threading.get_ident()] = [
+                b["t_ns"] for b in getattr(profile._tls, "baselines", [])]
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=stream) for _ in range(24)]
+        for t in threads:
+            t.start()
+        go.set()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(was)
+    assert not errors and not any(t.is_alive() for t in threads)
+    assert len(counts) == 24
+    assert sum(1 for times in counts.values() if times) >= 4
+    for times in counts.values():
+        assert len(times) <= 4
+        assert all(b - a >= profile.BASELINE_NS
+                   for a, b in zip(times, times[1:]))
+    assert len(host.reads) == sum(len(v) for v in counts.values())
+
+
+def test_a_slow_span_is_measured_from_the_baseline_before_it(
+        host, monkeypatch):
+    host.gains = {"thread_cpu_ns": 10 * MS}
+    #       warm    | an outer span holding a refresh, then a slow one
+    _clock(monkeypatch, [0, 1,
+                         20 * MS,                       # outer in
+                         130 * MS, 131 * MS,            # fast: refresh due
+                         140 * MS, 200 * MS,            # slow inner
+                         201 * MS])                     # outer out
+    with span("warm"):
+        pass
+    with span("el_norm", None, 7):
+        with span("el_net", "prep"):
+            pass
+        with span("el_net", "dispatch"):
+            pass
+    outer, inner = profile.pauses()
+    assert host.reads == [1, 131 * MS, 200 * MS, 201 * MS]
+    # the inner from the refresh 9 ms before it, the outer from the
+    # reading before ITS start: the refresh and the inner's lie inside it
+    assert (inner.name, inner.baseline_age_ns) == ("el_net/dispatch", 9 * MS)
+    assert inner.deltas == {"thread_cpu_ns": 10 * MS}
+    assert (outer.name, outer.baseline_age_ns) == ("el_norm", 20 * MS - 1)
+    assert outer.deltas == {"thread_cpu_ns": 30 * MS}
+    assert inner.window == outer.window == 7
+    # what was read inside the outer serves no later span
+    assert [b["t_ns"] for b in profile._tls.baselines] == [1, 201 * MS]
+
+
+def test_a_thread_without_a_baseline_says_so_and_its_pause_leaves_one(
+        host, monkeypatch):
+    # the gate is shut: this thread's fast spans read nothing
+    monkeypatch.setattr(profile, "_gate_ns", 10 ** 15)
+    host.gains = {"thread_cpu_ns": 3 * MS, "process_cpu_ns": 4 * MS}
+    _clock(monkeypatch, [0, 1, 10 * MS, 80 * MS, 90 * MS, 150 * MS])
+    with span("el_net", "prep"):
+        pass
+    assert host.reads == []
+    for _ in range(2):
+        with span("el_sink", "fence"):
+            pass
+    first, second = profile.pauses()
+    assert (first.baseline_age_ns, first.deltas) == (None, {})
+    assert first.cause == profile.UNEXPLAINED
+    # the reading that closed the first opens the second
+    assert second.baseline_age_ns == 10 * MS
+    assert second.deltas == {"thread_cpu_ns": 3 * MS,
+                             "process_cpu_ns": 4 * MS}
+
+
+def test_pauses_are_bounded_and_the_dropped_are_counted(host, monkeypatch):
+    assert profile._REC.pauses.maxlen == profile.SLOW_MAX == 1024
+    rec = profile._Recorder({"setup": 3, "window": 3, "slow": 9, "pause": 3})
+    monkeypatch.setattr(profile, "_REC", rec)
+    _clock(monkeypatch, (i * 60 * MS for i in range(100)))
+    for i in range(5):
+        with span(f"s{i}", "x"):
+            pass
+    assert [p.name for p in profile.pauses()] == ["s2/x", "s3/x", "s4/x"]
+    assert profile.pauses_dropped() == 2
+    # the spans' own count says what it said: three lists, none lost
+    assert profile.spans_dropped() == {"setup": 0, "window": 0, "slow": 0}
+    assert len(profile.spans()) == 5
+    profile.clear()
+    assert profile.pauses() == [] and profile.pauses_dropped() == 0
+
+
+def test_a_collection_on_another_thread_is_charged_to_the_pause_it_overlaps(
+        host):
+    import gc
+    import threading
+
+    owner = type("Owner", (), {})()
+    profile.watch_collections(owner)
+    started = threading.Event()
+
+    def collect():
+        started.wait(timeout=30)
+        time.sleep(0.01)
+        gc.collect()
+
+    t = threading.Thread(target=collect)
+    t.start()
+    try:
+        with span("warm"):
+            pass
+        with span("el_sink", "fence"):
+            started.set()
+            t.join(timeout=30)
+            time.sleep(0.06)
+    finally:
+        profile.unwatch_collections(owner)
+    assert not t.is_alive()
+    (pause,) = profile.pauses()
+    assert pause.deltas["gc_collections"] >= 1
+    assert pause.deltas["gc_generation"] == 2
+    assert pause.deltas["gc_other_thread"] == 1
+    assert 0 < pause.deltas["gc_ns"] <= pause.end_ns - pause.start_ns
+    # a pause that no collection overlaps is charged none
+    with span("el_sink", "fence"):
+        time.sleep(0.06)
+    assert "gc_ns" not in profile.pauses()[-1].deltas   # nobody watches now
+
+
+def test_overlapping_collections_are_clipped_to_the_pause():
+    watch = profile._Collections()
+    watch.recent.extend([(0, 10, 0, 1), (90, 120, 2, 2), (190, 260, 1, 1),
+                         (300, 400, 2, 1)])
+    assert watch.overlapping(100, 200) == [(100, 120, 2, 2),
+                                           (190, 200, 1, 1)]
+    watch._began = (150, 3)              # one still running
+    assert watch.overlapping(100, 200)[-1] == (150, 200, -1, 3)
+    assert watch.recent.maxlen == profile.COLLECTIONS_KEPT
+
+
+def test_the_gc_callback_lives_from_the_first_start_to_the_last_stop(
+        monkeypatch):
+    import gc
+
+    from nnstreamer_tpu.core import TensorsSpec
+    from nnstreamer_tpu.elements.basic import AppSink, AppSrc
+    from nnstreamer_tpu.runtime import Pipeline
+
+    def line():
+        pipe = Pipeline()
+        src = AppSrc(name="src", spec=TensorsSpec.parse("4", "float32"))
+        sink = AppSink(name="out")
+        pipe.add(src, sink).link(src, sink)
+        return pipe
+
+    ours = profile._GC._callback
+    assert ours not in gc.callbacks
+    a, b = line(), line()
+    a.start()
+    b.start()
+    b.start()                            # playing already: nothing new
+    assert gc.callbacks.count(ours) == 1
+    a.stop()
+    assert gc.callbacks.count(ours) == 1
+    b.stop()
+    b.stop()
+    assert ours not in gc.callbacks
+    monkeypatch.setattr(hooks, "DISABLED", True)
+    a.start()
+    try:
+        assert ours not in gc.callbacks
+    finally:
+        a.stop()
+
+
+def test_report_slow_names_the_cause_once_a_name_and_cause(host, monkeypatch):
+    host.gains = {"thread_cpu_ns": 108 * MS, "process_cpu_ns": 120 * MS}
+    _clock(monkeypatch, [0, 1,
+                         10 * MS, 120 * MS, 130 * MS, 250 * MS,
+                         300 * MS, 360 * MS])
+    with span("warm"):
+        pass
+    for _ in range(2):
+        with span("el_sink", "fence"):
+            pass
+    host.gains = {"thread_cpu_ns": 0, "process_cpu_ns": MS}
+    with span("el_sink", "fence"):
+        pass
+    lines = []
+    assert profile.report_slow(lambda msg, *a: lines.append(msg % a)) == 3
+    assert len(lines) == 2
+    assert "el_sink/fence: 2 over 50 ms, 230.0 ms in all, longest " \
+        "120.0 ms; cause on_cpu (process_cpu 240.0 ms, thread_cpu " \
+        "216.0 ms)" in lines[0]
+    assert "el_sink/fence: 1 over 50 ms" in lines[1]
+    assert "; cause unexplained (process_cpu 1.0 ms, thread_cpu 0.0 ms)" \
+        in lines[1]
+    # said once: nothing is fresh at the next stop
+    assert profile.report_slow(lambda msg, *a: lines.append(msg % a)) == 0
+
+
+def test_this_hosts_own_clocks_only_grow_and_name_a_thread_that_computes():
+    a = profile._reading(1)
+    sum(i * i for i in range(200000))
+    b = profile._reading(2)
+    assert a.keys() == b.keys() == {"t_ns", "thread_cpu_ns",
+                                    "process_cpu_ns"}
+    assert all(b[k] >= a[k] for k in a)
+    assert b["thread_cpu_ns"] > a["thread_cpu_ns"]
+    # a span that computes for its whole length is the thread's own
+    # doing, by this host's clocks (the gate is wherever it is: the
+    # pause's own closing reading is the second one's baseline)
+    for _ in range(2):
+        with span("el_net", "prep"):
+            until = time.thread_time_ns() + 80 * MS
+            while time.thread_time_ns() < until:
+                pass
+    pause = profile.pauses()[-1]
+    length = pause.end_ns - pause.start_ns
+    timed = profile.charges(pause.deltas, pause.baseline_age_ns)
+    assert timed["on_cpu"] >= 70 * MS
+    assert pause.cause == ("on_cpu" if 2 * timed["on_cpu"] >= length
+                           else profile.UNEXPLAINED)
+
+
+def test_a_pause_is_kept_under_a_capture_too_and_spans_gain_no_kind(
+        capture, host):
+    with span("warm"):
+        pass
+    with span("el_net", "dispatch", 3):
+        time.sleep(0.06)
+    (pause,) = profile.pauses()
+    assert (pause.name, pause.window) == ("el_net/dispatch", 3)
+    assert {s.kind for s in profile.spans()} == {"window"}
+    assert [s.name for s in profile.spans()] == ["warm", "el_net/dispatch"]

@@ -275,7 +275,8 @@ def route(cfg: DeepSeekV2Config, x, router):
 def dispatch(cfg: DeepSeekV2Config, idx, n_tokens: int):
     """The plan of the grouped product over the experts held here
     (``models/moe.py`` ``dispatch``)."""
-    return moe.dispatch(idx, n_tokens, cfg.expert0, cfg.experts)
+    return moe.dispatch(idx, n_tokens, cfg.expert0, cfg.experts,
+                        cfg.n_routed_experts)
 
 
 grouped_experts = moe.grouped_experts          # gated by SiLU, its default

@@ -220,7 +220,8 @@ def moe_parts(cfg: ExaoneMoeConfig, p, u):
         idx, weight = moe.route_sigmoid(u, p["router"], p["router_bias"],
                                         cfg.top_k, cfg.routed_scaling_factor)
     with jax.named_scope("dispatch"):
-        plan = moe.dispatch(idx, n, cfg.expert0, cfg.experts)
+        plan = moe.dispatch(idx, n, cfg.expert0, cfg.experts,
+                            cfg.n_routed_experts)
     with jax.named_scope("experts"):
         out = moe.grouped_experts(p["experts"], u, plan, "silu")
     with jax.named_scope("combine"):

@@ -246,7 +246,8 @@ def moe_parts(cfg: LongCatFlashConfig, p, u):
     with jax.named_scope("dispatch"):
         # a pick on a zero-compute expert lies beyond every held expert
         # and falls out of the plan like one held elsewhere
-        plan = moe.dispatch(idx, n, cfg.expert0, cfg.experts)
+        plan = moe.dispatch(idx, n, cfg.expert0, cfg.experts,
+                            cfg.router_width)
     with jax.named_scope("experts"):
         out = moe.grouped_experts(p["experts"], u, plan)
     with jax.named_scope("zero"):

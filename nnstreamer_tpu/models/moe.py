@@ -4,9 +4,10 @@
 expert (counts and ranks from a one-hot of the pairs' experts and its
 running sums: no sort), the product over blocks of one expert's rows,
 and the weighted sum back to tokens.  No token is dropped and there is
-no capacity factor; only the blocks in use are computed, so the work
-follows the tokens routed to the experts HELD here (``[expert0,
-expert0 + experts)`` of the router's width), not how many are held.
+no capacity factor; only the blocks in use are computed and only the
+rows computed are summed, so the work of both follows the tokens routed
+to the experts HELD here (``[expert0, expert0 + experts)`` of the
+router's width), not how many are held nor how many picks a token has.
 
 What differs between the models is a parameter of the call: which
 experts are held, and the form of one (``silu`` or ``relu``: gated,
@@ -113,20 +114,44 @@ def block_rows(n_tokens: int) -> int:
     return int(min(256, -(-n_tokens // 8) * 8))
 
 
+def plan_rows(n_tokens: int, k: int, held: int) -> int:
+    """Rows a plan of :func:`dispatch` lays out: the pairs in whole
+    blocks, and a block more an expert held (each expert's rows end in
+    a block of their own)."""
+    blk = block_rows(n_tokens)
+    return -(-n_tokens * k // blk) * blk + held * blk
+
+
 #: The most ``rows x pairs`` cells :func:`dispatch` inverts ``dest`` over
 #: by a compare and a minimum (a decode step's plans: 0.2-3.9 M); a
 #: larger plan (a prefill chunk's: 300-650 M) takes the one scatter.
 ROW_TOKEN_COMPARE_CELLS = 1 << 23
 
 
-def dispatch(idx, n_tokens: int, expert0: int, held: int):
+def _by_row(dest, value, none, rows: int):
+    """``value [pairs]`` laid out by the row ``dest [pairs]`` sends each
+    pair to (``rows`` = none), ``none`` (above every value) in a row no
+    pair is sent to: ``dest`` inverted, over at most
+    ``ROW_TOKEN_COMPARE_CELLS`` cells of ``rows x pairs`` by a compare
+    and a minimum, over more by one scatter."""
+    if rows * dest.shape[0] <= ROW_TOKEN_COMPARE_CELLS:
+        return jnp.min(jnp.where(
+            dest[None, :] == jnp.arange(rows, dtype=jnp.int32)[:, None],
+            value[None, :], none), axis=1)
+    return jnp.full((rows,), none, value.dtype).at[dest].set(
+        value, mode="drop")
+
+
+def dispatch(idx, n_tokens: int, expert0: int, held: int, routed: int):
     """Lay the (token, expert) pairs of ``idx [n_tokens, k]`` that fall
-    on a HELD expert out by expert, each expert's rows in pair order and
-    in whole blocks.  Returns the plan of the grouped product: per padded
-    row the token it holds (``n_tokens`` = none), per pair the row its
-    result lands in (the last row = none: an expert held elsewhere), per
-    block its expert, the number of blocks in use and the tokens each
-    held expert got.
+    on a HELD expert (``held`` of the ``routed`` a pick can name) out by
+    expert, each expert's rows in pair order and in whole blocks.
+    Returns the plan of the grouped product: per padded row the token it
+    holds (``n_tokens`` = none), per pair the row its result lands in
+    (the last row = none: an expert held elsewhere), per block its
+    expert, the number of blocks in use, the tokens each held expert
+    got, and the share of picks that name an expert held ``elsewhere``
+    (static: :func:`combine` chooses its program by it).
 
     Nothing is sorted: a one-hot ``[held, pairs]`` of the pairs' experts
     gives the counts as its row sums and a pair's rank among its
@@ -138,7 +163,7 @@ def dispatch(idx, n_tokens: int, expert0: int, held: int):
     k = idx.shape[1]
     blk = block_rows(n_tokens)
     pairs = n_tokens * k
-    rows = -(-pairs // blk) * blk + held * blk
+    rows = plan_rows(n_tokens, k, held)
     local = idx.reshape(-1) - expert0
     hot = (local[None, :] == jnp.arange(held, dtype=jnp.int32)[:, None]
            ).astype(jnp.int32)
@@ -150,20 +175,15 @@ def dispatch(idx, n_tokens: int, expert0: int, held: int):
     row = (pad_end - padded)[:, None] + jnp.cumsum(hot, axis=1) - hot
     dest = jnp.where((local >= 0) & (local < held),
                      jnp.sum(hot * row, axis=0), rows)
-    token = jnp.arange(pairs, dtype=jnp.int32) // k
-    if rows * pairs <= ROW_TOKEN_COMPARE_CELLS:
-        row_token = jnp.min(jnp.where(
-            dest[None, :] == jnp.arange(rows, dtype=jnp.int32)[:, None],
-            token[None, :], n_tokens), axis=1)
-    else:
-        row_token = jnp.full((rows,), n_tokens, jnp.int32).at[dest].set(
-            token, mode="drop")
+    row_token = _by_row(dest, jnp.arange(pairs, dtype=jnp.int32) // k,
+                        n_tokens, rows)
     block_expert = jnp.minimum(jnp.searchsorted(
         pad_end, jnp.arange(rows // blk, dtype=jnp.int32) * blk,
         side="right", method="compare_all"), held - 1).astype(jnp.int32)
     return {"row_token": row_token, "dest": dest.reshape(n_tokens, k),
             "block_expert": block_expert, "blocks": pad_end[-1] // blk,
-            "counts": counts, "blk": blk, "rows": rows}
+            "counts": counts, "blk": blk, "rows": rows,
+            "elsewhere": max(routed - held, 0) / routed}
 
 
 def one_group_plan(n_rows: int):
@@ -183,7 +203,8 @@ def grouped_experts(p, x, plan, act: str = "silu"):
     one expert's rows) at a time: ``[rows + 1, hidden]``, the last row
     zero.  Only the blocks in use are computed, so the work follows the
     tokens routed here, not the held experts; a row no pair's ``dest``
-    points at holds anything.
+    points at holds anything, which is why :func:`combine` reads an
+    expert's first ``counts[e]`` rows and no other.
 
     One algorithm, two programs, chosen from the shapes: the pipelined
     kernel (``ops/kernels.py`` ``grouped_gated_product``: the next
@@ -234,7 +255,72 @@ def grouped_experts_loop(p, x, plan, act: str = "silu"):
                          jnp.zeros((rows + 1, x.shape[1]), x.dtype))
 
 
+#: elements of rows (pairs of experts held elsewhere x hidden) the
+#: gather must be spared before the walk's fixed cost is worth paying:
+#: a launch, its lists and the first copies' latency read 9-12 us alone
+#: on the chip, what the gather takes over 4 M elements (``PERF.md``
+#: section 6, PR 54)
+WALK_WORTH_ELEMENTS = 1 << 22
+
+
 def combine(out, plan, weight):
-    """Each token's weighted sum of its pairs' rows, float32."""
-    return jnp.sum(out[plan["dest"]].astype(jnp.float32)
-                   * weight[..., None], axis=1)
+    """Each token's weighted sum of its pairs' rows: ``[tokens, hidden]``
+    float32 from ``out`` (:func:`grouped_experts`'), the plan and
+    ``weight [tokens, k]`` float32.
+
+    Only rows a pair's ``dest`` points at are read, each in the type it
+    is stored in; it is widened to float32, scaled by its pair's weight
+    and added in float32.  Neither a block's padding rows nor a block
+    past ``plan["blocks"]`` enters a sum, whatever they hold.  A token
+    none of whose picks is held gets zeros; a NaN, an infinity or a
+    ``-0.0`` in a held row reaches its own token's sum and no other.
+    No value of ``[tokens, k, hidden]`` exists in either program.
+
+    One sum, two programs, chosen from the shapes.  The rows this chip
+    computed are WALKED (``ops/kernels.py`` ``weighted_row_sum``):
+    expert ``e``'s first ``plan["counts"][e]`` rows from where its
+    blocks start, each read once, a token's rows added in the order
+    they lie in (expert by expert: the pick order's sum up to the
+    float32 rounding of at most ``k`` terms), so the work follows
+    ``sum(counts)`` and not the pairs.  Where that spares little (the
+    pairs ``plan["elsewhere"]`` expects on other chips are fewer than
+    ``WALK_WORTH_ELEMENTS`` of rows: a decode step of a few hundred
+    pairs, or every expert held here) or the kernel refuses the shapes,
+    the pairs are GATHERED (:func:`combine_gather`), ``k`` rows a token
+    summed in pick order, the zero row for a pair held elsewhere.  The
+    set-up span this is traced under says which (``utils/profile.py``
+    ``note``)."""
+    tokens, k = weight.shape
+    rows, hidden = plan["rows"], out.shape[1]
+    shapes = f"combine {tokens} tokens x {k} picks of {rows} rows, " \
+             f"{tuple(out.shape)} {out.dtype.name}"
+    spared = int(tokens * k * plan["elsewhere"])
+    refusal = f"the walk would spare it {spared} rows of {tokens * k}" \
+        if spared * hidden <= WALK_WORTH_ELEMENTS else \
+        kernels.weighted_row_sum_refusal(out.shape, out.dtype, tokens,
+                                         plan["blk"])
+    if refusal:
+        _profile.note(f"{shapes}: the gather ({refusal})")
+        return combine_gather(out, plan, weight)
+    _profile.note(f"{shapes}: the row walk, tiles of "
+                  f"{kernels.row_sum_tile(tokens, hidden)} columns")
+    # a row's weight, as :func:`dispatch` finds its token; the walk
+    # reads neither of a row no pair is sent to
+    row_weight = _by_row(plan["dest"].reshape(-1), weight.reshape(-1),
+                         jnp.inf, rows)
+    return kernels.weighted_row_sum(out, plan["row_token"], row_weight,
+                                    plan["counts"], plan["blk"], tokens)
+
+
+def combine_gather(out, plan, weight):
+    """:func:`combine` a pair at a time: ``k`` gathers of one row a
+    token, each widened, scaled and added to the float32 sum in pick
+    order.  A pair held elsewhere reads the zero row: the work follows
+    the pairs.  The program of every plan the walk would spare little
+    and of every shape the kernel refuses, and what the kernel is
+    tested against."""
+    total = jnp.zeros((weight.shape[0], out.shape[1]), jnp.float32)
+    for pick in range(weight.shape[1]):
+        total = total + out[plan["dest"][:, pick]].astype(jnp.float32) \
+            * weight[:, pick, None]
+    return total

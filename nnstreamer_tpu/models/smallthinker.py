@@ -231,7 +231,8 @@ def _layers(cfg: SmallThinkerConfig, params, x, caches, attend):
                 with jax.named_scope("router"):
                     idx, weight = route(cfg, h, layer["moe"]["router"])
                 with jax.named_scope("dispatch"):
-                    plan = moe.dispatch(idx, n, 0, cfg.experts)
+                    plan = moe.dispatch(idx, n, 0, cfg.experts,
+                                        cfg.experts)
             with jax.named_scope(attn):
                 a, caches[i] = attend(i, layer["attn"], h, caches[i])
                 x = x + a

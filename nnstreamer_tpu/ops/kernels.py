@@ -1,8 +1,9 @@
 """Pallas TPU kernels: fused normalize/typecast, flash attention, short
 attention, latent decode attention, latent prefill attention,
 grouped-query decode attention, grouped-query prefill attention, the
-routed experts' grouped product, the Mamba-2 decode step and the
-Mamba-1 selective scan of a prefill chunk.
+routed experts' grouped product and the sum of its rows into their
+tokens, the Mamba-2 decode step and the Mamba-1 selective scan of a
+prefill chunk.
 
 Parity/role:
 - ``scale_bias_cast`` is the tensor_transform arithmetic prologue
@@ -59,6 +60,13 @@ Parity/role:
   one call whose grid walks the plan's blocks with the block's expert
   prefetched, so the next expert's matrices stream in while this one's
   are multiplied.
+- ``weighted_row_sum`` is what follows it (``models/moe.py``
+  ``combine``): the rows the product computed, each scaled by its
+  pair's weight and added into its token's float32 row.  The rows stay
+  in HBM; the kernel copies an expert's real rows by the plan's counts,
+  sixteen a copy and a few copies in flight, and holds a column tile of
+  the result for every token in fast memory, so its work follows the
+  pairs held HERE and no ``[tokens, picks, hidden]`` value exists.
 - ``ssm_decode_step`` is one token of the Mamba-2 recurrence for every
   stream (``models/nemotron_h.py``'s decode step): a stream's float32
   state is copied in once (from its snapshot or live, as a prefetched
@@ -80,10 +88,10 @@ multiple of the dtype's tile height (8 rows of 4-byte, 16 of 2-byte, 32
 of 1-byte elements); ``short_attention``, ``latent_decode_attention``,
 ``latent_prefill_attention``, ``gqa_decode_attention``,
 ``gqa_prefill_attention``, ``grouped_gated_product``,
-``ssm_decode_step`` and ``selective_scan`` refuse a shape they
-cannot take (``*_refusal`` says why: axes that do not fill tiles, a
-type the kernel is not written for, blocks over a fast-memory budget)
-and leave the choice to the caller.
+``weighted_row_sum``, ``ssm_decode_step`` and ``selective_scan`` refuse
+a shape they cannot take (``*_refusal`` says why: axes that do not
+fill tiles, a type the kernel is not written for, blocks over a
+fast-memory budget) and leave the choice to the caller.
 Either way the ``*_available`` / ``*_refusal`` predicates are the whole
 eligibility rule, so the fallback is a decision made here, never an
 exception caught somewhere.
@@ -2339,6 +2347,180 @@ def grouped_gated_product(x, gate, up, down, row_token, block_expert,
     )(block_expert.astype(jnp.int32),
       jnp.reshape(blocks, (1,)).astype(jnp.int32), *row_operands, *ins,
       down)
+
+
+# -- the routed experts' rows summed into their tokens ------------------------
+
+#: rows of one copy of :func:`weighted_row_sum`: a whole tile of either
+#: type it takes, so that an expert's rows are copied in pieces no
+#: larger than its count rounded up to this
+_ROW_SUM_UNIT = 16
+#: bytes one buffer of the result's column tile may take (the pipeline
+#: keeps two: a tile is written back while the next is summed), and the
+#: copies in flight beside the one being summed
+_ROW_SUM_TILE_BYTES = 24 << 20
+_ROW_SUM_SLOTS = 4
+#: 32-bit words of scalar memory the plan's two per-row arrays may take
+_ROW_SUM_SMEM_WORDS = 1 << 16
+
+
+def row_sum_tile(tokens: int, hidden: int) -> int:
+    """Columns of the result a grid step of :func:`weighted_row_sum`
+    holds for every token: the largest divisor of ``hidden`` of whole
+    lanes whose ``[tokens, tile]`` float32 fits
+    ``_ROW_SUM_TILE_BYTES``; 0 where none does."""
+    for tile in range(hidden // _LANE * _LANE, 0, -_LANE):
+        if hidden % tile == 0 and tokens * tile * 4 <= _ROW_SUM_TILE_BYTES:
+            return tile
+    return 0
+
+
+def weighted_row_sum_refusal(out_shape, dtype, tokens: int, blk: int
+                             ) -> Optional[str]:
+    """Why :func:`weighted_row_sum` cannot take these shapes, or None:
+    ``out [rows + 1, hidden]`` of bf16 or float32, ``hidden`` whole
+    lanes, blocks of whole copies, a column tile of the result that
+    fits, and a plan whose per-row arrays fit scalar memory."""
+    name = np.dtype(dtype).name
+    if name not in ("bfloat16", "float32"):
+        return f"rows of {name}: bfloat16 or float32"
+    if len(out_shape) != 2 or out_shape[1] % _LANE:
+        return f"rows of {tuple(out_shape)}: not [rows + 1, whole lanes " \
+               f"of {_LANE}]"
+    if blk < 1 or blk % _ROW_SUM_UNIT or (out_shape[0] - 1) % blk:
+        return f"blocks of {blk} rows are not whole copies of " \
+               f"{_ROW_SUM_UNIT}"
+    if not row_sum_tile(tokens, out_shape[1]):
+        return f"no column tile of [{tokens}, {out_shape[1]}] float32 " \
+               f"fits {_ROW_SUM_TILE_BYTES >> 20} MiB"
+    if 2 * (out_shape[0] - 1) > _ROW_SUM_SMEM_WORDS:
+        return f"{out_shape[0] - 1} rows: their tokens and weights do " \
+               f"not fit {_ROW_SUM_SMEM_WORDS >> 8} KiB of scalar memory"
+    return None
+
+
+def weighted_row_sum(out, row_token, row_weight, counts, blk: int,
+                     tokens: int):
+    """``[tokens, hidden]`` float32: row ``r`` of ``out [rows + 1,
+    hidden]`` times ``row_weight[r]`` (float32) added into token
+    ``row_token[r]``, over the REAL rows of a plan of ``models/moe.py``
+    ``dispatch`` alone: expert ``e``'s are the first ``counts[e]`` rows
+    from where its blocks (of ``blk`` rows) start.  A token no real row
+    names gets zeros; a token's rows are added in the order they lie
+    in, expert by expert.  No other row enters a sum: a block's padding
+    travels with the last copy of its expert's rows (at most
+    ``_ROW_SUM_UNIT - 1`` rows) and is dropped there; blocks not in use
+    are never copied.
+
+    ``out`` stays in HBM.  The grid walks column tiles of the result
+    (:func:`row_sum_tile`), which a step holds for every token; within
+    a step the kernel lists the copies the counts ask for, keeps
+    ``_ROW_SUM_SLOTS - 1`` of them in flight, widens one to float32
+    and adds its real rows, each scaled by its weight, into its
+    token's row.  The work follows ``sum(counts)``.  A shape
+    :func:`weighted_row_sum_refusal` names is an error: the caller
+    chooses."""
+    import jax.numpy as jnp
+
+    refusal = weighted_row_sum_refusal(out.shape, out.dtype, tokens, blk)
+    if refusal:
+        raise ValueError(f"weighted_row_sum: {refusal}")
+    call = _row_sum_call(out.shape[0] - 1, out.shape[1], tokens,
+                         counts.shape[0], blk, np.dtype(out.dtype).name,
+                         _interpret())
+    return call(counts.astype(jnp.int32), row_token.astype(jnp.int32),
+                row_weight.astype(jnp.float32), out)
+
+
+@functools.lru_cache(maxsize=16)
+def _row_sum_call(rows: int, hidden: int, tokens: int, held: int, blk: int,
+                  dtype: str, interpret: bool):
+    """The jitted call of :func:`weighted_row_sum` for one shape, built
+    once: a model's sparse layers share the function, so a program
+    traces and lowers one kernel (as :func:`_gqa_prefill_call`
+    does)."""
+    import jax.numpy as jnp
+
+    jax, pl, pltpu = _pl()
+    unit, slots = _ROW_SUM_UNIT, _ROW_SUM_SLOTS
+    tile = row_sum_tile(tokens, hidden)
+    ahead = slots - 1
+
+    def kernel(counts_ref, token_ref, weight_ref, out_ref, o_ref,
+               first_ref, real_ref, buf, wide, arrived):
+        col = pl.multiple_of(pl.program_id(0) * tile, _LANE)
+
+        # the copies the counts ask for, in the order the rows lie in:
+        # where each starts and how many of its rows are real
+        def expert(e, carry):
+            start, n = carry
+
+            def piece(u, n):
+                first_ref[n] = start + u * unit
+                real_ref[n] = jnp.minimum(counts_ref[e] - u * unit, unit)
+                return n + 1
+
+            n = jax.lax.fori_loop(
+                0, (counts_ref[e] + unit - 1) // unit, piece, n)
+            return start + (counts_ref[e] + blk - 1) // blk * blk, n
+
+        _, pieces = jax.lax.fori_loop(
+            0, held, expert, (jnp.int32(0), jnp.int32(0)))
+
+        def copy(n):
+            return pltpu.make_async_copy(
+                out_ref.at[pl.ds(pl.multiple_of(first_ref[n], unit), unit),
+                           pl.ds(col, tile)],
+                buf.at[n % slots], arrived.at[n % slots])
+
+        for n in range(ahead):
+            pl.when(n < pieces)(lambda n=n: copy(n).start())
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+        def summed(n, _):
+            copy(n).wait()
+            pl.when(n + ahead < pieces)(lambda: copy(n + ahead).start())
+            wide[...] = buf[n % slots].astype(jnp.float32)
+            first = first_ref[n]
+
+            def add(r, _):
+                token = pl.ds(token_ref[first + r], 1)
+                o_ref[token, :] = o_ref[token, :] \
+                    + wide[pl.ds(r, 1), :] * weight_ref[first + r]
+                return 0
+
+            jax.lax.fori_loop(0, real_ref[n], add, 0)
+            return 0
+
+        jax.lax.fori_loop(0, pieces, summed, 0)
+
+    size = np.dtype(dtype).itemsize
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(hidden // tile,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((tokens, tile), lambda c, *_: (0, c)),
+        scratch_shapes=[
+            pltpu.SMEM((rows // unit,), jnp.int32),       # a copy's first row
+            pltpu.SMEM((rows // unit,), jnp.int32),       # its real rows
+            pltpu.VMEM((slots, unit, tile), jnp.dtype(dtype)),
+            pltpu.VMEM((unit, tile), jnp.float32),
+            pltpu.SemaphoreType.DMA((slots,)),
+        ])
+    call = pl.pallas_call(
+        kernel, grid_spec=grid,
+        out_shape=jax.ShapeDtypeStruct((tokens, hidden), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * tokens * tile * 4
+            + unit * tile * (slots * size + 4) + (8 << 20)),
+        interpret=interpret)
+
+    # no ``name=``: the caller's scope (``.../moe/combine``) is the stage
+    # this call's device time is booked to
+    def weighted_row_sum(*operands):
+        return call(*operands)
+
+    return jax.jit(weighted_row_sum)
 
 
 # -- the Mamba-2 decode step --------------------------------------------------

@@ -512,8 +512,8 @@ def _experts_before_the_move(p, x, plan):
 def test_the_shared_expert_module_gives_the_same_bits(toy, files, dtype):
     """The sort-by-expert plan, the block loop and the combine moved to
     ``models/moe.py`` (which ``smallthinker.py`` runs with ReLU and all
-    its experts held): through it ``moe_parts`` gives, bit for bit, what
-    the code gave where it stood, and ReLU is another function."""
+    its experts held): through it ``moe_parts`` gives what the code gave
+    where it stood, and ReLU is another function."""
     from nnstreamer_tpu.models import moe
 
     cfg = dsv2.DeepSeekV2Config.from_dict(toy)
@@ -533,7 +533,12 @@ def test_the_shared_expert_module_gives_the_same_bits(toy, files, dtype):
                        * weight[..., None], axis=1), plan
 
     want, plan = jax.jit(before)(p, x)
-    assert np.array_equal(np.asarray(routed), np.asarray(want))
+    # the plan and the product bit for bit; the combine (since PR 54 a
+    # pick at a time, or the rows walked) to the float32 rounding of
+    # six terms summed in another order
+    np.testing.assert_allclose(
+        np.asarray(routed), np.asarray(want), rtol=0,
+        atol=6 * np.finfo(np.float32).eps * float(np.abs(want).max()))
     assert np.array_equal(np.asarray(counts), np.asarray(plan["counts"]))
     mine = dsv2.dispatch(cfg, dsv2.route(cfg, x, p["router"])[0], 40)
     for key in ("row_token", "dest", "block_expert", "blocks", "counts"):

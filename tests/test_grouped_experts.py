@@ -61,7 +61,7 @@ def _both(dtype, act, case, tile):
     n = {"one-token": 1, "a-prefill-chunk": 264}.get(case, 32)
     p = _experts(dtype, act=act)
     x = jax.random.normal(jax.random.PRNGKey(1), (n, HIDDEN)).astype(dtype)
-    plan = moe.dispatch(_routes(case, n), n, 0, HELD)
+    plan = moe.dispatch(_routes(case, n), n, 0, HELD, ROUTED)
     got = kernels.grouped_gated_product(
         x, p.get("gate"), p["up"], p["down"], plan["row_token"],
         plan["block_expert"], plan["blocks"], plan["blk"],
@@ -140,7 +140,8 @@ def test_a_refused_shape_keeps_the_loop(x, gate, dtypes, blk, says):
          "up": jax.random.normal(keys[1], gate, dtype),
          "down": jax.random.normal(keys[2], down, dtype)}
     xs = jax.random.normal(keys[3], x, dtype)
-    plan = moe.dispatch(_routes("seeded", x[0]) % gate[0], x[0], 0, gate[0])
+    plan = moe.dispatch(_routes("seeded", x[0]) % gate[0], x[0], 0, gate[0],
+                        gate[0])
     assert plan["blk"] == blk
     with pytest.raises(ValueError, match="grouped_gated_product"):
         kernels.grouped_gated_product(
@@ -165,7 +166,7 @@ def test_kernel_and_loop_are_the_per_token_product(act, inter):
                       else {"up", "down"})
     x = jax.random.normal(jax.random.PRNGKey(1), (24, HIDDEN))
     idx = _routes("seeded", 24)
-    plan = moe.dispatch(idx, 24, 0, HELD)
+    plan = moe.dispatch(idx, 24, 0, HELD, ROUTED)
     weight = jax.random.uniform(jax.random.PRNGKey(3), idx.shape)
     text = str(jax.make_jaxpr(
         lambda p, x: moe.grouped_experts(p, x, plan, act))(p, x))
@@ -196,7 +197,7 @@ def test_an_ungated_expert_streams_two_tiles_a_step():
     operands are the plan's two scalars, the rows' two, ``up`` and
     ``down``."""
     p, x = _experts(jnp.float32, act="relu2"), jnp.zeros((32, HIDDEN))
-    plan = moe.dispatch(_routes("seeded", 32), 32, 0, HELD)
+    plan = moe.dispatch(_routes("seeded", 32), 32, 0, HELD, ROUTED)
     calls = {act: _calls(jax.make_jaxpr(
         lambda p, x: moe.grouped_experts(p, x, plan, act))(q, x).jaxpr, [])
         for act, q in (("relu2", p), ("relu", _experts(jnp.float32)))}
@@ -232,7 +233,7 @@ def test_the_span_it_is_traced_under_says_which_path():
     ``trace_lower``), once for each distinct call with its count."""
     p, x = _experts(jnp.float32), jnp.zeros((32, HIDDEN))
     small = {k: v[:, :64, :64] for k, v in p.items()}
-    plan = moe.dispatch(_routes("seeded", 32), 32, 0, HELD)
+    plan = moe.dispatch(_routes("seeded", 32), 32, 0, HELD, ROUTED)
     profile.clear()
     with profile.span("el_net", "trace_lower", setup=True):
         for _ in range(2):
@@ -400,14 +401,17 @@ def test_the_plan_is_the_sorted_plan_key_for_key(case, form, monkeypatch):
     plan the stable sort gave, value for value, in either form of
     ``row_token`` (the shapes choose one; both are held here wherever
     the compare's ``rows x pairs`` cells fit a test)."""
-    n, k, expert0, held, _ = PLANS[case]
+    n, k, expert0, held, width = PLANS[case]
     if form == "compare":
         monkeypatch.setattr(moe, "ROW_TOKEN_COMPARE_CELLS", 1 << 62)
     elif form == "scatter":
         monkeypatch.setattr(moe, "ROW_TOKEN_COMPARE_CELLS", 0)
     idx = _picks(case)
     want = dispatch_by_sort(idx, n, expert0, held)
-    got = moe.dispatch(idx, n, expert0, held)
+    got = moe.dispatch(idx, n, expert0, held, width)
+    # the share of picks held elsewhere is the shapes': the sort knew
+    # no width
+    assert got.pop("elsewhere") == (width - held) / width
     assert set(got) == set(want)
     for key in ("blk", "rows"):
         assert got[key] == want[key], key
@@ -425,9 +429,10 @@ def _plan_operations(case, dispatch=moe.dispatch):
     """``{operation: count}`` of the sorts, scatters and gathers in the
     lowered plan of ``case``: operations, not the words (a scatter's own
     attributes say ``indices_are_sorted``)."""
-    n, k, expert0, held, _ = PLANS[case]
-    text = jax.jit(dispatch, static_argnums=(1, 2, 3)).lower(
-        _picks(case), n, expert0, held).as_text()
+    n, k, expert0, held, width = PLANS[case]
+    sizes = (n, expert0, held) + ((width,) if dispatch is moe.dispatch else ())
+    text = jax.jit(dispatch, static_argnums=tuple(range(1, 1 + len(sizes)))
+                   ).lower(_picks(case), *sizes).as_text()
     return {op: len(re.findall(rf'= "?stablehlo\.{op}"?[ (]', text))
             for op in ("sort", "scatter", "gather")}
 

@@ -484,7 +484,7 @@ def test_dispatch_drops_every_pick_beyond_the_real_experts():
     idx = np.stack([rng.permutation(768)[:12] for _ in range(128)])
     idx[0] = np.arange(512, 524)                 # a token of zero picks
     idx[1, :3] = [0, 7, 8]
-    plan = moe.dispatch(jnp.asarray(idx, jnp.int32), 128, 0, 8)
+    plan = moe.dispatch(jnp.asarray(idx, jnp.int32), 128, 0, 8, 768)
     here = idx < 8
     assert int(plan["counts"].sum()) == here.sum()
     assert np.array_equal(np.asarray(plan["counts"]),

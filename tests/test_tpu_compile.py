@@ -121,6 +121,43 @@ def test_grouped_gated_product_compiles_at_the_cells_shapes(
         < (1 << 20) + (grid * blk * hidden * 2 if tokens > blk else 0)
 
 
+@pytest.mark.parametrize("tokens,k,held,hidden,tile", [
+    (2048, 12, 8, 6144, 3072), (128, 12, 8, 6144, 6144),
+    (2048, 6, 40, 5120, 2560), (2048, 8, 16, 6144, 3072),
+    (2048, 6, 16, 2688, 2688), (2048, 6, 64, 2560, 2560)],
+    ids=["longcat.prefill", "longcat.decode4k", "dsv2.prefill",
+         "kexaone.prefill", "nemotron3.prefill", "every-pair-held"])
+def test_weighted_row_sum_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch, tokens, k, held, hidden, tile):
+    """The routed experts' rows summed into their tokens (PR 54), bf16
+    rows into float32, as a Mosaic kernel at the shapes `combine` walks
+    (four cells' chunks and `longcat.decode4k`'s step) and at a chunk
+    whose every pair is held (which `combine` gathers): the plan's
+    26,624-28,672 tokens and weights a row in scalar memory, the
+    result's column tile (two buffers of up to 24 MiB) and four copies
+    of 16 rows in fast memory, which the call asks for; one custom
+    call and nothing beside it."""
+    from nnstreamer_tpu.models import moe
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    blk, rows = moe.block_rows(tokens), moe.plan_rows(tokens, k, held)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    assert kernels.weighted_row_sum_refusal(
+        (rows + 1, hidden), jnp.bfloat16, tokens, blk) is None
+    assert kernels.row_sum_tile(tokens, hidden) == tile
+    fn = jax.jit(functools.partial(kernels.weighted_row_sum, blk=blk,
+                                   tokens=tokens))
+    compiled = fn.lower(shape((rows + 1, hidden), jnp.bfloat16),
+                        shape((rows,), jnp.int32),
+                        shape((rows,), jnp.float32),
+                        shape((held,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def test_the_hybrid_cells_kernels_compile_at_its_shapes(one_chip, monkeypatch):
     """`nemotron3.decode4k`'s calls, bf16, as Mosaic kernels: attention
     of 128 streams, 2 groups of 16 heads of 128 over a dense cache of
@@ -617,8 +654,20 @@ def test_the_two_cache_cells_programs_fit_the_chip(one_chip, monkeypatch,
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 15.5e9
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == product_calls + (
+    # since PR 54 a sparse layer's `moe/combine` is a kernel too (the
+    # row walk, `weighted_row_sum`: as many as grouped products) in
+    # both chunks and in `longcat.decode4k`'s step of 1,536 pairs; a
+    # step of `dsv2.decode16k`'s 192 pairs gathers them
+    walks = entry == "prefill" or cell.startswith("longcat")
+    assert text.count("tpu_custom_call") == (1 + walks) * product_calls + (
         step_calls if entry == "decode" else chunk_calls)
+    # and no value a (token, pick) pair is left: `[tokens, k, hidden]`,
+    # float32 or not, nor a gather of `tokens x k` rows
+    tokens, (k, hidden) = lengths[0], (6, 5120) if cell.startswith(
+        "deepseek") else (12, 6144)
+    assert f"[{tokens},{k},{hidden}]" not in text
+    assert not [line.strip()[:120] for line in text.splitlines()
+                if f"[{tokens * k},{hidden}]" in line and " gather(" in line]
     # no copy, transposition or relayout of a cache's shape
     shape = "[" + ",".join(str(n) for n in caches[0].shape) + "]"
     moved = [line.strip()[:120] for line in text.splitlines()
